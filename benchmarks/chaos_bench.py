@@ -998,7 +998,7 @@ async def run_integrity(seed: int = 20260806) -> dict:
 
 
 def run_integrity_gates(report: dict) -> list[str]:
-    """Hard gates (ISSUE 18 / BENCHMARKS.md "integrity"). Returns
+    """Hard gates (ISSUE 18). Returns
     failures; empty means every injected corruption was detected on the
     right tier and zero streams diverged."""
     failures: list[str] = []

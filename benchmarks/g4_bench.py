@@ -474,7 +474,7 @@ async def run_g4(
 
 
 def run_gates(report: dict) -> list[str]:
-    """Hard gates (BENCHMARKS.md 'G4 peer tier'). Returns failures."""
+    """Hard gates. Returns failures."""
     failures: list[str] = []
     pull = report["pull"]
     if not pull["streams_identical"]:

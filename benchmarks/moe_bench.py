@@ -1,6 +1,6 @@
 """MoE dispatch microbenchmark: dense (all experts, gate-masked) vs
 capacity (per-expert buffers, selected FLOPs only), single-device and
-under an ep-sharded mesh (VERDICT r03 #7).
+under an ep-sharded mesh.
 
 Dense computes E/topk times the selected FLOPs; capacity pays
 scatter/gather dispatch. This measures the crossover that backs the

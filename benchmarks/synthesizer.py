@@ -111,7 +111,7 @@ def prefix_stats(reqs: list[Request]) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Trace-driven replay (VERDICT r03 missing #5). Two on-disk formats:
+# Trace-driven replay. Two on-disk formats:
 #
 # - Mooncake-format JSONL (the reference synthesizer's input —
 #   reference: benchmarks/data_generator/synthesizer.py:48-75): one record

@@ -1,6 +1,6 @@
 """KV-block transfer benchmark: device path (HBM→HBM) vs host-staged TCP.
 
-VERDICT r02 #6's acceptance gate: the same-process device path must move
+The acceptance gate: the same-process device path must move
 blocks ≥5× faster than gather→TCP→scatter. Run on the real chip:
 
     python benchmarks/transfer_bench.py

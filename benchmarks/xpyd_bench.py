@@ -6,7 +6,7 @@ clock, constants pinned to the recorded r04/r05 chip runs by
 planner/calibration.py) across 1P1D / 2P1D / 2P2D disaggregated
 topologies and aggregated baselines — both throughput-max ``batch``
 mode and the SLO-holding ``coloc`` mode (the PR 8 unified-step shape) —
-and emits the projection table BENCHMARKS.md records (ROADMAP #4: the
+and emits the projection table (ROADMAP #4: the
 pillar-#1 "+30 % disagg" claim, finally quantified).
 
 Legs:
@@ -108,9 +108,9 @@ def drain_leg(
 
 def router_ab(trials: int = 200, seed: int = 0) -> dict:
     """Heterogeneous-link A/B through the production selector
-    (llm/kv_router/scheduler.py): worker 1 ingests at the measured
-    21.7 GB/s device rate, worker 2 at the measured 0.012 GB/s host-
-    roundtrip rate (BENCHMARKS.md "Batched KV block IO"). Identical
+    (llm/kv_router/scheduler.py): worker 1 ingests at the calibrated
+    21.7 GB/s device rate, worker 2 at a 0.012 GB/s host-roundtrip
+    rate (both from an older harness, not reproduced). Identical
     load and overlap otherwise — plain mode has no reason to prefer
     either (ties split via the predicted-load bump), network-aware mode
     must send decode traffic to the fast link."""
@@ -177,7 +177,7 @@ def run_gates(
         "disagg_beats_single_agg": (
             by_top["2P1D"]["tok_s"] > by_top["1xAGG"]["tok_s"]
         ),
-        # The BENCHMARKS.md "+30%" pillar-claim bound, enforced HERE so
+        # The "+30%" pillar-claim bound, enforced HERE so
         # the ci.sh leg (not just the test suite) fails if a cost-model
         # change erodes the projected margin.
         "disagg_beats_coloc_fleet_by_30pct": (

@@ -79,8 +79,7 @@ ingress_leg() {
 
 g4_leg() {
   say "mocker G4 peer tier"
-  # G4 peer-tier leg (docs/architecture/kvbm_g4.md; BENCHMARKS.md "G4
-  # peer tier"): a cold worker PULLS a fleet peer's packed KV rows
+  # G4 peer-tier leg (docs/architecture/kvbm_g4.md): a cold worker PULLS a fleet peer's packed KV rows
   # instead of recomputing them, pre-placement warms a joining worker
   # before traffic reaches it, and a peer killed mid-pull degrades to
   # local recompute. HARD-FAILS unless the pulled TTFT beats recompute
@@ -101,8 +100,7 @@ wquant_leg() {
   # weight-bytes term — the freed weight HBM converts to KV lanes.
   # HARD-FAILS unless the int8-weights leg delivers >= 1.3x decode
   # tok/s/chip at equal ITL SLO with zero mid-traffic compiles and the
-  # unchanged <= 8-program budget ladder (BENCHMARKS.md "Weight quant
-  # A/B"). Toggles: WQUANT_ONLY=1 runs just this leg (the ci.yml red
+  # unchanged <= 8-program budget ladder. Toggles: WQUANT_ONLY=1 runs just this leg (the ci.yml red
   # check); SKIP_WQUANT=1 skips it (when it already ran standalone).
   BENCH_WQUANT=1 python bench.py
 }
@@ -136,7 +134,7 @@ spec_leg() {
   # and the recorded phased-spec baseline, warmup stays within the
   # budget ladder (spec adds ZERO programs), every leg pays zero
   # mid-traffic compiles, and the auto-gate's free-when-losing
-  # probe-window bound holds (BENCHMARKS.md "Speculative decode A/B").
+  # probe-window bound holds.
   # Toggles: SPEC_ONLY=1 runs just this leg (the ci.yml red check);
   # SKIP_SPEC=1 skips it (when it already ran standalone).
   BENCH_SPEC=1 python bench.py
@@ -337,15 +335,14 @@ if [[ -z "${SKIP_BENCH:-}" ]]; then
   # ISL3000-style mixed load — HARD-FAILS unless the adaptive leg's
   # decode ITL p95 holds within the SLO, its prefill throughput meets
   # or exceeds the static baseline's, and it pays zero mid-traffic
-  # compiles (BENCHMARKS.md "Co-location A/B").
+  # compiles.
   BENCH_SMOKE=1 BENCH_MOCKER=1 BENCH_COLOC=1 python bench.py
   say "mocker quant A/B"
   # Quantized-KV leg (docs/architecture/kv_quant.md): int8 KV at the
   # SAME simulated HBM byte budget vs the bf16 baseline, priced by the
   # r04-calibrated decode HBM-bytes term — HARD-FAILS unless int8
   # delivers >= 1.5x decode tok/s/chip at equal ITL SLO with zero
-  # mid-traffic compiles and the unchanged <= 8-program budget ladder
-  # (BENCHMARKS.md "Quantized KV A/B").
+  # mid-traffic compiles and the unchanged <= 8-program budget ladder.
   BENCH_QUANT=1 python bench.py
   if [[ -z "${SKIP_WQUANT:-}" ]]; then
     wquant_leg
@@ -390,10 +387,10 @@ if [[ -z "${SKIP_BENCH:-}" ]]; then
   say "xPyD fleet projection"
   # Fleet-planner leg (ROADMAP #4; docs/architecture/planner.md): the
   # calibrated-mocker xPyD simulation — HARD-FAILS unless the mocker
-  # cost model reproduces the recorded BENCH_r04 headline within 10%,
+  # cost model reproduces the recorded r04 headline (older harness, not reproduced) within 10%,
   # the 2P1D topology beats the 1-worker aggregated baseline on the
   # prefill-heavy replay, and a decode scale-down mid-run drops zero
-  # requests (BENCHMARKS.md "xPyD projection").
+  # requests.
   BENCH_XPYD=1 python bench.py
   say "network-aware router A/B"
   # NetKV-style decode selection on heterogeneous simulated links: the

@@ -28,7 +28,6 @@ import asyncio
 import contextlib
 import json
 import logging
-import os
 import signal
 import sys
 import time
@@ -188,10 +187,11 @@ def build_parser() -> argparse.ArgumentParser:
                      metavar="DIR|auto|none",
                      help="persistent XLA compile cache base dir "
                           "(fingerprint-namespaced; warmed programs "
-                          "replay from disk on relaunch). auto = "
-                          "$DYNAMO_TPU_COMPILE_CACHE_DIR, else under the "
-                          "model dir, else ~/.cache/dynamo_tpu/xla; "
-                          "none disables")
+                          "replay from disk on relaunch). "
+                          "$JAX_COMPILATION_CACHE_DIR, when set, is the "
+                          "directory whatever is given here; auto = "
+                          "$DYNAMO_TPU_COMPILE_CACHE_DIR, else "
+                          ".jax_cache in the checkout; none disables")
     run.add_argument("--shape-manifest", default=None, metavar="FILE.json",
                      help="shape-manifest path (records the shapes "
                           "serving executes; warmup compiles exactly "
@@ -361,30 +361,6 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _apply_platform_env() -> None:
-    """Honor JAX_PLATFORMS even when the interpreter's startup hooks
-    (sitecustomize) pre-registered another platform: the env var must
-    win, or `JAX_PLATFORMS=cpu dynamo-tpu run --mesh sp=8 ...` silently
-    lands on whatever backend was pre-selected. Called from the
-    device-using command handlers only — non-device subcommands
-    (control-plane, api-store, operator, --help) must not pay the jax
-    import."""
-    want_platform = os.environ.get("JAX_PLATFORMS")
-    if not want_platform:
-        return
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", want_platform)
-    except Exception as exc:  # noqa: BLE001 — backend already initialized
-        print(
-            f"warning: JAX_PLATFORMS={want_platform} did not take "
-            f"effect (backend already initialized: {exc}) — running on "
-            f"{jax.default_backend()}",
-            file=sys.stderr,
-        )
-
-
 def main(argv: list[str] | None = None) -> None:
     args = build_parser().parse_args(argv)
     logging.basicConfig(
@@ -392,7 +368,6 @@ def main(argv: list[str] | None = None) -> None:
         format="%(asctime)s %(levelname).1s %(name)s: %(message)s",
     )
     if args.cmd == "run":
-        _apply_platform_env()
         asyncio.run(_run(args))
     elif args.cmd == "control-plane":
         asyncio.run(_control_plane(args))
@@ -883,11 +858,6 @@ def _tpu_local_and_cfg(args):
     )
     max_len = min(args.max_model_len, local.card.context_length)
     local.card.context_length = max_len
-    model_dir = (
-        local.model_path
-        if local.model_path and Path(local.model_path).is_dir()
-        else None
-    )
     ecfg = EngineConfig(
         model=local.config,
         dtype=args.dtype,
@@ -911,9 +881,7 @@ def _tpu_local_and_cfg(args):
         coordinator=args.coordinator,
         num_nodes=args.num_nodes,
         node_rank=args.node_rank,
-        compile_cache_dir=resolve_cache_base(
-            args.compile_cache_dir, model_dir
-        ),
+        compile_cache_dir=resolve_cache_base(args.compile_cache_dir),
         shape_manifest_path=args.shape_manifest,
         # With warmup on, hold admission until the hot shape set compiles
         # (requests queue instead of racing the compiles); --no-warmup
@@ -1079,7 +1047,7 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             tail = engine.warm_tail_pending
             print(
                 f"warmup: {n} programs in {time.monotonic() - t0:.1f}s "
-                f"({cs.replayed_programs} replayed from cache"
+                f"({cs.replayed_programs} already in the ledger"
                 + (f", {tail} deferred to background" if tail else "")
                 + ") — engine ready",
                 flush=True,

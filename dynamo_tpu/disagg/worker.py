@@ -327,8 +327,7 @@ class PrefillWorker:
     async def _run(self) -> None:
         # Drain in BATCHES up to the engine's fused prefill width: a
         # serial per-request drain left the prefill engine at 1/lanes of
-        # its fused prefill throughput (the r05 disagg-bench diagnosis —
-        # BENCHMARKS.md "Disaggregation measured on the chip").
+        # its fused prefill throughput.
         width = max(1, getattr(self.engine.cfg, "prefill_batch", 1))
         while not self._stopping.is_set():
             got = await self.queue.dequeue(timeout_s=0.2)

@@ -1,7 +1,7 @@
 """SLO-aware prefill/decode co-location controller (ROADMAP item #3).
 
-r05 measured the honest result that a one-chip prefill/decode SPLIT
-loses 0.33-0.43x. The unified step (docs/architecture/unified_step.md)
+An older harness recorded (not reproduced) that a one-chip
+prefill/decode SPLIT loses 0.33-0.43x. The unified step (docs/architecture/unified_step.md)
 built the third option's mechanism — one ragged dispatch mixing decode
 lanes with chunked-prefill quanta — but left the policy static: a
 hand-tuned ``unified_prefill_quantum``. This module is the policy: the

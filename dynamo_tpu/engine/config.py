@@ -43,8 +43,7 @@ class EngineConfig:
     # tp head-sharding (per-device KV = 1/(sp*tp) of the total). The
     # engine allocator stripes logical block i onto sp shard i % sp and
     # each shard's attention (Pallas or jnp) scans ONLY its own stripe,
-    # so attention FLOPs partition over sp too (measured ~ideal:
-    # BENCHMARKS.md r05); per-shard partials merge with a logsumexp
+    # so attention FLOPs partition over sp too; per-shard partials merge with a logsumexp
     # combine (ops/attention.py AttnDispatch). Requires sp > 1 and
     # num_blocks % sp == 0 (validated at runner build).
     kv_sp: bool = False
@@ -82,8 +81,8 @@ class EngineConfig:
     # kv_quant (weights and KV halve independently). Supersedes the
     # legacy whole-model `quant` flag (mutually exclusive).
     weight_quant: str | None = None
-    # EXPERIMENTAL (r05 A/B: net −17% on the random-weight harness, no
-    # demonstrated win without a real checkpoint — BENCHMARKS.md r05;
+    # EXPERIMENTAL (net −17% on random weights — older harness, not
+    # reproduced; no demonstrated win without a real checkpoint;
     # watch spec_tokens_per_step on /metrics before enabling in prod).
     # Prompt-lookup speculative decoding ON THE UNIFIED STEP
     # (docs/architecture/unified_step.md "Speculative decode on the
@@ -96,16 +95,16 @@ class EngineConfig:
     # prefixes (exact equivalence with sequential greedy); sampled
     # lanes fall back to 1 token/step.
     speculative_k: int = 0
-    # Speculative auto-gating (VERDICT r03 weak #7): each spec step scores
+    # Speculative auto-gating: each spec step scores
     # K+1 positions, so below ~1.4 delivered tokens/step speculation is a
-    # net LOSS (~27% measured at K=3, BENCHMARKS.md). The engine tracks
+    # net LOSS (~27% at K=3 — older harness, not reproduced). The engine tracks
     # delivered tokens/step over a rolling window; if the mean sits below
     # break-even it falls back to plain decode, then re-probes after
     # speculative_probe_steps plain steps in case traffic changed.
     speculative_break_even: float = 1.4
     speculative_window: int = 128      # spec steps per measurement window
     speculative_probe_steps: int = 1024  # plain steps before re-probing
-    # Re-probe cost cap (VERDICT weak #6 "free when losing"): a re-probe
+    # Re-probe cost cap: a re-probe
     # after the gate disabled speculation runs only this many spec steps
     # before re-judging, instead of a full speculative_window — so on
     # traffic where speculation keeps losing, the steady-state overhead is
@@ -168,7 +167,7 @@ class EngineConfig:
 
     # Host-tier (G2) onboarding is only a win when moving the bytes beats
     # recomputing the prefill — true on PCIe-attached hosts, false when the
-    # host↔device link is slow (e.g. a tunneled dev chip). The engine
+    # host↔device link is slow. The engine
     # measures both rates live (EMA of onboard bytes/s and prefill tok/s)
     # and skips onboarding while it predicts a loss; the first onboard
     # always runs to seed the estimate.

@@ -15,8 +15,7 @@ loading them raises with a clear message (TPU serving wants bf16; requant
 is an offline tool's job).
 
 A minimal writer is included for building fixture/test files and for
-shipping tokenizer+config snapshots (the model-card "GGUF build" gap in
-VERDICT r02 §L1).
+shipping tokenizer+config snapshots (the model-card "GGUF build" gap).
 """
 
 from __future__ import annotations

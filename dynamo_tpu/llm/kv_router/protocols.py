@@ -26,7 +26,7 @@ class ForwardPassMetrics:
     gpu_cache_usage_perc: float = 0.0
     gpu_prefix_cache_hit_rate: float = 0.0
     data_parallel_rank: int = 0
-    # Speculative decoding observability (VERDICT r04 weak #6): delivered
+    # Speculative decoding observability: delivered
     # tokens per spec step (≥1.0 when winning; 0.0 = engine not built
     # with speculative_k), whether the auto-gate currently has it on,
     # and the unified draft-verify split — draft tokens fed vs accepted

@@ -58,7 +58,7 @@ class KvRouterConfig:
     # the absolute value just scales the audited transfer_ms.
     block_bytes: int = 16 * KV_BYTES_PER_TOKEN
     # Fallback link when a worker exports no rate EMA yet (fresh spawn,
-    # no KVBM): the measured batched device channel (BENCHMARKS.md),
+    # no KVBM): the measured batched device channel,
     # single-sourced from planner/calibration.py so a re-fit reprices
     # the router and the G4 peer tier together (drift-gated in
     # tests/test_calibration.py).

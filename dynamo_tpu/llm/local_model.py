@@ -11,7 +11,7 @@ Accepted references:
 - a local directory — HF checkout: ``config.json`` + ``*.safetensors`` +
   tokenizer files.
 - ``hf://org/name`` — resolved through the local HF hub cache
-  (``HF_HOME``/``~/.cache/huggingface``); zero-egress environments must have
+  (``HF_HOME``, or the hub's default under the home directory); zero-egress environments must have
   the snapshot pre-cached (reference: lib/llm/src/hub.rs).
 """
 

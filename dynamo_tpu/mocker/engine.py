@@ -70,8 +70,8 @@ class MockerConfig:
     #     / (decode_hbm_gbps · 1e9)   seconds.
     # 0.0 keeps the legacy context-free pricing (every existing
     # scenario unchanged). Calibrated values live in
-    # planner/calibration.py: decode_hbm_gbps from BENCH_r04's measured
-    # 282.8 GB/s effective, kv_bytes_per_token = the 32 KiB/token 1B
+    # planner/calibration.py: decode_hbm_gbps from the r04 recording's
+    # 282.8 GB/s effective (older harness, not reproduced), kv_bytes_per_token = the 32 KiB/token 1B
     # layout, kv_bytes_ratio ~0.502 for int8+scales (1.0 bf16).
     decode_hbm_gbps: float = 0.0
     kv_bytes_per_token: float = float(KV_BYTES_PER_TOKEN)
@@ -89,7 +89,7 @@ class MockerConfig:
     # so un-calibrated scenarios can A/B precision. Defaults (0.0 / 1.0)
     # keep every existing scenario byte-identical. Calibrated value:
     # planner/calibration.py WEIGHT_BYTES_PER_STEP (~3.02 GB, the r04
-    # base at the measured 282.8 GB/s); int8-weights ratio ~0.501 from
+    # base at the recorded 282.8 GB/s — older harness, not reproduced); int8-weights ratio ~0.501 from
     # calibration.weight_quant_bytes_ratio().
     weight_bytes_per_step: float = 0.0
     weight_bytes_ratio: float = 1.0

@@ -100,7 +100,7 @@ class ModelConfig:
     # compute; capacity overflow drops follow the standard rule).
     # "auto" (default) picks capacity when num_experts >= 16 — the
     # crossover where dense's E/topk FLOP waste outweighs dispatch
-    # overhead (measured in BENCHMARKS.md "MoE dispatch").
+    # overhead (older harness, not reproduced).
     moe_dispatch: str = "auto"
     moe_capacity_factor: float = 2.0
 

@@ -74,7 +74,7 @@ class MoeConfig:
 
 
 # Dense runs E/topk times the selected FLOPs; capacity pays scatter/gather
-# overhead. E=16 is the measured crossover region (BENCHMARKS.md).
+# overhead. E=16 is the measured crossover region.
 AUTO_CAPACITY_MIN_EXPERTS = 16
 
 

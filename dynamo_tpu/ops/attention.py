@@ -27,13 +27,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.utils.jax_compat import ensure_current_defaults
-
-# Drift-sensitive defaults (threefry partitionability) must be set before
-# the first trace anywhere in the process — every engine/model path
-# imports this module ahead of touching params or caches.
-ensure_current_defaults()
-
 NEG_INF = -1e30
 
 
@@ -83,9 +76,7 @@ class AttnDispatch:
     kv_sp: bool = False
 
     def _wrap(self, fn, in_specs, out_specs):
-        from dynamo_tpu.utils.jax_compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -465,8 +456,7 @@ def _prefill_partials(
     ``offset, offset+stride, offset+2*stride, ...`` — the striped-scan
     mode where sp shard r (holding the blocks the striped allocator
     placed at logical indices ≡ r mod sp) scans ONLY its own pages, so
-    attention FLOPs partition sp-ways along with the memory (the r04
-    full-scan replication VERDICT flagged is gone)."""
+    attention FLOPs partition sp-ways along with the memory."""
     T, H, D = q.shape
     kvH = k_cache.shape[1]
     G = H // kvH
@@ -738,7 +728,7 @@ def full_causal_attention(
 # ---------------------------------------------------------------------------
 # sp-sharded cache: the paged KV SLOT axis sharded over the `sp` mesh axis,
 # so total KV CAPACITY is sp x one device's arrays — the beyond-chip
-# long-context mode (SURVEY §5; VERDICT r03 #6). With ``num_shards`` set,
+# long-context mode (SURVEY §5). With ``num_shards`` set,
 # each shard runs a STRIDED scan over only the logical pages the striped
 # allocator (engine/kv_cache.py BlockAllocator num_shards) placed on it —
 # attention FLOPs and memory both partition sp-ways. Partials then merge

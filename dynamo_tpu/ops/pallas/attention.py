@@ -20,10 +20,13 @@ Cache-layout contract (Mosaic DMA constraints drove this):
   padding is mathematically transparent to attention (zero lanes add
   nothing to scores or outputs). ``pallas_supported()`` gates the path;
   unsupported shapes fall back to the jnp reference.
-- inside the kernel, per-page refs are re-viewed as ``[bs, kvH, D]`` via
-  ``Ref.reshape`` (a sublane-merge view, which Mosaic supports — lane
-  splits are not) and consumed by dot_generals whose batch dim sits at
-  different positions per operand, avoiding any VMEM transposes.
+- inside the kernel, a ring slot's pages are loaded as their 2-D tile,
+  cast to f32 and the VALUE reshaped to ``[rows, kvH, D]``
+  (``heads_view``). Re-viewing the packed REF instead compiles for any
+  ``kvH`` but reads the wrong rows on the chip unless ``kvH`` fills a
+  sublane tile (8): exact at the 1B/8B's 8 kv heads, wrong on a tp=4
+  shard's 2 (found on the v5e by chip_smoke.py, PR 22; interpret mode
+  cannot show it).
 
 Reference provenance: the reference delegates paged attention to
 vLLM/FlashAttention CUDA kernels (SURVEY §2 'Native components' #3 makes a
@@ -43,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.utils.jax_compat import MEMORY_SPACE_ANY
+MEMORY_SPACE_ANY = pltpu.MemorySpace.ANY
 
 NEG_INF = -1e30
 LANE = 128
@@ -61,6 +64,12 @@ def pallas_supported(block_size: int, kvH: int, D: int, dtype) -> bool:
     # quantized-KV cache dtype — docs/architecture/kv_quant.md).
     sublane = {1: 32, 2: 16}.get(jnp.dtype(dtype).itemsize, 8)
     return D % LANE == 0 and (block_size * kvH) % sublane == 0
+
+
+def heads_view(buf, slot, rows: int, kvH: int, D: int):
+    """Ring slot ``slot`` of a page buffer ``[NBUF, rows*kvH, D]`` as f32
+    ``[rows, kvH, D]`` — load, cast, THEN reshape (module docstring)."""
+    return buf[slot].astype(jnp.float32).reshape(rows, kvH, D)
 
 
 def cache_head_dim(D: int) -> int:
@@ -242,12 +251,8 @@ def _decode_kernel(
                 (s0 + i * PP) * bs
                 + jax.lax.broadcasted_iota(jnp.int32, (PP * bs, 1, 1), 0)
             ) < nb * bs
-            k = k_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(
-                jnp.float32
-            )
-            v = v_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(
-                jnp.float32
-            )
+            k = heads_view(k_buf, slot, PP * bs, kvH, D)
+            v = heads_view(v_buf, slot, PP * bs, kvH, D)
             v = jnp.where(fetched, v, 0.0)
             kT = jnp.swapaxes(k, 0, 1)  # [kvH, PP*bs, D]
             vT = jnp.swapaxes(v, 0, 1)
@@ -395,10 +400,10 @@ def paged_decode_attention_pallas(
 
 # Pages folded into one prefill pipeline step, mirroring DECODE_PP: one
 # wait + ONE attention fold per PP pages widens the score matmuls' key
-# dimension from bs (=16) to PP*bs (=128) — the r05 8B profile measured
-# the single-page prefill kernel at ~65% of prefill device time with
-# ~2.6% MFU in its dots; PP-wide folds are the same fix that took the
-# decode kernel 160→78 µs/layer in r04.
+# dimension from bs (=16) to PP*bs (=128) — an older harness's 8B profile
+# (not reproduced) put the single-page prefill kernel at ~65% of prefill
+# device time with ~2.6% MFU in its dots; PP-wide folds are the same fix
+# that took the decode kernel 160→78 µs/layer there.
 PREFILL_PP = 8
 
 
@@ -522,8 +527,8 @@ def _prefill_kernel(
                 jnp.int32, (PP * bs, 1, 1), 0
             ) // bs
         ) < nb
-        k = k_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(jnp.float32)
-        v = v_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(jnp.float32)
+        k = heads_view(k_buf, slot, PP * bs, kvH, D)
+        v = heads_view(v_buf, slot, PP * bs, kvH, D)
         v = jnp.where(fetched, v, 0.0)
         kT = jnp.swapaxes(k, 0, 1)  # [kvH, PP*bs, D]
         vT = jnp.swapaxes(v, 0, 1)

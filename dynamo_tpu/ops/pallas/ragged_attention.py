@@ -49,7 +49,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.utils.jax_compat import MEMORY_SPACE_ANY, tpu_memory_space
+from dynamo_tpu.ops.pallas.attention import heads_view
+
+MEMORY_SPACE_ANY = pltpu.MemorySpace.ANY
 
 NEG_INF = -1e30
 
@@ -218,12 +220,8 @@ def _ragged_kernel(
                         jnp.int32, (PP * bs, 1, 1), 0
                     ) // bs
                 ) < nb
-                k = k_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(
-                    jnp.float32
-                )
-                v = v_buf.at[slot].reshape(PP * bs, kvH, D)[...].astype(
-                    jnp.float32
-                )
+                k = heads_view(k_buf, slot, PP * bs, kvH, D)
+                v = heads_view(v_buf, slot, PP * bs, kvH, D)
                 if quantized:
                     # In-register dequant: one [kvH] scale row per page,
                     # loaded from VMEM by physical page id (same id the
@@ -235,12 +233,8 @@ def _ragged_kernel(
                     for h in range(PP):
                         j = jnp.minimum(f * PP + h, max_blocks - 1)
                         page = block_tables_ref[s, j]
-                        ks = pl.load(
-                            k_scales_ref, (pl.ds(page, 1), slice(None))
-                        )  # [1, kvH]
-                        vs = pl.load(
-                            v_scales_ref, (pl.ds(page, 1), slice(None))
-                        )
+                        ks = k_scales_ref[pl.ds(page, 1), :]  # [1, kvH]
+                        vs = v_scales_ref[pl.ds(page, 1), :]
                         ks_rows.append(jnp.broadcast_to(ks, (bs, kvH)))
                         vs_rows.append(jnp.broadcast_to(vs, (bs, kvH)))
                     k = k * jnp.concatenate(ks_rows, axis=0)[:, :, None]
@@ -359,7 +353,7 @@ def ragged_paged_attention_pallas(
     # aligning spans. The pad rows are never written back.
     qpad = jnp.pad(q, ((0, TQ), (0, 0), (0, 0)))
 
-    vmem = tpu_memory_space().VMEM
+    vmem = pltpu.MemorySpace.VMEM
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(S,),

@@ -1,8 +1,7 @@
 """Weight-only int8 quantization for the serving path.
 
 Decode is weight-streaming-bound: every step reads the full parameter set
-from HBM, so bytes/param is the throughput ceiling (BENCHMARKS.md measures
-the bf16 path at ~48% of v5e HBM peak). Storing the big matmul weights as
+from HBM, so bytes/param is the throughput ceiling. Storing the big matmul weights as
 int8 with per-output-channel symmetric scales halves the streamed bytes;
 XLA fuses the int8→bf16 convert into the matmul operand read, so the MXU
 still runs a bf16 contraction and nothing extra round-trips through HBM.
@@ -331,7 +330,7 @@ def quant_tree_stats(params: Params, dtype_bytes: int = 2) -> tuple[float, float
 # ---------------------------------------------------------------------------
 # KV-cache block quantization (docs/architecture/kv_quant.md).
 #
-# Decode is HBM-bandwidth-bound (BENCH_r04 measured 282.8 GB/s effective),
+# Decode is HBM-bandwidth-bound (282.8 GB/s effective — older harness, not reproduced),
 # so int8 KV blocks roughly double effective decode bandwidth AND double
 # KV capacity per chip. The cache keeps its [num_slots, kvH, D] layout but
 # stores int8; a per-(block, kv-head) float32 scale rides alongside the

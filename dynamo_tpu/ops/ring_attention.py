@@ -96,10 +96,8 @@ def ring_attention_sharded(mesh, q, k, v, axis_name: str = "sp"):
     over `axis_name`; returns [T, H, D] with the same sharding."""
     from jax.sharding import PartitionSpec as P
 
-    from dynamo_tpu.utils.jax_compat import shard_map
-
     spec = P(axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis_name),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -177,7 +177,7 @@ def run_multihost_check(
     jax.distributed + gloo into one ``total_devices``-wide mesh serving the
     tiny model; assert every process emits identical tokens and return
     them. The caller compares against a single-process run of the same
-    mesh shape (the token-identity gate from VERDICT r03 #1).
+    mesh shape (the token-identity gate).
 
     The coordinator port is probed then released before rank 0 binds it
     (unavoidable across processes), so a lost race surfaces as a child

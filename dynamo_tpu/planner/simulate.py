@@ -5,7 +5,7 @@ Replays a workload through the mocker's per-phase cost model
 Python-scheduler contamination, deterministic — so CI can project
 1P1D / 2P1D / 2P2D disaggregated topologies against aggregated
 baselines in milliseconds of real time (benchmarks/xpyd_bench.py emits
-the table; BENCHMARKS.md records it).
+the table).
 
 Pricing (planner/calibration.py pins the constants to the recorded
 r04/r05 chip runs; tests/test_xpyd.py gates the single-worker

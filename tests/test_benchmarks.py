@@ -66,7 +66,7 @@ async def test_sweep_and_agg_vs_disagg_on_mocker():
 
 
 def test_mooncake_trace_replay_preserves_structure(tmp_path):
-    """VERDICT r03 missing #5: Mooncake-format traces drive the workload
+    """Mooncake-format traces drive the workload
     generator — shared hash_ids become shared token prefixes (the trace's
     radix structure), arrivals scale by speedup_ratio, and loading is
     deterministic."""
@@ -159,7 +159,7 @@ async def test_trace_replay_hits_prefix_cache_on_mocker(tmp_path):
 
 
 def test_prefix_analyzer_over_capture_jsonl(tmp_path):
-    """benchmarks/prefix_analyzer.py (VERDICT missing #4): prefix-sharing
+    """benchmarks/prefix_analyzer.py: prefix-sharing
     stats + the theoretical hit-rate-vs-cache-size curve over the repo's
     capture/replay JSONL, in the engine's own block-hash identity."""
     import json

@@ -1,7 +1,6 @@
 """Drift guards for the single-sourced transfer calibration.
 
-The 21.7 GB/s batched-KV handoff rate (BENCHMARKS.md "Batched KV block
-IO") is recorded in exactly ONE symbol —
+The 21.7 GB/s batched-KV handoff rate is recorded in exactly ONE symbol —
 ``planner.calibration.HANDOFF_GBPS`` — and every consumer (the router's
 network-aware selector, the G4 peer pricing law) must read it from
 there. A re-calibration run edits one line; these tests fail if a copy

@@ -1,0 +1,204 @@
+"""Ask the TPU's compiler, without a TPU, whether the main path compiles.
+
+The sandbox has libtpu installed and no chip attached: a v5e:2x2 topology
+can be DESCRIBED and jit programs compiled for it (nothing runs). That
+catches what interpret mode cannot — tile-misaligned slices, VMEM
+overruns, unpartitionable kernels, programs that do not fit HBM — at the
+real widths (Llama-3.2-1B / 3.1-8B attention: H=32, kvH=8, D=128
+lane-padded, block 16) before any chip time is spent.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that SKIPS when it
+cannot be — never at import, never in conftest, never autouse; every
+compile runs in the test's own process (libtpu's lock belongs to the
+worker that was handed this file); the persistent compile cache is off
+around them (an entry compiled for a described chip cannot be read back
+without one); and interpret mode is steered from here by monkeypatch,
+not by an option of the program. A compile that passes is not a chip
+run — `python chip_smoke.py` is.
+"""
+
+from __future__ import annotations
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import AttnDispatch
+from dynamo_tpu.ops.pallas import attention as phase_kernels
+from dynamo_tpu.ops.pallas import ragged_attention as ragged_kernel
+
+# The CLI's default engine sizes (dynamo_tpu/cli.py): 2048 blocks of 16
+# tokens, 32 decode slots + 4 prefill lanes of metadata, 2048-token
+# sequences (128 blocks each).
+BS, NUM_BLOCKS, S, MAX_BLOCKS = 16, 2048, 36, 128
+H, KVH, D = 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("tp",))
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile the kernels for the chip (not the interpreter) with the
+    persistent cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(ragged_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(phase_kernels, "_interpret", lambda: False)
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_count(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("T", [16, 256])
+def test_ragged_kernel_compiles_at_served_widths(mosaic, one_chip, T, kv_dtype):
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    cache = _sds((NUM_BLOCKS * BS, KVH, D), jnp.dtype(kv_dtype), one_chip)
+    scales = {}
+    if kv_dtype == "int8":
+        sc = _sds((NUM_BLOCKS, KVH), jnp.float32, one_chip)
+        scales = {"k_scales": sc, "v_scales": sc}
+    compiled = ragged_kernel.ragged_paged_attention_pallas.lower(
+        _sds((T, H, D), jnp.bfloat16, one_chip), cache, cache,
+        i32((S, MAX_BLOCKS)), i32((S,)), i32((S,)), i32((S,)), i32((S,)),
+        block_size=BS, **scales,
+    ).compile()
+    assert _kernel_count(compiled.as_text()) == 1
+
+
+@pytest.mark.parametrize("with_stats", [False, True])
+def test_phase_split_kernels_compile_at_served_widths(
+    mosaic, one_chip, with_stats
+):
+    """What still reaches the phase-split kernels: ``--kv-sp`` runs the
+    ragged batch through the decode kernel's strided with-stats form
+    (AttnDispatch._kv_sp_decode), and the runner's legacy prefill/decode
+    programs (multihost bring-up, the graft entry) call the plain forms.
+    The prefill kernel's with-stats form is NOT asked for: no served
+    path reaches it, and the chip's compiler refuses its [TQ, kvH, G] ->
+    [TQ, H] stats reshape (CHANGES.md, PR 22)."""
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    cache = _sds((NUM_BLOCKS * BS, KVH, D), jnp.bfloat16, one_chip)
+    kw = (
+        {"with_stats": True, "page_stride": 4, "page_offset": i32((1,))}
+        if with_stats
+        else {}
+    )
+    decode = phase_kernels.paged_decode_attention_pallas.lower(
+        _sds((32, H, D), jnp.bfloat16, one_chip), cache, cache,
+        i32((32, MAX_BLOCKS)), i32((32,)), block_size=BS, **kw,
+    ).compile()
+    assert _kernel_count(decode.as_text()) == 1
+    if not with_stats:
+        prefill = phase_kernels.paged_prefill_attention_pallas.lower(
+            _sds((4, 256, H, D), jnp.bfloat16, one_chip), cache, cache,
+            i32((4, MAX_BLOCKS)), i32((4,)), i32((4,)), block_size=BS,
+        ).compile()
+        assert _kernel_count(prefill.as_text()) == 1
+
+
+def _unified_step_args(cfg: ModelConfig, T: int, sharding_of):
+    """Shapes of one ``llama.unified`` dispatch at the CLI's default
+    sizes; ``sharding_of(partition_spec)`` places each operand."""
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    from dynamo_tpu.parallel.sharding import kv_cache_spec, llama_param_specs
+
+    specs = llama_param_specs(cfg)
+    params = jax.tree.map(
+        lambda a, s: _sds(a.shape, a.dtype, sharding_of(s)),
+        params, specs,
+    )
+    kv_sh = sharding_of(kv_cache_spec(False))
+    kv = [
+        (
+            _sds((NUM_BLOCKS * BS, cfg.num_kv_heads, D), jnp.bfloat16, kv_sh),
+            _sds((NUM_BLOCKS * BS, cfg.num_kv_heads, D), jnp.bfloat16, kv_sh),
+        )
+        for _ in range(cfg.num_layers)
+    ]
+    i32 = partial(_sds, dtype=jnp.int32, sharding=sharding_of(P()))
+    meta = (
+        i32((T,)), i32((T,)), i32((T,)), i32((T,)), i32((S, MAX_BLOCKS)),
+        i32((S,)), i32((S,)), i32((S,)), i32((S,)),
+    )
+    return params, kv, meta
+
+
+def _compile_unified(cfg: ModelConfig, T: int, attn, sharding_of):
+    params, kv, meta = _unified_step_args(cfg, T, sharding_of)
+
+    def step(params, kv, *meta):
+        logits, kv = llama.unified(cfg, params, kv, *meta, BS, attn=attn)
+        return jnp.argmax(logits, axis=-1), kv
+
+    return jax.jit(step, donate_argnums=(1,)).lower(
+        params, kv, *meta
+    ).compile()
+
+
+def test_unified_step_1b_compiles_one_chip_and_tp4(mosaic, one_chip, tp4):
+    """One whole Llama-3.2-1B unified step (16 layers, T=256, donated
+    lane-padded caches) on one chip, and the same step head-sharded over
+    the four chips of the host: a kernel per layer either way, about a
+    quarter of the bytes per device, and the collectives tensor
+    parallelism needs."""
+    cfg = ModelConfig.llama32_1b()
+    single = _compile_unified(
+        cfg, 256, AttnDispatch(use_pallas=True), lambda _spec: one_chip
+    )
+    sharded = _compile_unified(
+        cfg, 256, AttnDispatch(use_pallas=True, mesh=tp4),
+        lambda spec: NamedSharding(tp4, spec),
+    )
+    one_text, tp_text = single.as_text(), sharded.as_text()
+    assert _kernel_count(one_text) == cfg.num_layers
+    assert _kernel_count(tp_text) == cfg.num_layers
+    assert "all-reduce" in tp_text and "all-reduce" not in one_text
+    one_bytes = single.memory_analysis().argument_size_in_bytes
+    tp_bytes = sharded.memory_analysis().argument_size_in_bytes
+    # Weights + KV of the 1B at these sizes are ~4.6 GB on one chip.
+    assert 4.0e9 < one_bytes < 5.5e9, one_bytes
+    assert 0.2 < tp_bytes / one_bytes < 0.3, (tp_bytes, one_bytes)

@@ -103,8 +103,7 @@ async def test_cli_batch_echo(tmp_path):
 
 async def test_cli_http_serves_tpu_preset():
     """One shell command serves OpenAI-compatible chat on the real engine
-    (tiny preset, CPU): the VERDICT r02 'can't be launched from a shell'
-    gap, closed."""
+    (tiny preset, CPU)."""
     proc, m = await _spawn_cli(
         "run", "--in", "http", "--out", "tpu",
         "--model-path", "preset:tiny-test",
@@ -188,3 +187,52 @@ async def test_cli_worker_joins_frontend():
         await _stop(front)
         if worker is not None:
             await _stop(worker)
+
+
+async def test_chip_smoke_serve_phase_rehearsal(monkeypatch, capsys):
+    """chip_smoke.py's serve and model phases, rehearsed here: the same
+    functions the chip run calls, through the same CLI entry (cli._run,
+    warmup on, HTTP on 127.0.0.1, SIGTERM to stop), with the tiny preset
+    and the Pallas kernel in interpret mode. Catches wrong arguments and
+    control flow before chip time is spent; says nothing about the
+    chip."""
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+
+    report, runner = await chip_smoke.serve_and_query(
+        "preset:tiny-test",
+        cli_args=[
+            "--max-model-len", "256", "--num-blocks", "64",
+            "--max-num-seqs", "4", "--unified-token-budget", "32",
+            "--unified-prefill-quantum", "16",
+        ],
+        prompt_lens=[20, 60, 5, 90, 33], max_tokens=4,
+        expect_mosaic=False, startup_timeout_s=300, request_timeout_s=120,
+    )
+    assert report["tokens_returned"] == [4] * 5
+    assert report["attention_path"] == "pallas"
+    assert report["health"][-1] == [200, "ready"]
+    assert report["mid_traffic_compiles_total"] == 0
+    assert report["mixed_dispatches"] > 0
+    out = capsys.readouterr().out
+    assert '"phase": "serve"' in out and '"phase": "shutdown"' in out
+    model = chip_smoke.pallas_vs_xla_model(runner, prompt_lens=[7, 19, 5])
+    assert [s["step"] for s in model["steps"]] == [
+        "prefill", "decode1", "decode2", "decode3"
+    ]
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """The driver's contract: with no accelerator the script exits
+    non-zero and prints no result line."""
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs 1 TPU chip" in proc.stderr

@@ -156,6 +156,84 @@ def test_cache_ledger_persists_per_fingerprint(tmp_path):
     assert not other.has("prefill:t64")
 
 
+@pytest.fixture
+def restore_jax_cache_config():
+    """activate() flips process-global jax config: put it back so a
+    tmp_path cache cannot outlive its test."""
+    import jax
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    before = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in before.items():
+        jax.config.update(n, v)
+
+
+def test_cache_rule_env_var_places_everything(
+    tmp_path, monkeypatch, restore_jax_cache_config
+):
+    """$JAX_COMPILATION_CACHE_DIR set: that directory whatever else was
+    asked for, no cache-dir config call, ledger under it."""
+    import jax
+
+    from dynamo_tpu.engine.compile_cache import resolve_cache_base
+
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    monkeypatch.delenv("DYNAMO_TPU_COMPILE_CACHE_DIR")
+    before = jax.config.jax_compilation_cache_dir
+    # Whatever base the rule or a caller hands over (an EngineConfig's
+    # own compile_cache_dir, say), the cache object places itself.
+    assert PersistentCompileCache(
+        resolve_cache_base("auto"), engine_fingerprint(_cfg())
+    ).base_dir == outside
+    cache = PersistentCompileCache(
+        resolve_cache_base(str(tmp_path / "explicit")),
+        engine_fingerprint(_cfg()),
+    )
+    assert cache.base_dir == outside
+    cache.activate()
+    assert jax.config.jax_compilation_cache_dir == before
+    cache.note("unified:t16")
+    cache.flush()
+    assert os.path.exists(
+        os.path.join(outside, cache.key, PersistentCompileCache.LEDGER)
+    )
+    assert not (tmp_path / "explicit").exists()
+
+
+def test_cache_rule_default_is_inside_checkout_and_none_disables(
+    tmp_path, monkeypatch, restore_jax_cache_config
+):
+    import jax
+
+    from dynamo_tpu.engine.compile_cache import resolve_cache_base
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    # The suite's own setting (tests/conftest.py) is the disable sentinel.
+    assert os.environ["DYNAMO_TPU_COMPILE_CACHE_DIR"] == "none"
+    assert resolve_cache_base("auto") is None
+    assert resolve_cache_base("none") is None
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert resolve_cache_base("none") is None  # even placed from outside
+    assert resolve_cache_base("auto") is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.delenv("DYNAMO_TPU_COMPILE_CACHE_DIR")
+    assert resolve_cache_base("auto") == os.path.join(repo, ".jax_cache")
+    monkeypatch.setenv("DYNAMO_TPU_COMPILE_CACHE_DIR", str(tmp_path / "dep"))
+    assert resolve_cache_base("auto") == str(tmp_path / "dep")
+    explicit = str(tmp_path / "explicit")
+    assert resolve_cache_base(explicit) == explicit
+    # Unset, activate() is what points jax at the base.
+    PersistentCompileCache(explicit, engine_fingerprint(_cfg())).activate()
+    assert jax.config.jax_compilation_cache_dir == explicit
+
+
 class _StubWarmRunner:
     """Counting stub standing in for XLA when no TPU is present: a shape
     whose key is in the persistent-cache ledger 'replays from disk'

@@ -1,4 +1,4 @@
-"""Install-path validation (VERDICT r04 missing #1/#2): the Helm chart
+"""Install-path validation: the Helm chart
 renders to valid k8s objects wired to the image container/Dockerfile
 builds, and every CLI flag the pod specs pass actually exists.
 

@@ -250,7 +250,7 @@ async def test_native_receiver_rejects_unauthenticated_peer():
 async def test_queue_age_sla_signal():
     """Oldest-item age rides the queue (surviving redelivery) and flips
     the disagg decision to local when the pool is stalled — the per-item
-    SLA signal depth alone can't give (VERDICT r02 weak #7)."""
+    SLA signal depth alone can't give."""
     from dynamo_tpu.disagg.router import DisaggConfig, DisaggRouter
     from dynamo_tpu.runtime.transports.bus import InProcQueue
 
@@ -293,8 +293,8 @@ async def test_queue_age_sla_signal():
     ((2, 1), "device"),
 ])
 async def test_heterogeneous_tp_prefill_decode_roundtrip(tp_pair, transport):
-    """xPyD with DIFFERENT tensor-parallel degrees per pool (VERDICT r03
-    #5; reference: docs/architecture/disagg_serving.md:100-109): a
+    """xPyD with DIFFERENT tensor-parallel degrees per pool
+    (reference: docs/architecture/disagg_serving.md:100-109): a
     tp-sharded prefill engine feeds a decode engine of another tp over
     the wire path, and greedy tokens must match the plain local engine.
     The wire carries blocks in the LOGICAL [L, 2, bs, H_total, D] layout,

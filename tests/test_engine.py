@@ -416,7 +416,7 @@ def test_rope_scaling_llama3_formula(tmp_path):
     assert ModelConfig.llama31_8b().rope_scaling.factor == 8.0
 
 
-# -- sampling extras: seed / penalties / logprobs (VERDICT r03 #4) ----------
+# -- sampling extras: seed / penalties / logprobs ----------
 
 async def collect_full(engine, prompt, max_tokens=8, sampling=None,
                        logprobs=None):
@@ -608,7 +608,7 @@ async def test_sliding_window_engine_matches_oracle():
 
 
 async def test_rolling_buffer_eviction_plateaus_and_is_exact():
-    """Rolling-buffer KV eviction (VERDICT r04 weak #4): a fully-windowed
+    """Rolling-buffer KV eviction: a fully-windowed
     model's long generation must (a) hold only O(window/bs) live blocks —
     behind-window pages are released as decoding advances — and (b)
     produce tokens identical to the same engine with eviction disabled."""

@@ -140,7 +140,7 @@ async def test_http_completions_endpoint():
 async def test_http_embeddings_end_to_end():
     """/v1/embeddings over the full stack: register an embeddings model,
     watcher builds the tokenize-only pipeline, vectors come back unit-norm
-    and deterministic (VERDICT r02 missing #5, closed)."""
+    and deterministic."""
     import math
 
     from dynamo_tpu.llm.embedding import EmbeddingEngine
@@ -247,8 +247,7 @@ async def _setup_logprob():
 async def test_http_logprobs_chat_and_completions():
     """OpenAI logprob payloads end to end: chat logprobs.content entries
     (token/logprob/bytes/top_logprobs) in both streamed chunks and the
-    aggregated response; legacy parallel lists on /v1/completions
-    (VERDICT r03 weak #3: parsed-but-ignored parameters)."""
+    aggregated response; legacy parallel lists on /v1/completions."""
     drt, service = await _setup_logprob()
     base = f"http://127.0.0.1:{service.port}"
     try:

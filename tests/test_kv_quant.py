@@ -671,7 +671,7 @@ def test_kv_quant_config_validation():
 
 
 def test_calibration_hbm_constant_rederives_from_artifact():
-    """DECODE_HBM_GBPS must equal the recorded BENCH_r04 measurement —
+    """DECODE_HBM_GBPS must equal the recorded r04 measurement —
     the constant and the artifact can't drift apart (same contract as
     the PR 10 decode constants)."""
     from dynamo_tpu.planner import calibration as cal

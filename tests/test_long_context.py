@@ -1,5 +1,5 @@
 """Beyond-one-chip contexts: the paged KV cache's slot axis sharded over
-the sp mesh axis (VERDICT r03 #6; SURVEY §5 long-context row).
+the sp mesh axis.
 
 The engine mode under test: mesh {"sp": n} + EngineConfig.kv_sp=True puts
 1/n of the cache slots on each device and runs attention as per-shard
@@ -39,8 +39,6 @@ def test_sp_attention_matches_replicated_oracle():
     replicated-cache reference on a random paged cache."""
     from jax.sharding import PartitionSpec as P
 
-    from dynamo_tpu.utils.jax_compat import shard_map
-
     from dynamo_tpu.ops.attention import (
         paged_decode_attention,
         paged_decode_attention_sp,
@@ -65,7 +63,7 @@ def test_sp_attention_matches_replicated_oracle():
         q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx), bs
     )
     sp_cache = P("sp", None, None)
-    got = shard_map(
+    got = jax.shard_map(
         lambda *a: paged_decode_attention_sp(*a, block_size=bs),
         mesh=mesh,
         in_specs=(P(), sp_cache, sp_cache, P(), P()),
@@ -87,7 +85,7 @@ def test_sp_attention_matches_replicated_oracle():
             qq, k_cache, v_cache, b, ps, tl, bs
         )
     )(qp, bt, q_start, total)
-    got_p = shard_map(
+    got_p = jax.shard_map(
         lambda *a: paged_prefill_attention_sp(*a, block_size=bs),
         mesh=mesh,
         in_specs=(P(), sp_cache, sp_cache, P(), P(), P()),
@@ -236,7 +234,7 @@ def test_sp_striped_scan_matches_oracle(use_pallas):
 
 
 async def test_engine_kv_sp_composes_with_tp():
-    """The r04 VERDICT gate: a {tp: 2, sp: 2} kv_sp engine — heads
+    """A {tp: 2, sp: 2} kv_sp engine — heads
     sharded over tp AND slots over sp, striped allocator — serves
     token-identically to the replicated single-chip oracle. This is the
     mode a model too big for one chip needs for beyond-chip contexts."""

@@ -38,7 +38,7 @@ def test_two_process_mesh_token_identical():
 
 
 # ---------------------------------------------------------------------------
-# Full-stack multi-host serving (VERDICT r04 weak #7): control plane +
+# Full-stack multi-host serving: control plane +
 # HTTP frontend here, a 2-process × 4-device mesh worker joined via the
 # CLI's --coordinator path (rank 0 = step leader serving the endpoint,
 # rank 1 = stepcast follower), one REAL HTTP completion — token-identical

@@ -232,7 +232,7 @@ async def test_cross_process_disagg_roundtrip(plane, transport):
 
 
 async def test_prefill_worker_death_after_dequeue_redelivers(plane):
-    """VERDICT r02 'done' gate for the durable queue: a prefill worker that
+    """The 'done' gate for the durable queue: a prefill worker that
     crashes AFTER dequeuing (before pushing KV) must not lose the request —
     its connection death nacks the leased item, a later worker picks it up,
     and the decode stream still completes bit-identical to a local run."""
@@ -305,7 +305,7 @@ async def test_cross_process_sharded_worker_matches_local(plane):
     process, and its greedy tokens are identical to a local single-device
     engine with the same weights (the determinism contract both sides
     build from PRNGKey(0) fp32). This is the multi-process × multi-device
-    shape VERDICT r02 asked for (reference: one engine process per host,
+    shape (reference: one engine process per host,
     TP inside — lib/llm/src/engines.rs:42-60 MultiNodeConfig)."""
     import jax
 

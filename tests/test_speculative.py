@@ -187,7 +187,7 @@ def test_speculative_config_validation():
 
 
 async def test_speculative_auto_gates_below_break_even_and_reprobes():
-    """VERDICT r03 weak #7: sampled lanes accept zero drafts (exactly 1.0
+    """sampled lanes accept zero drafts (exactly 1.0
     delivered token/step < break-even 1.4), so the engine must disable
     speculation after a window, serve plain decode correctly, then
     re-probe after speculative_probe_steps plain steps."""
@@ -326,7 +326,7 @@ async def test_spec_reprobe_recovers_on_accepting_traffic():
 
 
 async def test_spec_gate_is_free_when_losing_mocker_ab():
-    """VERDICT weak #6 (narrow scope): once the gate has disabled
+    """Narrow scope: once the gate has disabled
     speculation, plain decode must pay ~0% overhead — each RE-probe runs
     only speculative_probe_window spec steps (not a full measurement
     window), so the steady-state loss is probe_window/probe_steps. The

@@ -2,7 +2,7 @@
 (ROADMAP #4; docs/architecture/planner.md).
 
 The calibration fixture is the drift gate: the checked-in constants
-(planner/calibration.py) must keep reproducing the RECORDED BENCH_r04
+(planner/calibration.py) must keep reproducing the RECORDED r04
 headline within 10 % — a mocker cost-model edit that silently skews the
 xPyD projections fails here, not in a later postmortem."""
 
@@ -23,7 +23,7 @@ from dynamo_tpu.planner import simulate as sim
 
 
 def test_calibration_constants_match_recorded_artifact():
-    """The decode-dispatch constants are DERIVED from BENCH_r04.json's
+    """The decode-dispatch constants are DERIVED from the r04 recording's
     two measured step times; re-derive from the artifact and compare so
     the constants and the recording can't drift apart."""
     rec = cal.recorded_r04()
@@ -38,7 +38,7 @@ def test_calibration_constants_match_recorded_artifact():
 
 
 def test_calibrated_sim_reproduces_r04_headline_within_10pct():
-    """Acceptance: mocker cost model reproduces recorded BENCH_r04
+    """Acceptance: mocker cost model reproduces the recorded r04
     aggregated tok/s and p50 TTFT within 10%."""
     cfg = sim.SimConfig()
     wl = sim.synth_workload(cal.R04_NUM_REQUESTS, cal.R04_ISL, cal.R04_OSL)
@@ -62,7 +62,7 @@ def test_calibrated_mocker_config_carries_constants():
 
 def test_handoff_transfer_term_matches_measured_channel():
     """ISL-3000 over the measured 21.7 GB/s device channel lands in
-    ~9 ms (BENCHMARKS.md 'Batched KV block IO') — the fixed 2-dispatch
+    ~9 ms — the fixed 2-dispatch
     cost plus bytes/rate."""
     s = cal.handoff_seconds(3000)
     assert 0.004 < s < 0.012
