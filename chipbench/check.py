@@ -24,6 +24,28 @@ The reference (``chipbench/reference/<family>.py``, float32, ``highest``,
 weights drawn again from the seed) gives the logits of the same positions
 from one full forward pass.
 
+A reference of a new family is one module that imports nothing of the
+program and provides
+
+    logits(published, seed, tokens, rows, dtype, *, source_values=None,
+           share=None) -> float32 [B, R, published["vocab_size"]]
+
+* ``published`` is the configuration file's block, every key of it; the
+  two keyword arguments are the file's ``source_values`` and ``share``
+  blocks, passed only where the file has them (``share_arguments``; a
+  family whose experts and heads are never held in part needs neither,
+  as ``reference/mistral.py``);
+* it draws the weights again from ``seed`` in the program's own order of
+  splits and in the served ``dtype``, then computes in float32 at
+  ``highest`` — it takes no array the program has made;
+* it computes the same share and no more: the router at the source's
+  width (``source_values``), the experts, heads and vocabulary rows that
+  ``share`` says live here, what the absent ones would have added left
+  out, as the program leaves it out. A sliced vocabulary is a smaller
+  vocabulary: the sample's ids are drawn below ``published["vocab_size"]``
+  and the logits are over the slice on both sides;
+* it returns, for each sequence ``b``, the logits at positions ``rows[b]``.
+
 Compared, each with its limit in the configuration's ``check`` block:
 
 ``rel_err``
@@ -50,7 +72,11 @@ Compared, each with its limit in the configuration's ``check`` block:
 
 from __future__ import annotations
 
+import inspect
+
 import numpy as np
+
+from chipbench import modelcfg
 
 #: prompt lengths of the sample (every seed the same sizes) and decode steps
 PROMPT_LENS = (5, 37, 80, 150, 230, 300, 450, 601)
@@ -206,14 +232,35 @@ def compare(data: dict, seed: int, runner, *, weights_seed: int,
     )
     assert np.isfinite(got).all(), "the runner's logits are not finite"
     free(runner)
+    cut = share_arguments(data, ref)
     with jax.default_device(jax.devices()[0]):
         want = np.asarray(ref.logits(
             data["published"], weights_seed, tokens, rows,
-            dtype=data["dtype"],
+            dtype=data["dtype"], **cut,
         ))
     return verdict(
         got, want, served, decode, quantile, phase_quantile, token_margin
     )
+
+
+def share_arguments(data: dict, ref) -> dict:
+    """The file's ``source_values`` and ``share`` blocks as keyword
+    arguments for ``ref.logits``; a file without them gets the call it
+    always got. A reference that takes neither is told nothing where only
+    the vocabulary is sliced (a sliced vocabulary is a smaller vocabulary,
+    which ``published`` says in full), and is refused where experts or
+    heads are held in part, which it would compute whole."""
+    cut = {k: data[k] for k in ("source_values", "share") if k in data}
+    takes = inspect.signature(ref.logits).parameters
+    if all(k in takes for k in cut):
+        return cut
+    counts = sorted(set(data.get("reduced", [])) & set(modelcfg.COUNTS))
+    if counts:
+        raise ValueError(
+            f"{data['name']}: {counts} are held in part, but reference/"
+            f"{data['reference']}.py takes no source_values and share"
+        )
+    return {}
 
 
 def verdict(got, want, served, decode, quantile: float = 100,
@@ -248,32 +295,40 @@ def verdict(got, want, served, decode, quantile: float = 100,
         "token_mismatches": int((judged & ~agree).sum()),
         "token_mismatches_all_rows": int((~agree).sum()),
         "largest_logit": float(np.abs(want).max()),
+        "logit_width": [int(got.shape[-1]), int(want.shape[-1])],
+        "largest_served_token": int(np.max(served)),
     }
+
+
+def held(verdict: dict, limits: dict) -> dict[str, dict]:
+    """Each number compared beside its limit, ``{"value", "limit"}`` under
+    a short plain name: what ``judge`` decides by and what a run prints."""
+    out = {
+        f"rel_err_p{verdict['quantile']:g}":
+            {"value": verdict["rel_err"], "limit": limits["limit"]},
+    }
+    if "phase_limit" in limits:
+        for phase, value in verdict["rel_err_by_phase"].items():
+            out[f"rel_err_{phase}_p{verdict['phase_quantile']:g}"] = {
+                "value": value, "limit": limits["phase_limit"]}
+    out["token_mismatches"] = {
+        "value": verdict["token_mismatches"],
+        "limit": limits.get("token_mismatch_limit", 0),
+    }
+    return out
 
 
 def judge(verdict: dict, limits: dict) -> list[str]:
     """Why ``verdict`` is not correct under the configuration's ``check``
-    block ``limits``: empty when it is."""
-    held = [("all rows", verdict["quantile"], verdict["rel_err"],
-             limits["limit"])]
-    if "phase_limit" in limits:
-        held += [
-            (f"{phase} rows", verdict["phase_quantile"], value,
-             limits["phase_limit"])
-            for phase, value in verdict["rel_err_by_phase"].items()
-        ]
-    why = [
-        f"logits off the reference: relative error (p{q}, {where}) "
-        f"{value:.5f} > {limit}"
-        for where, q, value, limit in held if not value <= limit
+    block ``limits``: empty when it is. ``rel_err_*`` is a quantile of the
+    rows' relative logit error against the reference; ``token_mismatches``
+    counts served greedy tokens that are not the reference's argmax where
+    its lead is clear."""
+    return [
+        f"{name} {c['value']:.6g} > {c['limit']}"
+        for name, c in held(verdict, limits).items()
+        if not c["value"] <= c["limit"]
     ]
-    most = limits.get("token_mismatch_limit", 0)
-    if verdict["token_mismatches"] > most:
-        why.append(
-            f"{verdict['token_mismatches']} served greedy tokens (limit "
-            f"{most}) are not the reference's argmax where its lead is clear"
-        )
-    return why
 
 
 #: the keys of a configuration's ``check`` block that ``compare`` takes

@@ -1,14 +1,10 @@
-"""Operations and bytes a kernel's call needs, from its shapes alone.
-
-Each function takes one dispatch's lanes as the harness recorded them
-(``[(prefix_len, new_tokens), ...]``) and returns ``(flops, bytes)`` on ONE
-chip for ALL layers of the served model: what the algorithm needs, not
-what an implementation moves."""
+"""The ragged paged attention kernel over a ``(k, v)`` cache
+(``ops/pallas/ragged_attention.py``)."""
 
 from __future__ import annotations
 
 
-def ragged_paged_attention(lanes, *, model: dict, engine: dict):
+def cost(lanes, *, model: dict, engine: dict):
     """Causal attention of each span's ``n`` new rows over its
     ``prefix + n`` cached positions (fewer under a sliding window).
     FLOPs: QK^T and PV, 2 each per (query, key, head, dim). Bytes: every

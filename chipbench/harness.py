@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import json
 import os
 import shutil
@@ -369,11 +370,7 @@ async def run(opts) -> dict:
         readiness_edges=edges, traces=traces,
         compile_events=events.events,
         dispatches=dispatch_log.seen,
-        model={
-            "num_layers": model.num_layers, "num_heads": model.num_heads,
-            "num_kv_heads": model.num_kv_heads, "head_dim": model.head_dim,
-            "sliding_window": model.sliding_window,
-        },
+        model=scalar_fields(model),
         engine=engine_facts(runner),
         device_kind=device["kind"],
     )
@@ -441,8 +438,21 @@ async def run(opts) -> dict:
     })
     failures.extend(check.judge(verdict, limits))
     say("requests", attempted=len(load["records"]), failed=failed, limit=0)
+    # Each number compared beside its limit, as the last lines on standard
+    # error and as the result line's last key: what is kept of a run that
+    # is not correct.
+    compared = {
+        **check.held(verdict, limits),
+        "requests_failed": {"value": failed, "limit": 0},
+        "compiles_in_window": {"value": compiles, "limit": 0},
+    }
     for f in failures:
         say("not_correct", why=f)
+        print(f"chipbench not_correct: {f}", file=sys.stderr)
+    for name, c in compared.items():
+        print(f"chipbench compared {name} {c['value']:.6g} limit "
+              f"{c['limit']}", file=sys.stderr)
+    sys.stderr.flush()
 
     result = {
         "correct": not failures and failed == 0,
@@ -453,6 +463,7 @@ async def run(opts) -> dict:
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    result["compared"] = compared
     shutil.rmtree(workdir, ignore_errors=True)
     return result
 
@@ -473,19 +484,32 @@ def engine_traces() -> list[dict]:
     return tracer().snapshot(n=1 << 20)["recent"]
 
 
+def scalar_fields(model) -> dict:
+    """Every scalar field of the served ``ModelConfig``, under the
+    program's own names: what a kernel's cost function may read."""
+    return {
+        f.name: value for f in dataclasses.fields(model)
+        if isinstance(value := getattr(model, f.name),
+                      (bool, int, float, str))
+    }
+
+
 def engine_facts(runner) -> dict:
+    import jax
     import jax.numpy as jnp
 
     cfg = runner.cfg
-    k_cache = runner.kv_caches[0][0]
+    # The first leaf, not [0][0]: a layer's cache need not be a (k, v) pair.
+    leaves = jax.tree.leaves(runner.kv_caches)
     return {
         "block_size": cfg.block_size,
         "num_blocks": cfg.num_blocks,
         "token_budget": cfg.unified_token_budget,
         "tp": int((cfg.mesh_shape or {}).get("tp", 1)),
-        "cache_head_dim": int(k_cache.shape[-1]),
+        "cache_head_dim": int(leaves[0].shape[-1]),
+        "cache_arrays_per_layer": len(leaves) // cfg.model.num_layers,
         "dtype_bytes": jnp.dtype(cfg.dtype).itemsize,
-        "kv_dtype_bytes": k_cache.dtype.itemsize,
+        "kv_dtype_bytes": leaves[0].dtype.itemsize,
     }
 
 

@@ -1,13 +1,33 @@
 """The benchmark's data files, found by name, and their consistency.
 
-``chipbench/workloads/<cell>.json`` names a configuration, a traffic mix,
-the chips and the metrics the cell reports; ``chipbench/metrics/<name>.json``
-gives a metric's unit, layer, ``moves`` and the reader (a module of
-``chipbench/readers/``) with its parameters; ``configs/`` and ``traffic/``
-hold the sizes. ``BENCHMARK.json`` at the root of the checkout is the view
-of the same facts that the driver reads; ``check()`` holds the two
-together, so a cell, a metric, a configuration or a traffic mix is added
-by new files and new entries, never by an edit.
+``BENCHMARK.json`` at the root of the checkout is the view that the driver
+reads; the files under ``chipbench/`` hold the same facts and what the
+harness needs beside them, and ``check()`` holds the two together. A cell,
+a metric, a traffic mix, a configuration — of a family the benchmark has
+never seen, cut to one chip's share of a deployment — joins by new files
+and new entries, never by an edit of a file that is there:
+
+``configs/<name>.json``
+    the ``preset`` of the program, the ``reference`` module, ``dtype``,
+    ``published`` (any key of the source's ``config.json``), ``reduced``
+    and, for a cut other than depth, ``source_values`` and ``share``; the
+    optional ``fields`` block (published key -> ``ModelConfig`` field) and
+    ``layer_period``; ``assumed``, ``deployment``, ``chips``,
+    ``serve_args`` and the ``check`` block with the limits of ``correct``.
+    ``modelcfg`` says how it resolves and what a cut is held to.
+``reference/<family>.py``
+    the plain reference of a family: one ``logits`` function
+    (``check``'s docstring has its signature and what it must do).
+``traffic/<name>.json`` (+ ``distributions/<name>.py``)
+    a traffic mix's parameters, which ``traffic.py`` generates from.
+``workloads/<config>.<traffic>.json``
+    a cell: its configuration, traffic, chips, ``why`` and the metrics it
+    reports.
+``metrics/<name>.json`` (+ ``readers/<name>.py``)
+    a metric's unit, layer, ``moves``, and its reader with its ``params``.
+``costs/<kernel>.py``
+    a kernel's operations and bytes, ``cost(lanes, *, model, engine)``,
+    which a ``<kernel>_roofline`` metric names under ``params.cost``.
 """
 
 from __future__ import annotations
@@ -122,6 +142,8 @@ def check(bench: dict | None = None) -> list[str]:
         if name in per_layer and m["moves"] not in e2e:
             bad.append(f"metric {name}: moves {m['moves']}, not end to end")
         registry.load("readers", data["reader"])
+        if "cost" in data.get("params", {}):
+            registry.load("costs", data["params"]["cost"])
         cells_of = sorted(reported.get(name, []))
         declared = sorted(m.get("workloads", cells))
         if cells_of != declared:

@@ -1,12 +1,13 @@
-"""Modules found by name: a reader, a length distribution or a reference
-architecture joins by being a module in its directory."""
+"""Modules found by name: a reader, a length distribution, a reference
+architecture or a kernel's cost function joins by being a module in its
+directory."""
 
 from __future__ import annotations
 
 import importlib
 import re
 
-KINDS = ("readers", "distributions", "reference")
+KINDS = ("readers", "distributions", "reference", "costs")
 _NAME = re.compile(r"^[A-Za-z0-9_]+$")
 
 

@@ -2,13 +2,16 @@
 and the arithmetic kept with the benchmark (kernel cost, peaks)."""
 
 import dataclasses
+import functools
+import sys
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import check, kernel_cost
+from chipbench import check, manifest, registry
 from chipbench.peaks import peaks_for
 from chipbench.reference import mistral as ref
 from dynamo_tpu.models import llama
@@ -159,29 +162,119 @@ def test_row_errors_is_relative_l2_by_row():
 
 
 def test_ragged_attention_cost():
+    cost = registry.load("costs", "ragged_paged_attention").cost
     model = {"num_layers": 2, "num_heads": 8, "num_kv_heads": 2,
              "head_dim": 128, "sliding_window": 0}
     engine = {"tp": 1, "cache_head_dim": 128, "dtype_bytes": 2}
     # one decode lane at context 100: 100 (query, key) pairs
-    flops, nbytes = kernel_cost.ragged_paged_attention(
+    flops, nbytes = cost(
         [(99, 1)], model=model, engine=engine
     )
     assert flops == 2 * 4 * 100 * 8 * 128
     assert nbytes == 2 * (2 * 100 * 2 * 128 * 2 + 2 * 1 * 8 * 128 * 2)
     # a prefill chunk of 4 rows from 0: 1 + 2 + 3 + 4 pairs
-    flops, _ = kernel_cost.ragged_paged_attention(
+    flops, _ = cost(
         [(0, 4), (0, 0)], model=model, engine=engine
     )
     assert flops == 2 * 4 * 10 * 8 * 128
     # tp=2 halves both; a window caps the keys a row sees
-    f1, b1 = kernel_cost.ragged_paged_attention(
+    f1, b1 = cost(
         [(99, 1)], model=model, engine=dict(engine, tp=2)
     )
     assert (f1, b1) == (2 * 4 * 100 * 4 * 128, nbytes // 2)
-    fw, _ = kernel_cost.ragged_paged_attention(
+    fw, _ = cost(
         [(99, 1)], model=dict(model, sliding_window=10), engine=engine
     )
     assert fw == 2 * 4 * 10 * 8 * 128
+
+
+def test_a_cost_function_joins_by_its_file_and_reads_any_model_field(
+        monkeypatch):
+    """What ``costs/<name>.py`` would hold, put where the import finds it:
+    the reader loads it by the name a metric's file gives, and it reads a
+    field of the served model that no accepted kernel needs."""
+    from chipbench.harness import scalar_fields
+    from chipbench.observe import Observations
+
+    def latent_cost(lanes, *, model, engine):
+        rows = sum(prefix + n for prefix, n in lanes)
+        width = model["kv_lora_rank"] + model["qk_rope_head_dim"]
+        return 0, rows * width * engine["kv_dtype_bytes"] * (
+            engine["cache_arrays_per_layer"] * model["num_layers"])
+
+    module = types.ModuleType("chipbench.costs.latent_stub")
+    module.cost = latent_cost
+    monkeypatch.setitem(sys.modules, module.__name__, module)
+    model = scalar_fields(ModelConfig.tiny_mla_test())
+    assert model["kv_lora_rank"] > 0 and "rope_scaling" not in model
+    assert {"num_layers", "num_heads", "num_kv_heads", "head_dim",
+            "sliding_window"} <= set(model)
+    obs = Observations(
+        window=(0.0, 10.0), chips=1, setup_s=1.0, records=[],
+        trace={"op_seconds": {"latent_kernel.3": 0.5},
+               "host_window": (1.0, 2.0)},
+        dispatches=[(1.5, [(99, 1)])], model=model,
+        engine={"kv_dtype_bytes": 2, "cache_arrays_per_layer": 1},
+        device_kind="TPU v5 lite",
+    )
+    got = registry.load("readers", "kernel_roofline").read(
+        obs, kernel="latent_kernel", cost="latent_stub")
+    nbytes = latent_cost([(99, 1)], model=model, engine=obs.engine)[1]
+    assert got == pytest.approx(100 * (nbytes / 819e9) / 0.5)
+
+
+def _compare_with(monkeypatch, data, module):
+    """``check.compare`` with the runner's side canned: what the
+    reference named in ``data`` is called with."""
+    rows = np.zeros((1, 2), np.int32)
+    got = np.ones((1, 2, data["published"]["vocab_size"]), np.float32)
+    monkeypatch.setattr(
+        check, "runner_rows",
+        lambda *a, **kw: (rows, np.asarray([[False, True]]), got,
+                          np.zeros((1, 2), np.int64)),
+    )
+    monkeypatch.setattr(check, "free", lambda runner: None)
+    calls = []
+
+    def logits(*args, **kwargs):
+        calls.append((args, kwargs))
+        return got
+
+    # under the reference's own signature, which ``compare`` reads to see
+    # what it takes (``inspect.signature`` follows ``__wrapped__``)
+    monkeypatch.setattr(
+        module, "logits", functools.wraps(module.logits)(logits))
+    monkeypatch.setitem(sys.modules, module.__name__, module)
+    check.compare(data, 5, object(), weights_seed=5, prompt_lens=(3,),
+                  decode_steps=1, pad_to=8)
+    return calls
+
+
+def test_compare_tells_a_reference_of_the_share_and_mistral_of_nothing(
+        monkeypatch):
+    def logits(published, seed, tokens, rows, dtype, *, source_values=None,
+               share=None):
+        raise AssertionError("stood in for")
+
+    stub = types.ModuleType("chipbench.reference.share_stub")
+    stub.logits = logits
+    data = manifest.config("tiny-slice-rehearsal")
+    with monkeypatch.context() as m:
+        (args, kwargs), = _compare_with(
+            m, dict(data, reference="share_stub"), stub)
+    assert args[0] == data["published"] and args[1] == 5
+    assert kwargs == {"dtype": "float32", "share": data["share"],
+                      "source_values": {"vocab_size": 384}}
+    # reference/mistral.py takes neither: a file without the blocks gets
+    # the call it always got, and so does a sliced vocabulary
+    for name in ("tiny-rehearsal", "tiny-slice-rehearsal"):
+        with monkeypatch.context() as m:
+            (args, kwargs), = _compare_with(m, manifest.config(name), ref)
+        assert kwargs == {"dtype": "float32"}, name
+    # but experts held in part need a reference that is told of them
+    held = dict(data, reduced=["num_local_experts"])
+    with pytest.raises(ValueError, match="takes no source_values and share"):
+        check.share_arguments(held, ref)
 
 
 def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():

@@ -1,5 +1,6 @@
-"""One whole run on the CPU at the rehearsal's tiny sizes, the refusal
-without a chip, and the control of ``correct`` kept as a test."""
+"""One whole run on the CPU at the rehearsal's tiny sizes (whole, and cut
+to a share), the refusal without a chip, and the control of ``correct``
+and a planted fault kept as tests."""
 
 import json
 import os
@@ -38,18 +39,37 @@ def _run(*argv, timeout=900):
     )
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_whole_run_on_the_cpu_prints_a_well_formed_last_line(trace):
+def _last_lines(proc):
+    """The result line, the ``say`` lines before it, and what standard
+    error ends with."""
+    lines = proc.stdout.strip().splitlines()
+    said = [json.loads(ln) for ln in lines[:-1] if ln.startswith('{"chipbench"')]
+    return json.loads(lines[-1]), said, proc.stderr.strip().splitlines()
+
+
+@pytest.mark.parametrize("cell,trace", [
+    ("tiny-rehearsal.rehearsal", 0), ("tiny-rehearsal.rehearsal", 1),
+    ("tiny-slice-rehearsal.rehearsal", 0),
+])
+def test_whole_run_on_the_cpu_prints_a_well_formed_last_line(cell, trace):
     proc = _run(
-        "chipbench", "--workload", "tiny-rehearsal.rehearsal", "--seed",
+        "chipbench", "--workload", cell, "--seed",
         str(2**31 + 12345), "--seconds", "3", "--trace", str(trace),
         "--allow-cpu",
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = proc.stdout.strip().splitlines()
-    result = json.loads(lines[-1])
-    assert sorted(result) == [
-        "attempted", "correct", "device", "failed", "metrics",
+    result, said, errors = _last_lines(proc)
+    # each number compared beside its limit: the result's last key and
+    # the last lines of standard error
+    assert list(result) == [
+        "correct", "attempted", "failed", "metrics", "device", "compared",
+    ]
+    compared = result["compared"]
+    assert {"rel_err_p100", "token_mismatches", "requests_failed",
+            "compiles_in_window"} == set(compared)
+    assert all(c["value"] <= c["limit"] for c in compared.values())
+    assert [ln.split()[:3] for ln in errors[-len(compared):]] == [
+        ["chipbench", "compared", name] for name in compared
     ]
     assert result["device"]["platform"] == "cpu"  # never a device number
     assert result["device"]["count"] == 1
@@ -57,7 +77,7 @@ def test_whole_run_on_the_cpu_prints_a_well_formed_last_line(trace):
     assert result["correct"] is True, proc.stdout[-3000:]
     from chipbench import manifest
 
-    cell = manifest.workload("tiny-rehearsal.rehearsal")
+    cell = manifest.workload(cell)
     if trace:
         # device-trace metrics have nothing to read on the CPU and are
         # left out; the counters and spans are there
@@ -75,13 +95,55 @@ def test_whole_run_on_the_cpu_prints_a_well_formed_last_line(trace):
     for name, m in result["metrics"].items():
         assert m["unit"] == manifest.metric(name)["unit"]
         assert isinstance(m["value"], float)
-    said = [json.loads(ln) for ln in lines[:-1] if ln.startswith('{"chipbench"')]
     kinds = {s["chipbench"] for s in said}
     assert {"generator", "server_loop", "runner_vs_reference",
             "compiles_in_window", "requests"} <= kinds
     cmp_line = next(s for s in said if s["chipbench"] == "runner_vs_reference")
     assert cmp_line["rel_err"] <= cmp_line["limits"]["limit"]
     assert cmp_line["token_rows"] > 0 and cmp_line["token_mismatches"] == 0
+    # a sliced vocabulary is a smaller vocabulary, on both sides
+    vocab = manifest.config(cell["config"])["published"]["vocab_size"]
+    assert cmp_line["logit_width"] == [vocab, vocab]
+    assert cmp_line["largest_served_token"] < vocab
+    if cell["config"] == "tiny-slice-rehearsal":
+        assert vocab == 288
+
+
+#: the served path broken underneath the harness: every token altered
+#: where it is produced, the requests answered all the same
+ALTERED_TOKENS = """
+import sys
+from dynamo_tpu.engine.runner import ModelRunner
+from chipbench import harness
+
+real = ModelRunner.unified_step
+
+def unified_step(self, lanes, *a, **kw):
+    out = real(self, lanes, *a, **kw)
+    return out._replace(last=(out.last + 1) % self.cfg.model.vocab_size)
+
+ModelRunner.unified_step = unified_step
+harness.main(sys.argv[1:])
+"""
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct():
+    proc = subprocess.run(
+        ["nice", "-n", "15", sys.executable, "-c", ALTERED_TOKENS,
+         "--workload", "tiny-rehearsal.rehearsal", "--seed", "77",
+         "--seconds", "2", "--trace", "0", "--allow-cpu"],
+        cwd=REPO, env=_env(), capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    result, said, errors = _last_lines(proc)
+    assert result["correct"] is False
+    assert result["attempted"] > 0 and result["failed"] == 0
+    tokens = result["compared"]["token_mismatches"]
+    assert tokens["value"] > tokens["limit"] == 0
+    logits = result["compared"]["rel_err_p100"]
+    assert logits["value"] <= logits["limit"]   # the logits are sound
+    assert any(ln.startswith("chipbench not_correct: token_mismatches")
+               for ln in errors)
 
 
 def test_without_a_chip_it_exits_non_zero_and_prints_no_result():
