@@ -6,19 +6,43 @@ runner the way the engine drives it: every dispatch is a list of lanes
 ``(new_tokens, block_ids, prefix_len, sampling)`` handed to
 ``ModelRunner.unified_step`` — the served executables, the runner's own
 operand building, its paged cache and the Pallas path in the served
-dtype. Prompts are prefilled in chunks that share ragged dispatches at
-the top budget rung, then come decode steps of one token a lane through
-the cache. The tokens fed are the sample's own (seeded), never sampled
-ones, so both sides see the same sequence.
+dtype. Which dispatches, and which of their rows are read, is the
+family's own step, a driver found by name: the configuration's ``check``
+block names ``"step": "<module>"`` (``"span"`` where it names none: chunked
+prefill, then decode steps of one token a lane through the cache, under a
+causal mask) and gives it ``"step_params"``, ``{}`` where it gives none.
 
-Two things are read from each dispatch, one row for each span (at the
-span's last position):
+A step driver is one module, ``chipbench/steps/<name>.py``, that provides
 
-* the greedy token ``unified_step`` returns — the served program and its
-  sampler;
-* the logits, from the runner's model function jitted over the very
+    drive(runner, sample, lens, decode_steps, seed, /, **step_params)
+        -> {"rows": int32 [B, R], "decode": bool [B, R],
+            "logits": float32 [B, R, V], "served": int64 [B, R],
+            "judged": bool [B, R]}
+    sample_len(n, decode_steps, **step_params) -> int
+
+* ``sample_len`` says how many of the sample's tokens a sequence of prompt
+  length ``n`` consumes; ``sample [B, pad_to]`` holds that many seeded ids
+  a sequence. The driver feeds the sample's own tokens, never sampled
+  ones, so both sides see the same sequence;
+* ``served`` holds the greedy tokens ``runner.unified_step`` returns (the
+  served program and its sampler) and ``judged`` marks the rows in which
+  it handed one out: only those are counted for ``token_mismatches``;
+* ``logits`` comes from the program's model function jitted over the very
   operands ``ModelRunner._unified_operands`` built for that dispatch (the
-  served program hands out tokens only).
+  served program hands out tokens only), at positions ``rows``;
+* ``decode`` marks the rows of the steps after the prompt. A sequence has
+  a prefill row or more, and decode rows where its ``sample_len`` is above
+  ``n``; the arrays are rectangular, a short sequence's last row repeated
+  in all five;
+* it imports nothing of ``chipbench/reference/``, and gives back itself,
+  before it returns, whatever device arrays it put on the runner beside
+  the parameters and the cache that ``free`` gives back.
+
+Everything after the drive is common code. A step that yields several
+tokens a dispatch changes nothing of what the window holds the server to:
+the stream carries one SSE chunk a token and ``usage.completion_tokens``
+equals ``max_tokens`` (``harness.run``, "every request returned exactly
+the tokens it asked for"), as the speculative path streams today.
 
 The reference (``chipbench/reference/<family>.py``, float32, ``highest``,
 weights drawn again from the seed) gives the logits of the same positions
@@ -44,7 +68,8 @@ program and provides
   out, as the program leaves it out. A sliced vocabulary is a smaller
   vocabulary: the sample's ids are drawn below ``published["vocab_size"]``
   and the logits are over the slice on both sides;
-* it returns, for each sequence ``b``, the logits at positions ``rows[b]``.
+* it returns, for each sequence ``b``, the logits at positions ``rows[b]``,
+  from one full forward pass under the family's own mask.
 
 Compared, each with its limit in the configuration's ``check`` block:
 
@@ -62,11 +87,14 @@ Compared, each with its limit in the configuration's ``check`` block:
     where the block has a ``phase_limit``: the ``phase_quantile``-th
     percentile within the prefill rows and within the decode rows apart,
     so that a low quantile over all rows cannot pass while most of one
-    phase's rows are wrong.
+    phase's rows are wrong. A phase without rows reads ``null``; a file
+    that sets a ``phase_limit`` and asks for no decode rows is refused
+    where its ``check`` block is read (``compare_kwargs``, which
+    ``manifest.check`` calls for every configuration), not after a drive.
 ``token_mismatches``
-    rows whose reference logits put the first token ``token_margin``
-    logit-RMS or more ahead of the second, and where the served greedy
-    token is not the reference's argmax. At most
+    ``judged`` rows whose reference logits put the first token
+    ``token_margin`` logit-RMS or more ahead of the second, and where the
+    served greedy token is not the reference's argmax. At most
     ``token_mismatch_limit``, 0 unless the block says otherwise.
 """
 
@@ -82,106 +110,15 @@ from chipbench import modelcfg
 PROMPT_LENS = (5, 37, 80, 150, 230, 300, 450, 601)
 DECODE_STEPS = 6
 PAD_TO = 640
-GREEDY = (0.0, 0, 1.0)
 
 
-def sample_tokens(seed: int, vocab: int, lens=PROMPT_LENS,
-                  decode_steps: int = DECODE_STEPS, pad_to: int = PAD_TO):
+def sample_tokens(seed: int, vocab: int, counts, pad_to: int = PAD_TO):
+    """Seeded ids, ``counts[b]`` of them in row ``b`` and zeros behind."""
     rng = np.random.default_rng([int(seed), 7])
-    tokens = np.zeros((len(lens), pad_to), np.int32)
-    for b, n in enumerate(lens):
-        tokens[b, : n + decode_steps] = rng.integers(1, vocab, n + decode_steps)
+    tokens = np.zeros((len(counts), pad_to), np.int32)
+    for b, n in enumerate(counts):
+        tokens[b, :n] = rng.integers(1, vocab, n)
     return tokens
-
-
-def plan_steps(lens, decode_steps: int, budget: int):
-    """Dispatches as ``[(sequence, prefix_len, new_tokens), ...]``: prompts
-    packed greedily into the budget, a long one split across dispatches
-    (chunked prefill), then ``decode_steps`` dispatches of one token each."""
-    steps, cur, room = [], [], budget
-    for b, n in enumerate(lens):
-        done = 0
-        while done < n:
-            take = min(n - done, room)
-            cur.append((b, done, take))
-            done += take
-            room -= take
-            if room == 0:
-                steps.append(cur)
-                cur, room = [], budget
-    if cur:
-        steps.append(cur)
-    for i in range(decode_steps):
-        steps.append([(b, n + i, 1) for b, n in enumerate(lens)])
-    return steps
-
-
-def runner_rows(runner, tokens, lens=PROMPT_LENS,
-                decode_steps: int = DECODE_STEPS, seed: int = 0):
-    """``(rows [B, R], decode [B, R] bool, logits [B, R, V] float32,
-    served tokens [B, R])`` from the runner: one row per span per
-    dispatch, at the span's last position; ``decode`` marks the rows of
-    decode steps. Leaves the sample's keys and values in the runner's
-    cache."""
-    import jax
-    import jax.numpy as jnp
-
-    from dynamo_tpu.models import llama
-
-    cfg = runner.cfg
-    bs, T = cfg.block_size, cfg.unified_token_budget
-    rng = np.random.default_rng([int(seed), 8])
-    need = -(-(max(lens) + decode_steps) // bs)
-    assert need <= cfg.max_blocks_per_seq
-    ids = rng.permutation(np.arange(1, cfg.num_blocks))[: need * len(lens)]
-    tables = ids.reshape(len(lens), need).tolist()
-
-    def logits_fn(params, kv, sc, token_ids, *meta):
-        out = llama.unified(
-            cfg.model, params, kv, token_ids, *meta, bs, attn=runner.attn,
-            kv_scales=sc,
-        )
-        return (out[0].astype(jnp.float32), *out[1:])
-
-    scales = runner.kv_scales
-    kv_sh = jax.tree.map(lambda a: a.sharding, runner.kv_caches)
-    out_sh = (None, kv_sh) if scales is None else (None, kv_sh, scales.sharding)
-    fn = jax.jit(
-        logits_fn, donate_argnums=(1,) if scales is None else (1, 2),
-        out_shardings=out_sh,
-    )
-    rows = [[] for _ in lens]
-    decode = [[] for _ in lens]
-    got = [[] for _ in lens]
-    served = [[] for _ in lens]
-    for spans in plan_steps(lens, decode_steps, T):
-        lanes = [
-            (tokens[b, prefix : prefix + n].tolist(), tables[b], prefix, GREEDY)
-            for b, prefix, n in spans
-        ]
-        toks = np.asarray(runner.unified_step(lanes).last)
-        # The same dispatch again for its logits: the same keys and values
-        # go to the same slots.
-        base, meta, *_ = runner._unified_operands(lanes, None, T)
-        out = fn(*base, *meta)
-        runner.kv_caches = out[1]
-        if scales is not None:
-            runner.kv_scales = out[2]
-        logits = np.asarray(out[0])
-        for s, (b, prefix, n) in enumerate(spans):
-            rows[b].append(prefix + n - 1)
-            decode[b].append(prefix >= lens[b])
-            got[b].append(logits[s])
-            served[b].append(int(toks[s]))
-    width = max(len(r) for r in rows)
-    # Pad the short sequences by repeating their last row: both sides
-    # then hold the same (duplicated) rows.
-    for b in range(len(lens)):
-        while len(rows[b]) < width:
-            for per_row in (rows, decode, got, served):
-                per_row[b].append(per_row[b][-1])
-    return (np.asarray(rows, np.int32), np.asarray(decode, bool),
-            np.asarray(got, np.float32), np.asarray(served, np.int64))
 
 
 def free(runner) -> None:
@@ -214,7 +151,8 @@ def compare(data: dict, seed: int, runner, *, weights_seed: int,
             prompt_lens=PROMPT_LENS, decode_steps: int = DECODE_STEPS,
             pad_to: int = PAD_TO, quantile: float = 100,
             phase_quantile: float | None = None,
-            token_margin: float = 0.0) -> dict:
+            token_margin: float = 0.0, step: str = "span",
+            step_params: dict | None = None) -> dict:
     """The runner against the reference for configuration file ``data``;
     frees the runner's arrays on the way. ``weights_seed`` is what the
     served weights were drawn from."""
@@ -223,23 +161,29 @@ def compare(data: dict, seed: int, runner, *, weights_seed: int,
     from chipbench import registry
 
     ref = registry.load("reference", data["reference"])
+    driver = registry.load("steps", step)
+    step_params = step_params or {}
     vocab = data["published"]["vocab_size"]
     lens = tuple(prompt_lens)
-    assert max(lens) + decode_steps <= pad_to
-    tokens = sample_tokens(seed, vocab, lens, decode_steps, pad_to)
-    rows, decode, got, served = runner_rows(
-        runner, tokens, lens, decode_steps, seed
-    )
-    assert np.isfinite(got).all(), "the runner's logits are not finite"
+    counts = [driver.sample_len(n, decode_steps, **step_params) for n in lens]
+    if max(counts) > pad_to:
+        raise ValueError(
+            f"{data['name']}: a sequence of the sample takes {max(counts)} "
+            f"tokens, check.pad_to is {pad_to}")
+    tokens = sample_tokens(seed, vocab, counts, pad_to)
+    out = driver.drive(runner, tokens, lens, decode_steps, seed, **step_params)
+    assert np.isfinite(out["logits"]).all(), \
+        "the runner's logits are not finite"
     free(runner)
     cut = share_arguments(data, ref)
     with jax.default_device(jax.devices()[0]):
         want = np.asarray(ref.logits(
-            data["published"], weights_seed, tokens, rows,
+            data["published"], weights_seed, tokens, out["rows"],
             dtype=data["dtype"], **cut,
         ))
     return verdict(
-        got, want, served, decode, quantile, phase_quantile, token_margin
+        out["logits"], want, out["served"], out["decode"], out["judged"],
+        quantile, phase_quantile, token_margin,
     )
 
 
@@ -263,37 +207,44 @@ def share_arguments(data: dict, ref) -> dict:
     return {}
 
 
-def verdict(got, want, served, decode, quantile: float = 100,
+def verdict(got, want, served, decode, judged, quantile: float = 100,
             phase_quantile: float | None = None,
             token_margin: float = 0.0) -> dict:
     """The numbers ``judge`` holds to the limits, from the runner's
-    logits and served tokens and the reference's logits. A short
+    logits and served tokens and the reference's logits. ``judged`` marks
+    the rows in which the served program handed out a token. A short
     sequence's last row stands in the sample as often as the longest
-    sequence has rows (``runner_rows`` pads to a rectangle), so it weighs
-    that much in a quantile."""
+    sequence has rows (the driver pads to a rectangle), so it weighs that
+    much in a quantile."""
     if phase_quantile is None:
         phase_quantile = quantile
     errs = row_errors(got, want)
     decode = np.asarray(decode, bool).reshape(-1)
     agree = np.asarray(served).reshape(-1) == want.reshape(
         len(errs), -1).argmax(-1)
-    judged = token_margins(want) >= token_margin
+    judged = np.asarray(judged, bool).reshape(-1)
+    clear = judged & (token_margins(want) >= token_margin)
+
+    def within(phase):
+        if not phase.any():
+            return None
+        return float(np.percentile(errs[phase], phase_quantile))
+
     return {
         "rows": int(errs.size),
         "quantile": quantile,
         "rel_err": float(np.percentile(errs, quantile)),
         "phase_quantile": phase_quantile,
         "rel_err_by_phase": {
-            "prefill": float(np.percentile(errs[~decode], phase_quantile)),
-            "decode": float(np.percentile(errs[decode], phase_quantile)),
+            "prefill": within(~decode), "decode": within(decode),
         },
         "rel_err_quantiles": {
             f"p{q}": float(np.percentile(errs, q))
             for q in (5, 25, 50, 75, 100)
         },
-        "token_rows": int(judged.sum()),
-        "token_mismatches": int((judged & ~agree).sum()),
-        "token_mismatches_all_rows": int((~agree).sum()),
+        "token_rows": int(clear.sum()),
+        "token_mismatches": int((clear & ~agree).sum()),
+        "token_mismatches_all_rows": int((judged & ~agree).sum()),
         "largest_logit": float(np.abs(want).max()),
         "logit_width": [int(got.shape[-1]), int(want.shape[-1])],
         "largest_served_token": int(np.max(served)),
@@ -333,10 +284,25 @@ def judge(verdict: dict, limits: dict) -> list[str]:
 
 #: the keys of a configuration's ``check`` block that ``compare`` takes
 COMPARE_KEYS = ("prompt_lens", "decode_steps", "pad_to", "quantile",
-                "phase_quantile", "token_margin")
+                "phase_quantile", "token_margin", "step", "step_params")
 #: and those that ``judge`` holds the result to
 LIMIT_KEYS = ("limit", "phase_limit", "token_mismatch_limit")
 
 
 def compare_kwargs(data: dict) -> dict:
-    return {k: v for k, v in data["check"].items() if k in COMPARE_KEYS}
+    """What configuration file ``data`` asks of ``compare``. A file that
+    sets a ``phase_limit`` over a sample without decode rows is refused
+    here, before the drive."""
+    from chipbench import registry
+
+    block = data["check"]
+    asked = {k: v for k, v in block.items() if k in COMPARE_KEYS}
+    if "phase_limit" in block:
+        driver = registry.load("steps", asked.get("step", "span"))
+        steps = asked.get("decode_steps", DECODE_STEPS)
+        if all(driver.sample_len(n, steps, **asked.get("step_params", {})) <= n
+               for n in asked.get("prompt_lens", PROMPT_LENS)):
+            raise ValueError(
+                f"{data['name']}: check.phase_limit is set, but "
+                f"decode_steps {steps} leaves the sample without decode rows")
+    return asked

@@ -15,9 +15,20 @@ and new entries, never by an edit of a file that is there:
     ``layer_period``; ``assumed``, ``deployment``, ``chips``,
     ``serve_args`` and the ``check`` block with the limits of ``correct``.
     ``modelcfg`` says how it resolves and what a cut is held to.
+    The ``check`` block may name the family's step, ``"step":
+    "<module>"`` with its ``"step_params"`` (``"span"``, one token a lane
+    under a causal mask, where it names none).
 ``reference/<family>.py``
     the plain reference of a family: one ``logits`` function
     (``check``'s docstring has its signature and what it must do).
+``steps/<name>.py``
+    how the check drives a family's own step through the served runner:
+    ``drive`` and ``sample_len`` (``check``'s docstring has the contract).
+    ``steps/span.py`` is chunked prefill, then decode steps of one token a
+    lane, under a causal mask; a family whose step feeds or yields a block
+    of tokens brings its own. Whatever the step yields a dispatch, the
+    window holds the server to one SSE chunk a token and
+    ``usage.completion_tokens`` equal to ``max_tokens``.
 ``traffic/<name>.json`` (+ ``distributions/<name>.py``)
     a traffic mix's parameters, which ``traffic.py`` generates from.
 ``workloads/<config>.<traffic>.json``
@@ -74,7 +85,7 @@ def benchmark_json() -> dict:
 def check(bench: dict | None = None) -> list[str]:
     """Every inconsistency between ``BENCHMARK.json`` and the data files,
     as one line each (an empty list is a sound manifest)."""
-    from chipbench import registry, traffic
+    from chipbench import check as check_, registry, traffic
 
     bench = bench if bench is not None else benchmark_json()
     bad: list[str] = []
@@ -93,6 +104,10 @@ def check(bench: dict | None = None) -> list[str]:
         if sorted(data.get("reduced", [])) != sorted(c["reduced"]):
             bad.append(f"config {c['name']}: reduced differs from its file")
         registry.load("reference", data["reference"])
+        try:
+            check_.compare_kwargs(data)
+        except ValueError as e:
+            bad.append(str(e))
 
     reported: dict[str, list[str]] = {}
     for name, w in cells.items():

@@ -1,13 +1,13 @@
 """Modules found by name: a reader, a length distribution, a reference
-architecture or a kernel's cost function joins by being a module in its
-directory."""
+architecture, a kernel's cost function or the check's step driver joins
+by being a module in its directory."""
 
 from __future__ import annotations
 
 import importlib
 import re
 
-KINDS = ("readers", "distributions", "reference", "costs")
+KINDS = ("readers", "distributions", "reference", "costs", "steps")
 _NAME = re.compile(r"^[A-Za-z0-9_]+$")
 
 

@@ -90,6 +90,15 @@ def test_metric(name):
         assert m["unit"] == "%"
 
 
+def test_every_metric_file_is_declared():
+    # a metric taken out of BENCHMARK.json takes its file with it
+    files = {
+        os.path.basename(p)[:-len(".json")]
+        for p in glob.glob(os.path.join(manifest.ROOT, "metrics", "*.json"))
+    }
+    assert files == set(METRICS)
+
+
 @pytest.mark.parametrize("config", CONFIG_FILES)
 def test_config_widths_are_the_published_ones(config):
     data = manifest.config(config)
@@ -108,6 +117,8 @@ def test_config_widths_are_the_published_ones(config):
             assert getattr(cfg, field) == getattr(preset, field), key
     assert cfg.name == config
     assert 0 < data["check"]["limit"]
+    driver = registry.load("steps", data["check"].get("step", "span"))
+    assert callable(driver.drive) and callable(driver.sample_len)
     if config in CONFIGS:
         entry = next(c for c in BENCH["configs"] if c["name"] == config)
         assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
@@ -303,3 +314,6 @@ def test_registry_refuses_what_is_not_there():
     with pytest.raises(KeyError, match="costs/no_such_kernel.py"):
         registry.load("costs", "no_such_kernel")
     assert callable(registry.load("costs", "ragged_paged_attention").cost)
+    with pytest.raises(KeyError, match="steps/no_such_step.py"):
+        registry.load("steps", "no_such_step")
+    assert callable(registry.load("steps", "span").drive)

@@ -14,6 +14,7 @@ import pytest
 from chipbench import check, manifest, registry
 from chipbench.peaks import peaks_for
 from chipbench.reference import mistral as ref
+from chipbench.steps import span
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 
@@ -112,23 +113,24 @@ def test_verdict_by_phase_and_a_wrong_sampler_is_not_correct():
     want = rng.normal(size=(4, 5, 50)).astype(np.float32)
     decode = np.tile(np.asarray([False, False, True, True, True]), (4, 1))
     served = want.argmax(-1)
+    judged = np.ones(decode.shape, bool)
     limits = {"limit": 0.01}
     one = want.copy()
     one[2, 3] *= 1.5
-    v100 = check.verdict(one, want, served, decode)
+    v100 = check.verdict(one, want, served, decode, judged)
     assert v100["rel_err"] == pytest.approx(0.5)
     assert len(check.judge(v100, limits)) == 1
     prefill_wrong = want.copy()
     prefill_wrong[:, :2] *= 1.5
-    v25 = check.verdict(prefill_wrong, want, served, decode, quantile=25,
-                        phase_quantile=50)
+    v25 = check.verdict(prefill_wrong, want, served, decode, judged,
+                        quantile=25, phase_quantile=50)
     assert v25["rel_err"] == 0.0                       # 8 of 20 rows wrong
     assert check.judge(v25, limits) == []
     why = check.judge(v25, dict(limits, phase_limit=0.02))
     assert len(why) == 1 and "prefill" in why[0]
-    sound = check.verdict(want, want, served, decode)
+    sound = check.verdict(want, want, served, decode, judged)
     assert check.judge(sound, dict(limits, phase_limit=0.02)) == []
-    broken = check.verdict(want, want, (served + 1) % 50, decode)
+    broken = check.verdict(want, want, (served + 1) % 50, decode, judged)
     assert broken["token_mismatches"] == broken["token_rows"] == 20
     assert check.judge(broken, dict(limits, token_mismatch_limit=12))
 
@@ -142,7 +144,7 @@ def test_sliding_window_changes_the_answer():
 
 
 def test_plan_steps_chunks_a_long_prompt_and_then_decodes():
-    steps = check.plan_steps((5, 70, 20), decode_steps=2, budget=32)
+    steps = span.plan_steps((5, 70, 20), decode_steps=2, budget=32)
     assert all(sum(n for _, _, n in s) <= 32 for s in steps)
     covered = {b: 0 for b in range(3)}
     for s in steps[:-2]:
@@ -229,9 +231,12 @@ def _compare_with(monkeypatch, data, module):
     rows = np.zeros((1, 2), np.int32)
     got = np.ones((1, 2, data["published"]["vocab_size"]), np.float32)
     monkeypatch.setattr(
-        check, "runner_rows",
-        lambda *a, **kw: (rows, np.asarray([[False, True]]), got,
-                          np.zeros((1, 2), np.int64)),
+        span, "drive",
+        lambda *a, **kw: {
+            "rows": rows, "decode": np.asarray([[False, True]]),
+            "logits": got, "served": np.zeros((1, 2), np.int64),
+            "judged": np.ones((1, 2), bool),
+        },
     )
     monkeypatch.setattr(check, "free", lambda runner: None)
     calls = []
