@@ -1056,7 +1056,12 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
     else:
         raise SystemExit(f"bad --out {args.output!r}")
 
-    served = await endpoint.serve(engine)
+    # With the frontend in this same process (every --in but dyn://) its
+    # router calls the engine directly; a worker has no router to offer
+    # it to, and every other process reaches either over the wire.
+    served = await endpoint.serve(
+        engine, offer_local=not args.input.startswith("dyn://")
+    )
     await register_llm(drt, endpoint, card, model_type=card.model_type)
     print(f"model {card.name!r} registered at {endpoint_path}", flush=True)
     tpu_engine = engine if args.output == "tpu" and hasattr(

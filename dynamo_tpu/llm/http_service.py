@@ -281,6 +281,14 @@ class HttpService:
         self.metrics.set_gauge(
             "workers_marked_dead_total", float(FAILOVER.marked_dead_total)
         )
+        # Which way this process's routers dispatched (runtime/egress.py):
+        # a one-process deployment reads every request local and none on
+        # the wire, a frontend of remote workers the reverse.
+        for path in ("local", "wire"):
+            self.metrics.set_gauge(
+                f"router_dispatch_{path}_total",
+                float(FAILOVER.dispatch_total(path)),
+            )
         # Per-class shed counters (llm/slo.py; process-wide like
         # shed_requests_total): the cheapest-first contract is only
         # auditable with the split visible.
@@ -845,6 +853,11 @@ class HealthServer:
         self.metrics.set_gauge(
             "workers_marked_dead_total", float(FAILOVER.marked_dead_total)
         )
+        for path in ("local", "wire"):
+            self.metrics.set_gauge(
+                f"router_dispatch_{path}_total",
+                float(FAILOVER.dispatch_total(path)),
+            )
         # Router-plane gauges too: a RouterService process fronts its
         # KvRouter with a HealthServer, and the indexer-staleness /
         # scrape-failure counters live exactly there.

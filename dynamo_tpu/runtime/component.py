@@ -132,12 +132,22 @@ class Endpoint:
         """Per-instance request subject (reference: component.rs:335-346)."""
         return f"{self.component.service_name}.{self.name}-{lease_id:x}"
 
-    async def serve(self, engine: Any, metadata: dict | None = None):
+    async def serve(
+        self,
+        engine: Any,
+        metadata: dict | None = None,
+        offer_local: bool = False,
+    ):
         """Register this endpoint instance and start handling requests.
-        Returns a `ServedInstance` handle (stop() deregisters)."""
+        Returns a `ServedInstance` handle (stop() deregisters).
+        ``offer_local`` lets routers of this same runtime call the
+        engine directly (runtime/ingress.py); said only by code that
+        builds both halves of one process."""
         from dynamo_tpu.runtime.ingress import serve_endpoint
 
-        return await serve_endpoint(self._drt, self, engine, metadata)
+        return await serve_endpoint(
+            self._drt, self, engine, metadata, offer_local=offer_local
+        )
 
     async def client(self, **kwargs):
         from dynamo_tpu.runtime.egress import Client

@@ -51,6 +51,11 @@ class DistributedRuntime:
         self._keepalive = keepalive
         self._tcp_server: TcpStreamServer | None = None
         self._tcp_lock = asyncio.Lock()
+        #: Served instances that offer the local call, by bus subject
+        #: (runtime/ingress.py serve_endpoint(offer_local=True)): where
+        #: this runtime's routers find the engine behind an instance
+        #: they picked (runtime/egress.py PushRouter._dispatch).
+        self.local_instances: dict = {}
         runtime.token.on_cancel(self._on_shutdown)
 
     # -- constructors -------------------------------------------------------

@@ -3,10 +3,25 @@
 `Client` maintains a live instance list for an endpoint (static list or a
 discovery-store watch — reference: lib/runtime/src/component/client.rs:1-224).
 `PushRouter` picks an instance per request — Random / RoundRobin / Direct /
-KV-aware — publishes the request envelope to the instance's bus subject with
-embedded TCP connection info, and yields the response stream (reference:
-lib/runtime/src/pipeline/network/egress/push_router.rs:65-203,
-addressed_router.rs:59-178).
+KV-aware — and dispatches to it one of two ways, decided by what it finds,
+not by a setting:
+
+- **the wire** (any instance): publish the request envelope to the
+  instance's bus subject with embedded TCP connection info, wait for the
+  worker's connect-back, and yield the msgpack frames of the response stream
+  (reference: lib/runtime/src/pipeline/network/egress/push_router.rs:65-203,
+  addressed_router.rs:59-178);
+- **the local call** (an instance this very runtime serves and offers,
+  ``DistributedRuntime.local_instances``): the engine's own async generator
+  through ``ServedInstance.local_stream`` — no envelope, publish,
+  connect-back, socket or handler task (reference: `dynamo-run in=http
+  out=<engine>` hands the local engine to the HTTP service in-process; only
+  `out=dyn` goes over the network).
+
+Picking, the ``fleet.worker_kill`` fault point, mark-dead, the ``route`` span,
+``worker_id`` and the per-frame trace touch are one code path for both;
+``router_dispatch_local_total`` / ``router_dispatch_wire_total`` (FAILOVER)
+count which was taken.
 """
 
 from __future__ import annotations
@@ -369,7 +384,7 @@ class PushRouter:
                     exclude=tried or None,
                 )
             try:
-                receiver = await self._dispatch(instance, request)
+                frames = await self._dispatch(instance, request)
             except (
                 ConnectionError, OSError,
                 asyncio.TimeoutError, TimeoutError,
@@ -388,14 +403,14 @@ class PushRouter:
                     ) from exc
                 continue
             request.annotations["worker_id"] = instance.instance_id
-            async for item in self._relay(instance, receiver, request):
+            async for item in self._relay(instance, frames, request):
                 yield item
             return
 
     async def direct(self, request: Context, instance_id: int) -> AsyncIterator[Any]:
         instance = await self._pick(request.payload, instance_id)
         try:
-            receiver = await self._dispatch(instance, request)
+            frames = await self._dispatch(instance, request)
         except (
             ConnectionError, OSError, asyncio.TimeoutError, TimeoutError,
         ) as exc:
@@ -403,15 +418,25 @@ class PushRouter:
                 instance.instance_id, f"dispatch:{type(exc).__name__}"
             )
             raise
-        async for item in self._relay(instance, receiver, request):
+        async for item in self._relay(instance, frames, request):
             yield item
 
     async def _dispatch(self, instance: Instance, request: Context):
-        """Publish the request envelope and wait for the worker's
-        response connection (the dispatch ack). Raises the typed
-        transport error on a dead subject (NoSubscriberError), an
-        injected ``fleet.worker_kill`` fault, or a connect-back that
-        never arrives — the three faces of 'the worker is a corpse'."""
+        """Hand the request to `instance` and return its stream of
+        frames (an async generator), each as msgpack delivers it. An instance this runtime
+        serves and offers is called directly; any other gets the request
+        envelope published and its response connection awaited (the
+        dispatch ack). Raises the typed transport error on an injected
+        ``fleet.worker_kill`` fault, a dead subject (NoSubscriberError)
+        or a connect-back that never arrives — the three faces of 'the
+        worker is a corpse'."""
+        if FAULTS.active:
+            await FAULTS.maybe_fail_async("fleet.worker_kill")
+        served = self._drt.local_instances.get(instance.subject)
+        if served is not None:
+            FAILOVER.note_dispatch("local")
+            return served.local_stream(request)
+        FAILOVER.note_dispatch("wire")
         server = await self._drt.tcp_server()
         stream_id = uuid.uuid4().hex
         receiver = server.register(stream_id)
@@ -426,8 +451,6 @@ class PushRouter:
             "trace": tracer().context_wire(request.id, parent_span="route"),
         }
         try:
-            if FAULTS.active:
-                await FAULTS.maybe_fail_async("fleet.worker_kill")
             await self._drt.bus.publish(
                 instance.subject, msgpack.packb(envelope),
                 require_subscriber=True,
@@ -438,22 +461,22 @@ class PushRouter:
         except BaseException:
             server.unregister(stream_id)
             raise
-        return receiver
+        return _unpacked(receiver)
 
     async def _relay(
-        self, instance: Instance, receiver, request: Context
+        self, instance: Instance, frames, request: Context
     ) -> AsyncIterator[Any]:
         from dynamo_tpu.llm.protocols.common import WorkerDiedError
 
         try:
-            async for payload in receiver:
+            async for item in frames:
                 if request.is_killed:
                     break
                 # Each streamed frame proves the request is alive: refresh
                 # the frontend capture's TTL so a stream outliving ttl_s is
                 # not reaped (and falsely counted abandoned) mid-flight.
                 tracer().touch(request.id)
-                yield msgpack.unpackb(payload)
+                yield item
         except WorkerDiedError as exc:
             # Mid-stream death: evict + poison NOW so the failover
             # re-dispatch (and every other request) stops routing here.
@@ -465,3 +488,14 @@ class PushRouter:
             if getattr(exc, "transport_dead", False):
                 self.mark_dead(instance.instance_id, "stream")
             raise
+        finally:
+            # A caller that leaves early (a client gone, a stop string
+            # hit) ends a local call's engine stream now, not when the
+            # collector finds the generator.
+            await frames.aclose()
+
+
+async def _unpacked(receiver) -> AsyncIterator[Any]:
+    """The wire's response frames, decoded."""
+    async for payload in receiver:
+        yield msgpack.unpackb(payload)

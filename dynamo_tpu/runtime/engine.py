@@ -57,6 +57,13 @@ class Context(Generic[T]):
             annotations=self.annotations,
         )
 
+    def linked(self, payload: U) -> "Context[U]":
+        """New payload and fresh annotations under the same id and
+        signals: what a callee in this process receives in place of the
+        Context the wire would rebuild for it (runtime/ingress.py) —
+        the caller's stop/kill reach it, the caller's notes do not."""
+        return Context(payload, id=self.id, stop=self._stop, kill=self._kill)
+
     def stop_generating(self) -> None:
         self._stop.cancel()
 

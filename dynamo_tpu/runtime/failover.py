@@ -29,7 +29,10 @@ stream on it; now request survival is an *ingress-side* property
 ``FAILOVER`` is the process-wide counter registry
 (``failover_total`` / ``failover_success_total`` /
 ``workers_marked_dead_total``, split per reason), exported on all three
-metric surfaces next to ``retries_total``.
+metric surfaces next to ``retries_total``. It also counts the router's
+dispatches by the path each took (``router_dispatch_local_total`` /
+``router_dispatch_wire_total``, runtime/egress.py), on the two HTTP
+``/metrics`` surfaces: only a process that routes has them.
 """
 
 # dynarace: context[loop]
@@ -60,6 +63,17 @@ class FailoverStats:
         self.attempts_by_reason: dict[str, int] = {}
         self.success_by_reason: dict[str, int] = {}
         self.marked_dead_by_reason: dict[str, int] = {}
+        self.dispatch_by_path: dict[str, int] = {"local": 0, "wire": 0}
+
+    def note_dispatch(self, path: str) -> None:
+        """One PushRouter dispatch: ``local`` (a direct call of an engine
+        this process serves) or ``wire`` (envelope + response socket)."""
+        with self._lock:
+            self.dispatch_by_path[path] += 1
+
+    def dispatch_total(self, path: str) -> int:
+        with self._lock:
+            return self.dispatch_by_path[path]
 
     def note_attempt(self, reason: str) -> None:
         with self._lock:

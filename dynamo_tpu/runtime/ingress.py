@@ -9,24 +9,55 @@ network.rs:279-323 `Ingress::for_engine`).
 Request envelope (msgpack): ``{"id": str, "payload": <obj>, "resp":
 {host, port, stream_id}}``. Response frames carry msgpack-serialized items;
 the final frame is an end/err control frame (transports/tcp.py).
+
+An instance served with ``offer_local`` also answers a router of its own
+runtime without the wire (``ServedInstance.local_stream``): the same
+engine call under the same contract — tracked in ``inflight``, refused
+while draining, killed by ``kill()``, errors typed as the wire's decoder
+types them, frames equal to what msgpack would have delivered — with no
+envelope, socket or handler task (docs/architecture/request_plane.md).
 """
 
 from __future__ import annotations
 
 import asyncio
 import logging
-from typing import Any
+from typing import Any, AsyncIterator
 
 import msgpack
 
 from dynamo_tpu.runtime.component import Endpoint, Instance
 from dynamo_tpu.runtime.engine import AsyncEngine, Context
-from dynamo_tpu.runtime.transports.tcp import ConnectionInfo, TcpResponseSender
+from dynamo_tpu.runtime.transports.tcp import (
+    ConnectionInfo,
+    TcpResponseSender,
+    _typed_stream_error,
+)
 from dynamo_tpu.utils.logging import request_scope
 from dynamo_tpu.utils.task import spawn_tracked
 from dynamo_tpu.utils.tracing import TraceContext, tracer
 
 logger = logging.getLogger(__name__)
+
+
+class _LocalCall(asyncio.Future):
+    """One local call as the instance's ``inflight`` set holds it beside
+    the wire's handler tasks: done when its stream has ended, so
+    ``drain()`` waits for it, and cancelled by ``kill()``. The caller
+    runs the engine in its own task: a cancel that finds that task
+    inside the stream cancels it (``local_stream`` turns that back into
+    ``WorkerDiedError``); one that finds it between two frames is seen
+    when it asks for the next."""
+
+    stream: Any = None  # the local_stream generator
+    task: asyncio.Task | None = None  # its consumer
+
+    def cancel(self, msg: Any = None) -> bool:
+        if not super().cancel(msg):
+            return False
+        if self.stream.ag_running:
+            self.task.cancel()
+        return True
 
 
 class ServedInstance:
@@ -38,13 +69,15 @@ class ServedInstance:
     in-flight request handlers, then deregister."""
 
     def __init__(
-        self, drt, instance: Instance, sub, task, inflight: set
+        self, drt, instance: Instance, sub, task, inflight: set, engine
     ) -> None:
         self.instance = instance
         self._drt = drt
         self._sub = sub
         self._task = task
         self._inflight = inflight
+        self._engine = engine
+        self._draining = False
 
     def __getattr__(self, name):
         return getattr(self.instance, name)
@@ -68,6 +101,7 @@ class ServedInstance:
         responses (the response plane is direct TCP, independent of
         discovery, so they complete untouched). Returns True when nothing
         was abandoned."""
+        self._draining = True
         await self._deregister()
         self._sub.close()
         self._task.cancel()
@@ -86,7 +120,16 @@ class ServedInstance:
                 return False
         return True
 
+    def _withdraw_local(self) -> None:
+        """Stop offering the local call: a router that still picks this
+        instance goes to the wire and finds no subscriber, as it does for
+        any stopped or dead worker."""
+        offered = self._drt.local_instances
+        if offered.get(self.instance.subject) is self:
+            del offered[self.instance.subject]
+
     async def stop(self) -> None:
+        self._withdraw_local()
         self._sub.close()
         self._task.cancel()
         try:
@@ -104,7 +147,9 @@ class ServedInstance:
         does NOT deregister: a crashed process never gets to clean up
         discovery — the lease TTL (slow path) or the router's mark-dead
         fast path is what evicts the corpse, which is exactly the seam
-        the failover plane exists to cover."""
+        the failover plane exists to cover. A local call is cancelled
+        like a handler: its caller sees the same error."""
+        self._withdraw_local()
         self._sub.close()
         self._task.cancel()
         doomed = [self._task, *self._inflight]
@@ -116,15 +161,115 @@ class ServedInstance:
             except (asyncio.CancelledError, Exception):  # noqa: BLE001 — dying
                 pass
 
+    def local_stream(self, request: Context) -> AsyncIterator[Any]:
+        """The engine's stream for a caller in this process: what
+        ``_handle_request`` does for an envelope, in the caller's own
+        task and with nothing serialized."""
+        call = _LocalCall()
+        call.stream = self._local_stream(call, request)
+        return call.stream
+
+    async def _local_stream(
+        self, call: _LocalCall, request: Context
+    ) -> AsyncIterator[Any]:
+        from dynamo_tpu.llm.protocols.common import ShedError
+
+        if self._draining:
+            raise ShedError(
+                "instance draining — retry another instance", draining=True
+            )
+        rid = request.id
+        call.task = asyncio.current_task()
+        self._inflight.add(call)
+        call.add_done_callback(self._inflight.discard)
+        trace = tracer()
+        # The hop edge the envelope's trace context records on the wire.
+        hop = trace.context(rid, parent_span="route")
+        trace.adopt(rid, hop)
+        # The payload as the envelope delivers it: the engine's own copy.
+        frames = self._engine.generate(
+            request.linked(msgpack.unpackb(msgpack.packb(request.payload)))
+        ).__aiter__()
+        try:
+            while True:
+                try:
+                    # Scoped a step, not around the loop: a generator's
+                    # frames may be resumed and closed in other contexts.
+                    with request_scope(rid, hop.trace_id):
+                        item = await frames.__anext__()
+                except StopAsyncIteration:
+                    break
+                except asyncio.CancelledError:
+                    if not call.cancelled():
+                        raise  # the caller's own cancellation
+                    _trace_failed(rid)
+                    if call.task.uncancel():
+                        raise  # and its caller was cancelled besides
+                    raise _killed() from None
+                except Exception as exc:  # noqa: BLE001 — typed for the caller
+                    logger.exception("request %s failed", rid)
+                    _trace_failed(rid)
+                    raise _typed_stream_error(_wire_error(exc)) from exc
+                yield _as_wire(item)
+                if call.cancelled():
+                    _trace_failed(rid)
+                    raise _killed()
+            trace.finish(rid)
+        finally:
+            aclose = getattr(frames, "aclose", None)
+            if aclose is not None:
+                await aclose()
+            if not call.done():
+                call.set_result(None)
+
+
+def _killed() -> Exception:
+    """What the caller of a killed instance sees: the error the wire's
+    receiver raises for a socket closed with no terminal frame."""
+    from dynamo_tpu.llm.protocols.common import WorkerDiedError
+
+    err = WorkerDiedError(
+        "local call ended without a terminal frame — worker died mid-stream"
+    )
+    err.transport_dead = True
+    return err
+
+
+_PLAIN = frozenset((str, int, float, bool, bytes, type(None)))
+
+
+def _as_wire(item: Any) -> Any:
+    """`item` as ``msgpack.unpackb(msgpack.packb(item))`` would deliver
+    it. An engine's token frame — a dict of plain values and short lists
+    of them — is that already and passes as it is; anything else takes
+    the round trip, so both paths deliver equal frames by construction."""
+    if type(item) is dict:
+        for key, val in item.items():
+            kind = type(val)
+            if type(key) is str and (
+                kind in _PLAIN
+                or kind is list and all(type(v) in _PLAIN for v in val)
+            ):
+                continue
+            break
+        else:
+            return item
+    return msgpack.unpackb(msgpack.packb(item, default=_default))
+
 
 async def serve_endpoint(
     drt,
     endpoint: Endpoint,
     engine: AsyncEngine,
     metadata: dict | None = None,
+    offer_local: bool = False,
 ) -> ServedInstance:
     """Register `engine` as a live instance of `endpoint` and start the
-    request pump. Returns the registered instance handle."""
+    request pump. Returns the registered instance handle. With
+    ``offer_local`` a router of this same runtime that picks the instance
+    calls the engine directly (``ServedInstance.local_stream``);
+    discovery, the lease and the bus subject are the same either way, so
+    every other process still reaches it over the wire."""
     lease_id = drt.primary_lease_id
     subject = endpoint.subject_for(lease_id)
     instance = Instance(endpoint=endpoint.id, lease_id=lease_id, subject=subject)
@@ -148,9 +293,14 @@ async def serve_endpoint(
             pass
 
     task = asyncio.ensure_future(pump())
-    drt.runtime.token.on_cancel(lambda: (sub.close(), task.cancel()))
+    served = ServedInstance(drt, instance, sub, task, inflight, engine)
+    if offer_local:
+        drt.local_instances[subject] = served
+    drt.runtime.token.on_cancel(
+        lambda: (served._withdraw_local(), sub.close(), task.cancel())
+    )
     logger.info("serving %s on %s (lease %#x)", endpoint.id, subject, lease_id)
-    return ServedInstance(drt, instance, sub, task, inflight)
+    return served
 
 
 async def _handle_request(engine: AsyncEngine, raw: bytes) -> None:
@@ -182,23 +332,26 @@ async def _handle_request(engine: AsyncEngine, raw: bytes) -> None:
             # teardown): abort the response socket with NO terminal
             # frame — the caller must see WorkerDiedError and fail the
             # request over, not a clean-looking truncated stream.
-            tracer().mark_if_active(rid, "error")
-            tracer().finish(rid)
+            _trace_failed(rid)
             if sender is not None:
                 sender.abort()
             raise
         except Exception as exc:  # noqa: BLE001 — report to caller, don't die
             logger.exception("request %s failed", envelope.get("id"))
-            # The worker-side capture must not leak (or orphan) when the
-            # request dies on the error plane: mark + finish under the
-            # SAME trace id the caller will finish its half with.
-            tracer().mark_if_active(rid, "error")
-            tracer().finish(rid)
+            _trace_failed(rid)
             if sender is not None:
                 try:
                     await sender.error(_wire_error(exc))
                 except Exception:
                     pass
+
+
+def _trace_failed(rid: str) -> None:
+    """The worker-side capture must not leak (or orphan) when the request
+    dies on the error plane: mark + finish under the SAME trace id the
+    caller will finish its half with."""
+    tracer().mark_if_active(rid, "error")
+    tracer().finish(rid)
 
 
 def _wire_error(exc: Exception) -> str:

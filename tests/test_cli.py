@@ -5,6 +5,7 @@ Subprocess-driven like a user would run them; CPU backend, tiny preset.
 """
 
 import asyncio
+import json
 import os
 import re
 import signal
@@ -134,6 +135,55 @@ async def test_cli_http_serves_tpu_preset():
         await _stop(proc)
 
 
+def _dispatch_counts(metrics_text: str) -> dict[str, float]:
+    return {
+        path: float(re.search(
+            rf"^\S*router_dispatch_{path}_total (\S+)$", metrics_text, re.M
+        ).group(1))
+        for path in ("local", "wire")
+    }
+
+
+async def test_cli_one_process_dispatches_locally():
+    """`--in http --out <engine>` in one process: the frontend calls the
+    engine it lives with. Every request is counted local, none on the
+    wire, and the model is still registered for anyone else."""
+    proc, m = await _spawn_cli(
+        "run", "--in", "http", "--out", "echo_core",
+        "--http-host", "127.0.0.1", "--http-port", "0",
+        ready_pattern=r"OpenAI server on http://127\.0\.0\.1:(\d+)",
+    )
+    try:
+        base = f"http://127.0.0.1:{m.group(1)}"
+        async with httpx.AsyncClient() as client:
+            r = await client.get(f"{base}/v1/models")
+            assert [x["id"] for x in r.json()["data"]] == ["echo_core"]
+            for i in range(5):
+                body = {
+                    "model": "echo_core",
+                    "messages": [{"role": "user", "content": f"ping {i}"}],
+                    "stream": bool(i % 2),
+                }
+                r = await client.post(
+                    f"{base}/v1/chat/completions", json=body, timeout=60
+                )
+                assert r.status_code == 200, r.text
+                assert f"ping {i}" in (
+                    "".join(
+                        c["delta"].get("content") or ""
+                        for line in r.text.splitlines()
+                        if line.startswith("data: {")
+                        for c in json.loads(line[6:])["choices"]
+                    )
+                    if body["stream"]
+                    else r.json()["choices"][0]["message"]["content"]
+                )
+            counts = _dispatch_counts((await client.get(f"{base}/metrics")).text)
+            assert counts == {"local": 5.0, "wire": 0.0}
+    finally:
+        await _stop(proc)
+
+
 async def test_cli_worker_joins_frontend():
     """Two shell commands: a frontend hosting the control plane + HTTP, and
     a separate worker process joining it — the reference's
@@ -183,6 +233,11 @@ async def test_cli_worker_joins_frontend():
             )
             assert r.status_code == 200, r.text
             assert "ping pong" in r.json()["choices"][0]["message"]["content"]
+            # Two processes: the picked instance is never local.
+            counts = _dispatch_counts(
+                (await client.get(f"http://127.0.0.1:{port}/metrics")).text
+            )
+            assert counts == {"local": 0.0, "wire": 1.0}
     finally:
         await _stop(front)
         if worker is not None:
