@@ -2,8 +2,7 @@
 
 Runs the full TpuEngine (scheduler → paged KV cache → jitted steps) on a
 Llama-3.2-1B-shaped model with random weights: 32 requests, ISL 128 /
-OSL 64, greedy. Reports generated tokens/sec/chip plus a steady-state
-decode microbench (per-step ms and effective HBM bandwidth).
+OSL 64, greedy. Reports generated tokens/sec/chip.
 
 ``vs_baseline`` is measured against the only absolute rate the reference
 checks in — its echo test engine at 100 tok/s (reference:
@@ -133,7 +132,6 @@ def _engine_config():
             "BENCH_MAXLEN",
             max(512, 1 << (ISL + OSL - 1).bit_length()),
         ),
-        decode_chunk=8 if SMOKE else _env_int("BENCH_CHUNK", 16),
         prefill_batch=4 if SMOKE else _env_int("BENCH_PREFILL_BATCH", 16),
         enable_prefix_caching=True,
         # DYNAMO_TPU_QUANT=int8 serves int8 weights (ops/quant.py) — halves
@@ -143,7 +141,6 @@ def _engine_config():
         # random-prompt scenario accepts ~nothing — real value shows on
         # repetitive text; see tests/test_speculative.py).
         speculative_k=_env_int("BENCH_SPEC_K", 0),
-        unified=True,
         unified_token_budget=_env_int(
             "BENCH_UNIFIED_BUDGET", 64 if SMOKE else 256
         ),
@@ -251,11 +248,6 @@ async def _run_e2e() -> dict:
             "spec_active_at_end": engine.spec_active,
             "spec_gate_reprobes": engine.spec_probe_count,
         }
-    micro = (
-        {}
-        if MOCKER  # no device: per-step HBM numbers would be fiction
-        else await asyncio.to_thread(_decode_microbench, engine, cfg)
-    )
     # BENCH_SWEEP=0 skips the concurrency sweep (the heavyweight 8B /
     # long-context scenarios are long enough without it).
     sweep_levels = (
@@ -275,7 +267,6 @@ async def _run_e2e() -> dict:
         "quant": cfg.quant or "none",
         **spec,
         **compile_extras,
-        **micro,
         "sweep": sweep_levels,
     }
 
@@ -331,131 +322,6 @@ def _compile_lifecycle_report(
             "the r05 compile-stall signature"
         )
     return out
-
-
-def _decode_microbench(engine, cfg) -> dict:
-    """Steady-state fused-decode timing on the live runner: per-step ms and
-    effective HBM GB/s (weights + KV read per step / time). The E2E number
-    above includes prefill + scheduling; this isolates the decode hot loop
-    the ITL target cares about (reference bar: planner.md:86 ITL 4.83 ms)."""
-    import jax
-
-    r = engine.runner
-    B = cfg.max_num_seqs
-    ctx_len = ISL + OSL
-    # Tables must cover position + steps - 1 (decode_multi precondition) so
-    # the fused steps write real blocks, not aliased trash-block traffic.
-    blocks_per = (
-        ctx_len + cfg.decode_chunk + cfg.block_size - 1
-    ) // cfg.block_size
-    tables = np.zeros((B, cfg.max_blocks_per_seq), np.int32)
-    assert 1 + B * blocks_per <= cfg.num_blocks, (
-        f"microbench tables need {1 + B * blocks_per} blocks but the arena "
-        f"has {cfg.num_blocks} — raise BENCH_BLOCKS or lower "
-        f"BENCH_SEQS/ISL/OSL (out-of-range pages read garbage, not fail)"
-    )
-    nb = 1
-    for b in range(B):
-        tables[b, :blocks_per] = range(nb, nb + blocks_per)
-        nb += blocks_per
-    ctx = np.full(B, ctx_len, np.int32)
-    toks = np.ones(B, np.int32)
-    zeros_f = np.zeros(B, np.float32)
-    zeros_i = np.zeros(B, np.int32)
-    ones_f = np.ones(B, np.float32)
-    steps = cfg.decode_chunk
-
-    out = r.decode_multi(toks, ctx - 1, tables, ctx, zeros_f, zeros_i, ones_f, steps)
-    _ = np.asarray(out)  # compile + sync
-    t0 = time.monotonic()
-    N = 4
-    for _i in range(N):
-        out = r.decode_multi(
-            toks, ctx - 1, tables, ctx, zeros_f, zeros_i, ones_f, steps
-        )
-    _ = np.asarray(out)  # tokens forced = the ITL-visible sync point
-    per_step = (time.monotonic() - t0) / (N * steps)
-    # KV-write readiness is NOT awaited inside the window — serving never
-    # blocks on it (the next chunk queues behind the writes on device).
-    jax.block_until_ready(r.kv_caches[0][0])
-
-    m = cfg.model
-    dtype_bytes = np.dtype(cfg.dtype).itemsize
-    # Per-leaf dtype sizes: under quant="int8" the matmul weights are 1
-    # byte/param (+ f32 scales), which is exactly the point.
-    weight_bytes = sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(r.params)
-    )
-    kv_read = (
-        2 * m.num_layers * B * ctx_len * m.num_kv_heads
-        * r.cache_head_dim * dtype_bytes
-    )
-    out = {
-        "decode_step_ms": round(per_step * 1000, 2),
-        "decode_tok_per_s": round(B / per_step, 1),
-        "effective_hbm_gbps": round(
-            (weight_bytes + kv_read) / per_step / 1e9, 1
-        ),
-    }
-    gate_shape = B == 32 and cfg.decode_chunk == 16 and ctx_len == 192
-    if not SMOKE and not gate_shape:
-        out.update(_decode_microbench_b32(engine, cfg, weight_bytes))
-    return out
-
-
-def _decode_microbench_b32(engine, cfg, weight_bytes) -> dict:
-    """The gate shape: B=32, decode_chunk=16, ctx 192 —
-    measured on a second runner SHARING the serving runner's params (no
-    extra weight HBM; its own small KV arena)."""
-    import dataclasses
-
-    import jax
-
-    from dynamo_tpu.engine.runner import ModelRunner
-
-    cfg32 = dataclasses.replace(
-        cfg, max_num_seqs=32, num_blocks=512, decode_chunk=16,
-        sampling_extras=False,
-        # Params arrive ALREADY quantized from the serving runner — a
-        # quant mode here would re-quantize the int8 tree.
-        quant=None,
-    )
-    r = ModelRunner(cfg32, params=engine.runner.params)
-    B, steps = 32, 16
-    # The gate shape is FIXED at ctx 192 (ISL 128 + OSL 64) regardless of
-    # the env scenario — long-context ISL would also overrun the small
-    # 512-block arena this runner allocates.
-    ctx_len = 192
-    blocks_per = (ctx_len + steps + cfg32.block_size - 1) // cfg32.block_size
-    tables = np.zeros((B, cfg32.max_blocks_per_seq), np.int32)
-    nb = 1
-    for b in range(B):
-        tables[b, :blocks_per] = range(nb, nb + blocks_per)
-        nb += blocks_per
-    ctx = np.full(B, ctx_len, np.int32)
-    zf, zi, of = (
-        np.zeros(B, np.float32), np.zeros(B, np.int32), np.ones(B, np.float32),
-    )
-    toks = np.ones(B, np.int32)
-    out = r.decode_multi(toks, ctx - 1, tables, ctx, zf, zi, of, steps)
-    _ = np.asarray(out)  # compile + sync
-    t0 = time.monotonic()
-    N = 4
-    for _i in range(N):
-        out = r.decode_multi(toks, ctx - 1, tables, ctx, zf, zi, of, steps)
-    _ = np.asarray(out)  # tokens forced (see _decode_microbench)
-    per_step = (time.monotonic() - t0) / (N * steps)
-    jax.block_until_ready(r.kv_caches[0][0])
-    kv_read = (
-        2 * cfg.model.num_layers * B * ctx_len * cfg.model.num_kv_heads
-        * r.cache_head_dim * np.dtype(cfg.dtype).itemsize
-    )
-    return {
-        "decode_step_ms_b32c16": round(per_step * 1000, 2),
-        "effective_hbm_gbps_b32c16": round(
-            (weight_bytes + kv_read) / per_step / 1e9, 1
-        ),
-    }
 
 
 async def _sweep(engine) -> list[dict]:
@@ -969,8 +835,8 @@ async def _run_spec() -> dict:
     - accepting-draft spec throughput ≥ the RECORDED phased-spec
       baseline — computed from the phased pricing law this suite
       retained when the phased engine was deleted
-      (``decode_multi_spec`` charged the dispatch base ×(1+K) per
-      1-token step);
+      (its fused spec-decode program charged the dispatch base ×(1+K)
+      per 1-token step);
     - the losing leg's spec steps stay within
       window + probes × probe_window (the phased gate's bound,
       preserved).
@@ -1000,7 +866,6 @@ async def _run_spec() -> dict:
             max_model_len=512,
             dtype="float32",
             speculative_k=k,
-            unified=True,
             unified_token_budget=64,
             sampling_extras=False,
             **kw,
@@ -1082,7 +947,7 @@ async def _run_spec() -> dict:
         window + probes * probe_window + (probes + 1) * (n_req - 1)
     )
 
-    # The recorded phased-spec baseline: the deleted decode_multi_spec
+    # The recorded phased-spec baseline: the deleted phased spec-decode
     # sim charged decode_time_per_step_us × (1+K) per fused step and
     # delivered 1 token per lane per step — its throughput at these
     # constants is the closed form below (the law is retained here so
@@ -1213,7 +1078,6 @@ async def _run_coloc() -> dict:
         if colocated:
             cfg = dataclasses.replace(
                 base_cfg,
-                unified=True,
                 unified_token_budget=1024,
                 unified_prefill_quantum=64,
                 coloc="adaptive",
@@ -1226,7 +1090,6 @@ async def _run_coloc() -> dict:
             # adaptation.
             cfg = dataclasses.replace(
                 base_cfg,
-                unified=True,
                 unified_token_budget=1024,
                 unified_prefill_quantum=64,
                 coloc="static",
@@ -1399,7 +1262,6 @@ async def _run_quant() -> dict:
         prefill_batch=4,
         dtype="float32",
         sampling_extras=False,
-        unified=True,
         unified_token_budget=1024,
         unified_prefill_quantum=256,
         coloc="static",
@@ -1609,7 +1471,6 @@ async def _run_wquant() -> dict:
         prefill_batch=4,
         dtype="float32",
         sampling_extras=False,
-        unified=True,
         unified_token_budget=1024,
         unified_prefill_quantum=256,
         coloc="static",
@@ -2075,12 +1936,6 @@ def main() -> None:
 
         print(json.dumps(kvsp_main()))
         return
-    if os.environ.get("BENCH_8B"):
-        # 8B device-efficiency probe (benchmarks/eff8b_bench.py)
-        from benchmarks.eff8b_bench import main as eff_main
-
-        print(json.dumps(eff_main()))
-        return
     if os.environ.get("BENCH_ROUTER"):
         # KV-aware vs random routing A/B (benchmarks/router_bench.py;
         # reference bar: 3x TTFT, architecture.md:86-91)
@@ -2126,7 +1981,6 @@ def main() -> None:
             k: {
                 "tok_per_s": v["value"],
                 "p50_ttft_ms": v["extras"]["p50_ttft_ms"],
-                "decode_step_ms": v["extras"].get("decode_step_ms"),
             }
             for k, v in ab.items()
         }

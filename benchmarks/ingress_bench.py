@@ -202,7 +202,6 @@ async def run_ingress(
         max_num_seqs=max_num_seqs,
         max_model_len=512,
         dtype="float32",
-        decode_chunk=4,
         # Overload is shed at the ADMISSION gate (class-weighted, the
         # contract under test); engine-side bounds stay off so every
         # 429 is attributable to the gate.

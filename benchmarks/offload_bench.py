@@ -54,7 +54,6 @@ def _cfg() -> EngineConfig:
         block_size=16,
         max_num_seqs=4,
         max_model_len=1 << (PREFIX + TURN1_OSL + DELTA + TURN2_OSL).bit_length(),
-        decode_chunk=8,
         prefill_batch=4,
         enable_prefix_caching=True,
         quant=os.environ.get("DYNAMO_TPU_QUANT") or None,

@@ -58,7 +58,6 @@ def _cfg() -> EngineConfig:
         block_size=16,
         max_num_seqs=8,
         max_model_len=1 << (PREFIX + TURN1_OSL + DELTA + TURN2_OSL).bit_length(),
-        decode_chunk=8,
         prefill_batch=4,
         enable_prefix_caching=True,
         quant=os.environ.get("DYNAMO_TPU_QUANT") or None,
@@ -83,14 +82,9 @@ async def _spawn_worker(drt, component, cfg, params):
     await engine.start()
     await component.endpoint("generate").serve(engine)
     await wm.create_endpoint(component)
-    # Buckets: the post-hit suffix, the turn-1 prompt, and the FULL turn-2
-    # length (the cold-routed case) — an unwarmed bucket compiling inside
-    # the measured phase would masquerade as a routing effect.
-    await engine.warmup(
-        prompt_buckets=[
-            DELTA + TURN1_OSL, PREFIX, PREFIX + TURN1_OSL + DELTA,
-        ]
-    )
+    # An unwarmed shape compiling inside the measured phase would
+    # masquerade as a routing effect.
+    await engine.warmup()
     return engine
 
 

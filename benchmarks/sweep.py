@@ -119,7 +119,6 @@ def _mock_engine(max_len: int = 512):
             num_blocks=512,
             max_num_seqs=16,
             max_model_len=max_len,
-            decode_chunk=4,
         ),
         MockerConfig(),
     )

@@ -685,7 +685,7 @@ def tp_vs_one_chip(
         ecfg = EngineConfig(
             model=PRESETS[preset](), num_blocks=num_blocks,
             max_num_seqs=max_num_seqs, max_model_len=max_model_len,
-            unified=True, unified_token_budget=token_budget,
+            unified_token_budget=token_budget,
             mesh_shape=mesh, compile_cache_dir=None,
         )
         return ModelRunner(ecfg, rng_seed=ecfg.seed)

@@ -250,3 +250,8 @@ class OffloadManager:
     async def drain(self) -> None:
         while self._tasks:
             await asyncio.gather(*list(self._tasks), return_exceptions=True)
+            # gather over tasks that are ALL done already completes
+            # without yielding (Python 3.12), while the done callback
+            # that discards them from _tasks still waits for the loop:
+            # give it its turn, or this loop spins forever.
+            await asyncio.sleep(0)

@@ -112,7 +112,7 @@ def build_parser() -> argparse.ArgumentParser:
                           "policy's G1 knob (docs/architecture/"
                           "kv_quant.md): int8 KV blocks with per-block "
                           "scales, dequantized in-kernel on the ragged "
-                          "path (requires --unified); roughly halves "
+                          "path; roughly halves "
                           "decode's KV HBM reads and doubles KV capacity "
                           "per chip. G2 host / G3 disk KVBM tiers "
                           "quantize independently via their layout "
@@ -126,7 +126,7 @@ def build_parser() -> argparse.ArgumentParser:
                           "attn, mlp, unembed). Quantize-on-load — the "
                           "bf16 copy never materializes resident; scales "
                           "ride as jit state beside the matrices. Zero "
-                          "new XLA programs (requires --unified; composes "
+                          "new XLA programs (composes "
                           "with --kv-quant; supersedes --quant)")
     run.add_argument("--speculative-k", type=int, default=0,
                      help="prompt-lookup speculative decoding: draft up to "
@@ -136,16 +136,7 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--max-model-len", type=int, default=2048)
     run.add_argument("--num-blocks", type=int, default=2048)
     run.add_argument("--kv-cache-block-size", type=int, default=16)
-    # --decode-chunk (the phased fused-decode ladder knob) is GONE with
-    # the phase-alternating engine: argparse rejects it loudly
-    # ("unrecognized arguments"), which is the deprecation contract —
-    # a deploy still passing it must be updated, not silently ignored.
     run.add_argument("--prefill-batch", type=int, default=4)
-    run.add_argument("--unified", action="store_true",
-                     help="DEPRECATED no-op: unified single-dispatch "
-                     "serving is the ONLY engine path now (the "
-                     "phase-alternating engine was deleted; docs/"
-                     "architecture/unified_step.md)")
     run.add_argument("--unified-token-budget", type=int, default=256,
                      help="max tokens per unified dispatch (snapped to a "
                      "power-of-two ladder)")
@@ -844,12 +835,6 @@ def _tpu_local_and_cfg(args):
 
     from dynamo_tpu.engine.compile_cache import resolve_cache_base
 
-    if getattr(args, "unified", False):
-        logger.warning(
-            "--unified is deprecated and a no-op: the unified step is "
-            "the only engine path (the phase-alternating engine was "
-            "deleted)"
-        )
     local = LocalModel.prepare(
         args.model_path,
         name=args.model_name,
@@ -866,7 +851,6 @@ def _tpu_local_and_cfg(args):
         max_num_seqs=args.max_num_seqs,
         max_model_len=max_len,
         prefill_batch=args.prefill_batch,
-        unified=True,
         unified_token_budget=args.unified_token_budget,
         unified_prefill_quantum=args.unified_prefill_quantum,
         itl_slo_ms=args.itl_slo_ms,

@@ -33,7 +33,7 @@ import logging
 import os
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Iterable
+from typing import Any, Callable
 
 from dynamo_tpu.utils.atomic_io import atomic_write_text
 from dynamo_tpu.utils.concurrency import make_lock
@@ -131,13 +131,11 @@ def engine_fingerprint(cfg) -> dict:
         "num_blocks": cfg.num_blocks,
         "max_num_seqs": cfg.max_num_seqs,
         "max_model_len": cfg.max_model_len,
-        "prefill_chunk": cfg.prefill_chunk,
         "mesh_shape": dict(sorted((cfg.mesh_shape or {}).items())),
         "kv_sp": cfg.kv_sp,
         "speculative_k": cfg.speculative_k,
         "sampling_extras": cfg.sampling_extras,
         "multimodal": cfg.multimodal,
-        "unified": getattr(cfg, "unified", False),
         "unified_token_budget": getattr(cfg, "unified_token_budget", 0),
         "pallas": os.environ.get("DYNAMO_TPU_PALLAS", ""),
     }
@@ -307,8 +305,7 @@ class ShapeManifest:
 
     Warmup loads the previous run's manifest and warms exactly that set
     first — the measured workload's shapes, in usage order — instead of
-    the |prompt_buckets| x |lane_buckets| default grid (the r05
-    explosion). Entries are keyed by `shape_key`."""
+    the whole default grid. Entries are keyed by `shape_key`."""
 
     def __init__(self) -> None:
         self._lock = make_lock("compile.manifest")
@@ -503,23 +500,14 @@ class CompileStats:
 _DECODE_KINDS = ("unified", "unified_full", "unified_mm")
 
 
-def default_shape_grid(
-    cfg,
-    lane_buckets: Iterable[int] = (),
-    prompt_buckets: list[int] | None = None,
-    decode_chunks: list[int] | None = None,
-) -> list[ShapeSpec]:
+def default_shape_grid(cfg) -> list[ShapeSpec]:
     """The config-derived serving shape set — the unified budget ladder
     (one ragged program per budget rung; ROADMAP item #2, completed)
     plus ONE top-rung program per configured variant: "unified_full"
     (sampling extras — penalties/logprobs) and "unified_mm" (multimodal
     soft prompts). Extras/mm batches snap to the top rung at runtime, so
     each variant costs one warm program instead of a second ladder, and
-    the whole grid stays ≤ 8 programs at the default budget.
-
-    The phase×bucket×lane grid (and its lane ladder) is GONE — this IS
-    the delete-the-grid contract. ``lane_buckets``/``prompt_buckets``/
-    ``decode_chunks`` are accepted for API compatibility and ignored."""
+    the whole grid stays ≤ 8 programs at the default budget."""
     top = _bucket(cfg.unified_token_budget)
     specs: list[ShapeSpec] = [
         ("unified", b, 0, 0, 0)
@@ -565,7 +553,7 @@ def split_plan(
     for key, e in recorded:
         take(key, (e["kind"], e["t"], e["lanes"], e["steps"], e["draft_k"]))
     # Decode shapes stay hot even when the manifest missed them (a fresh
-    # traffic mix reaches any power-of-two chunk ≤ decode_chunk).
+    # traffic mix reaches any budget rung).
     for key, s in sorted(remaining.items()):
         if s[0] in _DECODE_KINDS:
             take(key)
@@ -581,17 +569,12 @@ class WarmupPlanMixin:
     shape."""
 
     def warmup_plan(
-        self,
-        prompt_buckets: list[int] | None = None,
-        decode_chunks: list[int] | None = None,
-        manifest: ShapeManifest | None = None,
+        self, manifest: ShapeManifest | None = None
     ) -> tuple[
         list[tuple[str, Callable[[], Any]]],
         list[tuple[str, Callable[[], Any]]],
     ]:
-        specs = default_shape_grid(
-            self.cfg, (), prompt_buckets, decode_chunks
-        )
+        specs = default_shape_grid(self.cfg)
         hot_specs, tail_specs = split_plan(specs, manifest)
 
         def ops(ss: list[ShapeSpec]) -> list[tuple[str, Callable[[], Any]]]:

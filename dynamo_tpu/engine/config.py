@@ -15,7 +15,6 @@ class EngineConfig:
     num_blocks: int = 512            # device KV blocks (block 0 is trash)
     max_num_seqs: int = 8            # decode batch slots
     max_model_len: int = 512         # context limit per sequence
-    prefill_chunk: int = 512         # max (padded) tokens per prefill call
     prefill_batch: int = 4           # prompts fused into one prefill call
     watermark: float = 0.05          # keep this fraction of blocks free
     enable_prefix_caching: bool = True
@@ -25,12 +24,6 @@ class EngineConfig:
     multimodal: bool = False
     seed: int = 0
     remote_kv_timeout_s: float = 30.0  # disagg: max wait for inbound KV
-    # Steps per fused call of the RAW decode_multi program (lax.scan on
-    # device) — microbench/parity/bring-up tooling only: the serving
-    # engine dispatches exclusively through unified_step (one token per
-    # lane per dispatch) and never reads this. The `--decode-chunk` CLI
-    # flag is gone with the phase-alternating engine.
-    decode_chunk: int = 8
     # Decode chunks allowed in flight before forcing results. Depth 2 hides
     # dispatch/fetch latency behind device compute: chunk N+1 feeds on
     # chunk N's device-resident tokens, so issuing never waits on a fetch.
@@ -64,10 +57,10 @@ class EngineConfig:
     # blocks as int8 with per-(block, kv-head) float32 scales riding the
     # block-table metadata — roughly half the decode HBM read bytes and
     # double the KV capacity per chip. Dequant happens in-kernel on the
-    # ragged path (the XLA oracle twin does identical arithmetic), so
-    # this requires unified=True; the G2/G3 KVBM tiers are always
-    # quantized when a block manager runs with a quantized layout,
-    # independent of this G1 knob (the per-tier precision policy).
+    # ragged path (the XLA oracle twin does identical arithmetic); the
+    # G2/G3 KVBM tiers are always quantized when a block manager runs
+    # with a quantized layout, independent of this G1 knob (the
+    # per-tier precision policy).
     kv_quant: str | None = None
     # Per-matmul weight-quantization policy (docs/architecture/
     # weight_quant.md; models/llama.py WeightQuantPolicy): None = serve
@@ -126,17 +119,12 @@ class EngineConfig:
     # skips compiling it and 400-rejects such requests.
     sampling_extras: bool = True
 
-    # Unified single-dispatch serving (ROADMAP item #2, COMPLETED;
-    # docs/architecture/unified_step.md): every engine step is ONE
-    # ragged token batch mixing decode lanes (draft-verify spans under
-    # speculative_k) with chunked-prefill quanta, run through the
-    # ragged unified attention kernel (ops/pallas/ragged_attention.py)
-    # — the only compiled extent is the total token budget, so warmup
-    # is the budget ladder (≤ 8 programs). This is the ONLY engine
-    # path: the phase-alternating engine is gone, and the flag survives
-    # solely so old configs/pickles deserialize (validate() rejects
-    # False loudly).
-    unified: bool = True
+    # The unified step (docs/architecture/unified_step.md): every engine
+    # step is ONE ragged token batch mixing decode lanes (draft-verify
+    # spans under speculative_k) with chunked-prefill quanta, run through
+    # the ragged attention kernel (ops/pallas/ragged_attention.py) — the
+    # only compiled extent is the total token budget, so warmup is the
+    # budget ladder (≤ 8 programs).
     # Max tokens per unified dispatch. Runtime batches snap UP through
     # compile_cache.token_budget() onto the power-of-two ladder
     # {16, 32, ..., bucket(unified_token_budget)} — the entire warmed
@@ -232,15 +220,6 @@ class EngineConfig:
             raise ValueError(
                 f"kv_quant={self.kv_quant!r} not in {self._QUANT_MODES}"
             )
-        if self.kv_quant and not self.unified:
-            raise ValueError(
-                "conflicting flags --kv-quant + unified=False: "
-                "kv_quant requires the unified engine path — "
-                "dequant-in-kernel is built on the ragged unified "
-                "attention path (ops/pallas/ragged_attention.py); the "
-                "phase-alternating programs read the cache in its "
-                "compute dtype. Drop --kv-quant or re-enable unified."
-            )
         if self.kv_quant and self.kv_sp:
             raise ValueError(
                 "conflicting flags --kv-quant + --kv-sp: kv_quant does "
@@ -261,15 +240,6 @@ class EngineConfig:
                     "weight_quant policy both own the weight tree — "
                     "use --weight-quant alone (--weight-quant int8 is "
                     "the superset of --quant int8)"
-                )
-            if not self.unified:
-                raise ValueError(
-                    "conflicting flags --weight-quant + unified=False: "
-                    "weight_quant is built on the unified engine path — "
-                    "the zero-new-programs contract (dequant-in-register "
-                    "inside the budget-ladder programs) is defined "
-                    "against the ragged unified step. Drop --weight-quant "
-                    "or re-enable unified."
                 )
         if self.speculative_k < 0 or self.speculative_k > self.block_size:
             raise ValueError(
@@ -295,13 +265,6 @@ class EngineConfig:
                 f"itl_slo_ms={self.itl_slo_ms} must be >= 0 (0 = no SLO)"
             )
         if self.coloc == "adaptive":
-            if not self.unified:
-                raise ValueError(
-                    "coloc='adaptive' requires unified=True — the "
-                    "controller adapts the unified step's prefill "
-                    "quantum (the phase-alternating path has no mixed "
-                    "batch to control)"
-                )
             if self.itl_slo_ms <= 0:
                 raise ValueError(
                     "coloc='adaptive' requires itl_slo_ms > 0 — the "
@@ -316,13 +279,6 @@ class EngineConfig:
             raise ValueError(
                 "max_waiting and max_queue_delay_s must be >= 0 "
                 "(0 = unbounded)"
-            )
-        if not self.unified:
-            raise ValueError(
-                "unified=False is gone: the phase-alternating engine was "
-                "deleted — the ragged unified step (which now carries "
-                "speculative decode, sampling extras, and multimodal) is "
-                "the only path"
             )
         if self.unified_token_budget < 16:
             raise ValueError(

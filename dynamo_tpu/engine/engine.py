@@ -353,11 +353,7 @@ class TpuEngine:
             path, fingerprint_key(engine_fingerprint(self.cfg))
         )
 
-    async def warmup(
-        self,
-        prompt_buckets: list[int] | None = None,
-        decode_chunks: list[int] | None = None,
-    ) -> int:
+    async def warmup(self) -> int:
         """Compile the serving shape set before taking traffic (runs on the
         engine thread; see ModelRunner.warmup). Serving without this pays
         tens of seconds of XLA compile on the first request of each new
@@ -365,7 +361,7 @@ class TpuEngine:
         if self._dead:
             raise RuntimeError(f"engine dead: {self._dead}")
         fut: asyncio.Future = self._loop.create_future()
-        self._submit_q.put(("warmup", (prompt_buckets, decode_chunks, fut)))
+        self._submit_q.put(("warmup", (fut,)))
         self._wakeup.set()
         return await fut
 
@@ -601,7 +597,7 @@ class TpuEngine:
             elif op == "warmup":
                 self._run_warmup(*arg)
 
-    def _run_warmup(self, prompt_buckets, decode_chunks, fut) -> None:
+    def _run_warmup(self, fut) -> None:
         """Warm the HOT shape set synchronously (the future resolves when
         it is compiled and the engine is ready for traffic); the tail —
         grid shapes a loaded manifest says serving didn't execute — warms
@@ -617,9 +613,7 @@ class TpuEngine:
 
         try:
             manifest = self._load_manifest()
-            hot, tail = self.runner.warmup_plan(
-                prompt_buckets, decode_chunks, manifest
-            )
+            hot, tail = self.runner.warmup_plan(manifest)
             if manifest is not None:
                 logger.info(
                     "shape-manifest warmup: %d hot programs (observed "

@@ -24,11 +24,9 @@ handful of programs. Three program variants share the trunk:
   rows scattered into the flat batch (carries the extras operands too,
   so mm and extras lanes co-batch).
 
-The phase-alternating engine path is GONE. `prefill` / `prefill_batch`
-/ `decode` / `decode_multi` remain as RAW program entry points only —
-TP/parity tests, the decode microbench, stepcast leader-follower drills
-and the multihost bring-up utility drive them directly; no engine step
-dispatches them and warmup no longer compiles them.
+`unified_step` is the runner's only step entry: the engine, the check,
+the stepcast leader/follower replay and the multihost bring-up utility
+all dispatch through it, and the runner builds no other step program.
 """
 
 from __future__ import annotations
@@ -45,7 +43,6 @@ from dynamo_tpu.engine.compile_cache import (
     CompileStats,
     PersistentCompileCache,
     WarmupPlanMixin,
-    _bucket,
     engine_fingerprint,
     token_budget,
 )
@@ -420,89 +417,6 @@ class ModelRunner(WarmupPlanMixin):
         bs = cfg.block_size
         attn = self.attn
 
-        def prefill_fn(
-            params, kv, token_ids, block_table, slot_mapping, prefix_len,
-            total_len, temp, top_k, top_p, seed, key,
-        ):
-            logits, kv = llama.prefill(
-                m, params, kv, token_ids, block_table, slot_mapping,
-                prefix_len, total_len, bs, attn=attn,
-            )
-            lg = logits[None, :]
-            tok = sample_tokens(
-                lg, key, temp, top_k, top_p,
-                seed=seed, sample_pos=jnp.reshape(total_len, (1,)),
-            )
-            lp = token_logprobs(lg, tok)
-            return tok[0], lp, kv
-
-        def prefill_mm_fn(
-            params, kv, token_ids, block_table, slot_mapping, prefix_len,
-            total_len, temp, top_k, top_p, seed, key, embeds, embed_mask,
-        ):
-            logits, kv = llama.prefill(
-                m, params, kv, token_ids, block_table, slot_mapping,
-                prefix_len, total_len, bs, attn=attn,
-                embeds=embeds, embed_mask=embed_mask,
-            )
-            lg = logits[None, :]
-            tok = sample_tokens(
-                lg, key, temp, top_k, top_p,
-                seed=seed, sample_pos=jnp.reshape(total_len, (1,)),
-            )
-            lp = token_logprobs(lg, tok)
-            return tok[0], lp, kv
-
-        def decode_fn(
-            params, kv, token_ids, positions, block_tables, context_lens,
-            slot_mapping, temp, top_k, top_p, seed, key,
-        ):
-            logits, kv = llama.decode(
-                m, params, kv, token_ids, positions, block_tables,
-                context_lens, slot_mapping, bs, attn=attn,
-            )
-            toks = sample_tokens(
-                logits, key, temp, top_k, top_p,
-                seed=seed, sample_pos=context_lens,
-            )
-            return toks, kv
-
-        def decode_multi_fn(
-            params, kv, token_ids, positions, block_tables, context_lens,
-            temp, top_k, top_p, seed, key, num_steps: int,
-        ):
-            """`num_steps` decode steps fused on device (slot mapping and
-            sampling computed in-loop); returns tokens [num_steps, B]."""
-            B = token_ids.shape[0]
-            rows = jnp.arange(B)
-
-            def step(carry, i):
-                kv, tok, pos, ctx = carry
-                active = ctx > 0
-                slot = (
-                    block_tables[rows, jnp.maximum(pos, 0) // bs] * bs
-                    + jnp.maximum(pos, 0) % bs
-                )
-                slot = jnp.where(active, slot, 0)  # trash block for idle rows
-                logits, kv = llama.decode(
-                    m, params, kv, tok, pos, block_tables, ctx, slot, bs,
-                    attn=attn,
-                )
-                nxt = sample_tokens(
-                    logits, jax.random.fold_in(key, i), temp, top_k, top_p,
-                    seed=seed, sample_pos=ctx,
-                )
-                nxt = jnp.where(active, nxt, 0)
-                inc = active.astype(pos.dtype)
-                return (kv, nxt, pos + inc, ctx + inc), nxt
-
-            (kv, _, _, _), toks = jax.lax.scan(
-                step,
-                (kv, token_ids, positions, context_lens),
-                jnp.arange(num_steps),
-            )
-            return toks, kv
-
         K_spec = cfg.speculative_k
 
         def _feed_tokens(token_ids, row_start, use_prev, prev_row, prev_toks):
@@ -665,21 +579,6 @@ class ModelRunner(WarmupPlanMixin):
         unified_full_fn = make_unified_extras_fn(with_mm=False)
         unified_mm_fn = make_unified_extras_fn(with_mm=True)
 
-        def prefill_batch_fn(
-            params, kv, token_ids, block_tables, slot_mapping, prefix_len,
-            total_len, temp, top_k, top_p, seed, key,
-        ):
-            logits, kv = llama.prefill_batch(
-                m, params, kv, token_ids, block_tables, slot_mapping,
-                prefix_len, total_len, bs, attn=attn,
-            )
-            toks = sample_tokens(
-                logits, key, temp, top_k, top_p,
-                seed=seed, sample_pos=total_len,
-            )
-            lp = token_logprobs(logits, toks)
-            return toks, lp, kv
-
         if mesh is None:
             tok_sh = kv_sh = sc_sh = None
         else:
@@ -719,21 +618,6 @@ class ModelRunner(WarmupPlanMixin):
             lambda: jnp.zeros(self.unified_slots, jnp.int32),
             tok_sh,
         )()
-        lp_sh = (tok_sh, tok_sh, tok_sh)
-        self._prefill = _jit(
-            prefill_fn, (tok_sh, lp_sh, kv_sh), donate_argnums=(1,)
-        )
-        self._prefill_mm = _jit(
-            prefill_mm_fn, (tok_sh, lp_sh, kv_sh), donate_argnums=(1,)
-        )
-        self._prefill_batch = _jit(
-            prefill_batch_fn, (tok_sh, lp_sh, kv_sh), donate_argnums=(1,)
-        )
-        self._decode = _jit(decode_fn, (tok_sh, kv_sh), donate_argnums=(1,))
-        self._decode_multi = _jit(
-            decode_multi_fn, (tok_sh, kv_sh), donate_argnums=(1,),
-            static_argnums=(11,),
-        )
         if K_spec > 0:
             self._unified = _jit(
                 unified_spec_fn,
@@ -757,9 +641,6 @@ class ModelRunner(WarmupPlanMixin):
         # counts) — engine state for the unified_full/mm variants; created
         # lazily so plain serving never allocates it.
         self._counts = None
-        # Logprob arrays from the most recent prefill call (device-resident;
-        # converted by the caller only when a request asked for logprobs).
-        self.last_logprobs = None
         # Logprob arrays (chosen_lp [S], top_ids [S, K], top_lps [S, K])
         # from the most recent unified_full/mm dispatch — device-resident,
         # forced by the engine at chunk retirement only when some lane
@@ -767,12 +648,7 @@ class ModelRunner(WarmupPlanMixin):
         self.last_unified_logprobs = None
 
     # -- warmup -------------------------------------------------------------
-    def warmup(
-        self,
-        prompt_buckets: list[int] | None = None,
-        decode_chunks: list[int] | None = None,
-        manifest=None,
-    ) -> int:
+    def warmup(self, manifest=None) -> int:
         """Compile the serving shape set off the clock: the unified
         budget ladder (plus the single extras/mm top-rung programs when
         configured) — ordered by `warmup_plan` (engine/compile_cache.py):
@@ -780,10 +656,8 @@ class ModelRunner(WarmupPlanMixin):
         first. All writes land in trash block 0, so the real
         cache/allocator state is untouched. Returns the number of XLA
         programs touched. First compiles dominate TTFT otherwise
-        (seconds per shape).
-        ``prompt_buckets``/``decode_chunks`` are accepted for API
-        compatibility and ignored — the unified grid has neither axis."""
-        hot, tail = self.warmup_plan(prompt_buckets, decode_chunks, manifest)
+        (seconds per shape)."""
+        hot, tail = self.warmup_plan(manifest)
         return self.run_warm_ops(hot + tail)
 
     def run_warm_ops(self, ops) -> int:
@@ -1048,124 +922,6 @@ class ModelRunner(WarmupPlanMixin):
         return self.prepare_blocks_host(deq), None
 
     # -- steps --------------------------------------------------------------
-    def prefill(
-        self,
-        new_tokens: list[int],
-        block_ids: list[int],
-        prefix_len: int,
-        sampling: tuple[float, int, float],
-        mm_embeds: list[tuple[int, np.ndarray]] | None = None,
-    ) -> int:
-        """Run one sequence's prefill (suffix after any prefix-cache hit);
-        returns the first sampled token. `mm_embeds` carries multimodal
-        soft-prompt segments as (offset_in_new_tokens, [n, hidden] array)
-        pairs whose rows replace the placeholder tokens' embeddings."""
-        T = _bucket(len(new_tokens))
-        if T > _bucket(max(1, self.cfg.prefill_chunk)):
-            # One oversized call would compile a one-off power-of-two
-            # bucket OUTSIDE the warmed shape set (a mid-traffic compile
-            # stall) — refuse instead of silently blowing the
-            # compile budget. (Raw-program entry: the serving engine
-            # chunks prompts through unified_step spans instead.)
-            raise ValueError(
-                f"prefill chunk of {len(new_tokens)} tokens exceeds "
-                f"prefill_chunk={self.cfg.prefill_chunk}; feed the prompt "
-                f"in chunks of at most prefill_chunk tokens"
-            )
-        token_ids = np.zeros(T, np.int32)
-        token_ids[: len(new_tokens)] = new_tokens
-        slot_mapping = np.zeros(T, np.int32)  # padding → trash block 0
-        for i in range(len(new_tokens)):
-            slot_mapping[i] = self.slot_of(block_ids, prefix_len + i)
-        temp, top_k, top_p, seed = _norm_sampling(sampling)
-
-        args = (
-            self.params,
-            self.kv_caches,
-            jnp.asarray(token_ids),
-            jnp.asarray(self._pad_table(block_ids)),
-            jnp.asarray(slot_mapping),
-            jnp.int32(prefix_len),
-            jnp.int32(prefix_len + len(new_tokens)),
-            jnp.asarray([temp], jnp.float32),
-            jnp.asarray([top_k], jnp.int32),
-            jnp.asarray([top_p], jnp.float32),
-            jnp.asarray([seed], jnp.int32),
-            self._next_key(),
-        )
-        if mm_embeds:
-            D = self.cfg.model.hidden_size
-            embeds = np.zeros((T, D), np.float32)
-            mask = np.zeros(T, bool)
-            for off, seg in mm_embeds:
-                # dynalint: allow[DT005] mm embeddings arrive as host arrays from the preprocessor; this is a dtype view, not a device fetch
-                seg = np.asarray(seg, np.float32)
-                n = min(len(seg), max(0, len(new_tokens) - off))
-                if n <= 0 or off < 0:
-                    continue
-                embeds[off : off + n] = seg[:n]
-                mask[off : off + n] = True
-            with self.compile_stats.observe("prefill_mm", t=T):
-                tok, lp, self.kv_caches = self._prefill_mm(
-                    *args, jnp.asarray(embeds), jnp.asarray(mask)
-                )
-        else:
-            with self.compile_stats.observe("prefill", t=T):
-                tok, lp, self.kv_caches = self._prefill(*args)
-        self.last_logprobs = lp
-        return int(tok)
-
-    def prefill_batch(
-        self, lanes: list[tuple[list[int], list[int], int, tuple]]
-    ) -> list[int]:
-        """Fused prefill of N lanes: [(new_tokens, block_ids, prefix_len,
-        (temp, top_k, top_p)), ...]. Returns one sampled token per lane.
-        Lane count snaps UP to a power-of-two bucket and T to ONE shared
-        bucket — so a single long lane drags every short lane's padding
-        up. That waste is inherent to the lane×bucket shape family,
-        which is why the engine serves through unified_step (packs by
-        tokens; no lane axis) — this entry remains for raw-program
-        parity tests and bring-up tools only."""
-        n_real = len(lanes)
-        N = _bucket(max(n_real, 1), minimum=2)
-        T = _bucket(max(len(t) for t, _, _, _ in lanes))
-        token_ids = np.zeros((N, T), np.int32)
-        block_tables = np.zeros((N, self.cfg.max_blocks_per_seq), np.int32)
-        slot_mapping = np.zeros((N, T), np.int32)  # padding → trash block 0
-        prefix_len = np.zeros(N, np.int32)
-        total_len = np.zeros(N, np.int32)
-        temp = np.zeros(N, np.float32)
-        top_k = np.zeros(N, np.int32)
-        top_p = np.ones(N, np.float32)
-        seed = np.full(N, -1, np.int32)
-        for i, (new_tokens, block_ids, prefix, sampling) in enumerate(lanes):
-            token_ids[i, : len(new_tokens)] = new_tokens
-            block_tables[i, : len(block_ids)] = block_ids
-            for j in range(len(new_tokens)):
-                slot_mapping[i, j] = self.slot_of(block_ids, prefix + j)
-            prefix_len[i] = prefix
-            total_len[i] = prefix + len(new_tokens)
-            temp[i], top_k[i], top_p[i], seed[i] = _norm_sampling(sampling)
-
-        with self.compile_stats.observe("prefill_batch", t=T, lanes=N):
-            toks, lp, self.kv_caches = self._prefill_batch(
-                self.params,
-                self.kv_caches,
-                jnp.asarray(token_ids),
-                jnp.asarray(block_tables),
-                jnp.asarray(slot_mapping),
-                jnp.asarray(prefix_len),
-                jnp.asarray(total_len),
-                jnp.asarray(temp),
-                jnp.asarray(top_k),
-                jnp.asarray(top_p),
-                jnp.asarray(seed),
-                self._next_key(),
-            )
-        self.last_logprobs = lp
-        # dynalint: allow[DT005] prefill's sampled tokens force once per prompt at the prefill boundary, not per decode step
-        return [int(t) for t in np.asarray(toks[:n_real])]
-
     @property
     def unified_slots(self) -> int:
         """Metadata rows per unified dispatch: every decode slot plus
@@ -1433,73 +1189,4 @@ class ModelRunner(WarmupPlanMixin):
         sees a recompile that keeps (kind, budget) and changes an input
         sharding or dtype, which CompileStats cannot."""
         return self._unified._cache_size()
-
-    def decode(
-        self,
-        token_ids: np.ndarray,      # [B] int32
-        positions: np.ndarray,      # [B] int32
-        block_tables: np.ndarray,   # [B, max_blocks] int32
-        context_lens: np.ndarray,   # [B] int32 (0 = inactive)
-        slot_mapping: np.ndarray,   # [B] int32
-        temp: np.ndarray,
-        top_k: np.ndarray,
-        top_p: np.ndarray,
-        seed: np.ndarray | None = None,
-    ) -> np.ndarray:
-        B = len(positions)
-        with self.compile_stats.observe("decode"):
-            toks, self.kv_caches = self._decode(
-                self.params,
-                self.kv_caches,
-                jnp.asarray(token_ids),
-                jnp.asarray(positions),
-                jnp.asarray(block_tables),
-                jnp.asarray(context_lens),
-                jnp.asarray(slot_mapping),
-                jnp.asarray(temp),
-                jnp.asarray(top_k),
-                jnp.asarray(top_p),
-                jnp.asarray(
-                    seed if seed is not None else np.full(B, -1, np.int32)
-                ),
-                self._next_key(),
-            )
-        # dynalint: allow[DT005] this runner entry is the engine's synchronous delivery contract: one force returns the fused batch's tokens (the pipelined paths keep device arrays instead)
-        return np.asarray(toks)
-
-    def decode_multi(
-        self,
-        token_ids: np.ndarray,      # [B]
-        positions: np.ndarray,      # [B]
-        block_tables: np.ndarray,   # [B, max_blocks]
-        context_lens: np.ndarray,   # [B] (0 = inactive)
-        temp: np.ndarray,
-        top_k: np.ndarray,
-        top_p: np.ndarray,
-        num_steps: int,
-        seed: np.ndarray | None = None,
-    ) -> np.ndarray:
-        """`num_steps` fused decode steps; returns sampled tokens
-        [num_steps, B]. Slot mapping is derived on device, so callers must
-        have pre-grown block tables to cover position + num_steps - 1."""
-        B = len(positions)
-        with self.compile_stats.observe("decode_multi", steps=num_steps):
-            toks, self.kv_caches = self._decode_multi(
-                self.params,
-                self.kv_caches,
-                jnp.asarray(token_ids),
-                jnp.asarray(positions),
-                jnp.asarray(block_tables),
-                jnp.asarray(context_lens),
-                jnp.asarray(temp),
-                jnp.asarray(top_k),
-                jnp.asarray(top_p),
-                jnp.asarray(
-                    seed if seed is not None else np.full(B, -1, np.int32)
-                ),
-                self._next_key(),
-                num_steps,
-            )
-        # dynalint: allow[DT005] this runner entry is the engine's synchronous delivery contract: one force returns the fused batch's tokens (the pipelined paths keep device arrays instead)
-        return np.asarray(toks)
 

@@ -20,7 +20,6 @@ import numpy as np
 from dynamo_tpu.engine.compile_cache import (
     CompileStats,
     WarmupPlanMixin,
-    _bucket,
     token_budget,
 )
 from dynamo_tpu.engine.config import EngineConfig
@@ -42,7 +41,8 @@ class MockerConfig:
     pass every step streams regardless of content),
     ``decode_time_per_lane_us`` prices each decode lane's KV read, and
     prefill tokens pay the linear(+quadratic) compute term. Standalone
-    phase-path prefill calls additionally pay
+    prefill calls (the fleet simulator's prefill pool,
+    planner/simulate.py; `_SimRunner` has none) additionally pay
     ``prefill_dispatch_base_us`` — their OWN weight pass, which is
     exactly what co-located prefill quanta don't pay (they ride the
     mixed dispatch's): the measurable mechanism behind the Nexus /
@@ -220,16 +220,12 @@ class _SimRunner(WarmupPlanMixin):
     def scatter_many_device(self, block_idxs, data) -> None:
         self.scatter_many(block_idxs, data)
 
-    # The sim never inspects sampling extras; `last_logprobs` mirrors the
-    # real runner's post-prefill attribute so the engine's capture path
-    # runs (None = no logprob arrays, which the engine treats as absent).
-    last_logprobs = None
     # unified_full/mm twin of the real runner's logprob-array attribute
     # (fake constant arrays set per extras dispatch).
     last_unified_logprobs = None
 
     def _prefill_cost_us(self, n: int) -> float:
-        """The one cost model both prefill entry points sleep by."""
+        """Token-compute time of ``n`` prefill (or draft-verify) rows."""
         return (
             self.sim.prefill_time_per_token_us * n
             + self.sim.prefill_quadratic_us * n * n
@@ -250,19 +246,11 @@ class _SimRunner(WarmupPlanMixin):
             positional=self.sim.det_positional,
         )
 
-    def _det_prefill_token(self, new_tokens, prefix_len: int) -> int:
-        return int(
-            self._det_next(new_tokens[-1], prefix_len + len(new_tokens))
-        )
-
     def _weight_pass_us(self, base_us: float) -> float:
         """The dispatch's weight-pass time at the configured precision:
         bytes-priced when the calibrated term is armed (replacing the
         flat base — the base IS the weight pass), else the flat base
-        scaled by the precision ratio. Shared by the decode dispatch
-        base and the standalone-prefill dispatch base, which is exactly
-        the asymmetry fix: both passes stream the same weights, so both
-        must reprice together when precision changes."""
+        scaled by the precision ratio."""
         sim = self.sim
         if sim.weight_bytes_per_step > 0 and sim.decode_hbm_gbps > 0:
             return (
@@ -280,44 +268,6 @@ class _SimRunner(WarmupPlanMixin):
             ctx_tokens * self.sim.kv_bytes_per_token * self.sim.kv_bytes_ratio
         )
         return bytes_ / (self.sim.decode_hbm_gbps * 1e9) * 1e6
-
-    def prefill(
-        self, new_tokens, block_ids, prefix_len, sampling, mm_embeds=None
-    ) -> int:
-        n = len(new_tokens)
-        with self.compile_stats.observe(
-            "prefill_mm" if mm_embeds else "prefill", t=_bucket(max(n, 1))
-        ):
-            time.sleep(
-                (
-                    self._weight_pass_us(self.sim.prefill_dispatch_base_us)
-                    + self._prefill_cost_us(n)
-                )
-                / 1e6
-            )
-        if self.sim.deterministic_tokens and n:
-            return self._det_prefill_token(new_tokens, prefix_len)
-        return int(self._rng.integers(0, self.sim.vocab_size))
-
-    def prefill_batch(self, lanes) -> list[int]:
-        T = _bucket(max(max(len(t) for t, _, _, _ in lanes), 1))
-        with self.compile_stats.observe(
-            "prefill_batch", t=T, lanes=_bucket(max(len(lanes), 1), minimum=2)
-        ):
-            # One dispatch base for the fused call (the lanes share its
-            # weight pass), then each lane's token compute.
-            time.sleep(
-                self._weight_pass_us(self.sim.prefill_dispatch_base_us) / 1e6
-            )
-            out = []
-            for toks, _blocks, prefix, _samp in lanes:
-                time.sleep(self._prefill_cost_us(len(toks)) / 1e6)
-                out.append(
-                    self._det_prefill_token(toks, prefix)
-                    if self.sim.deterministic_tokens and toks
-                    else int(self._rng.integers(0, self.sim.vocab_size))
-                )
-        return out
 
     @property
     def unified_slots(self) -> int:
@@ -365,9 +315,8 @@ class _SimRunner(WarmupPlanMixin):
         Spec verify spans (``draft_lens``): a lane of 1 + dl tokens
         stays a DECODE lane (its per-lane KV-read term covers the whole
         context) and its dl draft rows price as prefill tokens riding
-        the dispatch — the verify-width term, consistent with the
-        deleted phased ``decode_multi_spec`` law in that cost scales
-        linearly with verify width; the shared weight pass is paid once
+        the dispatch — the verify-width term: cost scales linearly
+        with verify width; the shared weight pass is paid once
         (which is the point of the port). Acceptance is deterministic
         against the closed-form chain, so the emitted stream follows the
         PR 13 failover byte-identity form across accepted AND rejected
@@ -466,60 +415,6 @@ class _SimRunner(WarmupPlanMixin):
         if self.cfg.speculative_k > 0:
             return UnifiedOut(last=last, toks=toks2d, counts=counts)
         return UnifiedOut(last=last, toks=None, counts=None)
-
-    def decode(
-        self, token_ids, positions, block_tables, context_lens, slot_mapping,
-        temp, top_k, top_p, seed=None,
-    ) -> np.ndarray:
-        time.sleep(
-            self._weight_pass_us(self.sim.decode_time_per_step_us) / 1e6
-        )
-        if self.sim.deterministic_tokens:
-            return self._det_next(
-                np.asarray(token_ids), np.asarray(positions) + 1
-            ).astype(np.int32)
-        return self._rng.integers(
-            0, self.sim.vocab_size, len(token_ids)
-        ).astype(np.int32)
-
-    def decode_multi(
-        self, token_ids, positions, block_tables, context_lens,
-        temp, top_k, top_p, num_steps: int, seed=None,
-    ) -> np.ndarray:
-        # KV bytes grow one token per active lane per fused step:
-        # sum(ctx) + active·s at step s.
-        active = int(np.sum(np.asarray(context_lens) > 0))
-        ctx_total = float(np.sum(np.maximum(np.asarray(context_lens), 0)))
-        kv_us = sum(
-            self._kv_read_us(ctx_total + active * s)
-            for s in range(num_steps)
-        )
-        with self.compile_stats.observe("decode_multi", steps=num_steps):
-            time.sleep(
-                (
-                    (
-                        self._weight_pass_us(self.sim.decode_time_per_step_us)
-                        + self.sim.decode_time_per_lane_us * len(token_ids)
-                    )
-                    * num_steps
-                    + kv_us
-                )
-                / 1e6
-            )
-        if self.sim.deterministic_tokens:
-            # Chain the affine hash through the fused steps: lane b's
-            # step-s token is f(step s-1's token, positions[b]+1+s).
-            prev = np.asarray(token_ids, np.int64)
-            pos = np.asarray(positions, np.int64)
-            out = np.zeros((num_steps, len(prev)), np.int32)
-            for s in range(num_steps):
-                prev = self._det_next(prev, pos + 1 + s)
-                out[s] = prev.astype(np.int32)
-            return out
-        return self._rng.integers(
-            0, self.sim.vocab_size, (num_steps, len(token_ids))
-        ).astype(np.int32)
-
 
 
 class MockerEngine(TpuEngine):

@@ -21,12 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops.attention import (
-    AttnDispatch,
-    decode_attention,
-    full_causal_attention,
-    prefill_attention,
-)
+from dynamo_tpu.ops.attention import AttnDispatch, full_causal_attention
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.quant import (
     CONTRACT_AXIS,
@@ -111,15 +106,6 @@ class WeightQuantPolicy:
         return ",".join(
             f"{s}={getattr(self, s)}" for s in self.SITES if getattr(self, s)
         )
-
-
-def _attn_fns(attn: AttnDispatch | None):
-    """Resolve the attention implementation: a per-runner AttnDispatch
-    (engine/runner.py threads one in — per-runner Pallas/mesh choice) or
-    the env-driven module defaults."""
-    if attn is None:
-        return prefill_attention, decode_attention
-    return attn.prefill, attn.decode
 
 
 def _dense_init(key, shape, dtype):
@@ -439,153 +425,6 @@ def _logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     return qdot(h, params["lm_head"]).astype(jnp.float32)
 
 
-def prefill(
-    cfg: ModelConfig,
-    params: Params,
-    kv_caches: list[tuple[jnp.ndarray, jnp.ndarray]],
-    token_ids: jnp.ndarray,    # [T] padded new tokens
-    block_table: jnp.ndarray,  # [max_blocks]
-    slot_mapping: jnp.ndarray, # [T] cache slots (trash slots for padding)
-    prefix_len: jnp.ndarray,   # scalar — prefix-cache hit length
-    total_len: jnp.ndarray,    # scalar — prefix + real new tokens
-    block_size: int,
-    attn: AttnDispatch | None = None,
-    embeds: jnp.ndarray | None = None,      # [T, D] soft-prompt overrides
-    embed_mask: jnp.ndarray | None = None,  # [T] bool — rows taken from embeds
-) -> tuple[jnp.ndarray, list[tuple[jnp.ndarray, jnp.ndarray]]]:
-    """Prefill one sequence's new tokens; returns (last-token logits [V],
-    updated kv_caches). Supports prefix reuse via prefix_len > 0.
-
-    `embeds`/`embed_mask` (a static trace-time branch — text-only runners
-    compile without the extra inputs) substitute projected multimodal
-    embeddings for placeholder-token rows: the soft-prompt mechanism the
-    multimodal encode worker feeds (llm/multimodal.py; reference analogue:
-    examples/multimodal encode_worker ahead of the decode worker)."""
-    prefill_attention, _ = _attn_fns(attn)
-    mesh = attn.mesh if attn is not None else None
-    T = token_ids.shape[0]
-    positions = prefix_len + jnp.arange(T)
-    x = _embed(params, cfg, token_ids)
-    if embeds is not None:
-        x = jnp.where(embed_mask[:, None], embeds.astype(x.dtype), x)
-
-    new_caches = []
-    for li, (layer, (k_cache, v_cache)) in enumerate(
-        zip(params["layers"], kv_caches)
-    ):
-        h = _ln(x, layer["ln_attn"], cfg)
-        if cfg.is_mla:
-            q, k, v = _qkv_mla(layer, h, cfg, positions)
-        else:
-            q, k, v = _qkv(layer, h, cfg)
-            th, sc = _layer_rope(cfg, li)
-            q = apply_rope(q, positions, th, sc)
-            k = apply_rope(k, positions, th, sc)
-        k_cache = k_cache.at[slot_mapping].set(_to_cache(k, k_cache))
-        v_cache = v_cache.at[slot_mapping].set(_to_cache(v, v_cache))
-        attn = prefill_attention(
-            q[None], k_cache, v_cache, block_table[None], prefix_len[None],
-            total_len[None], block_size, window=cfg.layer_window(li),
-        )[0]
-        if cfg.is_mla:
-            x = x + _mla_out(layer, attn, cfg)
-        else:
-            x = _residual_attn(x, layer, qdot(attn.reshape(T, -1), layer["wo"]), cfg)
-        x = _residual_mlp(x, layer, cfg, mesh)
-        new_caches.append((k_cache, v_cache))
-
-    last = jnp.clip(total_len - prefix_len - 1, 0, T - 1)
-    return _logits(params, cfg, x[last]), new_caches
-
-
-def prefill_batch(
-    cfg: ModelConfig,
-    params: Params,
-    kv_caches: list[tuple[jnp.ndarray, jnp.ndarray]],
-    token_ids: jnp.ndarray,     # [N, T] padded new tokens per lane
-    block_tables: jnp.ndarray,  # [N, max_blocks]
-    slot_mapping: jnp.ndarray,  # [N, T] (trash slots for padding/idle lanes)
-    prefix_len: jnp.ndarray,    # [N]
-    total_len: jnp.ndarray,     # [N] (0 = idle lane)
-    block_size: int,
-    attn: AttnDispatch | None = None,
-) -> tuple[jnp.ndarray, list[tuple[jnp.ndarray, jnp.ndarray]]]:
-    """N sequences' prefills fused into one call: the projections/MLP run as
-    one [N*T] batch on the MXU, K/V scatter once, and only the attention is
-    vmapped per lane (it reads the shared cache through per-lane block
-    tables). One dispatch amortizes host→device latency over N prompts —
-    the batched-prefill trick the reference inherits from vLLM's scheduler.
-    Returns last-token logits [N, V]. (Speculative verification lives on
-    the unified path now — ``unified(verify_rows=k+1)`` returns per-span
-    verify logits; this raw program serves parity tests and tools.)"""
-    prefill_attention, _ = _attn_fns(attn)
-    mesh = attn.mesh if attn is not None else None
-    N, T = token_ids.shape
-    H, kvH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    positions = prefix_len[:, None] + jnp.arange(T)[None, :]
-    x = _embed(params, cfg, token_ids)  # [N, T, D]
-
-    new_caches = []
-    for li, (layer, (k_cache, v_cache)) in enumerate(
-        zip(params["layers"], kv_caches)
-    ):
-        h = _ln(x, layer["ln_attn"], cfg)
-        flat_slots = slot_mapping.reshape(N * T)
-        if cfg.is_mla:
-            q, k, v = jax.vmap(
-                lambda xi, pi: _qkv_mla(layer, xi, cfg, pi)
-            )(h, positions)                     # q [N,T,H,dm], k/v [N,T,1,dm]
-            dm = k.shape[-1]
-            k_cache = k_cache.at[flat_slots].set(
-                _to_cache(k.reshape(N * T, 1, dm), k_cache)
-            )
-            v_cache = v_cache.at[flat_slots].set(
-                _to_cache(v.reshape(N * T, 1, dm), v_cache)
-            )
-        else:
-            q = qdot(h, layer["wq"])
-            k = qdot(h, layer["wk"])
-            v = qdot(h, layer["wv"])
-            if cfg.qkv_bias:
-                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-            q = q.reshape(N, T, H, hd)
-            k = k.reshape(N, T, kvH, hd)
-            if cfg.qk_norm:
-                q = _ln(q, layer["ln_q_head"], cfg)
-                k = _ln(k, layer["ln_k_head"], cfg)
-            if cfg.query_pre_attn_scalar:
-                q = q * jnp.asarray(
-                    (hd / cfg.query_pre_attn_scalar) ** 0.5, q.dtype
-                )
-            th, sc = _layer_rope(cfg, li)
-            rope = jax.vmap(lambda t, p: apply_rope(t, p, th, sc))
-            q = rope(q, positions)
-            k = rope(k, positions)
-            v = v.reshape(N, T, kvH, hd)
-            k_cache = k_cache.at[flat_slots].set(
-                _to_cache(k.reshape(N * T, kvH, hd), k_cache)
-            )
-            v_cache = v_cache.at[flat_slots].set(
-                _to_cache(v.reshape(N * T, kvH, hd), v_cache)
-            )
-        attn = prefill_attention(
-            q, k_cache, v_cache, block_tables, prefix_len, total_len,
-            block_size, window=cfg.layer_window(li),
-        )
-        if cfg.is_mla:
-            x = x + _mla_out(layer, attn, cfg)
-        else:
-            x = _residual_attn(
-                x, layer, qdot(attn.reshape(N, T, H * hd), layer["wo"]), cfg
-            )
-        x = _residual_mlp(x, layer, cfg, mesh)
-        new_caches.append((k_cache, v_cache))
-
-    last = jnp.clip(total_len - prefix_len - 1, 0, T - 1)  # [N]
-    hs = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [N, D]
-    return _logits(params, cfg, hs), new_caches
-
-
 def unified(
     cfg: ModelConfig,
     params: Params,
@@ -724,53 +563,6 @@ def unified(
     return logits, new_caches
 
 
-def decode(
-    cfg: ModelConfig,
-    params: Params,
-    kv_caches: list[tuple[jnp.ndarray, jnp.ndarray]],
-    token_ids: jnp.ndarray,     # [B]
-    positions: jnp.ndarray,     # [B] — context_len - 1 for active slots
-    block_tables: jnp.ndarray,  # [B, max_blocks]
-    context_lens: jnp.ndarray,  # [B] — 0 marks an inactive slot
-    slot_mapping: jnp.ndarray,  # [B] cache slots for the new token
-    block_size: int,
-    attn: AttnDispatch | None = None,
-) -> tuple[jnp.ndarray, list[tuple[jnp.ndarray, jnp.ndarray]]]:
-    """One decode step for the whole running batch; returns (logits [B, V],
-    updated kv_caches)."""
-    _, decode_attention = _attn_fns(attn)
-    mesh = attn.mesh if attn is not None else None
-    B = token_ids.shape[0]
-    x = _embed(params, cfg, token_ids)
-
-    new_caches = []
-    for li, (layer, (k_cache, v_cache)) in enumerate(
-        zip(params["layers"], kv_caches)
-    ):
-        h = _ln(x, layer["ln_attn"], cfg)
-        if cfg.is_mla:
-            q, k, v = _qkv_mla(layer, h, cfg, positions)
-        else:
-            q, k, v = _qkv(layer, h, cfg)
-            th, sc = _layer_rope(cfg, li)
-            q = apply_rope(q, positions, th, sc)
-            k = apply_rope(k, positions, th, sc)
-        k_cache = k_cache.at[slot_mapping].set(_to_cache(k, k_cache))
-        v_cache = v_cache.at[slot_mapping].set(_to_cache(v, v_cache))
-        attn = decode_attention(
-            q, k_cache, v_cache, block_tables, context_lens, block_size,
-            window=cfg.layer_window(li),
-        )
-        if cfg.is_mla:
-            x = x + _mla_out(layer, attn, cfg)
-        else:
-            x = _residual_attn(x, layer, qdot(attn.reshape(B, -1), layer["wo"]), cfg)
-        x = _residual_mlp(x, layer, cfg, mesh)
-        new_caches.append((k_cache, v_cache))
-
-    return _logits(params, cfg, x), new_caches
-
-
 def hidden_states(
     cfg: ModelConfig,
     params: Params,
@@ -781,7 +573,7 @@ def hidden_states(
     """Full no-cache trunk [T] -> pre-final-norm hidden states [T, D] —
     shared by the logits oracle below and the embeddings pooled forward
     (llm/embedding.py), so architecture changes live in one place.
-    `embeds`/`embed_mask` mirror prefill's soft-prompt substitution so the
+    `embeds`/`embed_mask` mirror `unified`'s soft-prompt substitution so the
     oracle covers the multimodal path too."""
     T = token_ids.shape[0]
     positions = jnp.arange(T)
@@ -813,7 +605,7 @@ def reference_forward(
     embed_mask: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Full no-cache forward [T] -> logits [T, V]; the correctness oracle the
-    paged prefill/decode paths are tested against."""
+    paged `unified` step is tested against."""
     return _logits(
         params, cfg, hidden_states(cfg, params, token_ids, embeds, embed_mask)
     )

@@ -90,24 +90,6 @@ class AttnDispatch:
         shape = getattr(self.mesh, "shape", {})
         return self.tp_axis if self.tp_axis in shape else None
 
-    def _dp(self, batch: int):
-        """The dp axis name when the mesh has dp>1 and it divides the
-        batch/lane dim — each dp group then runs the kernel on its own
-        batch slice (data-parallel serving within one engine)."""
-        shape = getattr(self.mesh, "shape", {})
-        n = shape.get("dp", 1)
-        return "dp" if n > 1 and batch % n == 0 else None
-
-    def _sp(self, T: int):
-        """The sp axis name when the mesh has sp>1 dividing the prefill
-        query length — sequence-parallel prefill: each sp shard computes
-        its query tile against the full (replicated) KV cache, the
-        long-context split SURVEY §5 calls for (no backend engine to hide
-        behind). Causality is preserved by offsetting q_start per shard."""
-        shape = getattr(self.mesh, "shape", {})
-        n = shape.get("sp", 1)
-        return "sp" if n > 1 and T % n == 0 else None
-
     @property
     def _sp_n(self) -> int:
         return getattr(self.mesh, "shape", {}).get("sp", 1)
@@ -183,43 +165,6 @@ class AttnDispatch:
             in_specs=(qh, sp_cache, sp_cache, P(), P()),
             out_specs=qh,
         )(qp, k_cache, v_cache, tables, ctx)
-
-    def decode(self, q, k_cache, v_cache, block_tables, context_lens,
-               block_size: int, window: int = 0):
-        D = q.shape[-1]
-        qp = _pad_q_for_cache(q, k_cache)
-        if self.kv_sp:
-            out = self._kv_sp_decode(
-                qp, k_cache, v_cache, block_tables, context_lens,
-                block_size, window,
-            )
-            return out[..., :D]
-        if not self.use_pallas:
-            out = paged_decode_attention(
-                qp, k_cache, v_cache, block_tables, context_lens, block_size,
-                window,
-            )
-        else:
-            from dynamo_tpu.ops.pallas import paged_decode_attention_pallas
-
-            fn = partial(
-                paged_decode_attention_pallas, block_size=block_size,
-                window=window,
-            )
-            if self.mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                dp = self._dp(q.shape[0])
-                qh = P(dp, self._ax, None)
-                kv_ax = None if self.kv_replicated else self._ax
-                kvh = P(None, kv_ax, None)  # cache replicated over dp
-                fn = self._wrap(
-                    fn,
-                    in_specs=(qh, kvh, kvh, P(dp, None), P(dp)),
-                    out_specs=qh,
-                )
-            out = fn(qp, k_cache, v_cache, block_tables, context_lens)
-        return out[..., :D]
 
     def ragged(
         self, q, k_cache, v_cache, block_tables, token_seq, token_pos,
@@ -303,77 +248,6 @@ class AttnDispatch:
             out = fn(*args)
         return out[..., :D]
 
-    def prefill(self, q, k_cache, v_cache, block_tables, q_start, total_len,
-                block_size: int, window: int = 0):
-        D = q.shape[-1]
-        qp = _pad_q_for_cache(q, k_cache)
-        if self.kv_sp:
-            from jax.sharding import PartitionSpec as P
-
-            sp = self._sp_n
-            _, sp_cache = self._kv_sp_specs()
-            qh = P(None, None, self._ax, None)  # [N, T, H, D]
-            if self.use_pallas:
-                from dynamo_tpu.ops.pallas import (
-                    paged_prefill_attention_pallas,
-                )
-
-                def body(qs, ks, vs, bt, q_starts, totals):
-                    lt, r = self._stripe_tables(bt, ks.shape[0] // block_size)
-                    o, m, l = paged_prefill_attention_pallas(
-                        qs, ks, vs, lt, q_starts, totals, block_size,
-                        window=window, page_offset=jnp.reshape(r, (1,)),
-                        page_stride=sp, with_stats=True,
-                    )
-                    return self._stats_merge(o, m, l, "sp").astype(qs.dtype)
-
-            else:
-                body = partial(
-                    paged_prefill_attention_sp, block_size=block_size,
-                    window=window, num_shards=sp,
-                )
-            out = self._wrap(
-                body,
-                in_specs=(qh, sp_cache, sp_cache, P(), P(), P()),
-                out_specs=qh,
-            )(qp, k_cache, v_cache, block_tables, q_start, total_len)
-            return out[..., :D]
-        if not self.use_pallas:
-            out = jax.vmap(
-                lambda qq, bt, ps, tl: paged_prefill_attention(
-                    qq, k_cache, v_cache, bt, ps, tl, block_size, window
-                )
-            )(qp, block_tables, q_start, total_len)
-        else:
-            from dynamo_tpu.ops.pallas import paged_prefill_attention_pallas
-
-            base = partial(
-                paged_prefill_attention_pallas, block_size=block_size,
-                window=window,
-            )
-            fn = base
-            if self.mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                dp = self._dp(q.shape[0])
-                sp = self._sp(q.shape[1])
-                if sp is not None:
-                    def fn(qs, ks, vs, bts, q_starts, totals):  # noqa: E306
-                        # Each sp shard holds a contiguous query tile; its
-                        # global start is q_start + shard_index * local_T.
-                        off = jax.lax.axis_index("sp") * qs.shape[1]
-                        return base(qs, ks, vs, bts, q_starts + off, totals)
-                qh = P(dp, sp, self._ax, None)
-                kv_ax = None if self.kv_replicated else self._ax
-                kvh = P(None, kv_ax, None)
-                fn = self._wrap(
-                    fn,
-                    in_specs=(qh, kvh, kvh, P(dp, None), P(dp), P(dp)),
-                    out_specs=qh,
-                )
-            out = fn(qp, k_cache, v_cache, block_tables, q_start, total_len)
-        return out[..., :D]
-
 
 def _pad_q_for_cache(q, k_cache):
     """Lane-pad q to a padded cache's head dim (ops/pallas/attention.py
@@ -385,43 +259,6 @@ def _pad_q_for_cache(q, k_cache):
         return q
     q = (q * jnp.asarray((Dc / D) ** 0.5, q.dtype)).astype(q.dtype)
     return jnp.pad(q, ((0, 0),) * (q.ndim - 1) + ((0, Dc - D),))
-
-
-def _use_pallas(k_cache, block_size: int) -> bool:
-    if not pallas_enabled():
-        return False
-    from dynamo_tpu.ops.pallas.attention import pallas_supported
-
-    return pallas_supported(
-        block_size, k_cache.shape[1], k_cache.shape[2], k_cache.dtype
-    )
-
-
-def _default_dispatch(k_cache, block_size: int) -> AttnDispatch:
-    return AttnDispatch(use_pallas=_use_pallas(k_cache, block_size))
-
-
-def decode_attention(
-    q, k_cache, v_cache, block_tables, context_lens, block_size: int,
-    window: int = 0,
-):
-    """Default (single-chip, env-driven) dispatch — used when no per-runner
-    AttnDispatch is threaded in. Handles lane-padded caches for both paths."""
-    return _default_dispatch(k_cache, block_size).decode(
-        q, k_cache, v_cache, block_tables, context_lens, block_size, window
-    )
-
-
-def prefill_attention(
-    q, k_cache, v_cache, block_tables, q_start, total_len, block_size: int,
-    window: int = 0,
-):
-    """Default dispatch for batched prefill attention: q [N, T, H, D],
-    lane-wise block tables / prefix lengths."""
-    return _default_dispatch(k_cache, block_size).prefill(
-        q, k_cache, v_cache, block_tables, q_start, total_len, block_size,
-        window,
-    )
 
 
 def _safe_div(acc: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarray:
@@ -695,8 +532,16 @@ def ragged_attention(
     q_len, kv_len, row_start, block_size: int, window: int = 0,
     k_scales=None, v_scales=None,
 ):
-    """Default (single-chip, env-driven) dispatch for the unified step."""
-    return _default_dispatch(k_cache, block_size).ragged(
+    """Default (single-chip, env-driven) dispatch for the unified step,
+    for callers with no per-runner AttnDispatch to thread in."""
+    use_pallas = False
+    if pallas_enabled():
+        from dynamo_tpu.ops.pallas.attention import pallas_supported
+
+        use_pallas = pallas_supported(
+            block_size, k_cache.shape[1], k_cache.shape[2], k_cache.dtype
+        )
+    return AttnDispatch(use_pallas=use_pallas).ragged(
         q, k_cache, v_cache, block_tables, token_seq, token_pos, q_start,
         q_len, kv_len, row_start, block_size, window,
         k_scales=k_scales, v_scales=v_scales,
@@ -780,22 +625,3 @@ def paged_decode_attention_sp(
     )
     acc_g, l_g = _sp_merge(acc, m, l, axis)
     return _safe_div(acc_g, l_g).reshape(B, H, D).astype(q.dtype)
-
-
-def paged_prefill_attention_sp(
-    q, k_cache, v_cache, block_tables, q_start, total_len, block_size: int,
-    axis: str = "sp", window: int = 0, num_shards: int = 1,
-):
-    """Per-shard batched-prefill body (q [N, T, H, D]); same contract as
-    AttnDispatch.prefill but over a slot-sharded cache."""
-    N, T, H, D = q.shape
-    off = jax.lax.axis_index(axis) if num_shards > 1 else 0
-    m, l, acc = jax.vmap(
-        lambda qq, bt, ps, tl: _prefill_partials(
-            qq, k_cache, v_cache, bt, ps, tl, block_size,
-            _local_slot_fn(axis), window, page_offset=off,
-            page_stride=num_shards,
-        )
-    )(q, block_tables, q_start, total_len)
-    acc_g, l_g = _sp_merge(acc, m, l, axis)
-    return _safe_div(acc_g, l_g).reshape(N, T, H, D).astype(q.dtype)

@@ -6,16 +6,12 @@ HBM→VMEM explicitly with double-buffered DMA, which is what gets decode
 attention to HBM-bandwidth-bound instead of gather-bound.
 """
 
-from dynamo_tpu.ops.pallas.attention import (
-    paged_decode_attention_pallas,
-    paged_prefill_attention_pallas,
-)
+from dynamo_tpu.ops.pallas.attention import paged_decode_attention_pallas
 from dynamo_tpu.ops.pallas.ragged_attention import (
     ragged_paged_attention_pallas,
 )
 
 __all__ = [
     "paged_decode_attention_pallas",
-    "paged_prefill_attention_pallas",
     "ragged_paged_attention_pallas",
 ]

@@ -1,4 +1,5 @@
-"""Ragged paged-attention Pallas kernels (decode + prefill).
+"""The paged decode-attention Pallas kernel (kv_sp's striped scan) and the
+cache-layout helpers the ragged kernel (ragged_attention.py) shares.
 
 Same math as the jnp reference (ops/attention.py — the test oracle); the
 kernels add what XLA can't express over a paged cache:
@@ -392,283 +393,3 @@ def paged_decode_attention_pallas(
         o, m, l = res
         return o, m[:, 0], l[:, 0]
     return res
-
-
-# ---------------------------------------------------------------------------
-# Prefill: a tile of query tokens per program, batched over lanes.
-# ---------------------------------------------------------------------------
-
-# Pages folded into one prefill pipeline step, mirroring DECODE_PP: one
-# wait + ONE attention fold per PP pages widens the score matmuls' key
-# dimension from bs (=16) to PP*bs (=128) — an older harness's 8B profile
-# (not reproduced) put the single-page prefill kernel at ~65% of prefill
-# device time with ~2.6% MFU in its dots; PP-wide folds are the same fix
-# that took the decode kernel 160→78 µs/layer there.
-PREFILL_PP = 8
-
-
-def _prefill_kernel(
-    # scalar prefetch
-    block_tables_ref,  # [N, max_blocks] SMEM (LOCAL stripe when strided)
-    q_start_ref,       # [N] SMEM — prefix length per lane
-    total_len_ref,     # [N] SMEM — prefix + real new tokens (0 = idle lane)
-    page_off_ref,      # [1] SMEM — this shard's logical-page residue
-    # inputs
-    q_ref,             # [1, TQ, H, D] VMEM (this lane + q tile)
-    k_hbm,             # [num_blocks, bs*kvH, D] HBM pages
-    v_hbm,
-    # outputs
-    o_ref,             # [1, TQ, H, D] VMEM (+ m/l [1, TQ, H] with stats)
-    # scratch (trailing; m/l outputs spliced before when with_stats)
-    *refs,
-    block_size: int,
-    num_kv_heads: int,
-    q_tile: int,
-    window: int = 0,
-    page_stride: int = 1,
-    with_stats: bool = False,
-):
-    if with_stats:
-        m_ref, l_ref = refs[0], refs[1]
-        k_buf, v_buf, k_sem, v_sem = refs[2:]
-    else:
-        k_buf, v_buf, k_sem, v_sem = refs
-    n = pl.program_id(0)
-    t0 = pl.program_id(1) * q_tile
-    q_start = q_start_ref[n]
-    total = total_len_ref[n]
-    off = page_off_ref[0]
-
-    TQ, H, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    kvH = num_kv_heads
-    G = H // kvH
-    bs = block_size
-    scale = 1.0 / (D**0.5)
-
-    def to_local(pages):
-        """Logical page count/index -> this shard's local count/index."""
-        if page_stride == 1:
-            return pages
-        return jnp.maximum(
-            (pages - off + page_stride - 1) // page_stride, 0
-        )
-
-    # Keys this tile can see: causal bound (q_start + t0 + TQ) clipped to
-    # the sequence's real length; with a sliding window, pages wholly
-    # before the tile's earliest visible key are skipped entirely.
-    hi = jnp.minimum(q_start + t0 + TQ, total)
-    nb = to_local(pl.cdiv(hi, block_size))
-    lo = (
-        to_local(jnp.maximum(q_start + t0 - window + 1, 0) // block_size)
-        if window
-        else jnp.int32(0)
-    )
-
-    # [TQ, H, D] -> [kvH, TQ*G, D]: fold the group dim into rows so each
-    # kv head's score matmul is a well-shaped [TQ*G, D] x [D, PP*bs].
-    q4 = (q_ref[0].astype(jnp.float32) * scale).reshape(TQ, kvH, G, D)
-    qf = jnp.transpose(q4, (1, 0, 2, 3)).reshape(kvH, TQ * G, D)
-    # Global query position per folded row (row r -> token r // G).
-    row_tok = jax.lax.broadcasted_iota(jnp.int32, (1, TQ * G, 1), 1) // G
-    q_pos = q_start + t0 + row_tok  # [1, TQ*G, 1]
-
-    # PP pages per pipeline step (see PREFILL_PP); ring as in the decode
-    # kernel, per-program (tiles have differing causal trip counts, so
-    # the flat cross-program ring position doesn't apply).
-    NBUF = DECODE_NBUF
-    PP = PREFILL_PP
-    lo_f = lo // PP          # first fold (window start aligns DOWN;
-    hi_f = pl.cdiv(nb, PP)   # behind-window pages mask out)
-
-    def issue(f):
-        """Issue the K/V DMAs for fold f's fetched pages."""
-        slot = jax.lax.rem(f, NBUF)
-        for h in range(PP):
-            j = f * PP + h
-
-            @pl.when((f < hi_f) & (j < nb))
-            def _():
-                page = block_tables_ref[n, j]
-                pltpu.make_async_copy(
-                    k_hbm.at[page],
-                    k_buf.at[slot, pl.ds(h * bs * kvH, bs * kvH)],
-                    k_sem.at[slot, h],
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[page],
-                    v_buf.at[slot, pl.ds(h * bs * kvH, bs * kvH)],
-                    v_sem.at[slot, h],
-                ).start()
-
-    jax.lax.fori_loop(lo_f, lo_f + NBUF - 1, lambda f, _: (issue(f), 0)[1], 0)
-
-    def body(f, carry):
-        m, l, acc = carry
-        issue(f + NBUF - 1)
-        slot = jax.lax.rem(f, NBUF)
-        for h in range(PP):
-            @pl.when(f * PP + h < nb)
-            def _():
-                pltpu.make_async_copy(
-                    k_hbm.at[0],
-                    k_buf.at[slot, pl.ds(h * bs * kvH, bs * kvH)],
-                    k_sem.at[slot, h],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[0],
-                    v_buf.at[slot, pl.ds(h * bs * kvH, bs * kvH)],
-                    v_sem.at[slot, h],
-                ).wait()
-        # Unfetched tail pages hold garbage (stale/uninitialized VMEM):
-        # zero V's rows (0 * NaN = NaN through the PV matmul); K needs
-        # nothing — NaN scores land only in masked columns.
-        fetched = (
-            f * PP + jax.lax.broadcasted_iota(
-                jnp.int32, (PP * bs, 1, 1), 0
-            ) // bs
-        ) < nb
-        k = heads_view(k_buf, slot, PP * bs, kvH, D)
-        v = heads_view(v_buf, slot, PP * bs, kvH, D)
-        v = jnp.where(fetched, v, 0.0)
-        kT = jnp.swapaxes(k, 0, 1)  # [kvH, PP*bs, D]
-        vT = jnp.swapaxes(v, 0, 1)
-
-        # [kvH, TQ*G, D] x [kvH, PP*bs, D] -> [kvH, TQ*G, PP*bs]
-        scores = jax.lax.dot_general(
-            qf, kT,
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        elem = jax.lax.broadcasted_iota(jnp.int32, (1, 1, PP * bs), 2)
-        if page_stride == 1:
-            key_pos = f * PP * bs + elem
-        else:
-            key_pos = (
-                off + (f * PP + elem // bs) * page_stride
-            ) * bs + elem % bs
-        mask = (key_pos <= q_pos) & (key_pos < total)  # [1, TQ*G, PP*bs]
-        if window:
-            mask = mask & (key_pos > q_pos - window)
-        scores = jnp.where(mask, scores, NEG_INF)
-
-        m_new = jnp.maximum(m, scores.max(axis=-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
-        l_new = l * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p, vT,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc * corr[..., None] + pv
-
-    init = (
-        jnp.full((kvH, TQ * G), NEG_INF, jnp.float32),
-        jnp.zeros((kvH, TQ * G), jnp.float32),
-        jnp.zeros((kvH, TQ * G, D), jnp.float32),
-    )
-    m, l, acc = jax.lax.fori_loop(lo_f, hi_f, body, init)
-    out = jnp.where(
-        l[..., None] > 0, acc / jnp.maximum(l[..., None], 1e-30), 0.0
-    )
-    # [kvH, TQ*G, D] -> [TQ, H, D]
-    out = jnp.transpose(out.reshape(kvH, TQ, G, D), (1, 0, 2, 3))
-    o_ref[0] = out.reshape(TQ, H, D).astype(o_ref.dtype)
-    if with_stats:
-        m_ref[0] = jnp.transpose(
-            m.reshape(kvH, TQ, G), (1, 0, 2)
-        ).reshape(TQ, H)
-        l_ref[0] = jnp.transpose(
-            l.reshape(kvH, TQ, G), (1, 0, 2)
-        ).reshape(TQ, H)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "block_size", "q_tile", "window", "page_stride", "with_stats",
-    ),
-)
-def paged_prefill_attention_pallas(
-    q: jnp.ndarray,             # [N, T, H, D] — new tokens' queries per lane
-    k_cache: jnp.ndarray,       # [num_slots, kvH, D]
-    v_cache: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [N, max_blocks] int32
-    q_start: jnp.ndarray,       # [N] — prefix length per lane
-    total_len: jnp.ndarray,     # [N] — prefix + real new tokens (0 = idle)
-    block_size: int,
-    q_tile: int = 64,
-    window: int = 0,
-    page_offset: jnp.ndarray | None = None,  # [1] — kv_sp shard residue
-    page_stride: int = 1,
-    with_stats: bool = False,
-):
-    """Returns out [N, T, H, D]; with ``with_stats`` returns (out, m, l)
-    with out in float32 and m/l [N, T, H] for the kv_sp shard merge."""
-    N, T, H, D = q.shape
-    kvH = k_cache.shape[1]
-    TQ = min(q_tile, T)
-    kp = k_cache.reshape(-1, block_size * kvH, D)
-    vp = v_cache.reshape(-1, block_size * kvH, D)
-    if page_offset is None:
-        page_offset = jnp.zeros((1,), jnp.int32)
-
-    qspec = pl.BlockSpec(
-        (1, TQ, H, D),
-        lambda n, t, *_: (n, t, 0, 0),
-        memory_space=pltpu.VMEM,
-    )
-    hspec = pl.BlockSpec(
-        (1, TQ, H), lambda n, t, *_: (n, t, 0), memory_space=pltpu.VMEM
-    )
-    out_shape = jax.ShapeDtypeStruct(
-        (N, T, H, D), jnp.float32 if with_stats else q.dtype
-    )
-    out_specs = qspec
-    if with_stats:
-        out_shape = (
-            out_shape,
-            jax.ShapeDtypeStruct((N, T, H), jnp.float32),
-            jax.ShapeDtypeStruct((N, T, H), jnp.float32),
-        )
-        out_specs = (qspec, hspec, hspec)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(N, pl.cdiv(T, TQ)),
-        in_specs=[
-            qspec,
-            pl.BlockSpec(memory_space=MEMORY_SPACE_ANY),
-            pl.BlockSpec(memory_space=MEMORY_SPACE_ANY),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM(
-                (DECODE_NBUF, PREFILL_PP * block_size * kvH, D),
-                k_cache.dtype,
-            ),
-            pltpu.VMEM(
-                (DECODE_NBUF, PREFILL_PP * block_size * kvH, D),
-                v_cache.dtype,
-            ),
-            pltpu.SemaphoreType.DMA((DECODE_NBUF, PREFILL_PP)),
-            pltpu.SemaphoreType.DMA((DECODE_NBUF, PREFILL_PP)),
-        ],
-    )
-    kernel = functools.partial(
-        _prefill_kernel, block_size=block_size, num_kv_heads=kvH, q_tile=TQ,
-        window=window, page_stride=page_stride, with_stats=with_stats,
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid_spec=grid_spec,
-        interpret=_interpret(),
-    )(
-        block_tables.astype(jnp.int32),
-        q_start.astype(jnp.int32),
-        total_len.astype(jnp.int32),
-        page_offset.astype(jnp.int32),
-        q,
-        kp,
-        vp,
-    )

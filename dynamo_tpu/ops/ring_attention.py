@@ -1,9 +1,8 @@
 """Ring attention: causal attention with K/V sharded over the `sp` axis.
 
 The long-context primitive SURVEY §5 requires natively (the reference
-delegates long context to its backend engines): sequence-parallel prefill
-in ops/attention.py shards only the QUERY tiles and replicates KV, so its
-memory ceiling is one chip's KV. Ring attention shards K/V too — each sp
+delegates long context to its backend engines): q, K and V are all
+sharded over the sequence, so no chip ever holds the whole KV — each sp
 shard holds one sequence block of q, k, v; K/V blocks rotate around the
 ring via `lax.ppermute` while every shard folds them into a flash-style
 online softmax (running max + normalizer). Per-chip memory is O(T/n) and

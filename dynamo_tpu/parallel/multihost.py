@@ -88,13 +88,12 @@ def initialize(cfg: MultiHostConfig) -> None:
 
 def serve_tokens(runner, ecfg, prompt: list[int], lanes: int, steps: int) -> list[int]:
     """Shared serve harness (also used by __graft_entry__): prefill
-    ``lanes`` copies of ``prompt`` into their own blocks, then one fused
-    ``steps``-step greedy decode; returns first + decoded tokens for
-    equality checks against another runner / process layout."""
+    ``lanes`` copies of ``prompt`` into their own blocks in one unified
+    dispatch, then ``steps`` greedy decode dispatches; returns first +
+    decoded tokens (step-major) for equality checks against another
+    runner / process layout."""
     bs = ecfg.block_size
-    B = ecfg.max_num_seqs
     blocks_per = (len(prompt) + steps + bs - 1) // bs
-    tables = np.zeros((B, ecfg.max_blocks_per_seq), np.int32)
     # kv_sp runners need STRIPED placement (logical block i on sp shard
     # i % sp — the engine allocator's contract, engine/kv_cache.py).
     shards = getattr(runner, "kv_shards", 1)
@@ -108,25 +107,21 @@ def serve_tokens(runner, ecfg, prompt: list[int], lanes: int, steps: int) -> lis
         assert b < (s + 1) * bps, "serve harness overflowed an sp shard"
         return b
 
-    firsts = []
-    for lane in range(lanes):
-        blocks = [take(i) for i in range(blocks_per)]
-        tables[lane, :blocks_per] = blocks
-        firsts.append(runner.prefill(prompt, blocks, 0, (0.0, 0, 1.0)))
-    n = len(prompt)
-    toks = runner.decode_multi(
-        np.asarray(firsts + [0] * (B - lanes), np.int32),
-        np.asarray([n] * lanes + [0] * (B - lanes), np.int32),
-        tables,
-        np.asarray([n + 1] * lanes + [0] * (B - lanes), np.int32),
-        np.zeros(B, np.float32),
-        np.zeros(B, np.int32),
-        np.ones(B, np.float32),
-        steps,
-    )
-    out = np.asarray(toks)[:, :lanes]
-    assert out.shape == (steps, lanes)
-    return firsts + [int(t) for t in out.ravel()]
+    tables = [[take(i) for i in range(blocks_per)] for _ in range(lanes)]
+
+    def step(spans: list[tuple[list[int], int]]) -> list[int]:
+        out = runner.unified_step([
+            (toks, tables[lane], prefix, (0.0, 0, 1.0))
+            for lane, (toks, prefix) in enumerate(spans)
+        ])
+        return [int(t) for t in np.asarray(out.last)[:lanes]]
+
+    toks = step([(prompt, 0)] * lanes)
+    served = list(toks)
+    for s in range(steps):
+        toks = step([([t], len(prompt) + s) for t in toks])
+        served += toks
+    return served
 
 
 def _tiny_engine_config():

@@ -14,7 +14,7 @@ vLLM, MPI for TRT-LLM, lib/llm/src/engines.rs:42-60; the TPU engine
 spans hosts itself, so the lockstep plane is ours to provide):
 
 - ``StepLeader`` wraps rank 0's ModelRunner. Every top-level device-call
-  the engine makes (prefill / decode chunks / warmup / block IO) is
+  the engine makes (the unified step / warmup / block IO) is
   published to the control-plane bus BEFORE it executes locally.
 - ``follower_serve`` runs on every other rank: subscribe, then replay
   each call verbatim against an identically-built local ModelRunner.
@@ -62,14 +62,8 @@ REPLAYED = (
     "warmup",
     # The serving step: ONE ragged unified dispatch per engine iteration
     # (decode lanes, prefill quanta, and draft-verify spans in one flat
-    # batch). The raw programs below remain replayable for parity tests
-    # and bring-up tools; decode_multi_full/decode_multi_spec are GONE
-    # with the phase-alternating engine.
+    # batch) — the only step program a runner has.
     "unified_step",
-    "prefill",
-    "prefill_batch",
-    "decode",
-    "decode_multi",
     "gather_block",
     "scatter_block",
     # Batched block IO (ops/kv_copy.py): same SPMD-program rule as the
@@ -432,19 +426,13 @@ class StepLeader:
         self._pending.append(fut)
         self._pending[:] = [f for f in self._pending if not f.done()]
 
-    def warmup_plan(
-        self, prompt_buckets=None, decode_chunks=None, manifest=None
-    ):
+    def warmup_plan(self, manifest=None):
         """Compile lifecycle (engine/compile_cache.py): followers replay
         `warmup` as ONE broadcast REPLAYED call, so the leader's plan
         collapses to that single op. No manifest/tail split across a mesh
         — every rank must compile the identical set in lockstep, and the
         thunks a per-shape plan carries are not wire-shippable."""
-
-        def op():
-            return self.warmup(prompt_buckets, decode_chunks)
-
-        return [("warmup", op)], []
+        return [("warmup", self.warmup)], []
 
     def run_warm_ops(self, ops) -> int:
         n = 0

@@ -159,9 +159,9 @@ def test_stepcast_codec_roundtrip():
     )
     kwargs = {"mm_embeds": np.ones((2, 3), np.float32), "flag": False}
     seq, name, out_args, out_kwargs = decode_step(
-        encode_step(3, "prefill", args, kwargs)
+        encode_step(3, "unified_step", args, kwargs)
     )
-    assert (seq, name) == (3, "prefill")
+    assert (seq, name) == (3, "unified_step")
     np.testing.assert_array_equal(out_args[0], toks)
     assert out_args[0].dtype == np.int32
     np.testing.assert_array_equal(out_args[1], tables)
@@ -188,24 +188,24 @@ def test_stepcast_rejects_malformed():
     # Unknown wire version.
     with pytest.raises(StepWireError, match="version"):
         decode_step(msgpack.packb(
-            {"v": 99, "seq": 0, "name": "prefill", "args": [], "kwargs": {}}
+            {"v": 99, "seq": 0, "name": "unified_step", "args": [], "kwargs": {}}
         ))
     # Extra field smuggled in.
     with pytest.raises(StepWireError, match="fields"):
         decode_step(msgpack.packb(
-            {"v": 1, "seq": 0, "name": "prefill", "args": [], "kwargs": {},
+            {"v": 1, "seq": 0, "name": "unified_step", "args": [], "kwargs": {},
              "__reduce__": "rm -rf"}
         ))
     # Unknown value tag.
     with pytest.raises(StepWireError, match="unknown wire tag"):
         decode_step(msgpack.packb(
-            {"v": 1, "seq": 0, "name": "prefill",
+            {"v": 1, "seq": 0, "name": "unified_step",
              "args": [{"__obj__": "x"}], "kwargs": {}}
         ))
     # Forbidden ndarray dtype (object arrays were pickle's attack surface).
     with pytest.raises(StepWireError, match="dtype"):
         decode_step(msgpack.packb(
-            {"v": 1, "seq": 0, "name": "prefill",
+            {"v": 1, "seq": 0, "name": "unified_step",
              "args": [{"__nd__": ["|O", [1], b"x"]}], "kwargs": {}}
         ))
     # Malformed ndarray payloads wrap into StepWireError too (reshape /
@@ -218,7 +218,7 @@ def test_stepcast_rejects_malformed():
     ):
         with pytest.raises(StepWireError):
             decode_step(msgpack.packb(
-                {"v": 1, "seq": 0, "name": "prefill", "args": [bad],
+                {"v": 1, "seq": 0, "name": "unified_step", "args": [bad],
                  "kwargs": {}}
             ))
     # Not even msgpack.
@@ -226,7 +226,7 @@ def test_stepcast_rejects_malformed():
         decode_step(b"\x80\x04\x95pickle-bytes")
     # Leader side refuses unshippable values instead of pickling them.
     with pytest.raises(TypeError):
-        encode_step(0, "prefill", (object(),), {})
+        encode_step(0, "unified_step", (object(),), {})
 
 
 def test_stepcast_has_no_pickle():
@@ -247,10 +247,17 @@ class _RecordingRunner:
 
     def __getattr__(self, name):
         def call(*args, **kwargs):
+            from dynamo_tpu.engine.runner import UnifiedOut
+
             self.calls.append((name, args, kwargs))
+            if name == "unified_step":  # the follower keeps `.last`
+                return UnifiedOut(last=np.zeros(4, np.int32))
             return None
 
         return call
+
+
+_ONE_TOKEN_STEP = [([1], [], 0, (0.0, 0, 1.0))]
 
 
 async def test_stepcast_leader_follower_typed_wire():
@@ -273,18 +280,19 @@ async def test_stepcast_leader_follower_typed_wire():
             timeout=5.0,
         )
         toks = np.arange(5, dtype=np.int32)
-        leader.prefill(toks, [1, 2], 0, (0.0, 0, 1.0))
-        leader.decode_multi(toks, toks, np.zeros((1, 2), np.int32), 4)
+        leader.unified_step([(toks, [1, 2], 0, (0.0, 0, 1.0))])
+        leader.scatter_many([1, 2], np.zeros((2, 2), np.int32))
         leader.attn = "passthrough-not-replayed"  # attribute proxying
         await asyncio.sleep(0.2)
         await leader.stop()
         assert await asyncio.wait_for(follower, 5.0) == 2
-        assert [c[0] for c in runner.calls] == ["prefill", "decode_multi"]
-        np.testing.assert_array_equal(runner.calls[0][1][0], toks)
-        assert runner.calls[0][1][3] == (0.0, 0, 1.0)
+        assert [c[0] for c in runner.calls] == ["unified_step", "scatter_many"]
+        (lane,) = runner.calls[0][1][0]
+        np.testing.assert_array_equal(lane[0], toks)
+        assert lane[3] == (0.0, 0, 1.0)
         # Leader executed locally too, and non-replayed attrs passed through.
         assert [c[0] for c in leader_runner.calls] == [
-            "prefill", "decode_multi"
+            "unified_step", "scatter_many"
         ]
         assert leader_runner.attn == "passthrough-not-replayed"
     finally:
@@ -387,9 +395,9 @@ async def test_stepcast_dropped_step_fails_loudly():
             ).start(),
             timeout=5.0,
         )
-        leader.prefill([1], [], 0, (0.0, 0, 1.0))
+        leader.unified_step(_ONE_TOKEN_STEP)
         FAULTS.arm("stepcast.broadcast", "drop", times=1)
-        leader.decode([1], [0], [[0]], [1], [0], 0.0, 0, 1.0)  # dropped
+        leader.unified_step([([1], [0], 1, (0.0, 0, 1.0))])  # dropped
         leader.gather_block(3)  # arrives with seq 2 — gap!
         # Prong 1: the follower's gap check fires on the next frame.
         with pytest.raises(RuntimeError, match="lost step"):
@@ -430,7 +438,7 @@ async def test_stepcast_replay_fault_kills_follower_loudly():
             timeout=5.0,
         )
         FAULTS.arm("stepcast.replay", "raise", times=1)
-        leader.prefill([1], [], 0, (0.0, 0, 1.0))
+        leader.unified_step(_ONE_TOKEN_STEP)
         with pytest.raises(FaultError):
             await asyncio.wait_for(follower, 5.0)
         assert FAULTS.injected["stepcast.replay"] == 1
@@ -460,7 +468,7 @@ async def test_stepcast_leader_detects_dead_follower():
             ).start(),
             timeout=5.0,
         )
-        leader.prefill([1], [], 0, (0.0, 0, 1.0))
+        leader.unified_step(_ONE_TOKEN_STEP)
         await asyncio.sleep(0.2)
         assert not lost  # heartbeats flowing — no false positive
         follower.cancel()  # the "process died" moment

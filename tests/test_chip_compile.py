@@ -108,16 +108,13 @@ def test_ragged_kernel_compiles_at_served_widths(mosaic, one_chip, T, kv_dtype):
 
 
 @pytest.mark.parametrize("with_stats", [False, True])
-def test_phase_split_kernels_compile_at_served_widths(
+def test_kv_sp_decode_kernel_compiles_at_served_widths(
     mosaic, one_chip, with_stats
 ):
-    """What still reaches the phase-split kernels: ``--kv-sp`` runs the
-    ragged batch through the decode kernel's strided with-stats form
-    (AttnDispatch._kv_sp_decode), and the runner's legacy prefill/decode
-    programs (multihost bring-up, the graft entry) call the plain forms.
-    The prefill kernel's with-stats form is NOT asked for: no served
-    path reaches it, and the chip's compiler refuses its [TQ, kvH, G] ->
-    [TQ, H] stats reshape (CHANGES.md, PR 22)."""
+    """The one phase-split kernel left, the paged decode kernel:
+    ``--kv-sp`` runs the ragged batch through its strided with-stats
+    form (AttnDispatch._kv_sp_decode); the plain form is what the
+    oracle tests hold it to."""
     i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
     cache = _sds((NUM_BLOCKS * BS, KVH, D), jnp.bfloat16, one_chip)
     kw = (
@@ -130,12 +127,6 @@ def test_phase_split_kernels_compile_at_served_widths(
         i32((32, MAX_BLOCKS)), i32((32,)), block_size=BS, **kw,
     ).compile()
     assert _kernel_count(decode.as_text()) == 1
-    if not with_stats:
-        prefill = phase_kernels.paged_prefill_attention_pallas.lower(
-            _sds((4, 256, H, D), jnp.bfloat16, one_chip), cache, cache,
-            i32((4, MAX_BLOCKS)), i32((4,)), i32((4,)), block_size=BS,
-        ).compile()
-        assert _kernel_count(prefill.as_text()) == 1
 
 
 def _unified_step_args(cfg: ModelConfig, T: int, sharding_of):

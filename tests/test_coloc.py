@@ -35,7 +35,7 @@ SLO = 10.0
 def _cfg(**kw) -> EngineConfig:
     base = dict(
         model=ModelConfig.tiny_test(), num_blocks=64, max_model_len=256,
-        unified=True, unified_token_budget=1024,
+        unified_token_budget=1024,
         unified_prefill_quantum=64, coloc="adaptive", itl_slo_ms=SLO,
         coloc_min_quantum=16,
     )
@@ -152,8 +152,6 @@ def test_admit_prefill_defers_under_pressure_with_bounded_streak():
 def test_config_validation_rejects_bad_coloc_combos():
     with pytest.raises(ValueError, match="coloc="):
         _cfg(coloc="magic").validate()
-    with pytest.raises(ValueError, match="requires unified"):
-        _cfg(unified=False).validate()
     with pytest.raises(ValueError, match="itl_slo_ms"):
         _cfg(itl_slo_ms=0.0).validate()
     with pytest.raises(ValueError, match="coloc_min_quantum"):
@@ -276,7 +274,7 @@ async def test_mocker_prefill_burst_mid_decode_holds_itl_slo():
         model=ModelConfig.tiny_test(), num_blocks=512, block_size=16,
         max_num_seqs=6, max_model_len=1024, prefill_batch=2,
         dtype="float32", sampling_extras=False,
-        unified=True, unified_token_budget=512,
+        unified_token_budget=512,
         unified_prefill_quantum=32, coloc="adaptive", itl_slo_ms=slo,
         coloc_min_quantum=16,
     )
@@ -356,7 +354,7 @@ async def test_mocker_static_vs_adaptive_quantum_moves_simulated_itl():
             model=ModelConfig.tiny_test(), num_blocks=512, block_size=16,
             max_num_seqs=4, max_model_len=1024, prefill_batch=2,
             dtype="float32", sampling_extras=False,
-            unified=True, unified_token_budget=512,
+            unified_token_budget=512,
             unified_prefill_quantum=quantum,
             coloc="static", itl_slo_ms=1e9,  # measure, never adapt
         )

@@ -47,8 +47,6 @@ def _cfg(**kw) -> EngineConfig:
         num_blocks=128,
         max_num_seqs=4,
         max_model_len=128,
-        prefill_chunk=128,
-        decode_chunk=4,
         prefill_batch=4,
     )
     defaults.update(kw)
@@ -395,8 +393,8 @@ async def test_real_runner_warmup_covers_serving_shapes():
     engine = TpuEngine(_cfg(
         model=ModelConfig.tiny_test(),
         max_model_len=64,
-        prefill_chunk=32,   # buckets {16, 32}; a 33-token prompt chunks
-        decode_chunk=2,     # small ladder — keeps the compile count low
+        unified_token_budget=32,  # rungs {16, 32}; a 33-token prompt chunks
+        unified_prefill_quantum=16,
         sampling_extras=False,
         dtype="float32",
     ))

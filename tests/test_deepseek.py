@@ -28,6 +28,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.moe import MoeConfig, moe_router
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -93,10 +94,10 @@ def test_mla_sharded_matches_single_chip():
     prompt = list(range(2, 18))
     blocks = [1, 2, 3, 4]
     single = ModelRunner(ecfg, params=PARAMS)
-    tok = single.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok = step_token(single, prompt, blocks)
     mesh = build_mesh({"tp": 2, "ep": 2, "dp": 2})
     sharded = ModelRunner(ecfg, params=PARAMS, mesh=mesh)
-    tok2 = sharded.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok2 = step_token(sharded, prompt, blocks)
     assert tok == tok2
 
 
@@ -302,7 +303,7 @@ def test_quantized_mla_matches_quantized_oracle():
     )
     r = ModelRunner(ecfg, params=PARAMS)
     prompt = [1, 5, 9, 2, 7]
-    tok = r.prefill(prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0))
+    tok = step_token(r, prompt, [1, 2, 3, 4])
     assert tok == q_oracle(prompt, 1)[0]
 
 

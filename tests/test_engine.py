@@ -169,30 +169,35 @@ def test_block_allocator_prefix_lifecycle():
     assert kinds.count("removed") == 2
 
 
-async def test_decode_chunk_sizes_agree():
-    """Fused multi-step decode must emit exactly the single-step stream
-    (greedy), including at the max_model_len boundary."""
+async def test_pipeline_depths_agree():
+    """Pipelined dispatch (dispatches in flight feeding on device-resident
+    tokens) must emit exactly the depth-1 stream (greedy), including at
+    the max_model_len boundary."""
     prompt = [3, 1, 4, 1, 5, 9, 2, 6]
     outs = []
-    for chunk in (1, 4, 8):
+    for depth in (1, 2, 4):
         engine = TpuEngine(
-            engine_config(decode_chunk=chunk, max_model_len=24), params=PARAMS
+            engine_config(pipeline_depth=depth, max_model_len=24),
+            params=PARAMS,
         )
         await engine.start()
         toks, finish = await collect(engine, prompt, max_tokens=64)
         await engine.stop()
         outs.append((toks, finish))
     assert outs[0] == outs[1] == outs[2]
+    assert outs[0][0] == oracle_greedy(prompt, 16)
     # 24-token context limit: 8 prompt + 16 generated, finish=length.
     assert len(outs[0][0]) == 16 and outs[0][1] is FinishReason.LENGTH
 
 
 async def test_chunked_prefill_matches_oracle():
-    """A prompt longer than prefill_chunk is fed in chunks; the result must
-    be bit-identical to the unchunked computation."""
-    prompt = list(range(1, 41))  # 40 tokens, chunk=8 -> 5 chunks
+    """A prompt longer than the token budget is fed in chunks; the result
+    must be bit-identical to the unchunked computation."""
+    prompt = list(range(1, 41))  # 40 tokens, budget 16 -> 3 chunks
     engine = TpuEngine(
-        engine_config(prefill_chunk=8, num_blocks=64), params=PARAMS
+        engine_config(unified_token_budget=16, unified_prefill_quantum=8,
+                      num_blocks=64),
+        params=PARAMS,
     )
     await engine.start()
     try:
@@ -619,7 +624,7 @@ async def test_rolling_buffer_eviction_plateaus_and_is_exact():
     prompt = [int(t) for t in
               np.random.default_rng(4).integers(1, CFG.vocab_size, 20)]
     OUT = 60  # final length 80 >> window 8
-    ecfg = engine_config(model=wcfg, max_model_len=128, decode_chunk=4)
+    ecfg = engine_config(model=wcfg, max_model_len=128)
 
     async def run(evict: bool):
         engine = TpuEngine(ecfg, params=params)
@@ -645,11 +650,11 @@ async def test_rolling_buffer_eviction_plateaus_and_is_exact():
     # Without eviction the live block count grows with the context; with
     # it, the tail of the run must sit at O(window/bs): window 8 / bs 4 =
     # 2 in-window pages + the partially-filled growth page + pipeline
-    # slack (chunks in flight keep sched_len ahead by 2*decode_chunk).
+    # slack (dispatches in flight keep sched_len ahead by pipeline_depth).
     bs = ecfg.block_size
     bound = (
         (wcfg.sliding_window + bs - 1) // bs + 1
-        + (2 * ecfg.decode_chunk) // bs + 1
+        + ecfg.pipeline_depth // bs + 1
     )
     assert max(peaks_off) >= (len(prompt) + OUT - 8) // bs  # grew ~O(ctx)
     assert max(peaks_on[len(peaks_on) // 2 :]) <= bound, (

@@ -136,7 +136,8 @@ async def test_gemma3_engine_matches_oracle():
     engine = TpuEngine(
         EngineConfig(
             model=GCFG, num_blocks=64, max_num_seqs=2, max_model_len=128,
-            dtype="float32", prefill_chunk=16,
+            dtype="float32", unified_token_budget=16,
+            unified_prefill_quantum=16,
         ),
         params=params,
     )

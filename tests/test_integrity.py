@@ -405,6 +405,34 @@ async def test_torn_write_crash_drill(tmp_path):
         await kvbm.stop()
 
 
+async def test_offload_drain_yields_to_a_pending_done_callback(monkeypatch):
+    """What hung the crash drill's child under load (one run in ~20 with
+    every core busy): a copy-down task already DONE whose discard callback
+    has not run yet. asyncio.gather over tasks that are all done completes
+    without yielding (Python 3.12), so ``drain`` must give the loop a turn
+    or it spins forever and the child never prints its next STORED line."""
+    from dynamo_tpu.block_manager import offload
+
+    mgr = offload.OffloadManager(None, None)
+    task = asyncio.ensure_future(asyncio.sleep(0))
+    await task
+    mgr._tasks.add(task)
+    task.add_done_callback(mgr._tasks.discard)  # scheduled, not yet run
+
+    calls = 0
+    real_gather = asyncio.gather
+
+    def counted(*a, **k):
+        nonlocal calls
+        calls += 1
+        assert calls < 50, "drain spins without yielding to the loop"
+        return real_gather(*a, **k)
+
+    monkeypatch.setattr(offload.asyncio, "gather", counted)
+    await asyncio.wait_for(mgr.drain(), 5)
+    assert not mgr._tasks and calls == 1
+
+
 def test_legacy_peer_blockset_refused(caplog):
     """Satellite regression: a checksumming worker REFUSES a legacy
     peer's blockset loudly — its rows are unverifiable here."""

@@ -285,7 +285,7 @@ def test_int8_spec_engine_stream_matches_plain():
             EngineConfig(
                 model=mcfg, dtype="float32", block_size=4, num_blocks=128,
                 max_num_seqs=2, max_model_len=128, kv_quant="int8",
-                unified=True, unified_token_budget=64,
+                unified_token_budget=64,
                 sampling_extras=False, speculative_k=spec_k,
             ),
             params=params,
@@ -434,7 +434,7 @@ def _unified_runner(kv_quant):
 
     cfg = EngineConfig(
         model=ModelConfig.tiny_test(), dtype="float32", num_blocks=32,
-        max_num_seqs=2, max_model_len=64, prefill_batch=2, unified=True,
+        max_num_seqs=2, max_model_len=64, prefill_batch=2,
         unified_token_budget=32, unified_prefill_quantum=16,
         sampling_extras=False, kv_quant=kv_quant,
     )
@@ -526,7 +526,7 @@ def test_int8_engine_cross_restore_via_quantized_host_tier():
     mcfg = ModelConfig.tiny_test()
     ecfg = EngineConfig(
         model=mcfg, num_blocks=32, max_num_seqs=2, max_model_len=128,
-        dtype="float32", unified=True, unified_token_budget=64,
+        dtype="float32", unified_token_budget=64,
         unified_prefill_quantum=16, sampling_extras=False,
         kv_quant="int8",
     )
@@ -604,7 +604,7 @@ def _greedy_quality(n_prompts, osl, threshold):
         cfg = EngineConfig(
             model=ModelConfig.tiny_test(), dtype="float32", num_blocks=64,
             max_num_seqs=4, max_model_len=128, prefill_batch=2,
-            unified=True, unified_token_budget=64,
+            unified_token_budget=64,
             unified_prefill_quantum=16, sampling_extras=False,
             kv_quant=kv_quant,
         )
@@ -654,18 +654,9 @@ def test_kv_quant_config_validation():
     from dynamo_tpu.engine.config import EngineConfig
     from dynamo_tpu.models.config import ModelConfig
 
-    # Unified is the only path now, so kv_quant validates by default...
     EngineConfig(model=ModelConfig.tiny_test(), kv_quant="int8").validate()
-    # ...a phased engine cannot even be configured...
-    cfg = EngineConfig(
-        model=ModelConfig.tiny_test(), kv_quant="int8", unified=False
-    )
-    with pytest.raises(ValueError, match="unified"):
-        cfg.validate()
-    # ...and unknown quant modes still reject.
-    cfg = EngineConfig(
-        model=ModelConfig.tiny_test(), kv_quant="fp4", unified=True
-    )
+    # Unknown quant modes reject.
+    cfg = EngineConfig(model=ModelConfig.tiny_test(), kv_quant="fp4")
     with pytest.raises(ValueError, match="kv_quant"):
         cfg.validate()
 

@@ -4,7 +4,7 @@ the sp mesh axis.
 The engine mode under test: mesh {"sp": n} + EngineConfig.kv_sp=True puts
 1/n of the cache slots on each device and runs attention as per-shard
 flash partials merged with a logsumexp combine (ops/attention.py
-paged_*_attention_sp) — per-call communication is O(query), never
+paged_decode_attention_sp / AttnDispatch._kv_sp_decode) — per-call communication is O(query), never
 O(cache). The serving proof: a sequence whose KV provably exceeds ONE
 device's cache arrays decodes token-identically to a replicated-cache
 oracle engine.
@@ -35,15 +35,13 @@ PARAMS = llama.init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
 
 
 def test_sp_attention_matches_replicated_oracle():
-    """Unit parity: slot-sharded decode/prefill attention vs the
-    replicated-cache reference on a random paged cache."""
+    """Unit parity: slot-sharded decode attention (kv_sp's XLA body) vs
+    the replicated-cache reference on a random paged cache."""
     from jax.sharding import PartitionSpec as P
 
     from dynamo_tpu.ops.attention import (
         paged_decode_attention,
         paged_decode_attention_sp,
-        paged_prefill_attention,
-        paged_prefill_attention_sp,
     )
 
     mesh = build_mesh({"sp": 4, "dp": 2})
@@ -72,28 +70,6 @@ def test_sp_attention_matches_replicated_oracle():
     )(q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx))
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
-    )
-
-    # Prefill: lane 0 extends a 5-token prefix by 8 new tokens.
-    T = 8
-    qp = jnp.asarray(rng.standard_normal((1, T, H, D)), jnp.float32)
-    bt = jnp.asarray(tables[1][None])
-    q_start = jnp.asarray([5])
-    total = jnp.asarray([13])
-    want_p = jax.vmap(
-        lambda qq, b, ps, tl: paged_prefill_attention(
-            qq, k_cache, v_cache, b, ps, tl, bs
-        )
-    )(qp, bt, q_start, total)
-    got_p = jax.shard_map(
-        lambda *a: paged_prefill_attention_sp(*a, block_size=bs),
-        mesh=mesh,
-        in_specs=(P(), sp_cache, sp_cache, P(), P(), P()),
-        out_specs=P(),
-        check_vma=False,
-    )(qp, k_cache, v_cache, bt, q_start, total)
-    np.testing.assert_allclose(
-        np.asarray(got_p), np.asarray(want_p), rtol=2e-5, atol=2e-5
     )
 
 
@@ -184,9 +160,11 @@ def _striped_tables(rng, sp: int, nblocks: int, lane_pages: list[int], width: in
 def test_sp_striped_scan_matches_oracle(use_pallas):
     """The r05 striped scan (each sp shard visits ONLY its own stripe of
     logical pages — FLOPs partition sp-ways) against the replicated
-    oracle, with tp head-sharding composed in, on both the jnp and the
-    Pallas (interpret) paths. Pallas needs D % 128 == 0, so the oracle
-    runs on a lane-padded cache too (the production envelope)."""
+    oracles, with tp head-sharding composed in, on both the jnp and the
+    Pallas (interpret) paths, through the one entry that reaches it:
+    AttnDispatch.ragged, decode lanes and a prefill span in one flat
+    batch. Pallas needs D % 128 == 0, so the oracle runs on a
+    lane-padded cache too (the production envelope)."""
     from dynamo_tpu.ops.attention import (
         AttnDispatch,
         paged_decode_attention,
@@ -200,37 +178,40 @@ def test_sp_striped_scan_matches_oracle(use_pallas):
     slots = nblocks * bs
     k_cache = jnp.asarray(rng.standard_normal((slots, kvH, D)), jnp.float32)
     v_cache = jnp.asarray(rng.standard_normal((slots, kvH, D)), jnp.float32)
-    B = 3
-    ctx = np.asarray([13, 30, 0], np.int32)
+    # Rows 0-1: decode lanes at contexts 13 and 30; row 2 idle; row 3 a
+    # prefill span extending a 5-token prefix by 8 tokens over lane 1's
+    # pages. Flat batch: 2 decode tokens, then the span, then padding.
     tables = _striped_tables(rng, 2, nblocks, [4, 8, 0], width=8)
-    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+    tables = jnp.asarray(np.concatenate([tables, tables[1:2]]))
+    q_start = np.asarray([12, 29, 0, 5], np.int32)
+    q_len = np.asarray([1, 1, 0, 8], np.int32)
+    row_start = np.asarray([0, 1, 0, 2], np.int32)
+    T = 16
+    token_seq = np.zeros(T, np.int32)
+    token_pos = np.full(T, -1, np.int32)
+    token_seq[:2], token_pos[:2] = [0, 1], [12, 29]
+    token_seq[2:10], token_pos[2:10] = 3, np.arange(5, 13)
+    q = jnp.asarray(rng.standard_normal((T, H, D)), jnp.float32)
 
-    want = paged_decode_attention(
-        q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx), bs
-    )
     disp = AttnDispatch(use_pallas=use_pallas, mesh=mesh, kv_sp=True)
-    got = disp.decode(
-        q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx), bs
+    got = np.asarray(disp.ragged(
+        q, k_cache, v_cache, tables, jnp.asarray(token_seq),
+        jnp.asarray(token_pos), jnp.asarray(q_start), jnp.asarray(q_len),
+        jnp.asarray(q_start + q_len), jnp.asarray(row_start), bs,
+    ))
+    want_d = paged_decode_attention(
+        q[:2], k_cache, v_cache, tables[:2], jnp.asarray([13, 30]), bs
     )
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+        got[:2], np.asarray(want_d), rtol=2e-5, atol=2e-5
     )
-
-    # Prefill: lane extends a 5-token prefix by 8 new tokens.
-    T = 8
-    qp = jnp.asarray(rng.standard_normal((1, T, H, D)), jnp.float32)
-    bt = jnp.asarray(tables[1][None])
-    q_start = jnp.asarray([5])
-    total = jnp.asarray([13])
-    want_p = jax.vmap(
-        lambda qq, b, ps, tl: paged_prefill_attention(
-            qq, k_cache, v_cache, b, ps, tl, bs
-        )
-    )(qp, bt, q_start, total)
-    got_p = disp.prefill(qp, k_cache, v_cache, bt, q_start, total, bs)
+    want_p = paged_prefill_attention(
+        q[2:10], k_cache, v_cache, tables[3], jnp.int32(5), jnp.int32(13), bs
+    )
     np.testing.assert_allclose(
-        np.asarray(got_p), np.asarray(want_p), rtol=2e-5, atol=2e-5
+        got[2:10], np.asarray(want_p), rtol=2e-5, atol=2e-5
     )
+    assert not got[10:].any()  # padding rows stay zero
 
 
 async def test_engine_kv_sp_composes_with_tp():

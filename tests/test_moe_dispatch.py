@@ -13,6 +13,7 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.moe import MoeConfig, init_moe_params, moe_mlp
 from dynamo_tpu.parallel.mesh import build_mesh
+from stepdrive import step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -142,12 +143,12 @@ def test_capacity_dispatch_sharded_matches_single():
         max_num_seqs=2, max_model_len=128,
     )
     prompt = list(range(2, 18))
-    tok = ModelRunner(ecfg, params=params).prefill(
-        prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0)
+    tok = step_token(
+        ModelRunner(ecfg, params=params), prompt, [1, 2, 3, 4]
     )
     mesh = build_mesh({"ep": 2, "tp": 2, "dp": 2})
-    tok2 = ModelRunner(ecfg, params=params, mesh=mesh).prefill(
-        prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0)
+    tok2 = step_token(
+        ModelRunner(ecfg, params=params, mesh=mesh), prompt, [1, 2, 3, 4]
     )
     assert tok == tok2
 

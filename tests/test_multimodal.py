@@ -62,12 +62,13 @@ def test_vision_encoder_shape_and_determinism():
 
 
 def test_runner_mm_prefill_matches_oracle():
-    """Soft-prompt prefill must agree with the no-cache oracle forward with
+    """A soft-prompt prefill span (unified_step(mm=...)) must agree with the no-cache oracle forward with
     the same embedding rows spliced in (greedy first token identical)."""
     import jax.numpy as jnp
 
     from dynamo_tpu.engine.runner import ModelRunner
     from dynamo_tpu.models import llama
+    from stepdrive import step_token
 
     mcfg = ModelConfig.tiny_test()
     ecfg = EngineConfig(
@@ -81,9 +82,7 @@ def test_runner_mm_prefill_matches_oracle():
     seg = rng.standard_normal((8, mcfg.hidden_size)).astype(np.float32)
     off = 5  # embeds replace prompt positions 5..12
 
-    tok = runner.prefill(
-        prompt, [1, 2], 0, (0.0, 0, 1.0), mm_embeds=[(off, seg)]
-    )
+    tok = step_token(runner, prompt, [1, 2], mm=[(off, seg)])
 
     embeds = np.zeros((len(prompt), mcfg.hidden_size), np.float32)
     mask = np.zeros(len(prompt), bool)
@@ -97,7 +96,7 @@ def test_runner_mm_prefill_matches_oracle():
 
     # And differs from the text-only prefill of the same tokens.
     runner2 = ModelRunner(ecfg, rng_seed=0)
-    plain = runner2.prefill(prompt, [1, 2], 0, (0.0, 0, 1.0))
+    plain = step_token(runner2, prompt, [1, 2])
     assert plain == int(
         np.argmax(
             np.asarray(

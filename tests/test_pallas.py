@@ -1,7 +1,9 @@
-"""Pallas kernel numerics: interpret-mode kernels vs the jnp oracles
-(ops/attention.py) over ragged batches, GQA, prefix hits, idle lanes.
-The same kernels compile under Mosaic on real TPU; interpret mode runs the
-identical kernel code path on the CPU backend."""
+"""The paged decode kernel's numerics (kv_sp's striped scan): the
+interpret-mode kernel vs the jnp oracle (ops/attention.py) over ragged
+batches, GQA, idle lanes. The ragged kernel, which serves every span, is
+held to its oracles in test_ragged_attention.py. The same kernels compile
+under Mosaic on real TPU; interpret mode runs the identical kernel code
+path on the CPU backend."""
 
 import numpy as np
 import pytest
@@ -9,14 +11,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.attention import (
-    paged_decode_attention,
-    paged_prefill_attention,
-)
-from dynamo_tpu.ops.pallas import (
-    paged_decode_attention_pallas,
-    paged_prefill_attention_pallas,
-)
+from dynamo_tpu.ops.attention import paged_decode_attention
+from dynamo_tpu.ops.pallas import paged_decode_attention_pallas
 
 BS = 16  # block size
 
@@ -63,38 +59,6 @@ def test_decode_kernel_bf16():
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), rtol=2e-2, atol=2e-2
     )
-
-
-@pytest.mark.parametrize("H,kvH,D", [(8, 8, 64), (8, 2, 64)])
-@pytest.mark.parametrize("q_tile", [8, 128])
-def test_prefill_kernel_matches_oracle(H, kvH, D, q_tile):
-    """Lanes with: no prefix, a prefix hit, padding (T > real tokens), and
-    an idle lane — against the vmapped jnp oracle."""
-    rng = np.random.default_rng(2)
-    N, T, max_blocks, num_blocks = 4, 24, 4, 64
-    q = jnp.asarray(rng.standard_normal((N, T, H, D)), jnp.float32)
-    k_cache, v_cache = _caches(rng, num_blocks, kvH, D)
-    tables = _tables(rng, N, max_blocks, num_blocks)
-    q_start = jnp.asarray([0, 16, 0, 0], jnp.int32)   # lane 1: prefix hit
-    total = jnp.asarray([24, 40, 10, 0], jnp.int32)   # lane 2 padded, 3 idle
-
-    want = jax.vmap(
-        lambda qq, bt, ps, tl: paged_prefill_attention(
-            qq, k_cache, v_cache, bt, ps, tl, BS
-        )
-    )(q, tables, q_start, total)
-    got = paged_prefill_attention_pallas(
-        q, k_cache, v_cache, tables, q_start, total, BS, q_tile=q_tile
-    )
-    # Compare only REAL token rows: the oracle zeroes fully-masked padded
-    # rows, the kernel lets them attend to valid keys (both are discarded
-    # by the engine — only `last` real row feeds logits).
-    for n in range(N):
-        real = int(total[n]) - int(q_start[n])
-        np.testing.assert_allclose(
-            got[n, :real], want[n, :real], rtol=2e-5, atol=2e-5,
-            err_msg=f"lane {n}",
-        )
 
 
 @pytest.mark.anyio
@@ -156,37 +120,8 @@ async def test_engine_end_to_end_pallas_interpret(monkeypatch):
         await engine.stop()
 
 
-def test_prefill_kernel_matches_full_attention_end_to_end():
-    """Scatter K/V into the cache then compare against plain causal
-    attention — the full no-cache oracle."""
-    from dynamo_tpu.ops.attention import full_causal_attention
-
-    rng = np.random.default_rng(3)
-    T, H, kvH, D, num_blocks = 40, 4, 2, 64, 16
-    q = jnp.asarray(rng.standard_normal((T, H, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((T, kvH, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((T, kvH, D)), jnp.float32)
-
-    k_cache = jnp.zeros((num_blocks * BS, kvH, D), jnp.float32)
-    v_cache = jnp.zeros_like(k_cache)
-    blocks = [1, 2, 3]  # 3 blocks cover 40 tokens
-    slots = jnp.asarray(
-        [blocks[t // BS] * BS + t % BS for t in range(T)], jnp.int32
-    )
-    k_cache = k_cache.at[slots].set(k)
-    v_cache = v_cache.at[slots].set(v)
-    table = jnp.asarray([blocks + [0]], jnp.int32)
-
-    want = full_causal_attention(q, k, v)
-    got = paged_prefill_attention_pallas(
-        q[None], k_cache, v_cache, table,
-        jnp.asarray([0], jnp.int32), jnp.asarray([T], jnp.int32), BS,
-    )[0]
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
 def test_kernels_sliding_window_matches_oracle():
-    """window-masked decode + prefill kernels vs the jnp reference."""
+    """The window-masked decode kernel vs the jnp reference."""
     rng = np.random.default_rng(9)
     B, H, kvH, D, max_blocks, num_blocks, W = 3, 8, 2, 128, 4, 64, 10
     q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
@@ -206,18 +141,3 @@ def test_kernels_sliding_window_matches_oracle():
         q, k_cache, v_cache, tables, ctx, BS
     )
     assert np.abs(np.asarray(got[0]) - np.asarray(full[0])).max() > 1e-4
-
-    N, T = 2, 24
-    qp = jnp.asarray(rng.standard_normal((N, T, H, D)), jnp.float32)
-    ptables = _tables(rng, N, max_blocks, num_blocks)
-    q_start = jnp.asarray([0, 16], jnp.int32)
-    total = jnp.asarray([24, 40], jnp.int32)
-    want_p = jax.vmap(
-        lambda qq, bt, ps, tl: paged_prefill_attention(
-            qq, k_cache, v_cache, bt, ps, tl, BS, window=W
-        )
-    )(qp, ptables, q_start, total)
-    got_p = paged_prefill_attention_pallas(
-        qp, k_cache, v_cache, ptables, q_start, total, BS, window=W
-    )
-    np.testing.assert_allclose(got_p, want_p, rtol=2e-5, atol=2e-5)

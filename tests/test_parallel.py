@@ -17,6 +17,7 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.parallel.mesh import MESH_AXES, build_mesh
 from dynamo_tpu.parallel.sharding import shard_params
 from dynamo_tpu.parallel.train import make_train_step
+from stepdrive import greedy_tokens
 
 
 def test_build_mesh_defaults_to_tp():
@@ -46,25 +47,7 @@ def test_sharded_decode_matches_single_device():
 
     def run(mesh):
         runner = ModelRunner(ecfg, mesh=mesh, rng_seed=0)
-        toks = [runner.prefill(prompt, [1], 0, (0.0, 0, 1.0))]
-        n = len(prompt)
-        for _ in range(4):
-            B = ecfg.max_num_seqs
-            table = np.zeros((B, ecfg.max_blocks_per_seq), np.int32)
-            table[0, 0] = 1
-            out = runner.decode(
-                np.array([toks[-1]] + [0] * (B - 1), np.int32),
-                np.array([n] + [0] * (B - 1), np.int32),
-                table,
-                np.array([n + 1] + [0] * (B - 1), np.int32),
-                np.array([16 + n] + [0] * (B - 1), np.int32),
-                np.zeros(B, np.float32),
-                np.zeros(B, np.int32),
-                np.ones(B, np.float32),
-            )
-            toks.append(int(out[0]))
-            n += 1
-        return toks
+        return greedy_tokens(runner, prompt, [1], 4)
 
     single = run(None)
     sharded = run(build_mesh({"dp": 2, "tp": 2, "sp": 2}))
@@ -119,22 +102,7 @@ def test_sharded_pallas_decode_matches_single_device_jnp(monkeypatch):
         assert runner.attn.use_pallas is pallas
         if pallas and mesh is not None:
             assert runner.attn.mesh is mesh  # shard_map path, not fallback
-        toks = [runner.prefill(prompt, [1], 0, (0.0, 0, 1.0))]
-        n = len(prompt)
-        B = ecfg.max_num_seqs
-        table = np.zeros((B, ecfg.max_blocks_per_seq), np.int32)
-        table[0, :4] = [1, 2, 3, 4]
-        out = runner.decode_multi(
-            np.array([toks[-1]] + [0] * (B - 1), np.int32),
-            np.array([n] + [0] * (B - 1), np.int32),
-            table,
-            np.array([n + 1] + [0] * (B - 1), np.int32),
-            np.zeros(B, np.float32),
-            np.zeros(B, np.int32),
-            np.ones(B, np.float32),
-            4,
-        )
-        return toks + [int(t) for t in out[:, 0]]
+        return greedy_tokens(runner, prompt, [1, 2, 3, 4], 4)
 
     baseline = run(None, pallas=False)
     assert run(build_mesh({"dp": 4, "tp": 2}), pallas=True) == baseline
@@ -191,52 +159,6 @@ def test_moe_ep_sharded_matches_replicated():
     np.testing.assert_allclose(gates.sum(axis=-1), 1.0, rtol=1e-5)
 
 
-def test_sequence_parallel_prefill_matches_single_device(monkeypatch):
-    """sp-sharded prefill (each shard's query tile vs full KV, Pallas under
-    shard_map with per-shard q_start offsets) must produce the same first
-    token and decode continuation as a single chip — the long-context
-    sequence-parallel path SURVEY §5 requires natively."""
-    cfg = ModelConfig.tiny_test()
-    ecfg = EngineConfig(
-        model=cfg, num_blocks=64, max_num_seqs=4, max_model_len=128,
-        dtype="float32",
-    )
-    prompt = list(range(1, 49))  # 48 tokens -> bucket 64, sp=4 divides
-
-    def run(mesh, pallas: bool):
-        monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1" if pallas else "0")
-        runner = ModelRunner(ecfg, mesh=mesh, rng_seed=0)
-        blocks = [1, 2, 3, 4]
-        first = runner.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
-        B = ecfg.max_num_seqs
-        table = np.zeros((B, ecfg.max_blocks_per_seq), np.int32)
-        table[0, : len(blocks)] = blocks
-        n = len(prompt)
-        out = runner.decode_multi(
-            np.array([first] + [0] * (B - 1), np.int32),
-            np.array([n] + [0] * (B - 1), np.int32),
-            table,
-            np.array([n + 1] + [0] * (B - 1), np.int32),
-            np.zeros(B, np.float32),
-            np.zeros(B, np.int32),
-            np.ones(B, np.float32),
-            4,
-        )
-        return [first] + [int(t) for t in out[:, 0]]
-
-    baseline = run(None, pallas=False)
-    assert run(build_mesh({"sp": 4, "tp": 2}), pallas=True) == baseline
-    # batched-prefill lanes under sp too
-    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
-    runner = ModelRunner(ecfg, mesh=build_mesh({"sp": 4, "tp": 2}), rng_seed=0)
-    lanes = [
-        (prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0)),
-        (prompt[:20], [5, 6], 0, (0.0, 0, 1.0)),
-    ]
-    toks = runner.prefill_batch(lanes)
-    assert toks[0] == baseline[0]
-
-
 def test_moe_model_ep_sharded_serving_matches_single_device(monkeypatch):
     """Mixtral-style MoE model under an ep×tp mesh: expert-parallel routed
     MLPs in the serving prefill/decode path must produce tokens identical
@@ -251,23 +173,7 @@ def test_moe_model_ep_sharded_serving_matches_single_device(monkeypatch):
 
     def run(mesh):
         runner = ModelRunner(ecfg, mesh=mesh, rng_seed=1)
-        blocks = [1, 2, 3]
-        first = runner.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
-        B = ecfg.max_num_seqs
-        table = np.zeros((B, ecfg.max_blocks_per_seq), np.int32)
-        table[0, : len(blocks)] = blocks
-        n = len(prompt)
-        out = runner.decode_multi(
-            np.array([first] + [0] * (B - 1), np.int32),
-            np.array([n] + [0] * (B - 1), np.int32),
-            table,
-            np.array([n + 1] + [0] * (B - 1), np.int32),
-            np.zeros(B, np.float32),
-            np.zeros(B, np.int32),
-            np.ones(B, np.float32),
-            8,
-        )
-        return [first] + [int(t) for t in out[:, 0]]
+        return greedy_tokens(runner, prompt, [1, 2, 3], 8)
 
     baseline = run(None)
     assert run(build_mesh({"ep": 2, "tp": 2, "dp": 2})) == baseline
@@ -300,7 +206,8 @@ def test_ring_attention_matches_full_causal():
 
 def test_llama70b_kv_sp_tp_sharded_step_lowers():
     """Scale proof at the compile-shape level (BASELINE.md steps 4-5):
-    the REAL Llama-3-70B config's decode step traces and lowers under a
+    the REAL Llama-3-70B config's step (every lane a one-token span of
+    the served llama.unified) traces and lowers under a
     {tp: 4, sp: 2} mesh with the kv_sp slot+head-sharded cache —
     abstract params only (280 GB of weights never materialize), so this
     validates shape/divisibility/sharding-spec consistency for the
@@ -341,8 +248,10 @@ def test_llama70b_kv_sp_tp_sharded_step_lowers():
     attn = AttnDispatch(use_pallas=False, mesh=mesh, kv_sp=True)
 
     def step(params, kv, toks, pos, tables, ctx, slots):
-        return llama.decode(
-            cfg, params, kv, toks, pos, tables, ctx, slots, bs, attn=attn
+        lanes = jnp.arange(B, dtype=jnp.int32)
+        return llama.unified(
+            cfg, params, kv, toks, pos, slots, lanes, tables, pos,
+            jnp.ones_like(lanes), ctx, lanes, bs, attn=attn,
         )
 
     lowered = jax.jit(step).lower(
@@ -367,7 +276,7 @@ def test_stepcast_replays_every_block_io_form():
     from dynamo_tpu.parallel.stepcast import REPLAYED
 
     for name in (
-        "prefill", "prefill_batch", "decode_multi",
+        "warmup", "unified_step",
         "gather_block", "scatter_block",
         "gather_many", "gather_many_device",
         "scatter_many", "scatter_many_device",

@@ -37,6 +37,7 @@ from dynamo_tpu.ops.quant import (
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.sharding import llama_param_specs
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -134,10 +135,10 @@ def test_sharded_quantized_prefill_matches_single_chip():
     blocks = [1, 2, 3, 4]
     prompt = list(range(2, 18))
     single = ModelRunner(ecfg)
-    tok_single = single.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok_single = step_token(single, prompt, blocks)
     mesh = build_mesh({"tp": 2, "dp": 4})
     sharded = ModelRunner(ecfg, mesh=mesh)
-    tok_sharded = sharded.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok_sharded = step_token(sharded, prompt, blocks)
     assert tok_single == tok_sharded
 
 
@@ -178,12 +179,12 @@ def test_tied_embed_quantization_roundtrip():
         max_num_seqs=2, max_model_len=128, quant="int8",
     )
     prompt = list(range(2, 18))
-    tok_single = ModelRunner(ecfg, params=tparams).prefill(
-        prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0)
+    tok_single = step_token(
+        ModelRunner(ecfg, params=tparams), prompt, [1, 2, 3, 4]
     )
     mesh = build_mesh({"tp": 2, "dp": 4})
-    tok_sharded = ModelRunner(ecfg, params=tparams, mesh=mesh).prefill(
-        prompt, [1, 2, 3, 4], 0, (0.0, 0, 1.0)
+    tok_sharded = step_token(
+        ModelRunner(ecfg, params=tparams, mesh=mesh), prompt, [1, 2, 3, 4]
     )
     assert tok_single == tok_sharded
 

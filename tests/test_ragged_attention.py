@@ -1,6 +1,6 @@
 """Ragged unified attention: the Pallas kernel (interpret mode) and the
-jnp twin (ops/attention.py ragged_paged_attention) against the phase-split
-oracles, over mixed prefill+decode batches, GQA, bf16, sliding windows,
+jnp twin (ops/attention.py ragged_paged_attention) against the per-phase
+jnp oracles (paged decode, paged prefill, full causal), over mixed prefill+decode batches, GQA, bf16, sliding windows,
 prefix hits, and idle metadata rows. The same kernel compiles under
 Mosaic on real TPU; interpret mode runs the identical code path on CPU."""
 
@@ -106,13 +106,14 @@ def test_decode_only_matches_decode_oracle():
     )
 
 
-@pytest.mark.parametrize("q_tile", [8, 32])
-def test_prefill_only_matches_prefill_oracle(q_tile):
+@pytest.mark.parametrize("H,kvH", [(8, 8), (8, 2)])
+@pytest.mark.parametrize("q_tile", [8, 32, 128])
+def test_prefill_only_matches_prefill_oracle(H, kvH, q_tile):
     """Prefill-only unified batches (incl. a prefix hit) against the
-    per-lane prefill oracle, across tile widths (full tiles + ragged
-    tails)."""
+    per-lane prefill oracle, across head geometries (MHA, GQA) and tile
+    widths (full tiles + ragged tails; 128 is wider than any span)."""
     rng = np.random.default_rng(2)
-    H, kvH, D = 8, 2, 128
+    D = 128
     k, v = _caches(rng, 64, kvH, D)
     tables = _tables(rng, 2, 4, 64)
     spans = [(0, 24), (16, 13)]  # span 1 extends a 16-token prefix
@@ -129,6 +130,60 @@ def test_prefill_only_matches_prefill_oracle(q_tile):
     )
     np.testing.assert_allclose(got[:24], np.asarray(o0), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(got[24:37], np.asarray(o1), rtol=2e-5, atol=2e-5)
+
+
+def test_prefill_span_matches_full_causal_attention_end_to_end():
+    """Scatter K/V into the paged cache, then hold a whole-prompt prefill
+    span (beside a decode lane of another sequence) to plain causal
+    attention — the no-cache oracle, with no paged code on its side."""
+    from dynamo_tpu.ops.attention import full_causal_attention
+
+    rng = np.random.default_rng(3)
+    T, H, kvH, D, num_blocks = 40, 4, 2, 128, 16
+    kx = jnp.asarray(rng.standard_normal((T, kvH, D)), jnp.float32)
+    vx = jnp.asarray(rng.standard_normal((T, kvH, D)), jnp.float32)
+    k, v = _caches(rng, num_blocks, kvH, D)  # other sequences' pages
+    blocks = [1, 2, 3]  # 3 blocks cover 40 tokens
+    slots = jnp.asarray(
+        [blocks[t // BS] * BS + t % BS for t in range(T)], jnp.int32
+    )
+    k, v = k.at[slots].set(kx), v.at[slots].set(vx)
+    tables = jnp.asarray([[7, 8, 0, 0], blocks + [0]], jnp.int32)
+    spans = [(20, 1), (0, T)]
+    q, qs, ql, kv_len, rs, tseq, tpos = _flat_batch(rng, spans, 48, H, D)
+    want_twin, got = _both(q, k, v, tables, qs, ql, kv_len, rs, tseq, tpos)
+    want = np.asarray(full_causal_attention(q[1 : 1 + T], kx, vx))
+    np.testing.assert_allclose(got[1 : 1 + T], want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        want_twin[1 : 1 + T], want, rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("window", [10, 24])
+def test_windowed_prefill_span_matches_prefill_oracle(window):
+    """Sliding-window prefill spans (one from position 0, one extending a
+    16-token prefix past the window) against the windowed per-lane
+    prefill oracle; the window changes the answer."""
+    rng = np.random.default_rng(9)
+    H, kvH, D = 8, 2, 128
+    k, v = _caches(rng, 64, kvH, D)
+    tables = _tables(rng, 2, 4, 64)
+    spans = [(0, 24), (16, 24)]
+    q, qs, ql, kv_len, rs, tseq, tpos = _flat_batch(rng, spans, 48, H, D)
+    _, got = _both(
+        q, k, v, tables, qs, ql, kv_len, rs, tseq, tpos, window=window
+    )
+    for lane, (start, n, row) in enumerate([(0, 24, 0), (16, 24, 24)]):
+        want = paged_prefill_attention(
+            q[row : row + n], k, v, tables[lane], jnp.int32(start),
+            jnp.int32(start + n), BS, window=window,
+        )
+        np.testing.assert_allclose(
+            got[row : row + n], np.asarray(want), rtol=2e-5, atol=2e-5,
+            err_msg=f"lane {lane}",
+        )
+    _, full = _both(q, k, v, tables, qs, ql, kv_len, rs, tseq, tpos)
+    assert np.abs(got[24:48] - full[24:48]).max() > 1e-4
 
 
 def test_bf16_mixed_batch():

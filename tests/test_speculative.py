@@ -1,4 +1,4 @@
-"""Prompt-lookup speculative decoding (engine/runner.py decode_multi_spec):
+"""Prompt-lookup speculative decoding (engine/runner.py unified_spec_fn):
 greedy output must be EXACTLY the sequential greedy output (same model,
 same cache — acceptance only keeps drafts the verify pass would have
 produced anyway), sampled lanes must degrade to plain decode, and
@@ -6,8 +6,9 @@ acceptance must actually exceed 1 token/step on repetitive text.
 
 The reference has no native engine to put this in (it delegates decode to
 vLLM, which ships the same technique as "prompt lookup / n-gram
-speculation") — here it is a first-class scan on device: drafts come from
-a device-resident history buffer, so no host round trip per step.
+speculation") — here draft and verify run inside the one ragged step:
+drafts come from a device-resident history buffer, so no host round trip
+per step.
 """
 
 import asyncio
@@ -44,7 +45,6 @@ def _cfg(**kw) -> EngineConfig:
         num_blocks=128,
         max_num_seqs=4,
         max_model_len=128,
-        decode_chunk=4,
         speculative_k=3,
     )
     defaults.update(kw)
@@ -96,7 +96,7 @@ async def test_speculative_accepts_on_cyclic_continuation():
         engine = TpuEngine(
             EngineConfig(
                 model=cfg0, dtype="float32", block_size=4, num_blocks=128,
-                max_num_seqs=2, max_model_len=128, decode_chunk=4,
+                max_num_seqs=2, max_model_len=128,
                 speculative_k=spec_k,
             ),
             params=params0,
@@ -330,7 +330,7 @@ async def test_spec_gate_is_free_when_losing_mocker_ab():
     speculation, plain decode must pay ~0% overhead — each RE-probe runs
     only speculative_probe_window spec steps (not a full measurement
     window), so the steady-state loss is probe_window/probe_steps. The
-    mocker's decode_multi_spec never accepts drafts (1.0 tok/step, a
+    mocker's unified_step never accepts drafts (1.0 tok/step, a
     guaranteed loss) and charges the verify width per step — the exact
     regime the gate must make free. A/B'd against a plain mocker engine
     on the same workload (the BENCH_SPEC_AB path, mocker mode)."""
@@ -343,7 +343,6 @@ async def test_spec_gate_is_free_when_losing_mocker_ab():
             num_blocks=128,
             max_num_seqs=2,
             max_model_len=512,
-            decode_chunk=4,
         )
         defaults.update(kw)
         return EngineConfig(**defaults)
@@ -351,9 +350,6 @@ async def test_spec_gate_is_free_when_losing_mocker_ab():
     window, probe_window, probe_steps = 8, 2, 32
     spec = MockerEngine(
         mocker_cfg(
-            # decode_chunk == probe_window: a spec chunk is the probe's
-            # quantum, so each re-probe costs exactly probe_window steps.
-            decode_chunk=2,
             speculative_k=3,
             speculative_window=window,
             speculative_probe_window=probe_window,

@@ -1,7 +1,7 @@
 """Unified single-dispatch serving (docs/architecture/unified_step.md):
 token-budget batch composition, the budget-ladder warmup contract, the
-runner's device feed, and end-to-end token parity against the
-phase-alternating path."""
+runner's device feed and its one step-program family, and end-to-end
+token parity against the no-cache oracle."""
 
 import asyncio
 
@@ -48,7 +48,7 @@ def test_unified_shape_grid_is_budget_ladder_only():
     holds with speculation enabled: the spec program IS the ladder."""
     cfg = EngineConfig(
         model=ModelConfig.tiny_test(), num_blocks=64, max_model_len=256,
-        unified=True, unified_token_budget=256, sampling_extras=False,
+        unified_token_budget=256, sampling_extras=False,
     )
     specs = default_shape_grid(cfg)
     assert specs == [("unified", b, 0, 0, 0) for b in (16, 32, 64, 128, 256)]
@@ -75,11 +75,10 @@ def test_unified_shape_grid_is_budget_ladder_only():
 
 def test_config_validation_one_path():
     base = dict(model=ModelConfig.tiny_test(), num_blocks=64,
-                max_model_len=256, unified=True)
+                max_model_len=256)
     for bad in (
         dict(unified_token_budget=8),
         dict(unified_prefill_quantum=0),
-        dict(unified=False),          # the phased path is GONE
         dict(speculative_k=16, unified_token_budget=16),  # span > half
     ):
         with pytest.raises(ValueError):
@@ -168,11 +167,11 @@ def test_compose_budget_exhaustion_stops_packing():
 # ---------------------------------------------------------------------------
 
 
-def _engine_cfg(unified: bool, **kw) -> EngineConfig:
+def _engine_cfg(**kw) -> EngineConfig:
     return EngineConfig(
         model=ModelConfig.tiny_test(), num_blocks=64, max_num_seqs=4,
-        max_model_len=96, prefill_chunk=32, dtype="float32",
-        unified=unified, unified_token_budget=64,
+        max_model_len=96, dtype="float32",
+        unified_token_budget=64,
         unified_prefill_quantum=32, sampling_extras=False, **kw,
     )
 
@@ -185,7 +184,7 @@ async def test_mocker_unified_warmup_and_zero_midtraffic_compiles():
 
     cfg = EngineConfig(
         model=ModelConfig.tiny_test(), num_blocks=64, max_num_seqs=4,
-        max_model_len=128, prefill_chunk=64, unified=True,
+        max_model_len=128,
         unified_token_budget=64, unified_prefill_quantum=16,
     )
     eng = MockerEngine(cfg, MockerConfig())
@@ -236,7 +235,7 @@ async def test_unified_remote_prefill_uses_budget_programs_only():
 
     cfg = EngineConfig(
         model=ModelConfig.tiny_test(), num_blocks=64, max_num_seqs=4,
-        max_model_len=128, prefill_chunk=64, unified=True,
+        max_model_len=128,
         unified_token_budget=64, unified_prefill_quantum=16,
     )
     eng = MockerEngine(cfg, MockerConfig())
@@ -274,7 +273,7 @@ async def test_unified_rejects_extras_only_when_disabled():
 
     cfg = EngineConfig(
         model=ModelConfig.tiny_test(), num_blocks=64, max_num_seqs=4,
-        max_model_len=128, unified=True, sampling_extras=False,
+        max_model_len=128, sampling_extras=False,
     )
     eng = MockerEngine(cfg, MockerConfig())
     await eng.start()
@@ -298,7 +297,7 @@ async def test_engine_spec_greedy_streams_byte_identical():
     from dynamo_tpu.engine.engine import TpuEngine
 
     async def run(spec_k: int) -> list[list[int]]:
-        eng = TpuEngine(_engine_cfg(True, speculative_k=spec_k))
+        eng = TpuEngine(_engine_cfg(speculative_k=spec_k))
         await eng.start()
         rng = np.random.default_rng(0)
         prompts = [
@@ -331,7 +330,7 @@ async def test_engine_unified_mixed_concurrency_and_prefix_cache():
     takes the prefix-cache hit path through the unified step."""
     from dynamo_tpu.engine.engine import TpuEngine
 
-    eng = TpuEngine(_engine_cfg(True))
+    eng = TpuEngine(_engine_cfg())
     await eng.start()
     rng = np.random.default_rng(1)
     base = rng.integers(0, 500, 48).tolist()
@@ -357,3 +356,66 @@ async def test_engine_unified_mixed_concurrency_and_prefix_cache():
     assert again == first[0]
     assert eng.prefix_hit_rate > 0
     await eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# one step program: what a runner builds, and what is asked of it
+# ---------------------------------------------------------------------------
+
+
+def _tiny_runner():
+    from dynamo_tpu.engine.runner import ModelRunner
+
+    return ModelRunner(
+        EngineConfig(
+            model=ModelConfig.tiny_test(), num_blocks=16, max_num_seqs=2,
+            max_model_len=32, dtype="float32",
+        )
+    )
+
+
+def test_runner_builds_only_the_unified_programs():
+    """A built ModelRunner holds exactly the unified family's jitted
+    callables (plus the resident zero feed one small jit produced) — no
+    phase-split prefill/decode program comes back unnoticed — and no
+    public step entry beside unified_step."""
+    import jax
+
+    runner = _tiny_runner()
+    jit_type = type(jax.jit(lambda: 0))
+    jitted = {k for k, v in vars(runner).items() if isinstance(v, jit_type)}
+    assert jitted == {"_unified", "_unified_full", "_unified_mm"}
+    assert isinstance(runner._zero_prev, jax.Array)
+    assert runner._zero_prev.shape == (runner.unified_slots,)
+    for gone in ("prefill", "prefill_batch", "decode", "decode_multi",
+                 "last_logprobs"):
+        assert not hasattr(runner, gone), gone
+
+
+def test_engine_and_stepcast_name_only_methods_the_runner_has():
+    """Every ``self.runner.<name>`` the engine touches and every method
+    stepcast replays exists on a built ModelRunner."""
+    import ast
+    import inspect
+
+    from dynamo_tpu.engine import engine as engine_mod
+    from dynamo_tpu.mocker.engine import _SimRunner
+    from dynamo_tpu.parallel.stepcast import REPLAYED
+
+    named = {
+        node.attr
+        for node in ast.walk(ast.parse(inspect.getsource(engine_mod)))
+        if isinstance(node, ast.Attribute)
+        and isinstance(node.value, ast.Attribute)
+        and node.value.attr == "runner"
+        and isinstance(node.value.value, ast.Name)
+        and node.value.value.id == "self"
+    }
+    assert "unified_step" in named and len(named) > 5
+    runner = _tiny_runner()
+    missing = sorted(n for n in named | set(REPLAYED) if not hasattr(runner, n))
+    assert not missing, missing
+    # The mocker's double has the one step entry and none of the gone.
+    assert hasattr(_SimRunner, "unified_step")
+    for gone in ("prefill", "prefill_batch", "decode", "decode_multi"):
+        assert not hasattr(_SimRunner, gone), gone

@@ -47,6 +47,7 @@ from dynamo_tpu.ops.quant import (
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.sharding import llama_param_specs
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -179,7 +180,7 @@ async def test_unified_engine_matches_policy_oracle(spec):
     cfg = EngineConfig(
         model=CFG, dtype="float32", block_size=4, num_blocks=64,
         max_num_seqs=4, max_model_len=128, weight_quant=spec,
-        unified=True, unified_token_budget=64, unified_prefill_quantum=16,
+        unified_token_budget=64, unified_prefill_quantum=16,
         sampling_extras=False,
     )
     engine = TpuEngine(cfg, params=jax.tree.map(jnp.copy, PARAMS))
@@ -256,10 +257,10 @@ def test_sharded_policy_engine_matches_single_chip():
     blocks = [1, 2, 3, 4]
     prompt = list(range(2, 18))
     single = ModelRunner(ecfg)
-    tok_single = single.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok_single = step_token(single, prompt, blocks)
     mesh = build_mesh({"tp": 2, "dp": 4})
     sharded = ModelRunner(ecfg, mesh=mesh)
-    tok_sharded = sharded.prefill(prompt, blocks, 0, (0.0, 0, 1.0))
+    tok_sharded = step_token(sharded, prompt, blocks)
     assert tok_single == tok_sharded
 
 
@@ -345,7 +346,7 @@ def _greedy_quality(n_prompts, osl, threshold):
         cfg = EngineConfig(
             model=ModelConfig.tiny_test(), dtype="float32", num_blocks=64,
             max_num_seqs=4, max_model_len=128, prefill_batch=2,
-            unified=True, unified_token_budget=64,
+            unified_token_budget=64,
             unified_prefill_quantum=16, sampling_extras=False,
             weight_quant=weight_quant,
         )
@@ -402,7 +403,7 @@ def test_weight_quant_composes_with_kv_quant():
     async def run():
         cfg = EngineConfig(
             model=ModelConfig.tiny_test(), dtype="float32", num_blocks=64,
-            max_num_seqs=2, max_model_len=128, unified=True,
+            max_num_seqs=2, max_model_len=128,
             unified_token_budget=64, unified_prefill_quantum=16,
             sampling_extras=False, weight_quant="int8", kv_quant="int8",
         )
@@ -427,14 +428,11 @@ def test_weight_quant_composes_with_kv_quant():
 
 
 def test_weight_quant_config_validation():
-    # Unified is the default path, so a bare policy validates...
+    # A bare policy validates...
     EngineConfig(model=CFG, weight_quant="int8").validate()
     EngineConfig(model=CFG, weight_quant="attn=int8,mlp=fp8").validate()
     # ...composes with kv_quant...
     EngineConfig(model=CFG, weight_quant="int8", kv_quant="int8").validate()
-    # ...rejects the phased engine, naming the conflicting pair...
-    with pytest.raises(ValueError, match="--weight-quant \\+ unified"):
-        EngineConfig(model=CFG, weight_quant="int8", unified=False).validate()
     # ...rejects stacking on the legacy whole-tree quant...
     with pytest.raises(ValueError, match="--quant \\+ --weight-quant"):
         EngineConfig(model=CFG, weight_quant="int8", quant="int8").validate()
@@ -446,8 +444,6 @@ def test_weight_quant_config_validation():
 
 
 def test_kv_quant_conflict_messages_name_flag_pairs():
-    with pytest.raises(ValueError, match="--kv-quant \\+ unified"):
-        EngineConfig(model=CFG, kv_quant="int8", unified=False).validate()
     with pytest.raises(ValueError, match="--kv-quant \\+ --kv-sp"):
         EngineConfig(
             model=CFG, kv_quant="int8", kv_sp=True,
