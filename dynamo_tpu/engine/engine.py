@@ -1715,6 +1715,9 @@ class TpuEngine:
             lanes=lanes,
             drafted=drafted,
             accepted=accepted,
+            # The runner's last dispatch IS this record's: plain records
+            # are noted at issue, spec records at retire under depth 1.
+            operand_transfers=getattr(self.runner, "operand_transfers", 0),
             inflight_depth=len(self._inflight),
             waiting=len(sched.waiting) if sched is not None else 0,
             running=len(sched.running) if sched is not None else 0,
@@ -2342,6 +2345,9 @@ class TpuEngine:
             m["unified_step_tokens_prefill_total"] = (
                 self._unified_prefill_tokens
             )
+            m["unified_operand_transfers_total"] = getattr(
+                self.runner, "operand_transfers_total", 0
+            )
             m["batch_fill_ratio"] = round(self._unified_fill_ratio, 4)
             # Co-location controller surface (engine/coloc.py):
             # quantum, ITL estimates vs the SLO, violation and
@@ -2592,6 +2598,9 @@ class TpuEngine:
         )
         d["unified_step_tokens_prefill_total"] = (
             self._unified_prefill_tokens
+        )
+        d["unified_operand_transfers_total"] = getattr(
+            self.runner, "operand_transfers_total", 0
         )
         d["batch_fill_ratio"] = round(self._unified_fill_ratio, 4)
         d.update(self.coloc.snapshot())

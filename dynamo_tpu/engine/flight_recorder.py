@@ -73,6 +73,7 @@ class FlightRecorder:
         headroom_ms: float = 0.0,
         drafted: int = 0,
         accepted: int = 0,
+        operand_transfers: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -82,7 +83,10 @@ class FlightRecorder:
         decision that caused it. ``kind="spec"`` records (unified
         draft-verify dispatches) carry the drafted/accepted token
         split — the per-step acceptance evidence next to the cumulative
-        spec counters on the metric surfaces."""
+        spec counters on the metric surfaces. ``operand_transfers`` is
+        the host arrays the runner handed to the device for the
+        dispatch: one packed buffer (engine/runner.py operand_layout),
+        plus a replayed host feed or the multimodal rows."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -93,6 +97,7 @@ class FlightRecorder:
             "lanes": lanes,
             "drafted": drafted,
             "accepted": accepted,
+            "operand_transfers": operand_transfers,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

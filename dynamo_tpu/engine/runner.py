@@ -32,7 +32,8 @@ all dispatch through it, and the runner builds no other step program.
 from __future__ import annotations
 
 import logging
-from functools import partial
+from functools import lru_cache, partial
+from itertools import chain
 from typing import Any, NamedTuple
 
 import jax
@@ -79,6 +80,129 @@ def _norm_sampling(sampling) -> tuple[float, int, float, int]:
         return t, k, p, -1
     return tuple(sampling)
 
+
+#: The nine metadata operands of ``llama.unified``, in its order.
+META_SEGMENTS = (
+    "token_ids", "token_pos", "slot_mapping", "token_seq", "block_tables",
+    "q_start", "q_len", "kv_len", "row_start",
+)
+
+
+class OperandLayout(NamedTuple):
+    """Where each operand of one unified dispatch lies in the ONE packed
+    int32 buffer the host transfers for it (docs/architecture/
+    unified_step.md "The operand buffer"). ``segs`` maps a name to
+    (first word, end word, shape, dtype): float32 and uint32 rows ride
+    as bit-exact views of the int32 words, bools as 0/1. ``template``
+    holds every segment's padding value; a dispatch starts from a copy
+    of it."""
+
+    size: int
+    segs: dict
+    template: np.ndarray
+
+    def views(self, buf: np.ndarray) -> dict:
+        """Host side: each segment as a writable view of ``buf``."""
+        return {
+            name: buf[lo:hi]
+            .view(np.int32 if dtype is bool else dtype)
+            .reshape(shape)
+            for name, (lo, hi, shape, dtype) in self.segs.items()
+        }
+
+    def unpack(self, packed) -> dict:
+        """Program side: each segment as a static slice of ``packed``."""
+        out = {}
+        for name, (lo, hi, shape, dtype) in self.segs.items():
+            x = packed[lo:hi].reshape(shape)
+            if dtype is bool:
+                x = x != 0
+            elif dtype is not np.int32:
+                x = jax.lax.bitcast_convert_type(x, dtype)
+            out[name] = x
+        return out
+
+
+@lru_cache(maxsize=None)
+def operand_layout(
+    T: int, S: int, max_blocks_per_seq: int, speculative_k: int, variant: str
+) -> OperandLayout:
+    """THE source of the packed buffer's offsets, for a dispatch of
+    budget ``T`` over ``S`` metadata rows. ``variant``: "plain" (the
+    budget ladder), "spec" (the ladder of a speculative engine: adds
+    ``drafts``/``draft_len``) or "extras" (the penalties/logprob and
+    multimodal programs: adds the count-buffer rows)."""
+    rows = [
+        ("token_ids", (T,), np.int32, 0),
+        ("token_pos", (T,), np.int32, -1),      # -1 = padding row
+        ("slot_mapping", (T,), np.int32, 0),    # padding -> trash block 0
+        ("token_seq", (T,), np.int32, 0),
+        ("block_tables", (S, max_blocks_per_seq), np.int32, 0),
+        ("q_start", (S,), np.int32, 0),
+        ("q_len", (S,), np.int32, 0),
+        ("kv_len", (S,), np.int32, 0),
+        ("row_start", (S,), np.int32, 0),
+        ("use_prev", (S,), bool, 0),
+        ("prev_row", (S,), np.int32, 0),
+        ("top_k", (S,), np.int32, 0),
+        ("seed", (S,), np.int32, -1),           # -1 = unseeded
+        ("temp", (S,), np.float32, 0.0),
+        ("top_p", (S,), np.float32, 1.0),
+        ("key", (2,), np.uint32, 0),
+    ]
+    if variant == "spec":
+        rows += [
+            ("drafts", (S, speculative_k), np.int32, 0),
+            ("draft_len", (S,), np.int32, 0),
+        ]
+    elif variant == "extras":
+        rows += [
+            ("span_slot", (S,), np.int32, -1),
+            ("counts_add", (S,), bool, 0),
+            ("reset", (S,), bool, 0),
+            ("freq", (S,), np.float32, 0.0),
+            ("pres", (S,), np.float32, 0.0),
+        ]
+    else:
+        assert variant == "plain", variant
+    segs, off = {}, 0
+    for name, shape, dtype, _fill in rows:
+        end = off + int(np.prod(shape))
+        segs[name] = (off, end, shape, dtype)
+        off = end
+    template = np.zeros(off, np.int32)
+    lay = OperandLayout(off, segs, template)
+    views = lay.views(template)
+    for name, _shape, _dtype, fill in rows:
+        if fill:
+            views[name][...] = fill
+    template.flags.writeable = False
+    return lay
+
+
+def operand_layout_of(
+    size: int, S: int, max_blocks_per_seq: int, speculative_k: int,
+    variant: str,
+) -> OperandLayout:
+    """The layout of a packed buffer of ``size`` words: the budget is
+    the one extent the program cannot read off its configuration."""
+    rest = (S, max_blocks_per_seq, speculative_k, variant)
+    fixed = operand_layout(0, *rest).size
+    per_token = operand_layout(1, *rest).size - fixed
+    lay = operand_layout((size - fixed) // per_token, *rest)
+    assert lay.size == size, (size, lay.size)
+    return lay
+
+
+class _Operands(NamedTuple):
+    """One dispatch's packed operands on the host: the buffer, its
+    segment views, the fed tokens (a device array) and how many host
+    arrays placing that feed took (0 or 1)."""
+
+    buf: np.ndarray
+    seg: dict
+    prev_toks: Any
+    feed_transfers: int
 
 
 def _unified_warm_lanes(
@@ -429,12 +553,25 @@ class ModelRunner(WarmupPlanMixin):
             rows = jnp.where(use_prev, row_start, T)
             return token_ids.at[rows].set(prev_toks[prev_row], mode="drop")
 
-        def unified_fn(
-            params, kv, kv_sc, token_ids, token_pos, slot_mapping,
-            token_seq, block_tables, q_start, q_len, kv_len, row_start,
-            use_prev, prev_row, prev_toks, temp, top_k, top_p, seed, key,
-        ):
+        S_rows = self.unified_slots
+        MB = cfg.max_blocks_per_seq
+
+        def _unpack(packed, variant, prev_toks):
+            """The packed buffer's segments by the layout's static
+            offsets, the fed tokens substituted, and ``llama.unified``'s
+            nine metadata operands in its order."""
+            o = operand_layout_of(
+                packed.shape[0], S_rows, MB, K_spec, variant
+            ).unpack(packed)
+            o["token_ids"] = _feed_tokens(
+                o["token_ids"], o["row_start"], o["use_prev"],
+                o["prev_row"], prev_toks,
+            )
+            return o, [o[name] for name in META_SEGMENTS]
+
+        def unified_fn(params, kv, kv_sc, packed, prev_toks):
             """One ragged mixed prefill+decode dispatch (llama.unified).
+            ``packed`` is the dispatch's ONE host operand (operand_layout).
             Decode spans can feed from the PREVIOUS unified dispatch's
             device-resident tokens (`use_prev`/`prev_row` map each span
             to its old metadata row), so steady-state decode never pays a
@@ -442,28 +579,19 @@ class ModelRunner(WarmupPlanMixin):
             KV scale state under kv_quant (None otherwise) — it rides
             the dispatch like the caches do, so steady-state decode pays
             no extra host traffic for quantization either."""
-            token_ids = _feed_tokens(
-                token_ids, row_start, use_prev, prev_row, prev_toks
-            )
+            o, meta = _unpack(packed, "plain", prev_toks)
             out = llama.unified(
-                m, params, kv, token_ids, token_pos, slot_mapping,
-                token_seq, block_tables, q_start, q_len, kv_len, row_start,
-                bs, attn=attn, kv_scales=kv_sc,
+                m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
             )
             logits, kv = out[0], out[1]
             kv_sc = out[2] if kv_sc is not None else None
             toks = sample_tokens(
-                logits, key, temp, top_k, top_p, seed=seed,
-                sample_pos=kv_len,
+                logits, o["key"], o["temp"], o["top_k"], o["top_p"],
+                seed=o["seed"], sample_pos=o["kv_len"],
             )
-            return jnp.where(q_len > 0, toks, 0), kv, kv_sc
+            return jnp.where(o["q_len"] > 0, toks, 0), kv, kv_sc
 
-        def unified_spec_fn(
-            params, kv, kv_sc, token_ids, token_pos, slot_mapping,
-            token_seq, block_tables, q_start, q_len, kv_len, row_start,
-            drafts, draft_len, use_prev, prev_row, prev_toks,
-            temp, top_k, top_p, seed, key,
-        ):
+        def unified_spec_fn(params, kv, kv_sc, packed, prev_toks):
             """The budget-ladder program of a spec-enabled engine
             (cfg.speculative_k > 0): the SAME ragged dispatch, with
             draft-verify spans of ``q_len = draft_len + 1`` rows and the
@@ -481,13 +609,11 @@ class ModelRunner(WarmupPlanMixin):
             (emitted [S, K+1], counts [S], bonus [S], kv, kv_sc) —
             row s carries counts[s] real tokens, bonus is the last
             delivered token (the device feed for the next dispatch)."""
-            token_ids = _feed_tokens(
-                token_ids, row_start, use_prev, prev_row, prev_toks
-            )
+            o, meta = _unpack(packed, "spec", prev_toks)
+            drafts, draft_len = o["drafts"], o["draft_len"]
+            q_len, kv_len, temp = o["q_len"], o["kv_len"], o["temp"]
             out = llama.unified(
-                m, params, kv, token_ids, token_pos, slot_mapping,
-                token_seq, block_tables, q_start, q_len, kv_len, row_start,
-                bs, attn=attn, kv_scales=kv_sc,
+                m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
                 draft_len=draft_len, verify_rows=K_spec + 1,
             )
             logits, kv = out[0], out[1]          # [S, K+1, V]
@@ -506,8 +632,8 @@ class ModelRunner(WarmupPlanMixin):
                 logits, acc[:, None, None], axis=1
             )[:, 0]                               # [S, V]
             bonus = sample_tokens(
-                at_acc, key, temp, top_k, top_p, seed=seed,
-                sample_pos=kv_len - draft_len + acc,
+                at_acc, o["key"], temp, o["top_k"], o["top_p"],
+                seed=o["seed"], sample_pos=kv_len - draft_len + acc,
             )
             bonus = jnp.where(q_len > 0, bonus, 0)
             offs = jnp.arange(K_spec + 1)[None, :]
@@ -527,23 +653,17 @@ class ModelRunner(WarmupPlanMixin):
             batches snap there, so these cost ONE warm program apiece
             instead of a second ladder."""
 
-            def fn(
-                params, kv, kv_sc, counts, token_ids, token_pos,
-                slot_mapping, token_seq, block_tables, q_start, q_len,
-                kv_len, row_start, span_slot, counts_add, reset, freq,
-                pres, use_prev, prev_row, prev_toks, temp, top_k, top_p,
-                seed, key, *mm_ops,
-            ):
-                token_ids = _feed_tokens(
-                    token_ids, row_start, use_prev, prev_row, prev_toks
+            def fn(params, kv, kv_sc, counts, packed, prev_toks, *mm_ops):
+                o, meta = _unpack(packed, "extras", prev_toks)
+                token_ids, q_len, row_start = (
+                    o["token_ids"], o["q_len"], o["row_start"]
                 )
+                span_slot, reset = o["span_slot"], o["reset"]
                 embeds, embed_mask = (
                     mm_ops if with_mm else (None, None)
                 )
                 out = llama.unified(
-                    m, params, kv, token_ids, token_pos, slot_mapping,
-                    token_seq, block_tables, q_start, q_len, kv_len,
-                    row_start, bs, attn=attn, kv_scales=kv_sc,
+                    m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
                     embeds=embeds, embed_mask=embed_mask,
                 )
                 logits, kv = out[0], out[1]       # [S, V]
@@ -561,14 +681,16 @@ class ModelRunner(WarmupPlanMixin):
                 fed = token_ids[
                     jnp.clip(row_start, 0, token_ids.shape[0] - 1)
                 ]
-                add = counts_add & valid
+                add = o["counts_add"] & valid
                 counts = counts.at[
                     jnp.where(add, slot_clip, B), fed
                 ].add(add.astype(counts.dtype), mode="drop")
-                pen = apply_penalties(logits, counts[slot_clip], freq, pres)
+                pen = apply_penalties(
+                    logits, counts[slot_clip], o["freq"], o["pres"]
+                )
                 toks = sample_tokens(
-                    pen, key, temp, top_k, top_p, seed=seed,
-                    sample_pos=kv_len,
+                    pen, o["key"], o["temp"], o["top_k"], o["top_p"],
+                    seed=o["seed"], sample_pos=o["kv_len"],
                 )
                 clp, tids, tlps = token_logprobs(pen, toks)
                 toks = jnp.where(q_len > 0, toks, 0)
@@ -610,6 +732,25 @@ class ModelRunner(WarmupPlanMixin):
             return jax.jit(fn, **kw)
 
         self._tok_sh = tok_sh
+        #: The budget ladder's operand layout variant.
+        self._ladder_variant = "spec" if K_spec > 0 else "plain"
+        #: Host arrays handed to the device, by the last dispatch and in
+        #: all (the flight recorder and /metrics read them).
+        self.operand_transfers = 0
+        self.operand_transfers_total = 0
+        # How a dispatch's host operand reaches the device: ONE placement
+        # at the sharding the program wants. Under a mesh that is the
+        # replicated token sharding, so nothing lands on chip 0 to be
+        # copied on by the jitted call. A mesh spanning processes takes
+        # the process-local form: `device_put` of a host array onto a
+        # sharding this process cannot address whole asserts the value
+        # equal across hosts first — a collective a step.
+        if tok_sh is None:
+            self._put = jax.device_put
+        elif tok_sh.is_fully_addressable:
+            self._put = partial(jax.device_put, device=tok_sh)
+        else:
+            self._put = partial(jax.make_array_from_process_local_data, tok_sh)
         # Stand-in for the fed tokens when no lane reads them, resident
         # and sharded like the unified programs' own token output (see
         # _unified_operands). Built by a jit so that under multi-host no
@@ -719,17 +860,16 @@ class ModelRunner(WarmupPlanMixin):
         )
 
     def ensure_counts(self):
-        """Lazy [B, V] output-token count buffer for the penalties path."""
+        """Lazy [B, V] output-token count buffer for the penalties path.
+        Under a mesh it is born with the sharding the extras programs
+        hand it back in (as ``_zero_prev`` is): an uncommitted first
+        buffer compiled them a second time on their second dispatch."""
         if self._counts is None:
             self._counts = jnp.zeros(
-                (self.cfg.max_num_seqs, self.cfg.model.vocab_size), jnp.int32
+                (self.cfg.max_num_seqs, self.cfg.model.vocab_size),
+                jnp.int32, device=self._tok_sh,
             )
         return self._counts
-
-    def _pad_table(self, block_ids: list[int]) -> np.ndarray:
-        table = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
-        table[: len(block_ids)] = block_ids
-        return table
 
     def slot_of(self, block_ids: list[int], position: int) -> int:
         bs = self.cfg.block_size
@@ -986,136 +1126,126 @@ class ModelRunner(WarmupPlanMixin):
             f"{total} tokens exceed the unified budget "
             f"{cfg.unified_token_budget}"
         )
-        (
-            base_args, meta_args, feed_args, samp_args, row_start, q_len,
-        ) = self._unified_operands(lanes, feed, T)
-        samp_args = (*samp_args, self._next_key())
-
+        variant = "extras" if use_full else self._ladder_variant
+        base_args, _meta, ops = self._unified_operands(lanes, feed, T, variant)
+        seg = ops.seg
+        seg["key"][:] = self._next_key()
+        mm_args = ()
         if use_full:
-            span_slot = np.full(S, -1, np.int32)
-            counts_add = np.zeros(S, bool)
-            reset = np.zeros(S, bool)
-            freq = np.zeros(S, np.float32)
-            pres = np.zeros(S, np.float32)
             if extras is not None:
                 n_l = len(lanes)
-                span_slot[:n_l] = extras["slots"]
-                counts_add[:n_l] = extras["counts_add"]
-                reset[:n_l] = extras["reset"]
-                freq[:n_l] = extras["freq"]
-                pres[:n_l] = extras["pres"]
-            extras_args = (
-                jnp.asarray(span_slot), jnp.asarray(counts_add),
-                jnp.asarray(reset), jnp.asarray(freq), jnp.asarray(pres),
-            )
+                seg["span_slot"][:n_l] = extras["slots"]
+                seg["counts_add"][:n_l] = extras["counts_add"]
+                seg["reset"][:n_l] = extras["reset"]
+                seg["freq"][:n_l] = extras["freq"]
+                seg["pres"][:n_l] = extras["pres"]
             if use_mm:
                 D = cfg.model.hidden_size
                 embeds = np.zeros((T, D), np.float32)
                 mask = np.zeros(T, bool)
+                row_start, q_len = seg["row_start"], seg["q_len"]
                 for s, segs in enumerate(mm):
                     if not segs:
                         continue
                     r0 = row_start[s]
                     n = q_len[s]
-                    for off, seg in segs:
+                    for off, mm_seg in segs:
                         # dynalint: allow[DT005] mm embeddings arrive as host arrays from the preprocessor; dtype view, not a device fetch
-                        seg = np.asarray(seg, np.float32)
-                        w = min(len(seg), max(0, int(n) - off))
+                        mm_seg = np.asarray(mm_seg, np.float32)
+                        w = min(len(mm_seg), max(0, int(n) - off))
                         if w <= 0 or off < 0:
                             continue
-                        embeds[r0 + off : r0 + off + w] = seg[:w]
+                        embeds[r0 + off : r0 + off + w] = mm_seg[:w]
                         mask[r0 + off : r0 + off + w] = True
-                with self.compile_stats.observe("unified_mm", t=T):
-                    (
-                        toks, clp, tids, tlps, self._counts,
-                        self.kv_caches, self.kv_scales,
-                    ) = self._unified_mm(
-                        *base_args, self.ensure_counts(), *meta_args,
-                        *extras_args, *feed_args, *samp_args,
-                        jnp.asarray(embeds), jnp.asarray(mask),
-                    )
-            else:
-                with self.compile_stats.observe("unified_full", t=T):
-                    (
-                        toks, clp, tids, tlps, self._counts,
-                        self.kv_caches, self.kv_scales,
-                    ) = self._unified_full(
-                        *base_args, self.ensure_counts(), *meta_args,
-                        *extras_args, *feed_args, *samp_args,
-                    )
+                mm_args = (self._put(embeds), self._put(mask))
+        elif variant == "spec" and draft_lens is not None:
+            drafts, dlen = seg["drafts"], seg["draft_len"]
+            for s, dl in enumerate(draft_lens):
+                if dl:
+                    dlen[s] = dl
+                    drafts[s, :dl] = lanes[s][0][-dl:]
+        self.operand_transfers = 1 + ops.feed_transfers + len(mm_args)
+        self.operand_transfers_total += self.operand_transfers
+        packed = self._put(ops.buf)
+
+        if use_full:
+            kind = "unified_mm" if use_mm else "unified_full"
+            program = self._unified_mm if use_mm else self._unified_full
+            with self.compile_stats.observe(kind, t=T):
+                (
+                    toks, clp, tids, tlps, self._counts,
+                    self.kv_caches, self.kv_scales,
+                ) = program(
+                    *base_args, self.ensure_counts(), packed,
+                    ops.prev_toks, *mm_args,
+                )
             self.last_unified_logprobs = (clp, tids, tlps)
             return UnifiedOut(last=toks, toks=None, counts=None)
 
-        if self.cfg.speculative_k > 0:
-            K = self.cfg.speculative_k
-            drafts = np.zeros((S, K), np.int32)
-            dlen = np.zeros(S, np.int32)
-            if draft_lens is not None:
-                for s, dl in enumerate(draft_lens):
-                    if dl:
-                        dlen[s] = dl
-                        drafts[s, :dl] = lanes[s][0][-dl:]
-            with self.compile_stats.observe("unified", t=T):
-                (
-                    toks2d, counts, bonus,
-                    self.kv_caches, self.kv_scales,
-                ) = self._unified(
-                    *base_args, *meta_args,
-                    jnp.asarray(drafts), jnp.asarray(dlen),
-                    *feed_args, *samp_args,
-                )
-            return UnifiedOut(last=bonus, toks=toks2d, counts=counts)
-
         with self.compile_stats.observe("unified", t=T):
-            toks, self.kv_caches, self.kv_scales = self._unified(
-                *base_args, *meta_args, *feed_args, *samp_args,
-            )
+            out = self._unified(*base_args, packed, ops.prev_toks)
+        if variant == "spec":
+            toks2d, counts, bonus, self.kv_caches, self.kv_scales = out
+            return UnifiedOut(last=bonus, toks=toks2d, counts=counts)
+        toks, self.kv_caches, self.kv_scales = out
         return UnifiedOut(last=toks, toks=None, counts=None)
 
-    def _unified_operands(self, lanes, feed, T: int):
-        """The operands every unified program variant shares, for one
-        dispatch of ``lanes`` padded to budget ``T``: (params/caches,
-        flat-token and per-span metadata, the device feed, sampling
-        rows WITHOUT the step key) plus the host ``row_start``/``q_len``
-        the multimodal variant places its segments by."""
+    def _unified_operands(self, lanes, feed, T: int, variant=None):
+        """One dispatch of ``lanes`` padded to budget ``T``, on the
+        host: ``(params, caches, scales)``, ``llama.unified``'s nine
+        metadata arrays in its order (views of the packed buffer — the
+        benchmark's check feeds them to the model function), and the
+        packed ``_Operands`` every program variant takes. Nothing is
+        transferred here but a host feed whose values are read."""
         cfg = self.cfg
         S = self.unified_slots
-        token_ids = np.zeros(T, np.int32)
-        token_pos = np.full(T, -1, np.int32)       # -1 = padding row
-        slot_mapping = np.zeros(T, np.int32)       # padding → trash block 0
-        token_seq = np.zeros(T, np.int32)
-        block_tables = np.zeros((S, cfg.max_blocks_per_seq), np.int32)
-        q_start = np.zeros(S, np.int32)
-        q_len = np.zeros(S, np.int32)
-        kv_len = np.zeros(S, np.int32)
-        row_start = np.zeros(S, np.int32)
-        temp = np.zeros(S, np.float32)
-        top_k = np.zeros(S, np.int32)
-        top_p = np.ones(S, np.float32)
-        seed = np.full(S, -1, np.int32)
-        cursor = 0
-        for s, (new_tokens, block_ids, prefix, sampling) in enumerate(lanes):
-            n = len(new_tokens)
-            row_start[s] = cursor
-            q_start[s] = prefix
-            q_len[s] = n
-            kv_len[s] = prefix + n
-            block_tables[s, : len(block_ids)] = block_ids
-            token_ids[cursor : cursor + n] = new_tokens
-            token_pos[cursor : cursor + n] = np.arange(prefix, prefix + n)
-            token_seq[cursor : cursor + n] = s
-            for j in range(n):
-                slot_mapping[cursor + j] = self.slot_of(block_ids, prefix + j)
-            temp[s], top_k[s], top_p[s], seed[s] = _norm_sampling(sampling)
-            cursor += n
+        bs = cfg.block_size
+        lay = operand_layout(
+            T, S, cfg.max_blocks_per_seq, cfg.speculative_k,
+            variant or self._ladder_variant,
+        )
+        # A fresh buffer every dispatch: at pipeline depth 2 the
+        # previous one may still be in flight to the device.
+        buf = lay.template.copy()
+        seg = lay.views(buf)
+        n_l = len(lanes)
+        if n_l:
+            q_len = np.fromiter((len(t) for t, _, _, _ in lanes), np.int32, n_l)
+            prefix = np.fromiter((p for _, _, p, _ in lanes), np.int32, n_l)
+            row_start = np.cumsum(q_len, dtype=np.int32) - q_len
+            total = int(row_start[-1] + q_len[-1])
+            seg["row_start"][:n_l] = row_start
+            seg["q_start"][:n_l] = prefix
+            seg["q_len"][:n_l] = q_len
+            seg["kv_len"][:n_l] = prefix + q_len
+            block_tables = seg["block_tables"]
+            temp, top_k, top_p, seed = (
+                seg["temp"], seg["top_k"], seg["top_p"], seg["seed"]
+            )
+            for s, (_toks, block_ids, _prefix, sampling) in enumerate(lanes):
+                block_tables[s, : len(block_ids)] = block_ids
+                temp[s], top_k[s], top_p[s], seed[s] = _norm_sampling(sampling)
+            # Every token's span, position and cache slot by array
+            # arithmetic over the flat batch, not a Python loop a token.
+            token_seq = np.repeat(np.arange(n_l, dtype=np.int32), q_len)
+            token_pos = np.arange(total, dtype=np.int32) - np.repeat(
+                row_start - prefix, q_len
+            )
+            seg["token_ids"][:total] = np.fromiter(
+                chain.from_iterable(t for t, _, _, _ in lanes), np.int32, total
+            )
+            seg["token_seq"][:total] = token_seq
+            seg["token_pos"][:total] = token_pos
+            seg["slot_mapping"][:total] = (
+                block_tables[token_seq, token_pos // bs] * bs + token_pos % bs
+            )
 
+        prev_toks = None
         if feed is not None:
             prev_toks, prev_row, use_prev = feed
-        else:
-            prev_toks = None
-            prev_row = np.zeros(S, np.int32)
-            use_prev = np.zeros(S, bool)
-
+            seg["prev_row"][:] = prev_row
+            seg["use_prev"][:] = use_prev
+        feed_transfers = 0
         if not isinstance(prev_toks, jax.Array):
             # The fed tokens must carry the SAME sharding whether they
             # are a previous dispatch's device output or a stand-in:
@@ -1124,44 +1254,18 @@ class ModelRunner(WarmupPlanMixin):
             # warmup and a second, UNCOUNTED one per budget rung on the
             # first fed dispatch mid-traffic (four chips, Llama-3.1-8B
             # tp=4: 78 s for ten requests — PERF.md, PR 22).
-            if prev_toks is None or not np.any(use_prev):
+            if prev_toks is None or not seg["use_prev"].any():
                 # No lane reads it (warmup, a first dispatch, a
                 # follower's placeholder): the resident zeros, no
                 # transfer and no cross-host check per step.
                 prev_toks = self._zero_prev
-            elif self._tok_sh is None:
-                prev_toks = jnp.asarray(prev_toks)
             else:
                 # A replayed host feed whose values ARE read.
-                prev_toks = jax.device_put(prev_toks, self._tok_sh)
-        base_args = (
-            self.params,
-            self.kv_caches,
-            self.kv_scales,
-        )
-        meta_args = (
-            jnp.asarray(token_ids),
-            jnp.asarray(token_pos),
-            jnp.asarray(slot_mapping),
-            jnp.asarray(token_seq),
-            jnp.asarray(block_tables),
-            jnp.asarray(q_start),
-            jnp.asarray(q_len),
-            jnp.asarray(kv_len),
-            jnp.asarray(row_start),
-        )
-        feed_args = (
-            jnp.asarray(use_prev),
-            jnp.asarray(prev_row),
-            prev_toks,
-        )
-        samp_args = (
-            jnp.asarray(temp),
-            jnp.asarray(top_k),
-            jnp.asarray(top_p),
-            jnp.asarray(seed),
-        )
-        return base_args, meta_args, feed_args, samp_args, row_start, q_len
+                prev_toks = self._put(prev_toks)
+                feed_transfers = 1
+        base_args = (self.params, self.kv_caches, self.kv_scales)
+        meta_args = tuple(seg[name] for name in META_SEGMENTS)
+        return base_args, meta_args, _Operands(buf, seg, prev_toks, feed_transfers)
 
     def lower_unified_top(self):
         """Lower (not compile, not run) this runner's own plain unified
@@ -1173,16 +1277,9 @@ class ModelRunner(WarmupPlanMixin):
             T, self.unified_slots, cfg.max_model_len,
             [0] * cfg.max_blocks_per_seq, (0.0, 0, 1.0),
         )
-        base, meta, feed, samp, _, _ = self._unified_operands(lanes, None, T)
-        spec = ()
-        if cfg.speculative_k > 0:
-            S = self.unified_slots
-            spec = (
-                jnp.zeros((S, cfg.speculative_k), jnp.int32),
-                jnp.zeros(S, jnp.int32),
-            )
-        key = np.zeros(2, np.uint32)  # _next_key() would advance the run
-        return self._unified.lower(*base, *meta, *spec, *feed, *samp, key)
+        # The key segment stays zero: _next_key() would advance the run.
+        base, _meta, ops = self._unified_operands(lanes, None, T)
+        return self._unified.lower(*base, self._put(ops.buf), ops.prev_toks)
 
     def unified_executables(self) -> int:
         """Executables the plain unified jit holds. jit's own count also

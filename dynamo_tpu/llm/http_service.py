@@ -208,6 +208,7 @@ class HttpService:
                 "degraded_requests_total",
                 "unified_step_tokens_decode_total",
                 "unified_step_tokens_prefill_total",
+                "unified_operand_transfers_total",
                 "batch_fill_ratio",
                 "coloc_quantum",
                 "itl_ema_ms",

@@ -51,6 +51,9 @@ class ForwardPassMetrics:
     # phase-alternating engine.
     unified_step_tokens_decode_total: int = 0
     unified_step_tokens_prefill_total: int = 0
+    # Host arrays handed to the device across unified dispatches: one
+    # packed operand buffer each (two with a replayed host feed).
+    unified_operand_transfers_total: int = 0
     batch_fill_ratio: float = 0.0
     # SLO-aware co-location (engine/coloc.py; ROADMAP #3): the live
     # prefill quantum, decode ITL EMA vs the configured SLO, dispatches

@@ -42,6 +42,7 @@ _GAUGES = (
     ("warmup_programs_total", "Programs compiled by warmup (budget ladder)"),
     ("unified_step_tokens_decode_total", "Decode tokens via unified steps"),
     ("unified_step_tokens_prefill_total", "Prefill tokens via unified steps"),
+    ("unified_operand_transfers_total", "Host arrays handed to the device by unified dispatches"),
     ("batch_fill_ratio", "Unified batch fill (real tokens / budget)"),
     ("coloc_quantum", "Live prefill quantum (coloc controller)"),
     ("itl_ema_ms", "Decode inter-token-latency EMA, ms"),

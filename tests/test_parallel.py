@@ -68,21 +68,70 @@ def test_fed_dispatch_under_a_mesh_compiles_nothing_new():
     runner = ModelRunner(
         ecfg, mesh=build_mesh({"dp": 2, "tp": 2, "sp": 2}), rng_seed=0
     )
+    # Every operand of every call into the program, as jit received it.
+    calls = []
+
+    class Spy:
+        def __init__(self, program):
+            self.program = program
+
+        def __call__(self, *args):
+            calls.append(args)
+            return self.program(*args)
+
+        def __getattr__(self, name):
+            return getattr(self.program, name)
+
+    runner._unified = Spy(runner._unified)
     runner.warmup()
     warmed = runner.unified_executables()
+    n_warm = len(calls)
     S, greedy = runner.unified_slots, (0.0, 0, 1.0)
     row, use = np.zeros(S, np.int32), np.zeros(S, bool)
     out = runner.unified_step(
         [([5, 9, 2, 7, 11], [1], 0, greedy)],
         feed=(np.zeros(S, np.int32), row, use),  # the engine's first step
     )
+    assert runner.operand_transfers == 1
     use[0] = True
     out = runner.unified_step([([0], [1], 5, greedy)], feed=(out.last, row, use))
+    assert runner.operand_transfers == 1
     # A replayed host feed whose values are read (stepcast, tools).
     host = np.asarray(out.last)
     runner.unified_step([([0], [1], 6, greedy)], feed=(host, row, use))
+    assert runner.operand_transfers == 2
     assert runner.unified_executables() == warmed
     assert runner.compile_stats.snapshot()["mid_traffic_compiles_total"] == 0
+    # The packed operand is placed ONCE, replicated over the mesh — on
+    # warmup, on the unfed and on both fed dispatches alike — and nothing
+    # the call takes sits on one chip for jit to copy to the others.
+    assert n_warm == warmed and len(calls) == n_warm + 3
+    devices = set(runner.mesh.devices.flat)
+    for args in calls:
+        packed, prev_toks = args[-2:]
+        assert packed.sharding == prev_toks.sharding == runner._tok_sh
+        assert packed.dtype == np.int32 and packed.ndim == 1
+        for leaf in jax.tree.leaves(args):
+            assert isinstance(leaf, jax.Array), type(leaf)
+            assert leaf.sharding.device_set == devices, leaf.sharding
+
+
+def test_extras_program_under_a_mesh_compiles_once():
+    """The count buffer is born with the sharding the extras program
+    returns it in, so its second dispatch finds the first's executable."""
+    ecfg = EngineConfig(
+        model=ModelConfig.tiny_test(), num_blocks=32, max_num_seqs=4,
+        max_model_len=64, unified_token_budget=32,
+    )
+    runner = ModelRunner(
+        ecfg, mesh=build_mesh({"dp": 2, "tp": 2, "sp": 2}), rng_seed=0
+    )
+    extras = {"slots": [0], "counts_add": [False], "reset": [True],
+              "freq": [0.0], "pres": [0.0]}
+    for _ in range(2):
+        runner.unified_step([([5, 9, 2], [1], 0, (0.0, 0, 1.0))], extras=extras)
+        assert runner._counts.sharding == runner._tok_sh
+    assert runner._unified_full._cache_size() == 1
 
 
 def test_sharded_pallas_decode_matches_single_device_jnp(monkeypatch):

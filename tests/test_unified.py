@@ -355,6 +355,12 @@ async def test_engine_unified_mixed_concurrency_and_prefix_cache():
     again = await run_one(base)
     assert again == first[0]
     assert eng.prefix_hit_rate > 0
+    # Every dispatch handed the device ONE host array (the packed operand
+    # buffer); the flight record and the readiness snapshot both say so.
+    steps = [r for r in eng.flight.snapshot() if r["kind"] == "unified"]
+    assert steps and all(r["operand_transfers"] == 1 for r in steps)
+    total = eng.readiness()["unified_operand_transfers_total"]
+    assert total == eng.runner.operand_transfers_total >= len(steps)
     await eng.stop()
 
 
@@ -419,3 +425,303 @@ def test_engine_and_stepcast_name_only_methods_the_runner_has():
     assert hasattr(_SimRunner, "unified_step")
     for gone in ("prefill", "prefill_batch", "decode", "decode_multi"):
         assert not hasattr(_SimRunner, gone), gone
+
+
+# ---------------------------------------------------------------------------
+# the packed operand buffer (docs/architecture/unified_step.md)
+# ---------------------------------------------------------------------------
+
+_BS = 16  # EngineConfig's default block size
+GREEDY = (0.0, 0, 1.0)
+
+
+def _plain_operands(runner, lanes, feed, T, key, draft_lens, extras):
+    """One dispatch's operands the plain way: an array each, a Python
+    loop a token through ``slot_of`` (the arithmetic the packed builder
+    replaced). ``{segment name: array}``."""
+    cfg, S = runner.cfg, runner.unified_slots
+    o = {
+        "token_ids": np.zeros(T, np.int32),
+        "token_pos": np.full(T, -1, np.int32),
+        "slot_mapping": np.zeros(T, np.int32),
+        "token_seq": np.zeros(T, np.int32),
+        "block_tables": np.zeros((S, cfg.max_blocks_per_seq), np.int32),
+        "temp": np.zeros(S, np.float32), "top_k": np.zeros(S, np.int32),
+        "top_p": np.ones(S, np.float32), "seed": np.full(S, -1, np.int32),
+        "use_prev": np.zeros(S, bool), "prev_row": np.zeros(S, np.int32),
+        "key": np.asarray(key, np.uint32),
+    }
+    for name in ("q_start", "q_len", "kv_len", "row_start"):
+        o[name] = np.zeros(S, np.int32)
+    cursor = 0
+    for s, (new_tokens, block_ids, prefix, sampling) in enumerate(lanes):
+        n = len(new_tokens)
+        o["row_start"][s], o["q_start"][s] = cursor, prefix
+        o["q_len"][s], o["kv_len"][s] = n, prefix + n
+        o["block_tables"][s, : len(block_ids)] = block_ids
+        o["token_ids"][cursor : cursor + n] = new_tokens
+        o["token_seq"][cursor : cursor + n] = s
+        for j in range(n):
+            o["token_pos"][cursor + j] = prefix + j
+            o["slot_mapping"][cursor + j] = runner.slot_of(block_ids, prefix + j)
+        sampling = tuple(sampling) + (-1,) * (4 - len(sampling))
+        o["temp"][s], o["top_k"][s], o["top_p"][s], o["seed"][s] = sampling
+        cursor += n
+    if feed is not None:
+        o["prev_row"][:], o["use_prev"][:] = feed[1], feed[2]
+    if cfg.speculative_k and extras is None:
+        o["drafts"] = np.zeros((S, cfg.speculative_k), np.int32)
+        o["draft_len"] = np.zeros(S, np.int32)
+        for s, dl in enumerate(draft_lens or ()):
+            if dl:
+                o["draft_len"][s] = dl
+                o["drafts"][s, :dl] = lanes[s][0][-dl:]
+    if extras is not None:
+        n_l = len(lanes)
+        o["span_slot"] = np.full(S, -1, np.int32)
+        o["span_slot"][:n_l] = extras["slots"]
+        for name, dtype in (
+            ("counts_add", bool), ("reset", bool),
+            ("freq", np.float32), ("pres", np.float32),
+        ):
+            o[name] = np.zeros(S, dtype)
+            o[name][:n_l] = extras[name]
+    return o
+
+
+def _bits(a) -> np.ndarray:
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype.itemsize == 4 else a
+
+
+def _assert_unpacks_to(runner, buf, want, variant) -> None:
+    """``buf`` through the PROGRAM's unpack (static slices, bitcasts,
+    ``!= 0``) gives ``want``: names, shapes, dtypes and every bit."""
+    import jax
+
+    from dynamo_tpu.engine.runner import operand_layout_of
+
+    cfg = runner.cfg
+    lay = operand_layout_of(
+        buf.shape[0], runner.unified_slots, cfg.max_blocks_per_seq,
+        cfg.speculative_k, variant,
+    )
+    got = jax.jit(lay.unpack)(buf)
+    assert set(got) == set(want)
+    for name, w in want.items():
+        g = np.asarray(got[name])
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(_bits(g), _bits(w)), name
+
+
+def _greedy_after(runner, context: list[int]) -> int:
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+
+    logits = llama.reference_forward(
+        runner.cfg.model, runner.params, jnp.asarray(context)
+    )
+    return int(jnp.argmax(logits[-1]))
+
+
+def _mix_decode_only(runner):
+    """Three decode lanes fed on the device from the prefill dispatch."""
+    prompts = [[5, 9, 2, 7, 11], [3, 1, 4, 1, 5, 9, 2], [8, 6, 7]]
+    first = runner.unified_step(
+        [(p, [i + 1], 0, GREEDY) for i, p in enumerate(prompts)]
+    )
+    S = runner.unified_slots
+    row, use = np.zeros(S, np.int32), np.zeros(S, bool)
+    row[:3], use[:3] = [0, 1, 2], True
+    fed = [_greedy_after(runner, p) for p in prompts]
+    lanes = [([0], [i + 1], len(p), GREEDY) for i, p in enumerate(prompts)]
+    return dict(
+        lanes=lanes, feed=(first.last, row, use),
+        contexts=[p + [t] for p, t in zip(prompts, fed)],
+    )
+
+
+def _mix_decode_and_chunk_over_a_block_edge(runner):
+    """A decode lane beside a prefill chunk whose span crosses from its
+    sequence's first block into its second."""
+    a, b = [5, 9, 2, 7], list(range(40, 40 + _BS + 4))
+    head = _BS - 3
+    first = runner.unified_step(
+        [(a, [1], 0, GREEDY), (b[:head], [2, 3], 0, GREEDY)]
+    )
+    tok = int(np.asarray(first.last)[0])
+    assert tok == _greedy_after(runner, a)
+    lanes = [([tok], [1], len(a), GREEDY), (b[head:], [2, 3], head, GREEDY)]
+    return dict(lanes=lanes, contexts=[a + [tok], b])
+
+
+def _mix_draft_verify(runner):
+    """A span whose drafts are the greedy continuation (all accepted) and
+    one whose drafts are wrong (none accepted), on a speculative engine."""
+    a, b = [5, 9, 2, 7, 11], [3, 1, 4, 1, 5]
+    first = np.asarray(
+        runner.unified_step([(a, [1], 0, GREEDY), (b, [2], 0, GREEDY)]).last
+    )
+    ctx_a = a + [int(first[0])]
+    good = [_greedy_after(runner, ctx_a)]
+    good.append(_greedy_after(runner, ctx_a + good))
+    wrong = [(_greedy_after(runner, b + [int(first[1])]) + 1) % 256, 7]
+    lanes = [
+        ([int(first[0])] + good, [1], len(a), GREEDY),
+        ([int(first[1])] + wrong, [2], len(b), GREEDY),
+    ]
+    return dict(
+        lanes=lanes, draft_lens=[2, 2],
+        # Emitted: both drafts and a bonus; then the bonus alone.
+        contexts=[ctx_a + good, b + [int(first[1])]], accepted=[2, 0],
+    )
+
+
+def _mix_extras(runner):
+    """The penalties/logprob program: a decode lane that counts its fed
+    token and a prefill span, at penalties that leave greedy unmoved."""
+    a, b = [5, 9, 2, 7], [3, 1, 4, 1, 5, 9]
+    tok = int(np.asarray(runner.unified_step([(a, [1], 0, GREEDY)]).last)[0])
+    lanes = [([tok], [1], len(a), GREEDY), (b, [2], 0, GREEDY)]
+    extras = {
+        "slots": [0, 1], "counts_add": [True, False], "reset": [True, True],
+        "freq": [0.0, 0.0], "pres": [0.0, 0.0],
+    }
+    return dict(lanes=lanes, extras=extras, contexts=[a + [tok], b])
+
+
+def _mix_empty_tail(runner):
+    """One span and nothing else: every row after it is padding."""
+    a = [5, 9, 2, 7, 11, 3]
+    return dict(lanes=[(a, [4], 0, GREEDY)], contexts=[a])
+
+
+@pytest.mark.parametrize(
+    "mix, cfg_kw",
+    [
+        (_mix_decode_only, {}),
+        (_mix_decode_and_chunk_over_a_block_edge, {}),
+        (_mix_draft_verify, {"speculative_k": 3}),
+        (_mix_extras, {"sampling_extras": True}),
+        (_mix_empty_tail, {}),
+    ],
+    ids=["decode-only", "decode-and-chunk-over-a-block-edge", "draft-verify",
+         "extras", "empty-tail"],
+)
+def test_packed_operands_equal_the_plain_builder(mix, cfg_kw):
+    """One transfer a dispatch carries what sixteen carried: the buffer
+    a dispatch hands to the device, unpacked as the program unpacks it,
+    equals a plain array-each builder bit for bit (float32 and uint32
+    rows through the bitcast); at most two host arrays reach the device;
+    and the dispatch's greedy tokens are the no-cache reference's."""
+    from dynamo_tpu.engine.compile_cache import token_budget
+    from dynamo_tpu.engine.runner import ModelRunner
+
+    ecfg = EngineConfig(
+        model=ModelConfig.tiny_test(), num_blocks=16, max_num_seqs=3,
+        max_model_len=64, dtype="float32", unified_token_budget=32,
+        seed=0xDEADBEEF, **cfg_kw,
+    )
+    runner = ModelRunner(ecfg)
+    case = mix(runner)
+    lanes, feed = case["lanes"], case.get("feed")
+    draft_lens, extras = case.get("draft_lens"), case.get("extras")
+    variant = (
+        "extras" if extras is not None
+        else "spec" if ecfg.speculative_k else "plain"
+    )
+    top = ecfg.unified_token_budget
+    T = token_budget(
+        top if extras is not None else sum(len(t) for t, *_ in lanes), top
+    )
+
+    placed, real_put = [], runner._put
+    runner._put = lambda x: placed.append(np.array(x)) or real_put(x)
+    out = runner.unified_step(
+        lanes, feed=feed, draft_lens=draft_lens, extras=extras
+    )
+    runner._put = real_put
+    assert len(placed) == runner.operand_transfers == 1  # at most 2: below
+    (buf,) = placed
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    key = [ecfg.seed & 0xFFFFFFFF, runner._step]
+    assert key[0] >> 31  # a key word no int32 holds: it rides as bits
+    want = _plain_operands(runner, lanes, feed, T, key, draft_lens, extras)
+    _assert_unpacks_to(runner, buf, want, variant)
+
+    # The tokens, against the no-cache reference.
+    last = np.asarray(out.last)
+    if draft_lens is None:
+        for s, ctx in enumerate(case["contexts"]):
+            assert int(last[s]) == _greedy_after(runner, ctx), s
+    else:
+        toks, counts = np.asarray(out.toks), np.asarray(out.counts)
+        for s, (ctx, acc) in enumerate(zip(case["contexts"], case["accepted"])):
+            assert counts[s] == acc + 1
+            assert toks[s, :acc].tolist() == ctx[len(ctx) - acc :]
+            assert int(toks[s, acc]) == int(last[s]) == _greedy_after(runner, ctx)
+    assert not last[len(lanes) :].any()  # idle rows hand out token 0
+
+    # Sampling rows no int32 holds as a value, and a host feed that is
+    # read (one transfer more): still bit for bit, nothing dispatched.
+    S = runner.unified_slots
+    odd = [
+        (t, b, p, (0.7, 5, 0.9, 1234567) if s % 2 else (1e-30, 0, 0.1))
+        for s, (t, b, p, _) in enumerate(lanes)
+    ]
+    row, use = np.arange(S, dtype=np.int32)[::-1].copy(), np.zeros(S, bool)
+    use[: len(lanes)] = True
+    host_feed = (np.arange(S, dtype=np.int32), row, use)
+    base, meta, ops = runner._unified_operands(odd, host_feed, T, variant)
+    assert ops.feed_transfers == 1 and isinstance(ops.buf, np.ndarray)
+    want = _plain_operands(
+        runner, odd, host_feed, T, [0, 0],
+        [0] * len(lanes) if ecfg.speculative_k else None,
+        None if extras is None else dict(
+            extras, slots=[-1] * len(lanes), counts_add=[False] * len(lanes),
+            reset=[False] * len(lanes),
+        ),
+    )
+    _assert_unpacks_to(runner, ops.buf, want, variant)
+    assert np.array_equal(np.asarray(ops.prev_toks), host_feed[0])
+
+
+def test_unified_operands_keep_the_contract_the_benchmark_reads():
+    """``chipbench/steps/span.py`` calls ``base, meta, *_ =
+    runner._unified_operands(lanes, None, T)`` and feeds ``fn(*base,
+    *meta)`` to ``llama.unified``: a 3-tuple of (params, caches,
+    scales), then the nine metadata arrays in the model function's
+    order."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.runner import META_SEGMENTS
+    from dynamo_tpu.models import llama
+
+    runner = _tiny_runner()
+    cfg = runner.cfg
+    a = [5, 9, 2, 7, 11, 3]
+    lanes = [(a, [1], 0, GREEDY), (a[:3], [2], 0, GREEDY)]
+    served = np.asarray(runner.unified_step(lanes).last)
+    base, meta, *_ = runner._unified_operands(lanes, None, 16)
+    assert isinstance(base, tuple) and len(base) == 3
+    assert base[0] is runner.params and base[1] is runner.kv_caches
+    assert len(meta) == len(META_SEGMENTS) == 9
+    S = runner.unified_slots
+    shapes = [(16,)] * 4 + [(S, cfg.max_blocks_per_seq)] + [(S,)] * 4
+    assert [m.shape for m in meta] == shapes
+    assert all(m.dtype == np.int32 for m in meta)
+
+    def logits_fn(params, kv, sc, token_ids, *rest):
+        out = llama.unified(
+            cfg.model, params, kv, token_ids, *rest, cfg.block_size,
+            attn=runner.attn, kv_scales=sc,
+        )
+        return out[0].astype(jnp.float32), out[1]
+
+    logits, runner.kv_caches = jax.jit(logits_fn, donate_argnums=(1,))(
+        *base, *meta
+    )
+    assert np.argmax(np.asarray(logits), -1)[:2].tolist() == served[:2].tolist()
+    assert served[0] == _greedy_after(runner, a)
