@@ -1,11 +1,11 @@
-"""MoE dispatch microbenchmark: dense (all experts, gate-masked) vs
-capacity (per-expert buffers, selected FLOPs only), single-device and
-under an ep-sharded mesh.
+"""MoE expert-path microbenchmark: dense (all experts, gate-masked) vs
+grouped (rows sorted by expert, one grouped product a projection, no
+capacity and no drop), single-device and under an ep-sharded mesh.
 
-Dense computes E/topk times the selected FLOPs; capacity pays
-scatter/gather dispatch. This measures the crossover that backs the
-"auto" default (models/moe.py AUTO_CAPACITY_MIN_EXPERTS) and verifies
-token-identical outputs between the two formulations (ample capacity).
+Dense computes E/topk times the selected FLOPs and reads every expert;
+grouped pays a sort and two gathers. This measures the crossover behind
+models/moe.py GROUPED_MIN_EXPERTS and verifies that the two exact
+formulations agree.
 
 Run on the real chip: ``python benchmarks/moe_bench.py``
 Virtual 8-device ep mesh: ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 python benchmarks/moe_bench.py --mesh ep=8``
@@ -23,6 +23,7 @@ def run(cfg_kw, T, mesh=None, iters=8):
     import jax
     import jax.numpy as jnp
 
+    from dynamo_tpu.models import moe
     from dynamo_tpu.models.moe import MoeConfig, init_moe_params, moe_mlp
 
     results = {}
@@ -38,8 +39,11 @@ def run(cfg_kw, T, mesh=None, iters=8):
 
         params = shard_moe_params(params, mesh)
     outs = {}
-    for mode in ("dense", "capacity"):
-        cfg = MoeConfig(**cfg_kw, dispatch=mode, capacity_factor=4.0)
+    cfg = MoeConfig(**cfg_kw)
+    line = moe.GROUPED_MIN_EXPERTS
+    for mode in ("dense", "grouped"):
+        # the path is chosen by the expert count alone: move the line
+        moe.GROUPED_MIN_EXPERTS = 0 if mode == "grouped" else 10**9
         fn = jax.jit(lambda p, xx: moe_mlp(p, xx, cfg, mesh=mesh))
         out = fn(params, x)
         out.block_until_ready()
@@ -49,9 +53,10 @@ def run(cfg_kw, T, mesh=None, iters=8):
         out.block_until_ready()
         results[mode] = (time.monotonic() - t0) / iters * 1000
         outs[mode] = np.asarray(out)
-    # Token-identity at ample capacity (factor 4): same experts, same math.
+    moe.GROUPED_MIN_EXPERTS = line
+    # Both are exact: same experts, same math, another order of sums.
     np.testing.assert_allclose(
-        outs["dense"], outs["capacity"], rtol=2e-4, atol=2e-4
+        outs["dense"], outs["grouped"], rtol=2e-4, atol=2e-4
     )
     return results
 
@@ -73,7 +78,7 @@ def main():
         mesh = build_mesh(shape)
 
     print(f"tokens={args.tokens} mesh={args.mesh or 'single'}")
-    print(f"{'E':>4} {'topk':>4} | {'dense ms':>9} {'capacity ms':>11} | winner")
+    print(f"{'E':>4} {'topk':>4} | {'dense ms':>9} {'grouped ms':>11} | winner")
     for E, topk in ((8, 2), (16, 4), (64, 8), (128, 8)):
         r = run(
             dict(
@@ -85,9 +90,9 @@ def main():
             args.tokens,
             mesh=mesh,
         )
-        win = "capacity" if r["capacity"] < r["dense"] else "dense"
+        win = "grouped" if r["grouped"] < r["dense"] else "dense"
         print(
-            f"{E:>4} {topk:>4} | {r['dense']:>9.2f} {r['capacity']:>11.2f}"
+            f"{E:>4} {topk:>4} | {r['dense']:>9.2f} {r['grouped']:>11.2f}"
             f" | {win}"
         )
 

@@ -246,6 +246,23 @@ class EngineConfig:
                 f"speculative_k={self.speculative_k} must be in "
                 f"[0, block_size={self.block_size}]"
             )
+        B = self.model.diffusion_block_length
+        if B:
+            # A block-diffusion model (docs/architecture/unified_step.md
+            # "The block step"): a page holds whole diffusion blocks, so a
+            # page is final once its last block is committed.
+            if self.block_size % B or self.unified_prefill_quantum % B:
+                raise ValueError(
+                    f"block_size={self.block_size} and "
+                    f"unified_prefill_quantum={self.unified_prefill_quantum}"
+                    f" must be multiples of the model's "
+                    f"diffusion_block_length={B}"
+                )
+            if self.speculative_k or self.kv_sp or self.model.sliding_window:
+                raise ValueError(
+                    "a block-diffusion model serves without speculative "
+                    "drafting, the striped kv_sp cache or a sliding window"
+                )
         if self.warmup_gate not in self._WARMUP_GATES:
             raise ValueError(
                 f"warmup_gate={self.warmup_gate!r} not in "

@@ -119,6 +119,9 @@ class TpuEngine:
         self._unified_decode_tokens = 0
         self._unified_prefill_tokens = 0
         self._unified_fill_ratio = 0.0
+        # Block diffusion: lane passes dispatched and tokens they committed.
+        self._diffusion_passes = 0
+        self._diffusion_committed = 0
         # SLO-aware co-location (engine/coloc.py; ROADMAP #3): the
         # controller owns the prefill quantum — static passthrough or
         # the adaptive AIMD loop fed by measured dispatch timings below.
@@ -390,6 +393,17 @@ class TpuEngine:
             raise RequestError(
                 "frequency_penalty/presence_penalty/logprobs are not "
                 "supported with speculative decoding"
+            )
+        if self.cfg.model.diffusion_block_length and (
+            extras or pre.mm_segments
+        ):
+            # A block's rows are sampled together and committed out of
+            # order: counts over "the tokens so far" and a per-token
+            # logprob stream have no defined meaning there yet.
+            raise RequestError(
+                "frequency_penalty/presence_penalty/logprobs and "
+                "multimodal inputs are not supported by a block-diffusion "
+                f"model ({self.cfg.model.name})"
             )
 
     # -- AsyncEngine --------------------------------------------------------
@@ -676,7 +690,8 @@ class TpuEngine:
         #    runs depth-1: each dispatch's variable progress (and the
         #    host token history prompt-lookup drafts from) must be
         #    host-known before the next issue — the same rule the
-        #    phased spec path ran under.
+        #    phased spec path ran under. (A block-diffusion model keeps
+        #    its depth: a block's next pass is fed from the device.)
         depth = 1 if self._spec_active else self.cfg.pipeline_depth
         while self._inflight and (
             len(self._inflight) >= depth
@@ -781,8 +796,9 @@ class TpuEngine:
                 # host-known when that dispatch processes.
                 continue
             decode_ready.append(seq)
+        B_blk = cfg.model.diffusion_block_length
         prefill_items = [
-            (s, len(s.prompt_tokens) - s.prefill_cursor)
+            (s, self._prefill_target(s) - s.prefill_cursor)
             for s in self._prefilling
             if s.status is SeqStatus.PREFILLING
         ]
@@ -815,7 +831,8 @@ class TpuEngine:
                 if drafts:
                     draft_map[id(seq)] = drafts
         decode_items = [
-            (seq, 1 + len(draft_map.get(id(seq), []))) for seq in decode_ready
+            (seq, B_blk or 1 + len(draft_map.get(id(seq), [])))
+            for seq in decode_ready
         ]
         decode_take, prefill_take = compose_unified(
             decode_items, prefill_items, cfg.unified_token_budget,
@@ -834,6 +851,32 @@ class TpuEngine:
         n_drafted = 0
         for seq, width in decode_take:
             s = len(lanes)
+            if B_blk:
+                # A block pass: the block's ids at its B positions, -1
+                # where a row is fed as a mask. Without a masked row it
+                # is the block's commit pass. With a pass of the block
+                # still in flight, what that pass was fed is host-known
+                # (everything before it has retired) and decides this
+                # one: behind a commit pass the NEXT block opens, all
+                # masks; behind a denoising pass the same block is fed
+                # from the device, whatever that pass commits.
+                if seq.blk_inflight > 0:
+                    if any(t < 0 for t in seq.blk_ids):
+                        use_prev[s] = True
+                        prev_row[s] = self._prev_unified_rows[id(seq)]
+                    else:
+                        self._open_block(seq)
+                        if seq.status is not SeqStatus.RUNNING:
+                            continue  # the next block passes the limit
+                lanes.append((
+                    seq.blk_ids, seq.block_ids, seq.blk_start,
+                    self._lane_sampling(seq),
+                ))
+                draft_lens.append(0)
+                roles.append((seq, "block", seq.blk_start, B_blk, True))
+                seq.inflight_chunks += 1
+                seq.blk_inflight += 1
+                continue
             n = seq.device_len
             drafts = draft_map.get(id(seq), []) if width > 1 else []
             if drafts:
@@ -868,6 +911,12 @@ class TpuEngine:
         for seq, n in prefill_take:
             s = len(lanes)
             start = seq.prefill_cursor
+            if B_blk:
+                # Quanta end on a diffusion block's boundary (the budget,
+                # the quantum and the target are multiples of B).
+                n -= n % B_blk
+                if n <= 0:
+                    continue
             toks = seq.prompt_tokens[start : start + n]
             lanes.append(
                 (toks, seq.block_ids, start, self._lane_sampling(seq))
@@ -878,10 +927,15 @@ class TpuEngine:
                     mm_rows.append(None)
                 mm_rows.append(_mm_for_chunk(seq, start, n))
             seq.prefill_cursor = start + n
-            done = seq.prefill_cursor >= len(seq.prompt_tokens)
-            roles.append((seq, "prefill", start, n, done))
+            done = seq.prefill_cursor >= self._prefill_target(seq)
+            roles.append((seq, "prefill", start, n, done and not B_blk))
             seq.inflight_chunks += 1
-            if done:
+            if done and B_blk:
+                # The prompt's whole blocks are fed; its tail opens the
+                # first generated block, whose passes hand out the tokens.
+                seq.status = SeqStatus.RUNNING
+                self._open_block(seq)
+            elif done:
                 # Decodable from the NEXT dispatch: its first generated
                 # token is this dispatch's sample at row s, read on
                 # device through the feed (delivered at process time).
@@ -919,7 +973,7 @@ class TpuEngine:
         prev = (
             self._prev_unified_out
             if self._prev_unified_out is not None
-            else np.zeros(S, np.int32)
+            else np.zeros((S, B_blk) if B_blk else S, np.int32)
         )
         # Dispatch-start timestamp: paired with the retire time in
         # _process_unified_chunk to measure what decode lanes actually
@@ -938,8 +992,8 @@ class TpuEngine:
         self._prev_unified_rows = {
             id(seq): i for i, (seq, *_r) in enumerate(roles)
         }
-        n_dec = len(decode_take)
-        n_pre = sum(n for _, n in prefill_take)
+        n_dec = len(decode_take) * (B_blk or 1)
+        n_pre = sum(r[3] for r in roles if r[1] == "prefill")
         self._unified_decode_tokens += n_dec
         self._unified_prefill_tokens += n_pre
         self._spec_drafted += n_drafted
@@ -983,7 +1037,11 @@ class TpuEngine:
                 (out, lp),
             )
         )
-        if n_drafted == 0:
+        if B_blk:
+            # A block dispatch records at retire too: what it committed
+            # is device-side until then.
+            self._diffusion_passes += len(decode_take)
+        elif n_drafted == 0:
             # Spec dispatches record at PROCESS time instead (the
             # accepted counts are device-side until retire); everything
             # else records at issue, as before.
@@ -1025,6 +1083,13 @@ class TpuEngine:
             n_dec, n_pre, t_issue, t_dispatch, drafted,
             spec_counted, compose_ms,
         ) = stats
+        B_blk = self.cfg.model.diffusion_block_length
+        blk_ids = None
+        experts_hit = 0
+        if B_blk:
+            if n_dec:
+                blk_ids = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
+            experts_hit = int(np.asarray(out.experts_hit))  # dynalint: allow[DT005] same retirement boundary as `toks`
         spec_counts = spec_toks = None
         if drafted:
             # Spec contract: the emitted rows + device-side accepted
@@ -1071,8 +1136,50 @@ class TpuEngine:
         for seq, *_rest in roles:
             seq.inflight_chunks -= 1
         n_accepted = 0
+        n_committed = denoise_rows = commit_rows = 0
         for i, (seq, kind, start, n, deliver) in enumerate(roles):
-            if kind in ("decode", "spec"):
+            if kind == "block":
+                # What the pass was fed: the host's state of the block
+                # (for a device-fed pass, what the pass before it left),
+                # or, where the sequence has moved on to its next block
+                # behind this pass, a block without a masked row.
+                seq.blk_inflight -= 1
+                moved_on = start != seq.blk_start
+                fed_masked = 0 if moved_on else sum(
+                    t < 0 for t in seq.blk_ids)
+                denoise_rows += B_blk if fed_masked else 0
+                commit_rows += 0 if fed_masked else B_blk
+                if seq.status is not SeqStatus.RUNNING:
+                    continue  # stopped while in flight; the pass is void
+                ids = blk_ids[i].tolist()  # dynalint: allow[DT005] a row of the host array forced above, no device value
+                if not moved_on:
+                    seq.blk_ids = ids
+                    left = sum(t < 0 for t in ids)
+                    n_committed += fed_masked - left
+                    # A token leaves once every position before it is
+                    # committed: deliver the committed run behind what
+                    # has been delivered (stop conditions end the request
+                    # at that token, whatever lies committed behind it).
+                    while seq.status is SeqStatus.RUNNING:
+                        j = seq.total_len - start
+                        if j >= B_blk or ids[j] < 0:
+                            break
+                        self._deliver(seq, ids[j])
+                    if fed_masked and not left:
+                        tracer().span_end(seq.request_id, "block_denoise")
+                if fed_masked == 0 and seq.status is SeqStatus.RUNNING:
+                    # The commit pass: the block's keys and values are
+                    # final. Its tokens join the hash chain, pages it
+                    # completed become reusable, and the next block opens
+                    # unless it was opened behind this pass already.
+                    if seq.hashes is not None:
+                        seq.hashes.extend(ids[len(seq.hashes) - start:])
+                    self.scheduler.register_filled_blocks(
+                        seq, start + B_blk
+                    )
+                    if not moved_on:
+                        self._open_block(seq)
+            elif kind in ("decode", "spec"):
                 if seq.status is not SeqStatus.RUNNING:
                     continue  # stopped while in flight; token discarded
                 if spec_counted:
@@ -1132,6 +1239,21 @@ class TpuEngine:
                 self.scheduler._release(seq)
             elif seq.status is SeqStatus.RUNNING:
                 self.scheduler.evict_behind_window(seq, seq.total_len)
+        if B_blk:
+            self._diffusion_committed += n_committed
+            self._note_step(
+                "unified",
+                decode_tokens=n_dec,
+                prefill_tokens=n_pre,
+                fill=self._unified_fill_ratio,
+                dispatch_ms=compose_ms,
+                lanes=len(roles),
+                diffusion_lanes=(denoise_rows + commit_rows) // B_blk,
+                denoise_rows=denoise_rows,
+                commit_rows=commit_rows,
+                committed_tokens=n_committed,
+                moe_experts_hit=experts_hit,
+            )
         if drafted:
             self._spec_accepted += n_accepted
             # Spec dispatches record their flight entry at retirement —
@@ -1176,6 +1298,39 @@ class TpuEngine:
         out, _lp = record[3]  # (kind, roles, stats, (UnifiedOut, lp))
         is_ready = getattr(out.last, "is_ready", None)
         return bool(is_ready()) if is_ready is not None else True
+
+    def _prefill_target(self, seq: Sequence) -> int:
+        """Prompt tokens that prefill feeds: all of them, or under block
+        diffusion the prompt's whole blocks (its last ``P mod B`` tokens
+        open the first generated block)."""
+        B = self.cfg.model.diffusion_block_length
+        P = len(seq.prompt_tokens)
+        return P - P % B if B else P
+
+    def _open_block(self, seq: Sequence) -> None:
+        """Open the diffusion block at the sequence's committed length:
+        the tokens already known there (the prompt's tail; after a
+        preemption the delivered rows, and whatever else the kept block
+        had committed), masks behind them. A block that would pass the
+        context limit ends the request."""
+        B = self.cfg.model.diffusion_block_length
+        start = seq.total_len - seq.total_len % B
+        if start + B > self.cfg.max_model_len:
+            self.scheduler.finish(seq, FinishReason.LENGTH)
+            return
+        P = len(seq.prompt_tokens)
+        known = seq.prompt_tokens[start:] + seq.output_tokens[max(start - P, 0):]
+        masks = [-1] * (B - len(known))
+        if (
+            seq.blk_start < 0
+            and len(seq.blk_ids) == B
+            and seq.blk_ids[: len(known)] == known
+        ):
+            # Re-admitted after a preemption: the block it was in.
+            masks = seq.blk_ids[len(known):]
+        seq.blk_ids = known + masks
+        seq.blk_start = start
+        tracer().span_begin(seq.request_id, "block_denoise")
 
     @staticmethod
     def _lane_sampling(seq: Sequence) -> tuple[float, int, float, int]:
@@ -1262,8 +1417,16 @@ class TpuEngine:
         if seq.num_cached_prefix:
             self._prefix_hits += 1
         self._note_kv_actual(seq)
-        seq.status = SeqStatus.PREFILLING
         seq.prefill_cursor = seq.num_cached_prefix
+        if (
+            self.cfg.model.diffusion_block_length
+            and seq.prefill_cursor >= self._prefill_target(seq)
+        ):
+            # Every whole block of the prompt is cached (or it has none):
+            # straight to the first generated block.
+            self._open_block(seq)
+            return
+        seq.status = SeqStatus.PREFILLING
         self._prefilling.append(seq)
 
     def _maybe_park_for_peer_pull(self, seq: Sequence) -> bool:
@@ -1698,6 +1861,7 @@ class TpuEngine:
         lanes: int = 0,
         drafted: int = 0,
         accepted: int = 0,
+        **diffusion: int,
     ) -> None:
         """One dispatch's flight record (engine thread). Counter fields
         are snapshots, so a reader diffs adjacent records to attribute a
@@ -1715,6 +1879,7 @@ class TpuEngine:
             lanes=lanes,
             drafted=drafted,
             accepted=accepted,
+            **diffusion,
             # The runner's last dispatch IS this record's: plain records
             # are noted at issue, spec records at retire under depth 1.
             operand_transfers=getattr(self.runner, "operand_transfers", 0),
@@ -2348,6 +2513,7 @@ class TpuEngine:
             m["unified_operand_transfers_total"] = getattr(
                 self.runner, "operand_transfers_total", 0
             )
+            m.update(self._diffusion_counters())
             m["batch_fill_ratio"] = round(self._unified_fill_ratio, 4)
             # Co-location controller surface (engine/coloc.py):
             # quantum, ITL estimates vs the SLO, violation and
@@ -2602,12 +2768,32 @@ class TpuEngine:
         d["unified_operand_transfers_total"] = getattr(
             self.runner, "operand_transfers_total", 0
         )
+        d.update(self._diffusion_counters())
         d["batch_fill_ratio"] = round(self._unified_fill_ratio, 4)
         d.update(self.coloc.snapshot())
         cs = getattr(self.runner, "compile_stats", None)
         if cs is not None:
             d.update(cs.snapshot())
         return d
+
+    def _diffusion_counters(self) -> dict:
+        """Block-diffusion and grouped-expert totals (zero on models
+        without them): lane passes dispatched, tokens they committed, and
+        routed rows through the grouped expert path (rows fed x experts a
+        token x expert layers, by arithmetic over the dispatches)."""
+        from dynamo_tpu.models.moe import GROUPED_MIN_EXPERTS
+
+        m = self.cfg.model
+        grouped = m.is_moe and m.num_experts >= GROUPED_MIN_EXPERTS
+        layers = m.num_layers - m.first_k_dense_replace
+        return {
+            "diffusion_passes_total": self._diffusion_passes,
+            "diffusion_committed_tokens_total": self._diffusion_committed,
+            "moe_grouped_rows_total": (
+                (self._unified_decode_tokens + self._unified_prefill_tokens)
+                * m.num_experts_per_tok * layers if grouped else 0
+            ),
+        }
 
     @property
     def degraded_requests(self) -> int:

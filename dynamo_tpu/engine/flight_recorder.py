@@ -74,6 +74,11 @@ class FlightRecorder:
         drafted: int = 0,
         accepted: int = 0,
         operand_transfers: int = 0,
+        diffusion_lanes: int = 0,
+        denoise_rows: int = 0,
+        commit_rows: int = 0,
+        committed_tokens: int = 0,
+        moe_experts_hit: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -86,7 +91,14 @@ class FlightRecorder:
         spec counters on the metric surfaces. ``operand_transfers`` is
         the host arrays the runner handed to the device for the
         dispatch: one packed buffer (engine/runner.py operand_layout),
-        plus a replayed host feed or the multimodal rows."""
+        plus a replayed host feed or the multimodal rows. The four
+        diffusion fields are a block-diffusion model's: lanes that fed a
+        block, the rows fed in denoising passes and in commit passes
+        (a block without a masked row), and the tokens the dispatch
+        committed (known at its retire, where such a dispatch records);
+        ``moe_experts_hit`` is the experts that had a row, summed over its
+        grouped expert layers: the weights its grouped kernels had to
+        read, which routing decides."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -98,6 +110,11 @@ class FlightRecorder:
             "drafted": drafted,
             "accepted": accepted,
             "operand_transfers": operand_transfers,
+            "diffusion_lanes": diffusion_lanes,
+            "denoise_rows": denoise_rows,
+            "commit_rows": commit_rows,
+            "committed_tokens": committed_tokens,
+            "moe_experts_hit": moe_experts_hit,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

@@ -17,6 +17,11 @@ handful of programs. Three program variants share the trunk:
   ``cfg.speculative_k > 0`` the SAME ladder carries draft-verify spans
   — per-span verify logits, greedy accept-prefix, and the bonus sample
   all run in-dispatch, so spec decode adds ZERO extra programs.
+  A block-diffusion model (``model.diffusion_block_length`` = B > 0)
+  runs the ladder's "block" variant instead: a lane's span is a block
+  of B rows, every row sampled with its confidence and the commit rule
+  applied in-dispatch, the block's next pass fed from the device
+  (docs/architecture/unified_step.md "The block step").
 - **unified_full** (one program, top budget rung): sampling extras —
   frequency/presence penalties over the per-slot count buffer plus
   top-logprob outputs — dispatched only for batches that need them.
@@ -52,6 +57,7 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.ops.sampling import (
     MAX_LOGPROBS,
     apply_penalties,
+    commit_block,
     sample_tokens,
     token_logprobs,
 )
@@ -65,11 +71,17 @@ class UnifiedOut(NamedTuple):
     ``last``: [S] — span s's (final) sampled token; the next dispatch's
     device feed. ``toks``/``counts`` are the spec contract ([S, K+1]
     emitted rows / accepted+1 per span) on a speculative engine's
-    budget-ladder program, None otherwise."""
+    budget-ladder program, or the block contract of a block-diffusion
+    model's ([S, B] the block's ids after the pass, -1 where a row is
+    still masked / rows committed by it; ``last`` is ``toks`` then: the
+    device feed of the block's next pass), None otherwise."""
 
     last: Any
     toks: Any = None
     counts: Any = None
+    #: block contract only: experts that had a row, summed over the
+    #: grouped expert layers (a scalar; 0 where the model has none)
+    experts_hit: Any = None
 
 
 def _norm_sampling(sampling) -> tuple[float, int, float, int]:
@@ -130,8 +142,10 @@ def operand_layout(
     """THE source of the packed buffer's offsets, for a dispatch of
     budget ``T`` over ``S`` metadata rows. ``variant``: "plain" (the
     budget ladder), "spec" (the ladder of a speculative engine: adds
-    ``drafts``/``draft_len``) or "extras" (the penalties/logprob and
-    multimodal programs: adds the count-buffer rows)."""
+    ``drafts``/``draft_len``), "block" (the ladder of a block-diffusion
+    model: adds ``tok_masked``, which rows are fed as masks) or "extras"
+    (the penalties/logprob and multimodal programs: adds the count-buffer
+    rows)."""
     rows = [
         ("token_ids", (T,), np.int32, 0),
         ("token_pos", (T,), np.int32, -1),      # -1 = padding row
@@ -155,6 +169,8 @@ def operand_layout(
             ("drafts", (S, speculative_k), np.int32, 0),
             ("draft_len", (S,), np.int32, 0),
         ]
+    elif variant == "block":
+        rows += [("tok_masked", (T,), bool, 0)]
     elif variant == "extras":
         rows += [
             ("span_slot", (S,), np.int32, -1),
@@ -553,6 +569,26 @@ class ModelRunner(WarmupPlanMixin):
             rows = jnp.where(use_prev, row_start, T)
             return token_ids.at[rows].set(prev_toks[prev_row], mode="drop")
 
+        def _feed_block(o, prev_ids):
+            """The block step's device feed: a feeding lane's B rows are
+            the PREVIOUS dispatch's block ids ([S, B], -1 = still masked)
+            from its old metadata row — the ids and which rows are masks
+            both come from the device, so a block's next pass is issued
+            before the host has read what the last one committed."""
+            T = o["token_ids"].shape[0]
+            B = prev_ids.shape[1]
+            rows = (
+                jnp.where(o["use_prev"], o["row_start"], T)[:, None]
+                + jnp.arange(B)[None, :]
+            )
+            fed = prev_ids[o["prev_row"]]                        # [S, B]
+            o["token_ids"] = o["token_ids"].at[rows].set(
+                jnp.where(fed < 0, m.mask_token_id, fed), mode="drop"
+            )
+            o["tok_masked"] = o["tok_masked"].at[rows].set(
+                fed < 0, mode="drop"
+            )
+
         S_rows = self.unified_slots
         MB = cfg.max_blocks_per_seq
 
@@ -563,10 +599,13 @@ class ModelRunner(WarmupPlanMixin):
             o = operand_layout_of(
                 packed.shape[0], S_rows, MB, K_spec, variant
             ).unpack(packed)
-            o["token_ids"] = _feed_tokens(
-                o["token_ids"], o["row_start"], o["use_prev"],
-                o["prev_row"], prev_toks,
-            )
+            if variant == "block":
+                _feed_block(o, prev_toks)
+            else:
+                o["token_ids"] = _feed_tokens(
+                    o["token_ids"], o["row_start"], o["use_prev"],
+                    o["prev_row"], prev_toks,
+                )
             return o, [o[name] for name in META_SEGMENTS]
 
         def unified_fn(params, kv, kv_sc, packed, prev_toks):
@@ -645,6 +684,54 @@ class ModelRunner(WarmupPlanMixin):
             )
             counts = jnp.where(q_len > 0, acc + 1, 0)
             return emitted, counts, bonus, kv, kv_sc
+
+        B_blk = m.diffusion_block_length
+
+        def block_unified_fn(params, kv, kv_sc, packed, prev_toks):
+            """(Named ``*unified_fn`` like the plain program: the device
+            trace's module name is how the benchmark finds the step.)
+            The budget-ladder program of a block-diffusion model
+            (``m.diffusion_block_length`` = B > 0): the SAME ragged
+            dispatch under the mask by block, every span's last B rows
+            read for logits (``llama.unified`` verify_rows — a block pass
+            IS a span of B rows, a prefill quantum's last block rides
+            along unread), every row sampled with its confidence and the
+            commit rule applied in-dispatch (ops/sampling.py
+            commit_block). A block fed without a masked row is its commit
+            pass: nothing is sampled into it and its keys and values are
+            what the cache keeps. Returns (ids [S, B], counts [S], the
+            experts that had a row summed over the grouped expert layers,
+            kv, kv_sc)."""
+            from dynamo_tpu.models.moe import collect_experts_hit
+
+            o, meta = _unpack(packed, "block", prev_toks)
+            q_len = o["q_len"]
+            with collect_experts_hit() as hit:
+                out = llama.unified(
+                    m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
+                    draft_len=jnp.full_like(q_len, B_blk - 1),
+                    verify_rows=B_blk,
+                )
+            experts_hit = sum(hit, jnp.zeros((), jnp.int32))
+            logits, kv = out[0], out[1]          # [S, B, V]
+            kv_sc = out[2] if kv_sc is not None else None
+            T = o["token_ids"].shape[0]
+            offs = jnp.arange(B_blk)[None, :]
+            rows = jnp.clip(
+                (o["row_start"] + jnp.maximum(q_len - B_blk, 0))[:, None]
+                + offs, 0, T - 1,
+            )
+            live = offs < q_len[:, None]
+            with jax.named_scope("block_sampler"):
+                ids, counts = commit_block(
+                    logits, o["token_ids"][rows],
+                    o["tok_masked"][rows] & live, o["key"], o["temp"],
+                    o["top_k"], o["top_p"], o["seed"],
+                    o["kv_len"] - jnp.minimum(q_len, B_blk),
+                    m.confidence_threshold,
+                    -(-B_blk // max(m.denoising_steps, 1)),
+                )
+            return jnp.where(live, ids, 0), counts, experts_hit, kv, kv_sc
 
         def make_unified_extras_fn(with_mm: bool):
             """Factory for the extras variants (penalties + logprobs over
@@ -733,7 +820,9 @@ class ModelRunner(WarmupPlanMixin):
 
         self._tok_sh = tok_sh
         #: The budget ladder's operand layout variant.
-        self._ladder_variant = "spec" if K_spec > 0 else "plain"
+        self._ladder_variant = (
+            "spec" if K_spec > 0 else "block" if B_blk else "plain"
+        )
         #: Host arrays handed to the device, by the last dispatch and in
         #: all (the flight recorder and /metrics read them).
         self.operand_transfers = 0
@@ -755,14 +844,22 @@ class ModelRunner(WarmupPlanMixin):
         # and sharded like the unified programs' own token output (see
         # _unified_operands). Built by a jit so that under multi-host no
         # host array has to be checked equal across processes.
+        # (A block-diffusion model feeds a block's ids: [S, B].)
+        prev_shape = (self.unified_slots,) + (
+            (m.diffusion_block_length,) if m.diffusion_block_length else ()
+        )
         self._zero_prev = _jit(
-            lambda: jnp.zeros(self.unified_slots, jnp.int32),
-            tok_sh,
+            lambda: jnp.zeros(prev_shape, jnp.int32), tok_sh,
         )()
         if K_spec > 0:
             self._unified = _jit(
                 unified_spec_fn,
                 (tok_sh, tok_sh, tok_sh, kv_sh, sc_sh),
+                donate_argnums=(1, 2),
+            )
+        elif B_blk:
+            self._unified = _jit(
+                block_unified_fn, (tok_sh, tok_sh, tok_sh, kv_sh, sc_sh),
                 donate_argnums=(1, 2),
             )
         else:
@@ -826,6 +923,10 @@ class ModelRunner(WarmupPlanMixin):
             return None
         if kind == "unified":
             return lambda: self.unified_step(warm_lanes)
+        if cfg.model.diffusion_block_length:
+            # A block-diffusion model refuses penalties, logprobs and
+            # multimodal inputs at the request: those programs never run.
+            return None
         if kind == "unified_full":
             if not cfg.sampling_extras:
                 return None
@@ -1081,7 +1182,9 @@ class ModelRunner(WarmupPlanMixin):
         ``lanes``: [(new_tokens, block_ids, prefix_len, sampling), ...] —
         span s of the flat batch is lane s's tokens; a decode lane is a
         single token, a prefill quantum its chunk, a draft-verify span
-        the fed token plus its drafts. Total tokens snap UP to the
+        the fed token plus its drafts, a block-diffusion model's block
+        pass the block's ids with -1 where a row is fed as a mask.
+        Total tokens snap UP to the
         budget ladder (compile_cache.token_budget) — the ONLY compiled
         extent, in place of the phase×bucket×lane grid.
 
@@ -1187,6 +1290,12 @@ class ModelRunner(WarmupPlanMixin):
         if variant == "spec":
             toks2d, counts, bonus, self.kv_caches, self.kv_scales = out
             return UnifiedOut(last=bonus, toks=toks2d, counts=counts)
+        if variant == "block":
+            ids, counts, hit, self.kv_caches, self.kv_scales = out
+            # `ids` is the next dispatch's device feed.
+            return UnifiedOut(
+                last=ids, toks=ids, counts=counts, experts_hit=hit
+            )
         toks, self.kv_caches, self.kv_scales = out
         return UnifiedOut(last=toks, toks=None, counts=None)
 
@@ -1234,6 +1343,12 @@ class ModelRunner(WarmupPlanMixin):
             seg["token_ids"][:total] = np.fromiter(
                 chain.from_iterable(t for t, _, _, _ in lanes), np.int32, total
             )
+            if "tok_masked" in seg:
+                # A negative id is a row fed as a mask (block diffusion).
+                ids = seg["token_ids"][:total]
+                masked = ids < 0
+                seg["tok_masked"][:total] = masked
+                ids[masked] = cfg.model.mask_token_id
             seg["token_seq"][:total] = token_seq
             seg["token_pos"][:total] = token_pos
             seg["slot_mapping"][:total] = (

@@ -359,11 +359,18 @@ class Scheduler:
                 # their in-flight dispatches retire, same as
                 # WAITING_REMOTE slots.
                 continue
+            B = self.cfg.model.diffusion_block_length
+            if B and seq.blk_start < 0:
+                continue  # no block open (engine _open_block)
             # Clamp to the block-table width: speculative lookahead can
             # overshoot the context cap; the engine caps draft_len so no
-            # verify-span write lands past the allocated span.
+            # verify-span write lands past the allocated span. A
+            # block-diffusion lane writes its whole block every pass, and
+            # behind a commit pass in flight the engine opens the NEXT
+            # block at issue: fund one block ahead.
             needed_block = min(
-                (seq.device_len - 2 + lookahead) // bs,
+                (seq.blk_start + 2 * B - 1) // bs if B
+                else (seq.device_len - 2 + lookahead) // bs,
                 self.cfg.max_blocks_per_seq - 1,
             )
             while needed_block >= len(seq.block_ids):
@@ -423,6 +430,14 @@ class Scheduler:
         seq.num_cached_prefix = 0
         seq.sched_len = 0
         seq.evicted_pages = 0  # re-admission refunds the whole prompt
+        # A block-diffusion sequence keeps a block that still has a masked
+        # row: re-admission opens it at the same position with the rows
+        # it had committed. A block without one is all delivered, so it
+        # is prompt now.
+        seq.blk_start = -1
+        seq.blk_inflight = 0
+        if all(t >= 0 for t in seq.blk_ids):
+            seq.blk_ids = []
         # Re-admission may land in a different slot whose [vocab] penalty
         # count row holds another sequence's history — re-arm the reset.
         seq.counts_reset_pending = True

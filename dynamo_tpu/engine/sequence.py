@@ -115,6 +115,18 @@ class Sequence:
     # un-prefilled prompt as fully cached context and emit from it.
     peer_parked: bool = False
 
+    # Block diffusion (models with diffusion_block_length = B > 0): the
+    # in-flight block. blk_start is the position of its first row (-1 =
+    # none open yet: the prompt's whole blocks are still prefilling);
+    # blk_ids its B ids, -1 where a row is still masked. Everything before
+    # blk_start is committed — final tokens, final keys and values — and
+    # output_tokens holds what was DELIVERED: the committed rows up to the
+    # first masked one. A preempted sequence keeps blk_ids, so its block
+    # resumes where it stood.
+    blk_start: int = -1
+    blk_ids: list[int] = field(default_factory=list)
+    blk_inflight: int = 0        # block passes issued and not yet retired
+
     @property
     def total_len(self) -> int:
         return len(self.prompt_tokens) + len(self.output_tokens)

@@ -209,6 +209,9 @@ class HttpService:
                 "unified_step_tokens_decode_total",
                 "unified_step_tokens_prefill_total",
                 "unified_operand_transfers_total",
+                "diffusion_passes_total",
+                "diffusion_committed_tokens_total",
+                "moe_grouped_rows_total",
                 "batch_fill_ratio",
                 "coloc_quantum",
                 "itl_ema_ms",

@@ -54,6 +54,11 @@ class ForwardPassMetrics:
     # Host arrays handed to the device across unified dispatches: one
     # packed operand buffer each (two with a replayed host feed).
     unified_operand_transfers_total: int = 0
+    # Block diffusion and the grouped expert path (zero on models without
+    # them): lane passes, tokens they committed, routed expert rows.
+    diffusion_passes_total: int = 0
+    diffusion_committed_tokens_total: int = 0
+    moe_grouped_rows_total: int = 0
     batch_fill_ratio: float = 0.0
     # SLO-aware co-location (engine/coloc.py; ROADMAP #3): the live
     # prefill quantum, decode ITL EMA vs the configured SLO, dispatches

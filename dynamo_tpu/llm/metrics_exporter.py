@@ -43,6 +43,9 @@ _GAUGES = (
     ("unified_step_tokens_decode_total", "Decode tokens via unified steps"),
     ("unified_step_tokens_prefill_total", "Prefill tokens via unified steps"),
     ("unified_operand_transfers_total", "Host arrays handed to the device by unified dispatches"),
+    ("diffusion_passes_total", "Block-diffusion lane passes dispatched"),
+    ("diffusion_committed_tokens_total", "Tokens committed by block-diffusion passes"),
+    ("moe_grouped_rows_total", "Routed rows through the grouped expert path"),
     ("batch_fill_ratio", "Unified batch fill (real tokens / budget)"),
     ("coloc_quantum", "Live prefill quantum (coloc controller)"),
     ("itl_ema_ms", "Decode inter-token-latency EMA, ms"),

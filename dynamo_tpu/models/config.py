@@ -93,16 +93,18 @@ class ModelConfig:
     # n_group groups; only the topk_group best groups are eligible).
     n_group: int = 1
     topk_group: int = 1
-    # Expert execution strategy (models/moe.py): "dense" runs every
-    # expert gate-masked (exact; fine for few experts); "capacity"
-    # dispatches tokens to per-expert buffers and runs only selected
-    # FLOPs — the large-expert-count serving mode (R1: 32× less MLP
-    # compute; capacity overflow drops follow the standard rule).
-    # "auto" (default) picks capacity when num_experts >= 16 — the
-    # crossover where dense's E/topk FLOP waste outweighs dispatch
-    # overhead (older harness, not reproduced).
-    moe_dispatch: str = "auto"
-    moe_capacity_factor: float = 2.0
+    # --- block diffusion (SDAR family; docs/architecture/unified_step.md
+    # "The block step") --- diffusion_block_length B > 0: attention is
+    # masked BY BLOCK (key j visible to query i iff j // B <= i // B),
+    # logits are read unshifted, and generation feeds a block of
+    # mask_token_id rows and commits the confident ones a pass: every
+    # masked row whose sampled token's probability reaches
+    # confidence_threshold, and at least ceil(B / denoising_steps) of the
+    # most confident. 0 = causal, one token a step.
+    diffusion_block_length: int = 0
+    mask_token_id: int = 0
+    denoising_steps: int = 0
+    confidence_threshold: float = 0.0
 
     @property
     def is_moe(self) -> bool:
@@ -174,6 +176,7 @@ class ModelConfig:
         num_heads = cfg["num_attention_heads"]
         hidden = cfg["hidden_size"]
         deepseek = "Deepseek" in arch or "deepseek" in cfg.get("model_type", "")
+        sdar = cfg.get("model_type", "").startswith("sdar")
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -188,7 +191,9 @@ class ModelConfig:
             max_position=cfg.get("max_position_embeddings", 8192),
             tie_word_embeddings=cfg.get("tie_word_embeddings", False),
             qkv_bias="Qwen2" in arch,
-            qk_norm="Qwen3" in arch,
+            # SDAR's config.json has no key for them; its layers are
+            # Qwen3's, per-head q/k norms included.
+            qk_norm="Qwen3" in arch or sdar,
             # Mistral carries sliding_window unconditionally (null = full
             # attention in v0.2+); Qwen2 gates it behind use_sliding_window.
             sliding_window=int(cfg.get("sliding_window") or 0)
@@ -197,9 +202,11 @@ class ModelConfig:
             max_window_layers=int(cfg.get("max_window_layers") or 0)
             if cfg.get("use_sliding_window", True)
             else 0,
-            # DeepSeek uses n_routed_experts; Mixtral num_local_experts.
+            # DeepSeek uses n_routed_experts; Mixtral num_local_experts;
+            # Qwen3-MoE and SDAR num_experts.
             num_experts=cfg.get(
-                "n_routed_experts", cfg.get("num_local_experts", 0)
+                "n_routed_experts",
+                cfg.get("num_local_experts", cfg.get("num_experts", 0)),
             ) or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
             rope_scaling=_rope_scaling(cfg.get("rope_scaling")),
@@ -216,6 +223,9 @@ class ModelConfig:
             routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
             n_group=cfg.get("n_group", 1) or 1,
             topk_group=cfg.get("topk_group", 1) or 1,
+            # Generation settings the family's config.json does not carry
+            # (its generate script's defaults).
+            **(SDAR_GENERATION if sdar else {}),
         )
 
     @staticmethod
@@ -540,6 +550,57 @@ class ModelConfig:
         )
 
     @staticmethod
+    def sdar_30b_a3b() -> "ModelConfig":
+        """SDAR-30B-A3B-Chat (HF JetLM/SDAR-30B-A3B-Chat config.json,
+        model_type sdar_moe): Qwen3-MoE layers (GQA 32/4 x 128 with
+        per-head q/k norms, 128 experts of width 768, 8 a token
+        renormalised, no shared expert, no dense layer) under a
+        block-causal mask, generated by block diffusion."""
+        return ModelConfig(
+            name="sdar-30b-a3b",
+            vocab_size=151936,
+            hidden_size=2048,
+            intermediate_size=6144,  # carried; every layer is sparse
+            num_layers=48,
+            num_heads=32,
+            num_kv_heads=4,
+            head_dim=128,
+            rope_theta=1000000.0,
+            rms_eps=1e-6,
+            max_position=32768,
+            qk_norm=True,
+            num_experts=128,
+            num_experts_per_tok=8,
+            moe_intermediate_size=768,
+            norm_topk_prob=True,
+            **SDAR_GENERATION,
+        )
+
+    @staticmethod
+    def tiny_sdar_test(vocab_size: int = 384) -> "ModelConfig":
+        """Hermetic SDAR-style test model: block diffusion over 16 routed
+        experts (the grouped expert path), 4 a token."""
+        return ModelConfig(
+            name="tiny-sdar-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=2,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=1000000.0,
+            rms_eps=1e-6,
+            max_position=512,
+            qk_norm=True,
+            num_experts=16,
+            num_experts_per_tok=4,
+            moe_intermediate_size=32,
+            norm_topk_prob=True,
+            **{**SDAR_GENERATION, "mask_token_id": vocab_size - 1},
+        )
+
+    @staticmethod
     def llama3_8b() -> "ModelConfig":
         return ModelConfig(
             name="llama3-8b",
@@ -637,8 +698,19 @@ class ModelConfig:
         return replace(self, **kwargs)
 
 
+#: SDAR's generation settings (its generate script's defaults; the
+#: config.json carries none of them).
+SDAR_GENERATION = {
+    "diffusion_block_length": 4,
+    "mask_token_id": 151669,
+    "denoising_steps": 4,
+    "confidence_threshold": 0.9,
+}
+
 PRESETS = {
     "tiny-test": ModelConfig.tiny_test,
+    "tiny-sdar-test": ModelConfig.tiny_sdar_test,
+    "sdar-30b-a3b": ModelConfig.sdar_30b_a3b,
     "tiny-moe-test": ModelConfig.tiny_moe_test,
     "tiny-mla-test": ModelConfig.tiny_mla_test,
     "deepseek-v2-lite": ModelConfig.deepseek_v2_lite,

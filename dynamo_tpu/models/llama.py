@@ -373,8 +373,8 @@ def _mlp(
 ) -> jnp.ndarray:
     # Structure-driven: a router in the layer means routed experts (MoE
     # models may keep their first_k_dense_replace layers dense). `mesh`
-    # (from the AttnDispatch) lets capacity dispatch pin its ep
-    # collectives explicitly (models/moe.py _moe_mlp_capacity).
+    # (from the AttnDispatch) places the grouped expert path's products
+    # per shard (models/moe.py _moe_mlp_grouped).
     if "w_router" in layer:
         return _moe_mlp(layer, x, cfg, mesh)
     return _swiglu(layer, x, act=cfg.hidden_act)
@@ -383,8 +383,9 @@ def _mlp(
 def _moe_mlp(
     layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None
 ) -> jnp.ndarray:
-    """Top-k routed expert MLP over arbitrary leading dims (models/moe.py
-    dense-einsum formulation, ep/tp-sharded under the mesh), plus
+    """Top-k routed expert MLP over arbitrary leading dims (models/moe.py:
+    dense einsums below 16 experts, the grouped path from there up;
+    ep/tp-sharded under the mesh), plus
     DeepSeekMoE always-on shared experts when present."""
     from dynamo_tpu.models.moe import MoeConfig, moe_mlp
 
@@ -398,8 +399,6 @@ def _moe_mlp(
         routed_scaling_factor=cfg.routed_scaling_factor,
         n_group=cfg.n_group,
         topk_group=cfg.topk_group,
-        dispatch=cfg.moe_dispatch,
-        capacity_factor=cfg.moe_capacity_factor,
     )
     lead = x.shape[:-1]
     flat = x.reshape(-1, cfg.hidden_size)
@@ -492,6 +491,12 @@ def unified(
     if kv_scales is not None:
         from dynamo_tpu.ops.quant import quantize_kv_write
 
+    # A block-diffusion model masks by block; every other keeps the
+    # causal call it had.
+    block_kw = (
+        {"diffusion_block": cfg.diffusion_block_length}
+        if cfg.diffusion_block_length > 1 else {}
+    )
     new_caches = []
     new_scales = []
     for li, (layer, (k_cache, v_cache)) in enumerate(
@@ -525,7 +530,7 @@ def unified(
         attn_out = ragged_fn(
             q, k_cache, v_cache, block_tables, token_seq, token_pos,
             q_start, q_len, kv_len, row_start, block_size,
-            window=cfg.layer_window(li), **scale_kw,
+            window=cfg.layer_window(li), **scale_kw, **block_kw,
         )
         if cfg.is_mla:
             x = x + _mla_out(layer, attn_out, cfg)
@@ -591,7 +596,10 @@ def hidden_states(
             th, sc = _layer_rope(cfg, li)
             q = apply_rope(q, positions, th, sc)
             k = apply_rope(k, positions, th, sc)
-            attn = full_causal_attention(q, k, v, window=cfg.layer_window(li))
+            attn = full_causal_attention(
+                q, k, v, window=cfg.layer_window(li),
+                diffusion_block=max(cfg.diffusion_block_length, 1),
+            )
             x = _residual_attn(x, layer, qdot(attn.reshape(T, -1), layer["wo"]), cfg)
         x = _residual_mlp(x, layer, cfg)
     return x

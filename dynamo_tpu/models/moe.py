@@ -7,18 +7,20 @@ first-class JAX: a top-k softmax router and a dense einsum formulation of
 the expert MLPs, with the expert dimension sharded over the mesh's ``ep``
 axis and the per-expert intermediate dim over ``tp`` (specs in
 ``moe_param_specs``). GSPMD turns the expert-dim contractions into
-psums over ep — no hand-written all-to-all at this stage; a capacity-based
-dispatch kernel is the later optimization.
+psums over ep — no hand-written all-to-all at this stage.
 
-The dense formulation computes every expert on every token and masks by
-the router's top-k gates. That is O(E/topk) extra FLOPs — acceptable for
-correctness scaffolding and small expert counts; the Pallas blocked
-dispatch replaces it when perf work reaches MoE.
+Two exact expert paths, chosen by the expert count alone
+(``GROUPED_MIN_EXPERTS``): below it the dense formulation computes every
+expert on every token and masks by the router's top-k gates (O(E/topk)
+extra FLOPs, fine for Mixtral's 8); from it up the grouped path sorts the
+routed rows by expert and runs one grouped matrix product an expert
+projection (``jax.lax.ragged_dot``) over the experts that have rows — no
+capacity, no dropped contribution, whatever the routing.
 """
 
 from __future__ import annotations
 
-import math
+import contextlib
 from dataclasses import dataclass
 
 import jax
@@ -42,40 +44,16 @@ class MoeConfig:
     # only the topk_group best groups stay eligible.
     n_group: int = 1
     topk_group: int = 1
-    # Expert execution: "dense" (all experts, gate-masked), "capacity"
-    # (per-expert token buffers, only selected FLOPs — see moe_mlp), or
-    # "auto" (capacity when num_experts >= AUTO_CAPACITY_MIN_EXPERTS).
-    dispatch: str = "auto"
-    capacity_factor: float = 2.0
-
     @property
-    def resolved_dispatch(self) -> str:
-        """Expert-count half of the "auto" rule; moe_mlp additionally
-        requires enough tokens per call (see auto_capacity_ok) — at
-        decode-size T the capacity C collapses toward 1 and collisions
-        DROP routed contributions, so "auto" falls back to dense there
-        (dense at tiny T is cheap anyway)."""
-        if self.dispatch == "auto":
-            return (
-                "capacity"
-                if self.num_experts >= AUTO_CAPACITY_MIN_EXPERTS
-                else "dense"
-            )
-        return self.dispatch
-
-    def auto_capacity_ok(self, num_tokens: int) -> bool:
-        """Token-count guard for "auto": expect >= 2 tokens per expert
-        so C = ceil(T*k/E * factor) stays comfortably above collision
-        range. Explicit dispatch="capacity" bypasses this (caller's
-        choice)."""
-        return (
-            num_tokens * self.num_experts_per_tok >= 2 * self.num_experts
-        )
+    def grouped(self) -> bool:
+        """Which exact expert path ``moe_mlp`` runs: the grouped one from
+        ``GROUPED_MIN_EXPERTS`` experts up, the dense one below."""
+        return self.num_experts >= GROUPED_MIN_EXPERTS
 
 
-# Dense runs E/topk times the selected FLOPs; capacity pays scatter/gather
-# overhead. E=16 is the measured crossover region.
-AUTO_CAPACITY_MIN_EXPERTS = 16
+# Dense runs E/topk times the selected FLOPs and reads every expert; the
+# grouped path pays a sort and two gathers. Mixtral's 8 experts stay dense.
+GROUPED_MIN_EXPERTS = 16
 
 
 def init_moe_params(key: jax.Array, cfg: MoeConfig, dtype=jnp.float32) -> dict:
@@ -106,9 +84,10 @@ def moe_param_specs() -> dict:
     }
 
 
-def moe_router(params: dict, x: jnp.ndarray, cfg: MoeConfig) -> jnp.ndarray:
-    """Top-k routing → dense gates [T, E] with mass only on each token's
-    selected experts.
+def moe_route(
+    params: dict, x: jnp.ndarray, cfg: MoeConfig
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k routing → (expert ids [T, k], their gate weights [T, k]).
 
     softmax (Mixtral/DeepSeek-V2): probs = softmax over all experts, top-k
     by prob, optionally renormalized over the selection.
@@ -152,9 +131,15 @@ def moe_router(params: dict, x: jnp.ndarray, cfg: MoeConfig) -> jnp.ndarray:
         gates_k = gates_k / jnp.maximum(
             gates_k.sum(axis=-1, keepdims=True), 1e-20
         )
-    gates_k = gates_k * cfg.routed_scaling_factor
-    return jnp.zeros_like(logits).at[
-        jnp.arange(T)[:, None], topi
+    return topi, gates_k * cfg.routed_scaling_factor
+
+
+def moe_router(params: dict, x: jnp.ndarray, cfg: MoeConfig) -> jnp.ndarray:
+    """``moe_route`` as dense gates [T, E] with mass only on each token's
+    selected experts."""
+    topi, gates_k = moe_route(params, x, cfg)
+    return jnp.zeros((x.shape[0], cfg.num_experts), jnp.float32).at[
+        jnp.arange(x.shape[0])[:, None], topi
     ].set(gates_k)
 
 
@@ -173,24 +158,15 @@ def _expert_einsum(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
 def moe_mlp(
     params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None
 ) -> jnp.ndarray:
-    """x [T, D] → [T, D] through top-k routed experts.
+    """x [T, D] → [T, D] through top-k routed experts, exactly.
 
-    dispatch="dense" computes every expert for every token and masks by
-    the gates — exact, simple, O(E/topk) extra FLOPs; right for small
-    expert counts and tiny tests. dispatch="capacity" gathers each
-    expert's assigned tokens into fixed [E, C, D] buffers and runs only
-    the selected experts' FLOPs (≈ topk/E of dense — at DeepSeek-R1
-    scale, 256 experts top-8, that is 32× less MLP compute); tokens
-    beyond an expert's capacity C = ceil(T·topk/E · factor) drop to zero
-    contribution for that expert, the standard capacity-overflow rule.
-    "auto" (default) picks by expert count. ``mesh`` (when ep > 1) pins
-    the dispatch collectives explicitly — see _moe_mlp_capacity.
-    """
-    use_capacity = cfg.resolved_dispatch == "capacity" and (
-        cfg.dispatch != "auto" or cfg.auto_capacity_ok(x.shape[0])
-    )
-    if use_capacity:
-        return _moe_mlp_capacity(params, x, cfg, mesh)
+    Below ``GROUPED_MIN_EXPERTS`` experts: every expert for every token,
+    masked by the gates (O(E/topk) extra FLOPs; GSPMD shards it). From
+    there up: ``_moe_mlp_grouped``. ``mesh`` places the grouped path's
+    products per shard."""
+    if cfg.grouped:
+        with jax.named_scope("moe_grouped_ffn"):
+            return _moe_mlp_grouped(params, x, cfg, mesh)
     gates = moe_router(params, x, cfg)
     xf = x.astype(jnp.float32)
     up = _expert_einsum("td,edi->tei", xf, params["w_up"])
@@ -200,77 +176,156 @@ def moe_mlp(
     return jnp.einsum("ted,te->td", out, gates).astype(x.dtype)
 
 
-def _moe_mlp_capacity(
-    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None
-) -> jnp.ndarray:
-    """Capacity-dispatch formulation: scatter tokens to per-expert
-    buffers, run per-expert SwiGLU as one [E, C, :] batched einsum (the
-    expert dim stays sharded over ep), gather weighted results back.
-    Static shapes throughout — C derives from T at trace time — so XLA
-    compiles one program per prefill bucket exactly like the dense path.
-
-    With a mesh carrying ep > 1, the token buffers are PINNED ep-sharded
-    (with_sharding_constraint), so the communication pattern is explicit
-    and stable: the scatter lands as a dispatch to each expert shard
-    (serving activations are replicated across ep, so this is a local
-    slice, not an all-to-all), each shard computes ONLY its local
-    experts' [E/ep, C, :] einsums, and the token-side gather of expert
-    outputs is the combine step. GSPMD left unpinned was free to
-    replicate the buffers and waste the ep axis entirely."""
-    T, D = x.shape
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
-    gates = moe_router(params, x, cfg)                      # [T, E]
-    gate_vals, expert_idx = jax.lax.top_k(gates, k)         # [T, k]
-    C = max(1, int(math.ceil(T * k / E * cfg.capacity_factor)))
-
-    flat_e = expert_idx.reshape(-1)                         # [T*k]
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    # rank of each entry within its expert (arrival order)
-    pos = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(-1)
-    keep = pos < C
-    idx_c = jnp.where(keep, pos, C)                         # C = drop slot
-
-    ep_sharded = (
-        mesh is not None and dict(mesh.shape).get("ep", 1) > 1
-    )
-
-    def pin(arr, spec):
-        if not ep_sharded:
-            return arr
-        from jax.sharding import NamedSharding
-
-        return jax.lax.with_sharding_constraint(
-            arr, NamedSharding(mesh, spec)
-        )
-
-    xf = x.astype(jnp.float32)
-    xx = jnp.repeat(xf, k, axis=0)                          # [T*k, D]
-    buf = jnp.zeros((E, C, D), jnp.float32).at[flat_e, idx_c].set(
-        xx, mode="drop"
-    )
-    buf = pin(buf, P("ep", None, None))
-    gate = _expert_einsum3("ecd,edi->eci", buf, params["w_gate"])
-    up = _expert_einsum3("ecd,edi->eci", buf, params["w_up"])
-    h = jax.nn.silu(gate) * up                              # [E, C, I]
-    h = pin(h, P("ep", None, "tp"))
-    out_e = _expert_einsum3("eci,eid->ecd", h, params["w_down"])
-    out_e = pin(out_e, P("ep", None, None))
-
-    y = out_e[flat_e, jnp.minimum(pos, C - 1)]              # [T*k, D]
-    y = jnp.where(keep[:, None], y, 0.0)
-    out = (y.reshape(T, k, D) * gate_vals[:, :, None]).sum(axis=1)
-    return pin(out.astype(x.dtype), P(None, None))
+#: While a step program is traced under ``collect_experts_hit``: one traced
+#: scalar a grouped expert layer, the experts that had a row.
+_EXPERTS_HIT: list | None = None
 
 
-def _expert_einsum3(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
-    """Batched-over-experts einsum against a possibly-quantized stacked
-    weight; the [E, out] scale broadcasts onto the [E, C, out] result."""
+@contextlib.contextmanager
+def collect_experts_hit():
+    """Collect, while the caller traces a model function, how many experts
+    had a row in each grouped expert layer (what the layer's kernels had
+    to read: routing decides it, so only the program can count it). Yields
+    the list the layers append their traced scalars to."""
+    global _EXPERTS_HIT
+    before, _EXPERTS_HIT = _EXPERTS_HIT, []
+    try:
+        yield _EXPERTS_HIT
+    finally:
+        _EXPERTS_HIT = before
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+#: rows a tile of the grouped matmul kernel: a group of fewer rows still
+#: reads its expert's whole matrix once, which is what bounds decode.
+GMM_ROWS = 128
+
+
+def _grouped_dot(rows, w, sizes, row_expert):
+    """``rows[r] @ w[expert of r]`` for rows sorted by expert, ``sizes``
+    rows an expert: one grouped product, float32 out. On the Pallas path
+    (ops/attention.py ``pallas_enabled``: a TPU, or interpret mode by
+    ``DYNAMO_TPU_PALLAS=1``) that is the megablox grouped matmul kernel,
+    each tile an expert's whole [K, N] matrix against up to ``GMM_ROWS``
+    of its rows (XLA's own ``ragged_dot`` read 26 % of the bytes bound at
+    SDAR's widths, this 73 %: my chip run, PR 36); elsewhere, and for
+    shapes the kernel's tiling does not take, ``jax.lax.ragged_dot``. A
+    quantized stacked weight (ops/quant.py ``{"q", "s"}``, scales per
+    (expert, out channel)) multiplies in its storage values and scales
+    each row's result."""
+    from dynamo_tpu.ops.attention import pallas_enabled
     from dynamo_tpu.ops.quant import is_quantized
 
-    if not is_quantized(w):
-        return jnp.einsum(pattern, x, w.astype(jnp.float32))
-    out = jnp.einsum(pattern, x, w["q"].astype(jnp.float32))
-    return out * w["s"][:, None, :]
+    if is_quantized(w):
+        q = w["q"].astype(rows.dtype)
+    else:
+        q, rows = w, rows.astype(w.dtype)
+    (M, K), N = rows.shape, q.shape[-1]
+    tm = min(GMM_ROWS, M)
+    if pallas_enabled() and not (M % tm or tm % 8 or K % 128 or N % 128):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        out = gmm(
+            rows, q, sizes, jnp.float32, (tm, K, N),
+            interpret=_interpret(),
+        )
+    else:
+        out = jax.lax.ragged_dot(
+            rows, q, sizes, preferred_element_type=jnp.float32
+        )
+    if is_quantized(w):
+        out = out * w["s"].astype(jnp.float32)[row_expert]
+    return out
+
+
+def _moe_mlp_grouped(
+    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None
+) -> jnp.ndarray:
+    """The dropless grouped path: the T*k routed (token, expert) rows
+    sorted by expert, one ``ragged_dot`` an expert projection over the
+    groups that have rows (an expert without rows is an empty group),
+    each row's result weighted by its gate and summed back to its token.
+    Exact for every routing; static shapes ([T*k, .] whatever the
+    routing), so one program a budget rung like the dense path.
+
+    Under a mesh the products run per shard (``shard_map``): each holds
+    its ``tp`` slice of every expert's width and, over ``ep``, its own
+    experts, whose rows lie together in the sorted order; the partial
+    results meet in one all-reduce."""
+    T, D = x.shape
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    topi, gates_k = moe_route(params, x, cfg)               # [T, k] each
+    flat_e = topi.reshape(-1)                                # [T*k]
+    order = jnp.argsort(flat_e, stable=True)                 # by expert
+    row_expert = flat_e[order]
+    rows = x[order // k]                                     # [T*k, D]
+    sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    if _EXPERTS_HIT is not None:
+        _EXPERTS_HIT.append((sizes > 0).sum().astype(jnp.int32))
+
+    def ffn(rows, sizes, row_expert, w_gate, w_up, w_down):
+        gate = _grouped_dot(rows, w_gate, sizes, row_expert)
+        up = _grouped_dot(rows, w_up, sizes, row_expert)
+        h = (jax.nn.silu(gate) * up).astype(rows.dtype)
+        return _grouped_dot(h, w_down, sizes, row_expert)    # [T*k, D] f32
+
+    axes = {
+        a: n for a, n in (dict(mesh.shape) if mesh is not None else {}).items()
+        if a in ("ep", "tp") and n > 1
+    }
+    weights = (params["w_gate"], params["w_up"], params["w_down"])
+    if not axes:
+        y = ffn(rows, sizes, row_expert, *weights)
+    else:
+        ep = axes.get("ep", 1)
+        El = E // ep
+
+        def shard(rows, sizes, row_expert, *w):
+            if ep == 1:
+                return jax.lax.psum(ffn(rows, sizes, row_expert, *w), "tp")
+            # This shard's experts [r*El, (r+1)*El): their rows are one
+            # run of the sorted order. Bring the run to the front, run the
+            # local groups, zero what lies behind them, put it back.
+            r = jax.lax.axis_index("ep")
+            local = jax.lax.dynamic_slice_in_dim(sizes, r * El, El)
+            first = jnp.where(jnp.arange(E) < r * El, sizes, 0).sum()
+            shift = lambda a, n: jnp.roll(a, n, axis=0)
+            y = ffn(
+                shift(rows, -first), local,
+                shift(row_expert, -first) - r * El, *w,
+            )
+            mine = jnp.arange(T * k) < local.sum()
+            y = shift(jnp.where(mine[:, None], y, 0.0), first)
+            return jax.lax.psum(y, tuple(axes))
+
+        tp = "tp" if "tp" in axes else None
+        e_ax = "ep" if ep > 1 else None
+        specs = moe_weight_specs(weights, e_ax, tp)
+        y = jax.shard_map(
+            shard, mesh=mesh,
+            in_specs=(P(), P(), P(), *specs), out_specs=P(),
+            check_vma=False,
+        )(rows, sizes, row_expert, *weights)
+    y = y * gates_k.reshape(-1)[order][:, None]
+    back = jnp.argsort(order)                                # row of (t, j)
+    return y[back].reshape(T, k, D).sum(axis=1).astype(x.dtype)
+
+
+def moe_weight_specs(weights, e_ax, tp):
+    """``shard_map`` specs of (w_gate, w_up, w_down), plain or quantized:
+    experts over ``e_ax``, the experts' width over ``tp``."""
+    from dynamo_tpu.ops.quant import is_quantized
+
+    def spec(w, wide_last: bool):
+        full = P(e_ax, None, tp) if wide_last else P(e_ax, tp, None)
+        if not is_quantized(w):
+            return full
+        return {"q": full, "s": P(e_ax, tp) if wide_last else P(e_ax, None)}
+
+    return tuple(spec(w, i < 2) for i, w in enumerate(weights))
 
 
 def shard_moe_params(params: dict, mesh) -> dict:

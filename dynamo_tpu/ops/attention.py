@@ -169,7 +169,7 @@ class AttnDispatch:
     def ragged(
         self, q, k_cache, v_cache, block_tables, token_seq, token_pos,
         q_start, q_len, kv_len, row_start, block_size: int, window: int = 0,
-        k_scales=None, v_scales=None,
+        k_scales=None, v_scales=None, diffusion_block: int = 1,
     ):
         """Unified mixed prefill+decode attention over one flat ragged
         token batch (the single-dispatch step — ops/pallas/
@@ -182,9 +182,16 @@ class AttnDispatch:
         int8-KV path on: the cache holds int8 pages that dequantize by
         per-(block, head) scale inside whichever implementation runs
         (kernel in-register, oracle on the gathered page). Under a mesh
-        the scales head axis shards exactly like the cache heads."""
+        the scales head axis shards exactly like the cache heads.
+
+        ``diffusion_block = B > 1``: the mask is by block of ``B``
+        positions (a query sees its own block whole and every earlier
+        one), in the kernel and in the twin alike; ``1`` is causal."""
         D = q.shape[-1]
         qp = _pad_q_for_cache(q, k_cache)
+        assert diffusion_block == 1 or not self.kv_sp, (
+            "the striped kv_sp scan is causal only"
+        )
         if self.kv_sp:
             # Slot-sharded cache: the ragged batch is exactly batched
             # decode attention with per-TOKEN block tables (the oracle's
@@ -205,6 +212,7 @@ class AttnDispatch:
             out = ragged_paged_attention(
                 qp, k_cache, v_cache, block_tables, token_seq, token_pos,
                 block_size, window, k_scales=k_scales, v_scales=v_scales,
+                diffusion_block=diffusion_block, kv_len=kv_len,
             )
         else:
             from dynamo_tpu.ops.pallas.ragged_attention import (
@@ -213,7 +221,7 @@ class AttnDispatch:
 
             base = partial(
                 ragged_paged_attention_pallas, block_size=block_size,
-                window=window,
+                window=window, diffusion_block=diffusion_block,
             )
             if k_scales is not None:
                 # Keyword-forward the trailing scale operands so the
@@ -500,6 +508,8 @@ def ragged_paged_attention(
     window: int = 0,
     k_scales: jnp.ndarray | None = None,  # [num_blocks, kvH] (int8 cache)
     v_scales: jnp.ndarray | None = None,
+    diffusion_block: int = 1,
+    kv_len: jnp.ndarray | None = None,    # [S] — clips a block's reach
 ) -> jnp.ndarray:
     """XLA twin of the ragged unified kernel (ops/pallas/
     ragged_attention.py) — identical semantics, jnp formulation, and the
@@ -514,13 +524,21 @@ def ragged_paged_attention(
     sequence, so the whole mixed batch reduces to batched decode attention
     with per-token block tables — one lax.scan over pages, no per-phase
     program. Padding rows carry ``token_pos = -1`` (context 0) and return
-    zeros."""
-    tables = jnp.take(
-        block_tables,
-        jnp.clip(token_seq, 0, block_tables.shape[0] - 1),
-        axis=0,
-    )  # [T, max_blocks]
+    zeros. Under a mask by block (``diffusion_block = B > 1``) a token's
+    context runs to the end of its own block of ``B`` positions, clipped
+    to its sequence's ``kv_len`` as the kernel clips it."""
+    rows = jnp.clip(token_seq, 0, block_tables.shape[0] - 1)
+    tables = jnp.take(block_tables, rows, axis=0)  # [T, max_blocks]
     ctx = jnp.maximum(token_pos + 1, 0)
+    if diffusion_block > 1:
+        assert not window, "no window under a block mask"
+        ctx = jnp.where(
+            token_pos >= 0,
+            (token_pos // diffusion_block + 1) * diffusion_block,
+            0,
+        )
+        if kv_len is not None:
+            ctx = jnp.minimum(ctx, kv_len[rows])
     return paged_decode_attention(
         q, k_cache, v_cache, tables, ctx, block_size, window,
         k_scales=k_scales, v_scales=v_scales,
@@ -530,7 +548,7 @@ def ragged_paged_attention(
 def ragged_attention(
     q, k_cache, v_cache, block_tables, token_seq, token_pos, q_start,
     q_len, kv_len, row_start, block_size: int, window: int = 0,
-    k_scales=None, v_scales=None,
+    k_scales=None, v_scales=None, diffusion_block: int = 1,
 ):
     """Default (single-chip, env-driven) dispatch for the unified step,
     for callers with no per-runner AttnDispatch to thread in."""
@@ -545,21 +563,26 @@ def ragged_attention(
         q, k_cache, v_cache, block_tables, token_seq, token_pos, q_start,
         q_len, kv_len, row_start, block_size, window,
         k_scales=k_scales, v_scales=v_scales,
+        diffusion_block=diffusion_block,
     )
 
 
 def full_causal_attention(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window: int = 0
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, window: int = 0,
+    diffusion_block: int = 1,
 ) -> jnp.ndarray:
     """Plain causal attention [T, H, D] x [T, kvH, D] — the no-cache
-    reference path used to validate the paged implementations."""
+    reference path used to validate the paged implementations. With
+    ``diffusion_block = B > 1`` the mask is by block: key j is visible to
+    query i iff ``j // B <= i // B``."""
     T, H, D = q.shape
     kvH = k.shape[1]
     G = H // kvH
     scale = 1.0 / (D**0.5)
     qr = (q.astype(jnp.float32) * scale).reshape(T, kvH, G, D)
     scores = jnp.einsum("tkgd,skd->tkgs", qr, k.astype(jnp.float32))
-    mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]  # [Tq, Tk]
+    B = diffusion_block
+    mask = jnp.arange(T)[None, :] // B <= jnp.arange(T)[:, None] // B
     if window:
         mask = mask & (
             jnp.arange(T)[None, :] > jnp.arange(T)[:, None] - window

@@ -85,8 +85,14 @@ def _ragged_kernel(
     q_tile_rows: int,
     window: int = 0,
     quantized: bool = False,
+    diffusion_block: int = 1,
 ):
     """One grid program per sequence; inner loop over its q tiles.
+
+    ``diffusion_block = B > 1`` masks BY BLOCK (block-diffusion models):
+    a query sees every key of its own block of ``B`` positions and of the
+    blocks before it, so the tile's page bound runs to the end of its
+    last row's block. ``B = 1`` is the causal mask, compiled as it was.
 
     Each tile DMAs ``TQ`` q rows in from the flat batch at the span's
     (dynamic) offset, streams the causally visible KV pages through the
@@ -147,7 +153,14 @@ def _ragged_kernel(
 
             # Keys this tile can see: causal bound clipped to the context;
             # with a window, pages wholly behind every row's window skip.
-            hi = jnp.minimum(q0 + tok0 + TQ, kv)
+            if diffusion_block == 1:
+                hi = jnp.minimum(q0 + tok0 + TQ, kv)
+            else:
+                hi = jnp.minimum(
+                    ((q0 + tok0 + TQ - 1) // diffusion_block + 1)
+                    * diffusion_block,
+                    kv,
+                )
             nb = pl.cdiv(hi, bs)
             lo = (
                 jnp.maximum(q0 + tok0 - window + 1, 0) // bs
@@ -192,6 +205,11 @@ def _ragged_kernel(
             )
             qf = jnp.transpose(q4, (1, 0, 2, 3)).reshape(kvH, TQ * G, D)
             q_pos = q0 + tok0 + row_idx          # [1, TQ*G, 1]
+            if diffusion_block > 1:
+                # The last key the row sees: the end of its own block.
+                q_pos = (
+                    q_pos // diffusion_block + 1
+                ) * diffusion_block - 1
             row_ok = row_idx < (ql - tok0)       # [1, TQ*G, 1]
 
             def fold(f, carry):
@@ -313,7 +331,8 @@ def _ragged_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_size", "q_tile", "window")
+    jax.jit,
+    static_argnames=("block_size", "q_tile", "window", "diffusion_block"),
 )
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,             # [T, H, D] flat token batch (budget-padded)
@@ -329,6 +348,7 @@ def ragged_paged_attention_pallas(
     window: int = 0,
     k_scales: jnp.ndarray | None = None,  # [num_blocks, kvH] f32 (int8 KV)
     v_scales: jnp.ndarray | None = None,
+    diffusion_block: int = 1,
 ) -> jnp.ndarray:
     """Mixed prefill+decode attention over one flat ragged batch; returns
     ``[T, H, D]``. Rows not covered by any span are returned ZEROED (the
@@ -344,6 +364,7 @@ def ragged_paged_attention_pallas(
     T, H, D = q.shape
     S = block_tables.shape[0]
     kvH = k_cache.shape[1]
+    assert diffusion_block == 1 or not window, "no window under a block mask"
     TQ = min(q_tile, max(T, 1))
     quantized = k_scales is not None
     kp = k_cache.reshape(-1, block_size * kvH, D)
@@ -391,6 +412,7 @@ def ragged_paged_attention_pallas(
     kernel = functools.partial(
         _ragged_kernel, block_size=block_size, num_kv_heads=kvH,
         q_tile_rows=TQ, window=window, quantized=quantized,
+        diffusion_block=diffusion_block,
     )
     operands = [
         block_tables.astype(jnp.int32),

@@ -133,3 +133,43 @@ def sample_tokens(
         jnp.all(temperature <= 0.0), lambda _: greedy_ids, sampled, None
     )
     return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids)
+
+
+def commit_block(
+    logits: jnp.ndarray,        # [S, B, V] float32 — a span's block rows
+    fed: jnp.ndarray,           # [S, B] int32 — the ids the rows were fed
+    masked: jnp.ndarray,        # [S, B] bool — rows fed as masks
+    key: jax.Array,
+    temperature: jnp.ndarray,   # [S]
+    top_k: jnp.ndarray,         # [S]
+    top_p: jnp.ndarray,         # [S]
+    seed: jnp.ndarray,          # [S]
+    first_pos: jnp.ndarray,     # [S] position of the block's first row
+    threshold: float,
+    floor_rows: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One denoising pass's commit rule (block diffusion, SDAR family):
+    every row is sampled like a decode row and the sampled token's
+    probability (softmax at temperature 1) is its confidence; a masked row
+    is committed when its confidence reaches ``threshold``, and so are the
+    ``floor_rows`` most confident masked rows whatever they read (ties to
+    the lower position). Returns (ids [S, B]: a committed row's token, a
+    row fed unmasked as it was fed, a row still masked -1; committed rows
+    a span [S])."""
+    S, B, V = logits.shape
+    flat = logits.reshape(S * B, V)
+    rep = lambda a: jnp.repeat(a, B)
+    toks = sample_tokens(
+        flat, key, rep(temperature), rep(top_k), rep(top_p), seed=rep(seed),
+        sample_pos=(first_pos[:, None] + 1 + jnp.arange(B)).reshape(-1),
+    ).reshape(S, B)
+    chosen = jnp.take_along_axis(logits, toks[..., None], axis=-1)[..., 0]
+    conf = jnp.exp(chosen - jax.nn.logsumexp(logits, axis=-1))   # [S, B]
+    ranked = jnp.where(masked, conf, -1.0)
+    # rank 0 = the most confident masked row; a stable sort keeps the
+    # lower position ahead on a tie.
+    order = jnp.argsort(-ranked, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    commit = masked & ((conf >= threshold) | (rank < floor_rows))
+    ids = jnp.where(commit, toks, jnp.where(masked, -1, fed))
+    return ids.astype(jnp.int32), commit.sum(axis=-1).astype(jnp.int32)

@@ -193,3 +193,25 @@ def test_unified_step_1b_compiles_one_chip_and_tp4(mosaic, one_chip, tp4):
     # Weights + KV of the 1B at these sizes are ~4.6 GB on one chip.
     assert 4.0e9 < one_bytes < 5.5e9, one_bytes
     assert 0.2 < tp_bytes / one_bytes < 0.3, (tp_bytes, one_bytes)
+
+
+def test_sdar_block_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch
+):
+    """Two whole SDAR-30B-A3B layers of one unified step at T=256 under
+    the mask by block, at the published widths: the ragged kernel with
+    ``diffusion_block=4`` and the grouped expert path's three grouped
+    matmul kernels a layer (128 experts of [2048, 768], 2048 routed rows)
+    pass the chip's compiler."""
+    from dynamo_tpu.models import moe
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = ModelConfig.sdar_30b_a3b().scaled(num_layers=2)
+    compiled = _compile_unified(
+        cfg, 256, AttnDispatch(use_pallas=True), lambda _spec: one_chip
+    )
+    text = compiled.as_text()
+    # a layer: the attention kernel and gate, up, down
+    assert _kernel_count(text) == 4 * cfg.num_layers, _kernel_count(text)
+    assert "all-reduce" not in text
