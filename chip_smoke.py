@@ -727,7 +727,7 @@ WIDTHS_1B = dict(
 )
 # Decode spans at contexts 1, 17, 130, 2047 (one exactly a block + 1, one
 # the full table); an idle row between spans; a prefill from 0 that starts
-# at flat row 4 and runs 83 rows (neither end on the kernel's 8-row tile);
+# at flat row 4 and runs 83 rows (neither end on the kernel's 16-row tile);
 # a prefix hit (3 cached blocks, 37 new rows); a mid-prompt chunk that
 # crosses fold boundaries; trailing idle rows and budget padding.
 MIXED_SPANS = [

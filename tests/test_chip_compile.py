@@ -107,6 +107,36 @@ def test_ragged_kernel_compiles_at_served_widths(mosaic, one_chip, T, kv_dtype):
     assert _kernel_count(compiled.as_text()) == 1
 
 
+@pytest.mark.parametrize(
+    "heads,kv_heads,block,T,kv_dtype",
+    [
+        (8, 2, 1, 256, "bfloat16"),    # mistral-7b-tp4: a chip's share
+        (8, 2, 1, 256, "int8"),
+        (32, 4, 4, 512, "bfloat16"),   # sdar-30b-a3b: spans of a block of 4
+        (32, 4, 4, 512, "int8"),
+    ],
+)
+def test_ragged_kernel_compiles_at_the_cells_chip_shapes(
+    mosaic, one_chip, heads, kv_heads, block, T, kv_dtype
+):
+    """The benchmark's other per-chip shapes (PERF.md §4): two KV heads a
+    chip under tp=4, and the short tile of a diffusion block."""
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    cache = _sds((NUM_BLOCKS * BS, kv_heads, D), jnp.dtype(kv_dtype), one_chip)
+    scales = {}
+    if kv_dtype == "int8":
+        sc = _sds((NUM_BLOCKS, kv_heads), jnp.float32, one_chip)
+        scales = {"k_scales": sc, "v_scales": sc}
+    lanes = 129
+    compiled = ragged_kernel.ragged_paged_attention_pallas.lower(
+        _sds((T, heads, D), jnp.bfloat16, one_chip), cache, cache,
+        i32((lanes, 256)), i32((lanes,)), i32((lanes,)), i32((lanes,)),
+        i32((lanes,)), block_size=BS, window=0 if block > 1 else 4096,
+        diffusion_block=block, **scales,
+    ).compile()
+    assert _kernel_count(compiled.as_text()) == 1
+
+
 @pytest.mark.parametrize("with_stats", [False, True])
 def test_kv_sp_decode_kernel_compiles_at_served_widths(
     mosaic, one_chip, with_stats

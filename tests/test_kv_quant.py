@@ -123,7 +123,7 @@ def test_int8_decode_only_matches_decode_oracle():
     )
 
 
-@pytest.mark.parametrize("q_tile", [8, 32])
+@pytest.mark.parametrize("q_tile", [8, 64])
 def test_int8_prefill_only_with_prefix_hit(q_tile):
     rng = np.random.default_rng(2)
     spans = [(0, 24), (16, 13)]  # span 1 extends a 16-token prefix

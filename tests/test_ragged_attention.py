@@ -107,11 +107,12 @@ def test_decode_only_matches_decode_oracle():
 
 
 @pytest.mark.parametrize("H,kvH", [(8, 8), (8, 2)])
-@pytest.mark.parametrize("q_tile", [8, 32, 128])
+@pytest.mark.parametrize("q_tile", [8, 64, 128])
 def test_prefill_only_matches_prefill_oracle(H, kvH, q_tile):
     """Prefill-only unified batches (incl. a prefix hit) against the
-    per-lane prefill oracle, across head geometries (MHA, GQA) and tile
-    widths (full tiles + ragged tails; 128 is wider than any span)."""
+    per-lane prefill oracle, across head geometries (MHA, GQA) and long
+    tiles (8 asks for less than the kernel's 32 rows and gets 32: a ragged
+    tail after no full tile; 64 and 128 are wider than any span here)."""
     rng = np.random.default_rng(2)
     D = 128
     k, v = _caches(rng, 64, kvH, D)
@@ -320,6 +321,173 @@ def test_spec_verify_spans_match_twin_windowed():
         np.testing.assert_allclose(
             got, want, rtol=2e-5, atol=2e-5, err_msg=f"window={window}"
         )
+
+
+# ---------------------------------------------------------------------------
+# The pipeline's seams: the kernel walks every span in ONE program, keeps
+# the page ring full across spans, starts outputs without waiting for them
+# and takes a short tile (a decode row, a diffusion block) or a long one by
+# the span's length. Each case is a mixed batch against the jnp twin.
+# ---------------------------------------------------------------------------
+
+from dynamo_tpu.ops.pallas import ragged_attention as ragged_kernel
+
+_NBUF, _PP = ragged_kernel.ring_shape(BS * 2 * 128 * 2)
+_FOLD = _PP * BS                  # keys a fold
+_LONG = ragged_kernel.LONG_TILE   # rows of a long span's tile
+
+# name -> (spans, geometry and mode). ``None`` in ``spans`` is an idle
+# metadata row; a span is (prefix, rows).
+_SEAMS = {
+    "idle_first_last_between": dict(
+        spans=[None, (36, 1), None, None, (0, 20), (9, 1), None]),
+    "single_span_decode": dict(spans=[(41, 1)]),
+    "single_span_long": dict(spans=[(5, 2 * _LONG + 3)]),
+    "all_idle": dict(spans=[None, None]),
+    "row_between_long_spans": dict(
+        spans=[(0, _LONG + 5), (30, 1), (16, _LONG), (0, 1), (3, 7)]),
+    "row_between_long_spans_wide_tile": dict(
+        spans=[(0, 40), (30, 1), (16, 37), (63, 1)], q_tile=128),
+    "less_than_one_fold": dict(spans=[(4, 1), (0, 1), (_FOLD - 2, 1)]),
+    "ring_depth_boundaries": dict(
+        # contexts of NBUF - 1 and NBUF folds, and one key more of each,
+        # between short neighbours: the ring wraps inside and across spans
+        spans=[(6, 1), ((_NBUF - 1) * _FOLD - 1, 1), ((_NBUF - 1) * _FOLD, 1),
+               (2, 1), (_NBUF * _FOLD - 1, 1), (_NBUF * _FOLD, 1), (77, 1)]),
+    "long_span_over_deep_context": dict(
+        spans=[(11, 1), (_NBUF * _FOLD - 9, _LONG + 2), (19, 1)]),
+    "tp4_chip_shape": dict(
+        spans=[(199, 1), (640, 1), (0, 50), (128, 1), (300, 40)],
+        H=8, kvH=2, window=4096),
+    "sdar_chip_shape": dict(
+        spans=[(8, 4), (0, 12), (36, 4), (4, 4), (16, 24)],
+        H=32, kvH=4, diffusion_block=4),
+    "diffusion_short_block": dict(
+        # the last block of a sequence may hold fewer rows than a block
+        spans=[(8, 2), (12, 4), (4, 1), (0, 8)], H=8, kvH=4,
+        diffusion_block=4),
+    "window_longer_than_context": dict(
+        spans=[(150, 1), (0, 33), (60, 1)], window=4096),
+    "window_binds": dict(
+        spans=[(300, 1), (100, 40), (17, 1), (200, 3)], window=48),
+    "int8_kv": dict(
+        spans=[(36, 1), None, (0, 40), (260, 1), (130, 35)], int8=True),
+    "int8_kv_window": dict(
+        spans=[(280, 1), (100, 36), (5, 1)], int8=True, window=64),
+    "bf16": dict(
+        spans=[(140, 1), (0, 35), (3, 1), None, (130, 2)],
+        dtype=jnp.bfloat16, H=8, kvH=4),
+    "bf16_tp4_chip_shape": dict(
+        spans=[(270, 1), (9, 1), (100, 33)], dtype=jnp.bfloat16, H=8, kvH=2,
+        window=4096),
+    "draft_verify_rows": dict(
+        spans=[(35, 4), (0, 3), (140, 1), (0, 10), (50, 5)]),
+    "rows_nobody_owns": dict(spans=[(20, 1), (0, 9)], gap=5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SEAMS))
+def test_pipeline_seams(name):
+    case = dict(_SEAMS[name])
+    spans = [(0, 0) if sp is None else sp for sp in case.pop("spans")]
+    H, kvH = case.get("H", 8), case.get("kvH", 2)
+    dtype = case.get("dtype", jnp.float32)
+    window = case.get("window", 0)
+    B = case.get("diffusion_block", 1)
+    gap = case.get("gap", 0)  # budget rows left between the spans
+    D = 128
+    rng = np.random.default_rng(sorted(_SEAMS).index(name))
+    max_blocks = max(-(-(p + n) // BS) for p, n in spans) + 1
+    num_blocks = len(spans) * max_blocks + 1
+    tables = _tables(rng, len(spans), max_blocks, num_blocks)
+    T = sum(n + gap for _, n in spans) + 3
+    q, qs, ql, kv_len, rs, tseq, tpos = _flat_batch(
+        rng, spans, T - gap * len(spans), H, D, dtype
+    )
+    if gap:
+        # spread the spans: ``gap`` rows nobody owns after each
+        rows = np.concatenate([
+            np.arange(int(r), int(r) + int(n)) for r, n in zip(rs, ql)
+        ])
+        shift = np.concatenate([
+            np.full(int(n), i * gap) for i, n in enumerate(ql)
+        ])
+        big = jnp.asarray(rng.standard_normal((T, H, D)), dtype)
+        q = big.at[rows + shift].set(q[rows])
+        seq2 = np.zeros(T, np.int32)
+        pos2 = np.full(T, -1, np.int32)
+        seq2[rows + shift] = np.asarray(tseq)[rows]
+        pos2[rows + shift] = np.asarray(tpos)[rows]
+        tseq, tpos = jnp.asarray(seq2), jnp.asarray(pos2)
+        rs = rs + jnp.arange(len(spans), dtype=jnp.int32) * gap
+    scales = {}
+    if case.get("int8"):
+        shape = (num_blocks * BS, kvH, D)
+        k = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        scales = {
+            name_: jnp.asarray(
+                rng.uniform(0.002, 0.02, (num_blocks, kvH)), jnp.float32
+            )
+            for name_ in ("k_scales", "v_scales")
+        }
+    else:
+        k, v = _caches(rng, num_blocks, kvH, D, dtype)
+    want = np.asarray(ragged_paged_attention(
+        q, k, v, tables, tseq, tpos, BS, window, diffusion_block=B,
+        kv_len=kv_len, **scales,
+    )).astype(np.float32)
+    got = np.asarray(ragged_paged_attention_pallas(
+        q, k, v, tables, qs, ql, kv_len, rs, BS,
+        q_tile=case.get("q_tile", 8), window=window, diffusion_block=B,
+        **scales,
+    )).astype(np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    owned = np.asarray(tpos) >= 0
+    assert owned.sum() == sum(n for _, n in spans)
+    assert not got[~owned].any(), "a row no span owns must read zero"
+    if owned.any():
+        assert np.abs(got[owned]).max() > 0
+
+
+@pytest.mark.parametrize(
+    "kv_heads,itemsize,block,want_pp",
+    [
+        (2, 2, 16, 16),    # tp=4's share: 8 KiB pages, folds of 256 keys
+        (8, 2, 16, 16),    # one chip: 32 KiB pages, a slot of 512 KiB
+        (4, 2, 16, 16),    # SDAR
+        (8, 1, 16, 16),    # int8 KV: half the bytes, the same keys
+        (32, 2, 16, 8),    # no GQA: 128 KiB pages cap the slot; 128 keys
+        (8, 2, 32, 8),     # a larger block: the same 256 keys
+        (64, 4, 16, 8),    # pages past the slot: never under a lane tile
+    ],
+)
+def test_ring_shape_follows_the_page(kv_heads, itemsize, block, want_pp):
+    """Pages a fold come from what the kernel can observe (a page's bytes
+    and the block size), never from a model's name."""
+    nbuf, pp = ragged_kernel.ring_shape(
+        block * kv_heads * 128 * itemsize, block
+    )
+    assert pp == want_pp
+    assert (pp * block) % 128 == 0      # a fold fills whole lane tiles
+    assert nbuf >= 2                    # the ring spans spans
+
+
+@pytest.mark.parametrize(
+    "heads,want",
+    [
+        (8, 32),     # a tp=4 chip's share of 32 heads
+        (16, 32),
+        (32, 16),    # one chip; SDAR
+        (64, 16),    # never under 16 rows
+    ],
+)
+def test_long_tile_follows_the_heads(heads, want):
+    """A long fold's work goes with rows x heads: fewer rows where a chip
+    holds more heads, so a draft-verify span of a few rows does not fold
+    1,024 (row, head) pairs for its five."""
+    assert ragged_kernel.long_tile(heads) == want
 
 
 def test_unified_verify_rows_match_reference_forward():

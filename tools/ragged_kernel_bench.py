@@ -1,0 +1,397 @@
+"""The ragged paged attention kernel alone, on the chip, at the per-chip
+shapes the benchmark's cells dispatch: microseconds a (span, layer) beside
+the bytes bound of ``chipbench/costs/ragged_paged_attention.py``.
+
+Not part of the benchmark (``BENCHMARK.json`` reads the kernel from a served
+run's device trace); this is where a kernel PR starts. It answers what the
+records cannot split:
+
+- ``cells``: each shape as its cell dispatches it (decode lanes of mixed
+  context and one prefill quantum);
+- ``split``: the context varied at fixed spans, then the spans varied at a
+  fixed context; a least-squares fit of both gives the cost a span (grid
+  step, q in, output out, a cold ring) and a page (DMA issue and wait,
+  the fold's arithmetic), beside what their bytes would take;
+- ``ladder``: the ring depth and pages a fold (``ring_shape`` in
+  ``ops/pallas/ragged_attention.py``), overridden point by point;
+- ``ops``: one traced chain, device time an op a layer: the kernel as the
+  benchmark's trace names it and what XLA runs around it;
+- ``check``: the kernel against its XLA twin on the cell's dispatch (a
+  kernel can pass interpret mode and be wrong on the chip).
+
+    chiprun -- python -m tools.ragged_kernel_bench --sweep check,cells,split
+    chiprun -- python -m tools.ragged_kernel_bench --sweep ladder --shapes tp4
+
+A line of JSON a measurement on stdout, all of them in
+``chiprun_out/ragged_kernel_bench.jsonl``. Times are host clock around
+``--layers`` chained calls inside one jitted scan, ended by
+``block_until_ready``: device time, never a CPU number (without a TPU the
+script stops, unless ``--allow-cpu`` rehearses it at a tiny size).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import functools
+import json
+import os
+import statistics
+import sys
+import threading
+import time
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from chipbench.costs.ragged_paged_attention import cost
+from chipbench.peaks import peaks_for
+from dynamo_tpu.ops.pallas import ragged_attention as kernel_mod
+
+BS = 16
+MAX_MODEL_LEN = 4096
+
+#: Per-chip shapes of the benchmark's five cells (``PERF.md`` §4): heads and
+#: KV heads a chip, decode lanes, rows a lane, the prefill quantum beside
+#: them, the token budget, the window and the block mask; then two shapes
+#: of draft-verify spans, which no cell sends.
+SHAPES = {
+    # mistral-7b-tp4.chat-c128: 32 / 8 heads over four chips
+    "tp4": dict(H=8, kvH=2, lanes=128, rows=1, prefill=128, T=256,
+                window=4096, diffusion_block=1),
+    # mistral-7b-l16.chat-c64, .chat-c64-think and mixtral-8x7b-l4.chat-c64
+    "dense": dict(H=32, kvH=8, lanes=64, rows=1, prefill=80, T=256,
+                  window=4096, diffusion_block=1),
+    # sdar-30b-a3b-l7.chat-c64: a lane is a block of 4 rows, no window
+    "sdar": dict(H=32, kvH=4, lanes=64, rows=4, prefill=60, T=512,
+                 window=0, diffusion_block=4),
+    # No cell's: a speculative engine's dispatch, every lane a draft-verify
+    # span of k + 1 = 5 rows (the long tile under ``diffusion_block=1``),
+    # at one chip's widths and at a tp=4 chip's. Named in ``--shapes``.
+    "verify": dict(H=32, kvH=8, lanes=64, rows=5, prefill=0, T=512,
+                   window=4096, diffusion_block=1),
+    "verify-tp4": dict(H=8, kvH=2, lanes=128, rows=5, prefill=0, T=1024,
+                       window=4096, diffusion_block=1),
+}
+D = 128
+
+
+def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
+    """One dispatch's operands: ``len(contexts)`` lanes of ``rows`` rows
+    whose context after the step is ``contexts[i]``, then one prefill
+    span of ``shape['prefill']`` rows ending at ``prefill_ctx``."""
+    rows, n_pre = shape["rows"], shape["prefill"]
+    spans = [(int(c) - rows, rows) for c in contexts]
+    if n_pre:
+        assert prefill_ctx >= n_pre, (prefill_ctx, n_pre)
+        spans.append((prefill_ctx - n_pre, n_pre))
+    S = len(spans)
+    max_blocks = MAX_MODEL_LEN // BS
+    need = sum(-(-(p + n) // BS) for p, n in spans)
+    num_blocks = need + 8
+    ids = rng.permutation(np.arange(1, num_blocks))
+    tables = np.zeros((S, max_blocks), np.int32)
+    q_start = np.zeros(S, np.int32)
+    q_len = np.zeros(S, np.int32)
+    row_start = np.zeros(S, np.int32)
+    used = cursor = 0
+    for s, (p, n) in enumerate(spans):
+        nb = -(-(p + n) // BS)
+        tables[s, :nb] = ids[used : used + nb]
+        used += nb
+        q_start[s], q_len[s], row_start[s] = p, n, cursor
+        cursor += n
+    assert cursor <= shape["T"], (cursor, shape["T"])
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    kq, kk, kv = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (shape["T"], shape["H"], D), jnp.bfloat16)
+    cshape = (num_blocks * BS, shape["kvH"], D)
+    k = jax.random.normal(kk, cshape, jnp.bfloat16)
+    v = jax.random.normal(kv, cshape, jnp.bfloat16)
+    meta = tuple(
+        jnp.asarray(a)
+        for a in (tables, q_start, q_len, q_start + q_len, row_start)
+    )
+    return q, k, v, meta, spans
+
+
+def bound_us(shape: dict, spans) -> tuple[float, float]:
+    """(bytes bound, operations bound) of one layer's call, microseconds."""
+    flops, nbytes = cost(
+        spans,
+        model=dict(num_heads=shape["H"], num_kv_heads=shape["kvH"],
+                   head_dim=D, num_layers=1,
+                   sliding_window=shape["window"]),
+        engine=dict(tp=1, cache_head_dim=D, dtype_bytes=2),
+    )
+    peaks = chip_peaks()
+    return (1e6 * nbytes / peaks["hbm_bytes_per_s"],
+            1e6 * flops / peaks["flops_bf16"])
+
+
+def chip_peaks() -> dict:
+    """The device's published peaks; a CPU rehearsal borrows the v5e's
+    (its lines are not times of anything)."""
+    kind = jax.devices()[0].device_kind if on_tpu() else "TPU v5 lite"
+    return peaks_for(kind)
+
+
+def time_call(shape: dict, operands, layers: int, reps: int,
+              trace_to: str | None = None) -> float:
+    """Median microseconds of ONE call, from ``layers`` chained calls;
+    with ``trace_to``, one more chain runs under the profiler."""
+    q, k, v, meta = operands
+    # A fresh jit a measurement: the ladder overrides module state that
+    # the kernel reads while it is traced.
+    call = functools.partial(
+        kernel_mod.ragged_paged_attention_pallas.__wrapped__,
+        block_size=BS, window=shape["window"],
+        diffusion_block=shape["diffusion_block"],
+    )
+
+    @jax.jit
+    def chain(q, k, v, meta):
+        def body(x, _):
+            out = call(x, k, v, *meta)
+            # the next layer's q depends on this layer's output
+            return q + out * jnp.asarray(1e-3, q.dtype), None
+
+        x, _ = jax.lax.scan(body, q, None, length=layers)
+        return x
+
+    jax.block_until_ready(chain(q, k, v, meta))
+    if trace_to:
+        with jax.profiler.trace(trace_to):
+            jax.block_until_ready(chain(q, k, v, meta))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(q, k, v, meta))
+        times.append(time.perf_counter() - t0)
+    return 1e6 * statistics.median(times) / layers
+
+
+def measure(name: str, what: str, shape: dict, contexts, prefill_ctx: int,
+            args, rng, **extra) -> dict:
+    q, k, v, meta, spans = build(shape, contexts, prefill_ctx, rng)
+    us = time_call(shape, (q, k, v, meta), args.layers, args.reps)
+    bytes_us, flops_us = bound_us(shape, spans)
+    pages = sum(-(-(p + n) // BS) for p, n in spans)
+    line = dict(
+        shape=name, sweep=what, spans=len(spans), pages=pages,
+        rows=sum(n for _, n in spans), call_us=round(us, 2),
+        us_per_span=round(us / len(spans), 3),
+        bytes_bound_us=round(bytes_us, 2),
+        bytes_bound_us_per_span=round(bytes_us / len(spans), 3),
+        flops_bound_us=round(flops_us, 2),
+        roofline_pct=round(100 * max(bytes_us, flops_us) / us, 2),
+        device=jax.devices()[0].device_kind, **extra,
+    )
+    emit(line)
+    return line
+
+
+def emit(line: dict) -> None:
+    text = json.dumps(line)
+    print(text, flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/ragged_kernel_bench.jsonl", "a") as f:
+        f.write(text + "\n")
+
+
+def sweep_check(name, shape, args, rng):
+    """Largest difference from the XLA twin, as a share of the largest
+    output; bf16 rounds at 2^-8, a wrong row reads near 1."""
+    from dynamo_tpu.ops.attention import ragged_paged_attention
+
+    contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
+    q, k, v, meta, spans = build(shape, contexts, args.ctx_hi // 2, rng)
+    tables, q_start, q_len, kv_len, row_start = meta
+    token_seq = np.zeros(shape["T"], np.int32)
+    token_pos = np.full(shape["T"], -1, np.int32)
+    cursor = 0
+    for s, (p, n) in enumerate(spans):
+        token_seq[cursor : cursor + n] = s
+        token_pos[cursor : cursor + n] = np.arange(p, p + n)
+        cursor += n
+    kw = dict(diffusion_block=shape["diffusion_block"])
+    want = ragged_paged_attention(
+        q, k, v, tables, jnp.asarray(token_seq), jnp.asarray(token_pos),
+        BS, shape["window"], kv_len=kv_len, **kw)
+    got = kernel_mod.ragged_paged_attention_pallas(
+        q, k, v, *meta, block_size=BS, window=shape["window"], **kw)
+    want = np.asarray(want, np.float32)
+    got = np.asarray(got, np.float32)
+    err = np.abs(got - want).max(axis=(1, 2)) / np.abs(want).max()
+    emit(dict(shape=name, sweep="check", spans=len(spans),
+              finite=bool(np.isfinite(got).all()),
+              worst_row=int(err.argmax()), worst_rel=round(float(err.max()), 5),
+              padding_zero=not got[cursor:].any(),
+              ok=bool(np.isfinite(got).all() and err.max() < 0.02
+                      and not got[cursor:].any())))
+
+
+def sweep_ops(name, shape, args, rng):
+    """One traced chain on the cell's dispatch: device time an op a layer
+    (the kernel as the benchmark's trace reads it, and what XLA runs
+    around it: the pad, the mask of rows nobody owns, the chain's add)."""
+    import tempfile
+
+    from chipbench import xprof
+
+    contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
+    q, k, v, meta, spans = build(shape, contexts, args.ctx_hi // 2, rng)
+    with tempfile.TemporaryDirectory() as logdir:
+        time_call(shape, (q, k, v, meta), args.layers, 1, trace_to=logdir)
+        reduced = xprof.reduce(xprof.load(logdir))
+    emit(dict(shape=name, sweep="ops", spans=len(spans),
+              us_per_layer={
+                  op: round(1e6 * sec / args.layers, 2)
+                  for op, sec in sorted(reduced["op_seconds"].items(),
+                                        key=lambda kv: -kv[1])[:12]
+              },
+              module_us_per_layer=round(
+                  1e6 * reduced["module_s"] / args.layers, 2)))
+
+
+def mixed_contexts(shape: dict, rng, lo: int, hi: int) -> np.ndarray:
+    return rng.integers(lo, hi + 1, size=shape["lanes"])
+
+
+def sweep_cells(name, shape, args, rng):
+    measure(name, "cells", shape,
+            mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi),
+            args.ctx_hi // 2, args, rng)
+
+
+def fit(lines: list[dict]) -> dict:
+    """Least squares of call time on (1, spans, pages)."""
+    a = np.array([[1.0, ln["spans"], ln["pages"]] for ln in lines])
+    y = np.array([ln["call_us"] for ln in lines])
+    coef, *_ = np.linalg.lstsq(a, y, rcond=None)
+    resid = float(np.abs(a @ coef - y).max())
+    return dict(us_per_call=round(float(coef[0]), 2),
+                us_per_span=round(float(coef[1]), 3),
+                us_per_page=round(float(coef[2]), 4),
+                worst_residual_us=round(resid, 2))
+
+
+def sweep_split(name, shape, args, rng):
+    """Decode lanes only (no prefill quantum), every lane at one context:
+    the context varied at the cell's lanes, then the lanes varied at one
+    context; fitted together."""
+    lanes_only = dict(shape, prefill=0)
+    lines = []
+    for ctx in args.contexts:
+        lines.append(measure(
+            name, "ctx", lanes_only,
+            np.full(shape["lanes"], ctx), 0, args, rng, ctx=ctx))
+    for lanes in args.lanes:
+        if lanes * shape["rows"] > shape["T"]:
+            continue
+        lines.append(measure(
+            name, "spans", lanes_only, np.full(lanes, args.fit_ctx), 0,
+            args, rng, ctx=args.fit_ctx))
+    page_bytes = 2 * BS * shape["kvH"] * D * 2  # K and V
+    emit(dict(shape=name, sweep="fit", **fit(lines),
+              page_bytes=page_bytes,
+              bytes_bound_us_per_page=round(
+                  1e6 * page_bytes / chip_peaks()["hbm_bytes_per_s"], 4)))
+    # the prefill quantum alone, beside the decode lanes' numbers
+    if shape["prefill"]:
+        for ctx in (shape["prefill"], 512, 1024):
+            measure(name, "prefill_alone", dict(shape, lanes=0),
+                    np.zeros(0, np.int64), max(ctx, shape["prefill"]),
+                    args, rng, ctx=ctx)
+
+
+def sweep_ladder(name, shape, args, rng):
+    contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
+    state = rng.bit_generator.state
+    for nbuf, pp in args.ladder:
+        rng.bit_generator.state = state  # the same operands a point
+        try:
+            with pinned_ring(nbuf, pp):
+                measure(name, "ladder", shape, contexts, args.ctx_hi // 2,
+                        args, rng, nbuf=nbuf, pp=pp)
+        except Exception as e:  # a point the compiler refuses
+            emit(dict(shape=name, sweep="ladder", nbuf=nbuf, pp=pp,
+                      error=f"{type(e).__name__}: {str(e)[:200]}"))
+
+
+@contextlib.contextmanager
+def pinned_ring(nbuf: int, pp: int):
+    """The ring's depth and the pages a fold, pinned for what is traced
+    inside (``time_call`` jits anew every measurement)."""
+    rule = kernel_mod.ring_shape
+    kernel_mod.ring_shape = lambda *a, **k: (nbuf, pp)
+    try:
+        yield
+    finally:
+        kernel_mod.ring_shape = rule
+
+
+def pairs(text: str) -> list[tuple[int, int]]:
+    return [tuple(int(x) for x in p.split("x")) for p in text.split(",")]
+
+
+def ints(text: str) -> list[int]:
+    return [int(x) for x in text.split(",")]
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="tp4,dense,sdar")
+    ap.add_argument("--sweep", default="cells,split",
+                    help="cells, split (ctx and spans, fitted), ladder, "
+                    "check, ops (a traced chain, time an op)")
+    ap.add_argument("--layers", type=int, default=16)
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--ctx-lo", type=int, default=200)
+    ap.add_argument("--ctx-hi", type=int, default=1500)
+    ap.add_argument("--contexts", type=ints,
+                    default=[64, 128, 256, 512, 1024, 2048])
+    ap.add_argument("--lanes", type=ints, default=[8, 16, 32, 64, 128])
+    ap.add_argument("--fit-ctx", type=int, default=512)
+    ap.add_argument("--ladder", type=pairs,
+                    default=pairs("2x16,3x16,4x16,6x16,4x8,3x32"),
+                    help="NBUFxPP points; the default is the ladder "
+                    "recorded in the kernel's docstring")
+    ap.add_argument("--set", action="append", default=[],
+                    metavar="NAME=INT", help="override a kernel constant")
+    ap.add_argument("--deadline", type=int, default=1200,
+                    help="hard stop, seconds: a DMA wait that never "
+                    "returns blocks inside the runtime")
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse on the CPU (interpret mode); no times")
+    args = ap.parse_args(argv)
+    if not on_tpu() and not args.allow_cpu:
+        print("no TPU: a CPU run gives no device time (--allow-cpu "
+              "rehearses)", file=sys.stderr)
+        return 1
+    watchdog = threading.Timer(args.deadline, lambda: os._exit(3))
+    watchdog.daemon = True
+    watchdog.start()
+    for item in args.set:
+        key, value = item.split("=")
+        assert hasattr(kernel_mod, key), key
+        setattr(kernel_mod, key, int(value))
+    rng = np.random.default_rng(args.seed)
+    sweeps = {"cells": sweep_cells, "split": sweep_split,
+              "ladder": sweep_ladder, "check": sweep_check,
+              "ops": sweep_ops}
+    for name in args.shapes.split(","):
+        for what in args.sweep.split(","):
+            sweeps[what](name, SHAPES[name], args, rng)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
