@@ -263,6 +263,39 @@ class EngineConfig:
                     "a block-diffusion model serves without speculative "
                     "drafting, the striped kv_sp cache or a sliding window"
                 )
+        if self.model.has_recurrent:
+            # A model with recurrent (linear-attention) layers
+            # (docs/architecture/unified_step.md "State that is not
+            # pages"): a sequence's past is pages AND a state that only
+            # its own slot holds, so everything that assumes the past is
+            # pages is off or refused, each by name.
+            if self.enable_prefix_caching:
+                import logging
+
+                logging.getLogger(__name__).info(
+                    "prefix caching is off for %s: a matched block has "
+                    "keys and values and no recurrent state behind it",
+                    self.model.name,
+                )
+                self.enable_prefix_caching = False
+            refused = {
+                "speculative drafting (speculative_k): a rejected draft "
+                "has already advanced the recurrent state":
+                    self.speculative_k,
+                "the striped kv_sp cache": self.kv_sp,
+                "int8 KV (kv_quant): the recurrent state has no per-block "
+                "scales": self.kv_quant,
+                "a device mesh (mesh_shape): the recurrent state is not "
+                "sharded": any(n > 1 for n in self.mesh_shape.values()),
+                "a sliding window": self.model.sliding_window,
+                "block diffusion": self.model.diffusion_block_length,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"{self.model.name} has recurrent layers and "
+                        f"serves without {what}"
+                    )
         if self.warmup_gate not in self._WARMUP_GATES:
             raise ValueError(
                 f"warmup_gate={self.warmup_gate!r} not in "

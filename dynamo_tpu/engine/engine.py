@@ -75,6 +75,16 @@ class TpuEngine:
         self._external_kv_event = on_kv_event
         self._on_metrics = on_metrics
         self.kvbm = block_manager  # KvBlockManager (G2/G3 tiers) or None
+        #: The model keeps a recurrent state beside the paged cache
+        #: (docs/architecture/unified_step.md "State that is not pages").
+        self._rec_on = cfg.model.has_recurrent
+        if self._rec_on and block_manager is not None:
+            raise ValueError(
+                f"{cfg.model.name} has recurrent layers and serves "
+                "without a block manager: KVBM offload and onboard and "
+                "peer parking move pages, and a page has no recurrent "
+                "state behind it"
+            )
         # Per-tier precision pairing (docs/architecture/kv_quant.md): an
         # int8 G1 offers (int8 data, scales) — an UNQUANTIZED tier
         # layout would silently drop the sidecars and fail every store
@@ -975,6 +985,12 @@ class TpuEngine:
             if self._prev_unified_out is not None
             else np.zeros((S, B_blk) if B_blk else S, np.int32)
         )
+        # A sequence's state slot is the batch slot it owns from admission
+        # to release (slot 0 of the table is the trash slot).
+        rec_kw = (
+            {"state_slots": [seq.slot + 1 for seq, *_r in roles]}
+            if self._rec_on else {}
+        )
         # Dispatch-start timestamp: paired with the retire time in
         # _process_unified_chunk to measure what decode lanes actually
         # waited (the mocker pays its simulated cost inside this call;
@@ -987,6 +1003,7 @@ class TpuEngine:
             draft_lens=(draft_lens if n_drafted else None),
             extras=extras,
             mm=mm_arg,
+            **rec_kw,
         )
         self._prev_unified_out = out.last
         self._prev_unified_rows = {
@@ -1041,17 +1058,14 @@ class TpuEngine:
             # A block dispatch records at retire too: what it committed
             # is device-side until then.
             self._diffusion_passes += len(decode_take)
-        elif n_drafted == 0:
+        elif n_drafted == 0 and out.moe_counts is None:
             # Spec dispatches record at PROCESS time instead (the
-            # accepted counts are device-side until retire); everything
-            # else records at issue, as before.
+            # accepted counts are device-side until retire), and so does
+            # one whose expert layers hand out counts; everything else
+            # records at issue, as before.
             self._note_step(
                 "unified",
-                decode_tokens=n_dec,
-                prefill_tokens=n_pre,
-                fill=self._unified_fill_ratio,
-                dispatch_ms=compose_ms,
-                lanes=len(roles),
+                **self._plain_note(roles, n_dec, n_pre, compose_ms),
             )
         # Auto-gate re-probe (semantics preserved from the phased gate):
         # after speculative_probe_steps plain decode steps, run a short
@@ -1090,6 +1104,16 @@ class TpuEngine:
             if n_dec:
                 blk_ids = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
             experts_hit = int(np.asarray(out.experts_hit))  # dynalint: allow[DT005] same retirement boundary as `toks`
+        elif out.moe_counts is not None:
+            # The plain program of a model with grouped expert layers: its
+            # flight record is noted here, with their counts.
+            hit, rows_held = np.asarray(out.moe_counts).tolist()  # dynalint: allow[DT005] same retirement boundary as `toks`
+            self._note_step(
+                "unified",
+                **self._plain_note(roles, n_dec, n_pre, compose_ms),
+                moe_experts_hit=hit,
+                moe_rows_held=rows_held,
+            )
         spec_counts = spec_toks = None
         if drafted:
             # Spec contract: the emitted rows + device-side accepted
@@ -1274,6 +1298,24 @@ class TpuEngine:
             )
         if self.cfg.speculative_k:
             self._maybe_gate_speculation()
+
+    def _plain_note(self, roles, n_dec, n_pre, compose_ms) -> dict:
+        """The flight-record fields of a plain unified dispatch; where the
+        model keeps recurrent state, what its state table saw beside them."""
+        note = dict(
+            decode_tokens=n_dec,
+            prefill_tokens=n_pre,
+            fill=self._unified_fill_ratio,
+            dispatch_ms=compose_ms,
+            lanes=len(roles),
+        )
+        if self._rec_on:
+            note.update(
+                kda_decode_lanes=sum(r[3] == 1 for r in roles),
+                kda_prefill_rows=sum(r[3] for r in roles if r[3] > 1),
+                kda_fresh_spans=sum(r[2] == 0 for r in roles),
+            )
+        return note
 
     @staticmethod
     def _lp_at(lp_np, seq: Sequence, lane: int, token: int) -> dict | None:
@@ -1861,7 +1903,7 @@ class TpuEngine:
         lanes: int = 0,
         drafted: int = 0,
         accepted: int = 0,
-        **diffusion: int,
+        **diffusion: int,  # and the expert layers' and recurrent layers' counts
     ) -> None:
         """One dispatch's flight record (engine thread). Counter fields
         are snapshots, so a reader diffs adjacent records to attribute a
@@ -1947,6 +1989,14 @@ class TpuEngine:
         batch — the batched path is the single implementation."""
         return await self.prefill_only_batch([(pre, request_id, device)])[0]
 
+    def _refuse_disagg(self) -> None:
+        if self._rec_on:
+            raise RequestError(
+                f"{self.cfg.model.name} has recurrent layers and serves "
+                "without remote prefill or disaggregation: a prompt's "
+                "blocks carry keys and values and no recurrent state"
+            )
+
     def prefill_only_batch(
         self,
         items: list[tuple[PreprocessedRequest, str, bool]],
@@ -1961,6 +2011,7 @@ class TpuEngine:
         depth-first, so early finishers ship (and release their arena
         blocks) while later prompts still compute; the caller must not
         wait for the whole batch before sending."""
+        self._refuse_disagg()
         futs = [self._loop.create_future() for _ in items]
         if self._draining:
             # Draining prefill worker: refuse the batch so the queue
@@ -2186,6 +2237,7 @@ class TpuEngine:
         """Decode side: admit `request` with remote KV. Returns an awaitable
         resolving to (num_blocks, stream) or None if admission failed
         (caller falls back to the local path)."""
+        self._refuse_disagg()
         if self._draining:
             OVERLOAD.note_shed(
                 "engine.draining", request_class=_request_class(pre)
@@ -2784,9 +2836,19 @@ class TpuEngine:
         from dynamo_tpu.models.moe import GROUPED_MIN_EXPERTS
 
         m = self.cfg.model
-        grouped = m.is_moe and m.num_experts >= GROUPED_MIN_EXPERTS
+        grouped = m.is_moe and m.experts_here >= GROUPED_MIN_EXPERTS
         layers = m.num_layers - m.first_k_dense_replace
+        sched = self.scheduler
         return {
+            # State that is not pages (a model with recurrent layers):
+            # slots of the state table that a sequence owns, and the
+            # table's bytes on the device. 0 for every other model.
+            "recurrent_state_slots_in_use": (
+                len(sched.running) if self._rec_on and sched is not None else 0
+            ),
+            "recurrent_state_bytes": getattr(
+                self.runner, "recurrent_state_bytes", 0
+            ),
             "diffusion_passes_total": self._diffusion_passes,
             "diffusion_committed_tokens_total": self._diffusion_committed,
             "moe_grouped_rows_total": (

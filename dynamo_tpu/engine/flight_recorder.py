@@ -79,6 +79,10 @@ class FlightRecorder:
         commit_rows: int = 0,
         committed_tokens: int = 0,
         moe_experts_hit: int = 0,
+        moe_rows_held: int = 0,
+        kda_decode_lanes: int = 0,
+        kda_prefill_rows: int = 0,
+        kda_fresh_spans: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -98,7 +102,12 @@ class FlightRecorder:
         committed (known at its retire, where such a dispatch records);
         ``moe_experts_hit`` is the experts that had a row, summed over its
         grouped expert layers: the weights its grouped kernels had to
-        read, which routing decides."""
+        read, which routing decides; ``moe_rows_held`` the routed (row,
+        expert) pairs that landed on an expert held here, summed likewise
+        (an expert share: models/moe.py). The three ``kda_`` fields are a
+        model's with recurrent layers: the lanes whose state advanced by
+        one row, the prefill rows that went through the chunk path, and
+        the spans that started from zeros."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -115,6 +124,10 @@ class FlightRecorder:
             "commit_rows": commit_rows,
             "committed_tokens": committed_tokens,
             "moe_experts_hit": moe_experts_hit,
+            "moe_rows_held": moe_rows_held,
+            "kda_decode_lanes": kda_decode_lanes,
+            "kda_prefill_rows": kda_prefill_rows,
+            "kda_fresh_spans": kda_fresh_spans,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

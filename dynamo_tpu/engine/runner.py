@@ -54,6 +54,7 @@ from dynamo_tpu.engine.compile_cache import (
 )
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.models import llama
+from dynamo_tpu.models.moe import GROUPED_MIN_EXPERTS, collect_experts_hit
 from dynamo_tpu.ops.sampling import (
     MAX_LOGPROBS,
     apply_penalties,
@@ -82,6 +83,10 @@ class UnifiedOut(NamedTuple):
     #: block contract only: experts that had a row, summed over the
     #: grouped expert layers (a scalar; 0 where the model has none)
     experts_hit: Any = None
+    #: the plain program of a model whose expert layers take the grouped
+    #: path: [2], the experts held here that had a row and the routed (row,
+    #: expert) pairs that landed on one, each summed over those layers
+    moe_counts: Any = None
 
 
 def _norm_sampling(sampling) -> tuple[float, int, float, int]:
@@ -145,7 +150,10 @@ def operand_layout(
     ``drafts``/``draft_len``), "block" (the ladder of a block-diffusion
     model: adds ``tok_masked``, which rows are fed as masks) or "extras"
     (the penalties/logprob and multimodal programs: adds the count-buffer
-    rows)."""
+    rows). ``+rec`` behind any of them (a model with recurrent layers)
+    adds ``state_slot``: the slot of the state table each span owns (0,
+    the trash slot, for an idle row)."""
+    variant, _, rec = variant.partition("+")
     rows = [
         ("token_ids", (T,), np.int32, 0),
         ("token_pos", (T,), np.int32, -1),      # -1 = padding row
@@ -181,6 +189,8 @@ def operand_layout(
         ]
     else:
         assert variant == "plain", variant
+    if rec:
+        rows += [("state_slot", (S,), np.int32, 0)]
     segs, off = {}, 0
     for name, shape, dtype, _fill in rows:
         end = off + int(np.prod(shape))
@@ -369,12 +379,15 @@ class ModelRunner(WarmupPlanMixin):
         kv_shape = (num_slots, cache_heads, self.cache_head_dim)
 
         def make_kv():
+            # A layer that keeps a recurrent state has no pages: its entry
+            # is empty and its state lives in `rec_state`.
             return [
                 (
                     jnp.zeros(kv_shape, self.kv_dtype),
                     jnp.zeros(kv_shape, self.kv_dtype),
                 )
-                for _ in range(m.num_layers)
+                if m.layer_kind(li) == "attn" else ()
+                for li in range(m.num_layers)
             ]
 
         def make_kv_scales():
@@ -536,6 +549,31 @@ class ModelRunner(WarmupPlanMixin):
         self.params = params
         self.kv_caches = kv_caches
         self.kv_scales = kv_scales
+        # The state that is not pages (docs/architecture/unified_step.md):
+        # for each recurrent layer a (state [N+1, H, d, d], convolution
+        # tail [N+1, K-1, 3*H*d]) pair over max_num_seqs + 1 slots, slot 0
+        # the trash slot; donated through every program beside the cache.
+        # None where the model has no such layer: no array, no operand.
+        rec_on = m.has_recurrent
+        self.rec_state = None
+        if rec_on:
+            n_slots = cfg.max_num_seqs + 1
+            H_l, d_l = m.num_heads, m.head_dim
+            self.rec_state = [
+                (
+                    jnp.zeros((n_slots, H_l, d_l, d_l), jnp.float32),
+                    jnp.zeros(
+                        (n_slots, m.linear_conv_kernel - 1, 3 * H_l * d_l),
+                        self.dtype,
+                    ),
+                )
+                for _ in m.recurrent_layers
+            ]
+        #: Bytes of recurrent state resident on the device (0 for a model
+        #: that keeps keys and values only); fixed at construction.
+        self.recurrent_state_bytes = sum(
+            a.nbytes for a in jax.tree.leaves(self.rec_state)
+        )
         self._step = 0
         # Weight-quant observability (DT011 surfaces read these via
         # getattr): bytes saved vs a full-precision tree, fraction of
@@ -591,13 +629,30 @@ class ModelRunner(WarmupPlanMixin):
 
         S_rows = self.unified_slots
         MB = cfg.max_blocks_per_seq
+        rec_sfx = "+rec" if rec_on else ""
+        #: Does the plain program hand out the expert layers' counts? Where
+        #: they take the grouped path (what the block program keys on too).
+        moe_counts_on = m.is_moe and m.experts_here >= GROUPED_MIN_EXPERTS
+
+        def _model(params, kv, kv_sc, o, meta, **kw):
+            """``llama.unified`` over a dispatch's operands -> (logits, kv,
+            kv_sc). ``kv`` is the cache operand as the programs donate it:
+            the paged caches, bundled with the recurrent state where the
+            model has recurrent layers (``_program_args``)."""
+            pages, rec = kv if rec_on else (kv, None)
+            out = llama.unified(
+                m, params, pages, *meta, bs, attn=attn, kv_scales=kv_sc,
+                rec_state=rec, state_slot=o.get("state_slot"), **kw,
+            )
+            kv = (out[1], out[-1]) if rec_on else out[1]
+            return out[0], kv, out[2] if kv_sc is not None else None
 
         def _unpack(packed, variant, prev_toks):
             """The packed buffer's segments by the layout's static
             offsets, the fed tokens substituted, and ``llama.unified``'s
             nine metadata operands in its order."""
             o = operand_layout_of(
-                packed.shape[0], S_rows, MB, K_spec, variant
+                packed.shape[0], S_rows, MB, K_spec, variant + rec_sfx
             ).unpack(packed)
             if variant == "block":
                 _feed_block(o, prev_toks)
@@ -617,18 +672,24 @@ class ModelRunner(WarmupPlanMixin):
             host round trip for token values. ``kv_sc`` is the per-block
             KV scale state under kv_quant (None otherwise) — it rides
             the dispatch like the caches do, so steady-state decode pays
-            no extra host traffic for quantization either."""
+            no extra host traffic for quantization either. Where the
+            model's expert layers take the grouped path (``moe_counts_on``)
+            their counts come back behind the tokens ([2]: the experts held
+            here that had a row, the routed rows that landed here, each
+            summed over the grouped expert layers), as the block program
+            hands out its own."""
             o, meta = _unpack(packed, "plain", prev_toks)
-            out = llama.unified(
-                m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
-            )
-            logits, kv = out[0], out[1]
-            kv_sc = out[2] if kv_sc is not None else None
+            with collect_experts_hit() as hit:
+                logits, kv, kv_sc = _model(params, kv, kv_sc, o, meta)
             toks = sample_tokens(
                 logits, o["key"], o["temp"], o["top_k"], o["top_p"],
                 seed=o["seed"], sample_pos=o["kv_len"],
             )
-            return jnp.where(o["q_len"] > 0, toks, 0), kv, kv_sc
+            toks = jnp.where(o["q_len"] > 0, toks, 0)
+            if not moe_counts_on:
+                return toks, kv, kv_sc
+            counts = jnp.stack([sum(hit), sum(hit.rows_held)])
+            return toks, counts, kv, kv_sc
 
         def unified_spec_fn(params, kv, kv_sc, packed, prev_toks):
             """The budget-ladder program of a spec-enabled engine
@@ -651,12 +712,10 @@ class ModelRunner(WarmupPlanMixin):
             o, meta = _unpack(packed, "spec", prev_toks)
             drafts, draft_len = o["drafts"], o["draft_len"]
             q_len, kv_len, temp = o["q_len"], o["kv_len"], o["temp"]
-            out = llama.unified(
-                m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
+            logits, kv, kv_sc = _model(          # [S, K+1, V]
+                params, kv, kv_sc, o, meta,
                 draft_len=draft_len, verify_rows=K_spec + 1,
             )
-            logits, kv = out[0], out[1]          # [S, K+1, V]
-            kv_sc = out[2] if kv_sc is not None else None
             greedy = jnp.argmax(logits, axis=-1)  # [S, K+1]
             matches = (drafts == greedy[:, :K_spec]) & (
                 jnp.arange(K_spec)[None, :] < draft_len[:, None]
@@ -702,19 +761,15 @@ class ModelRunner(WarmupPlanMixin):
             what the cache keeps. Returns (ids [S, B], counts [S], the
             experts that had a row summed over the grouped expert layers,
             kv, kv_sc)."""
-            from dynamo_tpu.models.moe import collect_experts_hit
-
             o, meta = _unpack(packed, "block", prev_toks)
             q_len = o["q_len"]
             with collect_experts_hit() as hit:
-                out = llama.unified(
-                    m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
+                logits, kv, kv_sc = _model(      # [S, B, V]
+                    params, kv, kv_sc, o, meta,
                     draft_len=jnp.full_like(q_len, B_blk - 1),
                     verify_rows=B_blk,
                 )
             experts_hit = sum(hit, jnp.zeros((), jnp.int32))
-            logits, kv = out[0], out[1]          # [S, B, V]
-            kv_sc = out[2] if kv_sc is not None else None
             T = o["token_ids"].shape[0]
             offs = jnp.arange(B_blk)[None, :]
             rows = jnp.clip(
@@ -749,12 +804,10 @@ class ModelRunner(WarmupPlanMixin):
                 embeds, embed_mask = (
                     mm_ops if with_mm else (None, None)
                 )
-                out = llama.unified(
-                    m, params, kv, *meta, bs, attn=attn, kv_scales=kv_sc,
+                logits, kv, kv_sc = _model(       # [S, V]
+                    params, kv, kv_sc, o, meta,
                     embeds=embeds, embed_mask=embed_mask,
                 )
-                logits, kv = out[0], out[1]       # [S, V]
-                kv_sc = out[2] if kv_sc is not None else None
                 B = counts.shape[0]
                 slot_clip = jnp.clip(span_slot, 0, B - 1)
                 valid = (span_slot >= 0) & (span_slot < B) & (q_len > 0)
@@ -822,7 +875,8 @@ class ModelRunner(WarmupPlanMixin):
         #: The budget ladder's operand layout variant.
         self._ladder_variant = (
             "spec" if K_spec > 0 else "block" if B_blk else "plain"
-        )
+        ) + rec_sfx
+        self._extras_variant = "extras" + rec_sfx
         #: Host arrays handed to the device, by the last dispatch and in
         #: all (the flight recorder and /metrics read them).
         self.operand_transfers = 0
@@ -864,7 +918,9 @@ class ModelRunner(WarmupPlanMixin):
             )
         else:
             self._unified = _jit(
-                unified_fn, (tok_sh, kv_sh, sc_sh), donate_argnums=(1, 2)
+                unified_fn,
+                (tok_sh,) * (1 + moe_counts_on) + (kv_sh, sc_sh),
+                donate_argnums=(1, 2),
             )
         lp4 = (tok_sh, tok_sh, tok_sh, tok_sh)
         self._unified_full = _jit(
@@ -903,7 +959,7 @@ class ModelRunner(WarmupPlanMixin):
         # Warm writes (trash block 0) must drain before serving reuses
         # the cache buffers under donation.
         # dynalint: allow[DT005] warmup drain, not serving: warm writes must land before donation; runs before traffic is admitted
-        jax.block_until_ready(self.kv_caches[0][0])
+        jax.block_until_ready(jax.tree.leaves(self.kv_caches)[0])
         return n
 
     def _warm_op(self, spec):
@@ -1176,6 +1232,7 @@ class ModelRunner(WarmupPlanMixin):
         draft_lens: list[int] | None = None,
         extras: dict | None = None,
         mm: list | None = None,
+        state_slots: list[int] | None = None,
     ) -> "UnifiedOut":
         """ONE ragged dispatch for a mixed prefill+decode batch.
 
@@ -1208,6 +1265,11 @@ class ModelRunner(WarmupPlanMixin):
         the unified_mm variant (top rung; carries the extras operands
         so mm and extras lanes co-batch).
 
+        ``state_slots``: per-lane slot of the recurrent-state table
+        (1..max_num_seqs; a model with recurrent layers only). A lane
+        whose ``prefix_len`` is 0 starts from zeros in the program; lanes
+        given no slot (warmup) aim at the trash slot 0.
+
         Returns a UnifiedOut of DEVICE arrays (not forced — the engine
         pipelines the fetch): ``last`` [S] is span s's (last) sampled
         token, and under the spec contract ``toks`` [S, K+1] /
@@ -1229,10 +1291,13 @@ class ModelRunner(WarmupPlanMixin):
             f"{total} tokens exceed the unified budget "
             f"{cfg.unified_token_budget}"
         )
-        variant = "extras" if use_full else self._ladder_variant
+        variant = self._extras_variant if use_full else self._ladder_variant
         base_args, _meta, ops = self._unified_operands(lanes, feed, T, variant)
         seg = ops.seg
         seg["key"][:] = self._next_key()
+        if state_slots is not None:
+            seg["state_slot"][: len(lanes)] = state_slots
+        base_args = self._program_args(base_args)
         mm_args = ()
         if use_full:
             if extras is not None:
@@ -1276,28 +1341,48 @@ class ModelRunner(WarmupPlanMixin):
             program = self._unified_mm if use_mm else self._unified_full
             with self.compile_stats.observe(kind, t=T):
                 (
-                    toks, clp, tids, tlps, self._counts,
-                    self.kv_caches, self.kv_scales,
+                    toks, clp, tids, tlps, self._counts, kv, self.kv_scales,
                 ) = program(
                     *base_args, self.ensure_counts(), packed,
                     ops.prev_toks, *mm_args,
                 )
+            self._set_kv(kv)
             self.last_unified_logprobs = (clp, tids, tlps)
             return UnifiedOut(last=toks, toks=None, counts=None)
 
         with self.compile_stats.observe("unified", t=T):
             out = self._unified(*base_args, packed, ops.prev_toks)
+        *heads, kv, self.kv_scales = out
+        self._set_kv(kv)
         if variant == "spec":
-            toks2d, counts, bonus, self.kv_caches, self.kv_scales = out
+            toks2d, counts, bonus = heads
             return UnifiedOut(last=bonus, toks=toks2d, counts=counts)
         if variant == "block":
-            ids, counts, hit, self.kv_caches, self.kv_scales = out
+            ids, counts, hit = heads
             # `ids` is the next dispatch's device feed.
             return UnifiedOut(
                 last=ids, toks=ids, counts=counts, experts_hit=hit
             )
-        toks, self.kv_caches, self.kv_scales = out
-        return UnifiedOut(last=toks, toks=None, counts=None)
+        toks, *moe_counts = heads
+        return UnifiedOut(last=toks, moe_counts=(moe_counts or [None])[0])
+
+    def _program_args(self, base_args: tuple) -> tuple:
+        """``_unified_operands``' (params, caches, scales) as the programs
+        take them: the recurrent state rides beside the pages in the cache
+        operand, donated with them."""
+        if self.rec_state is None:
+            return base_args
+        params, pages, scales = base_args
+        return params, (pages, self.rec_state), scales
+
+    def _set_kv(self, kv) -> None:
+        """Take a program's cache output back: the pages and, where the
+        model has recurrent layers, their state beside them."""
+        if self.rec_state is not None:
+            self.kv_caches, self.rec_state = kv
+        else:
+            self.kv_caches = kv
+
 
     def _unified_operands(self, lanes, feed, T: int, variant=None):
         """One dispatch of ``lanes`` padded to budget ``T``, on the
@@ -1394,7 +1479,9 @@ class ModelRunner(WarmupPlanMixin):
         )
         # The key segment stays zero: _next_key() would advance the run.
         base, _meta, ops = self._unified_operands(lanes, None, T)
-        return self._unified.lower(*base, self._put(ops.buf), ops.prev_toks)
+        return self._unified.lower(
+            *self._program_args(base), self._put(ops.buf), ops.prev_toks
+        )
 
     def unified_executables(self) -> int:
         """Executables the plain unified jit holds. jit's own count also

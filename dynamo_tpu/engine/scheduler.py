@@ -24,6 +24,7 @@ from dynamo_tpu.engine.sequence import Sequence, SeqStatus
 from dynamo_tpu.llm.protocols.common import FinishReason
 from dynamo_tpu.llm.tokens import TokenBlockSequence
 from dynamo_tpu.utils.deadline import OVERLOAD
+from dynamo_tpu.utils.tracing import tracer
 
 logger = logging.getLogger(__name__)
 
@@ -422,7 +423,13 @@ class Scheduler:
         become the new prompt, so generation resumes seamlessly). Shared by
         preemption and the disagg degradation path: a WAITING_REMOTE
         sequence whose KV transfer died falls back to LOCAL prefill through
-        here — the request is recomputed, never lost."""
+        here — the request is recomputed, never lost. A model with
+        recurrent layers loses the sequence's state with its slot: the
+        replay from position 0 starts the new slot's state from zeros."""
+        if self.cfg.model.has_recurrent:
+            tracer().mark_if_active(
+                seq.request_id, "recurrent_state_discarded"
+            )
         self._release(seq)
         seq.prompt_tokens = seq.prompt_tokens + seq.output_tokens
         seq.output_tokens = []

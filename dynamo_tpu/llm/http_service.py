@@ -212,6 +212,8 @@ class HttpService:
                 "diffusion_passes_total",
                 "diffusion_committed_tokens_total",
                 "moe_grouped_rows_total",
+                "recurrent_state_slots_in_use",
+                "recurrent_state_bytes",
                 "batch_fill_ratio",
                 "coloc_quantum",
                 "itl_ema_ms",

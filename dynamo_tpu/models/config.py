@@ -105,10 +105,60 @@ class ModelConfig:
     mask_token_id: int = 0
     denoising_steps: int = 0
     confidence_threshold: float = 0.0
+    # --- hybrid linear attention (Ling-3.0 family, model_type
+    # bailing_hybrid; docs/architecture/unified_step.md "State that is not
+    # pages") --- layer_group_size G > 0: layer l is softmax attention iff
+    # (l + 1) % G == 0, every other layer is a delta-rule linear-attention
+    # (KDA) layer that keeps a recurrent state of num_heads x head_dim x
+    # head_dim in float32 and the last linear_conv_kernel - 1 rows of its
+    # depthwise convolution's input a sequence, no keys and values.
+    layer_group_size: int = 0
+    linear_conv_kernel: int = 0
+    # The log of a KDA layer's per-channel decay lies in (kda_lower_bound, 0).
+    kda_lower_bound: float = 0.0
+    # --- an expert layer told which experts it holds (docs/architecture/
+    # expert_share.md) --- num_experts_held E_h > 0: the router keeps its
+    # num_experts outputs and its experts a token; this chip holds experts
+    # [expert_held_offset, expert_held_offset + E_h) and computes their part
+    # of a row's result. 0 = every expert is here.
+    num_experts_held: int = 0
+    expert_held_offset: int = 0
+    # SwiGLU clamp a layer (gate.clamp(max=L), up.clamp(-L, L); 0 = none),
+    # of the routed experts and of the shared expert. () = none anywhere.
+    expert_swiglu_limit: tuple = ()
+    shared_swiglu_limit: tuple = ()
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.num_experts_held or self.num_experts
+
+    def layer_kind(self, layer_idx: int) -> str:
+        """"kda" for a linear-attention layer (recurrent state), "attn"
+        for one that reads keys and values through the paged cache."""
+        if self.layer_group_size and (layer_idx + 1) % self.layer_group_size:
+            return "kda"
+        return "attn"
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """The layers that keep a recurrent state, in order."""
+        return tuple(
+            li for li in range(self.num_layers)
+            if self.layer_kind(li) == "kda"
+        )
+
+    @property
+    def has_recurrent(self) -> bool:
+        return bool(self.recurrent_layers)
+
+    def swiglu_limit(self, layer_idx: int, shared: bool = False) -> float:
+        limits = self.shared_swiglu_limit if shared else self.expert_swiglu_limit
+        return float(limits[layer_idx]) if layer_idx < len(limits) else 0.0
 
     @property
     def is_mla(self) -> bool:
@@ -177,6 +227,8 @@ class ModelConfig:
         hidden = cfg["hidden_size"]
         deepseek = "Deepseek" in arch or "deepseek" in cfg.get("model_type", "")
         sdar = cfg.get("model_type", "").startswith("sdar")
+        if cfg.get("model_type") == "bailing_hybrid":
+            return ModelConfig._from_hf_bailing_hybrid(cfg)
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -226,6 +278,64 @@ class ModelConfig:
             # Generation settings the family's config.json does not carry
             # (its generate script's defaults).
             **(SDAR_GENERATION if sdar else {}),
+        )
+
+    @staticmethod
+    def _from_hf_bailing_hybrid(cfg: dict) -> "ModelConfig":
+        """HF ``bailing_hybrid`` config.json (Ling-3.0) -> ModelConfig:
+        KDA linear-attention layers with one latent-attention layer a
+        group, sigmoid-scored experts with a selection bias behind the
+        leading dense layers. The multi-token-prediction module
+        (num_nextn_predict_layers) is not served."""
+        if cfg.get("use_kda_lora") or not cfg.get("no_kda_lora", True):
+            raise NotImplementedError(
+                "a low-rank KDA decay projection is not implemented "
+                "(no_kda_lora: true is)"
+            )
+        if cfg.get("gated_attention_proj_granularity_type", "head_wise") \
+                != "head_wise":
+            raise NotImplementedError(
+                "only the head_wise output gate of the KDA layers is "
+                "implemented"
+            )
+        return ModelConfig(
+            name=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get(
+                "num_key_value_heads", cfg["num_attention_heads"]),
+            head_dim=cfg["head_dim"],
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            rope_scaling=_rope_scaling(cfg.get("rope_scaling")),
+            kv_lora_rank=cfg["kv_lora_rank"],
+            q_lora_rank=cfg.get("q_lora_rank") or 0,
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            n_shared_experts=cfg.get("num_shared_experts", 0) or 0,
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            first_k_dense_replace=cfg.get("first_k_dense_replace", 0) or 0,
+            gating="sigmoid" if cfg.get("score_function") == "sigmoid"
+            else "softmax",
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+            n_group=cfg.get("n_group", 1) or 1,
+            topk_group=cfg.get("topk_group", 1) or 1,
+            layer_group_size=cfg["layer_group_size"],
+            linear_conv_kernel=cfg["short_conv_kernel_size"],
+            kda_lower_bound=float(cfg["kda_lower_bound"]),
+            expert_swiglu_limit=tuple(
+                cfg.get("expert_swiglu_limit_list") or ()),
+            shared_swiglu_limit=tuple(
+                cfg.get("share_expert_swiglu_limit_list") or ()),
         )
 
     @staticmethod
@@ -601,6 +711,99 @@ class ModelConfig:
         )
 
     @staticmethod
+    def ling_30_flash() -> "ModelConfig":
+        """Ling-3.0-flash (HF inclusionAI/Ling-3.0-flash config.json,
+        model_type bailing_hybrid): 42 layers, five KDA linear-attention
+        layers to one latent-attention layer (layer_group_size 6), two
+        leading dense layers, then 512 sigmoid-scored experts of width
+        768, 8 a token out of the 4 best of 8 groups, one shared expert.
+        The multi-token-prediction module is not served."""
+        return ModelConfig(
+            name="ling-3.0-flash",
+            vocab_size=157184,
+            hidden_size=2560,
+            intermediate_size=6144,
+            num_layers=42,
+            num_heads=32,
+            num_kv_heads=32,
+            head_dim=128,
+            rope_theta=6000000.0,
+            rms_eps=1e-6,
+            max_position=262144,
+            kv_lora_rank=512,
+            q_lora_rank=0,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            num_experts=512,
+            num_experts_per_tok=8,
+            n_shared_experts=1,
+            moe_intermediate_size=768,
+            first_k_dense_replace=2,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            routed_scaling_factor=2.5,
+            n_group=8,
+            topk_group=4,
+            layer_group_size=6,
+            linear_conv_kernel=4,
+            kda_lower_bound=-5.0,
+            expert_swiglu_limit=(0,) * 35 + (4,) * 7,
+            shared_swiglu_limit=(0,) * 34 + (5,) * 6 + (7,) * 2,
+        )
+
+    @staticmethod
+    def ling_30_flash_ep4_l8() -> "ModelConfig":
+        """One chip's share of a 4-way expert-parallel Ling-3.0-flash, the
+        first eight layers (both dense layers and one whole group): experts
+        0-127 of each expert layer, rows 0-39,295 of the vocabulary."""
+        return ModelConfig.ling_30_flash().scaled(
+            name="ling-3.0-flash-ep4-l8", num_layers=8,
+            num_experts_held=128, vocab_size=39296,
+        )
+
+    @staticmethod
+    def tiny_ling_test(vocab_size: int = 384, held: int = 0) -> "ModelConfig":
+        """Hermetic Ling-style test model: 2 dense + 6 expert layers in the
+        real pattern (layer 5 latent attention, the rest KDA), 32 experts
+        in 4 groups of which ``held`` (0 = all: the grouped path; 8: the
+        dense one) live here, a swiglu limit on one layer."""
+        return ModelConfig(
+            name="tiny-ling-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=8,
+            num_heads=4,
+            num_kv_heads=4,
+            head_dim=16,
+            rope_theta=10000.0,
+            rms_eps=1e-6,
+            max_position=512,
+            kv_lora_rank=32,
+            q_lora_rank=0,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=16,
+            num_experts=32,
+            num_experts_per_tok=4,
+            n_shared_experts=1,
+            moe_intermediate_size=32,
+            first_k_dense_replace=2,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            routed_scaling_factor=2.5,
+            n_group=4,
+            topk_group=2,
+            layer_group_size=6,
+            linear_conv_kernel=4,
+            kda_lower_bound=-5.0,
+            num_experts_held=held,
+            expert_swiglu_limit=(0, 0, 0, 1.5),
+            shared_swiglu_limit=(0, 0, 0, 0, 1.0),
+        )
+
+    @staticmethod
     def llama3_8b() -> "ModelConfig":
         return ModelConfig(
             name="llama3-8b",
@@ -725,4 +928,7 @@ PRESETS = {
     "mistral-7b": ModelConfig.mistral_7b,
     "gemma3-1b": ModelConfig.gemma3_1b,
     "tiny-gemma-test": ModelConfig.tiny_gemma_test,
+    "ling-3.0-flash": ModelConfig.ling_30_flash,
+    "ling-3.0-flash-ep4-l8": ModelConfig.ling_30_flash_ep4_l8,
+    "tiny-ling-test": ModelConfig.tiny_ling_test,
 }

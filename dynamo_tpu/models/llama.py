@@ -148,10 +148,13 @@ def _residual_attn(x, layer, attn_out, cfg: ModelConfig):
     return x + attn_out
 
 
-def _residual_mlp(x, layer, cfg: ModelConfig, mesh=None):
-    """Pre-norm → gated MLP → (optional post-norm) → residual add."""
+def _residual_mlp(
+    x, layer, cfg: ModelConfig, mesh=None, li: int = 0, valid=None
+):
+    """Pre-norm → gated MLP → (optional post-norm) → residual add.
+    ``valid`` marks the rows that hold a token (an expert share's)."""
     h = _ln(x, layer["ln_mlp"], cfg)
-    m = _mlp(layer, h, cfg, mesh)
+    m = _mlp(layer, h, cfg, mesh, li, valid)
     if cfg.post_norms:
         m = _ln(m, layer["ln_post_mlp"], cfg)
     return x + m
@@ -172,8 +175,13 @@ def init_layer_params(
     def norm_init(shape):
         return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, dtype)
 
-    keys = iter(jax.random.split(key, 16))
-    if cfg.is_mla:
+    kda = cfg.layer_kind(li) == "kda"
+    # (A linear-attention layer draws more matrices than 16 keys hold; the
+    # other kinds keep the split their weights have always come from.)
+    keys = iter(jax.random.split(key, 24 if kda else 16))
+    if kda:
+        layer = _init_kda_mixer(keys, cfg, dtype)
+    elif cfg.is_mla:
         # DeepSeek-V2/V3 MLA: latent KV compression (kv_lora_rank)
         # plus a decoupled roped path (qk_rope_head_dim); see
         # _qkv_mla for the absorbed-projection attention math.
@@ -213,14 +221,16 @@ def init_layer_params(
         # Sparse MLP (models/moe.py): router + stacked expert weights,
         # ep/tp-shardable; DeepSeekMoE adds always-on shared experts
         # and (V3/R1) a sigmoid router with a selection-bias term.
-        E = cfg.num_experts
+        # The router keeps its published width; the expert matrices are
+        # those held here (cfg.num_experts_held; all of them by default).
+        E, Eh = cfg.num_experts, cfg.experts_here
         Im = cfg.moe_intermediate_size or I
         layer["w_router"] = dense(next(keys), (D, E))
         if cfg.gating == "sigmoid":
             layer["router_bias"] = jnp.zeros((E,), jnp.float32)
-        layer["w_gate"] = _dense3(next(keys), (E, D, Im), D, dtype)
-        layer["w_up"] = _dense3(next(keys), (E, D, Im), D, dtype)
-        layer["w_down"] = _dense3(next(keys), (E, Im, D), Im, dtype)
+        layer["w_gate"] = _dense3(next(keys), (Eh, D, Im), D, dtype)
+        layer["w_up"] = _dense3(next(keys), (Eh, D, Im), D, dtype)
+        layer["w_down"] = _dense3(next(keys), (Eh, Im, D), Im, dtype)
         if cfg.n_shared_experts:
             Is = Im * cfg.n_shared_experts
             layer["w_shared_gate"] = dense(next(keys), (D, Is))
@@ -230,13 +240,41 @@ def init_layer_params(
         layer["w_gate"] = dense(next(keys), (D, I))
         layer["w_up"] = dense(next(keys), (D, I))
         layer["w_down"] = dense(next(keys), (I, D))
-    if cfg.qkv_bias:
+    if cfg.qkv_bias and not kda:
         layer["bq"] = jnp.zeros((H * hd,), dtype)
         layer["bk"] = jnp.zeros((kvH * hd,), dtype)
         layer["bv"] = jnp.zeros((kvH * hd,), dtype)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not kda:
         layer["ln_q_head"] = norm_init((hd,))
         layer["ln_k_head"] = norm_init((hd,))
+    return layer
+
+
+def _init_kda_mixer(keys, cfg: ModelConfig, dtype) -> Params:
+    """A linear-attention (KDA) layer's mixer and its two block norms:
+    q/k/v and the full-rank decay projection, the per-head beta and output
+    gate, one depthwise convolution each for q, k and v, the decay's
+    ``A_log`` (a head) and ``dt_bias`` (a channel), the output norm."""
+    D, H, hd, K = (
+        cfg.hidden_size, cfg.num_heads, cfg.head_dim, cfg.linear_conv_kernel
+    )
+    C = H * hd
+    layer = {
+        name: _dense_init(next(keys), (D, C), dtype)
+        for name in ("wq", "wk", "wv", "w_a")
+    }
+    layer["w_beta"] = _dense_init(next(keys), (D, H), dtype)
+    layer["w_g"] = _dense_init(next(keys), (D, H), dtype)
+    for name in ("conv_q", "conv_k", "conv_v"):
+        layer[name] = _dense_init(next(keys), (K, C), dtype)
+    layer["A_log"] = jnp.log(
+        jax.random.uniform(next(keys), (H,), jnp.float32, 1.0, 16.0)
+    )
+    layer["dt_bias"] = jax.random.normal(next(keys), (C,), jnp.float32)
+    layer["wo"] = _dense_init(next(keys), (C, D), dtype)
+    layer["ln_kda"] = jnp.ones((hd,), dtype)
+    layer["ln_attn"] = jnp.ones((D,), dtype)
+    layer["ln_mlp"] = jnp.ones((D,), dtype)
     return layer
 
 
@@ -356,32 +394,41 @@ def _mla_out(layer: Params, attn: jnp.ndarray, cfg: ModelConfig):
 
 
 def _swiglu(
-    layer: Params, x: jnp.ndarray, prefix: str = "w_", act: str = "silu"
+    layer: Params, x: jnp.ndarray, prefix: str = "w_", act: str = "silu",
+    limit: float = 0.0,
 ) -> jnp.ndarray:
     # "silu" = Llama SwiGLU; "gelu_tanh" = Gemma GeGLU (HF
     # hidden_activation="gelu_pytorch_tanh" = tanh-approximated gelu).
-    gate = qdot(x, layer[f"{prefix}gate"])
+    # `limit` L > 0 clamps before the activation: gate to at most L, up
+    # into [-L, L] (Ling-3.0's swiglu limit lists).
+    from dynamo_tpu.models.moe import clamp_swiglu
+
+    gate, up = clamp_swiglu(
+        qdot(x, layer[f"{prefix}gate"]), qdot(x, layer[f"{prefix}up"]), limit
+    )
     gate = (
         jax.nn.silu(gate) if act == "silu"
         else jax.nn.gelu(gate, approximate=True)
     )
-    return qdot(gate * qdot(x, layer[f"{prefix}up"]), layer[f"{prefix}down"])
+    return qdot(gate * up, layer[f"{prefix}down"])
 
 
 def _mlp(
-    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None
+    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None, li: int = 0,
+    valid=None,
 ) -> jnp.ndarray:
     # Structure-driven: a router in the layer means routed experts (MoE
     # models may keep their first_k_dense_replace layers dense). `mesh`
     # (from the AttnDispatch) places the grouped expert path's products
     # per shard (models/moe.py _moe_mlp_grouped).
     if "w_router" in layer:
-        return _moe_mlp(layer, x, cfg, mesh)
+        return _moe_mlp(layer, x, cfg, mesh, li, valid)
     return _swiglu(layer, x, act=cfg.hidden_act)
 
 
 def _moe_mlp(
-    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None
+    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None, li: int = 0,
+    valid=None,
 ) -> jnp.ndarray:
     """Top-k routed expert MLP over arbitrary leading dims (models/moe.py:
     dense einsums below 16 experts, the grouped path from there up;
@@ -399,13 +446,64 @@ def _moe_mlp(
         routed_scaling_factor=cfg.routed_scaling_factor,
         n_group=cfg.n_group,
         topk_group=cfg.topk_group,
+        num_experts_held=cfg.num_experts_held,
+        expert_held_offset=cfg.expert_held_offset,
+        swiglu_limit=cfg.swiglu_limit(li),
     )
     lead = x.shape[:-1]
     flat = x.reshape(-1, cfg.hidden_size)
-    out = moe_mlp(layer, flat, mcfg, mesh=mesh)
-    if "w_shared_gate" in layer:
-        out = out + _swiglu(layer, flat, prefix="w_shared_")
+    with jax.named_scope("expert_layer"):
+        out = moe_mlp(layer, flat, mcfg, mesh=mesh, valid=valid)
+        if "w_shared_gate" in layer:
+            out = out + _swiglu(
+                layer, flat, prefix="w_shared_",
+                limit=cfg.swiglu_limit(li, shared=True),
+            )
     return out.reshape(*lead, cfg.hidden_size)
+
+
+def _kda_mixer(
+    layer: Params, h: jnp.ndarray, cfg: ModelConfig, state, meta,
+    state_slot, use_pallas: bool,
+):
+    """A KDA linear-attention layer's mixer over the flat ragged batch
+    (ops/linear_attention.py): ``h`` [T, D] normed rows -> (y [T, D], the
+    layer's new state). ``state`` is the layer's (S [N+1, H, d, d],
+    convolution tail [N+1, K-1, 3*H*d]); ``meta`` the dispatch's
+    (token_seq, token_pos, q_start, q_len, row_start). conv -> silu ->
+    L2 norm on q and k, q scaled by d^-1/2; the decay's log in
+    (kda_lower_bound, 0) a channel; one beta and one output gate a head;
+    no rotary embedding."""
+    from dynamo_tpu.ops.linear_attention import causal_conv, kda_ragged
+
+    T = h.shape[0]
+    H, d = cfg.num_heads, cfg.head_dim
+    S, tail = state
+    qkv = jnp.concatenate(
+        [qdot(h, layer[w]) for w in ("wq", "wk", "wv")], axis=-1
+    )
+    conv_w = jnp.concatenate(
+        [layer[w] for w in ("conv_q", "conv_k", "conv_v")], axis=-1
+    )
+    qkv, tail = causal_conv(qkv, conv_w, tail, *meta, state_slot)
+    q, k, v = jnp.split(jax.nn.silu(qkv).reshape(T, 3 * H, d), 3, axis=1)
+
+    def l2(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(layer["A_log"])[None, :, None]
+        * (qdot(h, layer["w_a"]).astype(jnp.float32) + layer["dt_bias"])
+        .reshape(T, H, d)
+    )
+    beta = jax.nn.sigmoid(qdot(h, layer["w_beta"]).astype(jnp.float32))
+    o, S = kda_ragged(
+        l2(q) * d**-0.5, l2(k), v, g, beta, S, *meta, state_slot,
+        use_pallas=use_pallas,
+    )
+    gate = jax.nn.sigmoid(qdot(h, layer["w_g"]).astype(jnp.float32))
+    o = rms_norm(o, layer["ln_kda"], cfg.rms_eps) * gate[:, :, None]
+    return qdot(o.reshape(T, H * d).astype(h.dtype), layer["wo"]), (S, tail)
 
 
 def _to_cache(vals: jnp.ndarray, cache: jnp.ndarray) -> jnp.ndarray:
@@ -444,6 +542,8 @@ def unified(
     verify_rows: int = 1,                  # static: logit rows per span
     embeds: jnp.ndarray | None = None,     # [T, D] soft-prompt overrides
     embed_mask: jnp.ndarray | None = None, # [T] bool — rows from embeds
+    rec_state: list | None = None,         # a (S, tail) pair a KDA layer
+    state_slot: jnp.ndarray | None = None, # [S] each span's state slot
 ):
     """ONE forward for a mixed prefill+decode token batch (the unified
     step — docs/architecture/unified_step.md). The trunk is the single-
@@ -475,7 +575,12 @@ def unified(
     span row ``q_len - 1 - draft_len + j`` (clamped into the span) —
     for a draft-verify span row 0 scores the first draft and row
     ``draft_len`` is the bonus position; spans with fewer rows repeat
-    their last row (masked by the caller's acceptance law)."""
+    their last row (masked by the caller's acceptance law).
+
+    A model with linear-attention layers (``cfg.layer_kind``) takes
+    ``rec_state``, one (state, convolution tail) pair for each of them in
+    order, and ``state_slot``, and returns the new ``rec_state`` as its
+    last result; those layers' entries of ``kv_caches`` are empty."""
     if attn is None:
         from dynamo_tpu.ops import attention as attn_ops
 
@@ -499,12 +604,27 @@ def unified(
     )
     new_caches = []
     new_scales = []
-    for li, (layer, (k_cache, v_cache)) in enumerate(
-        zip(params["layers"], kv_caches)
-    ):
+    new_rec = []
+    # An expert share drops the budget's padding rows with the rows routed
+    # elsewhere; a model whose experts are all here computes every row.
+    valid = token_pos >= 0 if cfg.num_experts_held else None
+    for li, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
         h = _ln(x, layer["ln_attn"], cfg)
+        if cfg.layer_kind(li) == "kda":
+            with jax.named_scope("kda_mixer"):
+                y, state = _kda_mixer(
+                    layer, h, cfg, rec_state[len(new_rec)],
+                    (token_seq, token_pos, q_start, q_len, row_start),
+                    state_slot, attn is not None and attn.use_pallas,
+                )
+            new_rec.append(state)
+            new_caches.append(cache)
+            x = _residual_mlp(x + y, layer, cfg, mesh, li, valid)
+            continue
+        k_cache, v_cache = cache
         if cfg.is_mla:
-            q, k, v = _qkv_mla(layer, h, cfg, positions)
+            with jax.named_scope("latent_mixer"):
+                q, k, v = _qkv_mla(layer, h, cfg, positions)
         else:
             q, k, v = _qkv(layer, h, cfg)
             th, sc = _layer_rope(cfg, li)
@@ -533,12 +653,13 @@ def unified(
             window=cfg.layer_window(li), **scale_kw, **block_kw,
         )
         if cfg.is_mla:
-            x = x + _mla_out(layer, attn_out, cfg)
+            with jax.named_scope("latent_mixer"):
+                x = x + _mla_out(layer, attn_out, cfg)
         else:
             x = _residual_attn(
                 x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg
             )
-        x = _residual_mlp(x, layer, cfg, mesh)
+        x = _residual_mlp(x, layer, cfg, mesh, li, valid)
         new_caches.append((k_cache, v_cache))
 
     if verify_rows == 1:
@@ -563,9 +684,12 @@ def unified(
         )                                                    # [S, R]
         rows = jnp.clip(row_start[:, None] + span_row, 0, T - 1)
         logits = _logits(params, cfg, x[rows])               # [S, R, V]
+    out = (logits, new_caches)
     if kv_scales is not None:
-        return logits, new_caches, jnp.stack(new_scales)
-    return logits, new_caches
+        out += (jnp.stack(new_scales),)
+    if rec_state is not None:
+        out += (new_rec,)
+    return out
 
 
 def hidden_states(
@@ -587,7 +711,20 @@ def hidden_states(
         x = jnp.where(embed_mask[:, None], embeds.astype(x.dtype), x)
     for li, layer in enumerate(params["layers"]):
         h = _ln(x, layer["ln_attn"], cfg)
-        if cfg.is_mla:
+        if cfg.layer_kind(li) == "kda":
+            # One span from zeros: slot 1 of a fresh two-slot state.
+            H, d, K = cfg.num_heads, cfg.head_dim, cfg.linear_conv_kernel
+            one = jnp.ones((1,), jnp.int32)
+            y, _ = _kda_mixer(
+                layer, h, cfg,
+                (jnp.zeros((2, H, d, d), jnp.float32),
+                 jnp.zeros((2, K - 1, 3 * H * d), h.dtype)),
+                (jnp.zeros((T,), jnp.int32), positions, 0 * one, T * one,
+                 0 * one),
+                one, False,
+            )
+            x = x + y
+        elif cfg.is_mla:
             q, k, v = _qkv_mla(layer, h, cfg, positions)
             attn = full_causal_attention(q, k, v)
             x = x + _mla_out(layer, attn, cfg)
@@ -601,7 +738,7 @@ def hidden_states(
                 diffusion_block=max(cfg.diffusion_block_length, 1),
             )
             x = _residual_attn(x, layer, qdot(attn.reshape(T, -1), layer["wo"]), cfg)
-        x = _residual_mlp(x, layer, cfg)
+        x = _residual_mlp(x, layer, cfg, li=li)
     return x
 
 
@@ -667,6 +804,15 @@ def load_hf_weights(
     layers = []
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}"
+        if cfg.layer_kind(i) == "kda":
+            # The KDA mixer's checkpoint names are not mapped (the benchmark
+            # serves seeded weights): say which tensors are left over
+            # rather than load a layer without its mixer.
+            raise NotImplementedError(
+                f"load_hf_weights: layer {i} is a KDA linear-attention "
+                "layer, whose checkpoint tensors are not mapped: "
+                + ", ".join(sorted(n for n in tensors if n.startswith(p + ".")))
+            )
         if cfg.is_mla:
             # DeepSeek-V2/V3 MLA layout. kv_b_proj packs per-head
             # [k_nope ‖ v] up-projections over the latent; split it into
@@ -759,11 +905,12 @@ def load_hf_weights(
                     if bias_name in tensors
                     else jnp.zeros((cfg.num_experts,), jnp.float32)
                 )
+            lo = cfg.expert_held_offset
             for key, ename in zip(("w_gate", "w_up", "w_down"), enames):
                 layer[key] = jnp.stack(
                     [
                         w(f"{m}.experts.{e}.{ename}")
-                        for e in range(cfg.num_experts)
+                        for e in range(lo, lo + cfg.experts_here)
                     ]
                 )
             if cfg.n_shared_experts:

@@ -44,11 +44,27 @@ class MoeConfig:
     # only the topk_group best groups stay eligible.
     n_group: int = 1
     topk_group: int = 1
+    # The share of the experts held here (docs/architecture/
+    # expert_share.md): the router keeps its num_experts outputs and its
+    # experts a token; the stacked weights are those of experts
+    # [expert_held_offset, expert_held_offset + num_experts_held), and a
+    # row's result is the weighted sum over those of its experts that are
+    # held. 0 = every expert is here.
+    num_experts_held: int = 0
+    expert_held_offset: int = 0
+    # Clamp before the activation: gate to at most L, up into [-L, L];
+    # 0 = none.
+    swiglu_limit: float = 0.0
+
+    @property
+    def experts_here(self) -> int:
+        return self.num_experts_held or self.num_experts
+
     @property
     def grouped(self) -> bool:
         """Which exact expert path ``moe_mlp`` runs: the grouped one from
-        ``GROUPED_MIN_EXPERTS`` experts up, the dense one below."""
-        return self.num_experts >= GROUPED_MIN_EXPERTS
+        ``GROUPED_MIN_EXPERTS`` experts held here up, the dense one below."""
+        return self.experts_here >= GROUPED_MIN_EXPERTS
 
 
 # Dense runs E/topk times the selected FLOPs and reads every expert; the
@@ -135,12 +151,28 @@ def moe_route(
 
 
 def moe_router(params: dict, x: jnp.ndarray, cfg: MoeConfig) -> jnp.ndarray:
-    """``moe_route`` as dense gates [T, E] with mass only on each token's
-    selected experts."""
+    """``moe_route`` as dense gates [T, E_held] with mass only on those of
+    each token's selected experts that are held here (all of them where
+    every expert is)."""
     topi, gates_k = moe_route(params, x, cfg)
-    return jnp.zeros((x.shape[0], cfg.num_experts), jnp.float32).at[
+    gates = jnp.zeros((x.shape[0], cfg.num_experts), jnp.float32).at[
         jnp.arange(x.shape[0])[:, None], topi
     ].set(gates_k)
+    lo = cfg.expert_held_offset
+    return gates[:, lo : lo + cfg.experts_here]
+
+
+def clamp_swiglu(gate, up, limit: float):
+    """A swiglu limit ``L > 0`` before the activation: the gate to at most
+    ``L``, the up projection into ``[-L, L]``; 0 = none."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return gate, up
+
+
+def _act(gate, up, cfg: MoeConfig):
+    gate, up = clamp_swiglu(gate, up, cfg.swiglu_limit)
+    return jax.nn.silu(gate) * up
 
 
 def _expert_einsum(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -156,39 +188,51 @@ def _expert_einsum(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 def moe_mlp(
-    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None
+    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None
 ) -> jnp.ndarray:
     """x [T, D] → [T, D] through top-k routed experts, exactly.
 
     Below ``GROUPED_MIN_EXPERTS`` experts: every expert for every token,
     masked by the gates (O(E/topk) extra FLOPs; GSPMD shards it). From
     there up: ``_moe_mlp_grouped``. ``mesh`` places the grouped path's
-    products per shard."""
+    products per shard. ``valid`` [T] marks the rows that hold a token
+    (budget padding does not): an expert share drops the others with the
+    rows routed elsewhere."""
     if cfg.grouped:
         with jax.named_scope("moe_grouped_ffn"):
-            return _moe_mlp_grouped(params, x, cfg, mesh)
+            return _moe_mlp_grouped(params, x, cfg, mesh, valid)
     gates = moe_router(params, x, cfg)
     xf = x.astype(jnp.float32)
     up = _expert_einsum("td,edi->tei", xf, params["w_up"])
     gate = _expert_einsum("td,edi->tei", xf, params["w_gate"])
-    h = jax.nn.silu(gate) * up                                    # [T, E, I]
+    h = _act(gate, up, cfg)                                       # [T, E, I]
     out = _expert_einsum("tei,eid->ted", h, params["w_down"])
     return jnp.einsum("ted,te->td", out, gates).astype(x.dtype)
 
 
-#: While a step program is traced under ``collect_experts_hit``: one traced
-#: scalar a grouped expert layer, the experts that had a row.
-_EXPERTS_HIT: list | None = None
+class _Collected(list):
+    """One traced scalar a grouped expert layer: the experts held here that
+    had a row; ``rows_held`` beside it, the routed (row, expert) pairs that
+    landed on an expert held here."""
+
+    def __init__(self):
+        super().__init__()
+        self.rows_held: list = []
+
+
+#: While a step program is traced under ``collect_experts_hit``.
+_EXPERTS_HIT: _Collected | None = None
 
 
 @contextlib.contextmanager
 def collect_experts_hit():
     """Collect, while the caller traces a model function, how many experts
     had a row in each grouped expert layer (what the layer's kernels had
-    to read: routing decides it, so only the program can count it). Yields
-    the list the layers append their traced scalars to."""
+    to read: routing decides it, so only the program can count it) and, as
+    the list's ``rows_held``, how many routed rows landed here. Yields the
+    list the layers append their traced scalars to."""
     global _EXPERTS_HIT
-    before, _EXPERTS_HIT = _EXPERTS_HIT, []
+    before, _EXPERTS_HIT = _EXPERTS_HIT, _Collected()
     try:
         yield _EXPERTS_HIT
     finally:
@@ -242,7 +286,7 @@ def _grouped_dot(rows, w, sizes, row_expert):
 
 
 def _moe_mlp_grouped(
-    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None
+    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None
 ) -> jnp.ndarray:
     """The dropless grouped path: the T*k routed (token, expert) rows
     sorted by expert, one ``ragged_dot`` an expert projection over the
@@ -256,20 +300,33 @@ def _moe_mlp_grouped(
     experts, whose rows lie together in the sorted order; the partial
     results meet in one all-reduce."""
     T, D = x.shape
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    E, k = cfg.experts_here, cfg.num_experts_per_tok
     topi, gates_k = moe_route(params, x, cfg)               # [T, k] each
-    flat_e = topi.reshape(-1)                                # [T*k]
+    flat_e = topi.reshape(-1) - cfg.expert_held_offset       # [T*k]
+    held = None
+    if cfg.num_experts_held:
+        # Rows routed to an expert that is not held (and budget padding)
+        # are dropped before the sort: they sort behind every held
+        # expert's rows, belong to no group, and are zeroed behind the
+        # products (the sentinel E falls outside `sizes`: dropped).
+        held = (flat_e >= 0) & (flat_e < E)
+        if valid is not None:
+            held &= jnp.repeat(valid, k)
+        flat_e = jnp.where(held, flat_e, E)
     order = jnp.argsort(flat_e, stable=True)                 # by expert
     row_expert = flat_e[order]
+    if held is not None:
+        row_expert = jnp.minimum(row_expert, E - 1)
     rows = x[order // k]                                     # [T*k, D]
     sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
     if _EXPERTS_HIT is not None:
         _EXPERTS_HIT.append((sizes > 0).sum().astype(jnp.int32))
+        _EXPERTS_HIT.rows_held.append(sizes.sum())
 
     def ffn(rows, sizes, row_expert, w_gate, w_up, w_down):
         gate = _grouped_dot(rows, w_gate, sizes, row_expert)
         up = _grouped_dot(rows, w_up, sizes, row_expert)
-        h = (jax.nn.silu(gate) * up).astype(rows.dtype)
+        h = _act(gate, up, cfg).astype(rows.dtype)
         return _grouped_dot(h, w_down, sizes, row_expert)    # [T*k, D] f32
 
     axes = {
@@ -310,6 +367,9 @@ def _moe_mlp_grouped(
             check_vma=False,
         )(rows, sizes, row_expert, *weights)
     y = y * gates_k.reshape(-1)[order][:, None]
+    if held is not None:
+        # (Rows behind the last group are whatever the kernel left there.)
+        y = jnp.where(held[order][:, None], y, 0.0)
     back = jnp.argsort(order)                                # row of (t, j)
     return y[back].reshape(T, k, D).sum(axis=1).astype(x.dtype)
 

@@ -519,19 +519,20 @@ def init_params_policy(key, cfg, policy, dtype=jnp.bfloat16):
             for name, w in p.items()
         }
 
-    # One compile per layer KIND (dense vs MoE), not per layer index — the
-    # index only matters through cfg.moe_layer(li).
-    kind_repr = {
-        flag: next(
-            i for i in range(cfg.num_layers) if cfg.moe_layer(i) == flag
-        )
-        for flag in {cfg.moe_layer(i) for i in range(cfg.num_layers)}
-    }
+    # One compile per layer KIND (dense vs MoE, softmax vs linear
+    # attention), not per layer index — the index only matters through
+    # cfg.moe_layer(li) and cfg.layer_kind(li).
+    def kind(i):
+        return cfg.moe_layer(i), cfg.layer_kind(i)
+
+    kind_repr = {}
+    for i in range(cfg.num_layers):
+        kind_repr.setdefault(kind(i), i)
     lk, ek, hk = jax.random.split(key, 3)
     layer_keys = jax.random.split(lk, cfg.num_layers)
     layers = []
     for li in range(cfg.num_layers):
-        layer = one_layer(layer_keys[li], kind_repr[cfg.moe_layer(li)])
+        layer = one_layer(layer_keys[li], kind_repr[kind(li)])
         jax.block_until_ready(jax.tree.leaves(layer)[0])
         layers.append(layer)
 

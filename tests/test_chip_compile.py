@@ -245,3 +245,63 @@ def test_sdar_block_step_compiles_at_published_widths(
     # a layer: the attention kernel and gate, up, down
     assert _kernel_count(text) == 4 * cfg.num_layers, _kernel_count(text)
     assert "all-reduce" not in text
+
+
+@pytest.mark.slow  # 35 s of many-threaded compiling beside the suite's timing-gated tests
+def test_ling_share_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch
+):
+    """The whole of ``ling-3.0-flash-ep4-l8`` (two dense layers and one
+    group of six over 128 of 512 experts, a quarter of the vocabulary) in
+    one unified step at T=256 with the state table of 128 lanes: the
+    delta-rule kernels (two a recurrent layer, the state aliased in
+    place), the ragged kernel over one cached head of 640, the grouped
+    expert path's kernels, within one chip's memory."""
+    from dynamo_tpu.models import moe
+    from dynamo_tpu.ops.pallas import kda
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    cfg = ModelConfig.ling_30_flash_ep4_l8()
+    sds = partial(_sds, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+    lanes, rows = 129, 132
+    page = sds((20000 * BS, 1, 640), jnp.bfloat16)
+    kv = [(page, page) if cfg.layer_kind(li) == "attn" else ()
+          for li in range(cfg.num_layers)]
+    rec = [
+        (sds((lanes, 32, 128, 128), jnp.float32),
+         sds((lanes, 3, 3 * 32 * 128), jnp.bfloat16))
+        for _ in cfg.recurrent_layers
+    ]
+    i32 = partial(sds, dtype=jnp.int32)
+    T = 256
+    meta = (
+        i32((T,)), i32((T,)), i32((T,)), i32((T,)), i32((rows, 256)),
+        i32((rows,)), i32((rows,)), i32((rows,)), i32((rows,)),
+    )
+
+    def step(params, kv, rec, slot, *meta):
+        logits, kv, rec = llama.unified(
+            cfg, params, kv, *meta, BS, attn=AttnDispatch(use_pallas=True),
+            rec_state=rec, state_slot=slot,
+        )
+        return jnp.argmax(logits, axis=-1), kv, rec
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, kv, rec, i32((rows,)), *meta
+    ).compile()
+    # 7 recurrent layers x (kda_recurrent, kda_chunk), the one ragged
+    # kernel, 6 expert layers x (gate, up, down)
+    assert _kernel_count(compiled.as_text()) == 14 + 1 + 18
+    mem = compiled.memory_analysis()
+    # weights 10.54 GB, state 1.96 GB, pages 0.82 GB: all arguments, and
+    # the state and the pages alias their outputs
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.7e9
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9

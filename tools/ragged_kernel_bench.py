@@ -74,8 +74,18 @@ SHAPES = {
                    window=4096, diffusion_block=1),
     "verify-tp4": dict(H=8, kvH=2, lanes=128, rows=5, prefill=0, T=1024,
                        window=4096, diffusion_block=1),
+    # ling-3.0-flash-ep4-l8.chat-c128's one latent-attention layer: 32
+    # query heads over ONE cached head of 512 + 64, lane-padded to 640.
+    # Named in ``--shapes``.
+    "mla": dict(H=32, kvH=1, lanes=128, rows=1, prefill=64, T=256,
+                window=0, diffusion_block=1, D=640),
 }
 D = 128
+
+
+def width(shape: dict) -> int:
+    """The (padded) head width of a shape: ``D`` unless it names its own."""
+    return shape.get("D", D)
 
 
 def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
@@ -106,8 +116,9 @@ def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
     assert cursor <= shape["T"], (cursor, shape["T"])
     key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
     kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (shape["T"], shape["H"], D), jnp.bfloat16)
-    cshape = (num_blocks * BS, shape["kvH"], D)
+    q = jax.random.normal(
+        kq, (shape["T"], shape["H"], width(shape)), jnp.bfloat16)
+    cshape = (num_blocks * BS, shape["kvH"], width(shape))
     k = jax.random.normal(kk, cshape, jnp.bfloat16)
     v = jax.random.normal(kv, cshape, jnp.bfloat16)
     meta = tuple(
@@ -122,9 +133,9 @@ def bound_us(shape: dict, spans) -> tuple[float, float]:
     flops, nbytes = cost(
         spans,
         model=dict(num_heads=shape["H"], num_kv_heads=shape["kvH"],
-                   head_dim=D, num_layers=1,
+                   head_dim=width(shape), num_layers=1,
                    sliding_window=shape["window"]),
-        engine=dict(tp=1, cache_head_dim=D, dtype_bytes=2),
+        engine=dict(tp=1, cache_head_dim=width(shape), dtype_bytes=2),
     )
     peaks = chip_peaks()
     return (1e6 * nbytes / peaks["hbm_bytes_per_s"],
@@ -294,7 +305,7 @@ def sweep_split(name, shape, args, rng):
         lines.append(measure(
             name, "spans", lanes_only, np.full(lanes, args.fit_ctx), 0,
             args, rng, ctx=args.fit_ctx))
-    page_bytes = 2 * BS * shape["kvH"] * D * 2  # K and V
+    page_bytes = 2 * BS * shape["kvH"] * width(shape) * 2  # K and V
     emit(dict(shape=name, sweep="fit", **fit(lines),
               page_bytes=page_bytes,
               bytes_bound_us_per_page=round(
