@@ -474,6 +474,8 @@ class TpuEngine:
     ) -> AsyncIterator[dict]:
         count = 0
         last_tok_s: float | None = None
+        rid = request.id
+        trace = tracer()
         try:
             while True:
                 token, finish, lp = await out_q.get()
@@ -481,24 +483,29 @@ class TpuEngine:
                     count += 1
                     now = time.monotonic()
                     if count == 1:
-                        tracer().mark(request.id, "first_token")
+                        trace.mark(rid, "first_token")
                         # KV-ready → token-on-the-stream is the tail of
                         # the TTFT decomposition; steady-state decode is
                         # its own span from here.
-                        tracer().span_end(request.id, "decode_first")
-                        tracer().span_begin(request.id, "decode")
+                        trace.span_end(rid, "decode_first")
+                        trace.span_begin(rid, "decode")
                     else:
                         # Per-token ITL observation: the aggregate decode
                         # interval hides the tail — a single stalled gap
                         # is invisible in (finish - first)/n.
-                        tracer().observe_itl(
-                            1000.0 * (now - last_tok_s), request.id
+                        trace.observe_itl(
+                            1000.0 * (now - last_tok_s), rid, now
                         )
                     last_tok_s = now
-                    yield EngineOutput(
-                        token_ids=[token], cum_tokens=count,
-                        logprobs=[lp] if lp is not None else None,
-                    ).to_wire()
+                    # The frame as ``EngineOutput.to_wire`` spells it.
+                    frame = {
+                        "token_ids": [token], "text": None,
+                        "finish_reason": None, "cum_tokens": count,
+                        "kv_transfer_params": None,
+                    }
+                    if lp is not None:
+                        frame["logprobs"] = [lp]
+                    yield frame
                 if finish is not None:
                     if finish is FinishReason.ERROR:
                         # An engine fault reaches the consumer as an
@@ -507,7 +514,7 @@ class TpuEngine:
                         # clause ever marks the trace. Record it here or
                         # the capture shows a clean completion for a
                         # request that died.
-                        tracer().mark_if_active(request.id, "error")
+                        trace.mark_if_active(rid, "error")
                     yield EngineOutput(
                         token_ids=[], finish_reason=finish, cum_tokens=count
                     ).to_wire()

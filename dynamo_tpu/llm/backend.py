@@ -13,7 +13,6 @@ from __future__ import annotations
 from typing import Any, AsyncIterator
 
 from dynamo_tpu.llm.protocols.common import (
-    EngineOutput,
     FinishReason,
     PreprocessedRequest,
     StopConditions,
@@ -21,6 +20,10 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.llm.tokenizer import Tokenizer
 from dynamo_tpu.runtime.engine import AsyncEngine, Context
 from dynamo_tpu.runtime.pipeline import Operator
+
+
+_STOP = FinishReason.STOP.value
+_LENGTH = FinishReason.LENGTH.value
 
 
 class StopStringJail:
@@ -59,6 +62,12 @@ class StopStringJail:
 
 
 class Detokenizer(Operator):
+    """Frames in, frames out, both as the wire spells them (a dict; the
+    keys of ``EngineOutput.to_wire``): a frame is read where it lies and
+    not rebuilt as an ``EngineOutput``. One frame OUT a token, so a
+    client's stream is an event a token however many tokens the engine
+    put into a frame."""
+
     def __init__(self, tokenizer: Tokenizer) -> None:
         self.tokenizer = tokenizer
 
@@ -72,43 +81,64 @@ class Detokenizer(Operator):
             else payload
         )
         stop: StopConditions = pre.stop
-        stop_ids = set(stop.stop_token_ids)
-        decoder = self.tokenizer.decode_stream()
-        jail = StopStringJail(stop.stop)
+        # Made once a request: what each token is held against.
+        stop_ids = () if stop.ignore_eos else frozenset(stop.stop_token_ids)
+        max_tokens = stop.max_tokens
+        step = self.tokenizer.decode_stream().step
+        push = StopStringJail(stop.stop).push if any(stop.stop) else None
 
         generated = 0
         async for raw in downstream.generate(request.map(payload)):
-            out = EngineOutput.from_wire(raw) if isinstance(raw, dict) else raw
-            text_parts: list[str] = []
-            finish: FinishReason | None = out.finish_reason
-            stopped = False
-
-            for tid in out.token_ids:
+            frame = raw if type(raw) is dict else raw.to_wire()
+            toks = frame.get("token_ids")
+            if not toks:
+                # A text-native engine's delta (EchoEngineFull) or the
+                # engine's own finish frame: nothing to decode.
+                yield frame
+                if frame.get("finish_reason") is not None:
+                    request.stop_generating()
+                    return
+                continue
+            last = len(toks) - 1
+            logprobs = frame.get("logprobs")
+            for i, tid in enumerate(toks):
                 generated += 1
-                if tid in stop_ids and not stop.ignore_eos:
-                    finish = FinishReason.STOP
-                    stopped = True
-                    break
-                piece = decoder.step(tid)
-                if piece:
-                    emit, hit = jail.push(piece)
-                    if emit:
-                        text_parts.append(emit)
-                    if hit:
-                        finish = FinishReason.STOP
+                finish = frame.get("finish_reason") if i == last else None
+                # An engine's own text stands where no token gives any.
+                text = frame.get("text") if last == 0 else None
+                stopped = False
+                if tid in stop_ids:
+                    finish, stopped = _STOP, True
+                else:
+                    piece = step(tid)
+                    if piece:
+                        if push is None:
+                            text = piece
+                        else:
+                            emit, stopped = push(piece)
+                            text = emit or text
+                            if stopped:
+                                finish = _STOP
+                    if (
+                        not stopped
+                        and max_tokens is not None
+                        and generated >= max_tokens
+                    ):
                         stopped = True
-                        break
-                if stop.max_tokens is not None and generated >= stop.max_tokens:
-                    if finish is None:
-                        finish = FinishReason.LENGTH
-                    stopped = True
-                    break
-
-            # Preserve engine-supplied text when no tokens were decoded
-            # (EchoEngineFull and other text-native engines).
-            out.text = "".join(text_parts) if text_parts else out.text
-            out.finish_reason = finish
-            yield out.to_wire()
-            if stopped or finish is not None:
-                request.stop_generating()
-                break
+                        if finish is None:
+                            finish = _LENGTH
+                out = {
+                    "token_ids": toks if last == 0 else [tid],
+                    "text": text,
+                    "finish_reason": finish,
+                    "cum_tokens": frame.get("cum_tokens", 0) - (last - i),
+                    "kv_transfer_params": frame.get("kv_transfer_params"),
+                }
+                if logprobs is not None:
+                    out["logprobs"] = (
+                        logprobs if last == 0 else logprobs[i:i + 1]
+                    )
+                yield out
+                if stopped or finish is not None:
+                    request.stop_generating()
+                    return

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 
 from aiohttp import web
 
@@ -42,6 +43,7 @@ from dynamo_tpu.llm.protocols.common import (
     WorkerDiedError,
 )
 from dynamo_tpu.llm.protocols.sse import SseEvent
+from dynamo_tpu.llm.protocols.stream import ContentDelta, sse_event
 from dynamo_tpu.llm import slo
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.utils import concurrency
@@ -592,17 +594,21 @@ class HttpService:
             }
         )
         await resp.prepare(request)
+        # A token's whole cost in this frame: one rendered event, one
+        # transport write, and the two clock reads that say what they took
+        # (GIL waits included: the engine's thread shares the interpreter).
+        write = resp.write
+        metrics = self.metrics
+        events = metrics.stream_events
         try:
             async for chunk in engine.generate(ctx):
-                if isinstance(chunk, Annotated):
-                    await resp.write(chunk.to_sse().encode())
-                    continue
-                obj = (
-                    chunk.model_dump(exclude_none=True)
-                    if hasattr(chunk, "model_dump")
-                    else chunk
-                )
-                await resp.write(SseEvent.data_json(obj).encode())
+                arrived = time.monotonic()
+                if type(chunk) is ContentDelta:
+                    events["template"] += 1
+                else:
+                    events["object"] += 1
+                await write(sse_event(chunk))
+                metrics.stream_busy_s += time.monotonic() - arrived
             await resp.write(SseEvent.done().encode())
             guard.success()
         except (ConnectionResetError, asyncio.CancelledError):

@@ -25,6 +25,14 @@ class Metrics:
         # compile-stall counters; names ending in _total render as
         # counters).
         self.gauges: dict[str, float] = {}
+        # The streamed path (HttpService._stream): events written, by how
+        # they were rendered (llm/protocols/stream.py: "template" a plain
+        # text delta, "object" everything else), and the loop's wall
+        # seconds from an item's arrival there to its write's return.
+        # busy / events is what a streamed token costs the frontend's
+        # loop from the rendering down, as the served process sees it.
+        self.stream_events: dict[str, int] = {"template": 0, "object": 0}
+        self.stream_busy_s = 0.0
 
     def set_gauge(self, name: str, value: float) -> None:
         self.gauges[name] = value
@@ -76,6 +84,17 @@ class Metrics:
             lines.append(
                 f'{p}_request_duration_seconds_count{{model="{model}",endpoint="{endpoint}"}} {cum}'
             )
+        lines.append(f"# TYPE {p}_frontend_stream_events_total counter")
+        for render, count in sorted(self.stream_events.items()):
+            lines.append(
+                f'{p}_frontend_stream_events_total{{render="{render}"}} {count}'
+            )
+        lines.append(
+            f"# TYPE {p}_frontend_stream_busy_seconds_total counter"
+        )
+        lines.append(
+            f"{p}_frontend_stream_busy_seconds_total {self.stream_busy_s}"
+        )
         for name, value in sorted(self.gauges.items()):
             kind = "counter" if name.endswith("_total") else "gauge"
             lines.append(f"# TYPE {p}_{name} {kind}")

@@ -18,21 +18,18 @@ from dynamo_tpu.llm.protocols.annotated import Annotated
 from dynamo_tpu.llm.protocols.common import (
     MAX_LOGPROBS,
     DeadlineError,
-    EngineOutput,
     FinishReason,
     PreprocessedRequest,
     RequestError,
     ShedError,
 )
 from dynamo_tpu.llm.protocols.openai import (
-    ChatCompletionChunk,
     ChatCompletionRequest,
-    ChatDelta,
     CompletionRequest,
-    StreamChoice,
     Usage,
     new_request_id,
 )
+from dynamo_tpu.llm.protocols.stream import ChunkStream, ContentDelta
 from dynamo_tpu.llm.tokenizer import Tokenizer
 from dynamo_tpu.runtime.engine import AsyncEngine, Context
 from dynamo_tpu.runtime.pipeline import Operator
@@ -234,7 +231,16 @@ class OpenAIPreprocessor(Operator):
             m = ToolCallMatcher(oai.tool_choice or "auto")
             matcher = m if m.enabled else None
 
-        def tool_chunk(fallback_finish: str | None) -> ChatCompletionChunk:
+        # What is fixed for the request is made here, once
+        # (llm/protocols/stream.py). A streamed response's chunks that
+        # carry nothing but text take the template form; a chunk with
+        # anything else (the role, a finish reason, logprobs, tool calls),
+        # and every chunk of a response that is folded and not streamed,
+        # the object form.
+        stream = ChunkStream(rid, oai.model, chat=is_chat)
+        templated = bool(oai.stream)
+
+        def tool_chunk(fallback_finish: str | None):
             """Single buffered chunk: tool_calls if the text matches, else
             the whole content (used at engine finish AND stream-end flush
             so the two paths cannot diverge). With tool_choice="required"
@@ -242,26 +248,22 @@ class OpenAIPreprocessor(Operator):
             fallback."""
             text = "".join(buffered)
             calls = matcher.match(text)
-            lp = None
             if calls:
-                delta = ChatDelta(role="assistant", tool_calls=calls)
-                reason = "tool_calls"
-            else:
-                if matcher.required:
-                    raise RequestError(
-                        "tool_choice requires a tool call but the model "
-                        "produced none that matches"
-                    )
-                delta = ChatDelta(role="assistant", content=text)
-                reason = fallback_finish
-                if buffered_lp:
-                    lp = self._chat_logprobs(buffered_lp)
-            return ChatCompletionChunk(
-                id=rid,
-                model=oai.model,
-                choices=[StreamChoice(
-                    delta=delta, logprobs=lp, finish_reason=reason,
-                )],
+                return stream.chunk(
+                    role="assistant", tool_calls=calls,
+                    finish_reason="tool_calls",
+                )
+            if matcher.required:
+                raise RequestError(
+                    "tool_choice requires a tool call but the model "
+                    "produced none that matches"
+                )
+            return stream.chunk(
+                role="assistant", content=text,
+                logprobs=(
+                    self._chat_logprobs(buffered_lp) if buffered_lp else None
+                ),
+                finish_reason=fallback_finish,
             )
 
         completion_tokens = 0
@@ -271,28 +273,34 @@ class OpenAIPreprocessor(Operator):
         buffered_lp: list[dict] = []  # logprob entries held with the text
         text_offset = 0  # completions logprobs: running offset in generated text
         async for raw in downstream.generate(request.map(pre.to_wire())):
-            out = EngineOutput.from_wire(raw) if isinstance(raw, dict) else raw
-            completion_tokens += len(out.token_ids)
-            finish = out.finish_reason.value if out.finish_reason else None
-            if completion_tokens == 0 and not out.token_ids:
+            # A frame is read where it lies (the keys of
+            # ``EngineOutput.to_wire``), not rebuilt as an object.
+            frame = raw if type(raw) is dict else raw.to_wire()
+            toks = frame.get("token_ids")
+            text = frame.get("text")
+            finish = frame.get("finish_reason")
+            logprobs = frame.get("logprobs")
+            if toks:
+                completion_tokens += len(toks)
+            elif completion_tokens == 0:
                 # Shed/expired BEFORE any output: surface a typed error
                 # (HTTP 429/503/504), not an empty 200 — clients must be
                 # able to tell "retry elsewhere" from "done". Once tokens
                 # have streamed, the finish_reason rides the last chunk
                 # instead (partial output is better than a broken socket).
-                if out.finish_reason is FinishReason.SHED:
+                if finish == FinishReason.SHED.value:
                     raise ShedError(
                         "request shed under overload before execution"
                     )
-                if out.finish_reason is FinishReason.DEADLINE:
+                if finish == FinishReason.DEADLINE.value:
                     raise DeadlineError(
                         "request deadline expired before any output"
                     )
             if matcher is not None:
-                if out.text:
-                    buffered.append(out.text)
-                if out.logprobs:
-                    buffered_lp.extend(out.logprobs)
+                if text:
+                    buffered.append(text)
+                if logprobs:
+                    buffered_lp.extend(logprobs)
                 # Stream-through fast path (ADVICE r03): once the
                 # accumulated text can no longer open a tool-call JSON
                 # (not '{', '[' or a code fence), stop buffering and
@@ -307,54 +315,34 @@ class OpenAIPreprocessor(Operator):
                     and lead[0] not in "{[`"
                 ):
                     matcher = None
-                    out.text = "".join(buffered)
+                    text = "".join(buffered)
                     buffered.clear()
                     if buffered_lp:
                         # Re-attach every entry held while buffering so the
                         # flushed delta's logprobs align with its text.
-                        out.logprobs = list(buffered_lp)
+                        logprobs = list(buffered_lp)
                         buffered_lp.clear()
                 else:
                     if finish is None:
                         continue
                     yield tool_chunk(finish)
                     break
-            delta = ChatDelta(
-                role="assistant" if first else None, content=out.text
+            if templated and not first and finish is None and not logprobs:
+                yield ContentDelta(stream, text)
+                continue
+            lp = None
+            if logprobs:
+                if is_chat:
+                    lp = self._chat_logprobs(logprobs)
+                else:
+                    lp, text_offset = self._completion_logprobs(
+                        logprobs, text_offset
+                    )
+            yield stream.chunk(
+                role="assistant" if first else None, content=text,
+                logprobs=lp, finish_reason=finish,
             )
             first = False
-            if is_chat:
-                lp = (
-                    self._chat_logprobs(out.logprobs)
-                    if out.logprobs
-                    else None
-                )
-                yield ChatCompletionChunk(
-                    id=rid,
-                    model=oai.model,
-                    choices=[StreamChoice(
-                        delta=delta, logprobs=lp, finish_reason=finish,
-                    )],
-                )
-            else:
-                lp = None
-                if out.logprobs:
-                    lp, text_offset = self._completion_logprobs(
-                        out.logprobs, text_offset
-                    )
-                yield {
-                    "id": rid,
-                    "object": "text_completion",
-                    "model": oai.model,
-                    "choices": [
-                        {
-                            "index": 0,
-                            "text": out.text or "",
-                            "logprobs": lp,
-                            "finish_reason": finish,
-                        }
-                    ],
-                }
             if finish is not None:
                 break
 
@@ -362,20 +350,8 @@ class OpenAIPreprocessor(Operator):
             # Stream ended without a finish marker: flush the buffer.
             yield tool_chunk("stop")
 
-        usage = Usage(
+        yield stream.usage_chunk(Usage(
             prompt_tokens=prompt_tokens,
             completion_tokens=completion_tokens,
             total_tokens=prompt_tokens + completion_tokens,
-        )
-        if is_chat:
-            yield ChatCompletionChunk(
-                id=rid, model=oai.model, choices=[], usage=usage
-            )
-        else:
-            yield {
-                "id": rid,
-                "object": "text_completion",
-                "model": oai.model,
-                "choices": [],
-                "usage": usage.model_dump(),
-            }
+        ))

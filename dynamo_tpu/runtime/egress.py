@@ -371,20 +371,35 @@ class PushRouter:
             except Exception:  # noqa: BLE001 — a hook bug must not break routing
                 logger.exception("on_dead hook failed for %#x", instance_id)
 
-    async def generate(
+    def generate(
         self, request: Context, instance_id: int | None = None
     ) -> AsyncIterator[Any]:
+        """The picked instance's stream. A dispatch that finds its worker
+        dead marks it and, with nothing streamed yet and no instance
+        forced, picks again."""
+        return self._stream(request, instance_id, repick=True)
+
+    def direct(self, request: Context, instance_id: int) -> AsyncIterator[Any]:
+        """`instance_id`'s stream; a dead worker is marked and raised."""
+        return self._stream(request, instance_id, repick=False)
+
+    async def _open(
+        self, request: Context, instance_id: int | None, repick: bool
+    ) -> tuple[Instance, AsyncIterator[Any]]:
         from dynamo_tpu.llm.protocols.common import WorkerDiedError
 
         tried: set[int] = set()
         while True:
-            with tracer().span(request.id, "route"):
-                instance = await self._pick(
-                    request.payload, instance_id, request_id=request.id,
-                    exclude=tried or None,
-                )
+            if repick:
+                with tracer().span(request.id, "route"):
+                    instance = await self._pick(
+                        request.payload, instance_id, request_id=request.id,
+                        exclude=tried or None,
+                    )
+            else:
+                instance = await self._pick(request.payload, instance_id)
             try:
-                frames = await self._dispatch(instance, request)
+                return instance, await self._dispatch(instance, request)
             except (
                 ConnectionError, OSError,
                 asyncio.TimeoutError, TimeoutError,
@@ -395,31 +410,52 @@ class PushRouter:
                 self.mark_dead(
                     instance.instance_id, f"dispatch:{type(exc).__name__}"
                 )
+                if not repick:
+                    raise
                 tried.add(instance.instance_id)
                 if instance_id is not None or len(tried) >= MAX_DISPATCH_ATTEMPTS:
                     raise WorkerDiedError(
                         f"dispatch to {instance.instance_id:#x} failed: "
                         f"{exc}"
                     ) from exc
-                continue
-            request.annotations["worker_id"] = instance.instance_id
-            async for item in self._relay(instance, frames, request):
-                yield item
-            return
 
-    async def direct(self, request: Context, instance_id: int) -> AsyncIterator[Any]:
-        instance = await self._pick(request.payload, instance_id)
+    async def _stream(
+        self, request: Context, instance_id: int | None, repick: bool
+    ) -> AsyncIterator[Any]:
+        """The one generator between the router's caller and the
+        instance's frames: open, then relay."""
+        from dynamo_tpu.llm.protocols.common import WorkerDiedError
+
+        instance, frames = await self._open(request, instance_id, repick)
+        if repick:
+            request.annotations["worker_id"] = instance.instance_id
+        touch = tracer().touch
+        rid = request.id
         try:
-            frames = await self._dispatch(instance, request)
-        except (
-            ConnectionError, OSError, asyncio.TimeoutError, TimeoutError,
-        ) as exc:
-            self.mark_dead(
-                instance.instance_id, f"dispatch:{type(exc).__name__}"
-            )
+            async for item in frames:
+                if request.is_killed:
+                    break
+                # Each streamed frame proves the request is alive: refresh
+                # the frontend capture's TTL so a stream outliving ttl_s is
+                # not reaped (and falsely counted abandoned) mid-flight.
+                touch(rid)
+                yield item
+        except WorkerDiedError as exc:
+            # Mid-stream death: evict + poison NOW so the failover
+            # re-dispatch (and every other request) stops routing here.
+            # ONLY on transport evidence — a WorkerDiedError that crossed
+            # as an error FRAME was delivered by a live worker (a
+            # worker-local transient, e.g. a disagg pull reset): it still
+            # fails over, but evicting the reporter and pruning its radix
+            # blocks would punish the fleet for nothing.
+            if getattr(exc, "transport_dead", False):
+                self.mark_dead(instance.instance_id, "stream")
             raise
-        async for item in self._relay(instance, frames, request):
-            yield item
+        finally:
+            # A caller that leaves early (a client gone, a stop string
+            # hit) ends a local call's engine stream now, not when the
+            # collector finds the generator.
+            await frames.aclose()
 
     async def _dispatch(self, instance: Instance, request: Context):
         """Hand the request to `instance` and return its stream of
@@ -462,37 +498,6 @@ class PushRouter:
             server.unregister(stream_id)
             raise
         return _unpacked(receiver)
-
-    async def _relay(
-        self, instance: Instance, frames, request: Context
-    ) -> AsyncIterator[Any]:
-        from dynamo_tpu.llm.protocols.common import WorkerDiedError
-
-        try:
-            async for item in frames:
-                if request.is_killed:
-                    break
-                # Each streamed frame proves the request is alive: refresh
-                # the frontend capture's TTL so a stream outliving ttl_s is
-                # not reaped (and falsely counted abandoned) mid-flight.
-                tracer().touch(request.id)
-                yield item
-        except WorkerDiedError as exc:
-            # Mid-stream death: evict + poison NOW so the failover
-            # re-dispatch (and every other request) stops routing here.
-            # ONLY on transport evidence — a WorkerDiedError that crossed
-            # as an error FRAME was delivered by a live worker (a
-            # worker-local transient, e.g. a disagg pull reset): it still
-            # fails over, but evicting the reporter and pruning its radix
-            # blocks would punish the fleet for nothing.
-            if getattr(exc, "transport_dead", False):
-                self.mark_dead(instance.instance_id, "stream")
-            raise
-        finally:
-            # A caller that leaves early (a client gone, a stop string
-            # hit) ends a local call's engine stream now, not when the
-            # collector finds the generator.
-            await frames.aclose()
 
 
 async def _unpacked(receiver) -> AsyncIterator[Any]:

@@ -223,7 +223,12 @@ class FailoverEngine:
             death_from_error_frame = False
             try:
                 async for item in stream:
-                    fr = _finish_reason(item)
+                    if type(item) is dict:  # a frame as the wire spells it
+                        fr = item.get("finish_reason")
+                        toks = item.get("token_ids")
+                    else:
+                        fr = _finish_reason(item)
+                        toks = _token_ids(item)
                     if fr == FinishReason.ERROR.value:
                         # Engine fault frames end the stream NORMALLY
                         # (engine/engine.py _engine_loop) — re-typify to
@@ -239,7 +244,6 @@ class FailoverEngine:
                         )
                         death_from_error_frame = True
                         break
-                    toks = _token_ids(item)
                     if toks:
                         emitted.extend(toks)
                     if attempt and isinstance(item, dict) and (

@@ -33,7 +33,7 @@ from dynamo_tpu.runtime.transports.tcp import (
     TcpResponseSender,
     _typed_stream_error,
 )
-from dynamo_tpu.utils.logging import request_scope
+from dynamo_tpu.utils.logging import current_request_scope, request_scope
 from dynamo_tpu.utils.task import spawn_tracked
 from dynamo_tpu.utils.tracing import TraceContext, tracer
 
@@ -190,13 +190,22 @@ class ServedInstance:
         frames = self._engine.generate(
             request.linked(msgpack.unpackb(msgpack.packb(request.payload)))
         ).__aiter__()
+        scope = (rid, hop.trace_id)
+        step = frames.__anext__
         try:
             while True:
                 try:
-                    # Scoped a step, not around the loop: a generator's
-                    # frames may be resumed and closed in other contexts.
-                    with request_scope(rid, hop.trace_id):
-                        item = await frames.__anext__()
+                    # The engine's log lines carry the request's scope. A
+                    # caller that is in it already (the HTTP handler
+                    # enters it for the whole request) pays a comparison
+                    # a frame; any other is scoped a step, not around the
+                    # loop: a generator's frames may be resumed and
+                    # closed in other contexts.
+                    if current_request_scope() == scope:
+                        item = await step()
+                    else:
+                        with request_scope(rid, hop.trace_id):
+                            item = await step()
                 except StopAsyncIteration:
                     break
                 except asyncio.CancelledError:
@@ -238,11 +247,36 @@ def _killed() -> Exception:
 _PLAIN = frozenset((str, int, float, bool, bytes, type(None)))
 
 
+def _is_token_frame(item: Any) -> bool:
+    """Whether `item` is an engine's frame of one token and nothing else,
+    spelled as ``EngineOutput.to_wire`` spells it: a dict of five keys, an
+    int in a list, an int and three ``None``. A fixed number of checks,
+    whatever else an engine may yield."""
+    try:
+        toks = item["token_ids"]
+        return (
+            type(item) is dict
+            and len(item) == 5
+            and type(toks) is list
+            and len(toks) == 1
+            and type(toks[0]) is int
+            and type(item["cum_tokens"]) is int
+            and item["text"] is None
+            and item["finish_reason"] is None
+            and item["kv_transfer_params"] is None
+        )
+    except (KeyError, TypeError, IndexError):
+        return False
+
+
 def _as_wire(item: Any) -> Any:
     """`item` as ``msgpack.unpackb(msgpack.packb(item))`` would deliver
-    it. An engine's token frame — a dict of plain values and short lists
-    of them — is that already and passes as it is; anything else takes
-    the round trip, so both paths deliver equal frames by construction."""
+    it. An engine's token frame is that already and is recognised in
+    constant time; any other dict of plain values and short lists of them
+    is too and passes after a scan; anything else takes the round trip,
+    so both paths deliver equal frames by construction."""
+    if _is_token_frame(item):
+        return item
     if type(item) is dict:
         for key, val in item.items():
             kind = type(val)

@@ -546,16 +546,21 @@ class Tracer:
         with self._lock:
             self._hist_locked(name).observe(ms)
 
-    def observe_itl(self, ms: float, request_id: str | None = None) -> None:
+    def observe_itl(
+        self, ms: float, request_id: str | None = None,
+        now: float | None = None,
+    ) -> None:
         # One lock acquisition per token: the histogram observe and the
         # TTL refresh (each token proves the request is alive — keep its
         # trace out of the sweep's reach) share the critical section.
+        # `now` is the caller's own ``time.monotonic()`` reading of the
+        # token, where it has one.
         with self._lock:
             self._hist_locked("itl").observe(ms)
             if request_id is not None:
                 tr = self._active.get(request_id)
                 if tr is not None:
-                    tr.last_touch = time.monotonic()
+                    tr.last_touch = time.monotonic() if now is None else now
 
     # -- lifecycle -----------------------------------------------------------
     def finish(self, request_id: str) -> RequestTrace | None:
