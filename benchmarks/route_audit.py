@@ -11,7 +11,8 @@ kinds into the ``DYNTPU_TRACE`` capture:
                  ``_note_kv_actual``): blocks the request ACTUALLY reused,
                  split by tier (device G1 / host G2 / disk G3).
 
-This tool joins them by trace id and reports what the router's one-way
+This tool joins them by trace id (by request id where a trace was cut
+under a dispatch: ``join_report``) and reports what the router's one-way
 ``KVHitRateEvent`` never could: the predicted-vs-actual overlap-error
 distribution, how much of the error correlates with indexer staleness
 (pending events / stale metrics at score time), and the per-worker route
@@ -83,16 +84,31 @@ def _pctl(values: list[float], q: float) -> float:
 def join_report(
     routes: list[dict], actuals: list[dict], stale_pending_threshold: int = 1
 ) -> dict[str, Any]:
-    """Join predicted↔actual by trace id and compute the audit report."""
+    """Join predicted↔actual by trace id and compute the audit report.
+
+    A route whose trace holds no actual joins the actual of the SAME
+    request id that no route's trace claims: one dispatch whose trace was
+    cut between its halves. A router replica killed after it dispatched
+    finishes the request's trace; where every role shares one process, so
+    one tracer (ingress_bench.py), the worker that admits the request a
+    moment later stamps its actual with a fresh trace (on a loaded host,
+    9 of 574 routes). A route no actual answers under either key stays
+    an orphan."""
+    routed_traces = {r.get("trace") for r in routes}
     by_trace: dict[str, list[dict]] = defaultdict(list)
+    by_request: dict[str, list[dict]] = defaultdict(list)
     for a in actuals:
         if a.get("trace"):
             by_trace[a["trace"]].append(a)
+        if a.get("id") and a.get("trace") not in routed_traces:
+            by_request[a["id"]].append(a)
 
     joined: list[tuple[dict, dict]] = []
     orphan_routes: list[dict] = []
     for r in routes:
-        hits = by_trace.get(r.get("trace") or "")
+        hits = by_trace.get(r.get("trace") or "") or by_request.get(
+            r.get("id") or ""
+        )
         if hits:
             # Disagg can produce one actual per executing process; the
             # prefill-side report (the one with reuse) wins — max total.
@@ -107,7 +123,9 @@ def join_report(
         else:
             orphan_routes.append(r)
 
-    joined_traces = {r.get("trace") for r, _ in joined}
+    joined_traces = {
+        t for r, a in joined for t in (r.get("trace"), a.get("trace"))
+    }
     orphan_actuals = sum(
         1 for a in actuals if a.get("trace") and a["trace"] not in joined_traces
     )

@@ -66,8 +66,38 @@ _OUTLIVED = {
 }
 
 
+def pytest_configure(config):
+    # The order below is the schedule: xdist's `loadfile` would sort the
+    # files again by their count of tests alone.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def _starts_a_server(item) -> bool:
+    """The test starts a whole server in a process of its own: through
+    ``test_chipbench_run._run``, or as that function does."""
+    run = getattr(item.module, "_run", None)
+    return getattr(run, "__module__", None) == "test_chipbench_run" and bool(
+        {"_run", "subprocess"} & set(item.function.__code__.co_names)
+    )
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         reason = _OUTLIVED.get(item.nodeid)
         if reason:
             item.add_marker(pytest.mark.skip(reason=reason))
+    # Each file's tests together; the few, long tests that start a server
+    # first, in their file and among the files (handed out last they ran
+    # alone while every other worker sat idle; last in their file, the
+    # file xdist queues behind a worker's last two tests waited them out);
+    # then the rest by count of tests, largest first, in their order.
+    files = {}
+    for item in items:
+        files.setdefault(item.nodeid.split("::")[0], []).append(item)
+    groups = [
+        sorted(group, key=lambda item: not _starts_a_server(item))
+        for group in files.values()
+    ]
+    groups.sort(key=lambda g: (not _starts_a_server(g[0]), -len(g)))
+    items[:] = [item for group in groups for item in group]

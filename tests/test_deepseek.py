@@ -28,23 +28,12 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.moe import MoeConfig, moe_router
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.runtime.engine import Context
-from stepdrive import step_token
+from stepdrive import reference_greedy, step_token
 
 pytestmark = pytest.mark.anyio
 
 CFG = ModelConfig.tiny_mla_test()
 PARAMS = llama.init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
-
-
-def oracle_greedy(prompt: list[int], n: int) -> list[int]:
-    tokens = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = llama.reference_forward(CFG, PARAMS, jnp.asarray(tokens))
-        nxt = int(jnp.argmax(logits[-1]))
-        tokens.append(nxt)
-        out.append(nxt)
-    return out
 
 
 def test_mla_cache_geometry():
@@ -78,7 +67,7 @@ async def test_mla_engine_matches_oracle():
         tokens = []
         async for raw in engine.generate(Context(pre.to_wire())):
             tokens.extend(EngineOutput.from_wire(raw).token_ids)
-        assert tokens == oracle_greedy(prompt, 10)
+        assert tokens == reference_greedy(CFG, PARAMS, prompt, 10, length=128)
     finally:
         await engine.stop()
 
@@ -287,16 +276,6 @@ def test_quantized_mla_matches_quantized_oracle():
     assert layer1["w_uk"]["s"].shape == (H, dc)            # contract dn
     assert layer1["w_uv"]["s"].shape == (H, CFG.v_head_dim)  # contract dc
 
-    def q_oracle(prompt, n):
-        toks = list(prompt)
-        out = []
-        for _ in range(n):
-            logits = llama.reference_forward(CFG, qp, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     ecfg = EngineConfig(
         model=CFG, dtype="float32", block_size=4, num_blocks=64,
         max_num_seqs=2, max_model_len=128, quant="int8",
@@ -304,7 +283,7 @@ def test_quantized_mla_matches_quantized_oracle():
     r = ModelRunner(ecfg, params=PARAMS)
     prompt = [1, 5, 9, 2, 7]
     tok = step_token(r, prompt, [1, 2, 3, 4])
-    assert tok == q_oracle(prompt, 1)[0]
+    assert [tok] == reference_greedy(CFG, qp, prompt, 1, length=128)
 
 
 def test_hf_load_applies_rope_permutation(tmp_path):

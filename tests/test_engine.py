@@ -25,6 +25,7 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import reference_greedy
 
 pytestmark = pytest.mark.anyio
 
@@ -34,14 +35,7 @@ PARAMS = llama.init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
 
 def oracle_greedy(prompt: list[int], n: int) -> list[int]:
     """Full-recompute greedy continuation — the correctness reference."""
-    tokens = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = llama.reference_forward(CFG, PARAMS, jnp.asarray(tokens))
-        nxt = int(jnp.argmax(logits[-1]))
-        tokens.append(nxt)
-        out.append(nxt)
-    return out
+    return reference_greedy(CFG, PARAMS, prompt, n, length=128)
 
 
 def engine_config(**kw) -> EngineConfig:
@@ -297,21 +291,10 @@ async def test_moe_model_engine_matches_oracle():
     await engine.start()
     try:
         prompt = [4, 11, 7, 2, 19, 5]
-
-        def oracle(n):
-            tokens = list(prompt)
-            out = []
-            for _ in range(n):
-                logits = llama.reference_forward(
-                    moe_cfg, moe_params, jnp.asarray(tokens)
-                )
-                nxt = int(jnp.argmax(logits[-1]))
-                tokens.append(nxt)
-                out.append(nxt)
-            return out
-
         tokens, finish = await collect(engine, prompt, max_tokens=8)
-        assert tokens == oracle(8)
+        assert tokens == reference_greedy(
+            moe_cfg, moe_params, prompt, 8, length=128
+        )
         assert finish is FinishReason.LENGTH
     finally:
         await engine.stop()
@@ -545,21 +528,11 @@ async def test_qwen3_qk_norm_engine_matches_oracle():
     assert "ln_q_head" in params["layers"][0]
 
     prompt = [1, 5, 9, 2, 7]
-
-    def oracle(n):
-        toks, out = list(prompt), []
-        for _ in range(n):
-            logits = llama.reference_forward(q3cfg, params, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     engine = TpuEngine(engine_config(model=q3cfg), params=params)
     await engine.start()
     try:
         tokens, _ = await collect(engine, prompt, max_tokens=8)
-        assert tokens == oracle(8)
+        assert tokens == reference_greedy(q3cfg, params, prompt, 8, length=128)
     finally:
         await engine.stop()
 
@@ -590,26 +563,17 @@ async def test_sliding_window_engine_matches_oracle():
     prompt = [int(t) for t in
               np.random.default_rng(3).integers(1, CFG.vocab_size, 24)]
 
-    def oracle(cfg, n):
-        toks, out = list(prompt), []
-        for _ in range(n):
-            logits = llama.reference_forward(cfg, params, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     engine = TpuEngine(engine_config(model=wcfg), params=params)
     await engine.start()
     try:
         tokens, _ = await collect(engine, prompt, max_tokens=10)
-        assert tokens == oracle(wcfg, 10)
+        assert tokens == reference_greedy(wcfg, params, prompt, 10, length=128)
     finally:
         await engine.stop()
 
     # Window is live: the full-attention model diverges (ctx 24 >> 8).
-    full = oracle(dataclasses.replace(wcfg, sliding_window=0), 10)
-    assert tokens != full
+    full = dataclasses.replace(wcfg, sliding_window=0)
+    assert tokens != reference_greedy(full, params, prompt, 10, length=128)
 
 
 async def test_rolling_buffer_eviction_plateaus_and_is_exact():

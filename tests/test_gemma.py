@@ -36,6 +36,7 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import reference_greedy
 
 pytestmark = pytest.mark.anyio
 
@@ -124,15 +125,6 @@ async def test_gemma3_engine_matches_oracle():
     prompt = [int(t) for t in
               np.random.default_rng(9).integers(1, GCFG.vocab_size, 40)]
 
-    def oracle(cfg, n):
-        toks, out = list(prompt), []
-        for _ in range(n):
-            logits = llama.reference_forward(cfg, params, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     engine = TpuEngine(
         EngineConfig(
             model=GCFG, num_blocks=64, max_num_seqs=2, max_model_len=128,
@@ -146,11 +138,11 @@ async def test_gemma3_engine_matches_oracle():
         tokens = await _collect(engine, prompt, 10)
     finally:
         await engine.stop()
-    assert tokens == oracle(GCFG, 10)
+    assert tokens == reference_greedy(GCFG, params, prompt, 10, length=128)
     # The 2-pattern is live: making every layer global changes the tokens
     # (ctx 40 > window 32).
     all_global = dataclasses.replace(GCFG, sliding_window=0, window_pattern=0)
-    assert tokens != oracle(all_global, 10)
+    assert tokens != reference_greedy(all_global, params, prompt, 10, length=128)
 
 
 def test_gemma3_multimodal_sparse_text_config(tmp_path):

@@ -550,9 +550,18 @@ def test_route_audit_join_and_gates(tmp_path):
     rec.record(_route_rec("t9", overlap=4))
     rec.record(_route_rec("t1", overlap=4))
     rec.record(_actual_rec("t1", device=4))
+    # A trace cut between a dispatch's halves (the router replica died
+    # after it dispatched): the worker's actual carries a fresh trace and
+    # joins by request id; the failover's second route joins its own.
+    rec.record(_route_rec("t4", overlap=3))
+    rec.record({**_actual_rec("t4-fresh", device=3), "id": "req-t4"})
+    rec.record({**_route_rec("t5", overlap=6), "id": "req-t4"})
+    rec.record({**_actual_rec("t5", device=6), "id": "req-t4"})
     rec.close()
     routes, actuals, _planner = load_records([str(cap2)])
     report = join_report(routes, actuals)
+    assert report["joined"] == 3 and report["orphan_actuals"] == 0
+    assert report["overlap_error"]["exact"] == 3
     assert report["orphan_routes"] == 1
     assert run_asserts(report, 0.95)
     assert main([str(cap2), "--assert", "--json"]) == 1

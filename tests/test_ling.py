@@ -328,10 +328,14 @@ def test_oracle_forward_is_the_references_forward():
     the plain reference, every layer kind in it."""
     model = ModelConfig.tiny_ling_test()
     params = llama.init_params(jax.random.PRNGKey(SEED), model, jnp.float32)
-    tokens = np.arange(3, 40, dtype=np.int32)
-    got = llama.reference_forward(model, params, jnp.asarray(tokens))
-    want = reference_logits(tokens[None], np.arange(37, dtype=np.int32)[None])
-    assert check.row_errors(np.asarray(got), want[0]).max() < 2e-4
+    # (37 tokens at the one padded shape of ``follows_the_reference``: the
+    # reference compiles once for both.)
+    tokens = np.zeros((1, PAD_TO), np.int32)
+    tokens[0, :37] = np.arange(3, 40)
+    rows = np.minimum(np.arange(ROWS), 36).astype(np.int32)[None]
+    got = llama.reference_forward(model, params, jnp.asarray(tokens[0]))
+    want = reference_logits(tokens, rows)[0][:37]
+    assert check.row_errors(np.asarray(got)[:37], want).max() < 2e-4
 
 
 # -- what such a model refuses, and what it costs the others --------------

@@ -19,7 +19,7 @@ from dynamo_tpu.models.moe import (
     shard_moe_params,
 )
 from dynamo_tpu.parallel.mesh import build_mesh
-from stepdrive import step_token
+from stepdrive import reference_greedy, step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -174,16 +174,6 @@ async def test_grouped_path_engine_end_to_end():
     )
     params = llama.init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
 
-    def oracle(prompt, n):
-        toks = list(prompt)
-        out = []
-        for _ in range(n):
-            logits = llama.reference_forward(cfg, params, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     engine = TpuEngine(
         EngineConfig(
             model=cfg, dtype="float32", block_size=4, num_blocks=64,
@@ -202,7 +192,7 @@ async def test_grouped_path_engine_end_to_end():
         tokens = []
         async for raw in engine.generate(Context(pre.to_wire())):
             tokens.extend(EngineOutput.from_wire(raw).token_ids)
-        assert tokens == oracle(prompt, 8)
+        assert tokens == reference_greedy(cfg, params, prompt, 8, length=128)
     finally:
         await engine.stop()
 

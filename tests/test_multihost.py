@@ -41,24 +41,18 @@ def test_serve_tokens_match_reference_greedy():
     """The shared serve harness (one prefill dispatch for every lane, then
     one decode dispatch a step, all through unified_step) yields, in every
     lane, the no-cache reference forward's greedy continuation."""
-    import jax.numpy as jnp
-
     from dynamo_tpu.engine.runner import ModelRunner
-    from dynamo_tpu.models import llama
     from dynamo_tpu.parallel.multihost import _tiny_engine_config, serve_tokens
+    from stepdrive import reference_greedy
 
     ecfg = _tiny_engine_config()
     runner = ModelRunner(ecfg)
     prompt, lanes, steps = [1, 2, 3, 4, 5], 3, 6
     got = serve_tokens(runner, ecfg, prompt, lanes, steps)
 
-    tokens, want = list(prompt), []
-    for _ in range(steps + 1):
-        logits = llama.reference_forward(
-            ecfg.model, runner.params, jnp.asarray(tokens)
-        )
-        want.append(int(jnp.argmax(logits[-1])))
-        tokens.append(want[-1])
+    want = reference_greedy(
+        ecfg.model, runner.params, prompt, steps + 1, length=ecfg.max_model_len
+    )
     # Step-major: [first x lanes, step 1 x lanes, ...].
     assert got == [t for t in want for _ in range(lanes)]
 

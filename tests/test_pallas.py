@@ -79,6 +79,7 @@ async def test_engine_end_to_end_pallas_interpret(monkeypatch):
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.runtime.engine import Context
+    from stepdrive import reference_greedy
 
     monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
     cfg = ModelConfig.tiny_test()
@@ -108,14 +109,9 @@ async def test_engine_end_to_end_pallas_interpret(monkeypatch):
 
         results = await asyncio.gather(*[run(p) for p in prompts])
         for prompt, toks in zip(prompts, results):
-            want = []
-            tokens = list(prompt)
-            for _ in range(5):
-                logits = llama.reference_forward(cfg, params, jnp.asarray(tokens))
-                nxt = int(jnp.argmax(logits[-1]))
-                tokens.append(nxt)
-                want.append(nxt)
-            assert toks == want, prompt
+            assert toks == reference_greedy(
+                cfg, params, prompt, 5, length=64
+            ), prompt
     finally:
         await engine.stop()
 

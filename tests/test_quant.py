@@ -37,7 +37,7 @@ from dynamo_tpu.ops.quant import (
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.sharding import llama_param_specs
 from dynamo_tpu.runtime.engine import Context
-from stepdrive import step_token
+from stepdrive import reference_greedy, step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -90,14 +90,7 @@ def test_quantize_params_structure_and_specs_mirror():
 def oracle_greedy_quant(prompt: list[int], n: int) -> list[int]:
     """Greedy continuation through the QUANTIZED no-cache oracle — the
     paged int8 engine must match it exactly (same math, fp32 accum)."""
-    tokens = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = llama.reference_forward(CFG, QPARAMS, jnp.asarray(tokens))
-        nxt = int(jnp.argmax(logits[-1]))
-        tokens.append(nxt)
-        out.append(nxt)
-    return out
+    return reference_greedy(CFG, QPARAMS, prompt, n, length=128)
 
 
 async def _collect(engine, prompt, max_tokens=8):

@@ -30,6 +30,7 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import reference_greedy
 
 pytestmark = pytest.mark.anyio
 
@@ -115,16 +116,6 @@ async def test_speculative_accepts_on_cyclic_continuation():
 
 
 async def test_speculative_concurrent_lanes_match_oracle():
-    def oracle_greedy(prompt, n):
-        toks = list(prompt)
-        out = []
-        for _ in range(n):
-            logits = llama.reference_forward(CFG, PARAMS, jnp.asarray(toks))
-            nxt = int(jnp.argmax(logits[-1]))
-            toks.append(nxt)
-            out.append(nxt)
-        return out
-
     engine = TpuEngine(_cfg(), params=PARAMS)
     await engine.start()
     try:
@@ -133,7 +124,7 @@ async def test_speculative_concurrent_lanes_match_oracle():
             *[_generate(engine, p, max_tokens=16) for p in prompts]
         )
         for p, got in zip(prompts, results):
-            assert got == oracle_greedy(p, 16), p
+            assert got == reference_greedy(CFG, PARAMS, p, 16, length=128), p
     finally:
         await engine.stop()
 

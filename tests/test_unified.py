@@ -515,14 +515,12 @@ def _assert_unpacks_to(runner, buf, want, variant) -> None:
 
 
 def _greedy_after(runner, context: list[int]) -> int:
-    import jax.numpy as jnp
+    from stepdrive import reference_greedy
 
-    from dynamo_tpu.models import llama
-
-    logits = llama.reference_forward(
-        runner.cfg.model, runner.params, jnp.asarray(context)
-    )
-    return int(jnp.argmax(logits[-1]))
+    return reference_greedy(
+        runner.cfg.model, runner.params, context, 1,
+        length=runner.cfg.max_model_len,
+    )[0]
 
 
 def _mix_decode_only(runner):

@@ -47,7 +47,7 @@ from dynamo_tpu.ops.quant import (
 from dynamo_tpu.parallel.mesh import build_mesh
 from dynamo_tpu.parallel.sharding import llama_param_specs
 from dynamo_tpu.runtime.engine import Context
-from stepdrive import step_token
+from stepdrive import reference_greedy, step_token
 
 pytestmark = pytest.mark.anyio
 
@@ -144,14 +144,7 @@ def _oracle_greedy(qparams, prompt: list[int], n: int) -> list[int]:
     quantized tree — the paged unified engine must match it exactly
     (qdot is exact-contract, so site precision cannot drift between
     the oracle and the budget-ladder programs)."""
-    tokens = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = llama.reference_forward(CFG, qparams, jnp.asarray(tokens))
-        nxt = int(jnp.argmax(logits[-1]))
-        tokens.append(nxt)
-        out.append(nxt)
-    return out
+    return reference_greedy(CFG, qparams, prompt, n, length=128)
 
 
 async def _collect(engine, prompt, max_tokens=8):
