@@ -55,6 +55,18 @@ from dynamo_tpu.utils.tracing import tracer
 logger = logging.getLogger(__name__)
 
 
+def _drain_handoff(
+    batch: list[tuple[asyncio.Queue, tuple]], taken: Callable[[], None]
+) -> None:
+    """The loop's side of the hand-off (``TpuEngine._flush_outbox``): the
+    frames the engine's thread emitted, each into its stream's queue, in
+    the order they were emitted. ``taken`` is queued behind the streams
+    the puts woke, so it runs when each of them has written its frames."""
+    for out_q, item in batch:
+        out_q.put_nowait(item)
+    asyncio.get_running_loop().call_soon(taken)
+
+
 class TpuEngine:
     def __init__(
         self,
@@ -166,6 +178,19 @@ class TpuEngine:
         self.scheduler: Scheduler | None = None
 
         self._loop: asyncio.AbstractEventLoop | None = None
+        # The hand-off to the frontend's loop (docs/architecture/
+        # request_plane.md "The streamed path"): every emit appends
+        # (out_q, item) here, on the engine's thread only, and
+        # _flush_outbox hands the lot over in ONE wake-up of the loop.
+        self._outbox: list[tuple[asyncio.Queue, tuple]] = []
+        self._handoff_wakeups = 0
+        self._handoff_items = 0
+        self._handoff_noted = 0  # items at the last flight record
+        # Set by the loop when it has written the batch it was handed
+        # last: the next batch leaves behind it (_flush_outbox).
+        self._handoff_taken = threading.Event()
+        self._handoff_taken.set()
+        self._handoff_wait_s = 0.0
         self._submit_q: queue.Queue = queue.Queue()
         self._wakeup = threading.Event()
         self._stop = threading.Event()
@@ -443,20 +468,13 @@ class TpuEngine:
         s = pre.sampling
         self._validate_request(pre)
         out_q: asyncio.Queue = asyncio.Queue()
-        loop = self._loop
-        assert loop is not None
-
-        def emit(
-            token: int | None, finish: FinishReason | None, lp=None
-        ) -> None:
-            loop.call_soon_threadsafe(out_q.put_nowait, (token, finish, lp))
-
+        assert self._loop is not None
         seq = Sequence(
             request_id=request.id,
             prompt_tokens=list(pre.token_ids),
             sampling=s,
             stop=pre.stop,
-            emit=emit,
+            emit=self._emitter(out_q),
             logprobs=pre.logprobs,
             deadline=pre.deadline,
             slo_class=_request_class(pre),
@@ -468,6 +486,46 @@ class TpuEngine:
         self._wakeup.set()
         async for item in self._stream(request, seq, out_q):
             yield item
+
+    def _emitter(self, out_q: asyncio.Queue):
+        """A sequence's ``emit``: the frame joins the outbox (engine
+        thread only) and crosses to the loop at the next flush, behind
+        everything emitted before it, so a request's token and its finish
+        frame cannot pass each other."""
+
+        def emit(
+            token: int | None, finish: FinishReason | None, lp=None
+        ) -> None:
+            self._outbox.append((out_q, (token, finish, lp)))
+
+        return emit
+
+    def _flush_outbox(self) -> None:
+        """Hand what the engine's thread has emitted to the loop: ONE
+        wake-up for the lot (a retired dispatch's tokens, or whatever a
+        pass of the engine's loop emitted outside a retire), the
+        ``put_nowait``s in emission order on the loop's side."""
+        batch = self._outbox
+        if not batch:
+            return
+        taken = self._handoff_taken
+        if not taken.is_set():
+            # The loop is still writing the batch before this one: this
+            # thread is a step ahead of it, and hands the interpreter
+            # over until it has caught up. One step is in the channel at
+            # a time, so tokens queue nowhere when the two threads
+            # together need more of the interpreter than a device step
+            # lasts; where the device sets the pace the loop has long
+            # finished and nothing waits here.
+            t0 = time.monotonic()
+            while not taken.wait(0.1) and not self._stop.is_set():
+                pass
+            self._handoff_wait_s += time.monotonic() - t0
+        taken.clear()
+        self._outbox = []
+        self._handoff_wakeups += 1
+        self._handoff_items += len(batch)
+        self._loop.call_soon_threadsafe(_drain_handoff, batch, taken.set)
 
     async def _stream(
         self, request: Context, seq: Sequence, out_q: asyncio.Queue
@@ -550,18 +608,7 @@ class TpuEngine:
         concurrency.bind_thread("engine")
         try:
             while not self._stop.is_set():
-                did_work = self._step()
-                # Heartbeat: every completed loop pass (dispatch or idle
-                # poll) proves the thread is alive and not wedged inside
-                # a collective/compile — the stamp readiness() ages.
-                self._last_dispatch_mono = time.monotonic()
-                if not did_work and self._warm_tail:
-                    # Idle step: warm one deferred (tail) shape so the
-                    # long tail compiles between traffic, never under it.
-                    self._warm_one_tail()
-                    did_work = True
-                self._flush_side_channels()
-                if not did_work:
+                if not self._pass():
                     self._wakeup.wait(timeout=0.01)
                     self._wakeup.clear()
         # dynalint: allow[DT003] top-of-thread catch: records _dead, fails every queued seq loudly
@@ -602,6 +649,27 @@ class TpuEngine:
                             if not f.done()
                             else None
                         )
+            # The ERROR frames, and whatever the pass that died had
+            # emitted before it, leave on the way out.
+            self._flush_outbox()
+
+    def _pass(self) -> bool:
+        """One pass of the engine's loop; whether it did work."""
+        did_work = self._step()
+        # Heartbeat: every completed loop pass (dispatch or idle
+        # poll) proves the thread is alive and not wedged inside
+        # a collective/compile — the stamp readiness() ages.
+        self._last_dispatch_mono = time.monotonic()
+        if not did_work and self._warm_tail:
+            # Idle step: warm one deferred (tail) shape so the
+            # long tail compiles between traffic, never under it.
+            self._warm_one_tail()
+            did_work = True
+        self._flush_side_channels()
+        # What the pass emitted outside a retire (an expiry, a shed, an
+        # abort, a refused prompt) leaves now.
+        self._flush_outbox()
+        return did_work
 
     def _drain_submissions(self) -> None:
         while True:
@@ -1264,6 +1332,10 @@ class TpuEngine:
                         self._offload_prompt_blocks(seq)
                     tok = int(toks[i])
                     self._deliver(seq, tok, self._lp_at(lp_np, seq, i, tok))
+        # The dispatch's lanes are delivered: its tokens cross to the
+        # frontend's loop in one wake-up, and stream while this thread
+        # admits, composes and waits for the device in the next retire.
+        self._flush_outbox()
         for seq, *_rest in roles:
             if seq.defer_release and seq.inflight_chunks == 0:
                 seq.defer_release = False
@@ -1932,6 +2004,7 @@ class TpuEngine:
             # The runner's last dispatch IS this record's: plain records
             # are noted at issue, spec records at retire under depth 1.
             operand_transfers=getattr(self.runner, "operand_transfers", 0),
+            handoff_items=self._handoff_items - self._handoff_noted,
             inflight_depth=len(self._inflight),
             waiting=len(sched.waiting) if sched is not None else 0,
             running=len(sched.running) if sched is not None else 0,
@@ -1947,6 +2020,7 @@ class TpuEngine:
             itl_ema_ms=self.coloc.itl_ema_ms if kind == "unified" else 0.0,
             headroom_ms=self.coloc.headroom_ms if kind == "unified" else 0.0,
         )
+        self._handoff_noted = self._handoff_items
 
     def debug_steps(self, n: int | None = None) -> list[dict]:
         """The flight recorder's last ``n`` step records — the
@@ -2259,16 +2333,12 @@ class TpuEngine:
         tracer().adopt(request.id, pre.trace)
         out_q: asyncio.Queue = asyncio.Queue()
         loop = self._loop
-
-        def emit(token, finish, lp=None):
-            loop.call_soon_threadsafe(out_q.put_nowait, (token, finish, lp))
-
         seq = Sequence(
             request_id=request.id,
             prompt_tokens=list(pre.token_ids),
             sampling=pre.sampling,
             stop=pre.stop,
-            emit=emit,
+            emit=self._emitter(out_q),
             logprobs=pre.logprobs,
             deadline=pre.deadline,
             slo_class=_request_class(pre),
@@ -2763,6 +2833,13 @@ class TpuEngine:
             "deadline_exceeded_total": OVERLOAD.deadline_total,
             "abandoned_traces_total": tracer().abandoned_total,
             "flight_steps_total": self.flight.total_steps,
+            # The hand-off to the loop (_flush_outbox): items / wake-ups
+            # is the streamed frames a wake-up of the loop carries.
+            "engine_handoff_wakeups_total": self._handoff_wakeups,
+            "engine_handoff_items_total": self._handoff_items,
+            "engine_handoff_wait_seconds_total": round(
+                self._handoff_wait_s, 6
+            ),
             "kv_reused_device_blocks_total": self._reused_device_blocks,
             "kv_reused_host_blocks_total": self._reused_host_blocks,
             "kv_reused_disk_blocks_total": self._reused_disk_blocks,

@@ -74,6 +74,7 @@ class FlightRecorder:
         drafted: int = 0,
         accepted: int = 0,
         operand_transfers: int = 0,
+        handoff_items: int = 0,
         diffusion_lanes: int = 0,
         denoise_rows: int = 0,
         commit_rows: int = 0,
@@ -95,7 +96,11 @@ class FlightRecorder:
         spec counters on the metric surfaces. ``operand_transfers`` is
         the host arrays the runner handed to the device for the
         dispatch: one packed buffer (engine/runner.py operand_layout),
-        plus a replayed host feed or the multimodal rows. The four
+        plus a replayed host feed or the multimodal rows. ``handoff_items``
+        is the frames (a token or a finish each) the engine's thread
+        handed to the frontend's loop since the record before this one:
+        a plain dispatch records at its issue, so it reads the retire
+        just behind it. The four
         diffusion fields are a block-diffusion model's: lanes that fed a
         block, the rows fed in denoising passes and in commit passes
         (a block without a masked row), and the tokens the dispatch
@@ -119,6 +124,7 @@ class FlightRecorder:
             "drafted": drafted,
             "accepted": accepted,
             "operand_transfers": operand_transfers,
+            "handoff_items": handoff_items,
             "diffusion_lanes": diffusion_lanes,
             "denoise_rows": denoise_rows,
             "commit_rows": commit_rows,

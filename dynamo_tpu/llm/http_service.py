@@ -226,6 +226,9 @@ class HttpService:
                 "prefill_backlog_tokens",
                 "abandoned_traces_total",
                 "flight_steps_total",
+                "engine_handoff_wakeups_total",
+                "engine_handoff_items_total",
+                "engine_handoff_wait_seconds_total",
                 "last_dispatch_age_s",
                 "num_waiting_interactive",
                 "num_waiting_batch",

@@ -31,6 +31,12 @@ class Metrics:
         # seconds from an item's arrival there to its write's return.
         # busy / events is what a streamed token costs the frontend's
         # loop from the rendering down, as the served process sees it.
+        # What feeds that path is beside it among the gauges, from the
+        # engine's readiness: engine_handoff_items_total /
+        # engine_handoff_wakeups_total, the frames one wake-up of this
+        # loop by the engine's thread carries (a retired step's tokens),
+        # and engine_handoff_wait_seconds_total, what that thread waited
+        # for this loop to have written the step before.
         self.stream_events: dict[str, int] = {"template": 0, "object": 0}
         self.stream_busy_s = 0.0
 

@@ -5,6 +5,7 @@ All on the CPU backend with fp32 so greedy decoding is exactly reproducible.
 """
 
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -13,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.config import EngineConfig
-from dynamo_tpu.engine.engine import TpuEngine
+from dynamo_tpu.engine.engine import TpuEngine, _drain_handoff
 from dynamo_tpu.engine.kv_cache import BlockAllocator
 from dynamo_tpu.llm.protocols.common import (
     EngineOutput,
@@ -22,9 +23,11 @@ from dynamo_tpu.llm.protocols.common import (
     SamplingOptions,
     StopConditions,
 )
+from dynamo_tpu.mocker.engine import MockerConfig, MockerEngine
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.utils.deadline import Deadline
 from stepdrive import reference_greedy
 
 pytestmark = pytest.mark.anyio
@@ -624,3 +627,368 @@ async def test_rolling_buffer_eviction_plateaus_and_is_exact():
     assert max(peaks_on[len(peaks_on) // 2 :]) <= bound, (
         peaks_on, bound,
     )
+
+
+# -- the hand-off to the frontend's loop (engine.py `_flush_outbox`) --------
+# A retired dispatch's frames cross from the engine's thread to the loop in
+# ONE `call_soon_threadsafe`; whatever else emits in a pass leaves at its
+# end; a request's frames keep their order.
+
+
+class SpyLoop:
+    """The engine's view of the loop, every `call_soon_threadsafe` kept."""
+
+    def __init__(self, loop):
+        self._real = loop
+        self.calls: list[tuple] = []
+        self.hold = False  # keep the calls, run none (a loop that is busy)
+
+    def call_soon_threadsafe(self, fn, *args):
+        self.calls.append((fn, args))
+        if not self.hold:
+            return self._real.call_soon_threadsafe(fn, *args)
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    def handoffs(self) -> list[list]:
+        return [args[0] for fn, args in self.calls if fn is _drain_handoff]
+
+    def frames(self) -> dict[int, list[tuple]]:
+        """Each stream's frames as handed over: id(queue) -> [(the
+        hand-off's index, (token, finish, lp))], in order."""
+        by_stream: dict[int, list[tuple]] = {}
+        for n, batch in enumerate(self.handoffs()):
+            for out_q, item in batch:
+                by_stream.setdefault(id(out_q), []).append((n, item))
+        return by_stream
+
+
+class HandDriven(MockerEngine):
+    """The engine without its thread: the test makes the passes of
+    `_engine_loop` itself, one `one_pass()` at a time."""
+
+    def _engine_loop(self) -> None:
+        return
+
+    async def one_pass(self) -> bool:
+        # Off the loop's thread, as the engine's is: a hand-off may wait
+        # for the loop to have written the one before it.
+        return await asyncio.to_thread(self._pass)
+
+
+async def spied(engine) -> SpyLoop:
+    await engine.start()
+    engine._loop = spy = SpyLoop(engine._loop)
+    return spy
+
+
+def watch_retires(engine, spy) -> list[tuple[int, int]]:
+    """(loop calls made, frames handed over) by each retire, as they run."""
+    seen: list[tuple[int, int]] = []
+    inner = engine._process_unified_chunk
+
+    def retire(record):
+        calls, items = len(spy.calls), engine._handoff_items
+        inner(record)
+        seen.append((len(spy.calls) - calls, engine._handoff_items - items))
+
+    engine._process_unified_chunk = retire
+    return seen
+
+
+def mock_request(n=12, max_tokens=6, deadline=None, stop_token_ids=()):
+    return PreprocessedRequest(
+        token_ids=list(range(1, n + 1)),
+        sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(
+            max_tokens=max_tokens, stop_token_ids=list(stop_token_ids),
+            ignore_eos=not stop_token_ids,
+        ),
+        deadline=deadline,
+    )
+
+
+async def frames_of(engine, pre=None, ctx=None) -> list[dict]:
+    ctx = ctx or Context(pre.to_wire())
+    return [raw async for raw in engine.generate(ctx)]
+
+
+def assert_tokens_then_finish(frames, finish, n_tokens=None):
+    """One token a frame, then exactly one finish frame, the last."""
+    *toks, last = frames
+    assert all(
+        len(f["token_ids"]) == 1 and f["finish_reason"] is None for f in toks
+    )
+    assert [f["cum_tokens"] for f in toks] == list(range(1, len(toks) + 1))
+    assert last["token_ids"] == [] and last["finish_reason"] == finish.value
+    if n_tokens is not None:
+        assert len(toks) == n_tokens
+
+
+async def test_a_retire_hands_its_tokens_over_in_one_wakeup():
+    """Four streams decode side by side on the file's float32 model: a
+    retire that delivers four tokens to four streams makes ONE
+    `call_soon_threadsafe`, no call carries a single `put_nowait`, and the
+    two counters and the flight records count what crossed."""
+    engine = TpuEngine(engine_config(), params=PARAMS)
+    await engine.start()
+    try:
+        await engine.warmup()  # no compile parts the four streams
+        engine._loop = spy = SpyLoop(engine._loop)
+        retires = watch_retires(engine, spy)
+        prompts = [[1, 5, 9, 2], [3, 4, 6], [7, 8], [2, 2, 9, 1, 5]]
+        outs = await asyncio.gather(
+            *[collect(engine, p, max_tokens=8) for p in prompts]
+        )
+        for p, (tokens, finish) in zip(prompts, outs):
+            assert tokens == oracle_greedy(p, 8)
+            assert finish is FinishReason.LENGTH
+        assert all(fn is _drain_handoff for fn, _ in spy.calls)
+        assert retires and all(
+            calls == (1 if items else 0) for calls, items in retires
+        )
+        widest = max(spy.handoffs(), key=len)
+        tokens_in = [q for q, (tok, _f, _lp) in widest if tok is not None]
+        assert len(tokens_in) == len(set(map(id, tokens_in))) == 4
+        # 4 x (8 tokens + a finish frame), in far fewer wake-ups.
+        ready = engine.readiness()
+        assert ready["engine_handoff_items_total"] == 36
+        assert ready["engine_handoff_wakeups_total"] == len(spy.handoffs())
+        assert len(spy.handoffs()) <= len(retires)
+        assert sum(r["handoff_items"] for r in engine.debug_steps()) <= 36
+        assert max(r["handoff_items"] for r in engine.debug_steps()) >= 4
+    finally:
+        await engine.stop()
+
+
+@pytest.mark.parametrize("ending", ["stop", "max_model_len", "deadline"])
+async def test_a_token_and_its_finish_frame_arrive_in_order(ending):
+    """What ends a request inside `_deliver` emits the token and then the
+    finish in one pass: both leave in the same hand-off, the token first,
+    and the consumer reads tokens, then the one finish frame."""
+    cfg = engine_config(max_model_len=24 if ending == "max_model_len" else 128)
+    sim = MockerConfig(decode_time_per_step_us=2000.0)
+    if ending == "stop":
+        # The mocker's greedy stream is a pure function of the prompt:
+        # stop on its third token.
+        probe = MockerEngine(cfg, sim)
+        await probe.start()
+        try:
+            third = (await frames_of(probe, mock_request()))[2]["token_ids"]
+        finally:
+            await probe.stop()
+        pre, finish, n = mock_request(
+            max_tokens=32, stop_token_ids=third
+        ), FinishReason.STOP, 3
+    elif ending == "max_model_len":
+        pre, finish, n = (
+            mock_request(n=20, max_tokens=64), FinishReason.LENGTH, 4
+        )
+    else:
+        pre, finish, n = mock_request(
+            max_tokens=4096, deadline=Deadline.after(0.05)
+        ), FinishReason.DEADLINE, None
+    engine = MockerEngine(cfg, sim)
+    spy = await spied(engine)
+    try:
+        frames = await asyncio.wait_for(frames_of(engine, pre), 30.0)
+        assert_tokens_then_finish(frames, finish, n)
+        (handed,) = spy.frames().values()
+        (at_tok, (tok, f0, _)), (at_fin, (none, f1, _)) = handed[-2:]
+        assert at_tok == at_fin and tok is not None and f0 is None
+        assert none is None and f1 is finish
+        assert sum(f is not None for _, (_t, f, _lp) in handed) == 1
+    finally:
+        await engine.stop()
+
+
+async def test_an_abort_ends_a_stream_behind_its_tokens():
+    """A consumer that goes away: the engine's CANCELLED frame is the last
+    thing handed to that stream, behind every token, though the abort is
+    drained in the same pass as a retire that holds its next token."""
+    engine = MockerEngine(
+        engine_config(), MockerConfig(decode_time_per_step_us=2000.0)
+    )
+    spy = await spied(engine)
+    try:
+        ctx = Context(mock_request(max_tokens=4096).to_wire())
+        stream = engine.generate(ctx)
+        got = [await stream.__anext__() for _ in range(3)]
+        assert [f["token_ids"] != [] for f in got] == [True] * 3
+        await stream.aclose()  # `_stream`'s finally submits the abort
+        for _ in range(200):
+            if engine.drained:
+                break
+            await asyncio.sleep(0.01)
+        assert engine.drained
+        (handed,) = spy.frames().values()
+        *toks, (_, last) = handed
+        assert last == (None, FinishReason.CANCELLED, None)
+        assert len(toks) >= 3 and all(
+            tok is not None and f is None for _, (tok, f, _lp) in toks
+        )
+    finally:
+        await engine.stop()
+
+
+@pytest.mark.parametrize("ending", ["expiry", "shed", "abort"])
+async def test_a_finish_outside_a_retire_leaves_within_its_pass(ending):
+    """Admission is held (no warmup ran), so nothing is ever dispatched
+    and no retire flushes: a finish from the waiting queue's expiry, from
+    a shed or from the abort of a waiting request still reaches its stream
+    in the pass that emitted it."""
+    engine = HandDriven(
+        engine_config(warmup_gate="hold", max_waiting=1), MockerConfig()
+    )
+    spy = await spied(engine)
+    try:
+        deadline = Deadline.after(0.02) if ending == "expiry" else None
+        ctx = Context(mock_request(deadline=deadline).to_wire())
+        victim = asyncio.ensure_future(frames_of(engine, ctx=ctx))
+        await asyncio.sleep(0.03)  # submitted; past its deadline if it has one
+        await engine.one_pass()
+        finish = FinishReason.DEADLINE
+        if ending != "expiry":
+            assert spy.calls == [] and len(engine.scheduler.waiting) == 1
+        if ending == "shed":
+            # A second waiter over max_waiting=1 sheds the oldest.
+            other = asyncio.ensure_future(frames_of(engine, mock_request()))
+            await asyncio.sleep(0)
+            await engine.one_pass()
+            finish = FinishReason.SHED
+        elif ending == "abort":
+            ctx.stop_generating()
+            engine._submit_q.put(("abort", engine.scheduler.waiting[0]))
+            await engine.one_pass()
+            finish = FinishReason.CANCELLED
+        (batch,) = spy.handoffs()
+        assert [item for _q, item in batch] == [(None, finish, None)]
+        assert engine.flight.total_steps == 0
+        frames = await asyncio.wait_for(victim, 5.0)
+        assert [f["finish_reason"] for f in frames] == [finish.value]
+        if ending == "shed":
+            other.cancel()
+    finally:
+        await engine.stop()
+
+
+async def test_a_dead_engine_fails_running_waiting_and_queued_requests():
+    """The step raises with two requests running, two waiting and one
+    still in the submission queue: each stream ends in an ERROR frame,
+    all five in the death path's one hand-off."""
+    engine = MockerEngine(
+        engine_config(max_num_seqs=2),
+        MockerConfig(decode_time_per_step_us=1000.0),
+    )
+    spy = await spied(engine)
+    reached, go = threading.Event(), threading.Event()
+    inner, calls = engine.runner.unified_step, []
+
+    def failing_step(*a, **kw):
+        calls.append(1)
+        if len(calls) < 4:
+            return inner(*a, **kw)
+        reached.set()
+        go.wait(10.0)
+        raise RuntimeError("boom")
+
+    engine.runner.unified_step = failing_step
+    try:
+        early = [
+            asyncio.ensure_future(
+                frames_of(engine, mock_request(max_tokens=4096))
+            )
+            for _ in range(4)
+        ]
+        assert await asyncio.to_thread(reached.wait, 10.0)
+        assert len(engine.scheduler.running) == 2
+        assert len(engine.scheduler.waiting) == 2
+        late = asyncio.ensure_future(frames_of(engine, mock_request()))
+        while engine._submit_q.empty():
+            await asyncio.sleep(0)
+        go.set()
+        outs = await asyncio.wait_for(asyncio.gather(*early, late), 10.0)
+        assert [o[-1]["finish_reason"] for o in outs] == ["error"] * 5
+        assert [len(o) for o in outs[2:]] == [1, 1, 1]  # never ran
+        assert isinstance(engine._dead, RuntimeError)
+        errors = [
+            item for _q, item in spy.handoffs()[-1]
+            if item == (None, FinishReason.ERROR, None)
+        ]
+        assert len(errors) == 5
+    finally:
+        await engine.stop()
+
+
+async def test_a_step_leaves_when_the_loop_has_written_the_one_before():
+    """One step is in the channel at a time: with the loop still busy on
+    the batch it was handed, the engine's thread waits with the next one
+    (and counts the wait), then hands it over behind it, in order."""
+    engine = HandDriven(engine_config(), MockerConfig())
+    spy = await spied(engine)
+    try:
+        out_q: asyncio.Queue = asyncio.Queue()
+        emit = engine._emitter(out_q)
+        spy.hold = True
+        emit(1, None)
+        engine._flush_outbox()  # nothing before it: leaves at once
+        emit(2, None)
+        emit(None, FinishReason.LENGTH)
+        second = asyncio.ensure_future(
+            asyncio.to_thread(engine._flush_outbox)
+        )
+        await asyncio.sleep(0.05)
+        assert len(spy.calls) == 1 and not second.done()
+        spy.hold = False
+        (fn, args), = spy.calls
+        fn(*args)  # the loop gets to the first batch at last
+        await asyncio.wait_for(second, 5.0)
+        assert [len(b) for b in spy.handoffs()] == [1, 2]
+        await asyncio.sleep(0)
+        assert [out_q.get_nowait() for _ in range(3)] == [
+            (1, None, None), (2, None, None),
+            (None, FinishReason.LENGTH, None),
+        ]
+        ready = engine.readiness()
+        assert ready["engine_handoff_wait_seconds_total"] >= 0.04
+        assert ready["engine_handoff_wakeups_total"] == 2
+    finally:
+        await engine.stop()
+
+
+@pytest.mark.parametrize("family", ["block_diffusion", "draft_verify"])
+async def test_a_lane_that_yields_three_tokens_yields_three_frames(family):
+    """A block-diffusion lane that commits three tokens in a pass and a
+    draft-verify lane whose span accepts three: three items in the one
+    hand-off, three frames of one token each for the consumer."""
+    if family == "block_diffusion":
+        model = ModelConfig.tiny_sdar_test().scaled(confidence_threshold=0.02)
+        engine = TpuEngine(engine_config(
+            model=model, block_size=8, seed=3, unified_token_budget=32,
+            unified_prefill_quantum=16,
+        ))
+    else:
+        model = CFG.scaled(num_layers=0)  # greedy decoding enters a cycle
+        engine = TpuEngine(
+            engine_config(model=model, num_blocks=128, speculative_k=3),
+            params=llama.init_params(
+                jax.random.PRNGKey(0), model, dtype=jnp.float32
+            ),
+        )
+    spy = await spied(engine)
+    try:
+        pre = PreprocessedRequest(
+            token_ids=[1, 5, 9, 2, 7],
+            sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=40, ignore_eos=True),
+        )
+        frames = await frames_of(engine, pre)
+        assert_tokens_then_finish(frames, FinishReason.LENGTH, 40)
+        most = max(
+            sum(tok is not None for _q, (tok, _f, _lp) in batch)
+            for batch in spy.handoffs()
+        )
+        assert most >= 3, most
+    finally:
+        await engine.stop()
