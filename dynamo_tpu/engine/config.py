@@ -206,6 +206,33 @@ class EngineConfig:
     def max_blocks_per_seq(self) -> int:
         return (self.max_model_len + self.block_size - 1) // self.block_size
 
+    @property
+    def group_num_blocks(self) -> tuple:
+        """Blocks of each cache group's pool (``model.cache_groups``;
+        docs/architecture/cache_groups.md). ``num_blocks`` sizes the pool
+        of a model with one group, and of the full-attention group. A
+        windowed group beside others holds what its window can keep live:
+        for every sequence slot the window, the largest span a dispatch
+        writes ahead of it (``unified_token_budget``) and a block of
+        lookahead at each end, never more than ``num_blocks``: a pool cut
+        so still holds one sequence's need and the trash block, since
+        ``validate`` asks ``num_blocks`` for a whole table and one more,
+        so a span that finds it full can always preempt its way in
+        (``Scheduler.fund_span``)."""
+        groups = self.model.cache_groups
+        if len(groups) == 1:
+            return (self.num_blocks,)
+        bs = self.block_size
+
+        def windowed(w: int) -> int:
+            per_seq = -(-w // bs) + -(-self.unified_token_budget // bs) + 2
+            return min(
+                self.num_blocks,
+                self.max_num_seqs * min(per_seq, self.max_blocks_per_seq) + 1,
+            )
+
+        return tuple(windowed(w) if w else self.num_blocks for w in groups)
+
     def validate(self) -> None:
         if self.num_blocks < self.max_blocks_per_seq + 1:
             raise ValueError(
@@ -263,6 +290,40 @@ class EngineConfig:
                     "a block-diffusion model serves without speculative "
                     "drafting, the striped kv_sp cache or a sliding window"
                 )
+        if len(self.model.cache_groups) > 1:
+            # Window and full layers side by side, for a model that says
+            # ``cache_by_layer_group`` (docs/architecture/cache_groups.md;
+            # Gemma-3 and Qwen2 do not, and serve over one table with
+            # everything below as before): a sequence's past is a table a
+            # group, and
+            # the windowed group's has let go of what lies behind the
+            # window, so everything that reads ONE table as the whole past
+            # is off or refused, each by name.
+            if self.enable_prefix_caching:
+                import logging
+
+                logging.getLogger(__name__).info(
+                    "prefix caching is off for %s: a matched block of the "
+                    "full-attention group has no block of the windowed "
+                    "group behind it once the window has moved on",
+                    self.model.name,
+                )
+                self.enable_prefix_caching = False
+            refused = {
+                "speculative drafting (speculative_k): a verify span's "
+                "lookahead is not funded per group": self.speculative_k,
+                "the striped kv_sp cache": self.kv_sp,
+                "int8 KV (kv_quant): the per-block scales are one array "
+                "over one pool": self.kv_quant,
+                "block diffusion": self.model.diffusion_block_length,
+                "recurrent layers": self.model.has_recurrent,
+            }
+            for what, on in refused.items():
+                if on:
+                    raise ValueError(
+                        f"{self.model.name} keeps its cache by layer group "
+                        f"(window and full layers) and serves without {what}"
+                    )
         if self.model.has_recurrent:
             # A model with recurrent (linear-attention) layers
             # (docs/architecture/unified_step.md "State that is not

@@ -90,6 +90,18 @@ class TpuEngine:
         #: The model keeps a recurrent state beside the paged cache
         #: (docs/architecture/unified_step.md "State that is not pages").
         self._rec_on = cfg.model.has_recurrent
+        self._window_released_noted = 0
+        #: The model keeps its cache by layer group: window and full
+        #: layers in pools and tables of their own
+        #: (docs/architecture/cache_groups.md).
+        self._grouped = len(cfg.model.cache_groups) > 1
+        if self._grouped and block_manager is not None:
+            raise ValueError(
+                f"{cfg.model.name} keeps its cache by layer group and "
+                "serves without a block manager: KVBM offload and onboard "
+                "and peer parking move a block of ONE pool, and a position "
+                "here has a block in each group's"
+            )
         if self._rec_on and block_manager is not None:
             raise ValueError(
                 f"{cfg.model.name} has recurrent layers and serves "
@@ -285,7 +297,13 @@ class TpuEngine:
             on_event=self._queue_kv_event,
             num_shards=shards,
         )
-        self.scheduler = Scheduler(self.cfg, self.allocator)
+        # A further cache group (a windowed one beside the full-attention
+        # group) has a pool of its own; no prefix is matched there.
+        more = [
+            BlockAllocator(n, self.cfg.block_size, enable_prefix_caching=False)
+            for n in self.cfg.group_num_blocks[1:]
+        ]
+        self.scheduler = Scheduler(self.cfg, self.allocator, *more)
         # start() runs on the asyncio loop: bind it for the runtime
         # affinity checker (no-op unless DYNTPU_CHECK_THREADS=1).
         concurrency.bind_thread("loop")
@@ -954,7 +972,7 @@ class TpuEngine:
                         if seq.status is not SeqStatus.RUNNING:
                             continue  # the next block passes the limit
                 lanes.append((
-                    seq.blk_ids, seq.block_ids, seq.blk_start,
+                    seq.blk_ids, seq.lane_block_ids, seq.blk_start,
                     self._lane_sampling(seq),
                 ))
                 draft_lens.append(0)
@@ -970,7 +988,7 @@ class TpuEngine:
                 # in-dispatch and the accepted length comes back as a
                 # device array (processed at retire, like the tokens).
                 lanes.append((
-                    [seq.last_token] + drafts, seq.block_ids, n - 1,
+                    [seq.last_token] + drafts, seq.lane_block_ids, n - 1,
                     self._lane_sampling(seq),
                 ))
                 draft_lens.append(len(drafts))
@@ -986,13 +1004,14 @@ class TpuEngine:
             else:
                 tok = seq.last_token
             lanes.append(
-                ([tok], seq.block_ids, n - 1, self._lane_sampling(seq))
+                ([tok], seq.lane_block_ids, n - 1, self._lane_sampling(seq))
             )
             draft_lens.append(0)
             roles.append((seq, "decode", n - 1, 1, True))
             seq.inflight_chunks += 1
             seq.sched_len = n + 1
         mm_rows: list = []
+        starved = False
         for seq, n in prefill_take:
             s = len(lanes)
             start = seq.prefill_cursor
@@ -1002,9 +1021,12 @@ class TpuEngine:
                 n -= n % B_blk
                 if n <= 0:
                     continue
+            if not sched.fund_span(seq, start + n):
+                starved = True  # a windowed group's pool is full: next pass
+                continue
             toks = seq.prompt_tokens[start : start + n]
             lanes.append(
-                (toks, seq.block_ids, start, self._lane_sampling(seq))
+                (toks, seq.lane_block_ids, start, self._lane_sampling(seq))
             )
             draft_lens.append(0)
             if seq.mm_segments:
@@ -1030,6 +1052,10 @@ class TpuEngine:
                 seq.status = SeqStatus.RUNNING
                 seq.sched_len = seq.total_len + 1
 
+        if starved and not lanes:
+            # Nothing was funded: no empty dispatch; the caller retires
+            # the oldest one in flight, which releases blocks.
+            return False
         extras = None
         if has_extras:
             extras = {
@@ -1394,7 +1420,39 @@ class TpuEngine:
                 kda_prefill_rows=sum(r[3] for r in roles if r[3] > 1),
                 kda_fresh_spans=sum(r[2] == 0 for r in roles),
             )
+        if self._grouped:
+            note.update(self._group_note())
         return note
+
+    def _group_note(self) -> dict:
+        """What the cache by layer group holds as a step is noted: blocks
+        in use in the full-attention and in the windowed pools, blocks
+        released behind a window since the last note, the live bytes of
+        both, and the context tokens of the running sequences: each one's
+        WHOLE length, a prefilling one's too. The full-attention group has
+        drawn a block for every one of them at admission; a windowed group
+        funds a span at a time, so a prompt's tokens not yet prefilled are
+        counted and hold no bytes there yet."""
+        sched = self.scheduler
+        m, bs = self.cfg.model, self.cfg.block_size
+        used = [sched.blocks_in_use(g) for g in range(len(sched.windows))]
+        page = (
+            2 * bs * m.num_cache_heads * self.runner.cache_head_dim
+            * self.runner.kv_dtype.itemsize
+        )
+        released = sched.window_released
+        since, self._window_released_noted = (
+            released - self._window_released_noted, released)
+        return dict(
+            kv_full_blocks=sum(
+                n for n, w in zip(used, sched.windows) if not w),
+            kv_window_blocks=sum(n for n, w in zip(used, sched.windows) if w),
+            kv_window_released=since,
+            kv_bytes_live=page * sum(
+                n * layers for n, layers in zip(used, sched.group_layers)),
+            context_tokens_live=sum(
+                s.total_len for s in sched.running.values()),
+        )
 
     @staticmethod
     def _lp_at(lp_np, seq: Sequence, lane: int, token: int) -> dict | None:
@@ -2071,6 +2129,12 @@ class TpuEngine:
         return await self.prefill_only_batch([(pre, request_id, device)])[0]
 
     def _refuse_disagg(self) -> None:
+        if self._grouped:
+            raise RequestError(
+                f"{self.cfg.model.name} keeps its cache by layer group and "
+                "serves without remote prefill or disaggregation: a "
+                "prompt's blocks are handed over from ONE pool"
+            )
         if self._rec_on:
             raise RequestError(
                 f"{self.cfg.model.name} has recurrent layers and serves "
@@ -2883,7 +2947,8 @@ class TpuEngine:
             # Approximate reads off the asyncio thread (len() is atomic):
             # the live-load half of the admission watermark.
             d["num_requests_waiting"] = len(self.scheduler.waiting)
-            d["gpu_cache_usage_perc"] = self.allocator.usage()
+            d["gpu_cache_usage_perc"] = self.scheduler.cache_usage()
+            d.update(self.scheduler.group_gauges())
             # Engine-thread-refreshed per-class split of the waiting
             # depth (see _flush_side_channels).
             d["num_waiting_interactive"] = self._waiting_by_class.get(

@@ -84,6 +84,11 @@ class FlightRecorder:
         kda_decode_lanes: int = 0,
         kda_prefill_rows: int = 0,
         kda_fresh_spans: int = 0,
+        kv_full_blocks: int = 0,
+        kv_window_blocks: int = 0,
+        kv_window_released: int = 0,
+        kv_bytes_live: int = 0,
+        context_tokens_live: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -112,7 +117,13 @@ class FlightRecorder:
         (an expert share: models/moe.py). The three ``kda_`` fields are a
         model's with recurrent layers: the lanes whose state advanced by
         one row, the prefill rows that went through the chunk path, and
-        the spans that started from zeros."""
+        the spans that started from zeros. The five ``kv_`` /
+        ``context_`` fields are a model's that keeps its cache by layer
+        group (docs/architecture/cache_groups.md): blocks in use in the
+        full-attention and in the windowed pools as the step is noted,
+        blocks released behind a window since the record before, the live
+        bytes of both pools, and the context tokens of the running
+        sequences those bytes stand for."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -134,6 +145,11 @@ class FlightRecorder:
             "kda_decode_lanes": kda_decode_lanes,
             "kda_prefill_rows": kda_prefill_rows,
             "kda_fresh_spans": kda_fresh_spans,
+            "kv_full_blocks": kv_full_blocks,
+            "kv_window_blocks": kv_window_blocks,
+            "kv_window_released": kv_window_released,
+            "kv_bytes_live": kv_bytes_live,
+            "context_tokens_live": context_tokens_live,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

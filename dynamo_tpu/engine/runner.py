@@ -152,8 +152,14 @@ def operand_layout(
     (the penalties/logprob and multimodal programs: adds the count-buffer
     rows). ``+rec`` behind any of them (a model with recurrent layers)
     adds ``state_slot``: the slot of the state table each span owns (0,
-    the trash slot, for an idle row)."""
-    variant, _, rec = variant.partition("+")
+    the trash slot, for an idle row). ``+grpN`` (a model whose layers fall
+    into N > 1 cache groups, docs/architecture/cache_groups.md) adds a
+    ``block_tables_g`` and a ``slot_mapping_g`` segment for each group g
+    behind the first, which keeps the unnumbered pair: a segment a group,
+    not a new operand."""
+    variant, *mods = variant.split("+")
+    rec = "rec" in mods
+    groups = max([int(m[3:]) for m in mods if m.startswith("grp")] or [1])
     rows = [
         ("token_ids", (T,), np.int32, 0),
         ("token_pos", (T,), np.int32, -1),      # -1 = padding row
@@ -191,6 +197,11 @@ def operand_layout(
         assert variant == "plain", variant
     if rec:
         rows += [("state_slot", (S,), np.int32, 0)]
+    for g in range(1, groups):
+        rows += [
+            (f"block_tables_{g}", (S, max_blocks_per_seq), np.int32, 0),
+            (f"slot_mapping_{g}", (T,), np.int32, 0),
+        ]
     segs, off = {}, 0
     for name, shape, dtype, _fill in rows:
         end = off + int(np.prod(shape))
@@ -218,6 +229,19 @@ def operand_layout_of(
     lay = operand_layout((size - fixed) // per_token, *rest)
     assert lay.size == size, (size, lay.size)
     return lay
+
+
+def _meta_of(seg: dict, groups: int) -> list:
+    """``llama.unified``'s nine metadata operands in its order from a
+    layout's segments; with several cache groups ``slot_mapping`` and
+    ``block_tables`` are tuples, one entry a group."""
+    meta = [seg[name] for name in META_SEGMENTS]
+    if groups > 1:
+        for i, name in enumerate(META_SEGMENTS):
+            if name in ("slot_mapping", "block_tables"):
+                meta[i] = (seg[name],) + tuple(
+                    seg[f"{name}_{g}"] for g in range(1, groups))
+    return meta
 
 
 class _Operands(NamedTuple):
@@ -302,7 +326,9 @@ class ModelRunner(WarmupPlanMixin):
         self.kv_dtype = (
             jnp.dtype(jnp.int8) if cfg.kv_quant == "int8" else self.dtype
         )
-        num_slots = cfg.num_blocks * cfg.block_size
+        #: Blocks of each cache group's pool (one group: ``num_blocks``).
+        self.group_blocks = cfg.group_num_blocks
+        n_groups = len(self.group_blocks)
 
         # Per-runner attention path (ops/attention.py AttnDispatch): the
         # Pallas kernels need D % 128 == 0 inside the kernel, so smaller
@@ -376,15 +402,19 @@ class ModelRunner(WarmupPlanMixin):
             use_pallas=use_pallas, mesh=mesh, kv_replicated=m.is_mla,
             kv_sp=cfg.kv_sp,
         )
-        kv_shape = (num_slots, cache_heads, self.cache_head_dim)
+        def kv_shape(li: int) -> tuple:
+            slots = self.group_blocks[m.layer_cache_group(li)] * cfg.block_size
+            return (slots, cache_heads, self.cache_head_dim)
 
         def make_kv():
             # A layer that keeps a recurrent state has no pages: its entry
-            # is empty and its state lives in `rec_state`.
+            # is empty and its state lives in `rec_state`. A layer's pages
+            # are its cache group's pool: every layer's alike where the
+            # model has one group.
             return [
                 (
-                    jnp.zeros(kv_shape, self.kv_dtype),
-                    jnp.zeros(kv_shape, self.kv_dtype),
+                    jnp.zeros(kv_shape(li), self.kv_dtype),
+                    jnp.zeros(kv_shape(li), self.kv_dtype),
                 )
                 if m.layer_kind(li) == "attn" else ()
                 for li in range(m.num_layers)
@@ -629,7 +659,8 @@ class ModelRunner(WarmupPlanMixin):
 
         S_rows = self.unified_slots
         MB = cfg.max_blocks_per_seq
-        rec_sfx = "+rec" if rec_on else ""
+        rec_sfx = ("+rec" if rec_on else "") + (
+            f"+grp{n_groups}" if n_groups > 1 else "")
         #: Does the plain program hand out the expert layers' counts? Where
         #: they take the grouped path (what the block program keys on too).
         moe_counts_on = m.is_moe and m.experts_here >= GROUPED_MIN_EXPERTS
@@ -661,7 +692,7 @@ class ModelRunner(WarmupPlanMixin):
                     o["token_ids"], o["row_start"], o["use_prev"],
                     o["prev_row"], prev_toks,
                 )
-            return o, [o[name] for name in META_SEGMENTS]
+            return o, _meta_of(o, n_groups)
 
         def unified_fn(params, kv, kv_sc, packed, prev_toks):
             """One ragged mixed prefill+decode dispatch (llama.unified).
@@ -971,7 +1002,7 @@ class ModelRunner(WarmupPlanMixin):
         cfg = self.cfg
         kind, t, _lanes, _steps, _draft_k = spec
         sampling = (0.0, 0, 1.0)
-        trash = [0] * cfg.max_blocks_per_seq  # every slot -> trash block 0
+        trash = self._trash_table()
         warm_lanes = _unified_warm_lanes(
             t, self.unified_slots, cfg.max_model_len, trash, sampling
         )
@@ -1027,6 +1058,13 @@ class ModelRunner(WarmupPlanMixin):
                 jnp.int32, device=self._tok_sh,
             )
         return self._counts
+
+    def _trash_table(self):
+        """A lane's block table with every slot -> trash block 0 (one a
+        cache group where the model has several)."""
+        trash = [0] * self.cfg.max_blocks_per_seq
+        n = len(self.group_blocks)
+        return trash if n == 1 else (trash,) * n
 
     def slot_of(self, block_ids: list[int], position: int) -> int:
         bs = self.cfg.block_size
@@ -1412,12 +1450,24 @@ class ModelRunner(WarmupPlanMixin):
             seg["q_start"][:n_l] = prefix
             seg["q_len"][:n_l] = q_len
             seg["kv_len"][:n_l] = prefix + q_len
-            block_tables = seg["block_tables"]
+            # A table and the written rows' slots for each cache group; the
+            # first group's pair of segments is the unnumbered one.
+            n_groups = len(self.group_blocks)
+            tables = [seg["block_tables"]] + [
+                seg[f"block_tables_{g}"] for g in range(1, n_groups)]
+            slots = [seg["slot_mapping"]] + [
+                seg[f"slot_mapping_{g}"] for g in range(1, n_groups)]
             temp, top_k, top_p, seed = (
                 seg["temp"], seg["top_k"], seg["top_p"], seg["seed"]
             )
-            for s, (_toks, block_ids, _prefix, sampling) in enumerate(lanes):
-                block_tables[s, : len(block_ids)] = block_ids
+            # A lane's second place is its table, or one table a group:
+            # the lanes' ids, one list a group.
+            lane_ids = [block_ids for _, block_ids, _, _ in lanes]
+            ids_of = [lane_ids] if n_groups == 1 else zip(*lane_ids)
+            for table, ids_g in zip(tables, ids_of):
+                for s, ids in enumerate(ids_g):
+                    table[s, : len(ids)] = ids
+            for s, (_toks, _ids, _prefix, sampling) in enumerate(lanes):
                 temp[s], top_k[s], top_p[s], seed[s] = _norm_sampling(sampling)
             # Every token's span, position and cache slot by array
             # arithmetic over the flat batch, not a Python loop a token.
@@ -1436,9 +1486,10 @@ class ModelRunner(WarmupPlanMixin):
                 ids[masked] = cfg.model.mask_token_id
             seg["token_seq"][:total] = token_seq
             seg["token_pos"][:total] = token_pos
-            seg["slot_mapping"][:total] = (
-                block_tables[token_seq, token_pos // bs] * bs + token_pos % bs
-            )
+            for table, slot in zip(tables, slots):
+                slot[:total] = (
+                    table[token_seq, token_pos // bs] * bs + token_pos % bs
+                )
 
         prev_toks = None
         if feed is not None:
@@ -1464,7 +1515,7 @@ class ModelRunner(WarmupPlanMixin):
                 prev_toks = self._put(prev_toks)
                 feed_transfers = 1
         base_args = (self.params, self.kv_caches, self.kv_scales)
-        meta_args = tuple(seg[name] for name in META_SEGMENTS)
+        meta_args = tuple(_meta_of(seg, len(self.group_blocks)))
         return base_args, meta_args, _Operands(buf, seg, prev_toks, feed_transfers)
 
     def lower_unified_top(self):
@@ -1475,7 +1526,7 @@ class ModelRunner(WarmupPlanMixin):
         T = token_budget(cfg.unified_token_budget, cfg.unified_token_budget)
         lanes = _unified_warm_lanes(
             T, self.unified_slots, cfg.max_model_len,
-            [0] * cfg.max_blocks_per_seq, (0.0, 0, 1.0),
+            self._trash_table(), (0.0, 0, 1.0),
         )
         # The key segment stays zero: _next_key() would advance the run.
         base, _meta, ops = self._unified_operands(lanes, None, T)

@@ -117,9 +117,34 @@ def compose_unified(
 
 
 class Scheduler:
-    def __init__(self, cfg: EngineConfig, allocator: BlockAllocator) -> None:
+    def __init__(self, cfg: EngineConfig, *allocators: BlockAllocator) -> None:
+        """One allocator for each of the model's cache groups
+        (``cfg.model.cache_groups``, docs/architecture/cache_groups.md):
+        one for most models, and ``allocator`` is it."""
         self.cfg = cfg
-        self.allocator = allocator
+        self.allocators = list(allocators)
+        self.allocator = allocators[0]
+        #: each group's window in tokens (0 = the whole context)
+        self.windows = tuple(cfg.model.cache_groups)
+        assert len(self.windows) == len(self.allocators), (
+            "one pool for each of the model's cache groups"
+        )
+        #: the windowed groups as (group, window, its pool's release): what
+        #: evict_behind_window walks after every retire of every sequence
+        self._windowed = [
+            (g, w, a.release)
+            for g, (w, a) in enumerate(zip(self.windows, self.allocators)) if w
+        ]
+        self._bs = cfg.block_size
+        #: blocks released behind a window, and preemptions by the pool
+        #: that ran out (readiness() and /metrics read them)
+        self.window_released = 0
+        self.preemptions_by_group = [0] * len(self.allocators)
+        #: layers that keep keys and values in each group: a block of a
+        #: group is that many layers' pages
+        self.group_layers = [
+            cfg.model.group_layers(g) for g in range(len(self.windows))
+        ]
         self.waiting: deque[Sequence] = deque()
         self.running: dict[int, Sequence] = {}  # slot -> seq
         self._free_slots: list[int] = list(range(cfg.max_num_seqs - 1, -1, -1))
@@ -267,6 +292,14 @@ class Scheduler:
             for b in matched:
                 self.allocator.release(b)
             return False
+        # A further (windowed) group funds a span at a time (fund_span);
+        # admission asks it for room for the window, above its watermark.
+        for alloc, w in zip(self.allocators[1:], self.windows[1:]):
+            room = min(total_blocks, -(-w // bs) + 1)
+            if alloc.num_free - room < int(alloc.num_blocks * self.cfg.watermark):
+                for b in matched:
+                    self.allocator.release(b)
+                return False
 
         try:
             new_blocks = self.allocator.allocate_many(
@@ -277,7 +310,8 @@ class Scheduler:
                 self.allocator.release(b)
             return False
 
-        seq.block_ids = matched + new_blocks
+        seq.tables = [matched + new_blocks, *([] for _ in self.allocators[1:])]
+        seq.evicted = [0] * len(seq.tables)
         seq.num_cached_prefix = cached_tokens
         seq.hashes.extend(seq.prompt_tokens)
         seq.sched_len = seq.total_len
@@ -311,31 +345,52 @@ class Scheduler:
             )
 
     def evict_behind_window(self, seq: Sequence, covered: int) -> int:
-        """Rolling-buffer eviction for fully-windowed models (Mistral):
-        release blocks whose every position is behind the sliding window
-        of EVERY query this sequence can still issue (the earliest future
-        query position is ≥ `covered` − 1, so keys < covered − window are
-        dead). Entries become the 0 sentinel — windowed attention's page
-        skip starts strictly above them, so tables stay valid without
-        compaction. Registered blocks land in the allocator's REUSABLE
-        pool (their KV stays valid and hash-discoverable for prefix hits;
-        the router's radix view stays truthful — a 'removed' event fires
-        only if LRU pressure actually reclaims them). Returns the number
-        of blocks released."""
-        w = self.cfg.model.sliding_window
-        if not self.cfg.model.rolling_buffer:
-            return 0
-        upto = min(max(covered - w, 0) // self.cfg.block_size,
-                   len(seq.block_ids))
+        """Rolling-buffer eviction, per layer group: in every windowed
+        cache group release the blocks whose every position is behind the
+        sliding window of EVERY query this sequence can still issue (the
+        earliest future query position is ≥ `covered` − 1, so keys <
+        covered − window are dead). A full-attention group keeps the whole
+        history in ITS pool and releases nothing. Entries become the 0
+        sentinel — windowed attention's page skip starts strictly above
+        them, so tables stay valid without compaction. Registered blocks
+        land in the allocator's REUSABLE pool (their KV stays valid and
+        hash-discoverable for prefix hits; the router's radix view stays
+        truthful — a 'removed' event fires only if LRU pressure actually
+        reclaims them). Returns the number of blocks released."""
         n = 0
-        for i in range(seq.evicted_pages, upto):
-            b = seq.block_ids[i]
-            if b:
-                self.allocator.release(b)
-                seq.block_ids[i] = 0
-                n += 1
-        seq.evicted_pages = max(seq.evicted_pages, upto)
+        for g, w, release in self._windowed:
+            table, done = seq.tables[g], seq.evicted[g]
+            upto = min(max(covered - w, 0) // self._bs, len(table))
+            if upto <= done:
+                continue
+            for i in range(done, upto):
+                b = table[i]
+                if b:
+                    release(b)
+                    table[i] = 0
+                    n += 1
+            seq.evicted[g] = upto
+        self.window_released += n
         return n
+
+    def fund_span(self, seq: Sequence, upto: int) -> bool:
+        """Blocks in every cache group for a span that writes positions
+        below ``upto`` (a prefill quantum). The first group's were all
+        drawn at admission; a windowed group beside it draws a span's here.
+        Preempts the cheapest runnable sequence on
+        pressure, as decode growth does; False where nothing can be
+        preempted now: the span waits for a retire to release blocks."""
+        need = -(-upto // self.cfg.block_size)
+        for g, table in enumerate(seq.tables):
+            while len(table) < need:
+                try:
+                    table.append(self.allocators[g].allocate(len(table)))
+                except MemoryError:
+                    victim = self._pick_victim(exclude=seq)
+                    if victim is None:
+                        return False
+                    self._preempt(victim, pool=g)
+        return True
 
     # -- decode -------------------------------------------------------------
     def decode_batch(self, lookahead: int = 1) -> list[Sequence]:
@@ -374,22 +429,25 @@ class Scheduler:
                 else (seq.device_len - 2 + lookahead) // bs,
                 self.cfg.max_blocks_per_seq - 1,
             )
-            while needed_block >= len(seq.block_ids):
-                try:
-                    seq.block_ids.append(
-                        self.allocator.allocate(len(seq.block_ids))
-                    )
-                except MemoryError:
-                    victim = self._pick_victim(exclude=seq)
-                    if victim is not None:
-                        self._preempt(victim)
-                    elif seq.inflight_chunks == 0:
-                        self._preempt(seq)
-                        break
-                    else:
-                        # Can't preempt anything in flight — stall until the
-                        # pipeline drains and zombie blocks free up.
-                        return []
+            for g, table in enumerate(seq.tables):
+                if needed_block < len(table):
+                    continue  # the common step: no group grows
+                while (
+                    needed_block >= len(table)
+                    and seq.status is SeqStatus.RUNNING
+                ):
+                    try:
+                        table.append(self.allocators[g].allocate(len(table)))
+                    except MemoryError:
+                        victim = self._pick_victim(exclude=seq)
+                        if victim is not None:
+                            self._preempt(victim, pool=g)
+                        elif seq.inflight_chunks == 0:
+                            self._preempt(seq, pool=g)
+                        else:
+                            # Can't preempt anything in flight — stall until
+                            # the pipeline drains and zombie blocks free up.
+                            return []
             if seq.status is SeqStatus.RUNNING:
                 batch.append(seq)
         # A later iteration may have preempted an earlier batch member.
@@ -414,8 +472,10 @@ class Scheduler:
             key=lambda s: (s.slo_class == "batch", s.arrival_s),
         )
 
-    def _preempt(self, seq: Sequence) -> None:
+    def _preempt(self, seq: Sequence, pool: int = 0) -> None:
+        """``pool``: the cache group whose pool ran out."""
         logger.info("preempting %s (blocks exhausted)", seq.request_id)
+        self.preemptions_by_group[pool] += 1
         self.requeue_for_recompute(seq)
 
     def requeue_for_recompute(self, seq: Sequence) -> None:
@@ -436,7 +496,6 @@ class Scheduler:
         seq.hashes = None
         seq.num_cached_prefix = 0
         seq.sched_len = 0
-        seq.evicted_pages = 0  # re-admission refunds the whole prompt
         # A block-diffusion sequence keeps a block that still has a masked
         # row: re-admission opens it at the same position with the rows
         # it had committed. A block without one is all delivered, so it
@@ -463,14 +522,51 @@ class Scheduler:
             self._release(seq)
 
     def _release(self, seq: Sequence) -> None:
-        for b in seq.block_ids:
-            if b:  # 0 = rolling-buffer evicted page, already released
-                self.allocator.release(b)
-        seq.block_ids = []
+        for alloc, table in zip(self.allocators, seq.tables):
+            for b in table:
+                if b:  # 0 = rolling-buffer evicted page, already released
+                    alloc.release(b)
+        seq.tables, seq.evicted = [[]], [0]
         if seq.slot is not None:
             del self.running[seq.slot]
             self._free_slots.append(seq.slot)
             seq.slot = None
+
+    def blocks_in_use(self, group: int) -> int:
+        alloc = self.allocators[group]
+        return alloc.num_blocks - 1 - alloc.num_free
+
+    def cache_usage(self) -> float:
+        """The cache in use over the cache there is, one number: by bytes
+        over every group's pool (a group's block is its layers' pages);
+        the one pool's share of blocks where the model has one group."""
+        if len(self.allocators) == 1:
+            return self.allocator.usage()
+        used = sum(
+            n * self.blocks_in_use(g) for g, n in enumerate(self.group_layers)
+        )
+        have = sum(
+            n * (a.num_blocks - 1)
+            for n, a in zip(self.group_layers, self.allocators)
+        )
+        return used / max(have, 1)
+
+    def group_gauges(self) -> dict:
+        """Each pool's share in use under the kind of its layers
+        (``kv_full_usage_perc`` / ``kv_window_usage_perc``), the blocks
+        released behind a window and the preemptions by the pool that ran
+        out (``readiness()`` and ``/metrics``)."""
+        out = {
+            "kv_window_released_blocks_total": self.window_released,
+            "kv_preemptions_full_pool_total": 0,
+            "kv_preemptions_window_pool_total": 0,
+        }
+        for g, w in enumerate(self.windows):
+            kind = "window" if w else "full"
+            out[f"kv_{kind}_usage_perc"] = self.allocators[g].usage()
+            out[f"kv_preemptions_{kind}_pool_total"] += (
+                self.preemptions_by_group[g])
+        return out
 
     def waiting_prompt_tokens(self) -> int:
         """Prompt tokens queued behind admission — the waiting half of
@@ -494,11 +590,9 @@ class Scheduler:
         return {
             "request_active_slots": len(self.running),
             "request_total_slots": self.cfg.max_num_seqs,
-            "kv_active_blocks": self.allocator.num_blocks
-            - 1
-            - self.allocator.num_free,
+            "kv_active_blocks": self.blocks_in_use(0),
             "kv_total_blocks": self.allocator.num_blocks - 1,
             "num_requests_waiting": len(self.waiting),
-            "gpu_cache_usage_perc": self.allocator.usage(),
+            "gpu_cache_usage_perc": self.cache_usage(),
             "gpu_prefix_cache_hit_rate": 0.0,  # updated by the engine
         }

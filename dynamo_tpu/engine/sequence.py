@@ -39,7 +39,13 @@ class Sequence:
 
     status: SeqStatus = SeqStatus.WAITING
     output_tokens: list[int] = field(default_factory=list)
-    block_ids: list[int] = field(default_factory=list)
+    # One block table for each of the model's cache groups
+    # (docs/architecture/cache_groups.md), position-indexed; most models
+    # have one group, and ``block_ids`` is its table. Beside a
+    # full-attention group (the first: a block for every position of the
+    # context, drawn at admission) a windowed group's table grows a span
+    # at a time (Scheduler.fund_span).
+    tables: list[list[int]] = field(default_factory=lambda: [[]])
     num_cached_prefix: int = 0      # tokens covered by prefix-cache hit
     slot: int | None = None         # decode batch slot
     arrival_s: float = field(default_factory=time.monotonic)
@@ -85,12 +91,12 @@ class Sequence:
     inflight_chunks: int = 0
     sched_len: int = 0           # device-side length (total_len + issued)
     defer_release: bool = False  # finished while chunks were in flight
-    # Rolling-buffer eviction (fully-windowed models): logical pages
-    # [0, evicted_pages) were released back to the allocator; their
-    # block_ids entries hold the 0 sentinel (trash block — never
+    # Rolling-buffer eviction (a windowed cache group): logical pages
+    # [0, evicted[g]) of group g's table were released back to its
+    # allocator; their entries hold the 0 sentinel (trash block — never
     # allocated, never scanned: windowed attention's page skip starts
     # strictly above them). See Scheduler.evict_behind_window.
-    evicted_pages: int = 0
+    evicted: list[int] = field(default_factory=lambda: [0])
     # KV observatory — ACTUAL reuse split by tier, set at admission
     # (docs/architecture/observability.md): G1 prefix-cache blocks this
     # request found already on device, host-tier blocks onboarded for it,
@@ -126,6 +132,23 @@ class Sequence:
     blk_start: int = -1
     blk_ids: list[int] = field(default_factory=list)
     blk_inflight: int = 0        # block passes issued and not yet retired
+
+    @property
+    def block_ids(self) -> list[int]:
+        """The first cache group's block table: the only one of most
+        models, the full-attention layers' where a model has several."""
+        return self.tables[0]
+
+    @block_ids.setter
+    def block_ids(self, ids: list[int]) -> None:
+        self.tables[0] = ids
+
+    @property
+    def lane_block_ids(self):
+        """A lane's second place (``ModelRunner.unified_step``): the block
+        table, or a tuple of one table a cache group where the model has
+        several."""
+        return self.tables[0] if len(self.tables) == 1 else tuple(self.tables)
 
     @property
     def total_len(self) -> int:

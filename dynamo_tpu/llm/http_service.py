@@ -216,6 +216,15 @@ class HttpService:
                 "moe_grouped_rows_total",
                 "recurrent_state_slots_in_use",
                 "recurrent_state_bytes",
+                # The cache by layer group (docs/architecture/
+                # cache_groups.md): each pool's share in use, blocks
+                # released behind a window, preemptions by the pool that
+                # ran out.
+                "kv_full_usage_perc",
+                "kv_window_usage_perc",
+                "kv_window_released_blocks_total",
+                "kv_preemptions_full_pool_total",
+                "kv_preemptions_window_pool_total",
                 "batch_fill_ratio",
                 "coloc_quantum",
                 "itl_ema_ms",

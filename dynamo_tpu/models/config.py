@@ -127,6 +127,38 @@ class ModelConfig:
     # of the routed experts and of the shared expert. () = none anywhere.
     expert_swiglu_limit: tuple = ()
     shared_swiglu_limit: tuple = ()
+    # --- Command A+ family (model_type cohere2_moe; docs/architecture/
+    # cache_groups.md) --- parallel_block: ONE norm a layer, and attention
+    # and the FFN both read it: x + attn(h) + ffn(h). norm_centered: that
+    # norm (and the final one) is a LayerNorm without bias, (x - mean) /
+    # sqrt(var + eps) * w. rope_interleaved: rotary pairs are the adjacent
+    # channels (2i, 2i + 1) (HF rope_gptj), not the two halves.
+    # nope_full_layers: a full-attention layer of the window pattern takes
+    # NO rotary embedding. logit_scale multiplies the logits.
+    # shared_experts_average: the shared experts' sum is divided by
+    # n_shared_experts before it is added to the routed sum.
+    # cache_by_layer_group: the window layers and the full-attention
+    # layers keep a pool and a block table each (``cache_groups``). Off, a
+    # model that has both kinds serves over ONE pool and table as before
+    # (Gemma-3, Qwen2 with max_window_layers: the windows mask, nothing is
+    # released, and everything that reads one table as the whole past
+    # serves: prefix caching, a mesh, speculation, int8 KV, KVBM,
+    # disaggregation). embed_init_std: the embedding rows of SEEDED weights
+    # are drawn at this deviation (0 = 1 / sqrt(vocab_size), as every other
+    # family). At that default a row is a hundredth of a layer's output,
+    # the stream behind layer 0 is the attention's context mean, alike
+    # for every token, and every token picks the same few experts: which
+    # share of the rows an expert share holds is then the seed's luck
+    # (5.9-18.9 % where an eighth is due: PERF.md section 6, PR 45). At 1 a
+    # token's own row leads the stream and the choices are a token's own.
+    parallel_block: bool = False
+    norm_centered: bool = False
+    rope_interleaved: bool = False
+    nope_full_layers: bool = False
+    logit_scale: float = 1.0
+    shared_experts_average: bool = False
+    cache_by_layer_group: bool = False
+    embed_init_std: float = 0.0
 
     @property
     def is_moe(self) -> bool:
@@ -184,7 +216,9 @@ class ModelConfig:
     def layer_window(self, layer_idx: int) -> int:
         """Sliding-window size for one layer (0 = full attention): HF
         Qwen2 runs the first max_window_layers layers full-attention;
-        Gemma-3 makes every window_pattern-th layer global."""
+        Gemma-3 and Command A+ make every window_pattern-th layer global.
+        The layers of one window are one cache group (``cache_groups``):
+        they share a pool and a block table."""
         if not self.sliding_window:
             return 0
         if self.window_pattern:
@@ -196,17 +230,37 @@ class ModelConfig:
         return 0
 
     @property
-    def rolling_buffer(self) -> bool:
-        """True when EVERY layer is sliding-window attention, so KV blocks
-        wholly behind the window can be reclaimed (Mistral's rolling
-        buffer cache — reference analogue: mistral.rs rotating KV cache).
-        A single full-attention layer (Qwen2's max_window_layers > 0, or a
-        Gemma-3 global layer in the pattern) pins the whole history and
-        disables eviction."""
-        return (
-            bool(self.sliding_window)
-            and self.max_window_layers == 0
-            and self.window_pattern == 0
+    def cache_groups(self) -> tuple:
+        """The paged cache by layer group (docs/architecture/
+        cache_groups.md): each group's window, the full-attention group
+        (0) first. A group has its own pool and block table; a windowed
+        group's blocks are released behind the window as a sequence
+        advances (the rolling buffer, per layer group). One entry where
+        the attention layers are all of one kind (Mistral: its window);
+        the distinct ``layer_window`` values where they are not and the
+        model says ``cache_by_layer_group``; else ``(0,)``: one pool keeps
+        every layer's whole history."""
+        windows = {
+            self.layer_window(li) for li in range(self.num_layers)
+            if self.layer_kind(li) == "attn"
+        }
+        if len(windows) > 1 and not self.cache_by_layer_group:
+            return (0,)
+        return tuple(sorted(windows)) or (0,)
+
+    def layer_cache_group(self, layer_idx: int) -> int:
+        """Which of ``cache_groups`` a layer's keys and values live in."""
+        groups = self.cache_groups
+        if len(groups) == 1:
+            return 0
+        return groups.index(self.layer_window(layer_idx))
+
+    def group_layers(self, group: int) -> int:
+        """How many layers keep keys and values in cache group ``group``."""
+        return sum(
+            self.layer_kind(li) == "attn"
+            and self.layer_cache_group(li) == group
+            for li in range(self.num_layers)
         )
 
     @staticmethod
@@ -229,6 +283,8 @@ class ModelConfig:
         sdar = cfg.get("model_type", "").startswith("sdar")
         if cfg.get("model_type") == "bailing_hybrid":
             return ModelConfig._from_hf_bailing_hybrid(cfg)
+        if cfg.get("model_type") == "cohere2_moe":
+            return ModelConfig._from_hf_cohere2_moe(cfg)
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -336,6 +392,81 @@ class ModelConfig:
                 cfg.get("expert_swiglu_limit_list") or ()),
             shared_swiglu_limit=tuple(
                 cfg.get("share_expert_swiglu_limit_list") or ()),
+        )
+
+    @staticmethod
+    def _from_hf_cohere2_moe(cfg: dict) -> "ModelConfig":
+        """HF ``cohere2_moe`` config.json (Command A+) -> ModelConfig: a
+        parallel attention + FFN block under one bias-free LayerNorm,
+        ``layer_switch - 1`` sliding-window layers with interleaved rotary
+        pairs to one full-attention layer without rotary embedding,
+        sigmoid-selected experts (no groups, no bias) beside averaged
+        shared experts, tied embeddings under ``logit_scale``. The vision
+        tower is not part of this config and is not served."""
+        period = int(cfg.get("layer_switch") or 0)
+        n = cfg["num_hidden_layers"]
+        want = [
+            "full_attention" if period and (li + 1) % period == 0
+            else "sliding_attention" for li in range(n)
+        ]
+        if list(cfg.get("layer_types") or want) != want:
+            raise NotImplementedError(
+                "cohere2_moe: layer_types is not (layer_switch - 1) "
+                f"sliding_attention layers to one full_attention layer "
+                f"(layer_switch={period}); only that pattern is implemented"
+            )
+        unserved = {
+            "first_k_dense_replace (leading dense layers)":
+                cfg.get("first_k_dense_replace"),
+            "use_qk_norm": cfg.get("use_qk_norm"),
+            "attention_bias": cfg.get("attention_bias"),
+            "a partial rotary_pct": cfg.get("rotary_pct", 1) != 1,
+            "an ungated activation": not cfg.get("use_gated_activation", True),
+            "a block that is not parallel": not cfg.get(
+                "use_parallel_block", True),
+            "untied embeddings": not cfg.get("tie_word_embeddings", True),
+            "softmax expert selection":
+                cfg.get("expert_selection_fn", "sigmoid") != "sigmoid",
+            "shared experts that are not averaged": cfg.get(
+                "shared_expert_combination_strategy", "average") != "average",
+            "rotary pairs that are not interleaved": cfg.get(
+                "position_embedding_type", "rope_gptj") != "rope_gptj",
+        }
+        for what, on in unserved.items():
+            if on:
+                raise NotImplementedError(
+                    f"cohere2_moe with {what} is not implemented")
+        return ModelConfig(
+            name=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=n,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg["head_dim"],
+            rope_theta=cfg.get("rope_theta", 50000.0),
+            rms_eps=cfg.get("layer_norm_eps", 1e-5),
+            max_position=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=True,
+            sliding_window=int(cfg.get("sliding_window") or 0),
+            window_pattern=period,
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            n_shared_experts=cfg.get("num_shared_experts", 0) or 0,
+            # config.json has no key of its own for one expert's width:
+            # intermediate_size is read as it (the catalog's inference).
+            moe_intermediate_size=cfg["intermediate_size"],
+            gating="sigmoid",
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            parallel_block=True,
+            norm_centered=True,
+            rope_interleaved=True,
+            nope_full_layers=True,
+            logit_scale=float(cfg.get("logit_scale", 1.0)),
+            shared_experts_average=True,
+            cache_by_layer_group=True,
+            embed_init_std=1.0,
         )
 
     @staticmethod
@@ -804,6 +935,97 @@ class ModelConfig:
         )
 
     @staticmethod
+    def command_a_plus() -> "ModelConfig":
+        """Command A+ 05-2026 (HF CohereLabs/command-a-plus-05-2026
+        config.json, model_type cohere2_moe; 218B-A25B): 32 parallel-block
+        layers, three 4,096-token sliding-window layers (interleaved
+        rotary pairs) to one full-attention layer without rotary
+        embedding, 128 query heads over 8 cached heads, 128 sigmoid-
+        selected experts of width 4096, 8 a token, beside four shared
+        experts averaged. The vision tower is not served."""
+        return ModelConfig(
+            name="command-a-plus",
+            vocab_size=262144,
+            hidden_size=4096,
+            intermediate_size=4096,
+            num_layers=32,
+            num_heads=128,
+            num_kv_heads=8,
+            head_dim=128,
+            rope_theta=50000.0,
+            rms_eps=1e-5,
+            max_position=200000,
+            tie_word_embeddings=True,
+            sliding_window=4096,
+            window_pattern=4,
+            num_experts=128,
+            num_experts_per_tok=8,
+            n_shared_experts=4,
+            moe_intermediate_size=4096,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            parallel_block=True,
+            norm_centered=True,
+            rope_interleaved=True,
+            nope_full_layers=True,
+            logit_scale=1.0,
+            shared_experts_average=True,
+            cache_by_layer_group=True,
+            embed_init_std=1.0,
+        )
+
+    @staticmethod
+    def command_a_plus_ep8_l4() -> "ModelConfig":
+        """One chip's share of an 8-way expert-parallel Command A+, the
+        first four layers (one whole period: layers 0-2 window, layer 3
+        full): experts 0-15 of each layer, rows 0-32,767 of the
+        vocabulary."""
+        return ModelConfig.command_a_plus().scaled(
+            name="command-a-plus-ep8-l4", num_layers=4,
+            num_experts_held=16, vocab_size=32768,
+        )
+
+    @staticmethod
+    def tiny_command_a_test(
+        vocab_size: int = 384, held: int = 0
+    ) -> "ModelConfig":
+        """Hermetic Command-A+-style test model: 8 parallel-block layers
+        in the real pattern (layers 3 and 7 full without rotary, the rest
+        a 32-token window), 4 queries a cached head, 16 experts of which
+        ``held`` (0 = all) live here, 2 shared experts averaged."""
+        return ModelConfig(
+            name="tiny-command-a-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=32,
+            num_layers=8,
+            num_heads=8,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=50000.0,
+            rms_eps=1e-5,
+            max_position=1024,
+            tie_word_embeddings=True,
+            sliding_window=32,
+            window_pattern=4,
+            num_experts=16,
+            num_experts_per_tok=4,
+            n_shared_experts=2,
+            moe_intermediate_size=32,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            num_experts_held=held,
+            parallel_block=True,
+            norm_centered=True,
+            rope_interleaved=True,
+            nope_full_layers=True,
+            logit_scale=0.5,
+            shared_experts_average=True,
+            cache_by_layer_group=True,
+            embed_init_std=1.0,
+        )
+
+    @staticmethod
     def llama3_8b() -> "ModelConfig":
         return ModelConfig(
             name="llama3-8b",
@@ -931,4 +1153,7 @@ PRESETS = {
     "ling-3.0-flash": ModelConfig.ling_30_flash,
     "ling-3.0-flash-ep4-l8": ModelConfig.ling_30_flash_ep4_l8,
     "tiny-ling-test": ModelConfig.tiny_ling_test,
+    "command-a-plus": ModelConfig.command_a_plus,
+    "command-a-plus-ep8-l4": ModelConfig.command_a_plus_ep8_l4,
+    "tiny-command-a-test": ModelConfig.tiny_command_a_test,
 }

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import AttnDispatch, full_causal_attention
-from dynamo_tpu.ops.norms import rms_norm
+from dynamo_tpu.ops.norms import layer_norm, rms_norm
 from dynamo_tpu.ops.quant import (
     CONTRACT_AXIS,
     QUANT_AXES,
@@ -115,20 +115,39 @@ def _dense_init(key, shape, dtype):
 
 
 def _ln(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """RMSNorm with the family's scale convention: Gemma checkpoints store
-    w and scale by (1 + w) (HF Gemma3RMSNorm), everyone else scales by w."""
+    """The family's norm: RMSNorm with its scale convention (Gemma
+    checkpoints store w and scale by (1 + w), HF Gemma3RMSNorm; everyone
+    else scales by w), or Cohere's mean-centred LayerNorm without bias."""
+    if cfg.norm_centered:
+        return layer_norm(x, w, cfg.rms_eps)
     if cfg.norm_offset:
         w = 1.0 + w.astype(jnp.float32)
     return rms_norm(x, w, cfg.rms_eps)
 
 
-def _layer_rope(cfg: ModelConfig, li: int) -> tuple:
-    """(theta, scaling) for layer li: Gemma-3 runs its windowed (local)
-    layers on rope_local_theta with NO position scaling; global layers
-    keep rope_theta + rope_scaling (HF Gemma3 rope_local_base_freq)."""
+def _layer_rope(cfg: ModelConfig, li: int):
+    """(theta, scaling) for layer li, or "none": Gemma-3 runs its windowed
+    (local) layers on rope_local_theta with NO position scaling; global
+    layers keep rope_theta + rope_scaling (HF Gemma3 rope_local_base_freq).
+    A full-attention layer of a model with ``nope_full_layers`` (Command
+    A+) takes no rotary embedding at all."""
+    if cfg.nope_full_layers and not cfg.layer_window(li):
+        return "none"
     if cfg.rope_local_theta and cfg.layer_window(li):
         return cfg.rope_local_theta, None
     return cfg.rope_theta, cfg.rope_scaling
+
+
+def _rope_qk(cfg: ModelConfig, li: int, q, k, positions):
+    """q and k under layer li's rotary embedding (``_layer_rope``)."""
+    rope = _layer_rope(cfg, li)
+    if rope == "none":
+        return q, k
+    th, sc = rope
+    return (
+        apply_rope(q, positions, th, sc, cfg.rope_interleaved),
+        apply_rope(k, positions, th, sc, cfg.rope_interleaved),
+    )
 
 
 def _embed(params: Params, cfg: ModelConfig, token_ids: jnp.ndarray) -> jnp.ndarray:
@@ -149,11 +168,15 @@ def _residual_attn(x, layer, attn_out, cfg: ModelConfig):
 
 
 def _residual_mlp(
-    x, layer, cfg: ModelConfig, mesh=None, li: int = 0, valid=None
+    x, layer, cfg: ModelConfig, mesh=None, li: int = 0, valid=None, h=None
 ):
     """Pre-norm → gated MLP → (optional post-norm) → residual add.
-    ``valid`` marks the rows that hold a token (an expert share's)."""
-    h = _ln(x, layer["ln_mlp"], cfg)
+    ``valid`` marks the rows that hold a token (an expert share's). A
+    parallel block (``cfg.parallel_block``) hands in ``h``, the layer's
+    ONE normed input that its attention read too: the FFN reads it beside
+    the attention, not behind it, and the layer has no second norm."""
+    if h is None:
+        h = _ln(x, layer["ln_mlp"], cfg)
     m = _mlp(layer, h, cfg, mesh, li, valid)
     if cfg.post_norms:
         m = _ln(m, layer["ln_post_mlp"], cfg)
@@ -212,8 +235,9 @@ def init_layer_params(
             "wv": dense(next(keys), (D, kvH * hd)),
             "wo": dense(next(keys), (H * hd, D)),
             "ln_attn": norm_init((D,)),
-            "ln_mlp": norm_init((D,)),
         }
+        if not cfg.parallel_block:  # a parallel block has ONE norm
+            layer["ln_mlp"] = norm_init((D,))
         if cfg.post_norms:
             layer["ln_post_attn"] = norm_init((D,))
             layer["ln_post_mlp"] = norm_init((D,))
@@ -278,6 +302,17 @@ def _init_kda_mixer(keys, cfg: ModelConfig, dtype) -> Params:
     return layer
 
 
+def _embed_init(key, cfg: ModelConfig, dtype):
+    """The embedding rows: 1/sqrt(vocab_size) like every dense matrix, or
+    the deviation the model states (``ModelConfig.embed_init_std``)."""
+    shape = (cfg.vocab_size, cfg.hidden_size)
+    if not cfg.embed_init_std:
+        return _dense_init(key, shape, dtype)
+    return (
+        jax.random.normal(key, shape, jnp.float32) * cfg.embed_init_std
+    ).astype(dtype)
+
+
 def init_params(
     key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16
 ) -> Params:
@@ -285,7 +320,7 @@ def init_params(
     lk, ek, hk = jax.random.split(key, 3)
     layer_keys = jax.random.split(lk, cfg.num_layers)
     params: Params = {
-        "embed": _dense_init(ek, (cfg.vocab_size, cfg.hidden_size), dtype),
+        "embed": _embed_init(ek, cfg, dtype),
         "layers": [
             init_layer_params(layer_keys[li], cfg, li, dtype)
             for li in range(cfg.num_layers)
@@ -455,10 +490,15 @@ def _moe_mlp(
     with jax.named_scope("expert_layer"):
         out = moe_mlp(layer, flat, mcfg, mesh=mesh, valid=valid)
         if "w_shared_gate" in layer:
-            out = out + _swiglu(
-                layer, flat, prefix="w_shared_",
-                limit=cfg.swiglu_limit(li, shared=True),
-            )
+            with jax.named_scope("shared_experts"):
+                shared = _swiglu(
+                    layer, flat, prefix="w_shared_",
+                    limit=cfg.swiglu_limit(li, shared=True),
+                )
+            if cfg.shared_experts_average:
+                # The stacked shared experts' product IS their sum.
+                shared = shared / cfg.n_shared_experts
+            out = out + shared
     return out.reshape(*lead, cfg.hidden_size)
 
 
@@ -518,8 +558,10 @@ def _to_cache(vals: jnp.ndarray, cache: jnp.ndarray) -> jnp.ndarray:
 def _logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     h = _ln(h, params["ln_f"], cfg)
     if cfg.tie_word_embeddings:
-        return tied_head_mm(h, params["embed"]).astype(jnp.float32)
-    return qdot(h, params["lm_head"]).astype(jnp.float32)
+        logits = tied_head_mm(h, params["embed"]).astype(jnp.float32)
+    else:
+        logits = qdot(h, params["lm_head"]).astype(jnp.float32)
+    return logits * cfg.logit_scale if cfg.logit_scale != 1.0 else logits
 
 
 def unified(
@@ -530,7 +572,7 @@ def unified(
     token_pos: jnp.ndarray,     # [T] global position per token (-1 = pad)
     slot_mapping: jnp.ndarray,  # [T] cache slots (trash slots for padding)
     token_seq: jnp.ndarray,     # [T] owning metadata row per token
-    block_tables: jnp.ndarray,  # [S, max_blocks]
+    block_tables: jnp.ndarray,  # [S, max_blocks] (a tuple: one a cache group)
     q_start: jnp.ndarray,       # [S] span prefix length
     q_len: jnp.ndarray,         # [S] span rows (0 = idle row)
     kv_len: jnp.ndarray,        # [S] context after this step
@@ -577,6 +619,12 @@ def unified(
     ``draft_len`` is the bonus position; spans with fewer rows repeat
     their last row (masked by the caller's acceptance law).
 
+    A model whose layers fall into more than one cache group
+    (``cfg.cache_groups``: window and full layers side by side) takes
+    ``slot_mapping`` and ``block_tables`` as tuples, one a group, and each
+    layer writes and reads through its own group's
+    (docs/architecture/cache_groups.md).
+
     A model with linear-attention layers (``cfg.layer_kind``) takes
     ``rec_state``, one (state, convolution tail) pair for each of them in
     order, and ``state_slot``, and returns the new ``rec_state`` as its
@@ -608,6 +656,10 @@ def unified(
     # An expert share drops the budget's padding rows with the rows routed
     # elsewhere; a model whose experts are all here computes every row.
     valid = token_pos >= 0 if cfg.num_experts_held else None
+    if isinstance(block_tables, (tuple, list)):
+        slots_of, tables_of = slot_mapping, block_tables
+    else:  # one group, handed bare
+        slots_of, tables_of = (slot_mapping,), (block_tables,)
     for li, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
         h = _ln(x, layer["ln_attn"], cfg)
         if cfg.layer_kind(li) == "kda":
@@ -622,14 +674,14 @@ def unified(
             x = _residual_mlp(x + y, layer, cfg, mesh, li, valid)
             continue
         k_cache, v_cache = cache
+        g = cfg.layer_cache_group(li)
+        slot_mapping, block_tables = slots_of[g], tables_of[g]
         if cfg.is_mla:
             with jax.named_scope("latent_mixer"):
                 q, k, v = _qkv_mla(layer, h, cfg, positions)
         else:
             q, k, v = _qkv(layer, h, cfg)
-            th, sc = _layer_rope(cfg, li)
-            q = apply_rope(q, positions, th, sc)
-            k = apply_rope(k, positions, th, sc)
+            q, k = _rope_qk(cfg, li, q, k, positions)
         if kv_scales is not None:
             pad = k_cache.shape[-1] - k.shape[-1]
             if pad:  # lane-padded cache (Pallas head-dim contract)
@@ -647,11 +699,19 @@ def unified(
             k_cache = k_cache.at[slot_mapping].set(_to_cache(k, k_cache))
             v_cache = v_cache.at[slot_mapping].set(_to_cache(v, v_cache))
             scale_kw = {}
-        attn_out = ragged_fn(
-            q, k_cache, v_cache, block_tables, token_seq, token_pos,
-            q_start, q_len, kv_len, row_start, block_size,
-            window=cfg.layer_window(li), **scale_kw, **block_kw,
-        )
+        window = cfg.layer_window(li)
+        with jax.named_scope("attn_window" if window else "attn_full"):
+            attn_out = ragged_fn(
+                q, k_cache, v_cache, block_tables, token_seq, token_pos,
+                q_start, q_len, kv_len, row_start, block_size,
+                window=window, **scale_kw, **block_kw,
+            )
+        new_caches.append((k_cache, v_cache))
+        if cfg.parallel_block:
+            # x + attention(h) + ffn(h): both branches read the ONE norm.
+            a = qdot(attn_out.reshape(T, -1), layer["wo"])
+            x = _residual_mlp(x, layer, cfg, mesh, li, valid, h=h) + a
+            continue
         if cfg.is_mla:
             with jax.named_scope("latent_mixer"):
                 x = x + _mla_out(layer, attn_out, cfg)
@@ -660,7 +720,6 @@ def unified(
                 x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg
             )
         x = _residual_mlp(x, layer, cfg, mesh, li, valid)
-        new_caches.append((k_cache, v_cache))
 
     if verify_rows == 1:
         last = jnp.clip(row_start + q_len - 1, 0, T - 1)  # [S]
@@ -730,14 +789,16 @@ def hidden_states(
             x = x + _mla_out(layer, attn, cfg)
         else:
             q, k, v = _qkv(layer, h, cfg)
-            th, sc = _layer_rope(cfg, li)
-            q = apply_rope(q, positions, th, sc)
-            k = apply_rope(k, positions, th, sc)
+            q, k = _rope_qk(cfg, li, q, k, positions)
             attn = full_causal_attention(
                 q, k, v, window=cfg.layer_window(li),
                 diffusion_block=max(cfg.diffusion_block_length, 1),
             )
-            x = _residual_attn(x, layer, qdot(attn.reshape(T, -1), layer["wo"]), cfg)
+            a = qdot(attn.reshape(T, -1), layer["wo"])
+            if cfg.parallel_block:
+                x = _residual_mlp(x, layer, cfg, li=li, h=h) + a
+                continue
+            x = _residual_attn(x, layer, a, cfg)
         x = _residual_mlp(x, layer, cfg, li=li)
     return x
 

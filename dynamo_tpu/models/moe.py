@@ -246,6 +246,22 @@ def _interpret() -> bool:
 #: rows a tile of the grouped matmul kernel: a group of fewer rows still
 #: reads its expert's whole matrix once, which is what bounds decode.
 GMM_ROWS = 128
+#: the most one tile of an expert's matrix may hold (the kernel keeps two
+#: in flight in VMEM): an expert's whole [K, N] where it is under this
+#: (SDAR's 2048 x 768 and Ling's 2560 x 768 in bf16 are), else its
+#: columns in tiles (Command A+'s 4096 x 4096: eight of 512; whole, the
+#: chip's compiler refuses the kernel for 68 MB of VMEM).
+GMM_TILE_BYTES = 4 * 1024 * 1024
+
+
+def gmm_tile(K: int, N: int, itemsize: int) -> tuple[int, int]:
+    """``(tk, tn)`` of the grouped matmul kernel's weight tile: all of K
+    (one pass over a row tile, no accumulation across tiles) and as many
+    columns, a multiple of 128 that divides N, as ``GMM_TILE_BYTES`` hold."""
+    tn = N
+    while K * tn * itemsize > GMM_TILE_BYTES and tn % 256 == 0:
+        tn //= 2
+    return K, tn
 
 
 def _grouped_dot(rows, w, sizes, row_expert):
@@ -253,8 +269,9 @@ def _grouped_dot(rows, w, sizes, row_expert):
     rows an expert: one grouped product, float32 out. On the Pallas path
     (ops/attention.py ``pallas_enabled``: a TPU, or interpret mode by
     ``DYNAMO_TPU_PALLAS=1``) that is the megablox grouped matmul kernel,
-    each tile an expert's whole [K, N] matrix against up to ``GMM_ROWS``
-    of its rows (XLA's own ``ragged_dot`` read 26 % of the bytes bound at
+    each tile an expert's whole [K, N] matrix (its columns in tiles where
+    that is over ``GMM_TILE_BYTES``: ``gmm_tile``) against up to
+    ``GMM_ROWS`` of its rows (XLA's own ``ragged_dot`` read 26 % of the bytes bound at
     SDAR's widths, this 73 %: my chip run, PR 36); elsewhere, and for
     shapes the kernel's tiling does not take, ``jax.lax.ragged_dot``. A
     quantized stacked weight (ops/quant.py ``{"q", "s"}``, scales per
@@ -273,7 +290,8 @@ def _grouped_dot(rows, w, sizes, row_expert):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         out = gmm(
-            rows, q, sizes, jnp.float32, (tm, K, N),
+            rows, q, sizes, jnp.float32,
+            (tm, *gmm_tile(K, N, q.dtype.itemsize)),
             interpret=_interpret(),
         )
     else:

@@ -544,11 +544,11 @@ def init_params_policy(key, cfg, policy, dtype=jnp.bfloat16):
     if embed_fmt:
         embed = jax.jit(
             lambda k: quantize_weight(
-                llama._dense_init(k, (V, D), dtype), axis=-1, fmt=embed_fmt
+                llama._embed_init(k, cfg, dtype), axis=-1, fmt=embed_fmt
             )
         )(ek)
     else:
-        embed = jax.jit(lambda k: llama._dense_init(k, (V, D), dtype))(ek)
+        embed = jax.jit(lambda k: llama._embed_init(k, cfg, dtype))(ek)
     params = {
         "embed": embed,
         "layers": layers,

@@ -175,13 +175,22 @@ def apply_rope(
     positions: jnp.ndarray,
     theta: float = 10000.0,
     scaling: RopeScaling | None = None,
+    interleaved: bool = False,
 ) -> jnp.ndarray:
     """Rotate q or k. x: [..., n_heads, head_dim]; positions broadcastable to
-    x.shape[:-2]."""
+    x.shape[:-2]. ``interleaved``: pair i is channels (2i, 2i + 1) (HF
+    rope_gptj) and not (i, i + head_dim / 2)."""
     head_dim = x.shape[-1]
     cos, sin = _angles(positions, head_dim, theta, scaling)
     cos = cos[..., None, :]  # broadcast over heads
     sin = sin[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(x.shape)
+        return out.astype(x.dtype)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -61,6 +61,8 @@ def llama_param_specs(cfg: ModelConfig) -> Params:
                 "ln_attn": P(),
                 "ln_mlp": P(),
             }
+        if cfg.parallel_block:  # ONE norm a layer (models/llama.py)
+            del layer["ln_mlp"]
         if cfg.moe_layer(li):
             # MoE: experts over ep, per-expert intermediate over tp; tiny
             # router replicated — one source of truth in models/moe.py.

@@ -305,3 +305,60 @@ def test_ling_share_step_compiles_at_published_widths(
     assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
+
+
+@pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
+def test_command_a_share_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch
+):
+    """The whole of ``command-a-plus-ep8-l4`` (one period: three window
+    layers and one full layer, 16 of 128 experts, an eighth of the
+    vocabulary) in one unified step at T=1024 over TWO pools and two block
+    tables of 1,280 entries for 52 rows: the ragged kernel at 128 query
+    heads over 8 cached heads (16 a head: a 16-row long tile), with and
+    without the window, the grouped expert path's kernels, within one
+    chip's memory."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.models import moe
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = ModelConfig.command_a_plus_ep8_l4()
+    ecfg = EngineConfig(
+        model=cfg, max_num_seqs=48, max_model_len=20480, num_blocks=36000,
+        unified_token_budget=1024, unified_prefill_quantum=1024)
+    pools = ecfg.group_num_blocks
+    assert pools == (36000, 48 * (256 + 64 + 2) + 1)
+    sds = partial(_sds, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+    kv = []
+    for li in range(cfg.num_layers):
+        page = sds((pools[cfg.layer_cache_group(li)] * BS, 8, 128),
+                   jnp.bfloat16)
+        kv.append((page, page))
+    i32 = partial(sds, dtype=jnp.int32)
+    T, rows, MB = 1024, 52, ecfg.max_blocks_per_seq
+    meta = (
+        i32((T,)), i32((T,)), (i32((T,)), i32((T,))), i32((T,)),
+        (i32((rows, MB)), i32((rows, MB))),
+        i32((rows,)), i32((rows,)), i32((rows,)), i32((rows,)),
+    )
+
+    def step(params, kv, *meta):
+        logits, kv = llama.unified(
+            cfg, params, kv, *meta, BS, attn=AttnDispatch(use_pallas=True))
+        return jnp.argmax(logits, axis=-1), kv
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, kv, *meta).compile()
+    # four layers x (the ragged kernel + gate, up, down)
+    assert _kernel_count(compiled.as_text()) == 4 * 4
+    mem = compiled.memory_analysis()
+    # weights 9.47 GB, the window pool 3.04 GB, the full pool 2.36 GB
+    assert 14.6e9 < mem.argument_size_in_bytes < 15.1e9
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16.0e9
