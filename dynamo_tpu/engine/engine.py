@@ -1144,13 +1144,16 @@ class TpuEngine:
         # chunks, and this preserves that).
         spec_counted = spec_on and not has_extras and not has_mm
         compose_ms = 1000.0 * (time.monotonic() - t_compose)
+        # The runner's count for THIS dispatch (a record noted at its
+        # retire would read the next one's).
+        folds = getattr(self.runner, "attn_folds", (0, 0))
         self._inflight.append(
             (
                 "unified",
                 roles,
                 (
                     n_dec, n_pre, self._clock(), t_dispatch, n_drafted,
-                    spec_counted, compose_ms,
+                    spec_counted, compose_ms, folds,
                 ),
                 (out, lp),
             )
@@ -1166,7 +1169,7 @@ class TpuEngine:
             # records at issue, as before.
             self._note_step(
                 "unified",
-                **self._plain_note(roles, n_dec, n_pre, compose_ms),
+                **self._plain_note(roles, n_dec, n_pre, compose_ms, folds),
             )
         # Auto-gate re-probe (semantics preserved from the phased gate):
         # after speculative_probe_steps plain decode steps, run a short
@@ -1196,7 +1199,7 @@ class TpuEngine:
         toks = np.asarray(out.last)  # dynalint: allow[DT005] the pipeline's designed retire point — one forced transfer per dispatch, depth keeps it off the dispatch path
         (
             n_dec, n_pre, t_issue, t_dispatch, drafted,
-            spec_counted, compose_ms,
+            spec_counted, compose_ms, folds,
         ) = stats
         B_blk = self.cfg.model.diffusion_block_length
         blk_ids = None
@@ -1211,7 +1214,7 @@ class TpuEngine:
             hit, rows_held = np.asarray(out.moe_counts).tolist()  # dynalint: allow[DT005] same retirement boundary as `toks`
             self._note_step(
                 "unified",
-                **self._plain_note(roles, n_dec, n_pre, compose_ms),
+                **self._plain_note(roles, n_dec, n_pre, compose_ms, folds),
                 moe_experts_hit=hit,
                 moe_rows_held=rows_held,
             )
@@ -1382,6 +1385,7 @@ class TpuEngine:
                 commit_rows=commit_rows,
                 committed_tokens=n_committed,
                 moe_experts_hit=experts_hit,
+                folds=folds,
             )
         if drafted:
             self._spec_accepted += n_accepted
@@ -1400,11 +1404,12 @@ class TpuEngine:
                 lanes=len(roles),
                 drafted=drafted,
                 accepted=n_accepted,
+                folds=folds,
             )
         if self.cfg.speculative_k:
             self._maybe_gate_speculation()
 
-    def _plain_note(self, roles, n_dec, n_pre, compose_ms) -> dict:
+    def _plain_note(self, roles, n_dec, n_pre, compose_ms, folds) -> dict:
         """The flight-record fields of a plain unified dispatch; where the
         model keeps recurrent state, what its state table saw beside them."""
         note = dict(
@@ -1413,6 +1418,7 @@ class TpuEngine:
             fill=self._unified_fill_ratio,
             dispatch_ms=compose_ms,
             lanes=len(roles),
+            folds=folds,
         )
         if self._rec_on:
             note.update(
@@ -2040,6 +2046,7 @@ class TpuEngine:
         lanes: int = 0,
         drafted: int = 0,
         accepted: int = 0,
+        folds: tuple[int, int] = (0, 0),  # the runner's count AT ITS ISSUE
         **diffusion: int,  # and the expert layers' and recurrent layers' counts
     ) -> None:
         """One dispatch's flight record (engine thread). Counter fields
@@ -2058,6 +2065,8 @@ class TpuEngine:
             lanes=lanes,
             drafted=drafted,
             accepted=accepted,
+            attn_short_folds=folds[0],
+            attn_long_folds=folds[1],
             **diffusion,
             # The runner's last dispatch IS this record's: plain records
             # are noted at issue, spec records at retire under depth 1.
@@ -2904,6 +2913,14 @@ class TpuEngine:
             "engine_handoff_wait_seconds_total": round(
                 self._handoff_wait_s, 6
             ),
+            # The ragged kernel's work by fold body (runner._count_folds).
+            **{
+                f'attn_folds_total{{tile="{tile}"}}': n
+                for tile, n in zip(
+                    ("short", "long"),
+                    getattr(self.runner, "attn_folds_total", (0, 0)),
+                )
+            },
             "kv_reused_device_blocks_total": self._reused_device_blocks,
             "kv_reused_host_blocks_total": self._reused_host_blocks,
             "kv_reused_disk_blocks_total": self._reused_disk_blocks,

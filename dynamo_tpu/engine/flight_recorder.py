@@ -89,6 +89,8 @@ class FlightRecorder:
         kv_window_released: int = 0,
         kv_bytes_live: int = 0,
         context_tokens_live: int = 0,
+        attn_short_folds: int = 0,
+        attn_long_folds: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -123,7 +125,14 @@ class FlightRecorder:
         full-attention and in the windowed pools as the step is noted,
         blocks released behind a window since the record before, the live
         bytes of both pools, and the context tokens of the running
-        sequences those bytes stand for."""
+        sequences those bytes stand for. ``attn_short_folds`` /
+        ``attn_long_folds`` are the folds of its K/V ring the ragged
+        kernel's SHORT tile (decode rows, diffusion blocks) and LONG tile
+        (prefill quanta, verify spans) walk in the dispatch, summed over
+        the layers that call it: how the kernel's work divides between
+        its two fold bodies (the host's count from the spans,
+        ops/pallas/ragged_attention.py ``fold_counts``; 0 where the XLA
+        twin serves)."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -150,6 +159,8 @@ class FlightRecorder:
             "kv_window_released": kv_window_released,
             "kv_bytes_live": kv_bytes_live,
             "context_tokens_live": context_tokens_live,
+            "attn_short_folds": attn_short_folds,
+            "attn_long_folds": attn_long_folds,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

@@ -37,6 +37,7 @@ all dispatch through it, and the runner builds no other step program.
 from __future__ import annotations
 
 import logging
+from collections import Counter
 from functools import lru_cache, partial
 from itertools import chain
 from typing import Any, NamedTuple
@@ -402,6 +403,45 @@ class ModelRunner(WarmupPlanMixin):
             use_pallas=use_pallas, mesh=mesh, kv_replicated=m.is_mla,
             kv_sp=cfg.kv_sp,
         )
+        #: What the host's count of the ragged kernel's work goes by
+        #: (`_count_folds`): the long tile's rows and a fold's keys at a
+        #: chip's heads, and the layers that call the kernel by their
+        #: window; None where the XLA twin or the striped kv_sp scan
+        #: serves. `attn_folds` is the last dispatch's (short, long) folds
+        #: over those layers, `attn_folds_total` every dispatch's.
+        self._fold_plan = None
+        if use_pallas and not cfg.kv_sp:
+            from dynamo_tpu.ops.pallas.ragged_attention import (
+                fold_counts,
+                long_tile,
+                ring_shape,
+            )
+
+            page = (
+                cfg.block_size * local_heads * self.cache_head_dim
+                * self.kv_dtype.itemsize
+            )
+            self._fold_plan = dict(
+                count=partial(
+                    fold_counts,
+                    long_rows=long_tile(m.num_heads // tp, local_heads),
+                    fold_keys=(
+                        ring_shape(page, cfg.block_size)[1] * cfg.block_size
+                    ),
+                    diffusion_block=max(m.diffusion_block_length, 1),
+                ),
+                # a window no context can pass skips nothing
+                layers=Counter(
+                    w if w < cfg.max_model_len else 0
+                    for w in (
+                        m.layer_window(li) for li in range(m.num_layers)
+                        if m.layer_kind(li) == "attn"
+                    )
+                ),
+            )
+        self.attn_folds = (0, 0)
+        self.attn_folds_total = [0, 0]
+
         def kv_shape(li: int) -> tuple:
             slots = self.group_blocks[m.layer_cache_group(li)] * cfg.block_size
             return (slots, cache_heads, self.cache_head_dim)
@@ -1441,6 +1481,7 @@ class ModelRunner(WarmupPlanMixin):
         buf = lay.template.copy()
         seg = lay.views(buf)
         n_l = len(lanes)
+        self.attn_folds = (0, 0)
         if n_l:
             q_len = np.fromiter((len(t) for t, _, _, _ in lanes), np.int32, n_l)
             prefix = np.fromiter((p for _, _, p, _ in lanes), np.int32, n_l)
@@ -1449,7 +1490,9 @@ class ModelRunner(WarmupPlanMixin):
             seg["row_start"][:n_l] = row_start
             seg["q_start"][:n_l] = prefix
             seg["q_len"][:n_l] = q_len
-            seg["kv_len"][:n_l] = prefix + q_len
+            kv_len = prefix + q_len
+            seg["kv_len"][:n_l] = kv_len
+            self._count_folds(prefix, q_len, kv_len)
             # A table and the written rows' slots for each cache group; the
             # first group's pair of segments is the unnumbered one.
             n_groups = len(self.group_blocks)
@@ -1517,6 +1560,25 @@ class ModelRunner(WarmupPlanMixin):
         base_args = (self.params, self.kv_caches, self.kv_scales)
         meta_args = tuple(_meta_of(seg, len(self.group_blocks)))
         return base_args, meta_args, _Operands(buf, seg, prev_toks, feed_transfers)
+
+    def _count_folds(self, q_start, q_len, kv_len) -> None:
+        """Note how the dispatch's kernel work divides between the
+        kernel's two tiles: the folds of the ring its SHORT tile (decode
+        rows, diffusion blocks) and its LONG tile (prefill quanta, verify
+        spans) walk, summed over the layers that call it (ops/pallas/
+        ragged_attention.py ``fold_counts``; the engine's thread, every
+        dispatch: microseconds)."""
+        plan = self._fold_plan
+        if plan is None:
+            return
+        short = long = 0
+        for window, layers in plan["layers"].items():
+            s, l = plan["count"](q_start, q_len, kv_len, window=window)
+            short += layers * s
+            long += layers * l
+        self.attn_folds = (short, long)
+        self.attn_folds_total[0] += short
+        self.attn_folds_total[1] += long
 
     def lower_unified_top(self):
         """Lower (not compile, not run) this runner's own plain unified

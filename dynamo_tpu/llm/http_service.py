@@ -238,6 +238,8 @@ class HttpService:
                 "engine_handoff_wakeups_total",
                 "engine_handoff_items_total",
                 "engine_handoff_wait_seconds_total",
+                'attn_folds_total{tile="short"}',
+                'attn_folds_total{tile="long"}',
                 "last_dispatch_age_s",
                 "num_waiting_interactive",
                 "num_waiting_batch",

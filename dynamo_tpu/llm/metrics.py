@@ -101,9 +101,14 @@ class Metrics:
         lines.append(
             f"{p}_frontend_stream_busy_seconds_total {self.stream_busy_s}"
         )
+        typed = None
         for name, value in sorted(self.gauges.items()):
-            kind = "counter" if name.endswith("_total") else "gauge"
-            lines.append(f"# TYPE {p}_{name} {kind}")
+            # a name may carry labels: its family is typed once
+            family = name.partition("{")[0]
+            if family != typed:
+                kind = "counter" if family.endswith("_total") else "gauge"
+                lines.append(f"# TYPE {p}_{family} {kind}")
+                typed = family
             lines.append(f"{p}_{name} {value}")
         return "\n".join(lines) + "\n"
 

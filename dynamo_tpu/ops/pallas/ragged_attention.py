@@ -52,24 +52,66 @@ cold and paid ~2.7 us a span and 0.12-0.14 us a page whatever the bytes:
   own rows (whole tiles where the span covers them, single rows for the
   tail and for short spans), never a neighbour's.
 - **A span is tiled by its length**, read from ``q_len`` inside the one
-  compiled kernel; two static tiles:
+  compiled kernel; two static tiles, and ONE principle for both fold
+  bodies: a fold's vector work goes with the (query, visible key of its
+  own cached head) pairs, and its scores lie where the softmax's
+  reductions are cheapest. Each choice goes by what the kernel sees in
+  its shapes and dtypes (``G = H / kvH``, ``kvH``, ``diffusion_block``,
+  the cache's and q's dtype), never by a model's name:
+  - *K and V by cached head, as the cache stores them* (``slot_heads``,
+    both tiles). Under a bf16 cache and bf16 q with an even ``kvH`` (or
+    one head; ``by_word``) a head pair's keys are ONE strided read of the
+    slot's 32-bit words (Mosaic strides no packed rows; a packed reshape
+    read wrong rows at few heads, PR 22), a shift or a mask being the
+    cast (``head_rows``): no f32 cast of the slot, no ``[keys, kvH] ->
+    [kvH, keys]`` relayout. q stays bf16 and unscaled, the scores are
+    scaled after the product (bf16 x bf16 is exact in f32) and go
+    through ``exp2`` in units of log 2 (one multiply a score for scale
+    and base). Probabilities stay f32 into PV. int8 and f32 caches keep
+    ``dequant`` / ``heads_view``, a relayout and ``exp``.
   - SHORT, ``q_len <= diffusion_block`` rows (a decode row; a block of a
-    block-diffusion model): every query head is multiplied against the
-    ring slot AS IT LIES — ``[rows*H, D] x [PP*bs*kvH, D]^T`` — and a
-    score counts where the column's KV head is the row's. bf16 K goes to
-    the MXU as stored (exact products, f32 sums), so the per-fold f32
-    cast and the ``[keys, kvH] -> [kvH, keys]`` relayout of K, and the
-    relayout of V, are gone; the masked columns cost VPU work in
-    proportion to ``kvH``, as the bytes are.
+    block-diffusion model). **By cached head where a head's folded rows
+    fill a sublane tile** (``short_by_head``: ``rows * G >= 8`` and more
+    than one cached head; PR 47): scores ``[kvH, rows * G, keys]``, a
+    head's rows against its own keys, keys along the lanes, one online
+    softmax a head. At 128 heads over 8 a fold's scores are 32 vregs
+    where the fold below makes 256 of which one column in eight is a
+    real pair. **Below that** (4 queries a head: the tool read the fold
+    by head no faster there, 409 beside 409-412 us at ``dense`` and 508
+    beside 501 at ``tp4``; one latent head is already one head) every
+    query head is multiplied against the ring slot AS IT LIES, ``[rows *
+    H, D] x [keys * kvH, D]^T``, bf16 K to the MXU as stored, and a score
+    counts where the column's cached head is the row's: the masked
+    columns cost VPU work in proportion to ``kvH``, which 32 rows bear.
   - LONG, more rows (a prefill quantum, a draft-verify span of k+1
-    rows): tiles of ``long_tile(H)`` rows folded one KV head at a time
-    in f32, so the visible cache is streamed ``q_len / 32`` or ``/ 16``
-    times (``q_len / 8`` before). A fold's work goes with rows x heads
-    whatever the span holds, so the tile is 32 rows at a tp=4 chip's 8
-    heads and 16 at 32 heads: there 64 draft-verify spans of 5 rows
-    read 835 us at 32 rows, 604 at 16, 718 in the kernel before, and a
-    cell's dispatch with its 80-row quantum the same (423 | 424); at 8
-    heads the quantum costs 23 us more at 16 rows (my chip runs, PR 40).
+    rows): tiles of ``long_tile(H, kvH)`` rows against one fold of keys
+    at a time, one online softmax a cached head, so the visible cache is
+    streamed ``q_len / 32`` or ``/ 16`` times. *Scores are held
+    transposed*, ``[kvH, keys, rows * G]`` (PR 46's finding, built here):
+    keys down the sublanes, a cached head's folded rows along the lanes.
+    The max and the sum over keys are elementwise across vregs (eight
+    sublane partials; the sum's are carried and joined once a tile), the
+    running max, sum and correction are two vregs a head where rows down
+    the sublanes spend a vreg on every 8, and the accumulator is ``[kvH,
+    D, rows * G]``, turned once a tile. This, not the mask or the cast,
+    was what bound the fold. *The tile's rows follow the queries a
+    cached head serves*: 512 folded rows (``LONG_FOLD_ROWS``) within 16
+    to 32 rows and never under a lane tile of them, so 32 rows at 4, 8
+    and 16 queries a head and 16 at Ling's 32 over one latent head. At
+    16 queries a head 32 rows hold 17 MiB of VMEM (``VMEM_LIMIT``).
+  ``fold_counts`` is the host's mirror of ``tile_folds`` (a step's
+  ``attn_short_folds`` / ``attn_long_folds`` on its flight record).
+  Read and dropped (chip runs of PR 46 and PR 47, a window layer's call
+  at 128 heads over 8, us): the mask alone out of the old long fold
+  4,995 -> 4,818, its cast and relayout alone 4,592, both 4,413; the
+  strided read WITHOUT the transposed scores 4,980; probabilities as two
+  bf16 terms against bf16 V 4,643 where 4,028 without, as ONE bf16 term
+  3,965 beside 3,977: the MXU's passes are not what binds. An interior /
+  edge split of the long fold (no mask where every key of a fold is
+  visible to every row) on THIS layout: 3,874 beside 3,879 under the
+  window, 9,467 beside 9,785 (3.3 %) on a full layer with the quantum at
+  12k, 2,645 beside 2,565 at 2k, for a second traced body a program
+  (``setup_s``): not kept.
 - **Pages a fold come from the shape** (``ring_shape``): a fold is
   ``FOLD_KEYS`` keys, fewer where a slot would pass ``SLOT_BYTES`` (never
   under 128 keys, a lane tile of scores). The ring's depth does not: it
@@ -84,12 +126,19 @@ cold and paid ~2.7 us a span and 0.12-0.14 us a page whatever the bytes:
   H 8, kvH 2 (8 KiB), tp=4      523    516    533    509    593    513
   H 32, kvH 8 (32 KiB)          418    412    411    413    407    452
   H 32, kvH 4 (16 KiB), B=4     436    427    428    426    439    458
+  H 128, kvH 8, window 4,096  3,889  3,855  3,891  3,871  4,111  3,877
+  H 128, kvH 8, no window     9,470  9,398  9,472  9,388 10,406  8,988
   ==========================  =====  =====  =====  =====  =====  =====
 
   (129 / 65 / 65 spans of contexts 200-1,500; my chip runs, PR 40. The
-  kernel before read 1,410 / 854 / 835 at its 8x8.) Depth hardly matters
+  kernel before read 1,410 / 854 / 835 at its 8x8. The last two rows: 45
+  lanes at contexts 600-16,000 beside a 770-row quantum ending at 12k,
+  32 KiB pages, this kernel; my chip runs, PR 46.) Depth hardly matters
   once the ring spans spans; 128-key folds cost the small pages 15 %,
-  512-key folds the large ones 10 % (their clamped tails).
+  512-key folds the large ones 10 % (their clamped tails), and at long
+  contexts 128-key folds cost 6-11 % while 512-key folds (a 1 MiB slot,
+  over ``SLOT_BYTES``) gain 4 % on a layer without a window and nothing
+  under one.
 
 Scores, probabilities, the running max and sum and the accumulator are
 f32; every (query, visible key) pair is computed under the same mask as
@@ -106,6 +155,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -114,16 +164,25 @@ from dynamo_tpu.ops.pallas.attention import heads_view
 MEMORY_SPACE_ANY = pltpu.MemorySpace.ANY
 
 NEG_INF = -1e30
+LOG2E = 1.4426950408889634
 
 # The ring (module docstring has the ladder): folds in flight, keys a
 # fold, and the most one slot of K may hold.
 RAGGED_NBUF = 4
 FOLD_KEYS = 256
 SLOT_BYTES = 512 * 1024
-# Rows of a long span's tile: LONG_FOLD_ROWS (row, head) pairs a fold,
-# within 16 to LONG_TILE rows (module docstring).
+# Rows of a long span's tile: LONG_FOLD_ROWS (row, head) pairs a fold a
+# cached head within 16 to LONG_TILE rows, and LANE pairs at least (module
+# docstring).
 LONG_TILE = 32
 LONG_FOLD_ROWS = 512
+LANE = 128
+# Folded rows a cached head (rows x queries a head) from which a SHORT span
+# folds by cached head: a sublane tile.
+SHORT_HEAD_ROWS = 8
+# The kernel's scoped VMEM: a 32-row tile at 16 queries a cached head holds
+# 17 MiB (two 4 MiB f32 score arrays among it), over the compiler's 16.
+VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _interpret() -> bool:
@@ -140,9 +199,22 @@ def _cdiv(a, b: int):
     return _div(a + (b - 1), b)
 
 
-def long_tile(num_heads: int) -> int:
-    """Rows of a long span's tile, from the heads a chip holds."""
-    return min(LONG_TILE, max(16, LONG_FOLD_ROWS // num_heads))
+def long_tile(num_heads: int, num_kv_heads: int) -> int:
+    """Rows of a long span's tile, from the queries ``G`` a cached head
+    serves: a fold's scores a cached head are ``[keys, rows * G]``, the
+    folded rows along the lanes, so never under a lane tile of them."""
+    g = num_heads // num_kv_heads
+    rows = min(LONG_TILE, max(16, LONG_FOLD_ROWS // g))
+    return max(rows, LANE // g // 8 * 8)
+
+
+def short_by_head(rows: int, num_heads: int, num_kv_heads: int) -> bool:
+    """Whether a short span of ``rows`` rows (1, or the diffusion block)
+    folds one cached head at a time: where a head's ``rows * G`` folded
+    rows fill a sublane tile. Below that (4 queries a head: the tool read
+    it) and at one cached head the fold takes the ring slot as it lies."""
+    folded = rows * (num_heads // num_kv_heads)
+    return num_kv_heads > 1 and folded >= SHORT_HEAD_ROWS
 
 
 def ring_shape(page_bytes: int, block_size: int = 16) -> tuple[int, int]:
@@ -151,6 +223,43 @@ def ring_shape(page_bytes: int, block_size: int = 16) -> tuple[int, int]:
     D * itemsize``)."""
     pp = min(FOLD_KEYS // block_size, SLOT_BYTES // page_bytes)
     return RAGGED_NBUF, max(pp, 128 // block_size, 1)
+
+
+def fold_counts(
+    q_start, q_len, kv_len, *, long_rows: int, fold_keys: int,
+    window: int = 0, diffusion_block: int = 1,
+) -> tuple[int, int]:
+    """``(short, long)``: the folds of the ring one call's SHORT and LONG
+    tiles walk, on the host; the kernel's ``tile_folds`` summed over every
+    tile of the live spans (numpy ``int32`` arrays, no idle row).
+    ``long_rows`` is ``long_tile(H, kvH)``, ``fold_keys`` the ring's ``PP *
+    block_size``. A span of up to ``diffusion_block`` rows is one tile
+    whose last key is its ``kv_len - 1``: array arithmetic over all spans
+    at once (this runs on the engine's thread every step); the few longer
+    spans are then taken out of that sum and walked a tile at a time in
+    plain integers."""
+    B, K = diffusion_block, fold_keys
+    short = len(q_len) + int(np.add.reduce((kv_len - 1) // K))
+    # no span's window starts past fold 0 where no context passes it
+    windowed = window and int(kv_len.max()) > window
+    if windowed:
+        short -= int(np.add.reduce(
+            np.maximum(q_start - (window - 1), 0) // K))
+    long = 0
+    if int(q_len.max()) <= B:
+        return short, long
+    idx = (q_len > B).nonzero()[0]
+    for q0, kv in zip(q_start[idx].tolist(), kv_len[idx].tolist()):
+        lo = max(q0 - window + 1, 0) // K if windowed else 0
+        short -= (kv - 1) // K + 1 - lo
+        for first in range(q0, kv, long_rows):
+            hi = first + long_rows
+            if B > 1:
+                hi = ((hi - 1) // B + 1) * B
+            if windowed:
+                lo = max(first - window + 1, 0) // K
+            long += -(-min(hi, kv) // K) - lo
+    return short, long
 
 
 # Producer / consumer state, int32 scalars in SMEM scratch.
@@ -368,20 +477,73 @@ def _ragged_kernel(
 
         st[_OLN + slot] = 0
 
-    # -- a short span: every head against the ring slot as it lies ---------
+    # -- K and V of a ring slot by cached head ------------------------------
 
-    # Row r of the folded q is (row r // H of the span, head r % H); column
-    # c of a ring slot is (key c // kvH of the fold, KV head c % kvH). A
-    # score counts where the column's KV head is the row's.
-    M = TQS * H
-    row_i = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)
-    col_i = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-    row_tok = _div(row_i, H)
-    row_kvh = _div(jax.lax.rem(row_i, H), G)
-    col_key = _div(col_i, kvH)
-    col_kvh = jax.lax.rem(col_i, kvH)
+    KEYS = PP * bs
+    # A bf16 cache under bf16 q: K reaches the MXU as stored. Row ``key *
+    # kvH + h`` of a slot is half of the 32-bit word ``(key * kvH + h) //
+    # 2``, so a head pair's keys are ONE strided read of words (Mosaic
+    # strides no packed rows).
     mxu_direct = (not quantized) and q_s.dtype == k_buf.dtype == jnp.bfloat16
-    head_match = col_kvh == row_kvh            # [M, N]
+    by_word = mxu_direct and (kvH == 1 or kvH % 2 == 0)
+    # by word the scores go through exp2 in units of log 2: one multiply a
+    # score for scale and base, after the (exact) bf16 product
+    exp = jnp.exp2 if by_word else jnp.exp
+    post = scale * LOG2E if by_word else scale
+
+    def head_rows(buf, slot):
+        """A bf16 ring slot as f32 ``[kvH, KEYS, D]``: each cached head's
+        keys read at their stride, no cast of the slot and no relayout. A
+        word holds row ``2w`` low and row ``2w + 1`` high, and a bf16 is
+        the high half of its f32: a shift or a mask IS the cast."""
+        if kvH == 1:
+            return buf[slot].astype(f32)[None]
+        words = buf.bitcast(jnp.uint32)
+        heads = []
+        for j in range(kvH // 2):
+            w = words[slot, pl.ds(j, KEYS, stride=kvH // 2), :]
+            heads.append(pltpu.bitcast(w << 16, f32))
+            heads.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000), f32))
+        return jnp.stack(heads)
+
+    def slot_heads(s, f, slot, last):
+        """``(K, V)`` of a ring slot as ``[kvH, KEYS, D]``: under
+        ``by_word`` bf16 K (the MXU's operand as stored) and f32 V, else
+        both f32 through ``dequant`` / ``heads_view`` and a relayout."""
+        if by_word:
+            return (head_rows(k_buf, slot).astype(jnp.bfloat16),
+                    head_rows(v_buf, slot))
+        if quantized:
+            k = dequant(k_buf, k_scales_ref, s, f, slot, last)
+            v = dequant(v_buf, v_scales_ref, s, f, slot, last)
+        else:
+            k = heads_view(k_buf, slot, KEYS, kvH, D)
+            v = heads_view(v_buf, slot, KEYS, kvH, D)
+        return jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
+
+    # -- a short span: a row a lane, or a block of a block-diffusion model --
+
+    # BY CACHED HEAD where a head's folded rows fill a sublane tile
+    # (``short_by_head``): scores ``[kvH, TQS * G, KEYS]``, a head's rows
+    # against its own keys. Below that every head is multiplied against
+    # the ring slot AS IT LIES: row r of the folded q is (row r // H of the
+    # span, head r % H); column c of a ring slot is (key c // kvH of the
+    # fold, KV head c % kvH), and a score counts where the column's KV head
+    # is the row's.
+    M = TQS * H
+    GT = TQS * G
+    by_head = short_by_head(TQS, H, kvH)
+    # bf16 q against bf16 K as stored: by head K is read a word at a time
+    direct = by_word if by_head else mxu_direct
+    if by_head:
+        row_tok = _div(jax.lax.broadcasted_iota(jnp.int32, (1, GT, 1), 1), G)
+        col_key = jax.lax.broadcasted_iota(jnp.int32, (1, 1, KEYS), 2)
+    else:
+        row_i = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)
+        col_i = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+        row_tok = _div(row_i, H)
+        col_key = _div(col_i, kvH)
+        head_match = jax.lax.rem(col_i, kvH) == _div(jax.lax.rem(row_i, H), G)
 
     def short_span(s):
         ql = q_len_ref[s]
@@ -395,11 +557,17 @@ def _ragged_kernel(
         pltpu.make_async_copy(
             q_hbm.at[pl.ds(0, TQS)], q_s.at[qslot], qs_sem.at[qslot]
         ).wait()
-        q2 = jnp.concatenate(
-            [q_s[qslot, t].astype(f32) for t in range(TQS)], axis=0
-        )  # [M, D]
-        q2 = q2.astype(jnp.bfloat16) if mxu_direct else q2 * scale
-        q_pos = q0 + row_tok                       # [M, 1]
+        rows = [q_s[qslot, t].astype(f32) for t in range(TQS)]
+        if by_head:
+            # [TQS, H, D] -> [kvH, TQS * G, D]; relaid in f32 (a packed
+            # reshape reads wrong rows at few heads)
+            q2 = jnp.concatenate(
+                [r.reshape(kvH, 1, G, D) for r in rows], axis=1
+            ).reshape(kvH, GT, D)
+        else:
+            q2 = jnp.concatenate(rows, axis=0)     # [M, D]
+        q2 = q2.astype(jnp.bfloat16) if direct else q2 * scale
+        q_pos = q0 + row_tok
         if B > 1:
             q_pos = (_div(q_pos, B) + 1) * B - 1   # the end of its block
         # rows past the span see nothing
@@ -408,44 +576,55 @@ def _ragged_kernel(
         def fold(f, carry):
             m, l, acc = carry
             slot = take_fold(gc0 + (f - lo_f))
-            if quantized:
-                k = dequant(k_buf, k_scales_ref, s, f, slot, nb - 1)
-                v = dequant(v_buf, v_scales_ref, s, f, slot, nb - 1)
-                k, v = k.reshape(N, D), v.reshape(N, D)
+            if by_head:
+                k, v = slot_heads(s, f, slot, nb - 1)
+                qk = (((2,), (2,)), ((0,), (0,)))  # [kvH, GT, KEYS]
+                pv = (((2,), (1,)), ((0,), (0,)))  # [kvH, GT, D]
             else:
-                k = k_buf[slot] if mxu_direct else k_buf[slot].astype(f32)
-                v = v_buf[slot].astype(f32)
+                if quantized:
+                    k = dequant(k_buf, k_scales_ref, s, f, slot, nb - 1)
+                    v = dequant(v_buf, v_scales_ref, s, f, slot, nb - 1)
+                    k, v = k.reshape(N, D), v.reshape(N, D)
+                else:
+                    k = k_buf[slot] if direct else k_buf[slot].astype(f32)
+                    v = v_buf[slot].astype(f32)
+                qk = (((1,), (1,)), ((), ()))      # [M, N]
+                pv = (((1,), (0,)), ((), ()))      # [M, D]
             scores = jax.lax.dot_general(
-                q2, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
-            )  # [M, N]
-            if mxu_direct:
-                scores = scores * scale
-            key_pos = f * (PP * bs) + col_key      # [1, N]
-            mask = head_match & (key_pos <= q_pos) & (key_pos < kv)
+                q2, k, qk, preferred_element_type=f32)
+            if direct:
+                scores = scores * post
+            key_pos = f * KEYS + col_key
+            mask = (key_pos <= q_pos) & (key_pos < kv)
             if window:
                 mask = mask & (key_pos > q_pos - window)
+            if not by_head:
+                mask = mask & head_match
             scores = jnp.where(mask, scores, NEG_INF)
             m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
-            corr = jnp.exp(m - m_new)
-            p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+            corr = exp(m - m_new)
+            p = jnp.where(mask, exp(scores - m_new), 0.0)
             l_new = l * corr + p.sum(axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), preferred_element_type=f32
-            )  # [M, D]: a masked column adds exactly zero
-            return m_new, l_new, acc * corr + pv
+            # a masked column adds exactly zero
+            return m_new, l_new, acc * corr + jax.lax.dot_general(
+                p, v, pv, preferred_element_type=f32)
 
+        lead = (kvH, GT) if by_head else (M,)
         init = (
-            jnp.full((M, 1), NEG_INF, f32),
-            jnp.zeros((M, 1), f32),
-            jnp.zeros((M, D), f32),
+            jnp.full(lead + (1,), NEG_INF, f32),
+            jnp.zeros(lead + (1,), f32),
+            jnp.zeros(lead + (D,), f32),
         )
         m, l, acc = jax.lax.fori_loop(lo_f, hi_f, fold, init)
         out = jnp.where(l > 0, acc / jnp.maximum(l, 1e-30), 0.0)
+        if by_head:
+            out = out.reshape(kvH, TQS, G, D)
 
         oslot = jax.lax.rem(gt, 2)
         drain_short(oslot)
         for t in range(TQS):
-            o_s[oslot, t] = out[t * H:(t + 1) * H].astype(o_s.dtype)
+            row = out[:, t].reshape(H, D) if by_head else out[t * H:(t + 1) * H]
+            o_s[oslot, t] = row.astype(o_s.dtype)
         for r in range(TQS):
             @pl.when(r < ql)
             def _():
@@ -454,11 +633,22 @@ def _ragged_kernel(
         st[_GT] = gt + 1
         st[_GC] = gc0 + (hi_f - lo_f)
 
-    # -- a long span: tiles of TQL rows, one KV head at a time --------------
+    # -- a long span: tiles of TQL rows, one online softmax a cached head ---
 
     R = TQL * G
-    lrow = _div(jax.lax.broadcasted_iota(jnp.int32, (1, R, 1), 1), G)
-    elem = jax.lax.broadcasted_iota(jnp.int32, (1, 1, PP * bs), 2)
+    # Scores are held TRANSPOSED, [kvH, KEYS, R]: keys down the sublanes,
+    # folded rows along the lanes. The softmax's max and sum over keys are
+    # then elementwise across vregs (``by_sublane``; no cross-lane
+    # reduction a fold), and the running max and correction are
+    # [kvH, 1, R], the sum its eight sublane partials [kvH, 8, R]: two
+    # vregs a head where rows down the sublanes take a vreg every 8 rows.
+    lrow = _div(jax.lax.broadcasted_iota(jnp.int32, (1, 1, R), 2), G)
+    elem = jax.lax.broadcasted_iota(jnp.int32, (1, KEYS, 1), 1)
+
+    def by_sublane(x):
+        """``[kvH, KEYS, R]`` as the vregs it lies in, ``[kvH, KEYS / 8, 8,
+        R]``: a reduction over axis 1 is elementwise across vregs."""
+        return x.reshape(kvH, KEYS // 8, 8, R)
 
     def long_span(s):
         ql = q_len_ref[s]
@@ -491,10 +681,16 @@ def _ragged_kernel(
 
             # [TQL, H, D] -> [kvH, TQL*G, D] folded rows; rows past the
             # span read a neighbour's q but see no key, and are never
-            # written back.
-            q4 = (q_l[lslot].astype(f32) * scale).reshape(TQL, kvH, G, D)
+            # written back. Relaid in f32 (a packed reshape reads wrong
+            # rows at few heads); back to bf16 it is q digit for digit.
+            q4 = q_l[lslot].astype(f32)
+            if not by_word:
+                q4 = q4 * scale
+            q4 = q4.reshape(TQL, kvH, G, D)
             qf = jnp.transpose(q4, (1, 0, 2, 3)).reshape(kvH, R, D)
-            q_pos = q0 + tok0 + lrow                 # [1, R, 1]
+            if by_word:
+                qf = qf.astype(jnp.bfloat16)
+            q_pos = q0 + tok0 + lrow                 # [1, 1, R]
             if B > 1:
                 q_pos = (_div(q_pos, B) + 1) * B - 1
             q_pos = jnp.where(lrow < ql - tok0, q_pos, -1)
@@ -502,44 +698,40 @@ def _ragged_kernel(
             def fold(f, carry):
                 m, l, acc = carry
                 slot = take_fold(gc0 + (f - lo_f))
-                if quantized:
-                    k = dequant(k_buf, k_scales_ref, s, f, slot, nb - 1)
-                    v = dequant(v_buf, v_scales_ref, s, f, slot, nb - 1)
-                else:
-                    k = heads_view(k_buf, slot, PP * bs, kvH, D)
-                    v = heads_view(v_buf, slot, PP * bs, kvH, D)
-                kT = jnp.swapaxes(k, 0, 1)  # [kvH, PP*bs, D]
-                vT = jnp.swapaxes(v, 0, 1)
+                kT, vT = slot_heads(s, f, slot, nb - 1)
                 scores = jax.lax.dot_general(
-                    qf, kT,
+                    kT, qf,
                     (((2,), (2,)), ((0,), (0,))),
                     preferred_element_type=f32,
-                )  # [kvH, R, PP*bs]
-                key_pos = f * (PP * bs) + elem
+                )  # [kvH, KEYS, R]
+                if by_word:
+                    scores = scores * post
+                key_pos = f * KEYS + elem
                 mask = (key_pos <= q_pos) & (key_pos < kv)
                 if window:
                     mask = mask & (key_pos > q_pos - window)
                 scores = jnp.where(mask, scores, NEG_INF)
-                m_new = jnp.maximum(m, scores.max(axis=-1))
-                corr = jnp.exp(m - m_new)
-                p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
-                l_new = l * corr + p.sum(axis=-1)
+                m_new = jnp.maximum(m, by_sublane(scores).max(axis=1).max(
+                    axis=1, keepdims=True))
+                corr = exp(m - m_new)
+                p = jnp.where(mask, exp(scores - m_new), 0.0)
+                l_new = l * corr + by_sublane(p).sum(axis=1)
                 pv = jax.lax.dot_general(
-                    p, vT,
-                    (((2,), (1,)), ((0,), (0,))),
+                    vT, p,
+                    (((1,), (1,)), ((0,), (0,))),
                     preferred_element_type=f32,
-                )
-                return m_new, l_new, acc * corr[..., None] + pv
+                )  # [kvH, D, R]
+                return m_new, l_new, acc * corr + pv
 
             init = (
-                jnp.full((kvH, R), NEG_INF, f32),
-                jnp.zeros((kvH, R), f32),
-                jnp.zeros((kvH, R, D), f32),
+                jnp.full((kvH, 1, R), NEG_INF, f32),
+                jnp.zeros((kvH, 8, R), f32),
+                jnp.zeros((kvH, D, R), f32),
             )
             m, l, acc = jax.lax.fori_loop(lo_f, hi_f, fold, init)
-            out = jnp.where(
-                l[..., None] > 0, acc / jnp.maximum(l[..., None], 1e-30), 0.0
-            )
+            l = l.sum(axis=1, keepdims=True)
+            out = jnp.where(l > 0, acc / jnp.maximum(l, 1e-30), 0.0)
+            out = jnp.swapaxes(out, 1, 2)            # [kvH, R, D]
             out = jnp.transpose(out.reshape(kvH, TQL, G, D), (1, 0, 2, 3))
             drain_long(lslot)
             o_l[lslot] = out.reshape(TQL, H, D).astype(o_l.dtype)
@@ -627,7 +819,7 @@ def ragged_paged_attention_pallas(
     ``[T, H, D]``. Rows not covered by any span are returned ZEROED (the
     same contract as the jnp twin). ``q_tile`` is kept for its callers:
     it is the least the long spans' tile may be, the kernel takes
-    ``long_tile(H)`` (16 or 32) rows where that is more, so a value of 16
+    ``long_tile(H, kvH)`` (16 rows or more) where that is more, so a value of 16
     or less changes nothing and no caller in the repo passes one.
 
     With ``k_scales``/``v_scales`` the caches are int8 and pages
@@ -640,7 +832,7 @@ def ragged_paged_attention_pallas(
     kvH = k_cache.shape[1]
     assert diffusion_block == 1 or not window, "no window under a block mask"
     TQS = diffusion_block
-    TQL = max(q_tile, long_tile(H))
+    TQL = max(q_tile, long_tile(H, kvH))
     quantized = k_scales is not None
     kp = k_cache.reshape(-1, block_size * kvH, D)
     vp = v_cache.reshape(-1, block_size * kvH, D)
@@ -701,6 +893,7 @@ def ragged_paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((T + TQL, H, D), q.dtype),
         grid_spec=grid_spec,
         interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
     )(*operands)[:T]
     # Rows no span owns (budget padding between/after spans) may hold
     # whatever the output buffer held — zero them so the contract matches

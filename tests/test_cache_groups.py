@@ -138,6 +138,40 @@ def test_runner_logits_equal_the_references_forward_pass(
     assert v["token_mismatches"] == 0
 
 
+def test_runner_counts_the_ragged_kernels_folds_by_layer_window(monkeypatch):
+    """The host's count of the kernel's work a dispatch (the flight
+    record's ``attn_short_folds`` / ``attn_long_folds``): every layer that
+    calls the kernel under ITS window, six windowed and two full here,
+    from the spans as the operands are packed; nothing under the twin."""
+    from dynamo_tpu.ops.pallas.ragged_attention import long_tile, ring_shape
+
+    lanes = [
+        ([5], (list(range(1, 21)), list(range(1, 21))), 149, (0.0, 0, 1.0)),
+        ([7], ([21, 22, 23], [21, 22, 23]), 19, (0.0, 0, 1.0)),
+        (list(range(40)), (list(range(30, 48)), list(range(30, 48))), 100,
+         (0.0, 0, 1.0)),
+    ]
+    runner = ModelRunner(engine_config(), rng_seed=SEED)
+    runner._unified_operands(lanes, None, 64)
+    assert runner._fold_plan is None and runner.attn_folds == (0, 0)
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    runner = ModelRunner(engine_config(), rng_seed=SEED)
+    plan = runner._fold_plan
+    assert dict(plan["layers"]) == {WINDOW: 6, 0: 2}
+    rule = plan["count"].keywords
+    assert rule["long_rows"] == long_tile(8, 2) and rule["diffusion_block"] == 1
+    # a page of K: 8 tokens x 2 heads x a lane tile of f32
+    keys = ring_shape(8 * 2 * 128 * 4, 8)[1] * 8
+    assert rule["fold_keys"] == keys >= 192      # every context: one fold
+    runner._unified_operands(lanes, None, 64)
+    tiles = -(-40 // rule["long_rows"])
+    assert runner.attn_folds == (8 * 2, 8 * tiles)
+    runner._unified_operands(lanes[:2], None, 64)
+    assert runner.attn_folds == (8 * 2, 0)
+    assert runner.attn_folds_total == [8 * 4, 8 * tiles]
+
+
 @pytest.mark.parametrize("control", [
     "window_on_full", "rope_on_full", "sequential"])
 def test_the_comparison_refuses_a_wrong_layer(control):

@@ -108,19 +108,36 @@ def test_ragged_kernel_compiles_at_served_widths(mosaic, one_chip, T, kv_dtype):
 
 
 @pytest.mark.parametrize(
-    "heads,kv_heads,block,T,kv_dtype",
+    "heads,kv_heads,block,T,kv_dtype,window",
     [
-        (8, 2, 1, 256, "bfloat16"),    # mistral-7b-tp4: a chip's share
-        (8, 2, 1, 256, "int8"),
-        (32, 4, 4, 512, "bfloat16"),   # sdar-30b-a3b: spans of a block of 4
-        (32, 4, 4, 512, "int8"),
+        (8, 2, 1, 256, "bfloat16", 4096),  # mistral-7b-tp4: a chip's share
+        (8, 2, 1, 256, "int8", 4096),
+        (32, 4, 4, 512, "bfloat16", 0),    # sdar-30b-a3b: spans of a block of 4
+        (32, 4, 4, 512, "int8", 0),
+        # command-a-plus-ep8-l4: 16 queries a cached head, a window layer
+        # and a full one at a budget of 1,024 under the kernel's stated
+        # VMEM limit; both tiles fold by cached head and read K and V a
+        # head pair at a stride from the slot's 32-bit words (PR 47); int8
+        # pages take the same folds through a dequantised f32 slot
+        (128, 8, 1, 1024, "bfloat16", 4096),
+        (128, 8, 1, 1024, "bfloat16", 0),
+        (128, 8, 1, 1024, "int8", 0),
+        # no cell's: one, two and seven queries a cached head, where the
+        # long tile's folded rows along the lanes are no multiple of 128
+        (32, 32, 1, 256, "bfloat16", 0),   # a Llama-2-style model's 32 / 32
+        (16, 8, 1, 256, "bfloat16", 4096),
+        (56, 8, 1, 256, "bfloat16", 0),    # Qwen2's ratio at 8 KV heads
+        (56, 8, 1, 256, "int8", 0),
     ],
 )
 def test_ragged_kernel_compiles_at_the_cells_chip_shapes(
-    mosaic, one_chip, heads, kv_heads, block, T, kv_dtype
+    mosaic, one_chip, heads, kv_heads, block, T, kv_dtype, window
 ):
     """The benchmark's other per-chip shapes (PERF.md §4): two KV heads a
-    chip under tp=4, and the short tile of a diffusion block."""
+    chip under tp=4, the short tile of a diffusion block (by cached head,
+    32 rows a head), and 128 query heads over 8 cached heads at a budget of
+    1,024 (Mosaic's verdict on both folds' strided read and on VMEM at the
+    long tile, ``VMEM_LIMIT``)."""
     i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
     cache = _sds((NUM_BLOCKS * BS, kv_heads, D), jnp.dtype(kv_dtype), one_chip)
     scales = {}
@@ -131,7 +148,7 @@ def test_ragged_kernel_compiles_at_the_cells_chip_shapes(
     compiled = ragged_kernel.ragged_paged_attention_pallas.lower(
         _sds((T, heads, D), jnp.bfloat16, one_chip), cache, cache,
         i32((lanes, 256)), i32((lanes,)), i32((lanes,)), i32((lanes,)),
-        i32((lanes,)), block_size=BS, window=0 if block > 1 else 4096,
+        i32((lanes,)), block_size=BS, window=window,
         diffusion_block=block, **scales,
     ).compile()
     assert _kernel_count(compiled.as_text()) == 1

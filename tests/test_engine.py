@@ -762,6 +762,54 @@ async def test_a_retire_hands_its_tokens_over_in_one_wakeup():
         await engine.stop()
 
 
+async def test_a_steps_flight_record_counts_the_kernels_folds():
+    """``attn_short_folds`` / ``attn_long_folds`` ride a step's flight
+    record as the runner counted them for THAT dispatch, and their sums
+    are ``attn_folds_total{tile=...}`` on ``readiness()`` (and so on
+    ``/metrics``): decode rows walk short folds only, a prompt's quantum
+    long ones. The file's model is served by the XLA twin, which counts
+    nothing; the test hands the runner a plan, two layers of 16-key folds."""
+    from collections import Counter
+    from functools import partial
+
+    from dynamo_tpu.llm.metrics import Metrics
+    from dynamo_tpu.ops.pallas.ragged_attention import fold_counts
+
+    engine = TpuEngine(engine_config(), params=PARAMS)
+    assert engine.readiness()['attn_folds_total{tile="short"}'] == 0
+    await engine.start()
+    engine.runner._fold_plan = dict(
+        count=partial(fold_counts, long_rows=16, fold_keys=16),
+        layers=Counter({0: 2}),
+    )
+    try:
+        for prompt in (list(range(1, 40)), [3, 4, 6]):
+            await collect(engine, prompt, max_tokens=8)
+        steps = [r for r in engine.debug_steps() if "dispatch_ms" in r]
+        decode_only = [r for r in steps if not r["prefill_tokens"]]
+        assert decode_only and all(
+            r["attn_long_folds"] == 0 < r["attn_short_folds"]
+            for r in decode_only
+        )
+        # 39 rows from 0: tiles of 16 rows over 1, 2 and 3 folds, two layers
+        first = next(r for r in steps if r["prefill_tokens"] >= 39)
+        assert first["attn_long_folds"] == 2 * (1 + 2 + 3)
+        ready = engine.readiness()
+        for tile in ("short", "long"):
+            total = ready[f'attn_folds_total{{tile="{tile}"}}']
+            assert total == sum(r[f"attn_{tile}_folds"] for r in steps) > 0
+    finally:
+        await engine.stop()
+    # a name that carries labels: its family is typed once on /metrics
+    m = Metrics()
+    for tile in ("short", "long"):
+        key = f'attn_folds_total{{tile="{tile}"}}'
+        m.set_gauge(key, ready[key])
+    text = m.render()
+    assert text.count("_attn_folds_total counter") == 1
+    assert f'_attn_folds_total{{tile="long"}} {ready[key]}\n' in text
+
+
 @pytest.mark.parametrize("ending", ["stop", "max_model_len", "deadline"])
 async def test_a_token_and_its_finish_frame_arrive_in_order(ending):
     """What ends a request inside `_deliver` emits the token and then the

@@ -386,6 +386,94 @@ _SEAMS = {
 }
 
 
+# The long tile's fold (PR 46: scores held transposed, bf16 K and V read
+# from the slot's 32-bit words a head pair at a time) where its mask does
+# different work: folds every row sees whole, the diagonal, a window's
+# lower edge, a span's partial last tile. Each case is a long span between
+# two short ones, so the ring's one order runs through both tiles; at 16, 4,
+# 4 and 32 queries a cached head.
+_K = _FOLD
+
+
+def _long_fold_cases(H, kvH):
+    tl = ragged_kernel.long_tile(H, kvH)
+    bf16 = dict(dtype=jnp.bfloat16)
+    return {
+        # (a) a context under one fold: the diagonal is all there is
+        "only_edge_folds": dict(
+            spans=[(33, 1), (40, tl + 3), (7, 1)], **bf16),
+        # (b) window edge, an interior run, the diagonal
+        "interior_run_under_window": dict(
+            spans=[(2 * _K + 5, 1), (5 * _K + 7, 2 * tl), (90, 1)],
+            window=2 * _K + 88, **bf16),
+        # (c) a window shorter than a fold: its lower edge and the diagonal
+        # meet inside one fold, and no fold is interior
+        "window_edge_inside_a_fold": dict(
+            spans=[(300, 1), (2 * _K + 100, tl + 1), (12, 1)],
+            window=100, **bf16),
+        # (d) whole tiles over interior folds, then a partial tile whose
+        # dead rows see nothing and are never written
+        "partial_tile_beside_interior": dict(
+            spans=[(19, 1), (3 * _K + 9, 2 * tl + 5), (_K + 1, 1)], **bf16),
+        "partial_tile_beside_interior_f32": dict(
+            spans=[(19, 1), (2 * _K + 9, tl + 5), (_K + 1, 1)]),
+        # (e) a block mask over a span longer than a block
+        "block_mask_long_span": dict(
+            spans=[(8, 4), (2 * _K + 8, tl + 8), (36, 4)],
+            diffusion_block=4, **bf16),
+        # (f) int8 pages keep the dequantised f32 fold
+        "int8_pages_interior": dict(
+            spans=[(60, 1), (3 * _K + 30, tl + 2), (_K, 1)],
+            window=2 * _K + 40, int8=True),
+    }
+
+
+# Each axis, not the whole product (interpret mode at 128 heads is slow):
+# every case at 16 queries a cached head, two each at the other shapes.
+for _H, _kvH, _names in (
+    (128, 8, None),
+    (32, 8, ("interior_run_under_window", "partial_tile_beside_interior")),
+    (8, 2, ("window_edge_inside_a_fold", "block_mask_long_span")),
+    (32, 1, ("only_edge_folds", "int8_pages_interior")),
+):
+    for _name, _case in _long_fold_cases(_H, _kvH).items():
+        if _names is None or _name in _names:
+            _SEAMS[f"long_{_name}_h{_H}_kv{_kvH}"] = dict(
+                _case, H=_H, kvH=_kvH)
+
+# The short tile by cached head (PR 47: where a head's rows x queries fill
+# a sublane tile, a head's folded rows against its own keys, read at their
+# stride): rows of 1 and of a block of 4, contexts that end inside a fold
+# and on its edge, a window whose lower edge lies inside a fold, a short
+# last block, the three cache dtypes. The long cases above carry short
+# spans at every shape too (below the threshold at (32, 8), (8, 2), (32, 1)
+# with one row).
+_SEAMS.update({
+    "short_by_head_folds_h128_kv8": dict(
+        spans=[(3 * _K + 17, 1), (_K - 1, 1), (_K, 1), (5, 1)],
+        H=128, kvH=8, dtype=jnp.bfloat16),
+    "short_by_head_window_h128_kv8": dict(
+        spans=[(3 * _K + 17, 1), (2 * _K + 100, 1), (40, 1)],
+        H=128, kvH=8, window=_K + 60, dtype=jnp.bfloat16),
+    "short_by_head_blocks_h32_kv4": dict(
+        spans=[(2 * _K + 8, 4), (_K - 4, 4), (_K + 4, 2), (8, 4)],
+        H=32, kvH=4, diffusion_block=4, dtype=jnp.bfloat16),
+    "short_by_head_blocks_f32_h32_kv4": dict(
+        spans=[(_K + 8, 4), (12, 3)], H=32, kvH=4, diffusion_block=4),
+    "short_by_head_blocks_int8_h32_kv4": dict(
+        spans=[(_K + 8, 4), (12, 3)], H=32, kvH=4, diffusion_block=4,
+        int8=True),
+    # an odd number of cached heads: no head pair a word, the f32 fold
+    "short_by_head_odd_heads_h24_kv3": dict(
+        spans=[(_K + 9, 1), (30, 1), (3, 20)], H=24, kvH=3,
+        dtype=jnp.bfloat16),
+    # 4 queries a head fill a sublane tile only with a block's 4 rows
+    "short_by_head_blocks_h32_kv8": dict(
+        spans=[(_K + 12, 4), (20, 4), (4, 1)], H=32, kvH=8,
+        diffusion_block=4, dtype=jnp.bfloat16),
+})
+
+
 @pytest.mark.parametrize("name", sorted(_SEAMS))
 def test_pipeline_seams(name):
     case = dict(_SEAMS[name])
@@ -475,19 +563,94 @@ def test_ring_shape_follows_the_page(kv_heads, itemsize, block, want_pp):
 
 
 @pytest.mark.parametrize(
-    "heads,want",
+    "heads,kv_heads,want",
     [
-        (8, 32),     # a tp=4 chip's share of 32 heads
-        (16, 32),
-        (32, 16),    # one chip; SDAR
-        (64, 16),    # never under 16 rows
+        (8, 2, 32),     # a tp=4 chip's share of 32 heads over 8
+        (32, 8, 32),    # one chip, 4 queries a cached head
+        (32, 4, 32),    # SDAR, 8
+        (128, 8, 32),   # Command A+, 16: 512 folded rows a cached head
+        (32, 1, 16),    # one latent head under 32 queries: never under 16
+        (64, 1, 16),
+        (56, 8, 32),    # 7 queries a head: 224 folded rows
+        (24, 8, 40),    # 3: a lane tile of folded rows less 8 (whole 8 rows)
+        (16, 8, 64),    # 2, and
+        (32, 32, 128),  # no grouping: a lane tile of folded rows at least
+        (8, 8, 128),
     ],
 )
-def test_long_tile_follows_the_heads(heads, want):
-    """A long fold's work goes with rows x heads: fewer rows where a chip
-    holds more heads, so a draft-verify span of a few rows does not fold
-    1,024 (row, head) pairs for its five."""
-    assert ragged_kernel.long_tile(heads) == want
+def test_long_tile_follows_the_heads(heads, kv_heads, want):
+    """A long fold's scores a cached head are [keys, rows x G], the folded
+    rows along the lanes: the tile holds 512 of them where 16 to 32 rows
+    allow it and never under a lane tile (the tool's readings:
+    ops/pallas/ragged_attention.py), whatever the model."""
+    assert ragged_kernel.long_tile(heads, kv_heads) == want
+
+
+@pytest.mark.parametrize(
+    "rows,heads,kv_heads,want",
+    [
+        (1, 128, 8, True),    # Command A+: 16 queries a cached head
+        (1, 32, 4, True),     # 8: a sublane tile
+        (4, 32, 4, True),     # SDAR's block: 32 rows a head
+        (1, 32, 8, False),    # Mistral, Mixtral: 4 queries a head
+        (1, 8, 2, False),     # a tp=4 chip's share
+        (4, 32, 8, True),     # 4 a head under a block of 4
+        (2, 8, 4, False),
+        (1, 32, 1, False),    # one latent head: the slot is the head
+        (4, 32, 1, False),
+    ],
+)
+def test_short_fold_goes_by_head_where_a_heads_rows_fill_a_tile(
+    rows, heads, kv_heads, want
+):
+    """The short tile folds one cached head at a time where rows x queries
+    a head reach a sublane tile (the tool read 4 a head no faster by head)
+    and there is more than one head; by the shapes alone."""
+    assert ragged_kernel.short_by_head(rows, heads, kv_heads) is want
+
+
+def _tile_folds_by_hand(spans, tile, K, window, B):
+    """The kernel's ``tile_folds`` a tile at a time, in plain integers:
+    ``(short, long)`` folds of the spans' tiles."""
+    out = [0, 0]
+    for q0, n in spans:
+        tq = B if n <= B else tile
+        for first in range(q0, q0 + n, tq):
+            hi = first + tq if B == 1 else ((first + tq - 1) // B + 1) * B
+            nb = -(-min(hi, q0 + n) // BS)
+            lo_f = max(first - window + 1, 0) // K if window else 0
+            out[n > B] += -(-nb // (K // BS)) - lo_f
+    return tuple(out)
+
+
+def test_host_fold_counts_match_the_kernels_tile_folds():
+    """``fold_counts`` (the flight record's ``attn_short_folds`` /
+    ``attn_long_folds``) is ``tile_folds`` summed over every tile of every
+    live span: random spans, windows, block masks, tiles and fold sizes."""
+    rng = np.random.default_rng(47)
+    for _ in range(300):
+        B = int(rng.choice([1, 1, 4]))
+        window = 0 if B > 1 else int(rng.choice([0, 100, 777, 4096]))
+        n = int(rng.integers(1, 40))
+        K = int(rng.choice([128, 256]))
+        tile = int(rng.choice([16, 32, 40, 128]))
+        q0 = rng.integers(0, 9000, n).astype(np.int32) // B * B
+        short = rng.random(n) < 0.8
+        ql = np.where(
+            short, rng.integers(1, B + 1, n), rng.integers(B + 1, 900, n)
+        ).astype(np.int32)
+        ql = np.where(short, ql, -(-ql // B) * B).astype(np.int32)
+        got = ragged_kernel.fold_counts(
+            q0, ql, q0 + ql, long_rows=tile, fold_keys=K, window=window,
+            diffusion_block=B)
+        want = _tile_folds_by_hand(
+            list(zip(q0.tolist(), ql.tolist())), tile, K, window, B)
+        assert got == want, (got, want, B, window, K, tile)
+    # a step of decode rows only walks no long fold
+    ctx = np.asarray([1, 256, 257, 5000], np.int32)
+    assert ragged_kernel.fold_counts(
+        ctx - 1, np.ones(4, np.int32), ctx, long_rows=32, fold_keys=256,
+        window=4096) == (1 + 1 + 2 + 17, 0)
 
 
 def test_unified_verify_rows_match_reference_forward():

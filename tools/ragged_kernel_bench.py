@@ -7,7 +7,10 @@ run's device trace); this is where a kernel PR starts. It answers what the
 records cannot split:
 
 - ``cells``: each shape as its cell dispatches it (decode lanes of mixed
-  context and one prefill quantum);
+  context and one prefill quantum), with the folds of the ring its long
+  and short tiles take and the seconds ``.lower()`` takes on this host;
+- ``parts``: the decode lanes alone (the short tile) and the prefill
+  quantum alone (the long tile);
 - ``split``: the context varied at fixed spans, then the spans varied at a
   fixed context; a least-squares fit of both gives the cost a span (grid
   step, q in, output out, a cold ring) and a page (DMA issue and wait,
@@ -21,6 +24,12 @@ records cannot split:
 
     chiprun -- python -m tools.ragged_kernel_bench --sweep check,cells,split
     chiprun -- python -m tools.ragged_kernel_bench --sweep ladder --shapes tp4
+    chiprun -- python -m tools.ragged_kernel_bench --sweep cells \
+        --shapes cmda-window,cmda-full \
+        --kernel-file _parent/dynamo_tpu/ops/pallas/ragged_attention.py
+
+(``--kernel-file``: another checkout's kernel, so that parent and change are
+read in one call on one chip.)
 
 A line of JSON a measurement on stdout, all of them in
 ``chiprun_out/ragged_kernel_bench.jsonl``. Times are host clock around
@@ -34,12 +43,14 @@ from __future__ import annotations
 import argparse
 import contextlib
 import functools
+import importlib.util
 import json
 import os
 import statistics
 import sys
 import threading
 import time
+import zlib
 
 import numpy as np
 
@@ -47,16 +58,24 @@ import jax
 import jax.numpy as jnp
 
 from chipbench.costs.ragged_paged_attention import cost
+from chipbench.costs.window_full_paged_attention import one_layer
 from chipbench.peaks import peaks_for
-from dynamo_tpu.ops.pallas import ragged_attention as kernel_mod
+from dynamo_tpu.ops.pallas import ragged_attention as ragged_kernel
+
+#: The kernel under measurement: this checkout's, or ``--kernel-file``'s.
+kernel_mod = ragged_kernel
 
 BS = 16
-MAX_MODEL_LEN = 4096
+MAX_MODEL_LEN = 4096    # unless a shape names its own
 
-#: Per-chip shapes of the benchmark's five cells (``PERF.md`` §4): heads and
+#: Per-chip shapes of the benchmark's cells (``PERF.md`` §4): heads and
 #: KV heads a chip, decode lanes, rows a lane, the prefill quantum beside
 #: them, the token budget, the window and the block mask; then two shapes
-#: of draft-verify spans, which no cell sends.
+#: of draft-verify spans, which no cell sends. A shape may name its own
+#: ``max_model_len``, the lanes' contexts (``ctx``) and where its prefill
+#: quantum ends (``prefill_ends``: a measurement each).
+_LONGMIX = dict(max_model_len=20480, ctx=(600, 16000),
+                prefill_ends=(2048, 6144, 12288), by_layer_window=True)
 SHAPES = {
     # mistral-7b-tp4.chat-c128: 32 / 8 heads over four chips
     "tp4": dict(H=8, kvH=2, lanes=128, rows=1, prefill=128, T=256,
@@ -79,6 +98,24 @@ SHAPES = {
     # Named in ``--shapes``.
     "mla": dict(H=32, kvH=1, lanes=128, rows=1, prefill=64, T=256,
                 window=0, diffusion_block=1, D=640),
+    # No cell's: `dense` at one, two and seven queries a cached head (a
+    # Llama-2-style model's 32 heads over 32, the default preset's; 16 over
+    # 8; Qwen2's ratio at 56 over 8), where the long tile's folded rows a
+    # head are fewest or no multiple of a lane tile. Named in ``--shapes``.
+    "mha": dict(H=32, kvH=32, lanes=64, rows=1, prefill=80, T=256,
+                window=0, diffusion_block=1),
+    "gqa2": dict(H=16, kvH=8, lanes=64, rows=1, prefill=80, T=256,
+                 window=4096, diffusion_block=1),
+    "gqa7": dict(H=56, kvH=8, lanes=64, rows=1, prefill=80, T=256,
+                 window=0, diffusion_block=1),
+    # command-a-plus-ep8-l4.longmix-c48: 128 query heads over 8 cached
+    # heads, a mixed step's 45 decode lanes beside a 770-row quantum
+    # (``PERF.md`` §5), one layer of each kind: three of four under the
+    # window, the fourth over the whole context. Named in ``--shapes``.
+    "cmda-window": dict(H=128, kvH=8, lanes=45, rows=1, prefill=770, T=1024,
+                        window=4096, diffusion_block=1, **_LONGMIX),
+    "cmda-full": dict(H=128, kvH=8, lanes=45, rows=1, prefill=770, T=1024,
+                      window=0, diffusion_block=1, **_LONGMIX),
 }
 D = 128
 
@@ -98,7 +135,7 @@ def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
         assert prefill_ctx >= n_pre, (prefill_ctx, n_pre)
         spans.append((prefill_ctx - n_pre, n_pre))
     S = len(spans)
-    max_blocks = MAX_MODEL_LEN // BS
+    max_blocks = shape.get("max_model_len", MAX_MODEL_LEN) // BS
     need = sum(-(-(p + n) // BS) for p, n in spans)
     num_blocks = need + 8
     ids = rng.permutation(np.arange(1, num_blocks))
@@ -129,14 +166,21 @@ def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
 
 
 def bound_us(shape: dict, spans) -> tuple[float, float]:
-    """(bytes bound, operations bound) of one layer's call, microseconds."""
-    flops, nbytes = cost(
-        spans,
-        model=dict(num_heads=shape["H"], num_kv_heads=shape["kvH"],
-                   head_dim=width(shape), num_layers=1,
-                   sliding_window=shape["window"]),
-        engine=dict(tp=1, cache_head_dim=width(shape), dtype_bytes=2),
-    )
+    """(bytes bound, operations bound) of one layer's call, microseconds;
+    a shape of a model whose layers differ by window is reckoned by the
+    cost that reckons each layer by ITS window."""
+    if shape.get("by_layer_window"):
+        flops, nbytes = one_layer(
+            spans, shape["window"], heads=shape["H"], kv_heads=shape["kvH"],
+            d=width(shape), dc=width(shape), itemsize=2, kv_itemsize=2)
+    else:
+        flops, nbytes = cost(
+            spans,
+            model=dict(num_heads=shape["H"], num_kv_heads=shape["kvH"],
+                       head_dim=width(shape), num_layers=1,
+                       sliding_window=shape["window"]),
+            engine=dict(tp=1, cache_head_dim=width(shape), dtype_bytes=2),
+        )
     peaks = chip_peaks()
     return (1e6 * nbytes / peaks["hbm_bytes_per_s"],
             1e6 * flops / peaks["flops_bf16"])
@@ -149,18 +193,55 @@ def chip_peaks() -> dict:
     return peaks_for(kind)
 
 
+def layer_call(shape: dict):
+    """One layer's call of the kernel under measurement, unjitted: a fresh
+    jit a measurement, because the ladder overrides module state that the
+    kernel reads while it is traced."""
+    return functools.partial(
+        kernel_mod.ragged_paged_attention_pallas.__wrapped__,
+        block_size=BS, window=shape["window"],
+        diffusion_block=shape["diffusion_block"],
+    )
+
+
+def lower_seconds(shape: dict, operands) -> float:
+    """Seconds to trace and lower one call on this host (a start pays it a
+    program; the Mosaic compile behind it is not in it)."""
+    q, k, v, meta = operands
+    t0 = time.perf_counter()
+    jax.jit(layer_call(shape)).lower(q, k, v, *meta)
+    return time.perf_counter() - t0
+
+
+def long_tile_of(shape: dict) -> int:
+    """Rows of the measured kernel's long tile (a parent's rule may go by
+    the heads alone)."""
+    try:
+        rows = kernel_mod.long_tile(shape["H"], shape["kvH"])
+    except TypeError:
+        rows = kernel_mod.long_tile(shape["H"])
+    return max(8, rows)
+
+
+def fold_counts(shape: dict, spans) -> dict:
+    """Folds of the ring the call's long and short tiles take, by the
+    measured kernel's tile and ring (the host's count a step's flight
+    record carries)."""
+    _, pp = kernel_mod.ring_shape(BS * shape["kvH"] * width(shape) * 2, BS)
+    starts, rows = (np.asarray(x, np.int32) for x in zip(*spans))
+    short, long = ragged_kernel.fold_counts(
+        starts, rows, starts + rows, long_rows=long_tile_of(shape),
+        fold_keys=pp * BS, window=shape["window"],
+        diffusion_block=shape["diffusion_block"])
+    return dict(long_folds=long, short_folds=short)
+
+
 def time_call(shape: dict, operands, layers: int, reps: int,
               trace_to: str | None = None) -> float:
     """Median microseconds of ONE call, from ``layers`` chained calls;
     with ``trace_to``, one more chain runs under the profiler."""
     q, k, v, meta = operands
-    # A fresh jit a measurement: the ladder overrides module state that
-    # the kernel reads while it is traced.
-    call = functools.partial(
-        kernel_mod.ragged_paged_attention_pallas.__wrapped__,
-        block_size=BS, window=shape["window"],
-        diffusion_block=shape["diffusion_block"],
-    )
+    call = layer_call(shape)
 
     @jax.jit
     def chain(q, k, v, meta):
@@ -187,6 +268,10 @@ def time_call(shape: dict, operands, layers: int, reps: int,
 def measure(name: str, what: str, shape: dict, contexts, prefill_ctx: int,
             args, rng, **extra) -> dict:
     q, k, v, meta, spans = build(shape, contexts, prefill_ctx, rng)
+    if what == "cells":
+        extra = dict(extra, lower_s=round(
+            lower_seconds(shape, (q, k, v, meta)), 3),
+            **fold_counts(shape, spans))
     us = time_call(shape, (q, k, v, meta), args.layers, args.reps)
     bytes_us, flops_us = bound_us(shape, spans)
     pages = sum(-(-(p + n) // BS) for p, n in spans)
@@ -200,6 +285,8 @@ def measure(name: str, what: str, shape: dict, contexts, prefill_ctx: int,
         roofline_pct=round(100 * max(bytes_us, flops_us) / us, 2),
         device=jax.devices()[0].device_kind, **extra,
     )
+    if args.kernel_file:
+        line["kernel_file"] = args.kernel_file
     emit(line)
     return line
 
@@ -218,7 +305,8 @@ def sweep_check(name, shape, args, rng):
     from dynamo_tpu.ops.attention import ragged_paged_attention
 
     contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
-    q, k, v, meta, spans = build(shape, contexts, args.ctx_hi // 2, rng)
+    q, k, v, meta, spans = build(
+        shape, contexts, prefill_ends(shape, args)[-1], rng)
     tables, q_start, q_len, kv_len, row_start = meta
     token_seq = np.zeros(shape["T"], np.int32)
     token_pos = np.full(shape["T"], -1, np.int32)
@@ -237,6 +325,7 @@ def sweep_check(name, shape, args, rng):
     got = np.asarray(got, np.float32)
     err = np.abs(got - want).max(axis=(1, 2)) / np.abs(want).max()
     emit(dict(shape=name, sweep="check", spans=len(spans),
+              kernel_file=args.kernel_file,
               finite=bool(np.isfinite(got).all()),
               worst_row=int(err.argmax()), worst_rel=round(float(err.max()), 5),
               padding_zero=not got[cursor:].any(),
@@ -253,7 +342,8 @@ def sweep_ops(name, shape, args, rng):
     from chipbench import xprof
 
     contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
-    q, k, v, meta, spans = build(shape, contexts, args.ctx_hi // 2, rng)
+    q, k, v, meta, spans = build(
+        shape, contexts, prefill_ends(shape, args)[-1], rng)
     with tempfile.TemporaryDirectory() as logdir:
         time_call(shape, (q, k, v, meta), args.layers, 1, trace_to=logdir)
         reduced = xprof.reduce(xprof.load(logdir))
@@ -268,13 +358,19 @@ def sweep_ops(name, shape, args, rng):
 
 
 def mixed_contexts(shape: dict, rng, lo: int, hi: int) -> np.ndarray:
+    lo, hi = shape.get("ctx", (lo, hi))
     return rng.integers(lo, hi + 1, size=shape["lanes"])
 
 
+def prefill_ends(shape: dict, args) -> tuple[int, ...]:
+    return shape.get("prefill_ends", (args.ctx_hi // 2,))
+
+
 def sweep_cells(name, shape, args, rng):
-    measure(name, "cells", shape,
-            mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi),
-            args.ctx_hi // 2, args, rng)
+    contexts = mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi)
+    for end in prefill_ends(shape, args):
+        measure(name, "cells", shape, contexts, end, args, rng,
+                prefill_end=end)
 
 
 def fit(lines: list[dict]) -> dict:
@@ -311,11 +407,26 @@ def sweep_split(name, shape, args, rng):
               bytes_bound_us_per_page=round(
                   1e6 * page_bytes / chip_peaks()["hbm_bytes_per_s"], 4)))
     # the prefill quantum alone, beside the decode lanes' numbers
+    prefill_alone(name, shape, args, rng,
+                  shape.get("prefill_ends", (shape["prefill"], 512, 1024)))
+
+
+def prefill_alone(name, shape, args, rng, ends):
     if shape["prefill"]:
-        for ctx in (shape["prefill"], 512, 1024):
+        for ctx in ends:
             measure(name, "prefill_alone", dict(shape, lanes=0),
                     np.zeros(0, np.int64), max(ctx, shape["prefill"]),
                     args, rng, ctx=ctx)
+
+
+def sweep_parts(name, shape, args, rng):
+    """The cell's dispatch in its two parts: the decode lanes without the
+    prefill quantum (the short tile), the quantum without the lanes (the
+    long tile) at each place it ends."""
+    measure(name, "lanes_alone", dict(shape, prefill=0),
+            mixed_contexts(shape, rng, args.ctx_lo, args.ctx_hi), 0,
+            args, rng)
+    prefill_alone(name, shape, args, rng, prefill_ends(shape, args))
 
 
 def sweep_ladder(name, shape, args, rng):
@@ -325,8 +436,9 @@ def sweep_ladder(name, shape, args, rng):
         rng.bit_generator.state = state  # the same operands a point
         try:
             with pinned_ring(nbuf, pp):
-                measure(name, "ladder", shape, contexts, args.ctx_hi // 2,
-                        args, rng, nbuf=nbuf, pp=pp)
+                measure(name, "ladder", shape, contexts,
+                        prefill_ends(shape, args)[-1], args, rng,
+                        nbuf=nbuf, pp=pp)
         except Exception as e:  # a point the compiler refuses
             emit(dict(shape=name, sweep="ladder", nbuf=nbuf, pp=pp,
                       error=f"{type(e).__name__}: {str(e)[:200]}"))
@@ -360,8 +472,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shapes", default="tp4,dense,sdar")
     ap.add_argument("--sweep", default="cells,split",
-                    help="cells, split (ctx and spans, fitted), ladder, "
-                    "check, ops (a traced chain, time an op)")
+                    help="cells, parts (lanes alone, quantum alone), split "
+                    "(ctx and spans, fitted), ladder, check, ops (a traced "
+                    "chain, time an op)")
     ap.add_argument("--layers", type=int, default=16)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -375,6 +488,9 @@ def main(argv=None) -> int:
                     default=pairs("2x16,3x16,4x16,6x16,4x8,3x32"),
                     help="NBUFxPP points; the default is the ladder "
                     "recorded in the kernel's docstring")
+    ap.add_argument("--kernel-file", default=None, metavar="PATH",
+                    help="measure the kernel of this file (another "
+                    "checkout's ops/pallas/ragged_attention.py)")
     ap.add_argument("--set", action="append", default=[],
                     metavar="NAME=INT", help="override a kernel constant")
     ap.add_argument("--deadline", type=int, default=1200,
@@ -390,16 +506,25 @@ def main(argv=None) -> int:
     watchdog = threading.Timer(args.deadline, lambda: os._exit(3))
     watchdog.daemon = True
     watchdog.start()
+    if args.kernel_file:
+        global kernel_mod
+        spec = importlib.util.spec_from_file_location(
+            "ragged_attention_under_test", args.kernel_file)
+        kernel_mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(kernel_mod)
     for item in args.set:
         key, value = item.split("=")
         assert hasattr(kernel_mod, key), key
         setattr(kernel_mod, key, int(value))
-    rng = np.random.default_rng(args.seed)
     sweeps = {"cells": sweep_cells, "split": sweep_split,
+              "parts": sweep_parts,
               "ladder": sweep_ladder, "check": sweep_check,
               "ops": sweep_ops}
     for name in args.shapes.split(","):
         for what in args.sweep.split(","):
+            # the same operands a shape whatever else the call measures:
+            # parent and change, or two calls, read the same dispatch
+            rng = np.random.default_rng([args.seed, zlib.crc32(name.encode())])
             sweeps[what](name, SHAPES[name], args, rng)
     return 0
 
