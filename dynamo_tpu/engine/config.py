@@ -220,8 +220,10 @@ class EngineConfig:
         so a span that finds it full can always preempt its way in
         (``Scheduler.fund_span``)."""
         groups = self.model.cache_groups
-        if len(groups) == 1:
-            return (self.num_blocks,)
+        if len(groups) <= 1:
+            # one pool; none where no layer pages (``num_blocks`` is then
+            # not read)
+            return (self.num_blocks,) * len(groups)
         bs = self.block_size
 
         def windowed(w: int) -> int:
@@ -234,7 +236,10 @@ class EngineConfig:
         return tuple(windowed(w) if w else self.num_blocks for w in groups)
 
     def validate(self) -> None:
-        if self.num_blocks < self.max_blocks_per_seq + 1:
+        if (
+            self.model.has_pool
+            and self.num_blocks < self.max_blocks_per_seq + 1
+        ):
             raise ValueError(
                 f"num_blocks={self.num_blocks} cannot hold even one "
                 f"max-length sequence ({self.max_blocks_per_seq} blocks)"

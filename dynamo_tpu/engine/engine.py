@@ -90,6 +90,11 @@ class TpuEngine:
         #: The model keeps a recurrent state beside the paged cache
         #: (docs/architecture/unified_step.md "State that is not pages").
         self._rec_on = cfg.model.has_recurrent
+        #: the kind of its recurrent layers ("kda" | "retention")
+        self._rec_kind = (
+            cfg.model.layer_kind(cfg.model.recurrent_layers[0])
+            if self._rec_on else ""
+        )
         self._window_released_noted = 0
         #: The model keeps its cache by layer group: window and full
         #: layers in pools and tables of their own
@@ -290,20 +295,28 @@ class TpuEngine:
                 shards = self._mesh.shape.get("sp", 1)
             else:
                 shards = int(self.cfg.mesh_shape.get("sp", 1))
-        self.allocator = BlockAllocator(
-            self.cfg.num_blocks,
-            self.cfg.block_size,
-            enable_prefix_caching=self.cfg.enable_prefix_caching,
-            on_event=self._queue_kv_event,
-            num_shards=shards,
-        )
-        # A further cache group (a windowed one beside the full-attention
-        # group) has a pool of its own; no prefix is matched there.
-        more = [
-            BlockAllocator(n, self.cfg.block_size, enable_prefix_caching=False)
-            for n in self.cfg.group_num_blocks[1:]
-        ]
-        self.scheduler = Scheduler(self.cfg, self.allocator, *more)
+        # One pool a cache group; none for a model no layer of which pages
+        # (every layer keeps a recurrent state): slots are its only
+        # resource, and `allocator` stays None.
+        pools = []
+        if self.cfg.model.has_pool:
+            self.allocator = BlockAllocator(
+                self.cfg.num_blocks,
+                self.cfg.block_size,
+                enable_prefix_caching=self.cfg.enable_prefix_caching,
+                on_event=self._queue_kv_event,
+                num_shards=shards,
+            )
+            # A further cache group (a windowed one beside the
+            # full-attention group) has a pool of its own; no prefix is
+            # matched there.
+            pools = [self.allocator] + [
+                BlockAllocator(
+                    n, self.cfg.block_size, enable_prefix_caching=False
+                )
+                for n in self.cfg.group_num_blocks[1:]
+            ]
+        self.scheduler = Scheduler(self.cfg, *pools)
         # start() runs on the asyncio loop: bind it for the runtime
         # affinity checker (no-op unless DYNTPU_CHECK_THREADS=1).
         concurrency.bind_thread("loop")
@@ -1421,11 +1434,13 @@ class TpuEngine:
             folds=folds,
         )
         if self._rec_on:
-            note.update(
-                kda_decode_lanes=sum(r[3] == 1 for r in roles),
-                kda_prefill_rows=sum(r[3] for r in roles if r[3] > 1),
-                kda_fresh_spans=sum(r[2] == 0 for r in roles),
-            )
+            # What the state table saw, under its layers' kind.
+            kind = self._rec_kind
+            note.update({
+                f"{kind}_decode_lanes": sum(r[3] == 1 for r in roles),
+                f"{kind}_prefill_rows": sum(r[3] for r in roles if r[3] > 1),
+                f"{kind}_fresh_spans": sum(r[2] == 0 for r in roles),
+            })
         if self._grouped:
             note.update(self._group_note())
         return note
@@ -3014,6 +3029,11 @@ class TpuEngine:
             ),
             "recurrent_state_bytes": getattr(
                 self.runner, "recurrent_state_bytes", 0
+            ),
+            # slots a sequence owns / slots (the trash slot left out)
+            "recurrent_state_usage_perc": (
+                len(sched.running) / max(self.cfg.max_num_seqs, 1)
+                if self._rec_on and sched is not None else 0.0
             ),
             "diffusion_passes_total": self._diffusion_passes,
             "diffusion_committed_tokens_total": self._diffusion_committed,

@@ -84,6 +84,9 @@ class FlightRecorder:
         kda_decode_lanes: int = 0,
         kda_prefill_rows: int = 0,
         kda_fresh_spans: int = 0,
+        retention_decode_lanes: int = 0,
+        retention_prefill_rows: int = 0,
+        retention_fresh_spans: int = 0,
         kv_full_blocks: int = 0,
         kv_window_blocks: int = 0,
         kv_window_released: int = 0,
@@ -116,8 +119,10 @@ class FlightRecorder:
         grouped expert layers: the weights its grouped kernels had to
         read, which routing decides; ``moe_rows_held`` the routed (row,
         expert) pairs that landed on an expert held here, summed likewise
-        (an expert share: models/moe.py). The three ``kda_`` fields are a
-        model's with recurrent layers: the lanes whose state advanced by
+        (an expert share: models/moe.py). The three ``kda_`` fields and
+        the three ``retention_`` fields are what a model's state table
+        saw, by the kind of its recurrent layers (delta-rule linear
+        attention; power retention): the lanes whose state advanced by
         one row, the prefill rows that went through the chunk path, and
         the spans that started from zeros. The five ``kv_`` /
         ``context_`` fields are a model's that keeps its cache by layer
@@ -154,6 +159,9 @@ class FlightRecorder:
             "kda_decode_lanes": kda_decode_lanes,
             "kda_prefill_rows": kda_prefill_rows,
             "kda_fresh_spans": kda_fresh_spans,
+            "retention_decode_lanes": retention_decode_lanes,
+            "retention_prefill_rows": retention_prefill_rows,
+            "retention_fresh_spans": retention_fresh_spans,
             "kv_full_blocks": kv_full_blocks,
             "kv_window_blocks": kv_window_blocks,
             "kv_window_released": kv_window_released,

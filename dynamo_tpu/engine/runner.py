@@ -330,6 +330,9 @@ class ModelRunner(WarmupPlanMixin):
         #: Blocks of each cache group's pool (one group: ``num_blocks``).
         self.group_blocks = cfg.group_num_blocks
         n_groups = len(self.group_blocks)
+        #: A block table's width in the packed operands: one entry where
+        #: the model has no pool (nothing reads it).
+        self.table_width = cfg.max_blocks_per_seq if n_groups else 1
 
         # Per-runner attention path (ops/attention.py AttnDispatch): the
         # Pallas kernels need D % 128 == 0 inside the kernel, so smaller
@@ -410,7 +413,7 @@ class ModelRunner(WarmupPlanMixin):
         #: serves. `attn_folds` is the last dispatch's (short, long) folds
         #: over those layers, `attn_folds_total` every dispatch's.
         self._fold_plan = None
-        if use_pallas and not cfg.kv_sp:
+        if use_pallas and not cfg.kv_sp and n_groups:
             from dynamo_tpu.ops.pallas.ragged_attention import (
                 fold_counts,
                 long_tile,
@@ -450,15 +453,24 @@ class ModelRunner(WarmupPlanMixin):
             # A layer that keeps a recurrent state has no pages: its entry
             # is empty and its state lives in `rec_state`. A layer's pages
             # are its cache group's pool: every layer's alike where the
-            # model has one group.
-            return [
-                (
-                    jnp.zeros(kv_shape(li), self.kv_dtype),
-                    jnp.zeros(kv_shape(li), self.kv_dtype),
+            # model has one group. Where NO layer pages the cache is the
+            # empty cache: a (k, v) pair of no slots a layer, no bytes, so
+            # whoever asks a cache for its head size or dtype still can
+            # (the benchmark's harness does; ROADMAP.md Design #4 says when
+            # this goes and every such layer's entry becomes `()`).
+            def pages(li):
+                if m.layer_kind(li) == "attn":
+                    shape = kv_shape(li)
+                elif not n_groups:
+                    shape = (0, cache_heads, self.cache_head_dim)
+                else:
+                    return ()
+                return (
+                    jnp.zeros(shape, self.kv_dtype),
+                    jnp.zeros(shape, self.kv_dtype),
                 )
-                if m.layer_kind(li) == "attn" else ()
-                for li in range(m.num_layers)
-            ]
+
+            return [pages(li) for li in range(m.num_layers)]
 
         def make_kv_scales():
             # Per-(layer, K/V, block, head) scales; zero = empty block
@@ -620,29 +632,28 @@ class ModelRunner(WarmupPlanMixin):
         self.kv_caches = kv_caches
         self.kv_scales = kv_scales
         # The state that is not pages (docs/architecture/unified_step.md):
-        # for each recurrent layer a (state [N+1, H, d, d], convolution
-        # tail [N+1, K-1, 3*H*d]) pair over max_num_seqs + 1 slots, slot 0
-        # the trash slot; donated through every program beside the cache.
-        # None where the model has no such layer: no array, no operand.
+        # for each recurrent layer the arrays its kind keeps
+        # (``ModelConfig.recurrent_state_arrays``: a delta-rule layer's
+        # state and convolution tail, a retention layer's S and z) over
+        # max_num_seqs + 1 slots, slot 0 the trash slot; donated through
+        # every program beside the cache. None where the model has no
+        # such layer: no array, no operand.
         rec_on = m.has_recurrent
         self.rec_state = None
         if rec_on:
-            n_slots = cfg.max_num_seqs + 1
-            H_l, d_l = m.num_heads, m.head_dim
             self.rec_state = [
-                (
-                    jnp.zeros((n_slots, H_l, d_l, d_l), jnp.float32),
-                    jnp.zeros(
-                        (n_slots, m.linear_conv_kernel - 1, 3 * H_l * d_l),
-                        self.dtype,
-                    ),
+                tuple(
+                    jnp.zeros(shape, dt)
+                    for shape, dt in m.recurrent_state_arrays(
+                        li, cfg.max_num_seqs + 1, self.dtype.name
+                    )
                 )
-                for _ in m.recurrent_layers
+                for li in m.recurrent_layers
             ]
         #: Bytes of recurrent state resident on the device (0 for a model
         #: that keeps keys and values only); fixed at construction.
-        self.recurrent_state_bytes = sum(
-            a.nbytes for a in jax.tree.leaves(self.rec_state)
+        self.recurrent_state_bytes = m.recurrent_state_bytes(
+            cfg.max_num_seqs + 1, self.dtype.name
         )
         self._step = 0
         # Weight-quant observability (DT011 surfaces read these via
@@ -698,7 +709,7 @@ class ModelRunner(WarmupPlanMixin):
             )
 
         S_rows = self.unified_slots
-        MB = cfg.max_blocks_per_seq
+        MB = self.table_width
         rec_sfx = ("+rec" if rec_on else "") + (
             f"+grp{n_groups}" if n_groups > 1 else "")
         #: Does the plain program hand out the expert layers' counts? Where
@@ -1102,7 +1113,7 @@ class ModelRunner(WarmupPlanMixin):
     def _trash_table(self):
         """A lane's block table with every slot -> trash block 0 (one a
         cache group where the model has several)."""
-        trash = [0] * self.cfg.max_blocks_per_seq
+        trash = [0] * self.table_width
         n = len(self.group_blocks)
         return trash if n == 1 else (trash,) * n
 
@@ -1473,7 +1484,7 @@ class ModelRunner(WarmupPlanMixin):
         S = self.unified_slots
         bs = cfg.block_size
         lay = operand_layout(
-            T, S, cfg.max_blocks_per_seq, cfg.speculative_k,
+            T, S, self.table_width, cfg.speculative_k,
             variant or self._ladder_variant,
         )
         # A fresh buffer every dispatch: at pipeline depth 2 the
@@ -1505,9 +1516,11 @@ class ModelRunner(WarmupPlanMixin):
             )
             # A lane's second place is its table, or one table a group:
             # the lanes' ids, one list a group.
+            # (A model with no pool has no table to fill: whatever a lane
+            # carries there is not read.)
             lane_ids = [block_ids for _, block_ids, _, _ in lanes]
             ids_of = [lane_ids] if n_groups == 1 else zip(*lane_ids)
-            for table, ids_g in zip(tables, ids_of):
+            for table, ids_g in zip(tables[:n_groups], ids_of):
                 for s, ids in enumerate(ids_g):
                     table[s, : len(ids)] = ids
             for s, (_toks, _ids, _prefix, sampling) in enumerate(lanes):
@@ -1529,7 +1542,7 @@ class ModelRunner(WarmupPlanMixin):
                 ids[masked] = cfg.model.mask_token_id
             seg["token_seq"][:total] = token_seq
             seg["token_pos"][:total] = token_pos
-            for table, slot in zip(tables, slots):
+            for table, slot in zip(tables[:n_groups], slots):
                 slot[:total] = (
                     table[token_seq, token_pos // bs] * bs + token_pos % bs
                 )

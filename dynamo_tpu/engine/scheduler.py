@@ -120,10 +120,13 @@ class Scheduler:
     def __init__(self, cfg: EngineConfig, *allocators: BlockAllocator) -> None:
         """One allocator for each of the model's cache groups
         (``cfg.model.cache_groups``, docs/architecture/cache_groups.md):
-        one for most models, and ``allocator`` is it."""
+        one for most models, and ``allocator`` is it; none for a model no
+        layer of which pages (``allocator`` is None): a batch slot, which
+        is its state slot, is then the only thing admission hands out,
+        and a sequence's memory does not grow with its context."""
         self.cfg = cfg
         self.allocators = list(allocators)
-        self.allocator = allocators[0]
+        self.allocator = allocators[0] if allocators else None
         #: each group's window in tokens (0 = the whole context)
         self.windows = tuple(cfg.model.cache_groups)
         assert len(self.windows) == len(self.allocators), (
@@ -272,6 +275,13 @@ class Scheduler:
             return False
         bs = self.cfg.block_size
         P = len(seq.prompt_tokens)
+        if self.allocator is None:
+            # No pool: a free slot is all a sequence needs.
+            seq.tables, seq.evicted = [], []
+            seq.num_cached_prefix = 0
+            seq.hashes = None
+            self._seat(seq)
+            return True
 
         seq.hashes = TokenBlockSequence(block_size=bs)
         # Prefix match on full prompt blocks, capped so ≥1 token is computed.
@@ -314,11 +324,15 @@ class Scheduler:
         seq.evicted = [0] * len(seq.tables)
         seq.num_cached_prefix = cached_tokens
         seq.hashes.extend(seq.prompt_tokens)
+        self._seat(seq)
+        return True
+
+    def _seat(self, seq: Sequence) -> None:
+        """Hand an admitted sequence its batch slot (its state slot too)."""
         seq.sched_len = seq.total_len
         seq.slot = self._free_slots.pop()
         seq.status = SeqStatus.RUNNING
         self.running[seq.slot] = seq
-        return True
 
     def register_filled_blocks(self, seq: Sequence, covered_tokens: int) -> None:
         """Register every block whose KV is now fully written (the first
@@ -533,13 +547,19 @@ class Scheduler:
             seq.slot = None
 
     def blocks_in_use(self, group: int) -> int:
+        if group >= len(self.allocators):
+            return 0  # no pool
         alloc = self.allocators[group]
         return alloc.num_blocks - 1 - alloc.num_free
 
     def cache_usage(self) -> float:
         """The cache in use over the cache there is, one number: by bytes
         over every group's pool (a group's block is its layers' pages);
-        the one pool's share of blocks where the model has one group."""
+        the one pool's share of blocks where the model has one group;
+        the slots in use over the slots where it has no pool at all (the
+        state table is then the cache there is)."""
+        if not self.allocators:
+            return len(self.running) / max(self.cfg.max_num_seqs, 1)
         if len(self.allocators) == 1:
             return self.allocator.usage()
         used = sum(
@@ -591,7 +611,9 @@ class Scheduler:
             "request_active_slots": len(self.running),
             "request_total_slots": self.cfg.max_num_seqs,
             "kv_active_blocks": self.blocks_in_use(0),
-            "kv_total_blocks": self.allocator.num_blocks - 1,
+            "kv_total_blocks": (
+                self.allocator.num_blocks - 1 if self.allocator else 0
+            ),
             "num_requests_waiting": len(self.waiting),
             "gpu_cache_usage_perc": self.cache_usage(),
             "gpu_prefix_cache_hit_rate": 0.0,  # updated by the engine

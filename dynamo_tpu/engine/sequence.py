@@ -136,8 +136,9 @@ class Sequence:
     @property
     def block_ids(self) -> list[int]:
         """The first cache group's block table: the only one of most
-        models, the full-attention layers' where a model has several."""
-        return self.tables[0]
+        models, the full-attention layers' where a model has several, none
+        (empty) where the model has no pool."""
+        return self.tables[0] if self.tables else []
 
     @block_ids.setter
     def block_ids(self, ids: list[int]) -> None:

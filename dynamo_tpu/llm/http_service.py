@@ -216,6 +216,7 @@ class HttpService:
                 "moe_grouped_rows_total",
                 "recurrent_state_slots_in_use",
                 "recurrent_state_bytes",
+                "recurrent_state_usage_perc",
                 # The cache by layer group (docs/architecture/
                 # cache_groups.md): each pool's share in use, blocks
                 # released behind a window, preemptions by the pool that

@@ -47,7 +47,8 @@ _GAUGES = (
     ("diffusion_committed_tokens_total", "Tokens committed by block-diffusion passes"),
     ("moe_grouped_rows_total", "Routed rows through the grouped expert path"),
     ("recurrent_state_slots_in_use", "Recurrent-state slots a sequence owns"),
-    ("recurrent_state_bytes", "Recurrent (linear-attention) state resident on the device, bytes"),
+    ("recurrent_state_bytes", "Recurrent (linear-attention or retention) state resident on the device, bytes"),
+    ("recurrent_state_usage_perc", "Recurrent-state slots a sequence owns over the slots there are (0-1)"),
     ("batch_fill_ratio", "Unified batch fill (real tokens / budget)"),
     ("coloc_quantum", "Live prefill quantum (coloc controller)"),
     ("itl_ema_ms", "Decode inter-token-latency EMA, ms"),

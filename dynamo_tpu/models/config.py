@@ -159,6 +159,16 @@ class ModelConfig:
     shared_experts_average: bool = False
     cache_by_layer_group: bool = False
     embed_init_std: float = 0.0
+    # --- power retention (Brumby family, model_type brumby; arXiv:2507.04239;
+    # docs/architecture/unified_step.md "State that is not pages") ---
+    # retention_degree p > 0 (2 is the one implemented): EVERY layer is a
+    # power-retention layer: the q/k/v projections, per-head q/k norms and
+    # rotary embedding of an attention layer, a log-sigmoid gate a cached
+    # head, and in place of keys and values the gated sum of the keys'
+    # symmetric p-th powers against their values: num_kv_heads x (head_dim
+    # x (head_dim / 2 + 1)) x head_dim in float32 a sequence (ops/
+    # power_retention.py). No layer pages: the model has no pool.
+    retention_degree: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -170,8 +180,11 @@ class ModelConfig:
         return self.num_experts_held or self.num_experts
 
     def layer_kind(self, layer_idx: int) -> str:
-        """"kda" for a linear-attention layer (recurrent state), "attn"
+        """"kda" for a delta-rule linear-attention layer and "retention"
+        for a power-retention layer (both keep a recurrent state), "attn"
         for one that reads keys and values through the paged cache."""
+        if self.retention_degree:
+            return "retention"
         if self.layer_group_size and (layer_idx + 1) % self.layer_group_size:
             return "kda"
         return "attn"
@@ -181,12 +194,58 @@ class ModelConfig:
         """The layers that keep a recurrent state, in order."""
         return tuple(
             li for li in range(self.num_layers)
-            if self.layer_kind(li) == "kda"
+            if self.layer_kind(li) != "attn"
         )
 
     @property
     def has_recurrent(self) -> bool:
         return bool(self.recurrent_layers)
+
+    @property
+    def has_pool(self) -> bool:
+        """Does any layer keep keys and values in pages? A model none of
+        whose layers does has no pool, no block table and no allocator:
+        state slots are its only resource."""
+        return bool(self.cache_groups)
+
+    def recurrent_state_arrays(
+        self, layer_idx: int, n_slots: int, dtype: str
+    ) -> tuple:
+        """THE place that says what a recurrent layer keeps: ``((shape,
+        dtype), ...)`` of its arrays over ``n_slots`` slots of the state
+        table, by the layer's kind; ``()`` for a layer that pages.
+        ``dtype`` is the served activation dtype. "kda": the state ``[N,
+        H, d, d]`` float32 and the convolution's tail ``[N, K - 1, 3 H d]``.
+        "retention": the gated sum of ``phi(k) v^T``, ``[N, kvH, R, d, d]``
+        float32, and of ``phi(k)``, ``[N, kvH, R (padded to 8), d]``
+        float32 (ops/power_retention.py ``state_shapes``)."""
+        kind = self.layer_kind(layer_idx)
+        H, d = self.num_heads, self.head_dim
+        if kind == "kda":
+            return (
+                ((n_slots, H, d, d), "float32"),
+                ((n_slots, self.linear_conv_kernel - 1, 3 * H * d), dtype),
+            )
+        if kind == "retention":
+            from dynamo_tpu.ops.power_retention import state_shapes
+
+            return tuple(
+                (shape, "float32")
+                for shape in state_shapes(n_slots, self.num_kv_heads, d)
+            )
+        return ()
+
+    def recurrent_state_bytes(self, n_slots: int, dtype: str) -> int:
+        """Bytes of the whole state table over ``n_slots`` slots."""
+        import math
+
+        import numpy as np
+
+        return sum(
+            math.prod(shape) * np.dtype(dt).itemsize
+            for li in range(self.num_layers)
+            for shape, dt in self.recurrent_state_arrays(li, n_slots, dtype)
+        )
 
     def swiglu_limit(self, layer_idx: int, shared: bool = False) -> float:
         limits = self.shared_swiglu_limit if shared else self.expert_swiglu_limit
@@ -239,14 +298,15 @@ class ModelConfig:
         the attention layers are all of one kind (Mistral: its window);
         the distinct ``layer_window`` values where they are not and the
         model says ``cache_by_layer_group``; else ``(0,)``: one pool keeps
-        every layer's whole history."""
+        every layer's whole history. ``()`` where NO layer keeps keys and
+        values (every layer a recurrent one): the model has no pool."""
         windows = {
             self.layer_window(li) for li in range(self.num_layers)
             if self.layer_kind(li) == "attn"
         }
         if len(windows) > 1 and not self.cache_by_layer_group:
             return (0,)
-        return tuple(sorted(windows)) or (0,)
+        return tuple(sorted(windows))
 
     def layer_cache_group(self, layer_idx: int) -> int:
         """Which of ``cache_groups`` a layer's keys and values live in."""
@@ -285,6 +345,8 @@ class ModelConfig:
             return ModelConfig._from_hf_bailing_hybrid(cfg)
         if cfg.get("model_type") == "cohere2_moe":
             return ModelConfig._from_hf_cohere2_moe(cfg)
+        if cfg.get("model_type") == "brumby":
+            return ModelConfig._from_hf_brumby(cfg)
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -392,6 +454,47 @@ class ModelConfig:
                 cfg.get("expert_swiglu_limit_list") or ()),
             shared_swiglu_limit=tuple(
                 cfg.get("share_expert_swiglu_limit_list") or ()),
+        )
+
+    @staticmethod
+    def _from_hf_brumby(cfg: dict) -> "ModelConfig":
+        """HF ``brumby`` config.json (Brumby-14B-Base) -> ModelConfig:
+        Qwen3's dense decoder with every attention layer a power-retention
+        layer. The config carries the widths and ``rope_theta`` and nothing
+        of the retention itself: the degree (2), the gate and the
+        normaliser are the program's (the preset's docstring)."""
+        if cfg.get("sliding_window") is not None and cfg.get(
+                "use_sliding_window", True):
+            raise NotImplementedError(
+                "brumby with a sliding_window that is not null is not "
+                "implemented: a retention layer keeps no keys to window"
+            )
+        unserved = {
+            "attention_bias": cfg.get("attention_bias"),
+            "rope_scaling": cfg.get("rope_scaling"),
+            "an activation that is not silu":
+                cfg.get("hidden_act", "silu") != "silu",
+        }
+        for what, on in unserved.items():
+            if on:
+                raise NotImplementedError(
+                    f"brumby with {what} is not implemented")
+        heads = cfg["num_attention_heads"]
+        return ModelConfig(
+            name=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim", cfg["hidden_size"] // heads),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position=cfg.get("max_position_embeddings", 32768),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            qk_norm=True,
+            retention_degree=2,
         )
 
     @staticmethod
@@ -935,6 +1038,57 @@ class ModelConfig:
         )
 
     @staticmethod
+    def brumby_14b() -> "ModelConfig":
+        """Brumby-14B-Base (HF manifestai/Brumby-14B-Base config.json,
+        model_type brumby): Qwen3-14B's widths (40 layers, 5,120 wide, 40
+        query heads over 8 cached heads of 128, a 17,408-wide SwiGLU FFN,
+        an untied 151,936-row vocabulary, rope_theta 1e6) with every
+        attention layer a power-retention layer (arXiv:2507.04239). What
+        config.json does not state is set here: degree 2; a 5,120 -> 8
+        gate (a projection and a bias) through log-sigmoid, one a cached
+        head; the
+        normaliser ``phi(q) . z + 1e-6``; ``1 / sqrt(128)`` inside the
+        power; the per-head q/k RMSNorm and the rotary embedding kept on q
+        and k; the state in float32."""
+        return ModelConfig(
+            name="brumby",
+            vocab_size=151936,
+            hidden_size=5120,
+            intermediate_size=17408,
+            num_layers=40,
+            num_heads=40,
+            num_kv_heads=8,
+            head_dim=128,
+            rope_theta=1000000.0,
+            rms_eps=1e-6,
+            max_position=32768,
+            tie_word_embeddings=False,
+            qk_norm=True,
+            retention_degree=2,
+        )
+
+    @staticmethod
+    def tiny_brumby_test(vocab_size: int = 384) -> "ModelConfig":
+        """Hermetic Brumby-style test model: four retention layers, 4
+        query heads over 2 cached heads of 16."""
+        return ModelConfig(
+            name="tiny-brumby-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=4,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=1000000.0,
+            rms_eps=1e-6,
+            max_position=512,
+            tie_word_embeddings=False,
+            qk_norm=True,
+            retention_degree=2,
+        )
+
+    @staticmethod
     def command_a_plus() -> "ModelConfig":
         """Command A+ 05-2026 (HF CohereLabs/command-a-plus-05-2026
         config.json, model_type cohere2_moe; 218B-A25B): 32 parallel-block
@@ -1156,4 +1310,6 @@ PRESETS = {
     "command-a-plus": ModelConfig.command_a_plus,
     "command-a-plus-ep8-l4": ModelConfig.command_a_plus_ep8_l4,
     "tiny-command-a-test": ModelConfig.tiny_command_a_test,
+    "brumby-14b": ModelConfig.brumby_14b,
+    "tiny-brumby-test": ModelConfig.tiny_brumby_test,
 }

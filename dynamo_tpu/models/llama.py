@@ -15,6 +15,7 @@ covers Llama-2/3/3.x, Qwen2 (qkv_bias), and Mixtral-style sparse MoE
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any
 
 import jax
@@ -241,6 +242,11 @@ def init_layer_params(
         if cfg.post_norms:
             layer["ln_post_attn"] = norm_init((D,))
             layer["ln_post_mlp"] = norm_init((D,))
+        if cfg.layer_kind(li) == "retention":
+            # One gate a cached head, behind the attention layer's draws,
+            # and its bias (no draw: a head's time scale).
+            layer["w_gate_r"] = dense(next(keys), (D, kvH))
+            layer["b_gate_r"] = retention_gate_bias(kvH)
     if cfg.moe_layer(li):
         # Sparse MLP (models/moe.py): router + stacked expert weights,
         # ep/tp-shardable; DeepSeekMoE adds always-on shared experts
@@ -272,6 +278,18 @@ def init_layer_params(
         layer["ln_q_head"] = norm_init((hd,))
         layer["ln_k_head"] = norm_init((hd,))
     return layer
+
+
+def retention_gate_bias(kv_heads: int) -> jnp.ndarray:
+    """A retention layer's gate bias as seeded, float32 [kvH]: cached head
+    ``c`` sits at the multi-scale decay ``gamma_c = 1 - 2 ** -(5 + c % 8)``
+    of a retention network (arXiv:2307.08621, section 2.1), its logit
+    ``log(2 ** (5 + c % 8) - 1)``, so that a head's state remembers about
+    32, 64, .. 4,096 tokens and the gate's projection moves that by data.
+    Without it a seeded gate sits at sigmoid(0) and a state forgets a
+    token within a few: nothing carried from step to step would show."""
+    scale = 5 + jnp.arange(kv_heads) % 8
+    return jnp.log(2.0 ** scale.astype(jnp.float32) - 1.0)
 
 
 def _init_kda_mixer(keys, cfg: ModelConfig, dtype) -> Params:
@@ -546,6 +564,42 @@ def _kda_mixer(
     return qdot(o.reshape(T, H * d).astype(h.dtype), layer["wo"]), (S, tail)
 
 
+def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig, li,
+                      positions):
+    """A power-retention layer's (q, k, v, log gate) from its normed rows:
+    an attention layer's projections with per-head q/k norms and rotary
+    embedding, ``1 / sqrt(d)`` (the scale inside the power) folded into q,
+    and one gate a cached head (a projection and a bias) through
+    log-sigmoid, float32."""
+    q, k, v = _qkv(layer, h, cfg)
+    q, k = _rope_qk(cfg, li, q, k, positions)
+    q = q * jnp.asarray(cfg.head_dim ** -0.5, q.dtype)
+    lg = jax.nn.log_sigmoid(
+        qdot(h, layer["w_gate_r"]).astype(jnp.float32) + layer["b_gate_r"]
+    )
+    return q, k, v, lg
+
+
+def _retention_mixer(
+    layer: Params, h: jnp.ndarray, cfg: ModelConfig, state, meta,
+    state_slot, use_pallas: bool, *, li: int,
+):
+    """A power-retention layer's mixer over the flat ragged batch
+    (ops/power_retention.py): ``h`` [T, D] normed rows -> (y [T, D], the
+    layer's new state). ``state`` is the layer's (S, z) pair; ``meta`` the
+    dispatch's (token_seq, token_pos, q_start, q_len, row_start)."""
+    from dynamo_tpu.ops.power_retention import retention_ragged
+
+    T = h.shape[0]
+    q, k, v, lg = _retention_inputs(
+        layer, h, cfg, li, jnp.maximum(meta[1], 0)
+    )
+    y, state = retention_ragged(
+        q, k, v, lg, state, *meta, state_slot, use_pallas=use_pallas
+    )
+    return qdot(y.reshape(T, -1).astype(h.dtype), layer["wo"]), state
+
+
 def _to_cache(vals: jnp.ndarray, cache: jnp.ndarray) -> jnp.ndarray:
     """Cast (and lane-pad, when the cache head dim is padded for the
     Pallas kernels) K/V values for a cache scatter."""
@@ -584,7 +638,7 @@ def unified(
     verify_rows: int = 1,                  # static: logit rows per span
     embeds: jnp.ndarray | None = None,     # [T, D] soft-prompt overrides
     embed_mask: jnp.ndarray | None = None, # [T] bool — rows from embeds
-    rec_state: list | None = None,         # a (S, tail) pair a KDA layer
+    rec_state: list | None = None,         # its arrays a recurrent layer
     state_slot: jnp.ndarray | None = None, # [S] each span's state slot
 ):
     """ONE forward for a mixed prefill+decode token batch (the unified
@@ -625,9 +679,11 @@ def unified(
     layer writes and reads through its own group's
     (docs/architecture/cache_groups.md).
 
-    A model with linear-attention layers (``cfg.layer_kind``) takes
-    ``rec_state``, one (state, convolution tail) pair for each of them in
-    order, and ``state_slot``, and returns the new ``rec_state`` as its
+    A model with recurrent layers (``cfg.layer_kind``: "kda", a delta-rule
+    linear-attention layer; "retention", a power-retention layer) takes
+    ``rec_state``, the state arrays of each of them in order (``cfg.
+    recurrent_state_arrays``: a (state, convolution tail) pair; an (S, z)
+    pair), and ``state_slot``, and returns the new ``rec_state`` as its
     last result; those layers' entries of ``kv_caches`` are empty."""
     if attn is None:
         from dynamo_tpu.ops import attention as attn_ops
@@ -662,9 +718,15 @@ def unified(
         slots_of, tables_of = (slot_mapping,), (block_tables,)
     for li, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
         h = _ln(x, layer["ln_attn"], cfg)
-        if cfg.layer_kind(li) == "kda":
-            with jax.named_scope("kda_mixer"):
-                y, state = _kda_mixer(
+        kind = cfg.layer_kind(li)
+        if kind != "attn":
+            # A recurrent layer: its state in and out, no pages.
+            mixer = (
+                _kda_mixer if kind == "kda"
+                else partial(_retention_mixer, li=li)
+            )
+            with jax.named_scope(f"{kind}_mixer"):
+                y, state = mixer(
                     layer, h, cfg, rec_state[len(new_rec)],
                     (token_seq, token_pos, q_start, q_len, row_start),
                     state_slot, attn is not None and attn.use_pallas,
@@ -783,6 +845,14 @@ def hidden_states(
                 one, False,
             )
             x = x + y
+        elif cfg.layer_kind(li) == "retention":
+            # The attention form: no state at all.
+            from dynamo_tpu.ops.power_retention import retention_attention
+
+            y = retention_attention(
+                *_retention_inputs(layer, h, cfg, li, positions)
+            )
+            x = x + qdot(y.reshape(T, -1).astype(h.dtype), layer["wo"])
         elif cfg.is_mla:
             q, k, v = _qkv_mla(layer, h, cfg, positions)
             attn = full_causal_attention(q, k, v)
@@ -872,6 +942,12 @@ def load_hf_weights(
             raise NotImplementedError(
                 f"load_hf_weights: layer {i} is a KDA linear-attention "
                 "layer, whose checkpoint tensors are not mapped: "
+                + ", ".join(sorted(n for n in tensors if n.startswith(p + ".")))
+            )
+        if cfg.layer_kind(i) == "retention":
+            raise NotImplementedError(
+                f"load_hf_weights: layer {i} is a power-retention layer, "
+                "whose gate's checkpoint tensor is not mapped: "
                 + ", ".join(sorted(n for n in tensors if n.startswith(p + ".")))
             )
         if cfg.is_mla:

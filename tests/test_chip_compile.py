@@ -379,3 +379,105 @@ def test_command_a_share_step_compiles_at_published_widths(
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16.0e9
+
+
+@pytest.mark.slow  # many-threaded compiling beside the suite's timing-gated tests
+@pytest.mark.parametrize("L,NT,C", [(24, 32, 128), (24, 25, 16)])
+def test_retention_kernels_compile_at_published_widths(
+    mosaic, one_chip, monkeypatch, L, NT, C
+):
+    """``retention_recurrent`` over 24 lanes and ``retention_chunk`` over
+    the tiles of a 1,024-row (and a 16-row) budget at Brumby-14B's widths:
+    40 query heads over 8 cached heads of 128, a state of 65 x 128 x 128
+    float32 a (slot, cached head) over 21 slots, within the kernel's VMEM."""
+    from dynamo_tpu.ops import power_retention as pr
+    from dynamo_tpu.ops.pallas import retention as rk
+
+    monkeypatch.setattr(rk, "_interpret", lambda: False)
+    sds = partial(_sds, sharding=one_chip)
+    i32 = partial(sds, dtype=jnp.int32)
+    H_r, KVH_r, bf16, f32 = 40, 8, jnp.bfloat16, jnp.float32
+    S_shape, z_shape = pr.state_shapes(21, KVH_r, D)
+    assert S_shape[2] * D == 8320 and z_shape[2] == 72
+    state = (sds(S_shape, f32), sds(z_shape, f32))
+
+    def lanes(q, k, v, lg, S, z, slots, flags):
+        return rk.retention_recurrent(q, k, v, lg, (S, z), slots, flags)
+
+    def tiles(q, k, v, g, S, z, slots, flags, n):
+        return rk.retention_chunk(q, k, v, g, (S, z), slots, flags, n)
+
+    rec = jax.jit(lanes, donate_argnums=(4, 5)).lower(
+        sds((L, H_r, D), bf16), sds((L, KVH_r, D), bf16),
+        sds((L, KVH_r, D), bf16), sds((L, KVH_r), f32), *state,
+        i32((L,)), i32((L,)),
+    ).compile()
+    chunk = jax.jit(tiles, donate_argnums=(4, 5)).lower(
+        sds((NT, C, H_r, D), bf16), sds((NT, C, KVH_r, D), bf16),
+        sds((NT, C, KVH_r, D), bf16), sds((KVH_r, NT, C), f32), *state,
+        i32((NT,)), i32((NT,)), i32((NT,)),
+    ).compile()
+    for compiled in (rec, chunk):
+        assert _kernel_count(compiled.as_text()) == 1
+        mem = compiled.memory_analysis()
+        # the state is updated in place: no second table
+        assert mem.alias_size_in_bytes >= 21 * 8 * 65 * 128 * 128 * 4
+        assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.slow  # minutes of many-threaded compiling beside the suite's timing-gated tests
+def test_brumby_l8_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch
+):
+    """The whole of ``brumby-14b-l8`` (eight retention layers, the whole
+    vocabulary) in one unified step at T=1024 with the state table of 20
+    lanes and NO paged cache: two retention kernels a layer, no ragged
+    kernel, within one chip's memory."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.ops.pallas import retention as rk
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(rk, "_interpret", lambda: False)
+    cfg = ModelConfig.brumby_14b().scaled(num_layers=8)
+    ecfg = EngineConfig(
+        model=cfg, max_num_seqs=20, max_model_len=32768,
+        unified_token_budget=1024, unified_prefill_quantum=1024)
+    ecfg.validate()
+    assert ecfg.group_num_blocks == ()
+    sds = partial(_sds, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+    empty = sds((0, 8, 128), jnp.bfloat16)
+    kv = [(empty, empty)] * cfg.num_layers
+    rec = [
+        tuple(sds(shape, jnp.dtype(dt)) for shape, dt in
+              cfg.recurrent_state_arrays(li, 21, "bfloat16"))
+        for li in cfg.recurrent_layers
+    ]
+    i32 = partial(sds, dtype=jnp.int32)
+    T, rows = 1024, 24
+    meta = (
+        i32((T,)), i32((T,)), i32((T,)), i32((T,)), i32((rows, 1)),
+        i32((rows,)), i32((rows,)), i32((rows,)), i32((rows,)),
+    )
+
+    def step(params, kv, rec, slot, *meta):
+        logits, kv, rec = llama.unified(
+            cfg, params, kv, *meta, BS, attn=AttnDispatch(use_pallas=True),
+            rec_state=rec, state_slot=slot,
+        )
+        return jnp.argmax(logits, axis=-1), kv, rec
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, kv, rec, i32((rows,)), *meta
+    ).compile()
+    assert _kernel_count(compiled.as_text()) == 16
+    mem = compiled.memory_analysis()
+    # weights 8.40 GB and the state table 5.77 GB: all arguments, and the
+    # state aliases its output
+    assert 14.0e9 < mem.argument_size_in_bytes < 14.4e9
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.6e9

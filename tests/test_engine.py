@@ -738,6 +738,18 @@ async def test_a_retire_hands_its_tokens_over_in_one_wakeup():
         engine._loop = spy = SpyLoop(engine._loop)
         retires = watch_retires(engine, spy)
         prompts = [[1, 5, 9, 2], [3, 4, 6], [7, 8], [2, 2, 9, 1, 5]]
+        # All four are queued before the engine takes the first: one that a
+        # busy machine sent a dispatch late would decode in the pipeline's
+        # other phase, and no retire would carry all four.
+        drain, queued = engine._drain_submissions, engine._submit_q
+        all_four = []
+
+        def drain_once_all_four_wait():
+            if all_four or queued.qsize() >= len(prompts):
+                all_four.append(True)
+                drain()
+
+        engine._drain_submissions = drain_once_all_four_wait
         outs = await asyncio.gather(
             *[collect(engine, p, max_tokens=8) for p in prompts]
         )

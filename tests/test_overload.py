@@ -294,8 +294,9 @@ class _SlowEcho:
     """Engine stub: sleeps, then echoes — enough to hold admission slots
     and to observe deadline wire fields."""
 
-    def __init__(self, delay_s=0.0):
+    def __init__(self, delay_s=0.0, hold=None):
         self.delay_s = delay_s
+        self.hold = hold  # an asyncio.Event: answer only once it is set
         self.seen_deadlines: list = []
 
     async def generate(self, ctx):
@@ -304,6 +305,8 @@ class _SlowEcho:
         self.seen_deadlines.append(ctx.annotations.get("deadline"))
         if self.delay_s:
             await asyncio.sleep(self.delay_s)
+        if self.hold is not None:
+            await self.hold.wait()
         yield ChatCompletionChunk(
             id="c0", model="m",
             choices=[StreamChoice(
@@ -334,7 +337,10 @@ BODY = {
 
 
 async def test_http_admission_429_with_retry_after_and_drain_503():
-    engine = _SlowEcho(delay_s=0.5)
+    # The slow request holds the one slot until the 429 has been seen (no
+    # clock decides it: on a busy machine neither 0.1 s for its POST to
+    # arrive nor 0.5 s for the second to be refused was enough).
+    engine = _SlowEcho(hold=asyncio.Event())
     admission = AdmissionController(AdmissionConfig(max_inflight=1))
     service = await _http_service(engine, admission)
     base = f"http://127.0.0.1:{service.port}"
@@ -343,11 +349,16 @@ async def test_http_admission_429_with_retry_after_and_drain_503():
             slow = asyncio.ensure_future(
                 client.post(f"{base}/v1/chat/completions", json=BODY)
             )
-            await asyncio.sleep(0.1)  # slow request holds the one slot
+            for _ in range(3000):
+                if admission.inflight:
+                    break
+                await asyncio.sleep(0.01)
+            assert admission.inflight == 1
             r = await client.post(f"{base}/v1/chat/completions", json=BODY)
             assert r.status_code == 429
             assert "Retry-After" in r.headers
             assert r.json()["error"]["type"] == "overloaded_error"
+            engine.hold.set()
             assert (await slow).status_code == 200
 
             # Drain: health flips 503 first, new requests get 503 +
