@@ -95,6 +95,10 @@ class TpuEngine:
             cfg.model.layer_kind(cfg.model.recurrent_layers[0])
             if self._rec_on else ""
         )
+        #: what the delta rule's chunk kernel served: tiles, and the rows
+        #: in them (rows / (tiles x its tile) is how full the tiles run)
+        self._kda_chunk_tiles = 0
+        self._kda_chunk_rows = 0
         self._window_released_noted = 0
         #: The model keeps its cache by layer group: window and full
         #: layers in pools and tables of their own
@@ -1441,6 +1445,14 @@ class TpuEngine:
                 f"{kind}_prefill_rows": sum(r[3] for r in roles if r[3] > 1),
                 f"{kind}_fresh_spans": sum(r[2] == 0 for r in roles),
             })
+            if kind == "kda":
+                from dynamo_tpu.ops.pallas.kda import TILE
+
+                # A span of more rows is whole tiles of the chunk kernel.
+                tiles = sum(-(-r[3] // TILE) for r in roles if r[3] > 1)
+                note["kda_chunk_tiles"] = tiles
+                self._kda_chunk_tiles += tiles
+                self._kda_chunk_rows += note["kda_prefill_rows"]
         if self._grouped:
             note.update(self._group_note())
         return note
@@ -3035,6 +3047,8 @@ class TpuEngine:
                 len(sched.running) / max(self.cfg.max_num_seqs, 1)
                 if self._rec_on and sched is not None else 0.0
             ),
+            "kda_chunk_tiles_total": self._kda_chunk_tiles,
+            "kda_chunk_rows_total": self._kda_chunk_rows,
             "diffusion_passes_total": self._diffusion_passes,
             "diffusion_committed_tokens_total": self._diffusion_committed,
             "moe_grouped_rows_total": (

@@ -84,6 +84,7 @@ class FlightRecorder:
         kda_decode_lanes: int = 0,
         kda_prefill_rows: int = 0,
         kda_fresh_spans: int = 0,
+        kda_chunk_tiles: int = 0,
         retention_decode_lanes: int = 0,
         retention_prefill_rows: int = 0,
         retention_fresh_spans: int = 0,
@@ -124,7 +125,10 @@ class FlightRecorder:
         saw, by the kind of its recurrent layers (delta-rule linear
         attention; power retention): the lanes whose state advanced by
         one row, the prefill rows that went through the chunk path, and
-        the spans that started from zeros. The five ``kv_`` /
+        the spans that started from zeros; ``kda_chunk_tiles`` the tiles
+        of the delta rule's chunk kernel those rows filled (a span of more
+        rows is whole tiles of ``ops/pallas/kda.py`` ``TILE`` rows: rows /
+        (tiles x ``TILE``) is how full they run). The five ``kv_`` /
         ``context_`` fields are a model's that keeps its cache by layer
         group (docs/architecture/cache_groups.md): blocks in use in the
         full-attention and in the windowed pools as the step is noted,
@@ -159,6 +163,7 @@ class FlightRecorder:
             "kda_decode_lanes": kda_decode_lanes,
             "kda_prefill_rows": kda_prefill_rows,
             "kda_fresh_spans": kda_fresh_spans,
+            "kda_chunk_tiles": kda_chunk_tiles,
             "retention_decode_lanes": retention_decode_lanes,
             "retention_prefill_rows": retention_prefill_rows,
             "retention_fresh_spans": retention_fresh_spans,

@@ -217,6 +217,8 @@ class HttpService:
                 "recurrent_state_slots_in_use",
                 "recurrent_state_bytes",
                 "recurrent_state_usage_perc",
+                "kda_chunk_tiles_total",
+                "kda_chunk_rows_total",
                 # The cache by layer group (docs/architecture/
                 # cache_groups.md): each pool's share in use, blocks
                 # released behind a window, preemptions by the pool that

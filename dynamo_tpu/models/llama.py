@@ -557,7 +557,7 @@ def _kda_mixer(
     beta = jax.nn.sigmoid(qdot(h, layer["w_beta"]).astype(jnp.float32))
     o, S = kda_ragged(
         l2(q) * d**-0.5, l2(k), v, g, beta, S, *meta, state_slot,
-        use_pallas=use_pallas,
+        use_pallas=use_pallas, lower_bound=cfg.kda_lower_bound,
     )
     gate = jax.nn.sigmoid(qdot(h, layer["w_g"]).astype(jnp.float32))
     o = rms_norm(o, layer["ln_kda"], cfg.rms_eps) * gate[:, :, None]

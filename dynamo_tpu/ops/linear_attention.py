@@ -17,10 +17,12 @@ Per token ``t`` and head (``a_t = exp(g_t)`` the per-channel decay)::
 
 ``kda_ragged`` advances every span of a dispatch: spans of one row
 (decode lanes) through the ``kda_recurrent`` Pallas kernel, spans of more
-rows (prefill quanta) row by row through ``kda_chunk`` with the state
-held in VMEM across a span's rows (ops/pallas/kda.py); ``kda_ragged_xla``
-is the XLA twin of both, the path off the TPU. The state after a span does
-not depend on how the prompt was cut into spans.
+rows (prefill quanta) through ``kda_chunk``, the recurrence's chunkwise
+form in tiles of 64 rows as matrix products, with the state held in VMEM
+across a span's tiles (ops/pallas/kda.py); ``kda_ragged_xla`` is the XLA
+twin of both, the path off the TPU, and stays the recurrence row by row:
+it is what the kernels are held to. The state after a span does not
+depend on how the prompt was cut into spans.
 """
 
 from __future__ import annotations
@@ -119,43 +121,84 @@ def kda_ragged_xla(
     return o, state
 
 
+def span_tiles(q_len, T: int, C: int):
+    """The longer spans cut into tiles of ``C`` rows, a span's tiles in
+    order, spans in theirs: for each of ``min(T // 2, T // C + S)`` tiles
+    (static: a span of two rows or more fills a tile at least, and ``T``
+    rows hold no more) its span, its offset within the span and its rows
+    that belong to the span; and how many tiles are used (they come
+    first)."""
+    S = q_len.shape[0]
+    NT = max(1, min(T // 2, T // C + S))
+    n_tiles = jnp.where(q_len > 1, -(-q_len // C), 0)           # [S]
+    first = jnp.cumsum(n_tiles) - n_tiles                       # [S]
+    tile = jnp.arange(NT)
+    span = jnp.sum(tile[:, None] >= (first + n_tiles)[None, :], axis=1)
+    span = jnp.minimum(span, S - 1)
+    off = (tile - first[span]) * C
+    return span, off, jnp.clip(q_len[span] - off, 0, C), n_tiles.sum()
+
+
 def kda_ragged(
     q, k, v, g, beta, state, token_seq, token_pos, q_start, q_len,
-    row_start, state_slot, *, use_pallas: bool,
+    row_start, state_slot, *, use_pallas: bool, lower_bound: float,
 ):
-    """``kda_ragged_xla``'s contract; on the Pallas path spans of one row
-    go through ``kda_recurrent`` and spans of more through ``kda_chunk``."""
+    """``kda_ragged_xla``'s contract; on the Pallas path
+    ``kda_ragged_pallas`` over ops/pallas/kda.py. ``lower_bound`` is the
+    model's bound on a row's log decay (``g`` lies in ``(lower_bound,
+    0)``): a fact of the model that every caller states, since the chunk
+    kernel's sub-chunk follows from it and a wrong one is ``exp`` past
+    float32."""
+    operands = (
+        q, k, v, g, beta, state, token_seq, token_pos, q_start, q_len,
+        row_start, state_slot,
+    )
     if not use_pallas:
-        return kda_ragged_xla(
-            q, k, v, g, beta, state, token_seq, token_pos, q_start, q_len,
-            row_start, state_slot,
-        )
-    from dynamo_tpu.ops.pallas.kda import ACTIVE, FIRST, FRESH, kda_rows
+        return kda_ragged_xla(*operands)
+    from dynamo_tpu.ops.pallas import kda
 
-    T = q.shape[0]
-    j, owned = span_rows(token_seq, token_pos, q_start, q_len)
-    # The kernel's operands a row: the decay, k, beta * k, q, and beta * v.
+    return kda_ragged_pallas(kda, *operands, lower_bound=lower_bound)
+
+
+def kda_ragged_pallas(
+    kernels, q, k, v, g, beta, state, token_seq, token_pos, q_start, q_len,
+    row_start, state_slot, *, lower_bound: float,
+):
+    """Spans of one row through ``kernels.kda_rows`` (``kda_recurrent``)
+    and spans of more through ``kernels.kda_chunk``, the chunkwise form
+    (``kernels``: ops/pallas/kda.py; tools/kda_kernel_bench.py hands in
+    another checkout's). Spans lie in the flat batch in their order
+    (``row_start`` the running sum of ``q_len``, the runner's packing:
+    tests/test_ling.py pins it, the chunk kernel's output rows lean on
+    it)."""
+    kd, TILE = kernels, kernels.TILE
+    T, H, _ = q.shape
+    _, owned = span_rows(token_seq, token_pos, q_start, q_len)
+    # A row's operands: the log decay, k, beta * k, q, beta * v.
     b = beta[:, :, None]
-    x = jnp.concatenate([jnp.exp(g), k, b * k, q], axis=1)   # [T, 4H, d]
-    bv = b * v
-    fresh = FRESH * (q_start == 0)                           # [S]
+    x = jnp.concatenate([g, k, b * k, q, b * v], axis=1)     # [T, 5H, d]
+    fresh = kd.FRESH * (q_start == 0)                        # [S]
     # Decode lanes: one row a span, gathered by span.
     lane = q_len == 1
-    at = jnp.clip(row_start, 0, T - 1)
-    o_lane, state = kda_rows(
-        x[at], bv[at], state, jnp.where(lane, state_slot, 0),
-        jnp.where(lane, ACTIVE + fresh, 0), chunked=False,
-    )
-    # Prefill quanta: the flat rows of the longer spans, in order; a
-    # span's rows are one run of the grid, its state resident across it.
-    multi = owned & (q_len[token_seq] > 1)
-    o_rows, state = kda_rows(
-        x, bv, state, jnp.where(multi, state_slot[token_seq], 0),
-        jnp.where(
-            multi, ACTIVE + jnp.where(j == 0, FIRST + fresh[token_seq], 0), 0
+    x_lane = x[jnp.clip(row_start, 0, T - 1)]
+    o_lane, state = kd.kda_rows(
+        jnp.concatenate(
+            [jnp.exp(x_lane[:, :H]), x_lane[:, H : 4 * H]], axis=1
         ),
-        chunked=True,
+        x_lane[:, 4 * H :], state, jnp.where(lane, state_slot, 0),
+        jnp.where(lane, kd.ACTIVE + fresh, 0),
     )
+    # Prefill quanta: tiles of TILE rows read from the flat batch where
+    # they lie, a span's tiles consecutive on its state.
+    span, off, n, used = span_tiles(q_len, T, TILE)
+    o_rows, state = kd.kda_chunk(
+        x, state, state_slot[span],
+        jnp.where(off == 0, kd.FIRST + fresh[span], 0)
+        + jnp.where(off + TILE >= q_len[span], kd.LAST, 0),
+        row_start[span] + off, n, used,
+        sub=kd.sub_chunk(lower_bound, TILE),
+    )
+    multi = owned & (q_len[token_seq] > 1)
     o = jnp.where(
         multi[:, None, None], o_rows,
         jnp.where((owned & ~multi)[:, None, None], o_lane[token_seq], 0.0),

@@ -373,6 +373,11 @@ def test_state_arrays_by_kind_are_said_in_one_place():
 
 def test_named_scope_and_kernel_names_mark_the_layer(monkeypatch):
     monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    # jit keeps an inner function's jaxpr with the call stack of whoever
+    # traced it first: without this, frames of another file's test that
+    # ran before in this process (ragged_paged_attention_pallas) ride in
+    # this program's debug locations
+    jax.clear_caches()
     runner = ModelRunner(engine_config(), rng_seed=SEED)
     text = runner.lower_unified_top().as_text(debug_info=True)
     for name in ("retention_mixer", "retention_recurrent", "retention_chunk"):

@@ -324,6 +324,37 @@ def test_ling_share_step_compiles_at_published_widths(
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
 
 
+@pytest.mark.parametrize("T", [256, 16])
+def test_kda_kernels_compile_at_published_widths(
+    mosaic, one_chip, monkeypatch, T
+):
+    """The delta rule over one dispatch at Ling-3.0-flash's widths (32
+    heads of 128 x 128 float32, a state table of 129 slots): the one-row
+    lanes' kernel and the chunk kernel (a tile's strided reads of the flat
+    rows, its batched products at float32 contract precision, the state
+    and two tiles' rows within VMEM), at the top rung and at the lowest."""
+    from dynamo_tpu.ops import linear_attention as la
+    from dynamo_tpu.ops.pallas import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    sds = partial(_sds, sharding=one_chip)
+    i32, f32 = partial(sds, dtype=jnp.int32), jnp.float32
+    lanes, rows = 129, 132
+    compiled = jax.jit(
+        partial(la.kda_ragged, use_pallas=True, lower_bound=-5.0),
+        donate_argnums=(5,),
+    ).lower(
+        *[sds((T, 32, 128), f32)] * 4, sds((T, 32), f32),
+        sds((lanes, 32, 128, 128), f32), i32((T,)), i32((T,)),
+        *[i32((rows,))] * 4,
+    ).compile()
+    assert _kernel_count(compiled.as_text()) == 2
+    mem = compiled.memory_analysis()
+    # the state is updated in place by both kernels: no second table
+    assert mem.alias_size_in_bytes >= lanes * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 0.2e9, mem.temp_size_in_bytes
+
+
 @pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
 def test_command_a_share_step_compiles_at_published_widths(
     mosaic, one_chip, monkeypatch

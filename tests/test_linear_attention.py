@@ -63,7 +63,8 @@ def run(path, seqs, plan, T=16, S_rows=6, state=None):
             at += n
         meta = dispatch([(b + 1, start, n) for b, start, n in spans], T, S_rows)
         o, state = la.kda_ragged(
-            *map(jnp.asarray, flat), state, *meta, use_pallas=path == "pallas")
+            *map(jnp.asarray, flat), state, *meta, use_pallas=path == "pallas",
+            lower_bound=-5.0)
         at = 0
         for b, start, n in spans:
             outs[b].append(np.asarray(o[at : at + n]))
@@ -134,3 +135,67 @@ def test_causal_conv_continues_from_the_tail():
     np.testing.assert_allclose(np.concatenate(got), want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(tail[2]), x[-3:], rtol=0, atol=0)
     assert (np.asarray(tail[3]) == 9.0).all()
+
+
+def _prompt(seed, n, g=None, beta=None):
+    q, k, v, g0, b0 = draw(seed, n)
+    if g is not None:
+        g0 = np.full_like(g0, g)
+    if beta is not None:
+        b0 = np.full_like(b0, beta)
+    return q, k, v, g0, b0
+
+
+def _cuts(cut):
+    plan, at = [], 0
+    for n in cut:
+        plan.append([(0, at, n)])
+        at += n
+    return plan
+
+
+# (sequences, plan): the chunk kernel's tile is 64 rows and, at the bound of
+# -5 the glue assumes, its sub-chunk 16.
+CHUNK_CASES = {
+    **{
+        f"span-{n}": ([_prompt(10 + n, 3 + n)], _cuts([3, n]))
+        for n in (2, 15, 16, 17, 63, 64, 65, 130)
+    },
+    "cut-at-edges": ([_prompt(30, 150)], _cuts([16, 48, 64, 22])),
+    "cut-across-edges": ([_prompt(31, 150)], _cuts([17, 46, 66, 21])),
+    "cut-in-tiles": ([_prompt(32, 150)], _cuts([128, 22])),
+    "g-at-the-bound": ([_prompt(33, 70, g=-5.0)], _cuts([3, 64, 3])),
+    "g-zero": ([_prompt(34, 70, g=0.0)], _cuts([3, 64, 3])),
+    "beta-zero": ([_prompt(35, 40, beta=0.0)], _cuts([2, 38])),
+    "beta-one": ([_prompt(36, 40, beta=1.0)], _cuts([2, 38])),
+    "three-spans-beside-lanes": (
+        [_prompt(37, 80), _prompt(38, 30), _prompt(39, 10), _prompt(40, 4)],
+        [
+            [(0, 0, 10), (1, 0, 1), (3, 0, 2)],
+            # three multi-row spans, the first over a tile, beside a lane
+            [(0, 10, 70), (1, 1, 20), (2, 0, 9), (3, 2, 1)],
+            [(3, 3, 1), (1, 21, 9), (2, 9, 1)],
+        ],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_chunk_kernel_equals_the_naive_recurrence(case):
+    """The chunkwise form (interpret mode) against the recurrence in
+    float64: spans around the tile's and the sub-chunk's edges, a prompt
+    cut at and across them, the decay at its bound and absent, beta at both
+    ends, several spans beside decode lanes. The trash slot and a slot no
+    sequence owns keep what they held."""
+    seqs, plan = CHUNK_CASES[case]
+    held = jnp.full((SLOTS, H, D, D), 7.0, jnp.float32)
+    outs, state = run("pallas", seqs, plan, T=136, state=held)
+    assert np.isfinite(state).all()
+    for b, seq in enumerate(seqs):
+        want_o, want_s = naive(*seq, np.zeros((H, D, D)))
+        assert np.isfinite(outs[b]).all()
+        np.testing.assert_allclose(outs[b], want_o, rtol=5e-5, atol=5e-5)
+        np.testing.assert_allclose(state[b + 1], want_s, rtol=5e-5, atol=5e-5)
+    for slot in range(len(seqs) + 1, SLOTS):
+        assert np.array_equal(state[slot], held[slot])
+    assert np.array_equal(state[0], held[0])

@@ -102,20 +102,31 @@ def follows_the_reference(prompt, got, held: int = 0) -> None:
 
 # -- the served path against the reference -------------------------------
 
-@pytest.mark.parametrize("held,pallas", [(0, "0"), (8, "0"), (0, "1")])
+@pytest.mark.parametrize("held,pallas,lens,budget", [
+    (0, "0", (5, 37, 50), 32), (8, "0", (5, 37, 50), 32),
+    (0, "1", (5, 37, 50), 32), (0, "1", (70, 150), 128),
+])
 def test_runner_logits_equal_the_references_forward_pass(
-        monkeypatch, held, pallas):
+        monkeypatch, held, pallas, lens, budget):
     """Chunked prefill (prompts cut across dispatches, quanta beside decode
     lanes), then six decode steps, through the paged cache and the state
     table, by the benchmark's own step driver: logits against the
     reference's one full pass. Every expert held (the grouped path), a
-    quarter of them (the dense path), and the Pallas kernels interpreted."""
+    quarter of them (the dense path), and the Pallas kernels interpreted;
+    the longer prompts go in spans of 70, 58 and 92 rows, across the chunk
+    kernel's tile of 64 and its sub-chunks of 16."""
     monkeypatch.setenv("DYNAMO_TPU_PALLAS", pallas)
+    # the three older cases run on the default engine_config(); the longer
+    # prompts need a wider budget and a longer model length
+    longer = {} if budget == 32 else dict(
+        unified_token_budget=budget, unified_prefill_quantum=budget // 2,
+        max_model_len=160)
     runner = ModelRunner(
-        engine_config(ModelConfig.tiny_ling_test(held=held)), rng_seed=SEED)
+        engine_config(ModelConfig.tiny_ling_test(held=held), **longer),
+        rng_seed=SEED)
     assert runner.attention_path == ("pallas" if pallas == "1" else "xla")
-    lens = (5, 37, 50)
-    tokens = check.sample_tokens(11, 384, [n + 6 for n in lens], 64)
+    pad = 64 * -(-(max(lens) + 6) // 64)
+    tokens = check.sample_tokens(11, 384, [n + 6 for n in lens], pad)
     out = recurrent_span.drive(runner, tokens, lens, 6, 11)
     assert runner.rec_state is None          # the driver gave it back
     assert out["decode"].sum() >= 6 * len(lens)
@@ -124,6 +135,36 @@ def test_runner_logits_equal_the_references_forward_pass(
                       out["judged"])
     assert v["rel_err"] < 2e-4, v
     assert v["token_mismatches"] == 0
+
+
+def test_spans_lie_in_the_flat_batch_in_their_order():
+    """What ``kda_chunk`` leans on (ops/linear_attention.py ``kda_ragged``):
+    the runner packs a dispatch's spans into the flat batch in span order
+    with no gap, ``row_start`` the running sum of ``q_len``. A tile writes
+    64 rows from its first and the rows past its span's end are written
+    again by the tile that owns them: a packer that reorders spans has to
+    change the kernel's output path with it."""
+    from dynamo_tpu.engine.runner import META_SEGMENTS
+
+    runner = ModelRunner(engine_config(), rng_seed=SEED)
+    greedy = (0.0, 0, 1.0)
+    # a lane, a quantum, a lane, two quanta: lanes and quanta interleaved
+    spans = [(40, 1), (0, 9), (17, 1), (5, 16), (3, 2)]
+    lanes = [(list(range(n)), [1 + s], prefix, greedy)
+             for s, (prefix, n) in enumerate(spans)]
+    _base, meta, *_ = runner._unified_operands(lanes, None, 32)
+    m = {name: np.asarray(a) for name, a in zip(META_SEGMENTS, meta)}
+    q_len = np.array([n for _, n in spans])
+    want = np.cumsum(q_len) - q_len
+    assert np.array_equal(m["q_len"][: len(spans)], q_len)
+    assert np.array_equal(m["row_start"][: len(spans)], want)
+    owned = q_len.sum()
+    assert np.array_equal(m["token_seq"][:owned], np.repeat(
+        np.arange(len(spans)), q_len))
+    assert np.array_equal(m["token_pos"][:owned], np.concatenate(
+        [prefix + np.arange(n) for prefix, n in spans]))
+    assert (m["token_pos"][owned:] < 0).all() and not m["q_len"][
+        len(spans):].any()
 
 
 async def test_engine_serves_the_references_tokens_and_counts_its_state():
@@ -148,6 +189,13 @@ async def test_engine_serves_the_references_tokens_and_counts_its_state():
         assert sum(r["kda_prefill_rows"] + r["kda_decode_lanes"]
                    for r in steps) == sum(
             r["decode_tokens"] + r["prefill_tokens"] for r in steps)
+        # the chunk kernel's tiles, by hand: a span of more rows than one
+        # is whole tiles of 64 rows; this engine's quantum is 16 rows, so
+        # every such span is one tile
+        assert all(
+            r["kda_chunk_tiles"] == r["lanes"] - r["kda_decode_lanes"]
+            for r in steps
+        )
         # every expert is held: each row of the (padded) budget lands 4
         # times in each of 6 layers
         assert all(r["moe_rows_held"] % 24 == 0 and r["moe_rows_held"] >= 24 * (
@@ -157,6 +205,15 @@ async def test_engine_serves_the_references_tokens_and_counts_its_state():
         assert snap["recurrent_state_bytes"] == 7 * 5 * (
             4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4)
         assert snap["recurrent_state_slots_in_use"] == 0
+        assert snap["kda_chunk_tiles_total"] == sum(
+            r["kda_chunk_tiles"] for r in steps) > 0
+        assert snap["kda_chunk_rows_total"] == sum(
+            r["kda_prefill_rows"] for r in steps)
+        # spans of 130, 64, 65 and 2 rows beside a lane: 3 + 1 + 2 + 1
+        note = engine._plain_note(
+            [(None, None, at, n) for at, n in
+             ((0, 130), (7, 64), (0, 65), (3, 2), (9, 1))], 1, 261, 0.0, (0, 0))
+        assert (note["kda_chunk_tiles"], note["kda_prefill_rows"]) == (7, 261)
         assert snap["attention_path"] == "xla"
     finally:
         await engine.stop()
