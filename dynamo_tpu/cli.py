@@ -1029,9 +1029,15 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             n = await engine.warmup()
             cs = engine.runner.compile_stats
             tail = engine.warm_tail_pending
+            phases = ", ".join(
+                f"{phase} {secs:.1f}s"
+                for phase, secs in cs.warm_phase_s.items()
+            )
+            traces, calls = cs.layer_body()
             print(
                 f"warmup: {n} programs in {time.monotonic() - t0:.1f}s "
-                f"({cs.replayed_programs} already in the ledger"
+                f"({phases}; layer body traced {traces}x for {calls} "
+                f"calls; {cs.replayed_programs} already in the ledger"
                 + (f", {tail} deferred to background" if tail else "")
                 + ") — engine ready",
                 flush=True,

@@ -31,6 +31,7 @@ import hashlib
 import json
 import logging
 import os
+import sys
 import time
 from contextlib import contextmanager
 from typing import Any, Callable
@@ -402,6 +403,49 @@ class ShapeManifest:
 # ---------------------------------------------------------------------------
 
 
+#: jax's own duration events of a program's way to the device (jax/_src/
+#: dispatch.py), by the phase a start's summary names. "backend" is XLA's
+#: compile or, where the persistent cache holds the program, its read.
+JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "tracing",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowering",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
+@contextmanager
+def jax_phase_seconds(into: dict):
+    """Add to ``into[phase]`` the seconds jax spent in each of ``JAX_PHASES``
+    while the block ran (``jax.monitoring`` time spans, any thread's). A
+    jitted function traced inside another's trace reports a span of its
+    own inside the outer one, so a phase's seconds are the UNION of its
+    spans, not their sum. A process that never imported jax (the mocker's
+    runner) compiles nothing, and nothing is listened for."""
+    if "jax" not in sys.modules:
+        yield
+        return
+    import jax.monitoring
+
+    spans: dict[str, list] = {phase: [] for phase in JAX_PHASES.values()}
+
+    def on_span(event, start, end, **_kw):
+        phase = JAX_PHASES.get(event)
+        if phase is not None:
+            spans[phase].append((start, end))
+
+    jax.monitoring.register_event_time_span_listener(on_span)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(on_span)
+        for phase, seen in spans.items():
+            total, upto = 0.0, float("-inf")
+            for start, end in sorted(seen):
+                total += max(0.0, end - max(start, upto))
+                upto = max(upto, end)
+            into[phase] = into.get(phase, 0.0) + total
+
+
 class CompileStats:
     """Times the first execution of every program shape.
 
@@ -429,6 +473,9 @@ class CompileStats:
         self.mid_traffic_keys: list[str] = []
         self.compile_stall_ms_total = 0.0
         self.last_compile_stall_ms = 0.0
+        #: Where the warm-ups' seconds went, by jax's own account
+        #: (``jax_phase_seconds``; written by ``run_warm_ops``).
+        self.warm_phase_s = {phase: 0.0 for phase in JAX_PHASES.values()}
 
     @contextmanager
     def observe(
@@ -473,9 +520,26 @@ class CompileStats:
         if self.cache is not None:
             self.cache.note(key)
 
+    def layer_body(self) -> tuple[int, int]:
+        """(traces, calls) of the served model's layer body in this process
+        (models/llama.py ``LAYER_BODY``): 1 / 16 a program of a 16-layer
+        model whose layers are one program. (0, 0) where no model function
+        was imported (the mocker's runner)."""
+        llama = sys.modules.get("dynamo_tpu.models.llama")
+        if llama is None:
+            return 0, 0
+        return llama.LAYER_BODY["traces"], llama.LAYER_BODY["calls"]
+
     def snapshot(self) -> dict:
+        traces, calls = self.layer_body()
         with self._lock:
             return {
+                "layer_body_traces_total": traces,
+                "layer_body_calls_total": calls,
+                **{
+                    f"warmup_{phase}_seconds_total": round(secs, 3)
+                    for phase, secs in self.warm_phase_s.items()
+                },
                 "mid_traffic_compiles_total": self.mid_traffic_compiles,
                 "compile_stall_ms_total": round(
                     self.compile_stall_ms_total, 1
@@ -593,8 +657,9 @@ class WarmupPlanMixin:
         cs = self.compile_stats
         cs.warming = True
         try:
-            for _key, fn in ops:
-                fn()
+            with jax_phase_seconds(cs.warm_phase_s):
+                for _key, fn in ops:
+                    fn()
         finally:
             cs.warming = False
             if cs.cache is not None:

@@ -19,6 +19,26 @@ def _rope_scaling(d):
 
 
 @dataclass(frozen=True)
+class LayerSpec:
+    """What ``ModelConfig`` answers by layer index, as ONE hashable value
+    (``ModelConfig.layer_spec``): the static operand of the served model's
+    layer body (models/llama.py ``_layer``, ``_layer_rows``). Two layers are one traced and
+    lowered program exactly when their specs and their operands' shapes are
+    equal, so a fact that differs by layer and is not here would hand one
+    layer another's behaviour in silence: every method of ``ModelConfig``
+    that takes a layer index has its field (tests/test_layer_spec.py fails
+    by name on one that has not), and no index reaches the body."""
+
+    kind: str            # layer_kind: "attn" | "kda" | "retention"
+    window: int          # layer_window: 0 = full attention
+    cache_group: int     # layer_cache_group: whose slots and block table
+    rope: "tuple | str"  # layer_rope: (theta, scaling) or "none"
+    moe: bool            # moe_layer: routed experts, not a dense MLP
+    swiglu_limit: float  # swiglu_limit: the routed experts' clamp
+    shared_swiglu_limit: float  # swiglu_limit(shared=True)
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str = "llama"
     vocab_size: int = 32000
@@ -288,6 +308,31 @@ class ModelConfig:
             return self.sliding_window
         return 0
 
+    def layer_rope(self, layer_idx: int):
+        """(theta, scaling) of one layer's rotary embedding, or "none":
+        Gemma-3 runs its windowed (local) layers on rope_local_theta with NO
+        position scaling; global layers keep rope_theta + rope_scaling (HF
+        Gemma3 rope_local_base_freq). A full-attention layer of a model with
+        ``nope_full_layers`` (Command A+) takes no rotary embedding at all."""
+        window = self.layer_window(layer_idx)
+        if self.nope_full_layers and not window:
+            return "none"
+        if self.rope_local_theta and window:
+            return self.rope_local_theta, None
+        return self.rope_theta, self.rope_scaling
+
+    def layer_spec(self, layer_idx: int) -> LayerSpec:
+        """Every per-layer fact of one layer (``LayerSpec``)."""
+        return LayerSpec(
+            kind=self.layer_kind(layer_idx),
+            window=self.layer_window(layer_idx),
+            cache_group=self.layer_cache_group(layer_idx),
+            rope=self.layer_rope(layer_idx),
+            moe=self.moe_layer(layer_idx),
+            swiglu_limit=self.swiglu_limit(layer_idx),
+            shared_swiglu_limit=self.swiglu_limit(layer_idx, shared=True),
+        )
+
     @property
     def cache_groups(self) -> tuple:
         """The paged cache by layer group (docs/architecture/
@@ -311,7 +356,7 @@ class ModelConfig:
     def layer_cache_group(self, layer_idx: int) -> int:
         """Which of ``cache_groups`` a layer's keys and values live in."""
         groups = self.cache_groups
-        if len(groups) == 1:
+        if len(groups) <= 1:  # one pool, or none at all (group 0 of none)
             return 0
         return groups.index(self.layer_window(layer_idx))
 

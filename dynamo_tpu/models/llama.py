@@ -21,7 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import LayerSpec, ModelConfig
 from dynamo_tpu.ops.attention import AttnDispatch, full_causal_attention
 from dynamo_tpu.ops.norms import layer_norm, rms_norm
 from dynamo_tpu.ops.quant import (
@@ -126,25 +126,11 @@ def _ln(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     return rms_norm(x, w, cfg.rms_eps)
 
 
-def _layer_rope(cfg: ModelConfig, li: int):
-    """(theta, scaling) for layer li, or "none": Gemma-3 runs its windowed
-    (local) layers on rope_local_theta with NO position scaling; global
-    layers keep rope_theta + rope_scaling (HF Gemma3 rope_local_base_freq).
-    A full-attention layer of a model with ``nope_full_layers`` (Command
-    A+) takes no rotary embedding at all."""
-    if cfg.nope_full_layers and not cfg.layer_window(li):
-        return "none"
-    if cfg.rope_local_theta and cfg.layer_window(li):
-        return cfg.rope_local_theta, None
-    return cfg.rope_theta, cfg.rope_scaling
-
-
-def _rope_qk(cfg: ModelConfig, li: int, q, k, positions):
-    """q and k under layer li's rotary embedding (``_layer_rope``)."""
-    rope = _layer_rope(cfg, li)
-    if rope == "none":
+def _rope_qk(cfg: ModelConfig, spec: LayerSpec, q, k, positions):
+    """q and k under the layer's rotary embedding (``spec.rope``)."""
+    if spec.rope == "none":
         return q, k
-    th, sc = rope
+    th, sc = spec.rope
     return (
         apply_rope(q, positions, th, sc, cfg.rope_interleaved),
         apply_rope(k, positions, th, sc, cfg.rope_interleaved),
@@ -169,7 +155,7 @@ def _residual_attn(x, layer, attn_out, cfg: ModelConfig):
 
 
 def _residual_mlp(
-    x, layer, cfg: ModelConfig, mesh=None, li: int = 0, valid=None, h=None
+    x, layer, cfg: ModelConfig, spec: LayerSpec, mesh=None, valid=None, h=None
 ):
     """Pre-norm → gated MLP → (optional post-norm) → residual add.
     ``valid`` marks the rows that hold a token (an expert share's). A
@@ -178,7 +164,7 @@ def _residual_mlp(
     the attention, not behind it, and the layer has no second norm."""
     if h is None:
         h = _ln(x, layer["ln_mlp"], cfg)
-    m = _mlp(layer, h, cfg, mesh, li, valid)
+    m = _mlp(layer, h, cfg, spec, mesh, valid)
     if cfg.post_norms:
         m = _ln(m, layer["ln_post_mlp"], cfg)
     return x + m
@@ -467,21 +453,21 @@ def _swiglu(
 
 
 def _mlp(
-    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None, li: int = 0,
-    valid=None,
+    layer: Params, x: jnp.ndarray, cfg: ModelConfig, spec: LayerSpec,
+    mesh=None, valid=None,
 ) -> jnp.ndarray:
     # Structure-driven: a router in the layer means routed experts (MoE
     # models may keep their first_k_dense_replace layers dense). `mesh`
     # (from the AttnDispatch) places the grouped expert path's products
     # per shard (models/moe.py _moe_mlp_grouped).
     if "w_router" in layer:
-        return _moe_mlp(layer, x, cfg, mesh, li, valid)
+        return _moe_mlp(layer, x, cfg, spec, mesh, valid)
     return _swiglu(layer, x, act=cfg.hidden_act)
 
 
 def _moe_mlp(
-    layer: Params, x: jnp.ndarray, cfg: ModelConfig, mesh=None, li: int = 0,
-    valid=None,
+    layer: Params, x: jnp.ndarray, cfg: ModelConfig, spec: LayerSpec,
+    mesh=None, valid=None,
 ) -> jnp.ndarray:
     """Top-k routed expert MLP over arbitrary leading dims (models/moe.py:
     dense einsums below 16 experts, the grouped path from there up;
@@ -501,7 +487,7 @@ def _moe_mlp(
         topk_group=cfg.topk_group,
         num_experts_held=cfg.num_experts_held,
         expert_held_offset=cfg.expert_held_offset,
-        swiglu_limit=cfg.swiglu_limit(li),
+        swiglu_limit=spec.swiglu_limit,
     )
     lead = x.shape[:-1]
     flat = x.reshape(-1, cfg.hidden_size)
@@ -511,7 +497,7 @@ def _moe_mlp(
             with jax.named_scope("shared_experts"):
                 shared = _swiglu(
                     layer, flat, prefix="w_shared_",
-                    limit=cfg.swiglu_limit(li, shared=True),
+                    limit=spec.shared_swiglu_limit,
                 )
             if cfg.shared_experts_average:
                 # The stacked shared experts' product IS their sum.
@@ -564,15 +550,15 @@ def _kda_mixer(
     return qdot(o.reshape(T, H * d).astype(h.dtype), layer["wo"]), (S, tail)
 
 
-def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig, li,
-                      positions):
+def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig,
+                      spec: LayerSpec, positions):
     """A power-retention layer's (q, k, v, log gate) from its normed rows:
     an attention layer's projections with per-head q/k norms and rotary
     embedding, ``1 / sqrt(d)`` (the scale inside the power) folded into q,
     and one gate a cached head (a projection and a bias) through
     log-sigmoid, float32."""
     q, k, v = _qkv(layer, h, cfg)
-    q, k = _rope_qk(cfg, li, q, k, positions)
+    q, k = _rope_qk(cfg, spec, q, k, positions)
     q = q * jnp.asarray(cfg.head_dim ** -0.5, q.dtype)
     lg = jax.nn.log_sigmoid(
         qdot(h, layer["w_gate_r"]).astype(jnp.float32) + layer["b_gate_r"]
@@ -582,7 +568,7 @@ def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig, li,
 
 def _retention_mixer(
     layer: Params, h: jnp.ndarray, cfg: ModelConfig, state, meta,
-    state_slot, use_pallas: bool, *, li: int,
+    state_slot, use_pallas: bool, *, spec: LayerSpec,
 ):
     """A power-retention layer's mixer over the flat ragged batch
     (ops/power_retention.py): ``h`` [T, D] normed rows -> (y [T, D], the
@@ -592,7 +578,7 @@ def _retention_mixer(
 
     T = h.shape[0]
     q, k, v, lg = _retention_inputs(
-        layer, h, cfg, li, jnp.maximum(meta[1], 0)
+        layer, h, cfg, spec, jnp.maximum(meta[1], 0)
     )
     y, state = retention_ragged(
         q, k, v, lg, state, *meta, state_slot, use_pallas=use_pallas
@@ -616,6 +602,138 @@ def _logits(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     else:
         logits = qdot(h, params["lm_head"]).astype(jnp.float32)
     return logits * cfg.logit_scale if cfg.logit_scale != 1.0 else logits
+
+
+#: How often this process traced the layer body, and how often a traced
+#: model function called it (once a layer): 1 / 16 a program of a 16-layer
+#: model whose layers share one spec (``CompileStats.snapshot``:
+#: ``layer_body_traces_total`` / ``layer_body_calls_total``). The process's,
+#: as jit's cache is: a second runner of the same model traces nothing.
+LAYER_BODY = {"traces": 0, "calls": 0}
+
+
+def _layer(
+    cfg: ModelConfig, spec: LayerSpec, block_size: int,
+    attn: AttnDispatch | None, pallas: bool, *operands,
+):
+    """ONE layer of ``unified`` (``_layer_rows`` over ``operands``). Every
+    layer of every trace calls it through ``_layer_body``, the one
+    ``jax.jit`` of it, so a program traces and lowers it once a DISTINCT
+    layer and not once a layer (docs/architecture/unified_step.md "One
+    traced body a layer spec"). Static: the model, the layer's ``spec`` (NO
+    layer index: what differs by layer is in the spec or in the operands'
+    shapes, and jit's cache decides which layers are one program), the
+    block size, the runner's dispatch, and ``pallas`` (``pallas_enabled()``:
+    what the trace below reads of the process, here so that jit's cache
+    sees it). Returns ``_layer_rows``' results and the grouped expert
+    path's traced counts: they cannot leave this trace but as results
+    (models/moe.py ``note_experts_hit``)."""
+    from dynamo_tpu.models.moe import collect_experts_hit
+
+    LAYER_BODY["traces"] += 1
+    with collect_experts_hit() as hit:
+        out = _layer_rows(cfg, spec, block_size, attn, *operands)
+    return *out, hit.results()
+
+
+def _layer_rows(
+    cfg: ModelConfig, spec: LayerSpec, block_size: int,
+    attn: AttnDispatch | None,
+    layer: Params, cache, kv_scale, state, x, meta, slot_mapping,
+    block_tables, state_slot,
+):
+    """A layer's operations: norm, the mixer by kind, the cache write, the
+    attention call, the output product, the MLP. ``cache`` is the layer's
+    (k, v) pages, with ``kv_scale`` [2, num_blocks, kvH] where they are
+    int8; ``state`` a recurrent layer's arrays; ``meta`` the step's
+    (token_seq, token_pos, q_start, q_len, kv_len, row_start);
+    ``slot_mapping`` and ``block_tables`` its cache group's. Returns (x,
+    cache, kv_scale, state)."""
+    token_seq, token_pos, q_start, q_len, kv_len, row_start = meta
+    mesh = attn.mesh if attn is not None else None
+    T = x.shape[0]
+    # An expert share drops the budget's padding rows with the rows routed
+    # elsewhere; a model whose experts are all here computes every row.
+    valid = token_pos >= 0 if cfg.num_experts_held else None
+    h = _ln(x, layer["ln_attn"], cfg)
+    if spec.kind != "attn":
+        # A recurrent layer: its state in and out, no pages.
+        mixer = (
+            _kda_mixer if spec.kind == "kda"
+            else partial(_retention_mixer, spec=spec)
+        )
+        with jax.named_scope(f"{spec.kind}_mixer"):
+            y, state = mixer(
+                layer, h, cfg, state,
+                (token_seq, token_pos, q_start, q_len, row_start),
+                state_slot, attn is not None and attn.use_pallas,
+            )
+        x = _residual_mlp(x + y, layer, cfg, spec, mesh, valid)
+        return x, cache, kv_scale, state
+    k_cache, v_cache = cache
+    positions = jnp.maximum(token_pos, 0)
+    if cfg.is_mla:
+        with jax.named_scope("latent_mixer"):
+            q, k, v = _qkv_mla(layer, h, cfg, positions)
+    else:
+        q, k, v = _qkv(layer, h, cfg)
+        q, k = _rope_qk(cfg, spec, q, k, positions)
+    if kv_scale is not None:
+        from dynamo_tpu.ops.quant import quantize_kv_write
+
+        pad = k_cache.shape[-1] - k.shape[-1]
+        if pad:  # lane-padded cache (Pallas head-dim contract)
+            widen = ((0, 0),) * (k.ndim - 1) + ((0, pad),)
+            k, v = jnp.pad(k, widen), jnp.pad(v, widen)
+        k_cache, k_sc = quantize_kv_write(
+            k_cache, kv_scale[0], slot_mapping, k, block_size
+        )
+        v_cache, v_sc = quantize_kv_write(
+            v_cache, kv_scale[1], slot_mapping, v, block_size
+        )
+        kv_scale = jnp.stack([k_sc, v_sc])
+        scale_kw = {"k_scales": k_sc, "v_scales": v_sc}
+    else:
+        k_cache = k_cache.at[slot_mapping].set(_to_cache(k, k_cache))
+        v_cache = v_cache.at[slot_mapping].set(_to_cache(v, v_cache))
+        scale_kw = {}
+    if attn is None:
+        from dynamo_tpu.ops.attention import ragged_attention as ragged_fn
+    else:
+        ragged_fn = attn.ragged
+    # A block-diffusion model masks by block; every other keeps the
+    # causal call it had.
+    block_kw = (
+        {"diffusion_block": cfg.diffusion_block_length}
+        if cfg.diffusion_block_length > 1 else {}
+    )
+    with jax.named_scope("attn_window" if spec.window else "attn_full"):
+        attn_out = ragged_fn(
+            q, k_cache, v_cache, block_tables, token_seq, token_pos,
+            q_start, q_len, kv_len, row_start, block_size,
+            window=spec.window, **scale_kw, **block_kw,
+        )
+    cache = (k_cache, v_cache)
+    if cfg.parallel_block:
+        # x + attention(h) + ffn(h): both branches read the ONE norm.
+        a = qdot(attn_out.reshape(T, -1), layer["wo"])
+        x = _residual_mlp(x, layer, cfg, spec, mesh, valid, h=h) + a
+        return x, cache, kv_scale, state
+    if cfg.is_mla:
+        with jax.named_scope("latent_mixer"):
+            x = x + _mla_out(layer, attn_out, cfg)
+    else:
+        x = _residual_attn(
+            x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg
+        )
+    x = _residual_mlp(x, layer, cfg, spec, mesh, valid)
+    return x, cache, kv_scale, state
+
+
+# dynalint: allow[DT016] no program of its own on the serving path: every served call is inside a budget-ladder program's trace (engine/runner.py), where XLA inlines it; it compiles alone only where a test or a tool calls `unified` eagerly
+_layer_body = jax.jit(
+    _layer, static_argnames=("cfg", "spec", "block_size", "attn", "pallas")
+)
 
 
 def unified(
@@ -685,103 +803,42 @@ def unified(
     recurrent_state_arrays``: a (state, convolution tail) pair; an (S, z)
     pair), and ``state_slot``, and returns the new ``rec_state`` as its
     last result; those layers' entries of ``kv_caches`` are empty."""
-    if attn is None:
-        from dynamo_tpu.ops import attention as attn_ops
+    from dynamo_tpu.models.moe import note_experts_hit
+    from dynamo_tpu.ops.attention import pallas_enabled
 
-        ragged_fn = attn_ops.ragged_attention
-    else:
-        ragged_fn = attn.ragged
-    mesh = attn.mesh if attn is not None else None
     T = token_ids.shape[0]
-    positions = jnp.maximum(token_pos, 0)
     x = _embed(params, cfg, token_ids)
     if embeds is not None:
         x = jnp.where(embed_mask[:, None], embeds.astype(x.dtype), x)
-    if kv_scales is not None:
-        from dynamo_tpu.ops.quant import quantize_kv_write
-
-    # A block-diffusion model masks by block; every other keeps the
-    # causal call it had.
-    block_kw = (
-        {"diffusion_block": cfg.diffusion_block_length}
-        if cfg.diffusion_block_length > 1 else {}
-    )
     new_caches = []
     new_scales = []
     new_rec = []
-    # An expert share drops the budget's padding rows with the rows routed
-    # elsewhere; a model whose experts are all here computes every row.
-    valid = token_pos >= 0 if cfg.num_experts_held else None
     if isinstance(block_tables, (tuple, list)):
         slots_of, tables_of = slot_mapping, block_tables
     else:  # one group, handed bare
         slots_of, tables_of = (slot_mapping,), (block_tables,)
+    meta = (token_seq, token_pos, q_start, q_len, kv_len, row_start)
+    # What a trace reads of the process (DYNAMO_TPU_PALLAS, the backend) goes
+    # in as a static operand: jit's cache has to see it.
+    pallas = pallas_enabled()
     for li, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
-        h = _ln(x, layer["ln_attn"], cfg)
-        kind = cfg.layer_kind(li)
-        if kind != "attn":
-            # A recurrent layer: its state in and out, no pages.
-            mixer = (
-                _kda_mixer if kind == "kda"
-                else partial(_retention_mixer, li=li)
-            )
-            with jax.named_scope(f"{kind}_mixer"):
-                y, state = mixer(
-                    layer, h, cfg, rec_state[len(new_rec)],
-                    (token_seq, token_pos, q_start, q_len, row_start),
-                    state_slot, attn is not None and attn.use_pallas,
-                )
+        spec = cfg.layer_spec(li)
+        paged = spec.kind == "attn"
+        LAYER_BODY["calls"] += 1
+        x, cache, scale, state, hit = _layer_body(
+            cfg, spec, block_size, attn, pallas,
+            layer, cache,
+            kv_scales[li] if kv_scales is not None and paged else None,
+            None if paged else rec_state[len(new_rec)],
+            x, meta, slots_of[spec.cache_group], tables_of[spec.cache_group],
+            state_slot,
+        )
+        new_caches.append(cache)
+        if scale is not None:
+            new_scales.append(scale)
+        if not paged:
             new_rec.append(state)
-            new_caches.append(cache)
-            x = _residual_mlp(x + y, layer, cfg, mesh, li, valid)
-            continue
-        k_cache, v_cache = cache
-        g = cfg.layer_cache_group(li)
-        slot_mapping, block_tables = slots_of[g], tables_of[g]
-        if cfg.is_mla:
-            with jax.named_scope("latent_mixer"):
-                q, k, v = _qkv_mla(layer, h, cfg, positions)
-        else:
-            q, k, v = _qkv(layer, h, cfg)
-            q, k = _rope_qk(cfg, li, q, k, positions)
-        if kv_scales is not None:
-            pad = k_cache.shape[-1] - k.shape[-1]
-            if pad:  # lane-padded cache (Pallas head-dim contract)
-                widen = ((0, 0),) * (k.ndim - 1) + ((0, pad),)
-                k, v = jnp.pad(k, widen), jnp.pad(v, widen)
-            k_cache, k_sc = quantize_kv_write(
-                k_cache, kv_scales[li, 0], slot_mapping, k, block_size
-            )
-            v_cache, v_sc = quantize_kv_write(
-                v_cache, kv_scales[li, 1], slot_mapping, v, block_size
-            )
-            new_scales.append(jnp.stack([k_sc, v_sc]))
-            scale_kw = {"k_scales": k_sc, "v_scales": v_sc}
-        else:
-            k_cache = k_cache.at[slot_mapping].set(_to_cache(k, k_cache))
-            v_cache = v_cache.at[slot_mapping].set(_to_cache(v, v_cache))
-            scale_kw = {}
-        window = cfg.layer_window(li)
-        with jax.named_scope("attn_window" if window else "attn_full"):
-            attn_out = ragged_fn(
-                q, k_cache, v_cache, block_tables, token_seq, token_pos,
-                q_start, q_len, kv_len, row_start, block_size,
-                window=window, **scale_kw, **block_kw,
-            )
-        new_caches.append((k_cache, v_cache))
-        if cfg.parallel_block:
-            # x + attention(h) + ffn(h): both branches read the ONE norm.
-            a = qdot(attn_out.reshape(T, -1), layer["wo"])
-            x = _residual_mlp(x, layer, cfg, mesh, li, valid, h=h) + a
-            continue
-        if cfg.is_mla:
-            with jax.named_scope("latent_mixer"):
-                x = x + _mla_out(layer, attn_out, cfg)
-        else:
-            x = _residual_attn(
-                x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg
-            )
-        x = _residual_mlp(x, layer, cfg, mesh, li, valid)
+        note_experts_hit(*hit)
 
     if verify_rows == 1:
         last = jnp.clip(row_start + q_len - 1, 0, T - 1)  # [S]
@@ -831,8 +888,9 @@ def hidden_states(
     if embeds is not None:
         x = jnp.where(embed_mask[:, None], embeds.astype(x.dtype), x)
     for li, layer in enumerate(params["layers"]):
+        spec = cfg.layer_spec(li)
         h = _ln(x, layer["ln_attn"], cfg)
-        if cfg.layer_kind(li) == "kda":
+        if spec.kind == "kda":
             # One span from zeros: slot 1 of a fresh two-slot state.
             H, d, K = cfg.num_heads, cfg.head_dim, cfg.linear_conv_kernel
             one = jnp.ones((1,), jnp.int32)
@@ -845,12 +903,12 @@ def hidden_states(
                 one, False,
             )
             x = x + y
-        elif cfg.layer_kind(li) == "retention":
+        elif spec.kind == "retention":
             # The attention form: no state at all.
             from dynamo_tpu.ops.power_retention import retention_attention
 
             y = retention_attention(
-                *_retention_inputs(layer, h, cfg, li, positions)
+                *_retention_inputs(layer, h, cfg, spec, positions)
             )
             x = x + qdot(y.reshape(T, -1).astype(h.dtype), layer["wo"])
         elif cfg.is_mla:
@@ -859,17 +917,17 @@ def hidden_states(
             x = x + _mla_out(layer, attn, cfg)
         else:
             q, k, v = _qkv(layer, h, cfg)
-            q, k = _rope_qk(cfg, li, q, k, positions)
+            q, k = _rope_qk(cfg, spec, q, k, positions)
             attn = full_causal_attention(
-                q, k, v, window=cfg.layer_window(li),
+                q, k, v, window=spec.window,
                 diffusion_block=max(cfg.diffusion_block_length, 1),
             )
             a = qdot(attn.reshape(T, -1), layer["wo"])
             if cfg.parallel_block:
-                x = _residual_mlp(x, layer, cfg, li=li, h=h) + a
+                x = _residual_mlp(x, layer, cfg, spec, h=h) + a
                 continue
             x = _residual_attn(x, layer, a, cfg)
-        x = _residual_mlp(x, layer, cfg, li=li)
+        x = _residual_mlp(x, layer, cfg, spec)
     return x
 
 

@@ -219,6 +219,11 @@ class _Collected(list):
         super().__init__()
         self.rows_held: list = []
 
+    def results(self) -> tuple:
+        """(experts hit, rows held) as tuples: what a jitted function that
+        traced expert layers hands out for ``note_experts_hit``."""
+        return tuple(self), tuple(self.rows_held)
+
 
 #: While a step program is traced under ``collect_experts_hit``.
 _EXPERTS_HIT: _Collected | None = None
@@ -237,6 +242,16 @@ def collect_experts_hit():
         yield _EXPERTS_HIT
     finally:
         _EXPERTS_HIT = before
+
+
+def note_experts_hit(hit, rows_held) -> None:
+    """Hand the enclosing ``collect_experts_hit`` the counts that an inner
+    jitted function collected in ITS trace and returned as results (models/
+    llama.py ``_layer``): a traced scalar cannot cross a ``jax.jit``
+    boundary by a side effect."""
+    if _EXPERTS_HIT is not None:
+        _EXPERTS_HIT.extend(hit)
+        _EXPERTS_HIT.rows_held.extend(rows_held)
 
 
 def _interpret() -> bool:

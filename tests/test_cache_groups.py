@@ -671,7 +671,7 @@ def test_presets_and_from_hf(tmp_path):
     share = PRESETS["command-a-plus-ep8-l4"]()
     assert whole.num_layers == 32 and whole.experts_here == 128
     assert [whole.layer_window(li) for li in range(4)] == [4096] * 3 + [0]
-    assert [llama._layer_rope(whole, li) == "none" for li in range(4)] == [
+    assert [whole.layer_rope(li) == "none" for li in range(4)] == [
         False, False, False, True]
     assert share.num_experts == 128 and share.experts_here == 16
     assert share.num_layers == 4 and share.vocab_size == 32768

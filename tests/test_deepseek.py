@@ -449,11 +449,11 @@ def test_mla_absorbed_matches_standard_formulation():
         return qmm(o.reshape(T, H * cfg.v_head_dim), layer["wo"])
 
     x = embed_lookup(params["embed"], token_ids)
-    for layer in params["layers"]:
+    for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["ln_attn"], cfg.rms_eps)
         x = x + standard_mla_attn(layer, h)
         h = rms_norm(x, layer["ln_mlp"], cfg.rms_eps)
-        x = x + _mlp(layer, h, cfg)
+        x = x + _mlp(layer, h, cfg, cfg.layer_spec(li))
     standard_logits = np.asarray(_logits(params, cfg, x))
 
     absorbed_logits = np.asarray(
