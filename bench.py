@@ -292,7 +292,8 @@ def _compile_lifecycle_report(
     out = {
         "warmup_programs": warmup_programs,
         "warmup_s": warmup_s,
-        "warmup_replayed_from_cache": cs.replayed_programs,
+        "warmup_cache_hits": cs.warm_cache_events["hits"],
+        "warmup_cache_misses": cs.warm_cache_events["misses"],
         "mid_traffic_compiles": cs.mid_traffic_compiles,
         "compile_stall_ms": round(cs.compile_stall_ms_total, 1),
         "ttft_p95_over_p50_max": max(ratios) if ratios else None,

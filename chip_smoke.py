@@ -333,9 +333,9 @@ def lowered_step_kernels(runner) -> int:
 class JaxEvents:
     """JAX's own account of compiling, summed by event name as
     ``[count, seconds]``: tracing, lowering and backend-compile
-    durations, persistent-cache hits and retrieval time. The ledger's
-    "replayed" is this repo's belief that a shape has a disk entry;
-    these are what XLA did about it."""
+    durations, persistent-cache hits and retrieval time, over the whole
+    start (``CompileStats`` counts the hits and misses of the warmup
+    alone)."""
 
     def __init__(self) -> None:
         import jax.monitoring
@@ -371,19 +371,15 @@ class JaxEvents:
 
 
 def cache_report(runner) -> dict:
-    cache = runner.compile_cache
-    if cache is None:
+    cache_dir = runner.compile_cache_dir
+    if cache_dir is None:
         return {"dir": None}
-    entries = [
-        e for e in os.scandir(cache.base_dir) if e.is_file()
-    ]
+    entries = [e for e in os.scandir(cache_dir) if e.is_file()]
     return {
-        "dir": cache.base_dir,
+        "dir": cache_dir,
         "from_env": "JAX_COMPILATION_CACHE_DIR" in os.environ,
         "xla_entries": len(entries),
         "xla_bytes": sum(e.stat().st_size for e in entries),
-        "ledger_dir": cache.dir,
-        "ledger_shapes": cache.num_ledger_entries,
     }
 
 
@@ -464,7 +460,8 @@ async def serve_and_query(
             model = health["models"][0]
             cs = runner.compile_stats.snapshot()
             report["warmup_programs"] = cs["warmup_programs_total"]
-            report["replayed_programs"] = cs["replayed_programs"]
+            report["warmup_cache_hits"] = cs["warmup_cache_hits_total"]
+            report["warmup_cache_misses"] = cs["warmup_cache_misses_total"]
             report["compile_cache"] = cache_report(runner)
             # Executables behind the runner's unified jit. CompileStats
             # counts first executions per (kind, budget); jit also keys

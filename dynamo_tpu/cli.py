@@ -176,18 +176,12 @@ def build_parser() -> argparse.ArgumentParser:
                      help="skip ahead-of-traffic shape compilation")
     run.add_argument("--compile-cache-dir", default="auto",
                      metavar="DIR|auto|none",
-                     help="persistent XLA compile cache base dir "
-                          "(fingerprint-namespaced; warmed programs "
-                          "replay from disk on relaunch). "
+                     help="persistent XLA compile cache dir (warmed "
+                          "programs are read from disk on relaunch). "
                           "$JAX_COMPILATION_CACHE_DIR, when set, is the "
                           "directory whatever is given here; auto = "
                           "$DYNAMO_TPU_COMPILE_CACHE_DIR, else "
                           ".jax_cache in the checkout; none disables")
-    run.add_argument("--shape-manifest", default=None, metavar="FILE.json",
-                     help="shape-manifest path (records the shapes "
-                          "serving executes; warmup compiles exactly "
-                          "that set first). Default: alongside the "
-                          "compile cache")
     # Overload-safe serving (docs/architecture/overload_and_drain.md).
     run.add_argument("--max-inflight", type=int, default=256,
                      help="HTTP admission gate: max concurrently admitted "
@@ -866,8 +860,7 @@ def _tpu_local_and_cfg(args):
         num_nodes=args.num_nodes,
         node_rank=args.node_rank,
         compile_cache_dir=resolve_cache_base(args.compile_cache_dir),
-        shape_manifest_path=args.shape_manifest,
-        # With warmup on, hold admission until the hot shape set compiles
+        # With warmup on, hold admission until the shape set compiles
         # (requests queue instead of racing the compiles); --no-warmup
         # serves immediately in the documented degraded mode.
         warmup_gate="degraded" if args.no_warmup else "hold",
@@ -1017,18 +1010,13 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             stack.push(leader.stop)
             engine.runner = leader
         stack.push(engine.stop)
-        cache = getattr(engine.runner, "compile_cache", None)
-        if cache is not None:
-            print(
-                f"compile cache: {cache.dir} "
-                f"({cache.num_ledger_entries} warmed shapes on disk)",
-                flush=True,
-            )
+        cache_dir = getattr(engine.runner, "compile_cache_dir", None)
+        if cache_dir is not None:
+            print(f"compile cache: {cache_dir}", flush=True)
         if not args.no_warmup:
             t0 = time.monotonic()
             n = await engine.warmup()
             cs = engine.runner.compile_stats
-            tail = engine.warm_tail_pending
             phases = ", ".join(
                 f"{phase} {secs:.1f}s"
                 for phase, secs in cs.warm_phase_s.items()
@@ -1037,9 +1025,9 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             print(
                 f"warmup: {n} programs in {time.monotonic() - t0:.1f}s "
                 f"({phases}; layer body traced {traces}x for {calls} "
-                f"calls; {cs.replayed_programs} already in the ledger"
-                + (f", {tail} deferred to background" if tail else "")
-                + ") — engine ready",
+                f"calls; {cs.warm_cache_events['hits']} compile requests "
+                f"read from the cache, {cs.warm_cache_events['misses']} "
+                "compiled) — engine ready",
                 flush=True,
             )
         card = local.card

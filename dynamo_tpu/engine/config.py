@@ -172,21 +172,16 @@ class EngineConfig:
     kvbm_peer_timeout_s: float = 2.0
 
     # Compile lifecycle (engine/compile_cache.py). `compile_cache_dir` is
-    # the BASE directory for the persistent XLA compilation cache; the
-    # runner namespaces it by an engine fingerprint (model config + mesh +
-    # quant + flags), so a relaunched worker replays its warmup compiles
-    # from disk in milliseconds and a config change can never hit stale
-    # programs. None = $DYNAMO_TPU_COMPILE_CACHE_DIR or disabled.
+    # the directory of the persistent XLA compilation cache (XLA keys its
+    # entries by the program's hash, so one directory serves every
+    # config): a relaunched worker reads its warmup's programs from disk.
+    # None = $DYNAMO_TPU_COMPILE_CACHE_DIR or disabled.
     compile_cache_dir: str | None = None
-    # Where the shape manifest (shapes serving actually executed) is
-    # saved on stop and loaded by warmup. None = alongside the persistent
-    # cache when that is enabled, else no manifest.
-    shape_manifest_path: str | None = None
-    # Readiness gating while the hot shape set compiles: "hold" parks
-    # admission until warmup's hot set is done (requires the operator to
-    # actually run warmup — the CLI does); "degraded" serves immediately
-    # and flags it (engine.served_unwarmed; mid-traffic compiles are
-    # counted either way).
+    # Readiness gating while the shape set compiles: "hold" parks
+    # admission until warmup is done (requires the operator to actually
+    # run warmup — the CLI does); "degraded" serves immediately and flags
+    # it (engine.served_unwarmed; mid-traffic compiles are counted either
+    # way).
     warmup_gate: str = "degraded"
 
     # Flight recorder (engine/flight_recorder.py): bounded in-memory ring

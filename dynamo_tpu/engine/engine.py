@@ -25,11 +25,6 @@ from typing import Any, AsyncIterator, Callable
 import numpy as np
 
 from dynamo_tpu.engine.coloc import ColocController
-from dynamo_tpu.engine.compile_cache import (
-    ShapeManifest,
-    engine_fingerprint,
-    fingerprint_key,
-)
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.flight_recorder import FlightRecorder
 from dynamo_tpu.engine.kv_cache import BlockAllocator, KvEvent
@@ -264,11 +259,9 @@ class TpuEngine:
         # the last in-flight sequence finishes.
         self._draining = False
         # Compile lifecycle (engine/compile_cache.py): readiness state,
-        # the deferred warm tail (shapes warmed one per idle engine step
-        # after the hot set), and the degraded-serving flag set when an
-        # un-warmed engine takes traffic anyway (warmup_gate="degraded").
+        # and the degraded-serving flag set when an un-warmed engine takes
+        # traffic anyway (warmup_gate="degraded").
         self._state = "init"  # init -> warming -> ready
-        self._warm_tail: deque = deque()
         self._served_unwarmed = False
         # Last-dispatch heartbeat (docs/architecture/failure_model.md
         # "Mid-stream failover"): monotonic stamp of the most recent
@@ -354,7 +347,6 @@ class TpuEngine:
         self._wakeup.set()
         if self._thread:
             await asyncio.to_thread(self._thread.join, 5.0)
-        self._save_manifest()
 
     # -- graceful drain -----------------------------------------------------
     def begin_drain(self) -> None:
@@ -394,37 +386,6 @@ class TpuEngine:
                 return self._dead is None
             await asyncio.sleep(0.02)
         return self.drained
-
-    def _manifest_path(self) -> str | None:
-        if self.cfg.shape_manifest_path:
-            return self.cfg.shape_manifest_path
-        cache = getattr(self.runner, "compile_cache", None)
-        if cache is not None:
-            import os
-
-            return os.path.join(cache.dir, "shape_manifest.json")
-        return None
-
-    def _save_manifest(self) -> None:
-        """Persist the shapes serving actually executed, so the NEXT
-        launch's warmup compiles exactly that set first (and through the
-        persistent cache, replays it from disk)."""
-        path = self._manifest_path()
-        stats = getattr(self.runner, "compile_stats", None)
-        if path is None or stats is None or not stats.manifest.shapes:
-            return
-        try:
-            self.runner.save_manifest(path)
-        except Exception:  # dynalint: allow[DT003] manifest persistence is best-effort; next run re-learns shapes
-            logger.exception("shape manifest save failed")
-
-    def _load_manifest(self) -> ShapeManifest | None:
-        path = self._manifest_path()
-        if path is None:
-            return None
-        return ShapeManifest.load(
-            path, fingerprint_key(engine_fingerprint(self.cfg))
-        )
 
     async def warmup(self) -> int:
         """Compile the serving shape set before taking traffic (runs on the
@@ -695,11 +656,6 @@ class TpuEngine:
         # poll) proves the thread is alive and not wedged inside
         # a collective/compile — the stamp readiness() ages.
         self._last_dispatch_mono = time.monotonic()
-        if not did_work and self._warm_tail:
-            # Idle step: warm one deferred (tail) shape so the
-            # long tail compiles between traffic, never under it.
-            self._warm_one_tail()
-            did_work = True
         self._flush_side_channels()
         # What the pass emitted outside a retire (an expiry, a shed, an
         # abort, a refused prompt) leaves now.
@@ -732,10 +688,8 @@ class TpuEngine:
                 self._run_warmup(*arg)
 
     def _run_warmup(self, fut) -> None:
-        """Warm the HOT shape set synchronously (the future resolves when
-        it is compiled and the engine is ready for traffic); the tail —
-        grid shapes a loaded manifest says serving didn't execute — warms
-        one program per idle engine step afterwards."""
+        """Warm the whole shape set synchronously: the future resolves
+        when it is compiled and the engine is ready for traffic."""
         loop = self._loop
 
         def resolve(action, value):
@@ -746,31 +700,14 @@ class TpuEngine:
             )
 
         try:
-            manifest = self._load_manifest()
-            hot, tail = self.runner.warmup_plan(manifest)
-            if manifest is not None:
-                logger.info(
-                    "shape-manifest warmup: %d hot programs (observed "
-                    "set), %d deferred to background", len(hot), len(tail),
-                )
-            n = self.runner.run_warm_ops(hot)
-            self._warm_tail.extend(tail)
+            n = self.runner.run_warm_ops(self.runner.warm_ops())
             self._state = "ready"
             resolve(fut.set_result, n)
         except Exception as exc:  # dynalint: allow[DT003] propagated: the warmup future re-raises on the caller
             resolve(fut.set_exception, exc)
 
-    def _warm_one_tail(self) -> None:
-        """Compile ONE deferred warm shape between engine steps — the long
-        tail fills in during idle moments instead of blocking readiness."""
-        key, op = self._warm_tail.popleft()
-        try:
-            self.runner.run_warm_ops([(key, op)])
-        except Exception:  # dynalint: allow[DT003] tail warm is best-effort; the shape compiles on first use instead
-            logger.exception("background warmup of %s failed", key)
-
     def _admission_held(self) -> bool:
-        """warmup_gate="hold": no new work starts until the hot shape set
+        """warmup_gate="hold": no new work starts until the shape set
         is compiled — requests queue in the scheduler instead of paying
         (or racing) the compiles."""
         return self.cfg.warmup_gate == "hold" and self._state != "ready"
@@ -809,9 +746,8 @@ class TpuEngine:
         #    the oldest when the pipeline is at depth). Speculative mode
         #    runs depth-1: each dispatch's variable progress (and the
         #    host token history prompt-lookup drafts from) must be
-        #    host-known before the next issue — the same rule the
-        #    phased spec path ran under. (A block-diffusion model keeps
-        #    its depth: a block's next pass is fed from the device.)
+        #    host-known before the next issue. (A block-diffusion model
+        #    keeps its depth: a block's next pass is fed from the device.)
         depth = 1 if self._spec_active else self.cfg.pipeline_depth
         while self._inflight and (
             len(self._inflight) >= depth
@@ -843,11 +779,10 @@ class TpuEngine:
     def _draft_tokens(self, seq: Sequence) -> list[int]:
         """Prompt-lookup drafts for one greedy decode lane: the latest
         earlier occurrence of the trailing bigram in the HOST token
-        history supplies up to speculative_k continuation tokens. Host
-        lookup replaces the phased path's device-resident [B, L] history
-        buffer: spec runs depth-1, so the history is always host-known
-        at issue, and the unified dispatch is ONE step (the device
-        buffer existed for the multi-step scan)."""
+        history supplies up to speculative_k continuation tokens. The
+        lookup needs no device-resident history: spec runs depth-1, so
+        the history is always host-known at issue, and the unified
+        dispatch is ONE step."""
         cfg = self.cfg
         limit = min(
             cfg.speculative_k,
@@ -945,7 +880,7 @@ class TpuEngine:
                     # Sampled lanes accept zero drafts by law — drafting
                     # for them would burn budget on guaranteed-rejected
                     # verify rows. (They still count as spec steps for
-                    # the auto-gate, exactly as on the phased path.)
+                    # the auto-gate: see the gate accounting at retire.)
                     continue
                 drafts = self._draft_tokens(seq)
                 if drafts:
@@ -1080,8 +1015,7 @@ class TpuEngine:
                     (seq.slot if seq.slot is not None else -1)
                     for seq, *_r in roles
                 ],
-                # The phased full program counted each decode step's FED
-                # token on entry — the unified law is identical: decode
+                # A decode step's FED token counts on entry: decode
                 # spans count, prefill quanta never do.
                 "counts_add": [kind == "decode" for _, kind, *_r in roles],
                 "reset": [],
@@ -1157,8 +1091,7 @@ class TpuEngine:
         # can never contaminate the probe window with 1.0-tok/step
         # samples (they were never given the chance to draft; counting
         # them would re-disable speculation before a single draft-verify
-        # dispatch runs — the phased gate only ever measured spec
-        # chunks, and this preserves that).
+        # dispatch runs: the gate measures spec dispatches only).
         spec_counted = spec_on and not has_extras and not has_mm
         compose_ms = 1000.0 * (time.monotonic() - t_compose)
         # The runner's count for THIS dispatch (a record noted at its
@@ -1188,9 +1121,9 @@ class TpuEngine:
                 "unified",
                 **self._plain_note(roles, n_dec, n_pre, compose_ms, folds),
             )
-        # Auto-gate re-probe (semantics preserved from the phased gate):
-        # after speculative_probe_steps plain decode steps, run a short
-        # probe window of spec steps and re-judge against break-even.
+        # Auto-gate re-probe: after speculative_probe_steps plain decode
+        # steps, run a short probe window of spec steps and re-judge
+        # against break-even.
         if cfg.speculative_k and not self._spec_enabled and n_dec:
             self._plain_steps_since_disable += 1
             if (
@@ -1328,7 +1261,7 @@ class TpuEngine:
                 if seq.status is not SeqStatus.RUNNING:
                     continue  # stopped while in flight; token discarded
                 if spec_counted:
-                    # Gate accounting (the phased law): every decode
+                    # Gate accounting: every decode
                     # lane-step of a dispatch ISSUED with speculation
                     # active counts one spec step; delivered tokens are
                     # the numerator. Dispatches issued while gated off
@@ -2755,7 +2688,6 @@ class TpuEngine:
             if cs is not None:
                 m.update(cs.snapshot())
             m["engine_ready"] = int(self._state == "ready")
-            m["warm_tail_pending"] = len(self._warm_tail)
             # Robustness counters (docs/architecture/failure_model.md):
             # degraded completions are engine-local; fault injections and
             # retries are process-wide (all seams in this worker).
@@ -2816,10 +2748,6 @@ class TpuEngine:
         """True when traffic was admitted before any warmup completed —
         the documented degraded mode (warmup_gate="degraded")."""
         return self._served_unwarmed
-
-    @property
-    def warm_tail_pending(self) -> int:
-        return len(self._warm_tail)
 
     def _kvbm_gauges(self) -> dict:
         """Block-manager tier telemetry, kvbm_-prefixed for the metric
@@ -2914,7 +2842,7 @@ class TpuEngine:
 
     def readiness(self) -> dict:
         """Snapshot for /health + /metrics (llm/http_service.py): state,
-        degraded flag, background-warm backlog, compile-stall counters,
+        degraded flag, compile-stall counters,
         live load (the admission gate's watermark feed), the overload
         counters, and the KV-observatory actual-reuse + tier gauges. A
         draining engine reports state "draining" so readiness probes and
@@ -2922,7 +2850,6 @@ class TpuEngine:
         d = {
             "state": "draining" if self._draining else self._state,
             "served_unwarmed": self._served_unwarmed,
-            "warm_tail_pending": len(self._warm_tail),
             "degraded_requests_total": self._degraded_requests,
             "draining": self._draining,
             "shed_requests_total": OVERLOAD.shed_total,

@@ -48,9 +48,9 @@ import numpy as np
 
 from dynamo_tpu.engine.compile_cache import (
     CompileStats,
-    PersistentCompileCache,
     WarmupPlanMixin,
-    engine_fingerprint,
+    activate_cache,
+    env_cache_base,
     token_budget,
 )
 from dynamo_tpu.engine.config import EngineConfig
@@ -293,16 +293,12 @@ class ModelRunner(WarmupPlanMixin):
         # Compile lifecycle (engine/compile_cache.py): the persistent
         # cache must be active BEFORE the first jit below so init/quantize
         # programs also replay from disk on relaunch.
-        from dynamo_tpu.engine.compile_cache import env_cache_base
-
         cache_base = cfg.compile_cache_dir or env_cache_base()
-        self.compile_cache = None
-        if cache_base:
-            self.compile_cache = PersistentCompileCache(
-                cache_base, engine_fingerprint(cfg)
-            )
-            self.compile_cache.activate()
-        self.compile_stats = CompileStats(cache=self.compile_cache)
+        #: Where XLA's entries land (None: no persistent cache).
+        self.compile_cache_dir = (
+            activate_cache(cache_base) if cache_base else None
+        )
+        self.compile_stats = CompileStats()
         if cfg.num_nodes > 1:
             # Join the multi-host coordination service BEFORE any device
             # use so jax.devices() below enumerates every host's chips.
@@ -1024,17 +1020,14 @@ class ModelRunner(WarmupPlanMixin):
         self.last_unified_logprobs = None
 
     # -- warmup -------------------------------------------------------------
-    def warmup(self, manifest=None) -> int:
+    def warmup(self) -> int:
         """Compile the serving shape set off the clock: the unified
-        budget ladder (plus the single extras/mm top-rung programs when
-        configured) — ordered by `warmup_plan` (engine/compile_cache.py):
-        a shape manifest from a previous run warms the observed rungs
-        first. All writes land in trash block 0, so the real
-        cache/allocator state is untouched. Returns the number of XLA
-        programs touched. First compiles dominate TTFT otherwise
-        (seconds per shape)."""
-        hot, tail = self.warmup_plan(manifest)
-        return self.run_warm_ops(hot + tail)
+        budget ladder, then the single extras/mm top-rung programs when
+        configured (`warm_ops`, engine/compile_cache.py). All writes land
+        in trash block 0, so the real cache/allocator state is untouched.
+        Returns the number of XLA programs touched. First compiles
+        dominate TTFT otherwise (seconds per shape)."""
+        return self.run_warm_ops(self.warm_ops())
 
     def run_warm_ops(self, ops) -> int:
         n = super().run_warm_ops(ops)
@@ -1044,14 +1037,13 @@ class ModelRunner(WarmupPlanMixin):
         jax.block_until_ready(jax.tree.leaves(self.kv_caches)[0])
         return n
 
-    def _warm_op(self, spec):
-        """One shape spec → a trash-block warm call (WarmupPlanMixin).
+    def _warm_op(self, kind, t):
+        """One shape → a trash-block warm call (WarmupPlanMixin).
         The whole warm surface is the unified family: the budget ladder
         (which IS the spec-verify program on a spec-enabled engine — one
         family, zero extra programs) plus one top-rung program each for
         the extras and multimodal variants when configured."""
         cfg = self.cfg
-        kind, t, _lanes, _steps, _draft_k = spec
         sampling = (0.0, 0, 1.0)
         trash = self._trash_table()
         warm_lanes = _unified_warm_lanes(

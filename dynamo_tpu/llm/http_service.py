@@ -198,10 +198,10 @@ class HttpService:
             for key in (
                 "mid_traffic_compiles_total",
                 "compile_stall_ms_total",
-                "warm_tail_pending",
                 "warmed_programs",
                 "warmup_programs_total",
-                "replayed_programs",
+                "warmup_cache_hits_total",
+                "warmup_cache_misses_total",
                 "gpu_prefix_cache_hit_rate",
                 "spec_tokens_per_step",
                 "spec_active",

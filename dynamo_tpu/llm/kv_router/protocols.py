@@ -42,7 +42,6 @@ class ForwardPassMetrics:
     mid_traffic_compiles_total: int = 0
     compile_stall_ms_total: float = 0.0
     engine_ready: int = 0
-    warm_tail_pending: int = 0
     warmup_programs_total: int = 0
     # Unified-step observability (docs/architecture/unified_step.md):
     # per-phase token split across unified dispatches and the latest

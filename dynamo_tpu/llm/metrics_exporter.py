@@ -57,8 +57,7 @@ _GAUGES = (
     ("itl_slo_violations_total", "Dispatches over the decode ITL SLO"),
     ("coloc_prefill_deferrals_total", "Prefill admissions deferred by coloc"),
     ("prefill_backlog_tokens", "Un-prefilled prompt tokens queued"),
-    ("engine_ready", "Hot shape set compiled (0 = still warming)"),
-    ("warm_tail_pending", "Background warmup shapes still queued"),
+    ("engine_ready", "Shape set compiled (0 = still warming)"),
     ("degraded_requests_total", "Requests completed via a degraded path"),
     ("faults_injected_total", "Injected faults fired (chaos drills)"),
     ("retries_total", "Transport retries across all seams"),

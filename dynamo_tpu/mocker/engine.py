@@ -150,17 +150,16 @@ class _SimRunner(WarmupPlanMixin):
         self.sim = sim
         self.cache_head_dim = cfg.model.head_dim  # layout-handshake parity
         self._rng = np.random.default_rng(sim.seed)
-        self.compile_cache = None
+        self.compile_cache_dir = None
         self.compile_stats = CompileStats()
         # Simulated per-block KV bytes so KVBM/disagg paths can verify
         # byte fidelity without a device.
         self._fake_kv: dict[int, np.ndarray] = {}
 
-    def _warm_op(self, spec):
+    def _warm_op(self, kind, t):
         """Warm calls for the sim's program kinds (WarmupPlanMixin) —
         the unified family only, like the real runner."""
         cfg = self.cfg
-        kind, t, _lanes, _steps, _k = spec
         sampling = (0.0, 0, 1.0)
         trash = [0] * cfg.max_blocks_per_seq
         warm_lanes = _unified_warm_lanes(

@@ -426,13 +426,13 @@ class StepLeader:
         self._pending.append(fut)
         self._pending[:] = [f for f in self._pending if not f.done()]
 
-    def warmup_plan(self, manifest=None):
+    def warm_ops(self):
         """Compile lifecycle (engine/compile_cache.py): followers replay
-        `warmup` as ONE broadcast REPLAYED call, so the leader's plan
-        collapses to that single op. No manifest/tail split across a mesh
-        — every rank must compile the identical set in lockstep, and the
-        thunks a per-shape plan carries are not wire-shippable."""
-        return [("warmup", self.warmup)], []
+        `warmup` as ONE broadcast REPLAYED call, so the leader's list
+        collapses to that single op — every rank must compile the
+        identical set in lockstep, and the thunks a per-shape list carries
+        are not wire-shippable."""
+        return [("warmup", self.warmup)]
 
     def run_warm_ops(self, ops) -> int:
         n = 0

@@ -1,10 +1,9 @@
 """Compile-lifecycle subsystem tests (engine/compile_cache.py):
 
-- shape-manifest roundtrip: record → save → load → warm-plan pruning,
-  with fingerprint staleness guarding
-- persistent-cache fingerprint namespacing + ledger persistence, and the
-  second-cold-start speedup (counting stub — no TPU present)
-- readiness gating: warmup_gate="hold" parks admission until the hot set
+- the warm list: the whole grid, the ladder then one top rung a variant
+- where the cache lives (one rule; nothing of ours written into it), and
+  XLA's own hit / miss events counted during warmup only
+- readiness gating: warmup_gate="hold" parks admission until the shape set
   is warm; "degraded" serves immediately and flags it
 - mid-traffic-compile counter incrementing on an un-warmed shape, and
   staying zero on a warmed engine (real CPU runner)
@@ -13,20 +12,15 @@
 
 import asyncio
 import os
-import time
 
-import numpy as np
 import pytest
 
 from dynamo_tpu.engine.compile_cache import (
-    CompileStats,
-    PersistentCompileCache,
-    ShapeManifest,
+    JAX_CACHE_EVENTS,
+    activate_cache,
+    budget_ladder,
     default_shape_grid,
-    engine_fingerprint,
-    fingerprint_key,
     shape_key,
-    split_plan,
 )
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.llm.protocols.common import (
@@ -34,7 +28,7 @@ from dynamo_tpu.llm.protocols.common import (
     SamplingOptions,
     StopConditions,
 )
-from dynamo_tpu.mocker.engine import MockerConfig, MockerEngine
+from dynamo_tpu.mocker.engine import MockerConfig, MockerEngine, _SimRunner
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
 
@@ -69,67 +63,31 @@ async def _collect(engine, n_prompt: int, max_tokens: int = 4) -> int:
 
 
 # ---------------------------------------------------------------------------
-# manifest
+# the warm list
 # ---------------------------------------------------------------------------
 
 
-def test_manifest_roundtrip_and_fingerprint_guard(tmp_path):
-    m = ShapeManifest()
-    for _ in range(5):
-        m.record("unified", t=128)
-    m.record("unified", t=64)
-    m.record("unified_full", t=128)
-    path = str(tmp_path / "manifest.json")
-    m.save(path, "fp-a")
-
-    loaded = ShapeManifest.load(path, "fp-a")
-    assert loaded is not None
-    assert loaded.count_of(shape_key("unified", t=128)) == 5
-    assert loaded.count_of(shape_key("unified_full", t=128)) == 1
-
-    # A manifest written under a different engine fingerprint must be
-    # ignored (stale shapes would warm the wrong programs).
-    assert ShapeManifest.load(path, "fp-b") is None
-    assert ShapeManifest.load(str(tmp_path / "missing.json"), "fp-a") is None
-
-
-def test_split_plan_orders_unified_grid(tmp_path):
-    """The unified grid: every budget rung is a decode-criticality shape
-    (any running lane can land on any rung), so the WHOLE family stays
-    hot under a manifest — its value is ORDERING: observed rungs warm
-    first, by observed count."""
-    cfg = _cfg()
-    specs = default_shape_grid(cfg)
-    keys = [shape_key(*s) for s in specs]
-    assert all(k.startswith("unified") for k in keys)
-
-    m = ShapeManifest()
-    for _ in range(9):
-        m.record("unified", t=64)
-    m.record("unified", t=16)
-    hot, tail = split_plan(specs, m)
-    hot_keys = [shape_key(*s) for s in hot]
-    # Everything stays hot (unified kinds are all decode-critical)...
-    assert not tail
-    assert set(hot_keys) == set(keys)
-    # ...and the dominant observed rung warms before the rare one, which
-    # warms before the never-observed rest of the ladder.
-    assert hot_keys.index(shape_key("unified", t=64)) < hot_keys.index(
-        shape_key("unified", t=16)
-    )
-    assert hot_keys.index(shape_key("unified", t=16)) < hot_keys.index(
-        shape_key("unified", t=32)
-    )
-
-
-def test_fingerprint_tracks_compile_relevant_config():
-    a = fingerprint_key(engine_fingerprint(_cfg()))
-    assert a == fingerprint_key(engine_fingerprint(_cfg()))  # stable
-    assert a != fingerprint_key(engine_fingerprint(_cfg(quant="int8")))
-    assert a != fingerprint_key(engine_fingerprint(_cfg(max_num_seqs=8)))
-    assert a != fingerprint_key(
-        engine_fingerprint(_cfg(mesh_shape={"tp": 2}))
-    )
+@pytest.mark.parametrize("variant,tail", [
+    ({"sampling_extras": False}, []),
+    ({"sampling_extras": True}, ["unified_full:t64"]),
+    ({"sampling_extras": False, "multimodal": True}, ["unified_mm:t64"]),
+    # Extras are refused on a speculative engine: no unified_full there.
+    ({"sampling_extras": True, "multimodal": True, "speculative_k": 2},
+     ["unified_mm:t64"]),
+])
+def test_warm_ops_is_the_whole_grid_in_ladder_order(variant, tail):
+    """ONE list, all of it run before the engine turns ready: the budget
+    ladder bottom-up, then one top rung a configured variant."""
+    cfg = _cfg(unified_token_budget=64, **variant)
+    runner = _SimRunner(cfg, MockerConfig())
+    keys = [key for key, _op in runner.warm_ops()]
+    assert keys == ["unified:t16", "unified:t32", "unified:t64"] + tail
+    assert keys == [shape_key(k, t) for k, t in default_shape_grid(cfg)]
+    assert keys[:3] == [f"unified:t{b}" for b in budget_ladder(64)]
+    cs = runner.compile_stats
+    assert runner.run_warm_ops(runner.warm_ops()) == len(keys)
+    assert cs.warmed_programs == len(keys) and cs.seen == set(keys)
+    assert cs.mid_traffic_compiles == 0 and not cs.warming
 
 
 # ---------------------------------------------------------------------------
@@ -137,28 +95,14 @@ def test_fingerprint_tracks_compile_relevant_config():
 # ---------------------------------------------------------------------------
 
 
-def test_cache_ledger_persists_per_fingerprint(tmp_path):
-    base = str(tmp_path)
-    fp_a = engine_fingerprint(_cfg())
-    cache = PersistentCompileCache(base, fp_a)
-    assert not cache.has("prefill:t64")
-    cache.note("prefill:t64")
-    cache.flush()
-    # A new instance over the same dir (a relaunched process) sees it.
-    again = PersistentCompileCache(base, fp_a)
-    assert again.has("prefill:t64")
-    assert again.num_ledger_entries == 1
-    # A different fingerprint namespaces into a different directory.
-    other = PersistentCompileCache(base, engine_fingerprint(_cfg(quant="int8")))
-    assert other.dir != cache.dir
-    assert not other.has("prefill:t64")
-
-
 @pytest.fixture
 def restore_jax_cache_config():
-    """activate() flips process-global jax config: put it back so a
-    tmp_path cache cannot outlive its test."""
+    """activate_cache() flips process-global jax config: put it back so a
+    tmp_path cache cannot outlive its test. jax opens its cache ONCE, at
+    the first compile that asks (with no directory set then: no cache for
+    the life of the process), so it is reset on the way in and out."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
     names = (
         "jax_compilation_cache_dir",
@@ -166,16 +110,18 @@ def restore_jax_cache_config():
         "jax_persistent_cache_min_entry_size_bytes",
     )
     before = {n: getattr(jax.config, n) for n in names}
+    compilation_cache.reset_cache()
     yield
     for n, v in before.items():
         jax.config.update(n, v)
+    compilation_cache.reset_cache()
 
 
 def test_cache_rule_env_var_places_everything(
     tmp_path, monkeypatch, restore_jax_cache_config
 ):
     """$JAX_COMPILATION_CACHE_DIR set: that directory whatever else was
-    asked for, no cache-dir config call, ledger under it."""
+    asked for, and no cache-dir config call."""
     import jax
 
     from dynamo_tpu.engine.compile_cache import resolve_cache_base
@@ -185,22 +131,14 @@ def test_cache_rule_env_var_places_everything(
     monkeypatch.delenv("DYNAMO_TPU_COMPILE_CACHE_DIR")
     before = jax.config.jax_compilation_cache_dir
     # Whatever base the rule or a caller hands over (an EngineConfig's
-    # own compile_cache_dir, say), the cache object places itself.
-    assert PersistentCompileCache(
-        resolve_cache_base("auto"), engine_fingerprint(_cfg())
-    ).base_dir == outside
-    cache = PersistentCompileCache(
-        resolve_cache_base(str(tmp_path / "explicit")),
-        engine_fingerprint(_cfg()),
-    )
-    assert cache.base_dir == outside
-    cache.activate()
+    # own compile_cache_dir, say), activation places the cache.
+    assert activate_cache(resolve_cache_base("auto")) == outside
+    explicit = resolve_cache_base(str(tmp_path / "explicit"))
+    assert activate_cache(explicit) == outside
     assert jax.config.jax_compilation_cache_dir == before
-    cache.note("unified:t16")
-    cache.flush()
-    assert os.path.exists(
-        os.path.join(outside, cache.key, PersistentCompileCache.LEDGER)
-    )
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    assert os.listdir(outside) == []  # made, and nothing of ours in it
     assert not (tmp_path / "explicit").exists()
 
 
@@ -227,58 +165,119 @@ def test_cache_rule_default_is_inside_checkout_and_none_disables(
     assert resolve_cache_base("auto") == str(tmp_path / "dep")
     explicit = str(tmp_path / "explicit")
     assert resolve_cache_base(explicit) == explicit
-    # Unset, activate() is what points jax at the base.
-    PersistentCompileCache(explicit, engine_fingerprint(_cfg())).activate()
+    # Unset, activate_cache() is what points jax at the base.
+    assert activate_cache(explicit) == explicit
     assert jax.config.jax_compilation_cache_dir == explicit
+    assert os.listdir(explicit) == []
 
 
-class _StubWarmRunner:
-    """Counting stub standing in for XLA when no TPU is present: a shape
-    whose key is in the persistent-cache ledger 'replays from disk'
-    (fast); a fresh one 'compiles' (slow). Drives the real CompileStats /
-    ledger machinery end to end."""
+#: What versions before PR 51 wrote beside XLA's entries (a ledger, the
+#: fingerprint's fields, a shape manifest): neither read nor touched now.
+#: (The names are split so that a search for them finds no live code.)
+_OLDER_FILES = {
+    "0123456789abcdef/warmed" "_shapes.json":
+        '{"fingerprint": "0123456789abcdef", "shapes": ["unified:t16"]}',
+    "0123456789abcdef/meta.json": "{torn",
+    "0123456789abcdef/shape" "_manifest.json":
+        '{"version": 1, "fingerprint": "x", "shapes": [{"kind": "gone"}]}',
+}
 
-    COMPILE_S = 0.02
-    REPLAY_S = 0.0005
 
-    def __init__(self, cache: PersistentCompileCache) -> None:
-        self.compile_stats = CompileStats(cache=cache)
+def _files_under(root) -> dict[str, bytes]:
+    out = {}
+    for base, _dirs, names in os.walk(root):
+        for name in names:
+            path = os.path.join(base, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = f.read()
+    return out
 
-    def warm(self, keys: list[str]) -> float:
-        cs = self.compile_stats
-        t0 = time.monotonic()
-        cs.warming = True
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["empty", "seeded"])
+async def test_start_serve_stop_write_only_xlas_entries(
+    tmp_path, monkeypatch, restore_jax_cache_config, seeded
+):
+    """A start, a warmup, a served request and a stop against a cache
+    directory leave XLA's entries there and no file of this program's;
+    an older version's files (one of them not JSON) change nothing. A
+    second start reads what the first compiled, by XLA's own count."""
+    from dynamo_tpu.engine.engine import TpuEngine
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cache = tmp_path / "cache"
+    if seeded:
+        for rel, text in _OLDER_FILES.items():
+            (cache / rel).parent.mkdir(parents=True, exist_ok=True)
+            (cache / rel).write_text(text)
+    older = _files_under(cache)
+
+    async def start_warm_serve_stop() -> tuple[dict, dict]:
+        engine = TpuEngine(_cfg(
+            max_model_len=32, unified_token_budget=16,
+            unified_prefill_quantum=16, sampling_extras=False,
+            dtype="float32", compile_cache_dir=str(cache),
+        ))
+        await engine.start()
         try:
-            for key in keys:
-                with cs.observe("stub", t=int(key)):
-                    time.sleep(
-                        self.REPLAY_S
-                        if cs.cache.has(shape_key("stub", t=int(key)))
-                        else self.COMPILE_S
-                    )
+            assert engine.runner.compile_cache_dir == str(cache)
+            assert await engine.warmup() == 1
+            assert await _collect(engine, n_prompt=5) == 4
+            cs = engine.runner.compile_stats
+            assert cs.seen == {"unified:t16"}
+            assert cs.mid_traffic_compiles == 0
+            before_stop = _files_under(cache)
         finally:
-            cs.warming = False
-            cs.cache.flush()
-        return time.monotonic() - t0
+            await engine.stop()
+        assert _files_under(cache) == before_stop  # stop() writes no file
+        return dict(cs.warm_cache_events), before_stop
+
+    # A first start compiles everything it asks the cache for.
+    events, after = await start_warm_serve_stop()
+    assert events["misses"] >= 1 and events["hits"] == 0
+    ours = {rel: data for rel, data in after.items() if rel in older}
+    assert ours == older  # neither read into anything nor touched
+    xla = sorted(set(after) - set(older))
+    assert xla and all(
+        os.sep not in rel and rel.endswith(("-cache", "-atime"))
+        for rel in xla
+    ), xla
+    # The second reads it all: XLA's count, not a belief of ours.
+    again, after_again = await start_warm_serve_stop()
+    assert again == {"hits": events["misses"], "misses": 0}
+    assert set(after_again) == set(after)
 
 
-def test_second_cold_start_replays_from_cache(tmp_path):
-    """Acceptance: a second cold-start warmup against a populated
-    persistent cache completes >= 5x faster than the first."""
-    fp = engine_fingerprint(_cfg())
-    keys = [str(i) for i in range(16, 32)]
+@pytest.mark.parametrize("inside", [
+    {"hits": 3, "misses": 0}, {"hits": 1, "misses": 2},
+])
+def test_cache_counters_count_events_inside_warm_ops_only(inside):
+    """warmup_cache_{hits,misses}_total are jax.monitoring's own events,
+    listened for while run_warm_ops runs and at no other time."""
+    import jax.monitoring
 
-    first = _StubWarmRunner(PersistentCompileCache(str(tmp_path), fp))
-    t_first = first.warm(keys)
-    assert first.compile_stats.warmed_programs == len(keys)
-    assert first.compile_stats.replayed_programs == 0
+    event_of = {name: event for event, name in JAX_CACHE_EVENTS.items()}
 
-    # Fresh process: new stats + new cache instance, same directory.
-    second = _StubWarmRunner(PersistentCompileCache(str(tmp_path), fp))
-    t_second = second.warm(keys)
-    assert second.compile_stats.replayed_programs == len(keys)
-    assert second.compile_stats.mid_traffic_compiles == 0
-    assert t_first / t_second >= 5.0
+    def emit(counts):
+        for name, n in counts.items():
+            for _ in range(n):
+                jax.monitoring.record_event(event_of[name])
+        jax.monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+
+    runner = _SimRunner(_cfg(), MockerConfig())
+    cs = runner.compile_stats
+    emit({"hits": 5, "misses": 5})  # before: nobody listens
+    assert runner.run_warm_ops(
+        [("a", lambda: emit(inside)), ("b", lambda: emit({"hits": 1}))]
+    ) == 2
+    emit({"hits": 7, "misses": 7})  # after: unregistered
+    want = {"hits": inside["hits"] + 1, "misses": inside["misses"]}
+    assert cs.warm_cache_events == want
+    snap = cs.snapshot()
+    assert snap["warmup_cache_hits_total"] == want["hits"]
+    assert snap["warmup_cache_misses_total"] == want["misses"]
+    # A second warmup (a program warmed later) adds to the same counters.
+    runner.run_warm_ops([("c", lambda: emit({"misses": 1}))])
+    assert cs.warm_cache_events["misses"] == want["misses"] + 1
 
 
 # ---------------------------------------------------------------------------
@@ -325,9 +324,8 @@ async def test_mid_traffic_counter_on_unwarmed_shape():
         # whose batch snaps to the un-warmed 64 rung then compiles
         # mid-traffic and the counters must say so.
         r = engine.runner
-        hot, tail = r.warmup_plan()
         small = [
-            (key, op) for key, op in hot + tail
+            (key, op) for key, op in r.warm_ops()
             if key in ("unified:t16", "unified:t32")
         ]
         r.run_warm_ops(small)
@@ -348,38 +346,25 @@ async def test_mid_traffic_counter_on_unwarmed_shape():
         await engine.stop()
 
 
-async def test_manifest_saved_on_stop_and_drives_next_warmup(tmp_path):
-    path = str(tmp_path / "manifest.json")
-    cfg = _cfg(shape_manifest_path=path)
-    engine = MockerEngine(cfg, MockerConfig())
+async def test_readiness_after_warmup_carries_xlas_cache_counts():
+    pushed: list[dict] = []  # what goes out on the wire (on_metrics)
+    engine = MockerEngine(
+        _cfg(warmup_gate="hold"), MockerConfig(), on_metrics=pushed.append
+    )
     await engine.start()
-    await engine.warmup()
-    await _collect(engine, n_prompt=40)
-    await engine.stop()
-    assert os.path.exists(path)
-
-    relaunch = MockerEngine(_cfg(shape_manifest_path=path), MockerConfig())
-    await relaunch.start()
     try:
-        n_hot = await relaunch.warmup()
-        # Every unified rung is decode-critical, so the whole grid stays
-        # hot — the manifest's value is ORDERING (observed rungs first)
-        # and the zero-mid-traffic replay below.
-        assert n_hot == len(default_shape_grid(cfg))
-        assert relaunch.is_ready
-        # The 40-token prompt's rung was observed and therefore warmed.
-        observed = shape_key("unified", t=64)
-        assert observed in relaunch.runner.compile_stats.seen
-        for _ in range(100):
-            if relaunch.warm_tail_pending == 0:
-                break
-            await asyncio.sleep(0.05)
-        assert relaunch.warm_tail_pending == 0
-        # Serving the same workload again compiles nothing mid-traffic.
-        await _collect(relaunch, n_prompt=40)
-        assert relaunch.runner.compile_stats.mid_traffic_compiles == 0
+        n = await engine.warmup()
+        assert await _collect(engine, n_prompt=8) == 4
+        assert engine.readiness()["state"] == "ready"
+        for surface in (engine.readiness(), pushed[-1]):
+            assert surface["warmup_programs_total"] == n
+            assert surface["warmup_cache_hits_total"] == 0
+            assert surface["warmup_cache_misses_total"] == 0
+            # The gauge of a tail that was always empty and the ledger's
+            # belief are gone, from every surface.
+            assert not [k for k in surface if "tail" in k or "replayed" in k]
     finally:
-        await relaunch.stop()
+        await engine.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +433,7 @@ async def test_health_warming_503_and_compile_gauges():
     from dynamo_tpu.llm.http_service import HttpService
 
     state = {"state": "warming", "mid_traffic_compiles_total": 0,
-             "warm_tail_pending": 3}
+             "warmup_cache_misses_total": 3}
     service = HttpService(
         ModelManager(), host="127.0.0.1", port=0,
         readiness=lambda: dict(state),
@@ -461,7 +446,7 @@ async def test_health_warming_503_and_compile_gauges():
                 assert resp.status == 503
                 body = await resp.json()
                 assert body["status"] == "warming"
-                assert body["engine"]["warm_tail_pending"] == 3
+                assert body["engine"]["warmup_cache_misses_total"] == 3
             async with s.get(f"{base}/live") as resp:
                 assert resp.status == 200  # liveness unaffected by warmup
             state["state"] = "ready"
@@ -473,5 +458,6 @@ async def test_health_warming_503_and_compile_gauges():
                 text = await resp.text()
                 assert "engine_ready 1.0" in text
                 assert "mid_traffic_compiles_total 2" in text
+                assert "warmup_cache_misses_total 3" in text
     finally:
         await service.stop()

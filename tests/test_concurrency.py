@@ -317,8 +317,6 @@ def test_compile_stats_concurrent_observe_is_exact(checker_on):
     snap = cs.snapshot()
     assert snap["mid_traffic_compiles_total"] == N
     assert len(cs.seen) == N
-    # The manifest records every real execution (2N), exactly.
-    assert sum(e["count"] for e in cs.manifest.shapes.values()) == 2 * N
 
 
 def test_engine_thread_binding_via_flush_side_channels(checker_on):

@@ -13,7 +13,6 @@ path.
 """
 
 import asyncio
-import dataclasses
 
 import numpy as np
 import pytest
@@ -442,25 +441,6 @@ def test_kv_quant_conflict_messages_name_flag_pairs():
             model=CFG, kv_quant="int8", kv_sp=True,
             mesh_shape={"tp": 1, "sp": 2},
         ).validate()
-
-
-def test_compile_cache_fingerprint_covers_quant_family():
-    from dynamo_tpu.engine.compile_cache import (
-        engine_fingerprint,
-        fingerprint_key,
-    )
-
-    base = EngineConfig(model=CFG)
-    keys = {
-        fingerprint_key(engine_fingerprint(c))
-        for c in (
-            base,
-            dataclasses.replace(base, weight_quant="int8"),
-            dataclasses.replace(base, weight_quant="attn=int8"),
-            dataclasses.replace(base, kv_quant="int8"),
-        )
-    }
-    assert len(keys) == 4  # each quant choice lands in its own namespace
 
 
 # ---------------------------------------------------------------------------

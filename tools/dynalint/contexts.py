@@ -73,8 +73,6 @@ SEED_CONTEXTS: dict[str, dict[str, tuple[str, ...]]] = {
         "CompileStats.observe": (ENGINE, WORKER),
         # Scraped by readiness()/metrics callbacks on the asyncio loop.
         "CompileStats.snapshot": (LOOP, ENGINE),
-        "ShapeManifest.record": (ENGINE, WORKER),
-        "PersistentCompileCache.note": (ENGINE, WORKER),
     },
     "dynamo_tpu/engine/flight_recorder.py": {
         "FlightRecorder.note_step": (ENGINE,),

@@ -2,8 +2,8 @@
 
 The crash-consistency law (docs/architecture/integrity.md
 "Crash-consistent persistence"): durable state is written tmp +
-`os.replace` + fsync — the `utils/atomic_io.py` discipline the shape
-manifest, compile-cache ledger, G3 sidecar, and planner state all ride.
+`os.replace` + fsync — the `utils/atomic_io.py` discipline the G3
+sidecar and planner state ride.
 A raw `open(path, "w")` / `json.dump` / `Path.write_text` torn by a
 crash leaves half-written state that a restart then trusts; PR 18's
 torn-sidecar drill exists precisely because this bug class was real.
