@@ -1097,7 +1097,11 @@ class TpuEngine:
         compose_ms = 1000.0 * (time.monotonic() - t_compose)
         # The runner's count for THIS dispatch (a record noted at its
         # retire would read the next one's).
-        folds = getattr(self.runner, "attn_folds", (0, 0))
+        # (short folds, long folds, expanded spans, expanded rows)
+        folds = (
+            *getattr(self.runner, "attn_folds", (0, 0)),
+            *getattr(self.runner, "attn_expanded", (0, 0)),
+        )
         self._inflight.append(
             (
                 "unified",
@@ -2023,7 +2027,9 @@ class TpuEngine:
         lanes: int = 0,
         drafted: int = 0,
         accepted: int = 0,
-        folds: tuple[int, int] = (0, 0),  # the runner's count AT ITS ISSUE
+        # the runner's counts AT ITS ISSUE: the ragged kernel's (short,
+        # long) folds, then the (spans, rows) the expanded body took
+        folds: tuple[int, ...] = (0, 0, 0, 0),
         **diffusion: int,  # and the expert layers' and recurrent layers' counts
     ) -> None:
         """One dispatch's flight record (engine thread). Counter fields
@@ -2044,6 +2050,8 @@ class TpuEngine:
             accepted=accepted,
             attn_short_folds=folds[0],
             attn_long_folds=folds[1],
+            attn_expanded_spans=folds[2],
+            attn_expanded_rows=folds[3],
             **diffusion,
             # The runner's last dispatch IS this record's: plain records
             # are noted at issue, spec records at retire under depth 1.
@@ -2892,6 +2900,12 @@ class TpuEngine:
                     getattr(self.runner, "attn_folds_total", (0, 0)),
                 )
             },
+            # What left that kernel for the expanded body (long spans of a
+            # latent layer held once: ops/pallas/latent_expanded.py).
+            **dict(zip(
+                ("attn_expanded_spans_total", "attn_expanded_rows_total"),
+                getattr(self.runner, "attn_expanded_total", (0, 0)),
+            )),
             # The paged cache as allocated: arrays a layer (1 where a
             # latent is held once) and what a live token costs over all
             # layers.

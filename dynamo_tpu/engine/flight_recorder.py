@@ -95,6 +95,8 @@ class FlightRecorder:
         context_tokens_live: int = 0,
         attn_short_folds: int = 0,
         attn_long_folds: int = 0,
+        attn_expanded_spans: int = 0,
+        attn_expanded_rows: int = 0,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -141,7 +143,12 @@ class FlightRecorder:
         the layers that call it: how the kernel's work divides between
         its two fold bodies (the host's count from the spans,
         ops/pallas/ragged_attention.py ``fold_counts``; 0 where the XLA
-        twin serves)."""
+        twin serves). ``attn_expanded_spans`` / ``attn_expanded_rows`` are
+        the spans, and their rows, that a latent layer held once sent
+        through the expanded body instead (ops/pallas/latent_expanded.py
+        ``expanded_spans``, the program's own rule on the host): they are
+        no folds of the ragged kernel's, and rows / (decode + prefill
+        tokens) is how much of the dispatch the expanded form answered."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -174,6 +181,8 @@ class FlightRecorder:
             "context_tokens_live": context_tokens_live,
             "attn_short_folds": attn_short_folds,
             "attn_long_folds": attn_long_folds,
+            "attn_expanded_spans": attn_expanded_spans,
+            "attn_expanded_rows": attn_expanded_rows,
             "inflight_depth": inflight_depth,
             "waiting": waiting,
             "running": running,

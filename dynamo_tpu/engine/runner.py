@@ -407,7 +407,11 @@ class ModelRunner(WarmupPlanMixin):
         #: chip's heads, and the layers that call the kernel by their
         #: window; None where the XLA twin or the striped kv_sp scan
         #: serves. `attn_folds` is the last dispatch's (short, long) folds
-        #: over those layers, `attn_folds_total` every dispatch's.
+        #: over those layers, `attn_folds_total` every dispatch's. The LONG
+        #: spans of a latent layer held once leave that kernel for the
+        #: expanded body (ops/pallas/latent_expanded.py): `attn_expanded`
+        #: is the last dispatch's (spans, rows) that did, by the rule the
+        #: program applies, `attn_expanded_total` every dispatch's.
         self._fold_plan = None
         if use_pallas and not cfg.kv_sp and n_groups:
             from dynamo_tpu.ops.pallas.ragged_attention import (
@@ -440,6 +444,12 @@ class ModelRunner(WarmupPlanMixin):
             )
         self.attn_folds = (0, 0)
         self.attn_folds_total = [0, 0]
+        self.attn_expanded = (0, 0)
+        self.attn_expanded_total = [0, 0]
+        #: A chip's query heads where the layer body's static gates let a
+        #: long span through the expanded body, else 0 (set below, once the
+        #: weights are there).
+        self._expand_heads = 0
 
         def kv_shape(li: int) -> tuple:
             slots = self.group_blocks[m.layer_cache_group(li)] * cfg.block_size
@@ -679,6 +689,20 @@ class ModelRunner(WarmupPlanMixin):
             )
             self.weight_quant_bytes_saved = float(saved)
             self.weight_quant_density = float(density)
+
+        # The layer body's static gates for the expanded form, mirrored
+        # (models/llama.py `_layer_rows`): every layer's cache held once,
+        # the Pallas path, pages and `w_uk` / `w_uv` plain arrays of the
+        # model's dtype.
+        if self._fold_plan is not None and m.is_mla and m.cache_arrays == 1:
+            from dynamo_tpu.ops.quant import is_quantized
+
+            if self.kv_scales is None and all(
+                not is_quantized(w) and w.dtype == self.kv_dtype == self.dtype
+                for layer in self.params["layers"]
+                for w in (layer["w_uk"], layer["w_uv"])
+            ):
+                self._expand_heads = m.num_heads // tp
 
         bs = cfg.block_size
         attn = self.attn
@@ -1494,7 +1518,7 @@ class ModelRunner(WarmupPlanMixin):
         buf = lay.template.copy()
         seg = lay.views(buf)
         n_l = len(lanes)
-        self.attn_folds = (0, 0)
+        self.attn_folds = self.attn_expanded = (0, 0)
         if n_l:
             q_len = np.fromiter((len(t) for t, _, _, _ in lanes), np.int32, n_l)
             prefix = np.fromiter((p for _, _, p, _ in lanes), np.int32, n_l)
@@ -1505,7 +1529,7 @@ class ModelRunner(WarmupPlanMixin):
             seg["q_len"][:n_l] = q_len
             kv_len = prefix + q_len
             seg["kv_len"][:n_l] = kv_len
-            self._count_folds(prefix, q_len, kv_len)
+            self._count_folds(prefix, q_len, kv_len, T)
             # A table and the written rows' slots for each cache group; the
             # first group's pair of segments is the unnumbered one.
             n_groups = len(self.group_blocks)
@@ -1576,16 +1600,36 @@ class ModelRunner(WarmupPlanMixin):
         meta_args = tuple(_meta_of(seg, len(self.group_blocks)))
         return base_args, meta_args, _Operands(buf, seg, prev_toks, feed_transfers)
 
-    def _count_folds(self, q_start, q_len, kv_len) -> None:
+    def _count_folds(self, q_start, q_len, kv_len, T: int) -> None:
         """Note how the dispatch's kernel work divides between the
         kernel's two tiles: the folds of the ring its SHORT tile (decode
         rows, diffusion blocks) and its LONG tile (prefill quanta, verify
         spans) walk, summed over the layers that call it (ops/pallas/
         ragged_attention.py ``fold_counts``; the engine's thread, every
-        dispatch: microseconds)."""
+        dispatch: microseconds). The spans a latent layer held once sends
+        through the expanded body at this rung of ``T`` rows are counted
+        apart (``attn_expanded``: spans, rows) by the program's own rule
+        (ops/pallas/latent_expanded.py ``expanded_spans``), and are no
+        folds of the ragged kernel's."""
         plan = self._fold_plan
         if plan is None:
             return
+        if self._expand_heads:
+            from dynamo_tpu.ops.pallas.latent_expanded import (
+                expanded_k,
+                expanded_spans,
+            )
+
+            k = expanded_k(self.cfg.model, T, self._expand_heads)
+            gone = expanded_spans(q_len, kv_len, k) if k else None
+            if gone is not None and gone.any():
+                self.attn_expanded = (int(gone.sum()), int(q_len[gone].sum()))
+                self.attn_expanded_total[0] += self.attn_expanded[0]
+                self.attn_expanded_total[1] += self.attn_expanded[1]
+                q_start, q_len, kv_len = (
+                    a[~gone] for a in (q_start, q_len, kv_len))
+                if not len(q_len):
+                    return
         short = long = 0
         for window, layers in plan["layers"].items():
             s, l = plan["count"](q_start, q_len, kv_len, window=window)

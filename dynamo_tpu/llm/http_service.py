@@ -243,6 +243,8 @@ class HttpService:
                 "engine_handoff_wait_seconds_total",
                 'attn_folds_total{tile="short"}',
                 'attn_folds_total{tile="long"}',
+                "attn_expanded_spans_total",
+                "attn_expanded_rows_total",
                 "last_dispatch_age_s",
                 "num_waiting_interactive",
                 "num_waiting_batch",

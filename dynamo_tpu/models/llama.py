@@ -29,6 +29,7 @@ from dynamo_tpu.ops.quant import (
     QUANT_AXES,
     WEIGHT_FORMATS,
     embed_lookup,
+    is_quantized,
     policy_layer_fmts,
     qdot,
     qeinsum,
@@ -371,7 +372,8 @@ def _dense3(key, shape, fan_in, dtype):
     )
 
 
-def _qkv_mla(layer: Params, x: jnp.ndarray, cfg: ModelConfig, positions):
+def _qkv_mla(layer: Params, x: jnp.ndarray, cfg: ModelConfig, positions,
+             unabsorbed: bool = False):
     """DeepSeek MLA projections with the absorbed-matrix trick.
 
     Instead of materializing per-head K/V (reference models do at decode
@@ -383,6 +385,9 @@ def _qkv_mla(layer: Params, x: jnp.ndarray, cfg: ModelConfig, positions):
     (q [T, H, dc+dr], k_entry [T, 1, dc+dr], v_entry [T, 1, dc+dr])
     where v_entry is the latent zero-padded to the key width (its roped
     tail contributes nothing to the value read; _mla_out up-projects).
+    With ``unabsorbed`` a fourth result is the query as the published form
+    takes it, ``[T, H, dn + dr]`` with its tail rotated and NO scale (the
+    expanded body's operand, ops/pallas/latent_expanded.py).
     """
     H = cfg.num_heads
     dn, dr, dc = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -416,15 +421,24 @@ def _qkv_mla(layer: Params, x: jnp.ndarray, cfg: ModelConfig, positions):
     q_full = jnp.concatenate([q_lat, q_pe], axis=-1) * corr
     k_entry = jnp.concatenate([c, k_pe], axis=-1)[:, None, :]
     v_entry = jnp.pad(c, ((0, 0), (0, dr)))[:, None, :]
+    if unabsorbed:
+        q_exp = jnp.concatenate([q_nope, q_pe], axis=-1).astype(x.dtype)
+        return q_full.astype(x.dtype), k_entry, v_entry, q_exp
     return q_full.astype(x.dtype), k_entry, v_entry
 
 
-def _mla_out(layer: Params, attn: jnp.ndarray, cfg: ModelConfig):
+def _mla_out(layer: Params, attn: jnp.ndarray, cfg: ModelConfig,
+             expanded=None):
     """Attention output [..., H, dc+dr] → up-project the latent part per
-    head (absorbed W_uv) and apply the output projection."""
+    head (absorbed W_uv) and apply the output projection. ``expanded`` is
+    ``(rows [T] bool, values [T, H, v])``: the rows the expanded body
+    answered, whose values are up-projected already."""
     dc = cfg.kv_lora_rank
     o_lat = attn[..., :dc]
     o = qeinsum("...hc,hvc->...hv", o_lat, layer["w_uv"])
+    if expanded is not None:
+        rows, values = expanded
+        o = jnp.where(rows[:, None, None], values, o)
     lead = o.shape[:-2]
     return qdot(
         o.reshape(*lead, cfg.num_heads * cfg.v_head_dim).astype(attn.dtype),
@@ -672,7 +686,39 @@ def _layer_rows(
         x = _residual_mlp(x + y, layer, cfg, spec, mesh, valid)
         return x, cache, kv_scale, state
     positions = jnp.maximum(token_pos, 0)
-    if cfg.is_mla:
+    if attn is None and spec.cache_arrays == 1:
+        from dynamo_tpu.ops.attention import default_dispatch
+
+        attn = default_dispatch(block_size, cache[0])
+    # Two forms of latent attention, by span (docs/architecture/
+    # unified_step.md "Two forms, by span"): a layer whose cache is held
+    # once, read by the Pallas kernel from plain arrays of the model's
+    # dtype, sends its LONG spans through the expanded body; ``expand`` is
+    # the rule's K at this rung, 0 where no span of it can be long (the
+    # rung then compiles the program it had).
+    expand = 0
+    if (
+        spec.cache_arrays == 1 and attn.use_pallas and kv_scale is None
+        and not attn.kv_sp
+        and all(
+            not is_quantized(w) and w.dtype == cache[0].dtype == x.dtype
+            for w in (layer["w_uk"], layer["w_uv"])
+        )
+    ):
+        from dynamo_tpu.ops.pallas.latent_expanded import (
+            expanded_k,
+            expanded_spans,
+        )
+
+        tp = 1 if attn.mesh is None else attn.mesh.shape.get(attn.tp_axis, 1)
+        expand = expanded_k(cfg, T, cfg.num_heads // tp)
+    if expand:
+        with jax.named_scope("latent_mixer"):
+            q, k, v, q_exp = _qkv_mla(layer, h, cfg, positions, True)
+        long = expanded_spans(q_len, kv_len, expand)
+        q_len_long = jnp.where(long, q_len, 0)
+        q_len = jnp.where(long, 0, q_len)   # the absorbed call's
+    elif cfg.is_mla:
         with jax.named_scope("latent_mixer"):
             q, k, v = _qkv_mla(layer, h, cfg, positions)
     else:
@@ -730,8 +776,21 @@ def _layer_rows(
         x = _residual_mlp(x, layer, cfg, spec, mesh, valid, h=h) + a
         return x, cache, kv_scale, state
     if cfg.is_mla:
+        expanded = None
+        if expand:
+            with jax.named_scope("latent_mixer/attn_latent"):
+                scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+                if cfg.rope_scaling is not None:
+                    scale *= cfg.rope_scaling.attn_mscale() ** 2
+                values = attn.latent_expanded(
+                    q_exp, k_cache, layer["w_uk"], layer["w_uv"],
+                    block_tables, q_start, q_len_long, row_start,
+                    block_size, scale=scale,
+                )
+            rows = long[jnp.clip(token_seq, 0, long.shape[0] - 1)]
+            expanded = (rows & (token_pos >= 0), values)
         with jax.named_scope("latent_mixer"):
-            x = x + _mla_out(layer, attn_out, cfg)
+            x = x + _mla_out(layer, attn_out, cfg, expanded)
     else:
         x = _residual_attn(
             x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg

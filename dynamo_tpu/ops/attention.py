@@ -275,6 +275,36 @@ class AttnDispatch:
             )
         return out[..., :D]
 
+    def latent_expanded(
+        self, q, k_cache, w_uk, w_uv, block_tables, q_start, q_len,
+        row_start, block_size: int, *, scale: float,
+    ):
+        """The LONG spans of a latent layer whose cache is held once, in
+        the expanded form (ops/pallas/latent_expanded.py; the Pallas path
+        only: the twin stays absorbed): ``q`` ``[T, H, nope + rope]``
+        un-absorbed, ``q_len`` the long spans' rows and 0 elsewhere;
+        returns the up-projected values ``[T, H, v]``, zeros on every other
+        row. Under a mesh each shard runs its own query heads against the
+        replicated array, ``w_uk`` / ``w_uv`` sharded with them."""
+        from dynamo_tpu.ops.pallas.latent_expanded import (
+            ragged_paged_attention_pallas_expanded,
+        )
+
+        fn = partial(
+            ragged_paged_attention_pallas_expanded, block_size=block_size,
+            scale=scale,
+        )
+        if self.mesh is not None:
+            from jax.sharding import PartitionSpec as P
+
+            qh, wh = P(None, self._ax, None), P(self._ax, None, None)
+            fn = self._wrap(
+                fn, in_specs=(qh, P(), wh, wh, P(), P(), P(), P()),
+                out_specs=qh,
+            )
+        return fn(
+            q, k_cache, w_uk, w_uv, block_tables, q_start, q_len, row_start)
+
 
 def _pad_q_for_cache(q, k_cache):
     """Lane-pad q to a padded cache's head dim (ops/pallas/attention.py
@@ -571,6 +601,18 @@ def ragged_attention(
 ):
     """Default (single-chip, env-driven) dispatch for the unified step,
     for callers with no per-runner AttnDispatch to thread in."""
+    return default_dispatch(block_size, k_cache).ragged(
+        q, k_cache, v_cache, block_tables, token_seq, token_pos, q_start,
+        q_len, kv_len, row_start, block_size, window,
+        k_scales=k_scales, v_scales=v_scales,
+        diffusion_block=diffusion_block,
+    )
+
+
+def default_dispatch(block_size: int, k_cache) -> AttnDispatch:
+    """The single-chip dispatch the environment asks for over this cache:
+    the Pallas kernels where they are enabled and the cache's shape passes
+    their gate, else the XLA twin."""
     use_pallas = False
     if pallas_enabled():
         from dynamo_tpu.ops.pallas.attention import pallas_supported
@@ -578,12 +620,7 @@ def ragged_attention(
         use_pallas = pallas_supported(
             block_size, k_cache.shape[1], k_cache.shape[2], k_cache.dtype
         )
-    return AttnDispatch(use_pallas=use_pallas).ragged(
-        q, k_cache, v_cache, block_tables, token_seq, token_pos, q_start,
-        q_len, kv_len, row_start, block_size, window,
-        k_scales=k_scales, v_scales=v_scales,
-        diffusion_block=diffusion_block,
-    )
+    return AttnDispatch(use_pallas=use_pallas)
 
 
 def full_causal_attention(

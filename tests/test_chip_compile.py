@@ -547,6 +547,39 @@ def test_ragged_kernel_compiles_over_a_latent_held_once(
     assert _kernel_count(lowered.compile().as_text()) == 1
 
 
+@pytest.mark.parametrize("T,heads", [
+    (1024, 128),   # deepseek-v2-ep4-l5's top rung: one tile of 1,024 rows
+    (512, 128),    # the lowest rung that carries the body
+    (2048, 32),    # no cell's: a tp=4 chip's heads, spans of two tiles
+])
+def test_expanded_latent_body_compiles_at_published_widths(
+    mosaic, one_chip, T, heads
+):
+    """The expanded form's body (ops/pallas/latent_expanded.py) at
+    DeepSeek-V2's widths: un-absorbed queries of 128 + 64, ``w_uk`` /
+    ``w_uv`` of 128 x 512 a head, ONE cached head of 640 with a table of
+    1,280 entries a row (20,480 tokens of context), under the ragged
+    kernel's ``VMEM_LIMIT``; one kernel, under a name the benchmark's
+    readers sum with the ragged kernel's."""
+    from dynamo_tpu.ops.pallas import latent_expanded
+
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    bf = partial(_sds, dtype=jnp.bfloat16, sharding=one_chip)
+    lanes = 52
+    k = latent_expanded.expanded_k(ModelConfig.deepseek_v2_ep4_l5(), T)
+    assert k and k == latent_expanded.expanded_k(ModelConfig.deepseek_v2(), T)
+    assert not latent_expanded.expanded_k(ModelConfig.deepseek_v2_ep4_l5(), 256)
+    compiled = latent_expanded.ragged_paged_attention_pallas_expanded.lower(
+        bf((T, heads, 192)), bf((NUM_BLOCKS * BS, 1, 640)),
+        bf((heads, 128, 512)), bf((heads, 128, 512)),
+        i32((lanes, 1280)), i32((lanes,)), i32((lanes,)), i32((lanes,)),
+        block_size=BS, scale=0.1147,
+    ).compile()
+    text = compiled.as_text()
+    assert _kernel_count(text) == 1
+    assert "ragged_paged_attention_pallas_expanded" in text
+
+
 @pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
 def test_deepseek_v2_share_step_compiles_at_published_widths(
     mosaic, one_chip, monkeypatch
@@ -586,8 +619,9 @@ def test_deepseek_v2_share_step_compiles_at_published_widths(
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, kv, *meta).compile()
     text = compiled.as_text()
-    # five layers' ragged kernel + four expert layers x (gate, up, down)
-    assert _kernel_count(text) == 5 + 4 * 3
+    # five layers' ragged kernel and its expanded body (the top rung holds
+    # long spans) + four expert layers x (gate, up, down)
+    assert _kernel_count(text) == 5 * 2 + 4 * 3
     assert "latent_mixer/attn_latent" in text
     mem = compiled.memory_analysis()
     # weights 10.33 GB + 40,000 blocks x 100 KiB = 4.10 GB

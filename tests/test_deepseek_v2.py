@@ -124,13 +124,13 @@ async def generate(engine, prompt, n):
     return [t for c in chunks for t in c]
 
 
-def follows_the_reference(prompt, got) -> None:
+def follows_the_reference(prompt, got, pad_to: int = PAD_TO) -> None:
     """Every served token is the argmax of the reference's ONE full forward
     pass over the prompt and the tokens served before it, where its lead is
     clear."""
     n = len(prompt) + len(got)
-    assert n <= PAD_TO and len(got) <= ROWS
-    seq = np.zeros((1, PAD_TO), np.int32)
+    assert n <= pad_to and len(got) <= ROWS
+    seq = np.zeros((1, pad_to), np.int32)
     seq[0, :n] = list(prompt) + list(got)
     rows = np.minimum(
         np.arange(len(prompt) - 1, len(prompt) - 1 + ROWS), n - 2
@@ -384,6 +384,255 @@ def test_the_held_once_call_is_named_inside_the_latent_mixer():
     text = runner.lower_unified_top().as_text(debug_info=True)
     assert "latent_mixer/attn_latent" in text
     assert "attn_full" not in text and "attn_window" not in text
+
+
+# -- (c2) two forms of latent attention, by span (PR 53) ---------------------
+
+# The tiny widths' rule: absorbed 2 x (40 + 32) = 144 FLOP a pair, expanded
+# 2 x (24 + 16) = 80, the up-projection 2 x 32 x 32 = 2,048 a key:
+# K = 2 x 2,048 / 64 x the margin, at a budget of 256 rows.
+BUDGET = 256
+
+
+def _rule_k(budget: int = BUDGET) -> int:
+    from dynamo_tpu.ops.pallas.latent_expanded import (
+        EXPANDED_MARGIN,
+        expanded_k,
+    )
+
+    k = expanded_k(ModelConfig.tiny_deepseek_v2_test(), budget)
+    assert k == int(np.ceil(64 * EXPANDED_MARGIN)) and budget >= k
+    return k
+
+
+def _rule_sends(lens, decode_steps=0, budget=BUDGET):
+    """[spans, rows] the rule sends through the expanded body when the
+    benchmark's driver packs ``lens`` (``span.plan_steps``), and the
+    (prefix, rows) spans it sends."""
+    from dynamo_tpu.ops.pallas.latent_expanded import expanded_spans
+
+    sent = []
+    for step in span.plan_steps(lens, decode_steps, budget):
+        pre = np.int32([p for _, p, _ in step])
+        n = np.int32([n for _, _, n in step])
+        gone = expanded_spans(n, pre + n, _rule_k(budget))
+        sent += list(zip(pre[gone].tolist(), n[gone].tolist()))
+    return [len(sent), sum(n for _, n in sent)], sent
+
+
+def _long_runner(monkeypatch, pallas="1", held=16, **kw):
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", pallas)
+    cfg = dict(unified_token_budget=BUDGET, unified_prefill_quantum=BUDGET,
+               max_model_len=1024, num_blocks=512, max_num_seqs=4)
+    return ModelRunner(
+        engine_config(ModelConfig.tiny_deepseek_v2_test(held=held),
+                      **{**cfg, **kw}),
+        rng_seed=SEED)
+
+
+def test_long_spans_cross_attention_in_the_expanded_form(monkeypatch):
+    """A budget of 256 rows holds spans over the rule (interpret mode,
+    float32): prompts of 5, 200, 600 and 70 tokens packed into dispatches
+    of 256, the 600 cut across four of them (quanta of 51, 256, 256 and 37
+    rows behind 0, 51, 307 and 563 positions), every one past YaRN's
+    original 32 positions, then six decode steps: logits against the
+    reference's one pass in the published form. The long spans take the
+    expanded body, the short spans and the decode rows the absorbed kernel,
+    in ONE program; the scale (YaRN's ``mscale^2`` in it) is applied once:
+    without it the rows differ."""
+    runner = _long_runner(monkeypatch)
+    lens = (5, 200, 600, 70)
+    (spans, rows), sent = _rule_sends(lens, 6)
+    assert (0, 200) in sent and (51, 256) in sent and (307, 256) in sent
+    assert not [1 for p, n in sent if n in (5, 51, 37, 70, 1)]
+    tokens = check.sample_tokens(11, 384, [n + 6 for n in lens], 640)
+    out = span.drive(runner, tokens, lens, 6, 11)
+    want = reference_logits(tokens, out["rows"], 16)
+    v = check.verdict(out["logits"], want, out["served"], out["decode"],
+                      out["judged"])
+    assert v["rel_err"] < 2e-4, v
+    assert v["token_mismatches"] == 0
+    off = reference_logits(tokens, out["rows"], 16, rope_scaling=None)
+    assert check.verdict(out["logits"], off, out["served"], out["decode"],
+                         out["judged"])["rel_err"] > 1e-2
+    # the host counted what the program's rule sent (the driver packs each
+    # dispatch twice: once for the logits, once for the step)
+    assert runner.attn_expanded_total == [2 * spans, 2 * rows]
+    assert runner.attn_expanded == (0, 0)      # the last dispatch: decode
+
+
+def test_a_span_one_row_under_the_rule_stays_absorbed(monkeypatch):
+    """From position 0 a span passes the rule at K rows: two prompts of K -
+    1 and K rows share ONE dispatch, the first stays in the absorbed kernel
+    (it is the ragged kernel's only long fold), the second leaves it (the
+    counter reads it alone), and both equal the reference."""
+    k = _rule_k()
+    lens = (k - 1, k)
+    assert _rule_sends(lens, 2) == ([1, k], [(0, k)])
+    runner = _long_runner(monkeypatch)
+    tokens = check.sample_tokens(5, 384, [n + 2 for n in lens], 256)
+    out = span.drive(runner, tokens, lens, 2, 5)
+    want = reference_logits(tokens, out["rows"], 16)
+    v = check.verdict(out["logits"], want, out["served"], out["decode"],
+                      out["judged"])
+    assert v["rel_err"] < 2e-4 and v["token_mismatches"] == 0, v
+    assert runner.attn_expanded_total == [2, 2 * k]
+    # the K - 1 rows are still the ragged kernel's long tile's
+    assert runner.attn_folds_total[1] > 0
+
+
+async def test_a_prefix_hit_behind_a_long_span_and_the_engines_counters(
+        monkeypatch):
+    """The engine's own path: a second prompt reuses the first's 96
+    leading tokens (twelve blocks) and its remaining 120 rows, behind that
+    prefix, pass the rule (from position 0 they would not). A step's
+    flight record carries ``attn_expanded_spans`` / ``attn_expanded_rows``
+    as the runner counted them for THAT dispatch, their sums are
+    ``attn_expanded_*_total`` on ``readiness()``, and the served tokens
+    are the reference's."""
+    from dynamo_tpu.ops.pallas.latent_expanded import expanded_spans
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    k = _rule_k()
+    engine = TpuEngine(engine_config(
+        unified_token_budget=BUDGET, unified_prefill_quantum=BUDGET,
+        max_model_len=256, num_blocks=128))
+    await engine.start()
+    try:
+        assert engine.runner.attention_path == "pallas"
+        rng = np.random.default_rng(3)
+        first = rng.integers(1, 384, 200).tolist()
+        second = first[:96] + rng.integers(1, 384, 120).tolist()
+        for n, kv, sent in ((200, 200, True), (120, 120, False),
+                            (120, 216, True)):
+            assert bool(expanded_spans(
+                np.int32([n]), np.int32([kv]), k)[0]) == sent
+        got = await generate(engine, first, 4)
+        follows_the_reference(first, got, 256)
+        before = engine.readiness()["kv_reused_device_blocks_total"]
+        got = await generate(engine, second, 4)
+        follows_the_reference(second, got, 256)
+        ready = engine.readiness()
+        assert ready["kv_reused_device_blocks_total"] - before == 12
+        steps = [r for r in engine.debug_steps() if "dispatch_ms" in r]
+        took = [(r["attn_expanded_spans"], r["attn_expanded_rows"])
+                for r in steps if r["attn_expanded_rows"]]
+        assert took == [(1, 200), (1, 120)]
+        assert all(r["attn_long_folds"] == 0 for r in steps)
+        assert ready["attn_expanded_spans_total"] == 2
+        assert ready["attn_expanded_rows_total"] == 320
+    finally:
+        await engine.stop()
+
+
+@pytest.mark.parametrize("gate", ["xla_twin", "int8_weights", "int8_kv",
+                                  "short_rung"])
+def test_the_static_gates_keep_the_absorbed_program(monkeypatch, gate):
+    """What the layer body reads of its operands decides whether a rung
+    holds the expanded body at all: the XLA twin, int8 weights (``w_uk`` /
+    ``w_uv`` quantised), int8 pages and a rung that no long span fits each
+    compile the absorbed program alone, and the host counts nothing."""
+    kw = {"int8_weights": dict(quant="int8"),
+          "int8_kv": dict(kv_quant="int8"),
+          "short_rung": dict(unified_token_budget=32,
+                             unified_prefill_quantum=32)}.get(gate, {})
+    runner = _long_runner(
+        monkeypatch, pallas="0" if gate == "xla_twin" else "1", **kw)
+    text = runner.lower_unified_top().as_text(debug_info=True)
+    assert "pallas_expanded" not in text
+    n = runner.cfg.unified_token_budget - 8
+    lanes = [(list(range(1, n + 1)), [1 + i for i in range(-(-n // 8))],
+              0, (0.0, 0, 1.0))]
+    runner._unified_operands(lanes, None, runner.cfg.unified_token_budget)
+    assert runner.attn_expanded == (0, 0)
+    assert runner.attn_expanded_total == [0, 0]
+    if gate == "short_rung":
+        return
+    # the same model with the gate open holds both bodies
+    open_ = _long_runner(monkeypatch)
+    assert "pallas_expanded" in open_.lower_unified_top().as_text(
+        debug_info=True)
+    open_._unified_operands(lanes, None, BUDGET)
+    assert open_.attn_expanded == (1, n)
+
+
+def test_a_tp_mesh_runs_the_expanded_body_a_shard(monkeypatch):
+    """Under a ``tp`` mesh (the CPU's virtual devices) each shard runs the
+    expanded body on its own query heads, ``w_uk`` / ``w_uv`` sharded with
+    them, over the replicated array: a 200-row prompt and two decode steps
+    equal the reference."""
+    from dynamo_tpu.parallel.mesh import build_mesh
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    runner = ModelRunner(
+        engine_config(
+            unified_token_budget=BUDGET, unified_prefill_quantum=BUDGET,
+            max_model_len=512, num_blocks=128),
+        rng_seed=SEED, mesh=build_mesh({"tp": 2, "dp": 4}))
+    assert runner.attention_path == "pallas"
+    tokens = check.sample_tokens(7, 384, [202], 256)
+    out = span.drive(runner, tokens, (200,), 2, 7)
+    want = reference_logits(tokens, out["rows"])
+    v = check.verdict(out["logits"], want, out["served"], out["decode"],
+                      out["judged"])
+    assert v["rel_err"] < 2e-4 and v["token_mismatches"] == 0, v
+    assert runner.attn_expanded_total == [2, 2 * 200]
+
+
+#: sha256 (16 digits) of the unified step's jaxpr at T 256 and T 16 on the
+#: tree BEFORE the expanded form (commit 95a6a70), with the Pallas kernels:
+#: Ling's family calls ``_qkv_mla`` and keeps (k, v); Mistral's never enters
+#: ``latent_mixer``. Equal hashes: their programs are that tree's,
+#: operation for operation. A PR that MEANS to change those programs
+#: regenerates them (``_step_hash`` below, on its parent).
+PARENT_STEP_HASHES = {
+    "tiny_ling_test": ("6cde5bf6168414cc", "a43fb4b18db7ef0a"),
+    "tiny_test": ("89e97bf9378c8589", "b5a70d6f103a9f2b"),
+}
+
+
+def _step_hash(cfg: ModelConfig, T: int, rows: int = 12) -> str:
+    import hashlib
+    import re
+    from functools import partial
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops.pallas.attention import cache_head_dim
+
+    sds = jax.ShapeDtypeStruct
+    f32, bs = jnp.float32, 16
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, f32))
+    page = sds(
+        (64 * bs, cfg.num_cache_heads, cache_head_dim(cfg.kv_cache_head_dim)),
+        f32)
+    kv = [(page,) * cfg.layer_cache_arrays(li)
+          if cfg.layer_kind(li) == "attn" else ()
+          for li in range(cfg.num_layers)]
+    rec = [tuple(sds(s, d) for s, d in
+                 cfg.recurrent_state_arrays(li, rows, "float32"))
+           for li in cfg.recurrent_layers] or None
+    i32 = partial(sds, dtype=jnp.int32)
+    meta = (i32((T,)),) * 4 + (i32((rows, 32)),) + (i32((rows,)),) * 4
+
+    def step(params, kv, rec, slot, *meta):
+        kw = dict(rec_state=rec, state_slot=slot) if rec is not None else {}
+        return llama.unified(cfg, params, kv, *meta, bs,
+                             attn=AttnDispatch(use_pallas=True), **kw)
+
+    text = str(jax.make_jaxpr(step)(params, kv, rec, i32((rows,)), *meta))
+    # a kernel's source location: the checkout's path and a line number
+    text = re.sub(r" at /[^\s\]\)]*", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("preset", sorted(PARENT_STEP_HASHES))
+def test_lings_and_mistrals_programs_are_the_parents(monkeypatch, preset):
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    cfg = getattr(ModelConfig, preset)()
+    assert cfg.cache_arrays == 2
+    got = tuple(_step_hash(cfg, T) for T in (256, 16))
+    assert got == PARENT_STEP_HASHES[preset]
 
 
 # -- (d) the preset is the catalog's row -------------------------------------

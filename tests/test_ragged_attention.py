@@ -761,3 +761,161 @@ def test_unified_model_forward_matches_no_cache_oracle():
     np.testing.assert_allclose(
         np.asarray(logits[0]), np.asarray(want), rtol=2e-4, atol=2e-4
     )
+
+
+# -- latent attention's expanded form over a cache held once (PR 53) ---------
+
+# tiny latent widths (the tiny presets'): q_nope 16, rotated tail 8, latent
+# 32 in a cache entry lane-padded to 128, values 16
+L_NOPE, L_ROPE, L_RANK, L_V, L_DC = 16, 8, 32, 16, 128
+
+
+def _latent_case(rng, spans, T, H=4, gaps=None, num_blocks=64, max_blocks=28):
+    """One dispatch over a latent cache held once: spans ``[(prefix,
+    rows), ...]`` with ``gaps[i]`` padding rows before span i; un-absorbed
+    q, the one array, ``w_uk`` / ``w_uv``, span and token metadata."""
+    S = len(spans)
+    gaps = gaps or [0] * S
+    q_start = np.array([p for p, _ in spans], np.int32)
+    q_len = np.array([n for _, n in spans], np.int32)
+    row_start = np.zeros(S, np.int32)
+    token_seq = np.zeros(T, np.int32)
+    token_pos = np.full(T, -1, np.int32)
+    tables = np.zeros((S, max_blocks), np.int32)
+    ids = rng.permutation(np.arange(1, num_blocks))
+    cursor = used = 0
+    for s, (p, n) in enumerate(spans):
+        cursor += gaps[s]
+        row_start[s] = cursor
+        token_seq[cursor: cursor + n] = s
+        token_pos[cursor: cursor + n] = np.arange(p, p + n)
+        cursor += n
+        nb = -(-(p + n) // BS)
+        tables[s, :nb] = ids[used: used + nb]
+        used += nb
+    assert cursor <= T and used < num_blocks
+    f32 = jnp.float32
+    q = jnp.asarray(rng.standard_normal((T, H, L_NOPE + L_ROPE)), f32)
+    cache = np.zeros((num_blocks * BS, 1, L_DC), np.float32)
+    cache[..., : L_RANK + L_ROPE] = rng.standard_normal(
+        (num_blocks * BS, 1, L_RANK + L_ROPE))
+    w_uk = jnp.asarray(
+        rng.standard_normal((H, L_NOPE, L_RANK)) / L_RANK**0.5, f32)
+    w_uv = jnp.asarray(
+        rng.standard_normal((H, L_V, L_RANK)) / L_RANK**0.5, f32)
+    meta = tuple(jnp.asarray(a) for a in (
+        tables, q_start, q_len, q_start + q_len, row_start))
+    return (q, jnp.asarray(cache), w_uk, w_uv, meta,
+            jnp.asarray(token_seq), jnp.asarray(token_pos))
+
+
+def _absorbed(q, w_uk, scale):
+    """The absorbed call's q: projected into the latent space, the scale
+    folded in against the kernels' ``1 / sqrt(width)``, lane-padded."""
+    q_lat = jnp.einsum("thn,hnc->thc", q[..., :L_NOPE], w_uk)
+    q_abs = jnp.concatenate([q_lat, q[..., L_NOPE:]], -1) * (
+        scale * L_DC**0.5)
+    return jnp.pad(q_abs, ((0, 0), (0, 0), (0, L_DC - L_RANK - L_ROPE)))
+
+
+@pytest.mark.parametrize("name,spans,gaps,T,min_rows,tile", [
+    # (a) a long span from position 0, whole in one chunk
+    ("from_zero", [(0, 100)], None, 128, 20, None),
+    # (b) behind a prefix that is no multiple of the block (16) or the fold
+    # (256), its keys in two folds, rows in pieces of 16, chunks of 32 and
+    # tiles of 64
+    ("behind_prefix", [(261, 150)], None, 256, 20, (16, 32, 64)),
+    # (c) two long spans and decode lanes in one dispatch, padding rows
+    # between them; the lanes and the 7-row span are not this body's
+    ("mixed", [(5, 1), (21, 60), (9, 1), (130, 90), (4, 7)],
+     [0, 2, 1, 5, 0], 256, 20, (16, 32, 64)),
+    # a span of several tiles whose last tile is partial, from position 0
+    ("tiles", [(0, 170)], [3], 256, 20, (16, 32, 64)),
+    # the same dispatch in bfloat16: a step's heads leave the staged piece
+    # two to a 32-bit word
+    ("mixed_bf16", [(5, 1), (21, 60), (9, 1), (130, 90), (4, 7)],
+     [0, 2, 1, 5, 0], 256, 20, (16, 32, 64)),
+])
+def test_expanded_body_equals_the_twin_and_the_absorbed_kernel(
+    monkeypatch, name, spans, gaps, T, min_rows, tile
+):
+    """The expanded body (interpret mode, float32) over the same latent
+    pages: against the jnp twin and against the absorbed kernel, both
+    up-projected by ``w_uv`` afterwards, to 1e-5 on the long spans' rows;
+    every other row zero. ``tile`` shrinks the staged piece, the chunk and
+    the tile so that the piece loop, the chunk loop, the folds a chunk
+    skips and the tile loop all run."""
+    from dynamo_tpu.ops.pallas import latent_expanded as le
+
+    if tile:
+        monkeypatch.setattr(le, "EXPANDED_PIECE", tile[0])
+        monkeypatch.setattr(le, "EXPANDED_CHUNK", tile[1])
+        monkeypatch.setattr(le, "EXPANDED_TILE", tile[2])
+    rng = np.random.default_rng(len(name))
+    q, cache, w_uk, w_uv, meta, token_seq, token_pos = _latent_case(
+        rng, spans, T, gaps=gaps)
+    tol = 1e-5
+    if name.endswith("bf16"):
+        # bf16 operands against the float32 twin of the SAME rounded inputs
+        tol = 2e-2
+        q, cache, w_uk, w_uv = (
+            a.astype(jnp.bfloat16) for a in (q, cache, w_uk, w_uv))
+    tables, q_start, q_len, kv_len, row_start = meta
+    scale = 1.3 * (L_NOPE + L_ROPE) ** -0.5
+    long = q_len >= min_rows
+    # un-jitted: the constants above are read while it is traced
+    got = le.ragged_paged_attention_pallas_expanded.__wrapped__(
+        q, cache, w_uk, w_uv, tables, q_start, jnp.where(long, q_len, 0),
+        row_start, block_size=BS, scale=scale)
+    f32 = jnp.float32
+    q, cache, w_uk, w_uv = (a.astype(f32) for a in (q, cache, w_uk, w_uv))
+    got = got.astype(f32)
+    q_abs = _absorbed(q, w_uk, scale)
+    twin = ragged_paged_attention(
+        q_abs, cache, cache, tables, token_seq, token_pos, BS, kv_len=kv_len)
+    kernel = ragged_paged_attention_pallas(
+        q_abs, cache, None, *meta, block_size=BS)
+    mine = np.asarray(long)[np.asarray(token_seq)] & (
+        np.asarray(token_pos) >= 0)
+    assert mine.sum() == int(jnp.where(long, q_len, 0).sum())
+    for other in (twin, kernel):
+        want = jnp.einsum("thc,hvc->thv", other[..., :L_RANK], w_uv)
+        np.testing.assert_allclose(
+            got[mine], want[mine], rtol=tol, atol=tol)
+    assert not np.asarray(got)[~mine].any()
+
+
+def test_the_hosts_rule_is_the_programs_rule():
+    """``expanded_spans`` is ONE function: numpy int32 on the engine's
+    thread and traced jnp int32 inside the step agree on 1,000 random span
+    sets, at the thresholds of several width sets; and it is the
+    arithmetic it says it is (exact rationals), a span from position 0
+    passing at K rows and one behind a long prefix past K / 2."""
+    from fractions import Fraction
+
+    from dynamo_tpu.ops.pallas.latent_expanded import expanded_spans
+
+    rng = np.random.default_rng(53)
+    traced = jax.jit(expanded_spans, static_argnums=2)
+    for _ in range(1000):
+        k = int(rng.choice([96, 342, 512, 513, 1000]))
+        n = int(rng.integers(1, 48))
+        near = rng.random(n) < 0.5
+        q_len = np.where(
+            near, rng.integers(max(k // 2 - 3, 1), k + 4, n),
+            rng.integers(1, 4096, n)).astype(np.int32)
+        kv_len = (q_len + rng.integers(0, 40000, n) * (rng.random(n) < 0.8)
+                  ).astype(np.int32)
+        host = expanded_spans(q_len, kv_len, k)
+        assert host.dtype == np.bool_
+        np.testing.assert_array_equal(
+            host, np.asarray(traced(jnp.asarray(q_len), jnp.asarray(kv_len), k)))
+        for ql, kv, got in zip(q_len.tolist(), kv_len.tolist(), host):
+            pairs = Fraction(ql * (2 * kv - ql + 1), 2)
+            assert got == (pairs * 2 > kv * k), (ql, kv, k)
+    k = 342
+    at = lambda n, p: bool(expanded_spans(  # noqa: E731
+        np.int32([n]), np.int32([p + n]), k)[0])
+    assert not at(k - 1, 0) and at(k, 0)
+    assert not at(k // 2, 10**6) and at(k // 2 + 1, 10**6)
+    assert not at(1, 0) and not at(1, 10**6)

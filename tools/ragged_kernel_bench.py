@@ -20,7 +20,12 @@ records cannot split:
 - ``ops``: one traced chain, device time an op a layer: the kernel as the
   benchmark's trace names it and what XLA runs around it;
 - ``check``: the kernel against its XLA twin on the cell's dispatch (a
-  kernel can pass interpret mode and be wrong on the chip).
+  kernel can pass interpret mode and be wrong on the chip);
+- ``forms`` (a latent held once, ``--shapes dsv2``): ONE span through each
+  form of latent attention, the absorbed ragged kernel and the expanded
+  body (``ops/pallas/latent_expanded.py``), at ``--form-rows`` behind
+  ``--form-prefixes``: the table that sets ``EXPANDED_MARGIN``, and the
+  expanded body against the absorbed kernel at every point.
 
     chiprun -- python -m tools.ragged_kernel_bench --sweep check,cells,split
     chiprun -- python -m tools.ragged_kernel_bench --sweep ladder --shapes tp4
@@ -63,6 +68,7 @@ from chipbench.costs.latent_once_paged_attention import (
 from chipbench.costs.ragged_paged_attention import cost
 from chipbench.costs.window_full_paged_attention import one_layer
 from chipbench.peaks import peaks_for
+from dynamo_tpu.ops.pallas import latent_expanded
 from dynamo_tpu.ops.pallas import ragged_attention as ragged_kernel
 
 #: The kernel under measurement: this checkout's, or ``--kernel-file``'s.
@@ -369,13 +375,138 @@ def sweep_ops(name, shape, args, rng):
         time_call(shape, (q, k, v, meta), args.layers, 1, trace_to=logdir)
         reduced = xprof.reduce(xprof.load(logdir))
     emit(dict(shape=name, sweep="ops", spans=len(spans),
-              us_per_layer={
-                  op: round(1e6 * sec / args.layers, 2)
-                  for op, sec in sorted(reduced["op_seconds"].items(),
-                                        key=lambda kv: -kv[1])[:12]
-              },
+              us_per_layer=top_ops(reduced, args.layers, 12),
               module_us_per_layer=round(
                   1e6 * reduced["module_s"] / args.layers, 2)))
+
+
+def top_ops(reduced: dict, layers: int, n: int) -> dict:
+    """Device microseconds an op a layer, the ``n`` largest."""
+    return {
+        op: round(1e6 * sec / layers, 2)
+        for op, sec in sorted(reduced["op_seconds"].items(),
+                              key=lambda kv: -kv[1])[:n]
+    }
+
+
+def traced(run) -> dict:
+    """``run()`` under the profiler, reduced as the benchmark reduces."""
+    import tempfile
+
+    from chipbench import xprof
+
+    with tempfile.TemporaryDirectory() as logdir:
+        with jax.profiler.trace(logdir):
+            run()
+        return xprof.reduce(xprof.load(logdir))
+
+
+#: DeepSeek-V2's latent widths (``forms``): the un-absorbed q is ``NOPE +
+#: ROPE`` wide, the cache entry ``RANK + ROPE`` padded to the shape's ``D``.
+NOPE, ROPE, RANK, V_DIM = 128, 64, 512, 128
+
+
+def sweep_forms(name, shape, args, rng):
+    """One span of n rows behind a prefix, alone in the dispatch, through
+    the absorbed kernel and through the expanded body: microseconds a
+    layer's call each (with the XLA glue each wrapper runs), what the rule
+    says of the span, and the two forms' largest difference
+    after the absorbed output's up-projection, as a share of the largest
+    output."""
+    assert shape.get("once"), "forms: a latent held once"
+    H, T, Dc = shape["H"], shape["T"], width(shape)
+    max_len = shape.get("max_model_len", MAX_MODEL_LEN)
+    num_blocks = max_len // BS + 8
+    key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
+    kq, kk, ka, kb = jax.random.split(key, 4)
+    bf = jnp.bfloat16
+    q = jax.random.normal(kq, (T, H, NOPE + ROPE), bf)
+    cache = jax.random.normal(kk, (num_blocks * BS, 1, Dc), bf)
+    w_uk = (jax.random.normal(ka, (H, NOPE, RANK)) / RANK**0.5).astype(bf)
+    w_uv = (jax.random.normal(kb, (H, V_DIM, RANK)) / RANK**0.5).astype(bf)
+    scale = (NOPE + ROPE) ** -0.5
+    k_rule = latent_expanded.expanded_k(
+        argparse.Namespace(kv_lora_rank=RANK, qk_rope_head_dim=ROPE,
+                           qk_nope_head_dim=NOPE, v_head_dim=V_DIM), T, H)
+
+    def absorbed(q, meta):
+        q_lat = jnp.einsum("thn,hnc->thc", q[..., :NOPE], w_uk)
+        q_abs = jnp.concatenate([q_lat, q[..., NOPE:]], -1) * (
+            scale * Dc**0.5)
+        q_abs = jnp.pad(q_abs.astype(bf), ((0, 0), (0, 0),
+                                           (0, Dc - RANK - ROPE)))
+        return q_abs, lambda x: kernel_mod.ragged_paged_attention_pallas(
+            x, cache, None, *meta, block_size=BS)
+
+    def expanded(x, meta):
+        tables, q_start, q_len, _, row_start = meta
+        return latent_expanded.ragged_paged_attention_pallas_expanded(
+            x, cache, w_uk, w_uv, tables, q_start, q_len, row_start,
+            block_size=BS, scale=scale)
+
+    def chained(form):
+        @jax.jit
+        def chain(q, meta):
+            if form == "absorbed":
+                x0, call = absorbed(q, meta)
+            else:
+                x0, call = q, lambda x: expanded(x, meta)
+
+            def body(x, _):
+                out = call(x)
+                step = jnp.resize(out, x.shape) if form == "expanded" else out
+                return x + step * jnp.asarray(1e-3, x.dtype), None
+
+            return jax.lax.scan(body, x0, None, length=args.layers)[0]
+        return chain
+
+    @jax.jit
+    def both(q, meta):
+        x0, call = absorbed(q, meta)
+        want = jnp.einsum("thc,hvc->thv", call(x0)[..., :RANK], w_uv,
+                          preferred_element_type=jnp.float32)
+        return want, expanded(q, meta).astype(jnp.float32)
+
+    chains = {form: chained(form) for form in ("absorbed", "expanded")}
+    for prefix in args.form_prefixes:
+        for n in args.form_rows:
+            if prefix + n > max_len or n > T:
+                continue
+            nb = -(-(prefix + n) // BS)
+            tables = np.zeros((1, max_len // BS), np.int32)
+            tables[0, :nb] = rng.permutation(np.arange(1, num_blocks))[:nb]
+            meta = tuple(jnp.asarray(a, jnp.int32) for a in (
+                tables, [prefix], [n], [prefix + n], [0]))
+            want, got = (np.asarray(a) for a in both(q, meta))
+            err = float(np.abs(got - want).max() / np.abs(want).max())
+            line = dict(shape=name, sweep="forms", prefix=prefix, rows=n,
+                        rule_expands=bool(k_rule and latent_expanded.
+                                          expanded_spans(
+                            np.int32([n]), np.int32([prefix + n]), k_rule)[0]),
+                        worst_rel=round(err, 5),
+                        padding_zero=not got[n:].any())
+            for form, chain in chains.items():
+                jax.block_until_ready(chain(q, meta))
+                times = []
+                for _ in range(args.reps):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(chain(q, meta))
+                    times.append(time.perf_counter() - t0)
+                line[f"{form}_us"] = round(
+                    1e6 * statistics.median(times) / args.layers, 2)
+            line["absorbed_over_expanded"] = round(
+                line["absorbed_us"] / line["expanded_us"], 3)
+            emit(line)
+    if on_tpu():
+        # the last point's chains traced: the kernels as the benchmark's
+        # trace names them, and what XLA runs around each (the absorbed
+        # call's pad of q and zeroing of rows nobody owns, the expanded
+        # call's)
+        for form, chain in chains.items():
+            reduced = traced(lambda: jax.block_until_ready(chain(q, meta)))
+            emit(dict(shape=name, sweep="forms_ops", form=form,
+                      prefix=prefix, rows=n,
+                      us_per_layer=top_ops(reduced, args.layers, 10)))
 
 
 def mixed_contexts(shape: dict, rng, lo: int, hi: int) -> np.ndarray:
@@ -495,7 +626,8 @@ def main(argv=None) -> int:
     ap.add_argument("--sweep", default="cells,split",
                     help="cells, parts (lanes alone, quantum alone), split "
                     "(ctx and spans, fitted), ladder, check, ops (a traced "
-                    "chain, time an op)")
+                    "chain, time an op), forms (a latent held once: one "
+                    "span through each form)")
     ap.add_argument("--layers", type=int, default=16)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -509,6 +641,9 @@ def main(argv=None) -> int:
                     default=pairs("2x16,3x16,4x16,6x16,4x8,3x32"),
                     help="NBUFxPP points; the default is the ladder "
                     "recorded in the kernel's docstring")
+    ap.add_argument("--form-rows", type=ints, default=[128, 256, 384, 512, 977])
+    ap.add_argument("--form-prefixes", type=ints,
+                    default=[0, 2048, 8192, 16384])
     ap.add_argument("--kernel-file", default=None, metavar="PATH",
                     help="measure the kernel of this file (another "
                     "checkout's ops/pallas/ragged_attention.py)")
@@ -535,12 +670,14 @@ def main(argv=None) -> int:
         spec.loader.exec_module(kernel_mod)
     for item in args.set:
         key, value = item.split("=")
-        assert hasattr(kernel_mod, key), key
-        setattr(kernel_mod, key, int(value))
+        # a constant of the ragged kernel's file, or of the expanded body's
+        mod = kernel_mod if hasattr(kernel_mod, key) else latent_expanded
+        assert hasattr(mod, key), key
+        setattr(mod, key, int(value))
     sweeps = {"cells": sweep_cells, "split": sweep_split,
               "parts": sweep_parts,
               "ladder": sweep_ladder, "check": sweep_check,
-              "ops": sweep_ops}
+              "ops": sweep_ops, "forms": sweep_forms}
     for name in args.shapes.split(","):
         for what in args.sweep.split(","):
             # the same operands a shape whatever else the call measures:
