@@ -40,6 +40,7 @@ from dynamo_tpu.llm.protocols.common import (
     RequestError,
     ShedError,
 )
+from dynamo_tpu.ops.sampling import commit_floor_rows
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.failover import FAILOVER
 from dynamo_tpu.utils import concurrency
@@ -161,6 +162,10 @@ class TpuEngine:
         # Block diffusion: lane passes dispatched and tokens they committed.
         self._diffusion_passes = 0
         self._diffusion_committed = 0
+        # Blocks committed (fed unmasked, their keys and values final) as
+        # the first B rows of a 2B span, and by a commit pass of their own.
+        self._diffusion_ridden = 0
+        self._diffusion_lone = 0
         # SLO-aware co-location (engine/coloc.py; ROADMAP #3): the
         # controller owns the prefill quantum — static passthrough or
         # the adaptive AIMD loop fed by measured dispatch timings below.
@@ -886,10 +891,26 @@ class TpuEngine:
                 drafts = self._draft_tokens(seq)
                 if drafts:
                     draft_map[id(seq)] = drafts
-        decode_items = [
-            (seq, B_blk or 1 + len(draft_map.get(id(seq), [])))
-            for seq in decode_ready
-        ]
+        if B_blk:
+            # A lane's span is its block's B rows, or 2B where its commit
+            # rides (_commit_rides). Rides are granted inside the rows the
+            # budget has left once every lane has its B rows and a waiting
+            # prompt its quantum, so that a ride never costs another lane
+            # its step: a lane left without takes the lone commit pass.
+            room = (
+                cfg.unified_token_budget - B_blk * len(decode_ready)
+                - min(self.coloc.quantum, sum(r for _, r in prefill_items))
+            )
+            decode_items = []
+            for seq in decode_ready:
+                ride = room >= B_blk and self._commit_rides(seq)
+                room -= B_blk * ride
+                decode_items.append((seq, B_blk * (1 + ride)))
+        else:
+            decode_items = [
+                (seq, 1 + len(draft_map.get(id(seq), [])))
+                for seq in decode_ready
+            ]
         decode_take, prefill_take = compose_unified(
             decode_items, prefill_items, cfg.unified_token_budget,
             self.coloc.quantum, rotation=self._unified_rotation,
@@ -915,21 +936,28 @@ class TpuEngine:
                 # (everything before it has retired) and decides this
                 # one: behind a commit pass the NEXT block opens, all
                 # masks; behind a denoising pass the same block is fed
-                # from the device, whatever that pass commits.
+                # from the device, whatever that pass commits; and where
+                # that pass is known to complete the block (_commit_rides)
+                # the commit RIDES: one span of 2B rows, the finished
+                # block fed from the device, then the next block's masks.
+                start, fed = seq.blk_start, seq.blk_ids
                 if seq.blk_inflight > 0:
-                    if any(t < 0 for t in seq.blk_ids):
+                    if any(t < 0 for t in fed):
                         use_prev[s] = True
                         prev_row[s] = self._prev_unified_rows[id(seq)]
+                        if width > B_blk:
+                            self._open_block(seq)
+                            fed = fed + seq.blk_ids
                     else:
                         self._open_block(seq)
                         if seq.status is not SeqStatus.RUNNING:
                             continue  # the next block passes the limit
-                lanes.append((
-                    seq.blk_ids, seq.lane_block_ids, seq.blk_start,
-                    self._lane_sampling(seq),
-                ))
+                        start, fed = seq.blk_start, seq.blk_ids
+                lanes.append(
+                    (fed, seq.lane_block_ids, start, self._lane_sampling(seq))
+                )
                 draft_lens.append(0)
-                roles.append((seq, "block", seq.blk_start, B_blk, True))
+                roles.append((seq, "block", start, len(fed), True))
                 seq.inflight_chunks += 1
                 seq.blk_inflight += 1
                 continue
@@ -1062,7 +1090,10 @@ class TpuEngine:
         self._prev_unified_rows = {
             id(seq): i for i, (seq, *_r) in enumerate(roles)
         }
-        n_dec = len(decode_take) * (B_blk or 1)
+        n_dec = (
+            sum(r[3] for r in roles if r[1] == "block") if B_blk
+            else len(decode_take)
+        )
         n_pre = sum(r[3] for r in roles if r[1] == "prefill")
         self._unified_decode_tokens += n_dec
         self._unified_prefill_tokens += n_pre
@@ -1116,7 +1147,7 @@ class TpuEngine:
         if B_blk:
             # A block dispatch records at retire too: what it committed
             # is device-side until then.
-            self._diffusion_passes += len(decode_take)
+            self._diffusion_passes += sum(r[1] == "block" for r in roles)
         elif n_drafted == 0 and out.moe_counts is None:
             # Spec dispatches record at PROCESS time instead (the
             # accepted counts are device-side until retire), and so does
@@ -1219,47 +1250,59 @@ class TpuEngine:
         for seq, *_rest in roles:
             seq.inflight_chunks -= 1
         n_accepted = 0
-        n_committed = denoise_rows = commit_rows = 0
+        n_committed = denoise_rows = commit_rows = ride_rows = n_spans = 0
         for i, (seq, kind, start, n, deliver) in enumerate(roles):
             if kind == "block":
-                # What the pass was fed: the host's state of the block
-                # (for a device-fed pass, what the pass before it left),
-                # or, where the sequence has moved on to its next block
-                # behind this pass, a block without a masked row.
+                # The ids that come back are the span's LAST B rows': the
+                # block at ``head``. A span of 2B rows is a ride: its first
+                # B rows were the finished block before it, fed unmasked.
+                # What the pass was fed at ``head`` is the host's state of
+                # that block (for a device-fed pass, what the pass before
+                # it left): ``blk_ids``, or ``blk_behind`` where the
+                # sequence opened its next block at compose behind this
+                # pass (a lone commit pass; the pass a ride follows, which
+                # still delivers the block's last tokens).
                 seq.blk_inflight -= 1
-                moved_on = start != seq.blk_start
-                fed_masked = 0 if moved_on else sum(
-                    t < 0 for t in seq.blk_ids)
+                n_spans += 1
+                head = start + n - B_blk
+                moved_on = head != seq.blk_start
+                fed_masked = sum(
+                    t < 0 for t in (seq.blk_behind if moved_on else seq.blk_ids)
+                )
                 denoise_rows += B_blk if fed_masked else 0
-                commit_rows += 0 if fed_masked else B_blk
+                commit_rows += n - B_blk if fed_masked else n
+                ride_rows += n - B_blk
                 if seq.status is not SeqStatus.RUNNING:
                     continue  # stopped while in flight; the pass is void
+                if n > B_blk:
+                    self._commit_block(seq, start)
+                    self._diffusion_ridden += 1
                 ids = blk_ids[i].tolist()  # dynalint: allow[DT005] a row of the host array forced above, no device value
-                if not moved_on:
+                if moved_on:
+                    seq.blk_behind = ids
+                else:
                     seq.blk_ids = ids
-                    left = sum(t < 0 for t in ids)
-                    n_committed += fed_masked - left
-                    # A token leaves once every position before it is
-                    # committed: deliver the committed run behind what
-                    # has been delivered (stop conditions end the request
-                    # at that token, whatever lies committed behind it).
-                    while seq.status is SeqStatus.RUNNING:
-                        j = seq.total_len - start
-                        if j >= B_blk or ids[j] < 0:
-                            break
-                        self._deliver(seq, ids[j])
-                    if fed_masked and not left:
-                        tracer().span_end(seq.request_id, "block_denoise")
+                left = sum(t < 0 for t in ids)
+                n_committed += fed_masked - left
+                # A token leaves once every position before it is
+                # committed: deliver the committed run behind what
+                # has been delivered (stop conditions end the request
+                # at that token, whatever lies committed behind it).
+                while seq.status is SeqStatus.RUNNING:
+                    j = seq.total_len - head
+                    if j >= B_blk or ids[j] < 0:
+                        break
+                    self._deliver(seq, ids[j])
+                if fed_masked and not left:
+                    tracer().span_end(seq.request_id, "block_denoise")
+                    if moved_on and seq.status is SeqStatus.RUNNING:
+                        # the ride behind this pass denoises the next block
+                        tracer().span_begin(seq.request_id, "block_denoise")
                 if fed_masked == 0 and seq.status is SeqStatus.RUNNING:
-                    # The commit pass: the block's keys and values are
-                    # final. Its tokens join the hash chain, pages it
-                    # completed become reusable, and the next block opens
-                    # unless it was opened behind this pass already.
-                    if seq.hashes is not None:
-                        seq.hashes.extend(ids[len(seq.hashes) - start:])
-                    self.scheduler.register_filled_blocks(
-                        seq, start + B_blk
-                    )
+                    # The lone commit pass; the next block opens unless
+                    # it was opened behind this pass already.
+                    self._commit_block(seq, head)
+                    self._diffusion_lone += 1
                     if not moved_on:
                         self._open_block(seq)
             elif kind in ("decode", "spec"):
@@ -1335,9 +1378,10 @@ class TpuEngine:
                 fill=self._unified_fill_ratio,
                 dispatch_ms=compose_ms,
                 lanes=len(roles),
-                diffusion_lanes=(denoise_rows + commit_rows) // B_blk,
+                diffusion_lanes=n_spans,
                 denoise_rows=denoise_rows,
                 commit_rows=commit_rows,
+                ride_rows=ride_rows,
                 committed_tokens=n_committed,
                 moe_experts_hit=experts_hit,
                 folds=folds,
@@ -1363,6 +1407,20 @@ class TpuEngine:
             )
         if self.cfg.speculative_k:
             self._maybe_gate_speculation()
+
+    def _commit_block(self, seq: Sequence, start: int) -> None:
+        """The block at ``start`` was fed without a masked row (a lone
+        commit pass, or a ride's first B rows): its keys and values are
+        final. Its tokens, all delivered by now, join the hash chain (the
+        chain holds the prompt from admission on), and only then are the
+        pages it completed offered for reuse."""
+        end = start + self.cfg.model.diffusion_block_length
+        if seq.hashes is not None:
+            P = len(seq.prompt_tokens)
+            seq.hashes.extend(
+                seq.output_tokens[len(seq.hashes) - P : end - P]
+            )
+        self.scheduler.register_filled_blocks(seq, end)
 
     def _plain_note(self, roles, n_dec, n_pre, compose_ms, folds) -> dict:
         """The flight-record fields of a plain unified dispatch; where the
@@ -1473,14 +1531,50 @@ class TpuEngine:
         P = len(seq.prompt_tokens)
         return P - P % B if B else P
 
+    def _commit_rides(self, seq: Sequence) -> bool:
+        """Whether a block lane's next span may be 2B rows: its commit
+        RIDES the next block's first denoising pass. The pass in flight
+        was fed ``k`` masked rows (``blk_ids``: what came before it has
+        retired), and the commit rule's floor hands out at least
+        ``commit_floor_rows`` of them whatever the confidences read: with
+        ``1 <= k <= floor`` the block comes back complete, the host knows
+        it now, and the lane's next span is the finished block's B rows
+        (fed from the device, unmasked: the commit) and the next block's
+        B masks behind them, one pass where two. With more masks than the
+        floor only the data can finish the block early, which the host
+        cannot foresee: B rows, fed from the device, and a lone commit
+        pass if they turn out unmasked. No ride either where the next
+        block would pass the context limit or the pages funded
+        (scheduler decode_batch funds a block ahead), or where the
+        request's last token is in this block."""
+        m = self.cfg.model
+        B = m.diffusion_block_length
+        end = seq.blk_start + 2 * B
+        masks = sum(t < 0 for t in seq.blk_ids)
+        limit = seq.stop.max_tokens
+        return (
+            seq.blk_inflight == 1
+            and 1 <= masks <= commit_floor_rows(B, m.denoising_steps)
+            and end <= self.cfg.max_model_len
+            and all(len(t) * self.cfg.block_size >= end for t in seq.tables)
+            and (limit is None or end - B - len(seq.prompt_tokens) < limit)
+        )
+
     def _open_block(self, seq: Sequence) -> None:
-        """Open the diffusion block at the sequence's committed length:
-        the tokens already known there (the prompt's tail; after a
+        """Open the sequence's next diffusion block: the one behind its
+        open block, or where none is open the one at its committed length.
+        The tokens already known there (the prompt's tail; after a
         preemption the delivered rows, and whatever else the kept block
-        had committed), masks behind them. A block that would pass the
-        context limit ends the request."""
+        had committed), masks behind them. Opened at compose behind a pass
+        still in flight, the block left keeps its ids as that pass was
+        fed in ``blk_behind`` until the pass retires. A block that would
+        pass the context limit ends the request."""
         B = self.cfg.model.diffusion_block_length
-        start = seq.total_len - seq.total_len % B
+        if seq.blk_start >= 0:
+            start = seq.blk_start + B
+            seq.blk_behind = seq.blk_ids if seq.blk_inflight else []
+        else:
+            start = seq.total_len - seq.total_len % B
         if start + B > self.cfg.max_model_len:
             self.scheduler.finish(seq, FinishReason.LENGTH)
             return
@@ -3016,6 +3110,8 @@ class TpuEngine:
             "kda_chunk_rows_total": self._kda_chunk_rows,
             "diffusion_passes_total": self._diffusion_passes,
             "diffusion_committed_tokens_total": self._diffusion_committed,
+            "diffusion_commits_ridden_total": self._diffusion_ridden,
+            "diffusion_commits_lone_total": self._diffusion_lone,
             "moe_grouped_rows_total": (
                 (self._unified_decode_tokens + self._unified_prefill_tokens)
                 * m.num_experts_per_tok * layers if grouped else 0

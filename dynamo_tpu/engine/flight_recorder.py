@@ -78,6 +78,7 @@ class FlightRecorder:
         diffusion_lanes: int = 0,
         denoise_rows: int = 0,
         commit_rows: int = 0,
+        ride_rows: int = 0,
         committed_tokens: int = 0,
         moe_experts_hit: int = 0,
         moe_rows_held: int = 0,
@@ -113,11 +114,15 @@ class FlightRecorder:
         is the frames (a token or a finish each) the engine's thread
         handed to the frontend's loop since the record before this one:
         a plain dispatch records at its issue, so it reads the retire
-        just behind it. The four
-        diffusion fields are a block-diffusion model's: lanes that fed a
-        block, the rows fed in denoising passes and in commit passes
-        (a block without a masked row), and the tokens the dispatch
-        committed (known at its retire, where such a dispatch records);
+        just behind it. The five
+        diffusion fields are a block-diffusion model's: the block SPANS
+        of the dispatch (a lane's pass, B rows or the 2B of a ride), the
+        rows fed in denoising passes, EVERY row fed unmasked to write a
+        finished block's final keys and values (``commit_rows``: a lone
+        commit pass's, and the first B rows of a ride, which
+        ``ride_rows`` counts again on their own), and the tokens the
+        dispatch committed (known at its retire, where such a dispatch
+        records);
         ``moe_experts_hit`` is the experts that had a row, summed over its
         grouped expert layers: the weights its grouped kernels had to
         read, which routing decides; ``moe_rows_held`` the routed (row,
@@ -164,6 +169,7 @@ class FlightRecorder:
             "diffusion_lanes": diffusion_lanes,
             "denoise_rows": denoise_rows,
             "commit_rows": commit_rows,
+            "ride_rows": ride_rows,
             "committed_tokens": committed_tokens,
             "moe_experts_hit": moe_experts_hit,
             "moe_rows_held": moe_rows_held,

@@ -60,6 +60,7 @@ from dynamo_tpu.ops.sampling import (
     MAX_LOGPROBS,
     apply_penalties,
     commit_block,
+    commit_floor_rows,
     sample_tokens,
     token_logprobs,
 )
@@ -897,7 +898,7 @@ class ModelRunner(WarmupPlanMixin):
                     o["top_k"], o["top_p"], o["seed"],
                     o["kv_len"] - jnp.minimum(q_len, B_blk),
                     m.confidence_threshold,
-                    -(-B_blk // max(m.denoising_steps, 1)),
+                    commit_floor_rows(B_blk, m.denoising_steps),
                 )
             return jnp.where(live, ids, 0), counts, experts_hit, kv, kv_sc
 

@@ -436,8 +436,10 @@ class Scheduler:
             # overshoot the context cap; the engine caps draft_len so no
             # verify-span write lands past the allocated span. A
             # block-diffusion lane writes its whole block every pass, and
-            # behind a commit pass in flight the engine opens the NEXT
-            # block at issue: fund one block ahead.
+            # behind a pass in flight that leaves the block complete the
+            # engine opens the NEXT block at issue (behind a lone commit
+            # pass; in the same span where the commit rides): fund one
+            # block ahead.
             needed_block = min(
                 (seq.blk_start + 2 * B - 1) // bs if B
                 else (seq.device_len - 2 + lookahead) // bs,
@@ -516,6 +518,7 @@ class Scheduler:
         # is prompt now.
         seq.blk_start = -1
         seq.blk_inflight = 0
+        seq.blk_behind = []
         if all(t >= 0 for t in seq.blk_ids):
             seq.blk_ids = []
         # Re-admission may land in a different slot whose [vocab] penalty

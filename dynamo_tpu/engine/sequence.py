@@ -128,9 +128,13 @@ class Sequence:
     # blk_start is committed — final tokens, final keys and values — and
     # output_tokens holds what was DELIVERED: the committed rows up to the
     # first masked one. A preempted sequence keeps blk_ids, so its block
-    # resumes where it stood.
+    # resumes where it stood. Where the next block was opened at compose
+    # behind a pass still in flight (a lone commit pass, or the pass a
+    # ride follows: engine _issue_unified), blk_behind is the block behind
+    # blk_start as that pass was fed; its retire reads and updates it.
     blk_start: int = -1
     blk_ids: list[int] = field(default_factory=list)
+    blk_behind: list[int] = field(default_factory=list)
     blk_inflight: int = 0        # block passes issued and not yet retired
 
     @property

@@ -213,6 +213,8 @@ class HttpService:
                 "unified_operand_transfers_total",
                 "diffusion_passes_total",
                 "diffusion_committed_tokens_total",
+                "diffusion_commits_ridden_total",
+                "diffusion_commits_lone_total",
                 "moe_grouped_rows_total",
                 "recurrent_state_slots_in_use",
                 "recurrent_state_bytes",

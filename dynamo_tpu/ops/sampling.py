@@ -135,6 +135,16 @@ def sample_tokens(
     return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids)
 
 
+def commit_floor_rows(block_length: int, denoising_steps: int) -> int:
+    """The commit rule's floor: the masked rows a denoising pass commits
+    whatever their confidences read, so that a block is complete within
+    ``denoising_steps`` passes. A pass fed that many masked rows or fewer
+    comes back complete: the program's ``commit_block`` and the engine's
+    compose (which then knows the block's next span before the pass
+    retires) both go by this one expression."""
+    return -(-block_length // max(denoising_steps, 1))
+
+
 def commit_block(
     logits: jnp.ndarray,        # [S, B, V] float32 — a span's block rows
     fed: jnp.ndarray,           # [S, B] int32 — the ids the rows were fed

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from chipbench.reference import sdar
+from dynamo_tpu.engine import engine as engine_mod
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.engine.runner import ModelRunner, operand_layout
@@ -27,7 +28,7 @@ from dynamo_tpu.ops import attention as attn_ops
 from dynamo_tpu.ops.pallas.ragged_attention import (
     ragged_paged_attention_pallas,
 )
-from dynamo_tpu.ops.sampling import commit_block
+from dynamo_tpu.ops.sampling import commit_block, commit_floor_rows
 from dynamo_tpu.runtime.engine import Context
 
 pytestmark = pytest.mark.anyio
@@ -338,6 +339,372 @@ async def test_only_committed_blocks_are_offered_for_reuse(monkeypatch):
         assert out2 == want_tokens(model, second, 6)
     finally:
         await engine.stop()
+
+
+# -- the commit rides the next block's first denoising pass -----------------
+
+def no_rides(monkeypatch):
+    """The ride forced off in the TEST: the ENGINE's binding of the floor
+    reads 0, so compose never knows a block complete ahead of its retire
+    (the program keeps its own binding, and the commit rule with it)."""
+    monkeypatch.setattr(engine_mod, "commit_floor_rows", lambda B, steps: 0)
+
+
+async def served(cfg, prompts, n, hook=None, **stop):
+    """One engine's run of ``prompts`` at once: each request's tokens and
+    finish reason, what its last commit left (the end of the committed
+    positions, the hash chain over them, the keys and values the cache
+    holds there), the flight records of the block dispatches and the
+    counters. ``hook(engine)`` runs behind the start."""
+    engine = TpuEngine(cfg)
+    await engine.start()
+    if hook is not None:
+        hook(engine)
+    commits = {}
+    real = engine._commit_block
+
+    def commit_block_(seq, start):
+        real(seq, start)
+        assert seq.total_len >= start + 4       # every token of it delivered
+        commits.setdefault(tuple(seq.prompt_tokens[:3]), []).append((
+            start + 4,
+            [b.sequence_hash for b in seq.hashes.blocks],
+            list(seq.block_ids),
+        ))
+
+    engine._commit_block = commit_block_
+
+    async def one(prompt):
+        pre = PreprocessedRequest(
+            token_ids=list(prompt),
+            sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=n, **{"ignore_eos": True, **stop}),
+        )
+        toks, reason = [], None
+        async for raw in engine.generate(Context(pre.to_wire())):
+            out = EngineOutput.from_wire(raw)
+            toks += out.token_ids
+            reason = out.finish_reason or reason
+        return toks, reason
+
+    try:
+        outs = await asyncio.gather(*(one(p) for p in prompts))
+        for _ in range(500):      # the void passes behind a finish retire
+            if not engine._inflight and not engine.scheduler.running:
+                break
+            await asyncio.sleep(0.01)
+        bs = cfg.block_size
+        caches = [np.asarray(a) for layer in engine.runner.kv_caches
+                  for a in layer]
+        state = {}
+        for key, made in commits.items():
+            ends = [end for end, _h, _t in made]
+            assert ends == sorted(set(ends)), ends   # once a block, in order
+            end, hashes, table = made[-1]
+            pos = np.arange(end)
+            rows = np.asarray(table)[pos // bs] * bs + pos % bs
+            state[key] = (end, hashes[: end // bs], [c[rows] for c in caches])
+        steps = [r for r in engine.debug_steps() if r.get("diffusion_lanes")]
+        # every pass retired: the scheduler holds nothing
+        assert not engine.scheduler.running and not engine._inflight
+        return outs, state, steps, engine.readiness()
+    finally:
+        await engine.stop()
+
+
+def same_committed_state(a, b):
+    assert a.keys() == b.keys()
+    for key in a:
+        (end_a, hash_a, kv_a), (end_b, hash_b, kv_b) = a[key], b[key]
+        assert (end_a, hash_a) == (end_b, hash_b)
+        for x, y in zip(kv_a, kv_b):
+            np.testing.assert_allclose(x, y, atol=2e-5)
+
+
+RIDE_PROMPTS = [list(range(2, 10)), list(range(3, 8)), list(range(4, 18)),
+                list(range(5, 28))]       # P mod 4: 0, 1, 2, 3
+
+
+@pytest.mark.parametrize("depth", [2, 1])
+async def test_the_ride_is_the_lone_commits_result_in_one_pass_fewer(
+        monkeypatch, depth):
+    """With the ride and with it forced off: the same tokens (the plain
+    loop's), the same hash chain and the same keys and values over every
+    committed position, for prompts whose length is and is not a multiple
+    of B; a block costs a lane four passes where five, every commit is
+    counted once as ridden or lone, and a flight record's fields mean what
+    they say. At depth 1 no pass is in flight at compose: nothing rides."""
+    model = tiny()
+    cfg = lambda: engine_config(model, pipeline_depth=depth)
+    n = 21
+    ride = await served(cfg(), RIDE_PROMPTS, n)
+    no_rides(monkeypatch)
+    lone = await served(cfg(), RIDE_PROMPTS, n)
+    for (got, _r), (same, _r2), prompt in zip(ride[0], lone[0], RIDE_PROMPTS):
+        assert got == same == want_tokens(model, prompt, n), len(prompt)
+    same_committed_state(ride[1], lone[1])
+    blocks = sum(end - (len(p) - len(p) % 4) for p, (end, _h, _kv)
+                 in zip(RIDE_PROMPTS, (ride[1][tuple(p[:3])]
+                                       for p in RIDE_PROMPTS))) // 4
+    for (_o, _s, steps, snap), rides in ((ride, depth == 2), (lone, False)):
+        ridden = snap["diffusion_commits_ridden_total"]
+        assert ridden + snap["diffusion_commits_lone_total"] == blocks
+        assert (ridden > 0) == rides
+        assert snap["diffusion_passes_total"] == sum(
+            r["diffusion_lanes"] for r in steps)
+        # a span is B rows, or 2B where it carries a finished block
+        rows = sum(r["denoise_rows"] + r["commit_rows"] for r in steps)
+        assert rows == 4 * snap["diffusion_passes_total"] + sum(
+            r["ride_rows"] for r in steps)
+        assert sum(r["ride_rows"] for r in steps) >= 4 * ridden
+        assert sum(r["decode_tokens"] for r in steps) == rows
+        assert all(r["ride_rows"] <= r["commit_rows"] for r in steps)
+    if depth == 2:
+        # every block but a request's last (its commit is never needed)
+        # rides, and each ride is one pass fewer
+        assert ride[3]["diffusion_commits_lone_total"] == 0
+        assert (lone[3]["diffusion_passes_total"]
+                - ride[3]["diffusion_passes_total"]
+                == ride[3]["diffusion_commits_ridden_total"])
+
+
+async def test_a_block_costs_a_lane_four_passes_where_five(monkeypatch):
+    """One lane, ten whole blocks of four tokens at four denoising steps
+    (the floor hands out one row a pass): four passes a block with the
+    ride, five without (the last block's commit pass is issued behind its
+    last denoising pass either way and retires void), and tokens a lane
+    pass 0.98 where 0.8."""
+    model = tiny()
+    prompt = list(range(2, 10))
+    reads = []
+    for rides in (True, False):
+        if not rides:
+            no_rides(monkeypatch)
+        outs, _state, steps, snap = await served(
+            engine_config(model, max_num_seqs=1), [prompt], 40)
+        assert outs[0][0] == want_tokens(model, prompt, 40)
+        reads.append((
+            snap["diffusion_passes_total"],
+            snap["diffusion_commits_ridden_total"],
+            snap["diffusion_commits_lone_total"],
+            sum(r["committed_tokens"] for r in steps)
+            / sum(r["diffusion_lanes"] for r in steps),
+        ))
+    assert reads[0][:3] == (4 * 10 + 1, 9, 0)
+    assert reads[1][:3] == (5 * 10, 0, 9)
+    assert reads[0][3] == 40 / 41 and reads[1][3] == 0.8
+
+
+async def test_a_block_finished_early_by_the_data_pays_a_lone_commit():
+    """More masks in flight than the floor commits: only the data can
+    finish the block early, which the host cannot foresee, so the next
+    pass is fed from the device and, fed no mask, IS a lone commit pass.
+    At a threshold the tiny model's rows reach in part, some blocks end
+    by the floor (their commit rides) and some by the data (it cannot):
+    the tokens are the plain loop's all the same."""
+    model = tiny(0.03)
+    outs, _state, steps, snap = await served(
+        engine_config(model), RIDE_PROMPTS, 33)
+    for (got, _r), prompt in zip(outs, RIDE_PROMPTS):
+        assert got == want_tokens(model, prompt, 33), len(prompt)
+    assert snap["diffusion_commits_lone_total"] > 0
+    assert snap["diffusion_commits_ridden_total"] > 0
+    assert max(r["committed_tokens"] / r["diffusion_lanes"]
+               for r in steps) > 1.0
+
+
+async def test_a_ride_that_would_pass_the_context_limit_is_a_lone_commit():
+    """The block behind the open one would end past ``max_model_len``:
+    no span reaches past the limit, the open block's commit goes alone
+    (and retires void: the block's last token ended the request by
+    length), every token up to the limit is delivered."""
+    model = tiny()
+    prompt = list(range(2, 12))                   # 10 tokens: blocks from 8
+    outs, state, steps, snap = await served(
+        engine_config(model, max_model_len=32, max_num_seqs=1), [prompt], 99)
+    (got, reason), = outs
+    assert got == want_tokens(model, prompt, 22) and reason == "length"
+    # blocks 8 .. 24 rode with the block behind them; 28's could not
+    assert snap["diffusion_commits_ridden_total"] == 5
+    assert state[tuple(prompt[:3])][0] == 28
+    assert sum(r["ride_rows"] for r in steps) == 4 * 5
+    assert sum(r["commit_rows"] for r in steps) == 4 * 5 + 4
+    assert snap["diffusion_commits_lone_total"] == 0
+
+
+async def test_a_budget_with_room_for_one_ride_gives_the_rest_lone_commits():
+    """Three lanes in step in a budget of 16 rows: every lane's B rows
+    first, and what is left holds ONE more block. The lane that gets it
+    rides, the others take the lone commit pass in the same dispatch:
+    no lane ever skips a step, no token is lost."""
+    model = tiny()
+    prompts = [list(range(a, a + 8)) for a in (2, 30, 60)]
+    cfg = engine_config(model, max_num_seqs=3, unified_token_budget=16,
+                        unified_prefill_quantum=8)
+    outs, _state, steps, snap = await served(cfg, prompts, 24)
+    for (got, _r), prompt in zip(outs, prompts):
+        assert got == want_tokens(model, prompt, 24)
+    assert snap["diffusion_commits_ridden_total"] > 0
+    assert snap["diffusion_commits_lone_total"] > 0
+    assert max(r["decode_tokens"] for r in steps) == 16
+    # while all three run, every dispatch carries all three
+    busy = steps[4:-8]
+    assert busy and all(r["diffusion_lanes"] == 3 for r in busy), [
+        r["diffusion_lanes"] for r in steps]
+
+
+async def test_a_stop_token_in_a_block_voids_the_ride_behind_it():
+    """The block's last token ends the request while the ride is in
+    flight: the ride is void as a void pass is (its writes lie in pages
+    the sequence still owns), nothing of the next block is delivered, the
+    block it carried is not counted as committed and every page comes
+    back."""
+    model = tiny()
+    prompt = list(range(2, 10))
+    want = want_tokens(model, prompt, 16)
+    stop_tok = want[7]                            # the second block's last
+    assert stop_tok not in want[:7]
+    free = []
+    outs, state, steps, snap = await served(
+        engine_config(model, max_num_seqs=1), [prompt], 40,
+        hook=lambda e: free.append((e, e.scheduler.allocator.num_free)),
+        stop_token_ids=[stop_tok], ignore_eos=False)
+    (got, reason), = outs
+    assert reason == "stop" and got[:8] == want[:8][: len(got)]
+    assert len(got) in (7, 8) and stop_tok not in got[:7]
+    # block 1 rode; block 2's ride was in flight and is void
+    assert snap["diffusion_commits_ridden_total"] == 1
+    assert snap["diffusion_commits_lone_total"] == 0
+    assert state[tuple(prompt[:3])][0] == 12
+    assert sum(r["ride_rows"] for r in steps) == 8
+    engine, before = free[0]
+    assert engine.scheduler.allocator.num_free == before
+
+
+async def test_a_preempted_lane_rides_again_behind_its_readmission(
+        monkeypatch):
+    """Too few pages for both answers: a lane is preempted between its
+    passes (a lane with a pass in flight, a ride's too, is never the
+    victim: its writes pin its pages) with blocks committed by rides
+    behind it; recomputed from its delivered tokens it goes on to the
+    plain loop's tokens, riding again, and what it keeps of the block
+    left behind a ride does not outlive the preemption."""
+    model = tiny()
+    cfg = engine_config(model, num_blocks=9, max_model_len=64,
+                        max_num_seqs=2, enable_prefix_caching=False)
+    preempted = []
+    prompts = [list(range(5, 24)), list(range(40, 61))]
+    engine = TpuEngine(cfg)
+    await engine.start()
+    real = engine.scheduler.requeue_for_recompute
+
+    def requeue(seq):
+        real(seq)
+        preempted.append((seq.blk_behind, seq.blk_inflight))
+
+    monkeypatch.setattr(engine.scheduler, "requeue_for_recompute", requeue)
+    try:
+        outs = await asyncio.gather(
+            *(generate(engine, p, 26) for p in prompts))
+        assert preempted and all(p == ([], 0) for p in preempted), preempted
+        for prompt, chunks in zip(prompts, outs):
+            got = [t for c in chunks for t in c]
+            assert len(got) >= 26
+            assert got == want_tokens(model, prompt, len(got))
+        snap = engine.readiness()
+        assert snap["diffusion_commits_ridden_total"] > 8
+    finally:
+        await engine.stop()
+
+
+def test_the_floor_is_one_expression_for_the_program_and_the_engine():
+    from dynamo_tpu.engine import runner as runner_mod
+
+    assert engine_mod.commit_floor_rows is commit_floor_rows
+    assert runner_mod.commit_floor_rows is commit_floor_rows
+    assert [commit_floor_rows(4, s) for s in (0, 1, 2, 3, 4, 8)] == [
+        4, 4, 2, 2, 1, 1]
+
+
+def test_a_ride_is_one_span_of_two_blocks_for_the_program():
+    """The program takes the 2B span as it takes a prefill quantum: the
+    device's ids land on the span's FIRST B rows (the finished block, fed
+    unmasked), the ids that come back are its LAST B rows' (the next
+    block's first denoising pass), and they are what the lone commit pass
+    followed by the next block's first pass give."""
+    model = tiny()
+
+    def runner():
+        return ModelRunner(engine_config(model, max_num_seqs=2),
+                           rng_seed=SEED)
+
+    samp = (0.0, 0, 1.0)
+    first = [-1, 5, -1, 9]
+    masks = [-1] * 4
+    lone = runner()
+    a = lone.unified_step([(first, [1], 0, samp)])
+    ids = np.asarray(a.toks)[0].tolist()
+    done = [t if t >= 0 else 7 for t in ids]       # a finished block
+    S = lone.unified_slots
+    prev = np.zeros((S, 4), np.int32)
+    prev[1] = done
+    feed = (prev, np.ones(S, np.int32), np.arange(S) == 0)
+    lone.unified_step([(first, [1], 0, samp)], feed=feed)        # commit
+    want = np.asarray(lone.unified_step([(masks, [1], 4, samp)]).toks)[0]
+
+    ride = runner()
+    ride.unified_step([(first, [1], 0, samp)])
+    got = np.asarray(ride.unified_step(
+        [(first + masks, [1], 0, samp)], feed=feed).toks)[0]
+    np.testing.assert_array_equal(got, want)
+    assert (got >= 0).sum() == 1                   # the floor: one row
+    for x, y in zip(jax.tree.leaves(lone.kv_caches),
+                    jax.tree.leaves(ride.kv_caches)):
+        np.testing.assert_allclose(
+            np.asarray(x)[8:16], np.asarray(y)[8:16], atol=2e-5)
+
+
+#: sha256 (16 digits) of the runner's own ladder program (its jaxpr, the
+#: kernels' source locations taken out) at T 32 and T 16 on the tree BEFORE
+#: the ride (commit 89f6afb), with the Pallas kernels: a dense preset, one
+#: with a recurrent state beside a latent layer, and the block program
+#: itself, whose compiled ladder the ride leaves as it was (a 2B span is a
+#: span the program already took). A PR that MEANS to change those programs
+#: regenerates them (``_runner_step_hash`` below, on its parent).
+PARENT_RUNNER_HASHES = {
+    "tiny_test": ("55c71453ed8a5e23", "572dbe0b3a317d53"),
+    "tiny_ling_test": ("8435d41fc787258d", "a6b644bfcd10cd29"),
+    "tiny_sdar_test": ("ce23ed6b0ab6bb62", "a51a03e47cc677de"),
+}
+
+
+def _runner_step_hash(preset: str, T: int) -> str:
+    import hashlib
+    import re
+
+    model = getattr(ModelConfig, preset)()
+    cfg = EngineConfig(
+        model=model, dtype="float32", block_size=8, num_blocks=32,
+        max_num_seqs=4, max_model_len=64, seed=0,
+        unified_token_budget=32, unified_prefill_quantum=16,
+    )
+    runner = ModelRunner(cfg, rng_seed=0)
+    B = model.diffusion_block_length or 1
+    lanes = [([5] * B, [1], 0, (0.0, 0, 1.0)),
+             ([7] * 8, [2], 0, (0.0, 0, 1.0))]
+    base, _meta, ops = runner._unified_operands(lanes, None, T)
+    text = str(jax.make_jaxpr(runner._unified)(
+        *runner._program_args(base), ops.buf, ops.prev_toks))
+    # a kernel's source location: the checkout's path and a line number
+    text = re.sub(r" at /[^\s\]\)]*", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("preset", sorted(PARENT_RUNNER_HASHES))
+def test_the_step_programs_are_the_parents(monkeypatch, preset):
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    got = tuple(_runner_step_hash(preset, T) for T in (32, 16))
+    assert got == PARENT_RUNNER_HASHES[preset]
 
 
 async def test_what_a_block_diffusion_model_refuses():

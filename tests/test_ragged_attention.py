@@ -653,6 +653,79 @@ def test_host_fold_counts_match_the_kernels_tile_folds():
         window=4096) == (1 + 1 + 2 + 17, 0)
 
 
+def test_fold_counts_of_a_dispatch_that_mixes_b_and_2b_row_spans(monkeypatch):
+    """A block model's dispatch where some lanes' commits ride (PR 55):
+    spans of B rows take the SHORT tile, a span of 2B rows (a finished
+    block, then the next block's masks) the LONG tile, one tile whose last
+    row sees the span's end; a prefill quantum beside them. The host's
+    count is the kernel's ``tile_folds`` span by span, and the runner notes
+    it for the dispatch it packs (``attn_folds``: every layer's)."""
+    B, K, tile = 4, 256, 32
+    spans = [(100, 4), (1032, 8), (508, 4), (252, 8), (0, 100), (2044, 8),
+             (1020, 4), (256, 8)]
+    q0, ql = (np.asarray(x, np.int32) for x in zip(*spans))
+    got = ragged_kernel.fold_counts(
+        q0, ql, q0 + ql, long_rows=tile, fold_keys=K, diffusion_block=B)
+    assert got == _tile_folds_by_hand(spans, tile, K, 0, B)
+    # three B spans: 1 + 2 + 4 folds; four rides and the quantum (4 tiles)
+    assert got == (1 + 2 + 4, (5 + 2 + 9 + 2) + 4)
+    # a ride's one tile walks the folds the lone commit pass and the next
+    # block's first pass would each have walked
+    for p, n in spans:
+        if n == 2 * B:
+            lone = ragged_kernel.fold_counts(
+                *(np.asarray([x], np.int32) for x in (p + B, B, p + 2 * B)),
+                long_rows=tile, fold_keys=K, diffusion_block=B)
+            ride = ragged_kernel.fold_counts(
+                *(np.asarray([x], np.int32) for x in (p, n, p + n)),
+                long_rows=tile, fold_keys=K, diffusion_block=B)
+            assert ride == (0, lone[0])
+
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.runner import ModelRunner
+    from dynamo_tpu.models.config import ModelConfig
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    model = ModelConfig.tiny_sdar_test()
+    runner = ModelRunner(EngineConfig(
+        model=model, dtype="float32", block_size=8, num_blocks=64,
+        max_num_seqs=4, max_model_len=256, seed=0,
+        unified_token_budget=64, unified_prefill_quantum=16), rng_seed=0)
+    plan = runner._fold_plan["count"].keywords
+    small = [(40, 4), (132, 8), (0, 24), (200, 8), (16, 4)]
+    lanes = [([7] * n, [1], p, (0.0, 0, 1.0)) for p, n in small]
+    runner._unified_operands(lanes, None, 64)
+    short, long = _tile_folds_by_hand(
+        small, plan["long_rows"], plan["fold_keys"], 0, B)
+    assert long and runner.attn_folds == (
+        model.num_layers * short, model.num_layers * long)
+
+
+def test_the_tools_ride_shape_is_the_cells_dispatch_with_a_lane_in_four_riding():
+    """``tools/ragged_kernel_bench.py`` ``sdar-ride`` (what set the tile a
+    2B span takes): 64 lanes on block boundaries, sixteen of them spans of
+    two blocks, beside a quantum that starts on one; inside the rung."""
+    from tools import ragged_kernel_bench as tool
+
+    shape = tool.SHAPES["sdar-ride"]
+    B = shape["diffusion_block"]
+    rng = np.random.default_rng(0)
+    contexts = tool.mixed_contexts(shape, rng, 200, 1500)
+    assert contexts.min() >= 100 and contexts.max() <= 1500
+    # the operands at a head width that costs the CPU nothing
+    *_ops, meta, spans = tool.build(
+        dict(shape, H=2, kvH=1, D=8), contexts, shape["prefill_ends"][0], rng)
+    assert [n for _, n in spans] == [2 * B] * 16 + [B] * 48 + [100]
+    assert all(p % B == 0 for p, _ in spans)
+    assert sum(n for _, n in spans) <= shape["T"]
+    folds = tool.fold_counts(shape, spans)
+    assert folds["long_folds"] > 16 and folds["short_folds"] >= 48
+    # the plain cell's shape is built as it was: every lane B rows
+    *_ops, _meta, plain = tool.build(
+        dict(tool.SHAPES["sdar"], H=2, kvH=1, D=8), contexts, 750, rng)
+    assert [n for _, n in plain] == [B] * 64 + [60]
+
+
 def test_unified_verify_rows_match_reference_forward():
     """llama.unified verify_rows > 1: every verify row's logits equal
     the no-cache reference forward at the same position — the law the

@@ -126,6 +126,38 @@ def test_compose_prefill_quantum_cap_lifts_when_alone():
     assert shared == [("p0", 16)]
 
 
+@pytest.mark.parametrize("rides", [0, 16, 48])
+def test_compose_takes_block_spans_of_b_and_2b_rows_whole(rides):
+    """A block model's decode items are ``(seq, width)`` pairs of B rows,
+    or 2B where a commit rides the next block's first pass (engine
+    ``_commit_rides``): inside what the budget has left once every lane has
+    its B rows and a waiting prompt its quantum (the engine grants rides
+    there and nowhere else) every lane is taken at the width it asked for,
+    and the prompts get the quantum reserved for them and what is left."""
+    B, budget, quantum = 4, 512, 64
+    dec = [(f"d{i}", 2 * B if i < rides else B) for i in range(64)]
+    pre = [(f"p{i}", 300) for i in range(4)]
+    assert budget - B * 64 - quantum >= B * rides      # the engine's room
+    decode_take, prefill_take = compose_unified(dec, pre, budget, quantum)
+    assert decode_take == dec
+    used = sum(w for _, w in decode_take)
+    assert sum(n for _, n in prefill_take) == min(4 * quantum, budget - used)
+    assert prefill_take[0] == ("p0", quantum)
+
+
+def test_compose_defers_a_2b_span_that_does_not_fit_but_not_a_b_span_behind():
+    """Outside that room the rotated fill would defer a lane: a span of 2B
+    rows that does not fit is passed over for a B-row span that does, which
+    is why the engine never asks for a ride it has no room for."""
+    B = 4
+    dec = [("a", 2 * B), ("b", 2 * B), ("c", B)]
+    take, _ = compose_unified(dec, [], budget=12, quantum=4)
+    assert take == [("a", 2 * B), ("c", B)]
+    # the same lanes at B rows each all run
+    take, _ = compose_unified([(s, B) for s, _ in dec], [], 12, 4)
+    assert [s for s, _ in take] == ["a", "b", "c"]
+
+
 def test_compose_starvation_bounds():
     """A full decode population cannot starve prefill below one quantum,
     and prefill can never displace a decode lane that fits."""

@@ -95,6 +95,15 @@ SHAPES = {
     # sdar-30b-a3b-l7.chat-c64: a lane is a block of 4 rows, no window
     "sdar": dict(H=32, kvH=4, lanes=64, rows=4, prefill=60, T=512,
                  window=0, diffusion_block=4),
+    # The same cell where a commit rides the next block's first pass (PR
+    # 55): sixteen of the 64 lanes (a lane in four, its block's last pass
+    # in flight) are ONE span of 2 x 4 rows, which the kernel tiles by its
+    # length; spans start on a block's boundary, as the engine's do. Named
+    # in ``--shapes``; ``--kernel-file`` a copy whose short tile is 8 rows
+    # beside it is how the tile for that span was chosen (``PERF.md`` §6).
+    "sdar-ride": dict(H=32, kvH=4, lanes=64, rows=4, prefill=100, T=512,
+                      window=0, diffusion_block=4, wide=(16, 8),
+                      ctx=(100, 1500), prefill_ends=(752,)),
     # No cell's: a speculative engine's dispatch, every lane a draft-verify
     # span of k + 1 = 5 rows (the long tile under ``diffusion_block=1``),
     # at one chip's widths and at a tp=4 chip's. Named in ``--shapes``.
@@ -145,9 +154,17 @@ def width(shape: dict) -> int:
 def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
     """One dispatch's operands: ``len(contexts)`` lanes of ``rows`` rows
     whose context after the step is ``contexts[i]``, then one prefill
-    span of ``shape['prefill']`` rows ending at ``prefill_ctx``."""
+    span of ``shape['prefill']`` rows ending at ``prefill_ctx``. A shape
+    with ``wide = (n, r)`` makes its first ``n`` lanes spans of ``r`` rows,
+    and every lane's span start on a multiple of ``rows``."""
     rows, n_pre = shape["rows"], shape["prefill"]
+    n_wide, wide_rows = shape.get("wide", (0, rows))
     spans = [(int(c) - rows, rows) for c in contexts]
+    if n_wide:
+        spans = [
+            (p - p % rows, wide_rows if i < n_wide else n)
+            for i, (p, n) in enumerate(spans)
+        ]
     if n_pre:
         assert prefill_ctx >= n_pre, (prefill_ctx, n_pre)
         spans.append((prefill_ctx - n_pre, n_pre))
