@@ -87,7 +87,7 @@ class TpuEngine:
         #: The model keeps a recurrent state beside the paged cache
         #: (docs/architecture/unified_step.md "State that is not pages").
         self._rec_on = cfg.model.has_recurrent
-        #: the kind of its recurrent layers ("kda" | "retention")
+        #: the kind of its recurrent layers ("kda" | "retention" | "ssd")
         self._rec_kind = (
             cfg.model.layer_kind(cfg.model.recurrent_layers[0])
             if self._rec_on else ""
@@ -96,6 +96,11 @@ class TpuEngine:
         #: in them (rows / (tiles x its tile) is how full the tiles run)
         self._kda_chunk_tiles = 0
         self._kda_chunk_rows = 0
+        #: and the state-space kernels: the chunk kernel's tiles and rows,
+        #: the lanes of one row
+        self._ssd_chunk_tiles = 0
+        self._ssd_chunk_rows = 0
+        self._ssd_decode_lanes = 0
         self._window_released_noted = 0
         #: The model keeps its cache by layer group: window and full
         #: layers in pools and tables of their own
@@ -1436,11 +1441,23 @@ class TpuEngine:
         if self._rec_on:
             # What the state table saw, under its layers' kind.
             kind = self._rec_kind
+            lanes = sum(r[3] == 1 for r in roles)
+            rows = sum(r[3] for r in roles if r[3] > 1)
             note.update({
-                f"{kind}_decode_lanes": sum(r[3] == 1 for r in roles),
-                f"{kind}_prefill_rows": sum(r[3] for r in roles if r[3] > 1),
+                f"{kind}_decode_lanes": lanes,
+                # (the state-space record names the rows by their kernel)
+                f"{kind}_{'chunk' if kind == 'ssd' else 'prefill'}_rows": rows,
                 f"{kind}_fresh_spans": sum(r[2] == 0 for r in roles),
             })
+            if kind == "ssd":
+                from dynamo_tpu.ops.pallas.ssd import TILE
+
+                # A span of more rows is whole tiles of the chunk kernel.
+                tiles = sum(-(-r[3] // TILE) for r in roles if r[3] > 1)
+                note["ssd_chunk_tiles"] = tiles
+                self._ssd_chunk_tiles += tiles
+                self._ssd_chunk_rows += rows
+                self._ssd_decode_lanes += lanes
             if kind == "kda":
                 from dynamo_tpu.ops.pallas.kda import TILE
 
@@ -3089,7 +3106,7 @@ class TpuEngine:
 
         m = self.cfg.model
         grouped = m.is_moe and m.experts_here >= GROUPED_MIN_EXPERTS
-        layers = m.num_layers - m.first_k_dense_replace
+        layers = sum(m.moe_layer(li) for li in range(m.num_layers))
         sched = self.scheduler
         return {
             # State that is not pages (a model with recurrent layers):
@@ -3108,6 +3125,9 @@ class TpuEngine:
             ),
             "kda_chunk_tiles_total": self._kda_chunk_tiles,
             "kda_chunk_rows_total": self._kda_chunk_rows,
+            "ssd_chunk_tiles_total": self._ssd_chunk_tiles,
+            "ssd_chunk_rows_total": self._ssd_chunk_rows,
+            "ssd_decode_lanes_total": self._ssd_decode_lanes,
             "diffusion_passes_total": self._diffusion_passes,
             "diffusion_committed_tokens_total": self._diffusion_committed,
             "diffusion_commits_ridden_total": self._diffusion_ridden,

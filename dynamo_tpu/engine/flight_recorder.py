@@ -89,6 +89,10 @@ class FlightRecorder:
         retention_decode_lanes: int = 0,
         retention_prefill_rows: int = 0,
         retention_fresh_spans: int = 0,
+        ssd_decode_lanes: int = 0,
+        ssd_chunk_rows: int = 0,
+        ssd_chunk_tiles: int = 0,
+        ssd_fresh_spans: int = 0,
         kv_full_blocks: int = 0,
         kv_window_blocks: int = 0,
         kv_window_released: int = 0,
@@ -135,7 +139,11 @@ class FlightRecorder:
         the spans that started from zeros; ``kda_chunk_tiles`` the tiles
         of the delta rule's chunk kernel those rows filled (a span of more
         rows is whole tiles of ``ops/pallas/kda.py`` ``TILE`` rows: rows /
-        (tiles x ``TILE``) is how full they run). The five ``kv_`` /
+        (tiles x ``TILE``) is how full they run). The four ``ssd_`` fields
+        are a state-space (Mamba-2) model's: the lanes of one row
+        (``ssd_recurrent``), the rows of the longer spans and the tiles of
+        ``ops/pallas/ssd.py`` ``TILE`` rows they filled (``ssd_chunk``),
+        the spans that started from zeros. The five ``kv_`` /
         ``context_`` fields are a model's that keeps its cache by layer
         group (docs/architecture/cache_groups.md): blocks in use in the
         full-attention and in the windowed pools as the step is noted,
@@ -180,6 +188,10 @@ class FlightRecorder:
             "retention_decode_lanes": retention_decode_lanes,
             "retention_prefill_rows": retention_prefill_rows,
             "retention_fresh_spans": retention_fresh_spans,
+            "ssd_decode_lanes": ssd_decode_lanes,
+            "ssd_chunk_rows": ssd_chunk_rows,
+            "ssd_chunk_tiles": ssd_chunk_tiles,
+            "ssd_fresh_spans": ssd_fresh_spans,
             "kv_full_blocks": kv_full_blocks,
             "kv_window_blocks": kv_window_blocks,
             "kv_window_released": kv_window_released,

@@ -221,6 +221,9 @@ class HttpService:
                 "recurrent_state_usage_perc",
                 "kda_chunk_tiles_total",
                 "kda_chunk_rows_total",
+                "ssd_chunk_tiles_total",
+                "ssd_chunk_rows_total",
+                "ssd_decode_lanes_total",
                 # The cache by layer group (docs/architecture/
                 # cache_groups.md): each pool's share in use, blocks
                 # released behind a window, preemptions by the pool that

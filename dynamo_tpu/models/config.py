@@ -29,12 +29,13 @@ class LayerSpec:
     that takes a layer index has its field (tests/test_layer_spec.py fails
     by name on one that has not), and no index reaches the body."""
 
-    kind: str            # layer_kind: "attn" | "kda" | "retention"
+    kind: str            # layer_kind: "attn" | "kda" | "retention" | "ssd" | "none"
     cache_arrays: int    # layer_cache_arrays: 2 = (k, v), 1 = the latent once
     window: int          # layer_window: 0 = full attention
     cache_group: int     # layer_cache_group: whose slots and block table
     rope: "tuple | str"  # layer_rope: (theta, scaling) or "none"
-    moe: bool            # moe_layer: routed experts, not a dense MLP
+    ffn: str             # layer_ffn: "dense" | "moe" | "none" (a mixer alone);
+    #                      moe_layer is ffn == "moe", the same fact
     swiglu_limit: float  # swiglu_limit: the routed experts' clamp
     shared_swiglu_limit: float  # swiglu_limit(shared=True)
 
@@ -155,7 +156,8 @@ class ModelConfig:
     # sqrt(var + eps) * w. rope_interleaved: rotary pairs are the adjacent
     # channels (2i, 2i + 1) (HF rope_gptj), not the two halves.
     # nope_full_layers: a full-attention layer of the window pattern takes
-    # NO rotary embedding. logit_scale multiplies the logits.
+    # NO rotary embedding (a family with no window at all says it of every
+    # attention layer: Nemotron-H). logit_scale multiplies the logits.
     # shared_experts_average: the shared experts' sum is divided by
     # n_shared_experts before it is added to the routed sum.
     # cache_by_layer_group: the window layers and the full-attention
@@ -190,6 +192,35 @@ class ModelConfig:
     # x (head_dim / 2 + 1)) x head_dim in float32 a sequence (ops/
     # power_retention.py). No layer pages: the model has no pool.
     retention_degree: int = 0
+    # --- layers that are ONE part (Nemotron-H family, model_type nemotron_h;
+    # docs/architecture/unified_step.md "A layer that is one part") ---
+    # layer_pattern: a letter a layer of the PUBLISHED depth ("M" a Mamba-2
+    # state-space mixer, "*" attention, "E" an expert layer; the family's
+    # "-", a dense MLP alone, is refused by from_hf until a configuration
+    # has one); a layer is norm -> that one part -> residual, and num_layers
+    # takes the pattern's head, so a cut never rewrites it. "" = every
+    # layer is a mixer and a feed-forward part, as every other family.
+    # Mamba-2 (SSD, arXiv:2405.21060): mamba_num_heads H heads of
+    # mamba_head_dim P over mamba_n_groups groups of B and C (NOT n_group,
+    # the router's groups), a state of ssm_state_size N a (head, channel):
+    # [H, P, N] float32 a sequence beside the last linear_conv_kernel - 1
+    # rows of the convolution's input (ops/ssd.py).
+    # moe_latent_size Z > 0: the routed experts live in a latent: ONE
+    # projection D -> Z in front of them all and one back behind their
+    # weighted sum; the router and the shared expert read the D-wide row.
+    # moe_shared_expert_intermediate_size: the shared expert's width where
+    # it is not moe_intermediate_size x n_shared_experts. hidden_act
+    # "relu2": an MLP of TWO matrices, relu(x W1)^2 W2, routed experts and
+    # shared expert alike. No rotary embedding in any attention layer is
+    # nope_full_layers: the family has no window, so every attention layer
+    # is a full one.
+    layer_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    moe_latent_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -201,9 +232,13 @@ class ModelConfig:
         return self.num_experts_held or self.num_experts
 
     def layer_kind(self, layer_idx: int) -> str:
-        """"kda" for a delta-rule linear-attention layer and "retention"
-        for a power-retention layer (both keep a recurrent state), "attn"
-        for one that reads keys and values through the paged cache."""
+        """"kda" for a delta-rule linear-attention layer, "retention" for a
+        power-retention layer and "ssd" for a Mamba-2 state-space layer
+        (``RECURRENT_KINDS``: they keep a recurrent state), "attn" for one
+        that reads keys and values through the paged cache, "none" for a
+        layer that is a feed-forward part alone (it owns neither)."""
+        if self.layer_pattern:
+            return PATTERN_KINDS[self.layer_pattern[layer_idx]][0]
         if self.retention_degree:
             return "retention"
         if self.layer_group_size and (layer_idx + 1) % self.layer_group_size:
@@ -215,7 +250,7 @@ class ModelConfig:
         """The layers that keep a recurrent state, in order."""
         return tuple(
             li for li in range(self.num_layers)
-            if self.layer_kind(li) != "attn"
+            if self.layer_kind(li) in RECURRENT_KINDS
         )
 
     @property
@@ -239,9 +274,18 @@ class ModelConfig:
         H, d, d]`` float32 and the convolution's tail ``[N, K - 1, 3 H d]``.
         "retention": the gated sum of ``phi(k) v^T``, ``[N, kvH, R, d, d]``
         float32, and of ``phi(k)``, ``[N, kvH, R (padded to 8), d]``
-        float32 (ops/power_retention.py ``state_shapes``)."""
+        float32 (ops/power_retention.py ``state_shapes``). "ssd": the state
+        ``[N, H, P, n]`` float32 and the convolution's tail ``[N, K - 1,
+        H P + 2 G n]`` (ops/ssd.py)."""
         kind = self.layer_kind(layer_idx)
         H, d = self.num_heads, self.head_dim
+        if kind == "ssd":
+            return (
+                ((n_slots, self.mamba_num_heads, self.mamba_head_dim,
+                  self.ssm_state_size), "float32"),
+                ((n_slots, self.linear_conv_kernel - 1, self.ssd_conv_dim),
+                 dtype),
+            )
         if kind == "kda":
             return (
                 ((n_slots, H, d, d), "float32"),
@@ -267,6 +311,16 @@ class ModelConfig:
             for li in range(self.num_layers)
             for shape, dt in self.recurrent_state_arrays(li, n_slots, dtype)
         )
+
+    @property
+    def ssd_inner(self) -> int:
+        """A Mamba-2 mixer's inner width: heads x head size."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssd_conv_dim(self) -> int:
+        """Channels of a Mamba-2 mixer's convolution: x, B and C."""
+        return self.ssd_inner + 2 * self.mamba_n_groups * self.ssm_state_size
 
     def swiglu_limit(self, layer_idx: int, shared: bool = False) -> float:
         limits = self.shared_swiglu_limit if shared else self.expert_swiglu_limit
@@ -323,7 +377,16 @@ class ModelConfig:
 
     def moe_layer(self, layer_idx: int) -> bool:
         """Does this layer use the routed-experts MLP?"""
-        return self.is_moe and layer_idx >= self.first_k_dense_replace
+        return self.layer_ffn(layer_idx) == "moe"
+
+    def layer_ffn(self, layer_idx: int) -> str:
+        """A layer's feed-forward part: "moe" (routed experts), "dense" (one
+        MLP), or "none" for a layer that is a mixer alone."""
+        if self.layer_pattern:
+            return PATTERN_KINDS[self.layer_pattern[layer_idx]][1]
+        if self.is_moe and layer_idx >= self.first_k_dense_replace:
+            return "moe"
+        return "dense"
 
     def layer_window(self, layer_idx: int) -> int:
         """Sliding-window size for one layer (0 = full attention): HF
@@ -346,7 +409,8 @@ class ModelConfig:
         Gemma-3 runs its windowed (local) layers on rope_local_theta with NO
         position scaling; global layers keep rope_theta + rope_scaling (HF
         Gemma3 rope_local_base_freq). A full-attention layer of a model with
-        ``nope_full_layers`` (Command A+) takes no rotary embedding at all."""
+        ``nope_full_layers`` (Command A+; Nemotron-H, whose every attention
+        layer is a full one) takes no rotary embedding at all."""
         window = self.layer_window(layer_idx)
         if self.nope_full_layers and not window:
             return "none"
@@ -362,7 +426,7 @@ class ModelConfig:
             window=self.layer_window(layer_idx),
             cache_group=self.layer_cache_group(layer_idx),
             rope=self.layer_rope(layer_idx),
-            moe=self.moe_layer(layer_idx),
+            ffn=self.layer_ffn(layer_idx),
             swiglu_limit=self.swiglu_limit(layer_idx),
             shared_swiglu_limit=self.swiglu_limit(layer_idx, shared=True),
         )
@@ -426,6 +490,8 @@ class ModelConfig:
             return ModelConfig._from_hf_cohere2_moe(cfg)
         if cfg.get("model_type") == "brumby":
             return ModelConfig._from_hf_brumby(cfg)
+        if cfg.get("model_type") == "nemotron_h":
+            return ModelConfig._from_hf_nemotron_h(cfg)
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -540,6 +606,79 @@ class ModelConfig:
                 cfg.get("expert_swiglu_limit_list") or ()),
             shared_swiglu_limit=tuple(
                 cfg.get("share_expert_swiglu_limit_list") or ()),
+        )
+
+    @staticmethod
+    def _from_hf_nemotron_h(cfg: dict) -> "ModelConfig":
+        """HF ``nemotron_h`` config.json (Nemotron-3-Super) -> ModelConfig:
+        layers that are ONE part by ``hybrid_override_pattern`` (a Mamba-2
+        mixer, attention without rotary embedding, or sigmoid-scored
+        non-gated experts in a latent beside a shared expert). ``n_group``
+        is the router's groups and ``n_groups`` the Mamba mixer's: two keys
+        of one config, a field each. The multi-token-prediction module
+        (``num_nextn_predict_layers``) is not served."""
+        pattern = cfg["hybrid_override_pattern"]
+        unserved = {
+            "a letter of hybrid_override_pattern outside 'M*E'":
+                bool(set(pattern) - set(PATTERN_KINDS)),
+            "a pattern shorter than num_hidden_layers":
+                len(pattern) < cfg["num_hidden_layers"],
+            "attention_bias": cfg.get("attention_bias"),
+            "mamba_proj_bias": cfg.get("mamba_proj_bias"),
+            "mlp_bias": cfg.get("mlp_bias"),
+            "use_bias": cfg.get("use_bias"),
+            "a convolution without bias": not cfg.get("use_conv_bias", True),
+            "an MLP activation that is not relu2":
+                cfg.get("mlp_hidden_act", "relu2") != "relu2",
+            "a Mamba activation that is not silu":
+                cfg.get("mamba_hidden_act", "silu") != "silu",
+            "a sliding window": cfg.get("sliding_window"),
+            "router groups (n_group > 1)": (cfg.get("n_group") or 1) > 1,
+            "tied embeddings": cfg.get("tie_word_embeddings"),
+            "an inner width that is not expand x hidden_size":
+                cfg["mamba_num_heads"] * cfg["mamba_head_dim"]
+                != cfg.get("expand", 2) * cfg["hidden_size"],
+        }
+        for what, on in unserved.items():
+            if on:
+                raise NotImplementedError(
+                    f"nemotron_h with {what} is not implemented")
+        return ModelConfig(
+            name=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim")
+            or cfg["hidden_size"] // cfg["num_attention_heads"],
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_eps=cfg.get("layer_norm_epsilon", cfg.get("norm_eps", 1e-5)),
+            max_position=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=False,
+            hidden_act="relu2",
+            num_experts=cfg.get("n_routed_experts", 0) or 0,
+            num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
+            n_shared_experts=cfg.get("n_shared_experts", 0) or 0,
+            moe_intermediate_size=cfg.get("moe_intermediate_size", 0) or 0,
+            moe_shared_expert_intermediate_size=cfg.get(
+                "moe_shared_expert_intermediate_size", 0) or 0,
+            moe_latent_size=cfg.get("moe_latent_size", 0) or 0,
+            gating="sigmoid",
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            routed_scaling_factor=float(
+                cfg.get("routed_scaling_factor", 1.0)),
+            n_group=1,
+            topk_group=1,
+            layer_pattern=pattern,
+            mamba_num_heads=cfg["mamba_num_heads"],
+            mamba_head_dim=cfg["mamba_head_dim"],
+            mamba_n_groups=cfg["n_groups"],
+            ssm_state_size=cfg["ssm_state_size"],
+            linear_conv_kernel=cfg["conv_kernel"],
+            nope_full_layers=True,
+            embed_init_std=1.0,
         )
 
     @staticmethod
@@ -1273,6 +1412,108 @@ class ModelConfig:
         )
 
     @staticmethod
+    def nemotron_3_super() -> "ModelConfig":
+        """NVIDIA-Nemotron-3-Super-120B-A12B (HF nvidia/NVIDIA-Nemotron-3-
+        Super-120B-A12B-BF16 config.json, model_type nemotron_h): 88 layers
+        that are ONE part each by the published pattern (40 Mamba-2 mixers,
+        8 attention layers of 32 query heads over 2 cached heads of 128
+        without rotary embedding, 40 expert layers), one RMSNorm a layer.
+        A mixer: 128 heads of 64 over 8 groups of B and C, a state of 128
+        a channel, a convolution of 4 taps with a bias. An expert layer:
+        512 sigmoid-scored experts with a selection bias, 22 a token
+        renormalised and scaled by 5, each two matrices (relu squared) of
+        1,024 x 2,688 inside a 1,024-wide latent, beside one shared expert
+        of width 5,376 on the 4,096-wide row. The multi-token-prediction
+        module is not served."""
+        return ModelConfig(
+            name="nemotron-3-super",
+            vocab_size=131072,
+            hidden_size=4096,
+            intermediate_size=2688,
+            num_layers=88,
+            num_heads=32,
+            num_kv_heads=2,
+            head_dim=128,
+            rope_theta=10000.0,
+            rms_eps=1e-5,
+            max_position=262144,
+            tie_word_embeddings=False,
+            hidden_act="relu2",
+            num_experts=512,
+            num_experts_per_tok=22,
+            n_shared_experts=1,
+            moe_intermediate_size=2688,
+            moe_shared_expert_intermediate_size=5376,
+            moe_latent_size=1024,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            routed_scaling_factor=5.0,
+            n_group=1,
+            topk_group=1,
+            layer_pattern=NEMOTRON_3_SUPER_PATTERN,
+            mamba_num_heads=128,
+            mamba_head_dim=64,
+            mamba_n_groups=8,
+            ssm_state_size=128,
+            linear_conv_kernel=4,
+            nope_full_layers=True,
+            embed_init_std=1.0,
+        )
+
+    @staticmethod
+    def nemotron_3_super_ep4_l11() -> "ModelConfig":
+        """One chip's share of a 4-way expert-parallel Nemotron-3-Super,
+        the first eleven layers (one whole period, ``MEMEMEM*EME``: five
+        mixers, five expert layers, one attention layer): experts 0-127 of
+        each expert layer, rows 0-32,767 of the vocabulary."""
+        return ModelConfig.nemotron_3_super().scaled(
+            name="nemotron-3-super-ep4-l11", num_layers=11,
+            num_experts_held=128, vocab_size=32768,
+        )
+
+    @staticmethod
+    def tiny_nemotron_h_test(
+        vocab_size: int = 384, held: int = 0
+    ) -> "ModelConfig":
+        """Hermetic Nemotron-H-style test model: seven layers ``MEM*EME`` in
+        one part each, 8 Mamba heads of 8 over 2 groups and a state of 16,
+        32 experts (``held`` of them here, 0 = all: the grouped path either
+        way from 16 up), 6 a token, two matrices each in a latent of 32."""
+        return ModelConfig(
+            name="tiny-nemotron-h-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=48,
+            num_layers=7,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=10000.0,
+            rms_eps=1e-5,
+            max_position=512,
+            tie_word_embeddings=False,
+            hidden_act="relu2",
+            num_experts=32,
+            num_experts_per_tok=6,
+            n_shared_experts=1,
+            moe_intermediate_size=48,
+            moe_shared_expert_intermediate_size=96,
+            moe_latent_size=32,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            routed_scaling_factor=5.0,
+            num_experts_held=held,
+            layer_pattern="MEM*EME",
+            mamba_num_heads=8,
+            mamba_head_dim=8,
+            mamba_n_groups=2,
+            ssm_state_size=16,
+            linear_conv_kernel=4,
+            nope_full_layers=True,
+            embed_init_std=1.0,
+        )
+
+    @staticmethod
     def command_a_plus() -> "ModelConfig":
         """Command A+ 05-2026 (HF CohereLabs/command-a-plus-05-2026
         config.json, model_type cohere2_moe; 218B-A25B): 32 parallel-block
@@ -1461,6 +1702,19 @@ class ModelConfig:
         return replace(self, **kwargs)
 
 
+#: the layer kinds that keep a recurrent state in the state table
+RECURRENT_KINDS = ("kda", "retention", "ssd")
+#: a letter of ``ModelConfig.layer_pattern`` -> (layer_kind, layer_ffn)
+PATTERN_KINDS = {
+    "M": ("ssd", "none"), "*": ("attn", "none"),
+    "E": ("none", "moe"),
+}
+#: Nemotron-3-Super's ``hybrid_override_pattern``, 88 letters as published
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+)
+
 #: SDAR's generation settings (its generate script's defaults; the
 #: config.json carries none of them).
 SDAR_GENERATION = {
@@ -1497,6 +1751,9 @@ PRESETS = {
     "command-a-plus": ModelConfig.command_a_plus,
     "command-a-plus-ep8-l4": ModelConfig.command_a_plus_ep8_l4,
     "tiny-command-a-test": ModelConfig.tiny_command_a_test,
+    "nemotron-3-super": ModelConfig.nemotron_3_super,
+    "nemotron-3-super-ep4-l11": ModelConfig.nemotron_3_super_ep4_l11,
+    "tiny-nemotron-h-test": ModelConfig.tiny_nemotron_h_test,
     "brumby-14b": ModelConfig.brumby_14b,
     "tiny-brumby-test": ModelConfig.tiny_brumby_test,
 }

@@ -21,7 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import LayerSpec, ModelConfig
+from dynamo_tpu.models.config import RECURRENT_KINDS, LayerSpec, ModelConfig
 from dynamo_tpu.ops.attention import AttnDispatch, full_causal_attention
 from dynamo_tpu.ops.norms import layer_norm, rms_norm
 from dynamo_tpu.ops.quant import (
@@ -186,6 +186,8 @@ def init_layer_params(
     def norm_init(shape):
         return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, dtype)
 
+    if cfg.layer_pattern:
+        return _init_one_part_layer(key, cfg, li, dtype)
     kda = cfg.layer_kind(li) == "kda"
     # (A linear-attention layer draws more matrices than 16 keys hold; the
     # other kinds keep the split their weights have always come from.)
@@ -264,6 +266,80 @@ def init_layer_params(
     if cfg.qk_norm and not kda:
         layer["ln_q_head"] = norm_init((hd,))
         layer["ln_k_head"] = norm_init((hd,))
+    return layer
+
+
+def _init_one_part_layer(key, cfg: ModelConfig, li: int, dtype) -> Params:
+    """A layer of a model whose layers are ONE part each
+    (``cfg.layer_pattern``): its norm and only what its part has: a Mamba-2
+    mixer (``_init_ssd_mixer``), an attention layer's four projections, an
+    expert layer's router, latent projections, held experts and shared
+    expert. The MLPs are the family's form (``hidden_act`` "relu2": two
+    matrices, no gate). An initialiser of its own beside
+    ``init_layer_params``' body because that one's order of key splits
+    seeds the accepted cells' weights: a branch a part threaded through
+    it would either move their draws or draw keys for parts a layer of
+    this family has not."""
+    assert cfg.hidden_act == "relu2", cfg.hidden_act
+    D, H, kvH, hd = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(key, 16))
+    dense = lambda shape: _dense_init(next(keys), shape, dtype)
+    layer: Params = {"ln_attn": jnp.ones((D,), dtype)}
+    kind, ffn = cfg.layer_kind(li), cfg.layer_ffn(li)
+    if kind == "ssd":
+        layer.update(_init_ssd_mixer(keys, cfg, dtype))
+    elif kind == "attn":
+        layer.update(
+            wq=dense((D, H * hd)), wk=dense((D, kvH * hd)),
+            wv=dense((D, kvH * hd)), wo=dense((H * hd, D)),
+        )
+    if ffn == "moe":
+        E, Eh, Im = cfg.num_experts, cfg.experts_here, cfg.moe_intermediate_size
+        Z = cfg.moe_latent_size or D
+        layer["w_router"] = dense((D, E))
+        if cfg.gating == "sigmoid":
+            layer["router_bias"] = jnp.zeros((E,), jnp.float32)
+        if cfg.moe_latent_size:
+            layer["w_latent_down"] = dense((D, Z))
+        layer["w_up"] = _dense3(next(keys), (Eh, Z, Im), Z, dtype)
+        layer["w_down"] = _dense3(next(keys), (Eh, Im, Z), Im, dtype)
+        if cfg.moe_latent_size:
+            layer["w_latent_up"] = dense((Z, D))
+        if cfg.n_shared_experts:
+            Is = cfg.moe_shared_expert_intermediate_size or (
+                Im * cfg.n_shared_experts)
+            layer["w_shared_up"] = dense((D, Is))
+            layer["w_shared_down"] = dense((Is, D))
+    return layer
+
+
+def _init_ssd_mixer(keys, cfg: ModelConfig, dtype) -> Params:
+    """A Mamba-2 mixer: the in-projection to ``[z | x B C | dt]``, the
+    depthwise convolution over ``x B C`` WITH its bias, ``A_log``, ``D`` and
+    ``dt_bias`` a head, the gated norm's weight, the out-projection.
+    Seeded stand-ins for trained values (the configuration file's
+    ``assumed``): ``A_log = log(uniform(1, 16))``, ``D = 1``, ``dt_bias``
+    the inverse softplus of a log-uniform draw in [0.001, 0.1] floored at
+    1e-4 (the family's ``time_step_min`` / ``max`` / ``floor``)."""
+    import math
+
+    D, H, K = cfg.hidden_size, cfg.mamba_num_heads, cfg.linear_conv_kernel
+    di, cd = cfg.ssd_inner, cfg.ssd_conv_dim
+    layer = {
+        "w_in": _dense_init(next(keys), (D, di + cd + H), dtype),
+        "conv_w": _dense_init(next(keys), (K, cd), dtype),
+        "conv_b": _dense3(next(keys), (cd,), K, dtype),
+        "A_log": jnp.log(
+            jax.random.uniform(next(keys), (H,), jnp.float32, 1.0, 16.0)),
+    }
+    step = jnp.maximum(jnp.exp(
+        jax.random.uniform(next(keys), (H,), jnp.float32)
+        * (math.log(0.1) - math.log(0.001)) + math.log(0.001)
+    ), 1e-4)
+    layer["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
+    layer["D"] = jnp.ones((H,), jnp.float32)
+    layer["w_out"] = _dense_init(next(keys), (di, D), dtype)
+    layer["ln_ssd"] = jnp.ones((di,), dtype)
     return layer
 
 
@@ -479,6 +555,12 @@ def _mlp(
     return _swiglu(layer, x, act=cfg.hidden_act)
 
 
+def _relu2(layer: Params, x: jnp.ndarray, prefix: str = "w_") -> jnp.ndarray:
+    """A non-gated MLP of two matrices: ``relu(x W1)^2 W2``."""
+    up = jax.nn.relu(qdot(x, layer[f"{prefix}up"]))
+    return qdot(up * up, layer[f"{prefix}down"])
+
+
 def _moe_mlp(
     layer: Params, x: jnp.ndarray, cfg: ModelConfig, spec: LayerSpec,
     mesh=None, valid=None,
@@ -502,16 +584,32 @@ def _moe_mlp(
         num_experts_held=cfg.num_experts_held,
         expert_held_offset=cfg.expert_held_offset,
         swiglu_limit=spec.swiglu_limit,
+        act="relu2" if cfg.hidden_act == "relu2" else "swiglu",
+        expert_input_size=cfg.moe_latent_size,
     )
     lead = x.shape[:-1]
     flat = x.reshape(-1, cfg.hidden_size)
     with jax.named_scope("expert_layer"):
-        out = moe_mlp(layer, flat, mcfg, mesh=mesh, valid=valid)
-        if "w_shared_gate" in layer:
+        latent = None
+        if cfg.moe_latent_size:
+            # Experts in a latent: ONE projection in front of them all (the
+            # router and the shared expert read the whole row) and one back
+            # behind their weighted sum.
+            with jax.named_scope("latent_down"):
+                latent = qdot(flat, layer["w_latent_down"])
+        out = moe_mlp(
+            layer, flat, mcfg, mesh=mesh, valid=valid, expert_x=latent)
+        if latent is not None:
+            with jax.named_scope("latent_up"):
+                out = qdot(out, layer["w_latent_up"])
+        if "w_shared_up" in layer:
             with jax.named_scope("shared_experts"):
-                shared = _swiglu(
-                    layer, flat, prefix="w_shared_",
-                    limit=spec.shared_swiglu_limit,
+                shared = (
+                    _relu2(layer, flat, prefix="w_shared_")
+                    if cfg.hidden_act == "relu2" else _swiglu(
+                        layer, flat, prefix="w_shared_",
+                        limit=spec.shared_swiglu_limit,
+                    )
                 )
             if cfg.shared_experts_average:
                 # The stacked shared experts' product IS their sum.
@@ -562,6 +660,46 @@ def _kda_mixer(
     gate = jax.nn.sigmoid(qdot(h, layer["w_g"]).astype(jnp.float32))
     o = rms_norm(o, layer["ln_kda"], cfg.rms_eps) * gate[:, :, None]
     return qdot(o.reshape(T, H * d).astype(h.dtype), layer["wo"]), (S, tail)
+
+
+def _ssd_mixer(
+    layer: Params, h: jnp.ndarray, cfg: ModelConfig, state, meta,
+    state_slot, use_pallas: bool,
+):
+    """A Mamba-2 (SSD) layer's mixer over the flat ragged batch (ops/
+    ssd.py): ``h`` [T, D] normed rows -> (y [T, D], the layer's new state).
+    ``state`` is the layer's (S [N+1, H, P, n], convolution tail [N+1,
+    K-1, H P + 2 G n]); ``meta`` the dispatch's (token_seq, token_pos,
+    q_start, q_len, row_start). ``[z | xBC | dt] = h W_in``; conv WITH
+    bias -> silu on ``xBC``; ``dt = softplus(dt + dt_bias)``, the decay
+    ``exp(-exp(A_log) dt)`` a head; the skip ``D x``; the gate BEFORE the
+    norm, ``norm(y * silu(z))`` over groups of ``H P / G`` channels; no
+    rotary embedding."""
+    from dynamo_tpu.ops.linear_attention import causal_conv
+    from dynamo_tpu.ops.ssd import ssd_ragged
+
+    T = h.shape[0]
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N, di = cfg.mamba_n_groups, cfg.ssm_state_size, cfg.ssd_inner
+    S, tail = state
+    zxbcdt = qdot(h, layer["w_in"])
+    z, xbc, dt = jnp.split(zxbcdt, [di, di + cfg.ssd_conv_dim], axis=-1)
+    xbc, tail = causal_conv(
+        xbc, layer["conv_w"], tail, *meta, state_slot, bias=layer["conv_b"]
+    )
+    x, B, C = jnp.split(jax.nn.silu(xbc), [di, di + G * N], axis=-1)
+    x = x.reshape(T, H, P)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
+    y, S = ssd_ragged(
+        x, dt, -jnp.exp(layer["A_log"]) * dt, B.reshape(T, G, N),
+        C.reshape(T, G, N), S, *meta, state_slot, use_pallas=use_pallas,
+    )
+    y = (y + layer["D"][None, :, None] * x).reshape(T, di)
+    y = rms_norm(
+        (y * jax.nn.silu(z.astype(jnp.float32))).reshape(T, G, di // G),
+        layer["ln_ssd"].reshape(G, di // G), cfg.rms_eps,
+    ).reshape(T, di)
+    return qdot(y.astype(h.dtype), layer["w_out"]), (S, tail)
 
 
 def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig,
@@ -671,19 +809,27 @@ def _layer_rows(
     # elsewhere; a model whose experts are all here computes every row.
     valid = token_pos >= 0 if cfg.num_experts_held else None
     h = _ln(x, layer["ln_attn"], cfg)
+    if spec.kind == "none":
+        # A feed-forward part alone (docs/architecture/unified_step.md "A
+        # layer that is one part"): it reads the layer's ONE norm and owns
+        # neither pages nor state.
+        x = _residual_mlp(x, layer, cfg, spec, mesh, valid, h=h)
+        return x, cache, kv_scale, state
     if spec.kind != "attn":
         # A recurrent layer: its state in and out, no pages.
-        mixer = (
-            _kda_mixer if spec.kind == "kda"
-            else partial(_retention_mixer, spec=spec)
-        )
+        mixer = {
+            "kda": _kda_mixer, "ssd": _ssd_mixer,
+            "retention": partial(_retention_mixer, spec=spec),
+        }[spec.kind]
         with jax.named_scope(f"{spec.kind}_mixer"):
             y, state = mixer(
                 layer, h, cfg, state,
                 (token_seq, token_pos, q_start, q_len, row_start),
                 state_slot, attn is not None and attn.use_pallas,
             )
-        x = _residual_mlp(x + y, layer, cfg, spec, mesh, valid)
+        x = x + y
+        if spec.ffn != "none":
+            x = _residual_mlp(x, layer, cfg, spec, mesh, valid)
         return x, cache, kv_scale, state
     positions = jnp.maximum(token_pos, 0)
     if attn is None and spec.cache_arrays == 1:
@@ -795,7 +941,8 @@ def _layer_rows(
         x = _residual_attn(
             x, layer, qdot(attn_out.reshape(T, -1), layer["wo"]), cfg
         )
-    x = _residual_mlp(x, layer, cfg, spec, mesh, valid)
+    if spec.ffn != "none":
+        x = _residual_mlp(x, layer, cfg, spec, mesh, valid)
     return x, cache, kv_scale, state
 
 
@@ -893,19 +1040,20 @@ def unified(
     for li, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
         spec = cfg.layer_spec(li)
         paged = spec.kind == "attn"
+        recurrent = spec.kind in RECURRENT_KINDS
         LAYER_BODY["calls"] += 1
         x, cache, scale, state, hit = _layer_body(
             cfg, spec, block_size, attn, pallas,
             layer, cache,
             kv_scales[li] if kv_scales is not None and paged else None,
-            None if paged else rec_state[len(new_rec)],
+            rec_state[len(new_rec)] if recurrent else None,
             x, meta, slots_of[spec.cache_group], tables_of[spec.cache_group],
             state_slot,
         )
         new_caches.append(cache)
         if scale is not None:
             new_scales.append(scale)
-        if not paged:
+        if recurrent:
             new_rec.append(state)
         note_experts_hit(*hit)
 
@@ -959,14 +1107,19 @@ def hidden_states(
     for li, layer in enumerate(params["layers"]):
         spec = cfg.layer_spec(li)
         h = _ln(x, layer["ln_attn"], cfg)
-        if spec.kind == "kda":
+        if spec.kind == "none":     # a feed-forward part alone
+            x = _residual_mlp(x, layer, cfg, spec, h=h)
+            continue
+        if spec.kind in ("kda", "ssd"):
             # One span from zeros: slot 1 of a fresh two-slot state.
-            H, d, K = cfg.num_heads, cfg.head_dim, cfg.linear_conv_kernel
             one = jnp.ones((1,), jnp.int32)
-            y, _ = _kda_mixer(
+            mixer = _kda_mixer if spec.kind == "kda" else _ssd_mixer
+            y, _ = mixer(
                 layer, h, cfg,
-                (jnp.zeros((2, H, d, d), jnp.float32),
-                 jnp.zeros((2, K - 1, 3 * H * d), h.dtype)),
+                tuple(
+                    jnp.zeros(shape, dt) for shape, dt in
+                    cfg.recurrent_state_arrays(li, 2, h.dtype.name)
+                ),
                 (jnp.zeros((T,), jnp.int32), positions, 0 * one, T * one,
                  0 * one),
                 one, False,
@@ -996,7 +1149,8 @@ def hidden_states(
                 x = _residual_mlp(x, layer, cfg, spec, h=h) + a
                 continue
             x = _residual_attn(x, layer, a, cfg)
-        x = _residual_mlp(x, layer, cfg, spec)
+        if spec.ffn != "none":
+            x = _residual_mlp(x, layer, cfg, spec)
     return x
 
 
@@ -1012,6 +1166,68 @@ def reference_forward(
     return _logits(
         params, cfg, hidden_states(cfg, params, token_ids, embeds, embed_mask)
     )
+
+
+def _load_one_part_layers(cfg: ModelConfig, tensors: dict, w) -> Params:
+    """The params of a model whose layers are one part each
+    (``cfg.layer_pattern``; HF ``nemotron_h``) from its checkpoint tensors:
+    ``backbone.layers.{i}.norm`` and ``.mixer.*`` by the layer's letter (a
+    Mamba-2 mixer's ``in_proj``, ``conv1d`` ([C, 1, K] -> our [K, C]) with
+    its bias, ``A_log``, ``D``, ``dt_bias``, gated ``norm``, ``out_proj``;
+    attention's four projections; an expert layer's ``gate`` with its
+    ``e_score_correction_bias``, ``fc1_latent_proj`` / ``fc2_latent_proj``,
+    the held ``experts.{e}.up_proj`` / ``down_proj`` and
+    ``shared_experts``). ``w(name)`` reads a tensor transposed to [in,
+    out]. No checkpoint of the family is here: the names are the public
+    modelling code's, exercised on a seeded state dict
+    (tests/test_nemotron_h.py)."""
+    f32 = lambda name: jnp.asarray(tensors[name], jnp.float32)
+    layers = []
+    for i in range(cfg.num_layers):
+        m = f"backbone.layers.{i}.mixer"
+        layer = {
+            "ln_attn": w(f"backbone.layers.{i}.norm.weight", transpose=False)
+        }
+        kind, ffn = cfg.layer_kind(i), cfg.layer_ffn(i)
+        if kind == "ssd":
+            conv = jnp.asarray(tensors[f"{m}.conv1d.weight"])
+            layer.update(
+                w_in=w(f"{m}.in_proj.weight"),
+                conv_w=conv[:, 0, :].T.astype(layer["ln_attn"].dtype),
+                conv_b=w(f"{m}.conv1d.bias", transpose=False),
+                A_log=f32(f"{m}.A_log"), D=f32(f"{m}.D"),
+                dt_bias=f32(f"{m}.dt_bias"),
+                ln_ssd=w(f"{m}.norm.weight", transpose=False),
+                w_out=w(f"{m}.out_proj.weight"),
+            )
+        elif kind == "attn":
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wo", "o_proj")):
+                layer[ours] = w(f"{m}.{theirs}.weight")
+        if ffn == "moe":
+            layer["w_router"] = w(f"{m}.gate.weight")
+            layer["router_bias"] = f32(f"{m}.gate.e_score_correction_bias")
+            if cfg.moe_latent_size:
+                layer["w_latent_down"] = w(f"{m}.fc1_latent_proj.weight")
+                layer["w_latent_up"] = w(f"{m}.fc2_latent_proj.weight")
+            lo = cfg.expert_held_offset
+            for ours, theirs in (("w_up", "up_proj"), ("w_down", "down_proj")):
+                layer[ours] = jnp.stack([
+                    w(f"{m}.experts.{e}.{theirs}.weight")
+                    for e in range(lo, lo + cfg.experts_here)
+                ])
+            if cfg.n_shared_experts:
+                layer["w_shared_up"] = w(f"{m}.shared_experts.up_proj.weight")
+                layer["w_shared_down"] = w(
+                    f"{m}.shared_experts.down_proj.weight")
+        layers.append(layer)
+    V = cfg.vocab_size               # a share holds the leading rows
+    return {
+        "embed": w("backbone.embeddings.weight", transpose=False)[:V],
+        "layers": layers,
+        "ln_f": w("backbone.norm_f.weight", transpose=False),
+        "lm_head": w("lm_head.weight")[:, :V],
+    }
 
 
 def load_hf_weights(
@@ -1059,6 +1275,13 @@ def load_hf_weights(
             arr = arr.T
         return jnp.asarray(arr, dtype=dtype)
 
+    if cfg.layer_pattern:
+        if policy is not None and policy.active:
+            raise NotImplementedError(
+                "load_hf_weights: a weight-quant policy over a model whose "
+                "layers are one part each is not implemented"
+            )
+        return _load_one_part_layers(cfg, tensors, w)
     layers = []
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}"

@@ -55,6 +55,12 @@ class MoeConfig:
     # Clamp before the activation: gate to at most L, up into [-L, L];
     # 0 = none.
     swiglu_limit: float = 0.0
+    # An expert's form: "swiglu" (three matrices, silu(gate) * up) or
+    # "relu2" (two, relu(x W1)^2 W2: no ``w_gate`` in the params).
+    act: str = "swiglu"
+    # The width the experts read and write where it is not the router's
+    # (experts in a latent: ``moe_mlp``'s ``expert_x``); 0 = hidden_size.
+    expert_input_size: int = 0
 
     @property
     def experts_here(self) -> int:
@@ -171,8 +177,18 @@ def clamp_swiglu(gate, up, limit: float):
 
 
 def _act(gate, up, cfg: MoeConfig):
+    if cfg.act == "relu2":                  # non-gated: ``gate`` is None
+        return jnp.square(jax.nn.relu(up))
     gate, up = clamp_swiglu(gate, up, cfg.swiglu_limit)
     return jax.nn.silu(gate) * up
+
+
+def expert_weights(params: dict, cfg: MoeConfig) -> tuple:
+    """The stacked expert matrices in the order the products take them:
+    (gate, up, down), or (up, down) for a non-gated expert."""
+    names = ("w_up", "w_down") if cfg.act == "relu2" else (
+        "w_gate", "w_up", "w_down")
+    return tuple(params[n] for n in names)
 
 
 def _expert_einsum(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -188,9 +204,13 @@ def _expert_einsum(pattern: str, x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 def moe_mlp(
-    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None
+    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None,
+    expert_x=None,
 ) -> jnp.ndarray:
-    """x [T, D] → [T, D] through top-k routed experts, exactly.
+    """x [T, D] → [T, D] through top-k routed experts, exactly. With
+    ``expert_x`` [T, Z] (experts in a latent: ``cfg.expert_input_size``)
+    the router reads ``x`` and the experts read and write ``expert_x``'s
+    width: [T, Z] out.
 
     Below ``GROUPED_MIN_EXPERTS`` experts: every expert for every token,
     masked by the gates (O(E/topk) extra FLOPs; GSPMD shards it). From
@@ -198,13 +218,21 @@ def moe_mlp(
     products per shard. ``valid`` [T] marks the rows that hold a token
     (budget padding does not): an expert share drops the others with the
     rows routed elsewhere."""
+    width = cfg.expert_input_size or cfg.hidden_size
+    if (x if expert_x is None else expert_x).shape[-1] != width:
+        raise ValueError(
+            f"the experts read rows {width} wide (MoeConfig."
+            "expert_input_size): hand a latent in as expert_x"
+        )
     if cfg.grouped:
         with jax.named_scope("moe_grouped_ffn"):
-            return _moe_mlp_grouped(params, x, cfg, mesh, valid)
+            return _moe_mlp_grouped(params, x, cfg, mesh, valid, expert_x)
     gates = moe_router(params, x, cfg)
-    xf = x.astype(jnp.float32)
+    xf = (x if expert_x is None else expert_x).astype(jnp.float32)
     up = _expert_einsum("td,edi->tei", xf, params["w_up"])
-    gate = _expert_einsum("td,edi->tei", xf, params["w_gate"])
+    gate = None
+    if cfg.act != "relu2":
+        gate = _expert_einsum("td,edi->tei", xf, params["w_gate"])
     h = _act(gate, up, cfg)                                       # [T, E, I]
     out = _expert_einsum("tei,eid->ted", h, params["w_down"])
     return jnp.einsum("ted,te->td", out, gates).astype(x.dtype)
@@ -272,11 +300,17 @@ GMM_TILE_BYTES = 4 * 1024 * 1024
 def gmm_tile(K: int, N: int, itemsize: int) -> tuple[int, int]:
     """``(tk, tn)`` of the grouped matmul kernel's weight tile: all of K
     (one pass over a row tile, no accumulation across tiles) and as many
-    columns, a multiple of 128 that divides N, as ``GMM_TILE_BYTES`` hold."""
-    tn = N
-    while K * tn * itemsize > GMM_TILE_BYTES and tn % 256 == 0:
-        tn //= 2
-    return K, tn
+    columns as ``GMM_TILE_BYTES`` hold: the largest multiple of 128 that
+    divides N (2,688 = 21 x 128 -> 896 under K 1,024; a power of two times
+    128 halves as it always did), the smallest where none fits, all of N
+    where N is no multiple of 128 (the kernel's gate refuses that shape)."""
+    if N % 128 or K * N * itemsize <= GMM_TILE_BYTES:
+        return K, N
+    fits = [
+        tn for tn in range(128, N, 128)
+        if N % tn == 0 and K * tn * itemsize <= GMM_TILE_BYTES
+    ]
+    return K, max(fits, default=128)
 
 
 def _grouped_dot(rows, w, sizes, row_expert):
@@ -319,7 +353,8 @@ def _grouped_dot(rows, w, sizes, row_expert):
 
 
 def _moe_mlp_grouped(
-    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None
+    params: dict, x: jnp.ndarray, cfg: MoeConfig, mesh=None, valid=None,
+    expert_x=None,
 ) -> jnp.ndarray:
     """The dropless grouped path: the T*k routed (token, expert) rows
     sorted by expert, one ``ragged_dot`` an expert projection over the
@@ -332,9 +367,12 @@ def _moe_mlp_grouped(
     its ``tp`` slice of every expert's width and, over ``ep``, its own
     experts, whose rows lie together in the sorted order; the partial
     results meet in one all-reduce."""
-    T, D = x.shape
+    T = x.shape[0]
     E, k = cfg.experts_here, cfg.num_experts_per_tok
     topi, gates_k = moe_route(params, x, cfg)               # [T, k] each
+    if expert_x is not None:       # the experts' own width (a latent)
+        x = expert_x
+    D = x.shape[1]
     flat_e = topi.reshape(-1) - cfg.expert_held_offset       # [T*k]
     held = None
     if cfg.num_experts_held:
@@ -356,8 +394,12 @@ def _moe_mlp_grouped(
         _EXPERTS_HIT.append((sizes > 0).sum().astype(jnp.int32))
         _EXPERTS_HIT.rows_held.append(sizes.sum())
 
-    def ffn(rows, sizes, row_expert, w_gate, w_up, w_down):
-        gate = _grouped_dot(rows, w_gate, sizes, row_expert)
+    def ffn(rows, sizes, row_expert, *w):
+        *w_gate, w_up, w_down = w          # no gate: a non-gated expert
+        gate = (
+            _grouped_dot(rows, w_gate[0], sizes, row_expert)
+            if w_gate else None
+        )
         up = _grouped_dot(rows, w_up, sizes, row_expert)
         h = _act(gate, up, cfg).astype(rows.dtype)
         return _grouped_dot(h, w_down, sizes, row_expert)    # [T*k, D] f32
@@ -366,7 +408,7 @@ def _moe_mlp_grouped(
         a: n for a, n in (dict(mesh.shape) if mesh is not None else {}).items()
         if a in ("ep", "tp") and n > 1
     }
-    weights = (params["w_gate"], params["w_up"], params["w_down"])
+    weights = expert_weights(params, cfg)
     if not axes:
         y = ffn(rows, sizes, row_expert, *weights)
     else:
@@ -408,8 +450,9 @@ def _moe_mlp_grouped(
 
 
 def moe_weight_specs(weights, e_ax, tp):
-    """``shard_map`` specs of (w_gate, w_up, w_down), plain or quantized:
-    experts over ``e_ax``, the experts' width over ``tp``."""
+    """``shard_map`` specs of (w_gate, w_up, w_down) (or (w_up, w_down)),
+    plain or quantized: experts over ``e_ax``, the experts' width over
+    ``tp`` (the last matrix's rows, every other's columns)."""
     from dynamo_tpu.ops.quant import is_quantized
 
     def spec(w, wide_last: bool):
@@ -418,7 +461,8 @@ def moe_weight_specs(weights, e_ax, tp):
             return full
         return {"q": full, "s": P(e_ax, tp) if wide_last else P(e_ax, None)}
 
-    return tuple(spec(w, i < 2) for i, w in enumerate(weights))
+    return tuple(
+        spec(w, i < len(weights) - 1) for i, w in enumerate(weights))
 
 
 def shard_moe_params(params: dict, mesh) -> dict:

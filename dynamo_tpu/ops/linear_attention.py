@@ -39,14 +39,16 @@ def span_rows(token_seq, token_pos, q_start, q_len):
 
 
 def causal_conv(
-    x, w, tail, token_seq, token_pos, q_start, q_len, row_start, state_slot
+    x, w, tail, token_seq, token_pos, q_start, q_len, row_start, state_slot,
+    bias=None,
 ):
     """Depthwise causal convolution over each span's rows, continued from
     its slot's tail: ``y_t = sum_i w[i] * x_{t - (K-1) + i}`` (the torch
     conv1d order), rows before the sequence's first reading zero.
 
     ``x`` [T, C], ``w`` [K, C], ``tail`` [N + 1, K - 1, C] (the K - 1
-    input rows before each slot's next position). Returns (y [T, C] in
+    input rows before each slot's next position), ``bias`` [C] added to
+    every row's result where the layer has one. Returns (y [T, C] in
     float32, the new tail)."""
     K = w.shape[0]
     T = x.shape[0]
@@ -56,6 +58,8 @@ def causal_conv(
         fresh[:, None, None], jnp.zeros((), tail.dtype), tail[state_slot]
     )                                                        # [S, K-1, C]
     y = x.astype(jnp.float32) * w[K - 1].astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     for i in range(1, K):
         # The row i back: of this span where it has one, else the tail's.
         in_span = jnp.roll(x, i, axis=0)

@@ -69,6 +69,14 @@ _OUTLIVED = {
         "the LAST of every list it joined; held by name in "
         "test_chipbench_deepseek_v2.py::"
         "test_the_cell_before_still_holds_what_it_brought",
+    "tests/chipbench/test_chipbench_deepseek_v2.py::"
+    "test_the_cell_before_still_holds_what_it_brought":
+        "asserts that no later cell joined state.slots_used_peak_pct, which "
+        "the next cell with a state table in slots reports (PR 56, after "
+        "review); every other fact of it is held by name in "
+        "test_chipbench_nemotron_h.py::"
+        "test_the_cells_before_still_hold_what_they_brought"
+        "[brumby-14b-l8.longdoc-c20]",
 }
 
 

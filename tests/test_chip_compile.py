@@ -356,6 +356,101 @@ def test_kda_kernels_compile_at_published_widths(
 
 
 @pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
+def test_nemotron_share_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch
+):
+    """The whole of ``nemotron-3-super-ep4-l11`` (five Mamba-2 mixers, five
+    expert layers over 128 of 512 latent experts, one attention layer, a
+    quarter of the vocabulary) in one unified step at T=256 with the state
+    table of 128 lanes: the state-space kernels (two a mixer, the state
+    aliased in place), the ragged kernel over two cached heads of 128, the
+    grouped expert path's kernels (two a layer: non-gated), within one
+    chip's memory."""
+    from dynamo_tpu.models import moe
+    from dynamo_tpu.ops.pallas import ssd as ssd_kernels
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(ssd_kernels, "_interpret", lambda: False)
+    cfg = ModelConfig.nemotron_3_super_ep4_l11()
+    sds = partial(_sds, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+    lanes, rows = 129, 132
+    page = sds((65536 * BS, 2, 128), jnp.bfloat16)
+    kv = [(page, page) if cfg.layer_kind(li) == "attn" else ()
+          for li in range(cfg.num_layers)]
+    rec = [
+        tuple(sds(shape, dt) for shape, dt in
+              cfg.recurrent_state_arrays(li, lanes, "bfloat16"))
+        for li in cfg.recurrent_layers
+    ]
+    i32 = partial(sds, dtype=jnp.int32)
+    T = 256
+    meta = (
+        i32((T,)), i32((T,)), i32((T,)), i32((T,)), i32((rows, 512)),
+        i32((rows,)), i32((rows,)), i32((rows,)), i32((rows,)),
+    )
+
+    def step(params, kv, rec, slot, *meta):
+        logits, kv, rec = llama.unified(
+            cfg, params, kv, *meta, BS, attn=AttnDispatch(use_pallas=True),
+            rec_state=rec, state_slot=slot,
+        )
+        return jnp.argmax(logits, axis=-1), kv, rec
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, kv, rec, i32((rows,)), *meta
+    ).compile()
+    # 5 mixers x (ssd_recurrent, ssd_chunk), the one ragged kernel, 5
+    # expert layers x (up, down)
+    assert _kernel_count(compiled.as_text()) == 10 + 1 + 10
+    mem = compiled.memory_analysis()
+    # weights 9.30 GB, state 2.74 GB, pages 1.07 GB: all arguments, and
+    # the state and the pages alias their outputs
+    # (13.117 GB of arguments, 0.18 GB of temporaries, 3.82 GB aliased)
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.4e9, (
+        mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
+
+
+@pytest.mark.parametrize("T", [256, 16])
+def test_ssd_kernels_compile_at_published_widths(
+    mosaic, one_chip, monkeypatch, T
+):
+    """The state-space recurrence over one dispatch at Nemotron-3-Super's
+    widths (128 heads of 64 x 128 float32 in 8 groups, a state table of 129
+    slots): the one-row lanes' kernel (a group's 512 KiB block a grid step)
+    and the chunk kernel (a tile's strided reads of the flat rows, its
+    products at float32 contract precision, a group's state and two tiles'
+    rows within VMEM), at the top rung and at the lowest."""
+    from dynamo_tpu.ops import ssd
+    from dynamo_tpu.ops.pallas import ssd as kernels
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    sds = partial(_sds, sharding=one_chip)
+    i32, f32 = partial(sds, dtype=jnp.int32), jnp.float32
+    lanes, rows = 129, 132
+    compiled = jax.jit(
+        partial(ssd.ssd_ragged, use_pallas=True), donate_argnums=(5,),
+    ).lower(
+        sds((T, 128, 64), f32), sds((T, 128), f32), sds((T, 128), f32),
+        sds((T, 8, 128), f32), sds((T, 8, 128), f32),
+        sds((lanes, 128, 64, 128), f32), i32((T,)), i32((T,)),
+        *[i32((rows,))] * 4,
+    ).compile()
+    assert _kernel_count(compiled.as_text()) == 2
+    mem = compiled.memory_analysis()
+    # the state is updated in place by both kernels: no second table
+    assert mem.alias_size_in_bytes >= lanes * 128 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < 0.2e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
 def test_command_a_share_step_compiles_at_published_widths(
     mosaic, one_chip, monkeypatch
 ):

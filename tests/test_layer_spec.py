@@ -17,7 +17,12 @@ from dynamo_tpu.engine.compile_cache import budget_ladder, jax_phase_seconds
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.runner import ModelRunner, _unified_warm_lanes
 from dynamo_tpu.models import llama
-from dynamo_tpu.models.config import PRESETS, LayerSpec, ModelConfig
+from dynamo_tpu.models.config import (
+    PRESETS,
+    RECURRENT_KINDS,
+    LayerSpec,
+    ModelConfig,
+)
 from dynamo_tpu.parallel.mesh import build_mesh
 
 #: Distinct layer bodies of each family's tiny preset. tiny-mla-test: a dense
@@ -25,16 +30,19 @@ from dynamo_tpu.parallel.mesh import build_mesh
 #: theta and full layers on the global one; tiny-ling-test: KDA + dense MLP,
 #: KDA + experts, KDA + experts under a routed clamp, under a shared clamp,
 #: latent attention + experts; tiny-command-a-test: window layers with
-#: rotary pairs, full layers without.
+#: rotary pairs, full layers without; tiny-nemotron-h-test: a state-space
+#: mixer alone, attention alone, experts alone.
 TINY_BODIES = {
     "tiny-test": 1, "tiny-moe-test": 1, "tiny-mla-test": 2,
     "tiny-gemma-test": 2, "tiny-sdar-test": 1, "tiny-ling-test": 5,
     "tiny-command-a-test": 2, "tiny-brumby-test": 1,
+    "tiny-nemotron-h-test": 3,
 }
 #: And of the configurations the benchmark's cells serve (ISSUE 50's table).
 CELL_BODIES = {
     "mistral-7b": 1, "mixtral-8x7b": 1, "sdar-30b-a3b": 1, "brumby-14b": 1,
     "command-a-plus-ep8-l4": 2, "ling-3.0-flash-ep4-l8": 3,
+    "nemotron-3-super-ep4-l11": 3,
 }
 
 
@@ -126,7 +134,11 @@ SPEC_ANSWERS = {
     "layer_cache_group": lambda m, li, s: (
         m.layer_cache_group(li) == s.cache_group),
     "layer_rope": lambda m, li, s: m.layer_rope(li) == s.rope,
-    "moe_layer": lambda m, li, s: m.moe_layer(li) == s.moe,
+    "moe_layer": lambda m, li, s: m.moe_layer(li) == (s.ffn == "moe"),
+    # The feed-forward part, "none" for a layer that is a mixer alone (and
+    # kind "none" for one that is a feed-forward part alone).
+    "layer_ffn": lambda m, li, s: (
+        m.layer_ffn(li) == s.ffn and (s.kind, s.ffn) != ("none", "none")),
     "swiglu_limit": lambda m, li, s: (
         m.swiglu_limit(li) == s.swiglu_limit
         and m.swiglu_limit(li, shared=True) == s.shared_swiglu_limit),
@@ -134,7 +146,7 @@ SPEC_ANSWERS = {
     # operand's shapes, which jit's cache sees beside the spec.
     "recurrent_state_arrays": lambda m, li, s: (
         bool(m.recurrent_state_arrays(li, 3, "float32"))
-        == (s.kind != "attn")),
+        == (s.kind in RECURRENT_KINDS)),
     "layer_spec": lambda m, li, s: m.layer_spec(li) == s,
 }
 
@@ -157,7 +169,7 @@ def test_every_index_taking_method_is_in_the_spec(method):
 def test_the_table_names_no_method_that_is_gone():
     assert sorted(SPEC_ANSWERS) == index_methods()
     assert {f.name for f in LayerSpec.__dataclass_fields__.values()} == {
-        "kind", "cache_arrays", "window", "cache_group", "rope", "moe",
+        "kind", "cache_arrays", "window", "cache_group", "rope", "ffn",
         "swiglu_limit", "shared_swiglu_limit"}
 
 
@@ -173,7 +185,7 @@ def test_the_spec_agrees_with_every_index_taking_method(preset):
 BODY_AND_HELPERS = (
     "_layer", "_layer_rows", "_rope_qk", "_residual_attn", "_residual_mlp", "_mlp",
     "_moe_mlp", "_qkv", "_qkv_mla", "_mla_out", "_kda_mixer",
-    "_retention_inputs", "_retention_mixer",
+    "_retention_inputs", "_retention_mixer", "_ssd_mixer", "_relu2",
 )
 
 
