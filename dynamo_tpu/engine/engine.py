@@ -3028,6 +3028,15 @@ class TpuEngine:
             "kv_reused_host_blocks_total": self._reused_host_blocks,
             "kv_reused_disk_blocks_total": self._reused_disk_blocks,
             "kv_reused_peer_blocks_total": self._reused_peer_blocks,
+            # Blocks offered for prefix reuse (calls into
+            # BlockAllocator.register: one a block that filled, not one a
+            # token) and those that stored a new hash.
+            "kv_blocks_offered_total": (
+                self.allocator.offered_total if self.allocator else 0
+            ),
+            "kv_blocks_stored_total": (
+                self.allocator.stored_total if self.allocator else 0
+            ),
             # Surface parity (dynarace DT011): these were on the metrics
             # callback but missing from HTTP /metrics, which reads this
             # snapshot.

@@ -26,7 +26,7 @@ from __future__ import annotations
 import enum
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Callable
+from typing import Callable, Sequence
 
 
 class BlockState(enum.Enum):
@@ -91,6 +91,10 @@ class BlockAllocator:
         self._reusable: list[OrderedDict[int, None]] = [
             OrderedDict() for _ in range(self.num_shards)
         ]
+        # Calls into `register`, and those that stored a new hash
+        # (`kv_blocks_offered_total` / `kv_blocks_stored_total`).
+        self.offered_total = 0
+        self.stored_total = 0
 
     def shard_of(self, block: int) -> int:
         return block // self._bps
@@ -212,21 +216,24 @@ class BlockAllocator:
         block: int,
         sequence_hash: int,
         parent_hash: int | None = None,
-        token_ids: list[int] | None = None,
+        token_ids: Sequence[int] | None = None,
     ) -> None:
-        """Publish a full block under its chained sequence hash."""
+        """Publish a full block under its chained sequence hash.
+        ``token_ids`` is copied only where the block is stored."""
         self._expect(
             block, BlockState.ACTIVE, BlockState.REGISTERED, op="register"
         )
         if not self.enable_prefix_caching:
             return
-        existing = self._hash_to_block.get(sequence_hash)
-        if existing is not None:
-            # Either duplicate content (keep the first registration) or an
-            # idempotent re-register of this very block — in both cases the
-            # 'stored' event already went out; re-emitting would spam the
-            # routing plane every decode step.
+        self.offered_total += 1
+        if sequence_hash in self._hash_to_block:
+            # Either duplicate content (keep the first registration) or a
+            # re-register of this very block (the host-tier onboard and
+            # the retire may both offer it) — in both cases the 'stored'
+            # event already went out; re-emitting would spam the routing
+            # plane.
             return
+        self.stored_total += 1
         self._hash_to_block[sequence_hash] = block
         self._block_to_hash[block] = sequence_hash
         if self.on_event:
@@ -235,7 +242,7 @@ class BlockAllocator:
                     kind="stored",
                     block_hashes=[sequence_hash],
                     parent_hash=parent_hash,
-                    token_ids=[token_ids] if token_ids else None,
+                    token_ids=[list(token_ids)] if token_ids else None,
                 )
             )
 

@@ -279,7 +279,7 @@ class Scheduler:
             # No pool: a free slot is all a sequence needs.
             seq.tables, seq.evicted = [], []
             seq.num_cached_prefix = 0
-            seq.hashes = None
+            seq.hashes, seq.offered_blocks = None, 0
             self._seat(seq)
             return True
 
@@ -324,6 +324,7 @@ class Scheduler:
         seq.evicted = [0] * len(seq.tables)
         seq.num_cached_prefix = cached_tokens
         seq.hashes.extend(seq.prompt_tokens)
+        seq.offered_blocks = len(matched)  # registered already
         self._seat(seq)
         return True
 
@@ -335,19 +336,23 @@ class Scheduler:
         self.running[seq.slot] = seq
 
     def register_filled_blocks(self, seq: Sequence, covered_tokens: int) -> None:
-        """Register every block whose KV is now fully written (the first
-        `covered_tokens` positions)."""
+        """Offer for prefix reuse every block whose KV is now fully
+        written (the first `covered_tokens` positions) and that was not
+        offered before: a block is offered ONCE, when it fills, from the
+        mark `seq.offered_blocks`. The common step fills no block and
+        returns here at a compare."""
+        full = covered_tokens // self._bs
         if (
-            not self.cfg.enable_prefix_caching
+            full <= seq.offered_blocks
+            or not self.cfg.enable_prefix_caching
             or seq.hashes is None
             or seq.mm_segments
         ):
             return
-        bs = self.cfg.block_size
-        full = covered_tokens // bs
         hashes = seq.hashes.blocks
-        for idx in range(full):
-            block = seq.block_ids[idx]
+        block_ids = seq.block_ids
+        for idx in range(seq.offered_blocks, full):
+            block = block_ids[idx]
             if block == 0:
                 continue  # rolling-buffer evicted page (sentinel)
             h = hashes[idx]
@@ -355,8 +360,9 @@ class Scheduler:
                 block,
                 h.sequence_hash,
                 parent_hash=h.parent_sequence_hash,
-                token_ids=list(h.tokens),
+                token_ids=h.tokens,
             )
+        seq.offered_blocks = full
 
     def evict_behind_window(self, seq: Sequence, covered: int) -> int:
         """Rolling-buffer eviction, per layer group: in every windowed
@@ -509,7 +515,7 @@ class Scheduler:
         self._release(seq)
         seq.prompt_tokens = seq.prompt_tokens + seq.output_tokens
         seq.output_tokens = []
-        seq.hashes = None
+        seq.hashes, seq.offered_blocks = None, 0
         seq.num_cached_prefix = 0
         seq.sched_len = 0
         # A block-diffusion sequence keeps a block that still has a masked

@@ -52,6 +52,10 @@ class Sequence:
     first_token_s: float | None = None
     # Chained block hashes over prompt+output (prefix-cache registration).
     hashes: TokenBlockSequence | None = None
+    # How far ``hashes.blocks`` has been offered for prefix reuse (a block
+    # index; Scheduler.register_filled_blocks). It is set wherever
+    # ``hashes`` is: admission starts it behind the matched prefix.
+    offered_blocks: int = 0
     # Disaggregation handoff metadata (set for remote prefill).
     kv_transfer: dict[str, Any] | None = None
     # Disagg decode side completeness ledger (WAITING_REMOTE only): the

@@ -250,6 +250,8 @@ class HttpService:
                 'attn_folds_total{tile="long"}',
                 "attn_expanded_spans_total",
                 "attn_expanded_rows_total",
+                "kv_blocks_offered_total",
+                "kv_blocks_stored_total",
                 "last_dispatch_age_s",
                 "num_waiting_interactive",
                 "num_waiting_batch",

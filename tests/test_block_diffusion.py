@@ -355,8 +355,25 @@ async def served(cfg, prompts, n, hook=None, **stop):
     finish reason, what its last commit left (the end of the committed
     positions, the hash chain over them, the keys and values the cache
     holds there), the flight records of the block dispatches and the
-    counters. ``hook(engine)`` runs behind the start."""
+    counters. ``hook(engine)`` runs behind the start.
+
+    Passes are counted exactly by the tests that call this, so no clock
+    may decide one: the requests are taken up in ONE pass (the engine's
+    thread drains nothing until all are queued), the early retire of a
+    dispatch that the CPU already has ready (``_step_unified``) is held
+    back, as on a device slower than the host's round, and the counts are
+    read behind the engine thread's end (a pass is booked at its issue,
+    its flight record at its retire)."""
     engine = TpuEngine(cfg)
+    engine._chunk_ready = lambda record: False
+    drain, queued = engine._drain_submissions, []
+
+    def drain_once_all_are_queued():
+        if queued or engine._submit_q.qsize() >= len(prompts):
+            queued.append(True)
+            drain()
+
+    engine._drain_submissions = drain_once_all_are_queued
     await engine.start()
     if hook is not None:
         hook(engine)
@@ -393,6 +410,8 @@ async def served(cfg, prompts, n, hook=None, **stop):
             if not engine._inflight and not engine.scheduler.running:
                 break
             await asyncio.sleep(0.01)
+        # the last retire may still be under way: its pass ends first
+        await engine.stop()
         bs = cfg.block_size
         caches = [np.asarray(a) for layer in engine.runner.kv_caches
                   for a in layer]
