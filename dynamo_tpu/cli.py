@@ -917,10 +917,20 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
     endpoint = (
         drt.namespace(eid.namespace).component(eid.component).endpoint(eid.name)
     )
+    # What this start is made of, by phase (engine/flight_recorder.py
+    # START_PHASES; the engine takes it over and books its own two).
+    start = None
     if args.output == "tpu":
+        from dynamo_tpu.engine.flight_recorder import (
+            START_PHASES,
+            StepPhases,
+        )
+
+        start = StepPhases(START_PHASES, "start")
         # jax's first import/backend-init costs seconds and must not starve
         # the event loop past the lease TTL (see _build_embed note).
-        await asyncio.to_thread(__import__, "jax")
+        with start.phase("runtime"):
+            await asyncio.to_thread(__import__, "jax")
 
     if args.output in ("echo_core", "echo_full"):
         from dynamo_tpu.llm.engines import EchoEngineCore, EchoEngineFull
@@ -975,6 +985,12 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             initialize(MultiHostConfig(
                 args.coordinator, args.num_nodes, args.node_rank
             ))
+        import jax
+
+        # The backend's start (the TPU runtime's: seconds), here under its
+        # own name and not inside whatever touches a device first.
+        with start.phase("runtime"):
+            await asyncio.to_thread(jax.devices)
         local, ecfg = _tpu_local_and_cfg(args)
         # KV events + per-pass metrics feed the KV-aware router and the
         # planner over the control plane (in-process — no ZMQ bridge).
@@ -982,7 +998,8 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
         kv_pub = KvEventPublisher(drt, comp, drt.primary_lease_id)
         metrics_pub = WorkerMetricsPublisher()
         await metrics_pub.create_endpoint(comp)
-        params = await asyncio.to_thread(local.load_params, args.dtype)
+        with start.phase("weights"):
+            params = await asyncio.to_thread(local.load_params, args.dtype)
         engine = TpuEngine(
             ecfg,
             params=params,
@@ -994,6 +1011,7 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
             # Freshly loaded — hand ownership over so a quantized load
             # frees the bf16 buffers as the int8 copies materialize.
             donate_params=True,
+            start_phases=start,
         )
         await engine.start()
         if args.num_nodes > 1:
@@ -1022,12 +1040,17 @@ async def _start_engine(args, drt, stack, endpoint_path: str):
                 for phase, secs in cs.warm_phase_s.items()
             )
             traces, calls = cs.layer_body()
+            before = ", ".join(
+                f"{phase} {secs:.1f}s"
+                for phase, secs in start.seconds().items()
+                if phase != "warmup"
+            )
             print(
                 f"warmup: {n} programs in {time.monotonic() - t0:.1f}s "
                 f"({phases}; layer body traced {traces}x for {calls} "
                 f"calls; {cs.warm_cache_events['hits']} compile requests "
                 f"read from the cache, {cs.warm_cache_events['misses']} "
-                "compiled) — engine ready",
+                f"compiled; before it: {before}) — engine ready",
                 flush=True,
             )
         card = local.card

@@ -27,7 +27,11 @@ import numpy as np
 
 from dynamo_tpu.engine.coloc import ColocController
 from dynamo_tpu.engine.config import EngineConfig
-from dynamo_tpu.engine.flight_recorder import FlightRecorder
+from dynamo_tpu.engine.flight_recorder import (
+    START_PHASES,
+    FlightRecorder,
+    StepPhases,
+)
 from dynamo_tpu.engine.kv_cache import BlockAllocator, KvEvent
 from dynamo_tpu.engine.runner import ModelRunner
 from dynamo_tpu.engine.scheduler import Scheduler
@@ -50,6 +54,21 @@ from dynamo_tpu.utils.retry import RETRIES
 from dynamo_tpu.utils.tracing import tracer
 
 logger = logging.getLogger(__name__)
+
+
+def _in_phase(name: str):
+    """The method's whole call is the phase ``name`` of the engine
+    thread's pass (``self.phases``: flight_recorder.py ``PHASES``)."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def in_phase(self, *args, **kwargs):
+            with self.phases.phase(name):
+                return method(self, *args, **kwargs)
+
+        return in_phase
+
+    return wrap
 
 
 def _drain_handoff(
@@ -75,9 +94,20 @@ class TpuEngine:
         block_manager=None,
         donate_params: bool = False,
         on_kv_actual: Callable[[dict], None] | None = None,
+        start_phases: StepPhases | None = None,
     ) -> None:
         cfg.validate()
         self.cfg = cfg
+        #: Where the engine thread's time goes, by phase of its pass
+        #: (flight_recorder.py ``PHASES``): a step's flight record carries
+        #: what ran up since the record before, and the same names are the
+        #: profiler's host events ``engine/<phase>``.
+        self.phases = StepPhases()
+        #: And what this start was made of (``START_PHASES``; on
+        #: ``readiness()`` as ``start_<phase>_seconds``): the caller's,
+        #: where it has booked what it did before there was an engine (the
+        #: CLI: jax's import and the backend's start, a checkpoint's read).
+        self.start_phases = start_phases or StepPhases(START_PHASES, "start")
         self._params = params
         self._mesh = mesh
         self._donate_params = donate_params
@@ -338,10 +368,12 @@ class TpuEngine:
         self._thread.start()
 
     def _build_runner(self) -> None:
-        self.runner = ModelRunner(
-            self.cfg, params=self._params, mesh=self._mesh,
-            rng_seed=self.cfg.seed, donate_params=self._donate_params,
-        )
+        with self.start_phases.phase("build"):
+            self.runner = ModelRunner(
+                self.cfg, params=self._params, mesh=self._mesh,
+                rng_seed=self.cfg.seed, donate_params=self._donate_params,
+                phases=self.phases, start_phases=self.start_phases,
+            )
         if self.allocator and self.runner.kv_shards != self.allocator.num_shards:
             # Placement/scan contract violated (e.g. a mesh resolved to a
             # different sp than the allocator striped for) — serving would
@@ -525,8 +557,9 @@ class TpuEngine:
             # lasts; where the device sets the pace the loop has long
             # finished and nothing waits here.
             t0 = time.monotonic()
-            while not taken.wait(0.1) and not self._stop.is_set():
-                pass
+            with self.phases.phase("handoff_wait"):
+                while not taken.wait(0.1) and not self._stop.is_set():
+                    pass
             self._handoff_wait_s += time.monotonic() - t0
         taken.clear()
         self._outbox = []
@@ -616,7 +649,8 @@ class TpuEngine:
         try:
             while not self._stop.is_set():
                 if not self._pass():
-                    self._wakeup.wait(timeout=0.01)
+                    with self.phases.phase("idle"):
+                        self._wakeup.wait(timeout=0.01)
                     self._wakeup.clear()
         # dynalint: allow[DT003] top-of-thread catch: records _dead, fails every queued seq loudly
         except Exception as exc:
@@ -662,17 +696,20 @@ class TpuEngine:
 
     def _pass(self) -> bool:
         """One pass of the engine's loop; whether it did work."""
-        did_work = self._step()
-        # Heartbeat: every completed loop pass (dispatch or idle
-        # poll) proves the thread is alive and not wedged inside
-        # a collective/compile — the stamp readiness() ages.
-        self._last_dispatch_mono = time.monotonic()
-        self._flush_side_channels()
-        # What the pass emitted outside a retire (an expiry, a shed, an
-        # abort, a refused prompt) leaves now.
-        self._flush_outbox()
+        with self.phases.span("pass"):
+            did_work = self._step()
+            # Heartbeat: every completed loop pass (dispatch or idle
+            # poll) proves the thread is alive and not wedged inside
+            # a collective/compile — the stamp readiness() ages.
+            self._last_dispatch_mono = time.monotonic()
+            with self.phases.phase("side_channels"):
+                self._flush_side_channels()
+            # What the pass emitted outside a retire (an expiry, a shed, an
+            # abort, a refused prompt) leaves now.
+            self._flush_outbox()
         return did_work
 
+    @_in_phase("drain")
     def _drain_submissions(self) -> None:
         while True:
             try:
@@ -711,7 +748,10 @@ class TpuEngine:
             )
 
         try:
-            n = self.runner.run_warm_ops(self.runner.warm_ops())
+            with self.start_phases.phase("warmup"):
+                n = self.runner.run_warm_ops(self.runner.warm_ops())
+            # The warm-up's seconds are the start's, not a step's.
+            self.phases.take()
             self._state = "ready"
             resolve(fut.set_result, n)
         except Exception as exc:  # dynalint: allow[DT003] propagated: the warmup future re-raises on the caller
@@ -751,7 +791,8 @@ class TpuEngine:
         sched = self.scheduler
         did = False
         if sched.waiting:
-            sched.expire_waiting()
+            with self.phases.phase("admit"):
+                sched.expire_waiting()
 
         # 1. Retire in-flight unified dispatches (device-ready ones, plus
         #    the oldest when the pipeline is at depth). Speculative mode
@@ -839,6 +880,7 @@ class TpuEngine:
                 ]
         return []
 
+    @_in_phase("compose")
     def _issue_unified(self) -> bool:
         """Compose one token-budget batch (scheduler.compose_unified:
         decode lanes first — draft-verify spans when speculation is
@@ -1179,6 +1221,7 @@ class TpuEngine:
                 logger.info("speculative decode re-probing")
         return True
 
+    @_in_phase("retire")
     def _process_unified_chunk(self, record) -> None:
         """Force one unified dispatch's tokens and run the host-side
         bookkeeping: decode lanes deliver their token, draft-verify
@@ -1187,7 +1230,11 @@ class TpuEngine:
         blocks its KV writes filled."""
         _, roles, stats, payload = record
         out, lp = payload
-        toks = np.asarray(out.last)  # dynalint: allow[DT005] the pipeline's designed retire point — one forced transfer per dispatch, depth keeps it off the dispatch path
+        # The forced reads below are the wait for the device, a phase of
+        # their own inside the retire.
+        phase = self.phases.phase
+        with phase("retire_wait"):
+            toks = np.asarray(out.last)  # dynalint: allow[DT005] the pipeline's designed retire point — one forced transfer per dispatch, depth keeps it off the dispatch path
         (
             n_dec, n_pre, t_issue, t_dispatch, drafted,
             spec_counted, compose_ms, folds,
@@ -1196,13 +1243,15 @@ class TpuEngine:
         blk_ids = None
         experts_hit = 0
         if B_blk:
-            if n_dec:
-                blk_ids = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
-            experts_hit = int(np.asarray(out.experts_hit))  # dynalint: allow[DT005] same retirement boundary as `toks`
+            with phase("retire_wait"):
+                if n_dec:
+                    blk_ids = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
+                experts_hit = int(np.asarray(out.experts_hit))  # dynalint: allow[DT005] same retirement boundary as `toks`
         elif out.moe_counts is not None:
             # The plain program of a model with grouped expert layers: its
             # flight record is noted here, with their counts.
-            hit, rows_held = np.asarray(out.moe_counts).tolist()  # dynalint: allow[DT005] same retirement boundary as `toks`
+            with phase("retire_wait"):
+                hit, rows_held = np.asarray(out.moe_counts).tolist()  # dynalint: allow[DT005] same retirement boundary as `toks`
             self._note_step(
                 "unified",
                 **self._plain_note(roles, n_dec, n_pre, compose_ms, folds),
@@ -1214,14 +1263,16 @@ class TpuEngine:
             # Spec contract: the emitted rows + device-side accepted
             # lengths force at the same retirement boundary as the
             # tokens (no extra host RTT on the dispatch path).
-            spec_toks = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
-            spec_counts = np.asarray(out.counts)  # dynalint: allow[DT005] same retirement boundary as `toks`
+            with phase("retire_wait"):
+                spec_toks = np.asarray(out.toks)  # dynalint: allow[DT005] same retirement boundary as `toks`
+                spec_counts = np.asarray(out.counts)  # dynalint: allow[DT005] same retirement boundary as `toks`
         lp_np = None
         if lp is not None and any(
             s.logprobs is not None for s, *_r in roles
         ):
-            # dynalint: allow[DT005, DT005, DT005] logprob arrays force at the same chunk-retirement boundary as the tokens — one batched transfer
-            lp_np = tuple(np.asarray(a) for a in lp)
+            with phase("retire_wait"):
+                # dynalint: allow[DT005, DT005, DT005] logprob arrays force at the same chunk-retirement boundary as the tokens — one batched transfer
+                lp_np = tuple(np.asarray(a) for a in lp)
         now = self._clock()
         if n_dec:
             # ITL sample for the coloc controller: when this dispatch
@@ -1626,6 +1677,7 @@ class TpuEngine:
             seed,
         )
 
+    @_in_phase("admit")
     def _admit_prefills(self) -> None:
         """Admit waiting prompts into the PREFILLING set (admission
         hold, kvbm host-prefix onboarding, prefix-hit accounting, cursor
@@ -2147,7 +2199,9 @@ class TpuEngine:
         are snapshots, so a reader diffs adjacent records to attribute a
         stall or shed to the exact step that paid it. ``kind="spec"``
         records carry the drafted/accepted token split of a unified
-        draft-verify dispatch."""
+        draft-verify dispatch. ``host_*_ms`` on it are this thread's
+        seconds by phase since the record before (``StepPhases.take``),
+        wherever in the pass this one is noted."""
         cs = getattr(self.runner, "compile_stats", None)
         sched = self.scheduler
         self.flight.note_step(
@@ -2182,6 +2236,7 @@ class TpuEngine:
             quantum=self.coloc.quantum if kind == "unified" else 0,
             itl_ema_ms=self.coloc.itl_ema_ms if kind == "unified" else 0.0,
             headroom_ms=self.coloc.headroom_ms if kind == "unified" else 0.0,
+            host=self.phases.take(),
         )
         self._handoff_noted = self._handoff_items
 
@@ -3104,6 +3159,10 @@ class TpuEngine:
         cs = getattr(self.runner, "compile_stats", None)
         if cs is not None:
             d.update(cs.snapshot())
+        # What this start was made of; the warm-up's own split is the
+        # three warmup_*_seconds_total just above.
+        for name, secs in self.start_phases.seconds().items():
+            d[f"start_{name}_seconds"] = round(secs, 3)
         return d
 
     def _diffusion_counters(self) -> dict:

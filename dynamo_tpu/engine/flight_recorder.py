@@ -22,16 +22,25 @@ Two ways out of the ring:
 Thread model: the engine thread writes, HTTP handlers read — every
 access takes the (uncontended) lock, and records are plain dicts copied
 out at snapshot time.
+
+``StepPhases`` beside it names what the host does between two programs
+(``PHASES``: the engine thread's pass) and what a start is made of
+(``START_PHASES``): each phase's seconds on the host's clock, carried by
+the step records as ``host_<phase>_ms``, and the same names on the
+profiler's clock, as host events ``engine/<phase>`` / ``start/<phase>``
+beside the device's trace (docs/architecture/observability.md).
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
+import sys
 import time
 from collections import deque
-from typing import Any
+from typing import Any, Mapping
 
 from dynamo_tpu.utils.atomic_io import atomic_write_text
 from dynamo_tpu.utils.concurrency import make_lock
@@ -39,6 +48,122 @@ from dynamo_tpu.utils.concurrency import make_lock
 logger = logging.getLogger(__name__)
 
 DEFAULT_CAPACITY = 512
+
+#: The engine thread's pass, by phase (``TpuEngine``, ``ModelRunner``): what
+#: each is around is in docs/architecture/observability.md. ``idle``,
+#: ``retire_wait`` and ``handoff_wait`` are waits (for work, for the device,
+#: for the frontend's loop); the rest is the host's own work.
+PHASES = (
+    "idle", "drain", "retire_wait", "retire", "handoff_wait", "admit",
+    "compose", "pack", "put", "dispatch", "side_channels",
+)
+#: A step record's fields for them, and for the time outside every phase.
+_HOST_FIELDS = tuple(
+    (name, f"host_{name}_ms") for name in (*PHASES, "other")
+)
+#: What a start does before it serves (``cli._start_engine``,
+#: ``TpuEngine``, ``ModelRunner.__init__``).
+START_PHASES = ("runtime", "weights", "build", "warmup")
+
+_trace_annotation = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _annotation(label: str):
+    """``jax.profiler.TraceAnnotation(label)``: a host event on the
+    profiler's clock while a profiler session runs, some 0.4 us without
+    one. None in a process that has not imported jax (the first phase of a
+    start, a JAX-free frontend) or cannot: nothing is imported for its
+    sake, and the clock runs all the same."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if sys.modules.get("jax") is None:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(label)
+
+
+class _Phase:
+    __slots__ = ("_phases", "_name", "_note")
+
+    def __init__(self, phases: "StepPhases", name: str) -> None:
+        self._phases = phases
+        self._name = name
+
+    def __enter__(self) -> None:
+        p = self._phases
+        note = self._note = _annotation(p._labels[self._name])
+        if note is not None:
+            note.__enter__()
+        p._book()
+        p._open.append(self._name)
+
+    def __exit__(self, *exc) -> None:
+        p = self._phases
+        p._book()
+        p._open.pop()
+        if self._note is not None:
+            self._note.__exit__(*exc)
+
+
+class StepPhases:
+    """Where one thread's wall time goes, by named phase. ``with
+    phases.phase(name):`` books the seconds inside it to ``name``, LESS
+    what phases opened inside it took (self time: every instant belongs to
+    the innermost open phase, and to ``other`` where none is open), so the
+    phases and ``other`` sum to the wall time and nothing counts twice.
+    Always on: two clock reads and one profiler annotation a phase.
+
+    The annotation's name is the constant ``<prefix>/<name>``: the
+    benchmark sums the device's idle gaps by the host event that covers
+    them (chipbench/xprof.py), so no step number or request id goes into
+    it. One thread at a time uses an instance (the engine's thread for a
+    pass; a start's steps follow each other), and anyone may read
+    ``seconds()``."""
+
+    def __init__(
+        self, names: tuple[str, ...] = PHASES, prefix: str = "engine"
+    ) -> None:
+        self.names = names
+        self.prefix = prefix
+        self._labels = {name: f"{prefix}/{name}" for name in names}
+        self._acc = dict.fromkeys((*names, "other"), 0.0)
+        self._open: list[str] = []  # innermost last
+        self._mark = time.perf_counter()
+
+    def _book(self) -> None:
+        now = time.perf_counter()
+        self._acc[self._open[-1] if self._open else "other"] += (
+            now - self._mark
+        )
+        self._mark = now
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def span(self, name: str):
+        """The annotation alone, around a stretch whose time stays with
+        whatever phase it runs in: ``engine/pass`` around a pass, so that a
+        gap inside a pass and outside every phase still reads as the
+        engine's."""
+        return _annotation(f"{self.prefix}/{name}") or _NO_SPAN
+
+    def take(self) -> dict[str, float]:
+        """Seconds by phase, and ``other``, since the take before (or the
+        construction): they sum to the wall time between the two. A phase
+        that is open is booked up to now and runs on."""
+        self._book()
+        taken = self._acc
+        self._acc = dict.fromkeys(taken, 0.0)
+        return taken
+
+    def seconds(self) -> dict[str, float]:
+        """Seconds by phase so far; nothing is reset."""
+        return {name: self._acc[name] for name in self.names}
 
 
 class FlightRecorder:
@@ -102,6 +227,7 @@ class FlightRecorder:
         attn_long_folds: int = 0,
         attn_expanded_spans: int = 0,
         attn_expanded_rows: int = 0,
+        host: Mapping[str, float] | None = None,
     ) -> None:
         """One dispatch's record. Counter fields are the process totals
         AT the step, so a reader diffs adjacent records to see exactly
@@ -161,7 +287,17 @@ class FlightRecorder:
         through the expanded body instead (ops/pallas/latent_expanded.py
         ``expanded_spans``, the program's own rule on the host): they are
         no folds of the ragged kernel's, and rows / (decode + prefill
-        tokens) is how much of the dispatch the expanded form answered."""
+        tokens) is how much of the dispatch the expanded form answered.
+        ``host`` is what ``StepPhases.take()`` hands out: the engine
+        thread's seconds by phase since the record BEFORE this one,
+        written as ``host_<phase>_ms`` for every phase of ``PHASES``,
+        ``host_other_ms`` (the pass's own glue, outside every phase) and
+        ``host_period_ms``, the thread's wall time since that record, which
+        the twelve sum to. A record noted at its dispatch's issue (plain)
+        and one noted at its retire (expert layers, speculation, block
+        diffusion) both read "since the record before": a period is one
+        turn of the engine's loop either way, cut at another point of it,
+        and it is not the time of the dispatch the record describes."""
         rec = {
             "t_unix": round(time.time(), 6),
             "kind": kind,
@@ -212,6 +348,10 @@ class FlightRecorder:
             "itl_ema_ms": round(itl_ema_ms, 3),
             "headroom_ms": round(headroom_ms, 3),
         }
+        host = host or {}
+        for name, field in _HOST_FIELDS:
+            rec[field] = round(1e3 * host.get(name, 0.0), 4)
+        rec["host_period_ms"] = round(1e3 * sum(host.values()), 4)
         with self._lock:
             self._seq += 1
             self._steps += 1

@@ -54,6 +54,7 @@ from dynamo_tpu.engine.compile_cache import (
     token_budget,
 )
 from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.flight_recorder import START_PHASES, StepPhases
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.moe import GROUPED_MIN_EXPERTS, collect_experts_hit
 from dynamo_tpu.ops.sampling import (
@@ -283,12 +284,25 @@ class ModelRunner(WarmupPlanMixin):
         mesh=None,
         rng_seed: int = 0,
         donate_params: bool = False,
+        *,
+        phases: StepPhases | None = None,
+        start_phases: StepPhases | None = None,
     ) -> None:
         """`donate_params=True` lets the quantize step consume the caller's
         bf16 buffers as it writes the int8 copies — halving the transient
         HBM peak during a quantized load. The caller's `params` tree is
         INVALID afterwards; only pass it when handing over ownership (the
-        CLI load path does; tests that reuse a params tree must not)."""
+        CLI load path does; tests that reuse a params tree must not).
+        ``phases`` / ``start_phases`` are the engine's
+        (engine/flight_recorder.py): a dispatch books its ``pack``, ``put``
+        and ``dispatch`` to the first, and this constructor the ``weights``
+        to the second: drawn, sharded or quantized, as long as the HOST is
+        at it (a draw is traced, compiled and enqueued; its device time
+        runs on behind the rest of the build)."""
+        #: the engine thread's pass by phase (the engine's, or one nobody
+        #: reads where a runner is driven alone)
+        self.phases = phases or StepPhases()
+        start = start_phases or StepPhases(START_PHASES, "start")
         self.cfg = cfg
         m = cfg.model
         # Compile lifecycle (engine/compile_cache.py): the persistent
@@ -509,45 +523,46 @@ class ModelRunner(WarmupPlanMixin):
         )
         wq_active = wq_policy is not None and wq_policy.active
         if mesh is None:
-            if params is None and wq_active:
-                # Init layer-wise, straight into the policy's formats — the
-                # full bf16 tree of an 8B model would not even fit resident.
-                from dynamo_tpu.ops.quant import init_params_policy
+            with start.phase("weights"):
+                if params is None and wq_active:
+                    # Init layer-wise, straight into the policy's formats — the
+                    # full bf16 tree of an 8B model would not even fit resident.
+                    from dynamo_tpu.ops.quant import init_params_policy
 
-                params = init_params_policy(
-                    jax.random.PRNGKey(rng_seed), m, wq_policy,
-                    dtype=self.dtype,
-                )
-            elif params is None and quant == "int8":
-                # Init layer-wise, straight into int8 — the full bf16 tree
-                # of an 8B model would not even fit on a 16 GB chip.
-                from dynamo_tpu.ops.quant import init_params_int8
+                    params = init_params_policy(
+                        jax.random.PRNGKey(rng_seed), m, wq_policy,
+                        dtype=self.dtype,
+                    )
+                elif params is None and quant == "int8":
+                    # Init layer-wise, straight into int8 — the full bf16 tree
+                    # of an 8B model would not even fit on a 16 GB chip.
+                    from dynamo_tpu.ops.quant import init_params_int8
 
-                params = init_params_int8(
-                    jax.random.PRNGKey(rng_seed), m, dtype=self.dtype
-                )
-            elif params is None:
-                params = llama.init_params(
-                    jax.random.PRNGKey(rng_seed), m, dtype=self.dtype
-                )
-            elif wq_active:
-                from dynamo_tpu.ops.quant import quantize_params_policy
+                    params = init_params_int8(
+                        jax.random.PRNGKey(rng_seed), m, dtype=self.dtype
+                    )
+                elif params is None:
+                    params = llama.init_params(
+                        jax.random.PRNGKey(rng_seed), m, dtype=self.dtype
+                    )
+                elif wq_active:
+                    from dynamo_tpu.ops.quant import quantize_params_policy
 
-                params = jax.jit(
-                    partial(
-                        quantize_params_policy,
-                        policy=wq_policy,
-                        tie_embed=m.tie_word_embeddings,
-                    ),
-                    donate_argnums=(0,) if donate_params else (),
-                )(params)
-            elif quant == "int8":
-                from dynamo_tpu.ops.quant import quantize_params
+                    params = jax.jit(
+                        partial(
+                            quantize_params_policy,
+                            policy=wq_policy,
+                            tie_embed=m.tie_word_embeddings,
+                        ),
+                        donate_argnums=(0,) if donate_params else (),
+                    )(params)
+                elif quant == "int8":
+                    from dynamo_tpu.ops.quant import quantize_params
 
-                params = jax.jit(
-                    partial(quantize_params, tie_embed=m.tie_word_embeddings),
-                    donate_argnums=(0,) if donate_params else (),
-                )(params)
+                    params = jax.jit(
+                        partial(quantize_params, tie_embed=m.tie_word_embeddings),
+                        donate_argnums=(0,) if donate_params else (),
+                    )(params)
             kv_caches = make_kv()
             kv_scales = make_kv_scales()
         else:
@@ -591,38 +606,39 @@ class ModelRunner(WarmupPlanMixin):
                 specs,
                 is_leaf=lambda x: isinstance(x, P),
             )
-            if params is None:
-                def _init(key):
-                    p = llama.init_params(key, m, dtype=self.dtype)
-                    if wq_active:
-                        p = quantize_params_policy(
-                            p, wq_policy, tie_embed=m.tie_word_embeddings
-                        )
-                    elif quant == "int8":
-                        p = quantize_params(p, tie_embed=m.tie_word_embeddings)
-                    return p
+            with start.phase("weights"):
+                if params is None:
+                    def _init(key):
+                        p = llama.init_params(key, m, dtype=self.dtype)
+                        if wq_active:
+                            p = quantize_params_policy(
+                                p, wq_policy, tie_embed=m.tie_word_embeddings
+                            )
+                        elif quant == "int8":
+                            p = quantize_params(p, tie_embed=m.tie_word_embeddings)
+                        return p
 
-                params = jax.jit(_init, out_shardings=p_sh)(
-                    jax.random.PRNGKey(rng_seed)
-                )
-            elif wq_active:
-                params = jax.jit(
-                    partial(
-                        quantize_params_policy,
-                        policy=wq_policy,
-                        tie_embed=m.tie_word_embeddings,
-                    ),
-                    out_shardings=p_sh,
-                    donate_argnums=(0,) if donate_params else (),
-                )(params)
-            elif quant == "int8":
-                params = jax.jit(
-                    partial(quantize_params, tie_embed=m.tie_word_embeddings),
-                    out_shardings=p_sh,
-                    donate_argnums=(0,) if donate_params else (),
-                )(params)
-            else:
-                params = shard_params(params, mesh, cfg=m)
+                    params = jax.jit(_init, out_shardings=p_sh)(
+                        jax.random.PRNGKey(rng_seed)
+                    )
+                elif wq_active:
+                    params = jax.jit(
+                        partial(
+                            quantize_params_policy,
+                            policy=wq_policy,
+                            tie_embed=m.tie_word_embeddings,
+                        ),
+                        out_shardings=p_sh,
+                        donate_argnums=(0,) if donate_params else (),
+                    )(params)
+                elif quant == "int8":
+                    params = jax.jit(
+                        partial(quantize_params, tie_embed=m.tie_word_embeddings),
+                        out_shardings=p_sh,
+                        donate_argnums=(0,) if donate_params else (),
+                    )(params)
+                else:
+                    params = shard_params(params, mesh, cfg=m)
             kv_caches = jax.jit(
                 make_kv,
                 out_shardings=NamedSharding(
@@ -1408,7 +1424,11 @@ class ModelRunner(WarmupPlanMixin):
             f"{cfg.unified_token_budget}"
         )
         variant = self._extras_variant if use_full else self._ladder_variant
-        base_args, _meta, ops = self._unified_operands(lanes, feed, T, variant)
+        phase = self.phases.phase
+        with phase("pack"):
+            base_args, _meta, ops = self._unified_operands(
+                lanes, feed, T, variant
+            )
         seg = ops.seg
         seg["key"][:] = self._next_key()
         if state_slots is not None:
@@ -1441,7 +1461,8 @@ class ModelRunner(WarmupPlanMixin):
                             continue
                         embeds[r0 + off : r0 + off + w] = mm_seg[:w]
                         mask[r0 + off : r0 + off + w] = True
-                mm_args = (self._put(embeds), self._put(mask))
+                with phase("put"):
+                    mm_args = (self._put(embeds), self._put(mask))
         elif variant == "spec" and draft_lens is not None:
             drafts, dlen = seg["drafts"], seg["draft_len"]
             for s, dl in enumerate(draft_lens):
@@ -1450,12 +1471,13 @@ class ModelRunner(WarmupPlanMixin):
                     drafts[s, :dl] = lanes[s][0][-dl:]
         self.operand_transfers = 1 + ops.feed_transfers + len(mm_args)
         self.operand_transfers_total += self.operand_transfers
-        packed = self._put(ops.buf)
+        with phase("put"):
+            packed = self._put(ops.buf)
 
         if use_full:
             kind = "unified_mm" if use_mm else "unified_full"
             program = self._unified_mm if use_mm else self._unified_full
-            with self.compile_stats.observe(kind, t=T):
+            with phase("dispatch"), self.compile_stats.observe(kind, t=T):
                 (
                     toks, clp, tids, tlps, self._counts, kv, self.kv_scales,
                 ) = program(
@@ -1466,7 +1488,7 @@ class ModelRunner(WarmupPlanMixin):
             self.last_unified_logprobs = (clp, tids, tlps)
             return UnifiedOut(last=toks, toks=None, counts=None)
 
-        with self.compile_stats.observe("unified", t=T):
+        with phase("dispatch"), self.compile_stats.observe("unified", t=T):
             out = self._unified(*base_args, packed, ops.prev_toks)
         *heads, kv, self.kv_scales = out
         self._set_kv(kv)
@@ -1595,7 +1617,8 @@ class ModelRunner(WarmupPlanMixin):
                 prev_toks = self._zero_prev
             else:
                 # A replayed host feed whose values ARE read.
-                prev_toks = self._put(prev_toks)
+                with self.phases.phase("put"):
+                    prev_toks = self._put(prev_toks)
                 feed_transfers = 1
         base_args = (self.params, self.kv_caches, self.kv_scales)
         meta_args = tuple(_meta_of(seg, len(self.group_blocks)))

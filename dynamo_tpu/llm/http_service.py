@@ -202,6 +202,12 @@ class HttpService:
                 "warmup_programs_total",
                 "warmup_cache_hits_total",
                 "warmup_cache_misses_total",
+                # What the start was made of (engine/flight_recorder.py
+                # START_PHASES).
+                "start_runtime_seconds",
+                "start_weights_seconds",
+                "start_build_seconds",
+                "start_warmup_seconds",
                 "gpu_prefix_cache_hit_rate",
                 "spec_tokens_per_step",
                 "spec_active",

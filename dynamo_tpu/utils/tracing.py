@@ -74,6 +74,7 @@ SPAN_NAMES = (
     "decode_first",
     "decode",
     "failover",
+    "block_denoise",
 )
 
 #: Derived point-mark intervals (kept from the pre-span tracer; the
