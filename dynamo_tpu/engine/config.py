@@ -4,7 +4,40 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import CACHE_FORMS, ModelConfig
+
+
+def cache_form_of(
+    *, entries: int, pool: bool, latent: bool, kv_quant, kv_sp: bool
+) -> str:
+    """THE rule for the form a paged layer's pages take (``CACHE_FORMS``;
+    docs/architecture/unified_step.md "Three forms of a layer's pages"),
+    from what a configuration itself shows and nothing else (no flag, no
+    model's name, no dtype): ``EngineConfig.cache_form`` asks it, and
+    ``tools/ragged_kernel_bench.py`` for the shapes it builds.
+
+    - "once": the model holds a latent ONCE (``entries`` 1,
+      ``ModelConfig.cache_arrays``): one array a layer, the values are the
+      key entry's leading columns.
+    - "joined": a (k, v) layer whose K and V are one shape, with neither
+      int8 scales (K and V have a scale each, ``kv_scale``) nor the
+      striped ``kv_sp`` scan (it slices the SLOT axis): ONE array a layer,
+      a block's keys and then its values one contiguous page, so the
+      ragged kernel moves a page with one descriptor and the layer writes
+      both with one scatter.
+    - "apart": every other cache (int8, ``kv_sp``; a model with no pool
+      keeps the empty pair; a latent-attention layer that still stores its
+      latent twice, ``ModelConfig.layer_cache_arrays``: its V is its K's
+      leading columns, and what it goes to is "once"): K and V an array
+      each."""
+    if entries == 1:
+        form = "once"
+    elif pool and not latent and not kv_quant and not kv_sp:
+        form = "joined"
+    else:
+        form = "apart"
+    assert form in CACHE_FORMS
+    return form
 
 
 @dataclass
@@ -229,6 +262,19 @@ class EngineConfig:
             )
 
         return tuple(windowed(w) if w else self.num_blocks for w in groups)
+
+    @property
+    def cache_form(self) -> str:
+        """The form this configuration's paged layers take (``cache_form_of``
+        decides, here and nowhere else in the engine). The runner's
+        allocation and its sharding and ``readiness()`` read it here; the
+        layer body, the attention call and block IO read the form off the
+        arrays they are handed (``ops/attention.py`` ``page_form``)."""
+        m = self.model
+        return cache_form_of(
+            entries=m.cache_arrays, pool=m.has_pool, latent=m.is_mla,
+            kv_quant=self.kv_quant, kv_sp=self.kv_sp,
+        )
 
     def validate(self) -> None:
         if (

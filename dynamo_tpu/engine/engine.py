@@ -3073,9 +3073,22 @@ class TpuEngine:
                 getattr(self.runner, "attn_expanded_total", (0, 0)),
             )),
             # The paged cache as allocated: arrays a layer (1 where a
-            # latent is held once) and what a live token costs over all
-            # layers.
-            "kv_cache_arrays_per_layer": self.cfg.model.cache_arrays,
+            # latent is held once, and where a (k, v) layer's pages are
+            # joined: `EngineConfig.cache_form`), the bytes of ONE page
+            # descriptor of the ragged kernel's ring on a chip and the
+            # descriptors a fold starts (0 where the kernel does not serve:
+            # a step's `attn_*_folds` times the second is the descriptors it
+            # started), and what a live token costs over all layers.
+            "kv_cache_arrays_per_layer": getattr(
+                self.runner, "kv_arrays_per_layer",
+                self.cfg.model.cache_arrays,
+            ),
+            "kv_page_dma_bytes": getattr(
+                self.runner, "kv_page_dma_bytes", 0
+            ),
+            "kv_page_dmas_per_fold": getattr(
+                self.runner, "page_dmas_per_fold", 0
+            ),
             "kv_bytes_per_token": getattr(
                 self.runner, "kv_bytes_per_token", 0
             ),

@@ -428,6 +428,9 @@ class ModelRunner(WarmupPlanMixin):
         #: is the last dispatch's (spans, rows) that did, by the rule the
         #: program applies, `attn_expanded_total` every dispatch's.
         self._fold_plan = None
+        #: One page descriptor's bytes on a chip, and the descriptors a fold
+        #: of the kernel's ring starts (0 where it does not serve).
+        self.kv_page_dma_bytes = self.page_dmas_per_fold = 0
         if use_pallas and not cfg.kv_sp and n_groups:
             from dynamo_tpu.ops.pallas.ragged_attention import (
                 fold_counts,
@@ -438,6 +441,13 @@ class ModelRunner(WarmupPlanMixin):
             page = (
                 cfg.block_size * local_heads * self.cache_head_dim
                 * self.kv_dtype.itemsize
+            )
+            # PP pages a fold, of K and of V where they are apart, of both
+            # at once where joined (`EngineConfig.cache_form`).
+            streams = 2 if cfg.cache_form == "apart" else 1
+            self.kv_page_dma_bytes = page * m.cache_arrays // streams
+            self.page_dmas_per_fold = (
+                ring_shape(page, cfg.block_size)[1] * streams
             )
             self._fold_plan = dict(
                 count=partial(
@@ -466,9 +476,14 @@ class ModelRunner(WarmupPlanMixin):
         #: weights are there).
         self._expand_heads = 0
 
+        joined = cfg.cache_form == "joined"
+
         def kv_shape(li: int) -> tuple:
-            slots = self.group_blocks[m.layer_cache_group(li)] * cfg.block_size
-            return (slots, cache_heads, self.cache_head_dim)
+            blocks = self.group_blocks[m.layer_cache_group(li)]
+            entry = (cache_heads, self.cache_head_dim)
+            if joined:  # a block's keys and then its values, one page
+                return (blocks, 2, cfg.block_size, *entry)
+            return (blocks * cfg.block_size, *entry)
 
         def make_kv():
             # A layer that keeps a recurrent state has no pages: its entry
@@ -479,10 +494,11 @@ class ModelRunner(WarmupPlanMixin):
             # whoever asks a cache for its head size or dtype still can
             # (the benchmark's harness does; ROADMAP.md Design #4 says when
             # this goes and every such layer's entry becomes `()`).
-            # A paged layer's arrays are the model's to say
-            # (``ModelConfig.layer_cache_arrays``): keys and values apart,
-            # or ONE array where the values are the key entry's leading
-            # columns (a latent cache held once).
+            # A paged layer's arrays are the model's and the
+            # configuration's to say (``ModelConfig.layer_cache_arrays``,
+            # ``EngineConfig.cache_form``): keys and values apart; ONE
+            # array of joined pages; or ONE array where the values are the
+            # key entry's leading columns (a latent cache held once).
             def pages(li):
                 if m.layer_kind(li) == "attn":
                     shape = kv_shape(li)
@@ -490,9 +506,9 @@ class ModelRunner(WarmupPlanMixin):
                     shape = (0, cache_heads, self.cache_head_dim)
                 else:
                     return ()
+                arrays = 1 if joined else m.layer_cache_arrays(li) or 2
                 return tuple(
-                    jnp.zeros(shape, self.kv_dtype)
-                    for _ in range(m.layer_cache_arrays(li) or 2)
+                    jnp.zeros(shape, self.kv_dtype) for _ in range(arrays)
                 )
 
             return [pages(li) for li in range(m.num_layers)]
@@ -642,7 +658,8 @@ class ModelRunner(WarmupPlanMixin):
             kv_caches = jax.jit(
                 make_kv,
                 out_shardings=NamedSharding(
-                    mesh, kv_cache_spec(m.is_mla, sp=cfg.kv_sp)
+                    mesh,
+                    kv_cache_spec(m.is_mla, sp=cfg.kv_sp, form=cfg.cache_form),
                 ),
             )()
             kv_scales = None
@@ -660,10 +677,16 @@ class ModelRunner(WarmupPlanMixin):
         self.kv_caches = kv_caches
         self.kv_scales = kv_scales
         #: The paged cache as allocated (``readiness()``, ``/metrics``): the
-        #: bytes one cached token costs over every layer's arrays.
+        #: bytes one cached token costs over every layer's arrays (a leaf's
+        #: bytes over the tokens it holds: its slots, or its blocks'), and
+        #: the arrays a paged layer is.
         self.kv_bytes_per_token = sum(
-            leaf.nbytes // leaf.shape[0]
+            leaf.nbytes
+            // (leaf.shape[0] * (cfg.block_size if joined else 1))
             for leaf in jax.tree.leaves(kv_caches) if leaf.shape[0]
+        )
+        self.kv_arrays_per_layer = max(
+            (len(arrays) for arrays in kv_caches), default=m.cache_arrays
         )
         # The state that is not pages (docs/architecture/unified_step.md):
         # for each recurrent layer the arrays its kind keeps
@@ -986,7 +1009,8 @@ class ModelRunner(WarmupPlanMixin):
 
             tok_sh = NamedSharding(mesh, P())
             kv_sh = NamedSharding(
-                mesh, kv_cache_spec(m.is_mla, sp=cfg.kv_sp)
+                mesh,
+                kv_cache_spec(m.is_mla, sp=cfg.kv_sp, form=cfg.cache_form),
             )
             sc_sh = (
                 NamedSharding(

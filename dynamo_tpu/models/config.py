@@ -18,6 +18,12 @@ def _rope_scaling(d):
     return RopeScaling.from_hf(d)
 
 
+#: The forms a paged layer's pages take on the device: the configuration
+#: decides (``engine/config.py`` ``cache_form_of``), whoever is handed the
+#: arrays reads it off them (``ops/attention.py`` ``page_form``).
+CACHE_FORMS = ("apart", "joined", "once")
+
+
 @dataclass(frozen=True)
 class LayerSpec:
     """What ``ModelConfig`` answers by layer index, as ONE hashable value
@@ -31,6 +37,8 @@ class LayerSpec:
 
     kind: str            # layer_kind: "attn" | "kda" | "retention" | "ssd" | "none"
     cache_arrays: int    # layer_cache_arrays: 2 = (k, v), 1 = the latent once
+    #                      (entries a token; how many ARRAYS hold a (k, v)
+    #                      layer's is EngineConfig.cache_form's to say)
     window: int          # layer_window: 0 = full attention
     cache_group: int     # layer_cache_group: whose slots and block table
     rope: "tuple | str"  # layer_rope: (theta, scaling) or "none"
@@ -344,10 +352,12 @@ class ModelConfig:
         return 1 if self.is_mla else self.num_kv_heads
 
     def layer_cache_arrays(self, layer_idx: int) -> int:
-        """THE place that says how many arrays a layer's paged cache is
-        (docs/architecture/unified_step.md "A latent cache held once"):
-        the layer body, the runner's allocation and the attention call all
-        read it (``LayerSpec.cache_arrays``). 2, keys and values apart; 1
+        """THE place that says how many entries a layer's paged cache
+        holds of a token (docs/architecture/unified_step.md "A latent
+        cache held once"): the layer body, the runner's allocation and the
+        attention call all read it (``LayerSpec.cache_arrays``). 2, keys
+        and values (an array each, or ONE array of joined pages: the
+        configuration's choice, ``EngineConfig.cache_form``); 1
         for a latent-attention layer, whose values ARE the first
         ``kv_lora_rank`` columns of its keys ``[latent | rotated k_pe]``,
         so one array holds both and attention reads the values from the
@@ -367,9 +377,11 @@ class ModelConfig:
 
     @property
     def cache_arrays(self) -> int:
-        """Arrays a paged layer's cache is: one number a model (its paged
-        layers are of one kind); what a block's bytes, the int8 scales and
-        block IO go by. 2 where no layer pages (the empty cache's pair)."""
+        """Entries a paged layer's cache holds of a token (K and V, or the
+        latent once): one number a model (its paged layers are of one
+        kind); what a block's bytes, the int8 scales and block IO go by,
+        whatever form the pages take on the device (``EngineConfig.
+        cache_form``). 2 where no layer pages (the empty cache's pair)."""
         return max(
             (self.layer_cache_arrays(li) for li in range(self.num_layers)),
             default=0,

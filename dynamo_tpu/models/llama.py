@@ -22,7 +22,11 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import RECURRENT_KINDS, LayerSpec, ModelConfig
-from dynamo_tpu.ops.attention import AttnDispatch, full_causal_attention
+from dynamo_tpu.ops.attention import (
+    AttnDispatch,
+    full_causal_attention,
+    page_form,
+)
 from dynamo_tpu.ops.norms import layer_norm, rms_norm
 from dynamo_tpu.ops.quant import (
     CONTRACT_AXIS,
@@ -797,8 +801,10 @@ def _layer_rows(
     """A layer's operations: norm, the mixer by kind, the cache write, the
     attention call, the output product, the MLP. ``cache`` is the layer's
     pages, ``spec.cache_arrays`` arrays of them ((k, v), or the latent
-    once), with ``kv_scale`` [arrays, num_blocks, kvH] where they are
-    int8; ``state`` a recurrent layer's arrays; ``meta`` the step's
+    once) or ONE array of a (k, v) layer's joined pages (ops/attention.py
+    ``page_form``; the engine's choice, read here off the operand), with
+    ``kv_scale`` [arrays, num_blocks, kvH] where they are int8; ``state`` a
+    recurrent layer's arrays; ``meta`` the step's
     (token_seq, token_pos, q_start, q_len, kv_len, row_start);
     ``slot_mapping`` and ``block_tables`` its cache group's. Returns (x,
     cache, kv_scale, state)."""
@@ -872,9 +878,27 @@ def _layer_rows(
         q, k = _rope_qk(cfg, spec, q, k, positions)
     # What the layer writes: keys and values apart, or the latent ONCE
     # (``spec.cache_arrays`` 1: the values are the key entry's first
-    # kv_lora_rank columns, and attention reads them from the key slot).
+    # kv_lora_rank columns, and attention reads them from the key slot),
+    # or both into a block's ONE joined page.
     new = (k, v)[: spec.cache_arrays]
-    if kv_scale is not None:
+    if page_form(*cache) == "joined":
+        assert kv_scale is None and spec.cache_arrays == 2
+        (pages,) = cache
+        # ONE scatter puts every slot's key and its value, over the pages
+        # as rows ``[blocks * 2 * bs, kvH, Dc]`` (a bitcast): slot ``s`` of
+        # block ``b`` has its key at row ``s + b * bs`` and its value ``bs``
+        # rows on; padding rows land in block 0. The row scatter of the two
+        # arrays apart, once over 2T rows: indexed on the pages' own block
+        # and row axes XLA lays the whole pool out anew around it (a copy
+        # in and a copy out a layer at a budget of 1,024 rows).
+        rows = pages.reshape(-1, *pages.shape[3:])
+        key_row = slot_mapping + slot_mapping // block_size * block_size
+        rows = rows.at[
+            jnp.concatenate([key_row, key_row + block_size])
+        ].set(jnp.concatenate([_to_cache(a, pages) for a in new]))
+        cache = (rows.reshape(pages.shape),)
+        scale_kw = {}
+    elif kv_scale is not None:
         from dynamo_tpu.ops.quant import quantize_kv_write
 
         pad = cache[0].shape[-1] - k.shape[-1]
@@ -893,9 +917,7 @@ def _layer_rows(
             for pages, a in zip(cache, new)
         )
         scale_kw = {}
-    k_cache, v_cache = (
-        cache if spec.cache_arrays == 2 else (cache[0], None)
-    )
+    k_cache, v_cache = cache if len(cache) == 2 else (cache[0], None)
     if attn is None:
         from dynamo_tpu.ops.attention import ragged_attention as ragged_fn
     else:

@@ -8,6 +8,17 @@ the blocks listed by its block table, in order; the global position of a
 token equals its index in that slot ordering. Block 0 is the trash block:
 padded query positions write there and it is never allocated.
 
+A (k, v) layer's pages come in two forms (docs/architecture/
+unified_step.md "Three forms of a layer's pages"; ``EngineConfig.
+cache_form`` decides, everything that is handed the arrays reads the form
+off them in ONE function, ``page_form``). APART, the two arrays above.
+JOINED, ONE array
+``[num_blocks, 2, block_size, n_kv_heads, head_dim]`` in which block ``b``
+is one contiguous page, its keys and then its values: the layer writes both
+with one scatter (over the pages as rows), the ragged kernel streams a page with one descriptor, and
+the twin gathers both from the one array. A latent cache HELD ONCE is one
+``[num_slots, 1, head_dim]`` array whose keys hold the values.
+
 Both prefill and decode process key blocks with an online-softmax scan
 (flash-attention style) so peak memory is one key block per step — no
 materialized [ctx, ctx] score matrices and no full-cache gather. This is
@@ -28,6 +39,36 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+
+
+def page_form(k_cache, v_cache=None) -> str:
+    """THE place that reads the form of a layer's pages (``CACHE_FORMS``)
+    off the arrays a call is handed (the layer body, the attention call,
+    both kernels' wrappers and block IO ask here). "apart": K and V, an
+    array ``[num_slots, heads, D]`` each. "once": ONE such array whose keys
+    hold the values. "joined": ONE array ``[num_blocks, 2, block_size,
+    heads, D]``, told by its rank AND its pair axis (``v_cache`` is then
+    None or the same array). Any other operand is refused, so that a cache
+    of another shape never passes for one of these."""
+    if k_cache.ndim == 5 and k_cache.shape[1] == 2 and (
+        v_cache is None or v_cache.shape == k_cache.shape
+    ):
+        return "joined"
+    if k_cache.ndim == 3 and (v_cache is None or v_cache.ndim == 3):
+        return "apart" if v_cache is not None else "once"
+    raise ValueError(
+        f"no form of a layer's pages: {k_cache.shape}, "
+        f"{None if v_cache is None else v_cache.shape}"
+    )
+
+
+def join_pages(k, v, block_size: int):
+    """K and V ``[num_slots, kvH, D]`` as ONE array of joined pages
+    ``[num_blocks, 2, block_size, kvH, D]`` (tests and tools build their
+    caches with it; the engine allocates the joined array outright)."""
+    return jnp.stack(
+        [a.reshape(-1, block_size, *a.shape[1:]) for a in (k, v)], axis=1
+    )
 
 
 def pallas_enabled() -> bool:
@@ -195,10 +236,15 @@ class AttnDispatch:
         striped kv_sp scan is refused: ``EngineConfig.validate``). The
         output is then ``P @ K`` at the key's width, whose columns past
         ``kv_lora_rank`` the caller does not read (models/llama.py
-        ``_mla_out``); ``v_scales`` is ``k_scales``."""
+        ``_mla_out``); ``v_scales`` is ``k_scales``. ``v_cache=None`` over
+        an array of JOINED pages (``page_form``): the values are the second
+        half of each block's page; the kernel streams the one array, a
+        descriptor a page, and the twin gathers keys and values from it
+        (never int8, never under kv_sp: ``EngineConfig.cache_form``)."""
         D = q.shape[-1]
         qp = _pad_q_for_cache(q, k_cache)
         once = v_cache is None
+        joined = page_form(k_cache, v_cache) == "joined"
         if once:
             v_scales = k_scales
         assert diffusion_block == 1 or not self.kv_sp, (
@@ -216,7 +262,7 @@ class AttnDispatch:
                 axis=0,
             )  # [T, max_blocks]
             ctx = jnp.maximum(token_pos + 1, 0)
-            assert not once, "EngineConfig.validate refuses kv_sp here"
+            assert not once, "one array a layer serves without kv_sp"
             out = self._kv_sp_decode(
                 qp, k_cache, v_cache, tok_tables, ctx, block_size, window
             )
@@ -260,7 +306,10 @@ class AttnDispatch:
 
                 qh = P(None, self._ax, None)
                 kv_ax = None if self.kv_replicated else self._ax
-                kvh = (P(None, kv_ax, None),) * len(arrays)
+                kvh = (
+                    P(None, None, None, kv_ax, None) if joined
+                    else P(None, kv_ax, None),
+                ) * len(arrays)
                 # Scales shard their head axis with the cache heads
                 # (replicated for MLA / headless meshes).
                 sc = (P(None, kv_ax),) * len(scales)
@@ -432,9 +481,14 @@ def _decode_partials(
     ceil(window/bs)+1 — windowed decode cost is O(window), not O(ctx).
 
     ``page_offset``/``page_stride``: striped-scan mode (see
-    _prefill_partials) — scan only logical pages ≡ offset (mod stride)."""
+    _prefill_partials) — scan only logical pages ≡ offset (mod stride).
+
+    ``k_cache`` of JOINED pages (``page_form``; ``v_cache`` is then the
+    same array): a block's page is gathered whole and its two halves are
+    the keys and the values."""
     B, H, D = q.shape
-    kvH = k_cache.shape[1]
+    joined = page_form(k_cache, v_cache) == "joined"
+    kvH = k_cache.shape[-2]
     G = H // kvH
     scale = 1.0 / (D**0.5)
     qr = (q.astype(jnp.float32) * scale).reshape(B, kvH, G, D)
@@ -457,10 +511,15 @@ def _decode_partials(
         entry = jnp.take_along_axis(
             block_tables, jnp.minimum(blk, max_blocks - 1)[:, None], axis=1
         )[:, 0]
-        slots = entry[:, None] * block_size + jnp.arange(block_size)
-        idx, ok = slot_fn(k_cache, slots)
-        k = k_cache[idx].astype(jnp.float32)  # [B, bs, kvH, D]
-        v = v_cache[idx].astype(jnp.float32)
+        if joined:
+            page = k_cache[entry].astype(jnp.float32)  # [B, 2, bs, kvH, D]
+            k, v = page[:, 0], page[:, 1]
+            ok = jnp.ones((B, block_size), bool)
+        else:
+            slots = entry[:, None] * block_size + jnp.arange(block_size)
+            idx, ok = slot_fn(k_cache, slots)
+            k = k_cache[idx].astype(jnp.float32)  # [B, bs, kvH, D]
+            v = v_cache[idx].astype(jnp.float32)
         if k_scales is not None:
             k = _dequant_rows(k, entry, k_scales)
             v = _dequant_rows(v, entry, v_scales)
@@ -549,7 +608,7 @@ def paged_decode_attention(
 def ragged_paged_attention(
     q: jnp.ndarray,             # [T, H, D] — flat mixed prefill+decode batch
     k_cache: jnp.ndarray,       # [num_slots, n_kv_heads, head_dim]
-    v_cache: jnp.ndarray,
+    v_cache: jnp.ndarray,       # (joined pages: the one array for both)
     block_tables: jnp.ndarray,  # [S, max_blocks] int32 — per-sequence rows
     token_seq: jnp.ndarray,     # [T] int32 — owning sequence row per token
     token_pos: jnp.ndarray,     # [T] int32 — global position (-1 = padding)
@@ -618,7 +677,7 @@ def default_dispatch(block_size: int, k_cache) -> AttnDispatch:
         from dynamo_tpu.ops.pallas.attention import pallas_supported
 
         use_pallas = pallas_supported(
-            block_size, k_cache.shape[1], k_cache.shape[2], k_cache.dtype
+            block_size, k_cache.shape[-2], k_cache.shape[-1], k_cache.dtype
         )
     return AttnDispatch(use_pallas=use_pallas)
 

@@ -9,9 +9,13 @@ thread, serialized with steps, so the non-donated gather never races a
 donated step buffer.
 
 Layout contract: host block = [num_layers, A, block_size, kv_heads,
-head_dim], matching KvLayoutConfig; A is the arrays a layer's cache is, 2
-(k, v) or 1 (a latent held once: ModelConfig.layer_cache_arrays), the same
-for every layer of a model that moves blocks.
+head_dim], matching KvLayoutConfig; A is the entries a layer's cache holds
+of a token, 2 (k, v) or 1 (a latent held once: ModelConfig.
+layer_cache_arrays), the same for every layer of a model that moves blocks.
+A (k, v) layer whose pages are JOINED on the device (one array
+[num_blocks, 2, block_size, kv_heads, head_dim]: ops/attention.py
+``page_form``) moves the same host block: a block of that array IS its
+[2, block_size, kv_heads, head_dim].
 """
 
 from __future__ import annotations
@@ -22,15 +26,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.ops.attention import page_form
+
+
+def _joined(arrays) -> bool:
+    return bool(arrays) and page_form(*arrays) == "joined"
+
 
 @partial(jax.jit, static_argnames=("block_size",), donate_argnums=())
-def _gather(kv_caches, start: jnp.ndarray, *, block_size: int):
+def _gather(kv_caches, blk: jnp.ndarray, *, block_size: int):
     outs = []
     for arrays in kv_caches:
+        if _joined(arrays):
+            outs.append(jax.lax.dynamic_index_in_dim(
+                arrays[0], blk, 0, keepdims=False))
+            continue
         outs.append(
             jnp.stack(
                 [
-                    jax.lax.dynamic_slice_in_dim(a, start, block_size, 0)
+                    jax.lax.dynamic_slice_in_dim(
+                        a, blk * block_size, block_size, 0)
                     for a in arrays
                 ]
             )
@@ -39,13 +54,18 @@ def _gather(kv_caches, start: jnp.ndarray, *, block_size: int):
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter(kv_caches, start: jnp.ndarray, data: jnp.ndarray):
+def _scatter(kv_caches, blk: jnp.ndarray, data: jnp.ndarray):
     new = []
     for i, arrays in enumerate(kv_caches):
+        if _joined(arrays):
+            (a,) = arrays
+            new.append((jax.lax.dynamic_update_index_in_dim(
+                a, data[i].astype(a.dtype), blk, 0),))
+            continue
         new.append(
             tuple(
                 jax.lax.dynamic_update_slice_in_dim(
-                    a, data[i, j].astype(a.dtype), start, 0
+                    a, data[i, j].astype(a.dtype), blk * data.shape[2], 0
                 )
                 for j, a in enumerate(arrays)
             )
@@ -64,15 +84,13 @@ def gather_block_device(kv_caches, block_idx: int, block_size: int) -> jax.Array
     the HBM→HBM transfer path's snapshot (no host sync; scatter_block
     consumes it directly, so a same-process prefill→decode block move
     never touches host memory)."""
-    return _gather(
-        kv_caches, jnp.int32(block_idx * block_size), block_size=block_size
-    )
+    return _gather(kv_caches, jnp.int32(block_idx), block_size=block_size)
 
 
 def scatter_block(kv_caches, block_idx: int, block_size: int, data: np.ndarray):
     """Write one block's KV from host; returns the new cache list (donated
     update — caller must replace its reference)."""
-    return _scatter(kv_caches, jnp.int32(block_idx * block_size), jnp.asarray(data))
+    return _scatter(kv_caches, jnp.int32(block_idx), jnp.asarray(data))
 
 
 # -- batched block IO ---------------------------------------------------------
@@ -84,20 +102,27 @@ def scatter_block(kv_caches, block_idx: int, block_size: int, data: np.ndarray):
 
 
 @partial(jax.jit, static_argnames=("block_size",), donate_argnums=())
-def _gather_many(kv_caches, starts, *, block_size: int):
-    idx = starts[:, None] + jnp.arange(block_size)[None, :]  # [N, bs]
+def _gather_many(kv_caches, blks, *, block_size: int):
+    idx = blks[:, None] * block_size + jnp.arange(block_size)[None, :]  # [N, bs]
     outs = []
     for arrays in kv_caches:
+        if _joined(arrays):
+            outs.append(arrays[0][blks])
+            continue
         outs.append(jnp.stack([a[idx] for a in arrays], axis=1))  # [N, A, bs, H, D]
     return jnp.stack(outs, axis=1)  # [N, L, A, bs, H, D]
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter_many(kv_caches, starts, data):
+def _scatter_many(kv_caches, blks, data):
     bs = data.shape[3]
-    idx = (starts[:, None] + jnp.arange(bs)[None, :]).reshape(-1)  # [N*bs]
+    idx = (blks[:, None] * bs + jnp.arange(bs)[None, :]).reshape(-1)  # [N*bs]
     new = []
     for i, arrays in enumerate(kv_caches):
+        if _joined(arrays):
+            (a,) = arrays
+            new.append((a.at[blks].set(data[:, i].astype(a.dtype)),))
+            continue
         new.append(tuple(
             a.at[idx].set(
                 data[:, i, j].astype(a.dtype).reshape(-1, *a.shape[1:])
@@ -122,9 +147,9 @@ def gather_blocks_device(kv_caches, block_idxs, block_size: int) -> jax.Array:
     NO host sync. The copy is ordered before any later cache rewrite, so
     the caller may materialize it lazily (e.g. on the KVBM pump thread)."""
     n = len(block_idxs)
-    starts = np.zeros(_bucket(n), np.int32)
-    starts[:n] = np.asarray(block_idxs, np.int32) * block_size
-    out = _gather_many(kv_caches, jnp.asarray(starts), block_size=block_size)
+    blks = np.zeros(_bucket(n), np.int32)
+    blks[:n] = np.asarray(block_idxs, np.int32)
+    out = _gather_many(kv_caches, jnp.asarray(blks), block_size=block_size)
     return out[:n] if _bucket(n) != n else out
 
 
@@ -189,8 +214,8 @@ def scatter_blocks(kv_caches, block_idxs, block_size: int, data):
     zeros into trash block 0, which is never read as real KV."""
     n = len(block_idxs)
     b = _bucket(n)
-    starts = np.zeros(b, np.int32)
-    starts[:n] = np.asarray(block_idxs, np.int32) * block_size
+    blks = np.zeros(b, np.int32)
+    blks[:n] = np.asarray(block_idxs, np.int32)
     if isinstance(data, jax.Array):
         arr = data  # device-resident: pad on device, never touch host
         if b != n:
@@ -202,4 +227,4 @@ def scatter_blocks(kv_caches, block_idxs, block_size: int, data):
         if b != n:
             pad = np.zeros((b - n, *arr.shape[1:]), arr.dtype)
             arr = np.concatenate([arr, pad], axis=0)
-    return _scatter_many(kv_caches, jnp.asarray(starts), jnp.asarray(arr))
+    return _scatter_many(kv_caches, jnp.asarray(blks), jnp.asarray(arr))

@@ -25,15 +25,23 @@ Metadata (all per-sequence, scalar-prefetched to SMEM):
   ``q_start + q_len`` (kept explicit on the wire for clarity);
 - ``row_start[s]``: the span's first row in the flat batch.
 
-Layout contract is unchanged from ops/pallas/attention.py: the cache is
-``[num_slots, kvH, D]`` viewed as pages ``[num_blocks, bs*kvH, D]``, K and
-V apart, ``D % 128 == 0`` inside the kernel (lane-padded caches for
-smaller head dims); ``q`` and the output live in ANY (HBM) memory and
-move by DMA at dynamic row offsets, so spans need no alignment. A latent
-cache held once (``v_cache=None``; PR 52, docs/architecture/
-unified_step.md "A latent cache held once") is ONE such array: the values
-are the key entry's leading columns, so the kernel has no V operand and
-no V ring, and both fold bodies read their values from the key slot.
+Layout contract: a layer's pages take one of three forms (docs/
+architecture/unified_step.md "Three forms of a layer's pages";
+``EngineConfig.cache_form`` decides, the kernel reads the form off its
+operands: ops/attention.py ``page_form``). APART, as in ops/pallas/attention.py: K and V an
+array each, ``[num_slots, kvH, D]`` viewed as pages ``[num_blocks, bs*kvH,
+D]``, two rings, two descriptors a page. JOINED (``v_cache=None`` over
+``[num_blocks, 2, bs, kvH, D]``; PR 59): a block's keys and then its values
+are ONE contiguous page of ONE array, viewed ``[num_blocks, 2, bs*kvH, D]``
+(a bitcast), streamed with ONE descriptor a page through one ring of ``2 *
+NBUF`` rows, fold ``s`` at rows ``2s`` (its keys) and ``2s + 1`` (its
+values): the VMEM and the folds the two rings had, half the descriptors. A
+latent cache HELD ONCE (``v_cache=None`` over ``[num_slots, 1, D]``; PR 52)
+is one array whose values are the key entry's leading columns: no V operand,
+no V ring, both fold bodies read their values from the key's rows. In every
+form ``D % 128 == 0`` inside the kernel (lane-padded caches for smaller
+head dims), and ``q`` and the output live in ANY (HBM) memory and move by
+DMA at dynamic row offsets, so spans need no alignment.
 
 The kernel is ONE program (``grid=(1,)``) that walks the step's spans as
 a software pipeline (PR 40; before it, one grid program a span started
@@ -72,7 +80,7 @@ cold and paid ~2.7 us a span and 0.12-0.14 us a page whatever the bytes:
     scaled after the product (bf16 x bf16 is exact in f32) and go
     through ``exp2`` in units of log 2 (one multiply a score for scale
     and base). Probabilities stay f32 into PV. int8 and f32 caches keep
-    ``dequant`` / ``heads_view``, a relayout and ``exp``.
+    ``dequant`` / ``slab_heads``, a relayout and ``exp``.
   - SHORT, ``q_len <= diffusion_block`` rows (a decode row; a block of a
     block-diffusion model). **By cached head where a head's folded rows
     fill a sublane tile** (``short_by_head``: ``rows * G >= 8`` and more
@@ -127,22 +135,29 @@ cold and paid ~2.7 us a span and 0.12-0.14 us a page whatever the bytes:
   ==========================  =====  =====  =====  =====  =====  =====
   shape (page of K)            2x16   3x16   4x16   6x16    4x8   3x32
   ==========================  =====  =====  =====  =====  =====  =====
-  H 8, kvH 2 (8 KiB), tp=4      523    516    533    509    593    513
+  H 8, kvH 2 (8 KiB), tp=4      377    379    352    373    435    358
   H 32, kvH 8 (32 KiB)          418    412    411    413    407    452
   H 32, kvH 4 (16 KiB), B=4     436    427    428    426    439    458
   H 128, kvH 8, window 4,096  3,889  3,855  3,891  3,871  4,111  3,877
   H 128, kvH 8, no window     9,470  9,398  9,472  9,388 10,406  8,988
   ==========================  =====  =====  =====  =====  =====  =====
 
-  (129 / 65 / 65 spans of contexts 200-1,500; my chip runs, PR 40. The
-  kernel before read 1,410 / 854 / 835 at its 8x8. The last two rows: 45
+  (129 / 65 / 65 spans of contexts 200-1,500; my chip runs, PR 40; the
+  first row my chip runs, PR 59: its pages JOINED, 16 KiB a descriptor,
+  where K and V apart read 523 / 516 / 533 / 509 / 593 / 513. The
+  kernel before PR 40 read 1,410 / 854 / 835 at its 8x8. The last two rows: 45
   lanes at contexts 600-16,000 beside a 770-row quantum ending at 12k,
   32 KiB pages, this kernel; my chip runs, PR 46.) Depth hardly matters
   once the ring spans spans; 128-key folds cost the small pages 15 %,
   512-key folds the large ones 10 % (their clamped tails), and at long
   contexts 128-key folds cost 6-11 % while 512-key folds (a 1 MiB slot,
   over ``SLOT_BYTES``) gain 4 % on a layer without a window and nothing
-  under one.
+  under one. **What a page costs** (the split of ``--sweep split``, us a
+  page of K and V, two descriptors apart | one joined, beside its bytes;
+  my chip runs, PR 59): tp=4's 16 KiB 0.0488 | 0.0308 (0.020; the same
+  folds over ONE 8 KiB array, half the bytes, 0.0301: a page there costs
+  its descriptor, not its bytes), SDAR's 32 KiB 0.0603 | 0.0406 (0.040),
+  one chip's dense 64 KiB 0.0826 | 0.0825 (0.080).
 
 Scores, probabilities, the running max and sum and the accumulator are
 f32; every (query, visible key) pair is computed under the same mask as
@@ -163,7 +178,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.ops.pallas.attention import heads_view
+from dynamo_tpu.models.config import CACHE_FORMS
+from dynamo_tpu.ops.attention import page_form
 
 MEMORY_SPACE_ANY = pltpu.MemorySpace.ANY
 
@@ -283,8 +299,8 @@ def _ragged_kernel(
     # inputs (q/k/v in ANY memory, DMA'd manually; with `quantized`,
     # a per-block scale array each follows, whole-array-resident in VMEM)
     q_hbm,             # [T + TQL, H, D] flat queries (tail-padded)
-    k_hbm,             # [num_blocks, bs*kvH, D] pages
-    # v_hbm, unless `values_in_keys`
+    k_hbm,             # [num_blocks, bs*kvH, D] pages; [num_blocks, 2, ...]
+    # v_hbm, where ``values`` is "apart"
     # quantized only: k_scales_ref / v_scales_ref [num_blocks, kvH] VMEM
     *rest,
     block_size: int,
@@ -292,32 +308,38 @@ def _ragged_kernel(
     window: int = 0,
     quantized: bool = False,
     diffusion_block: int = 1,
-    values_in_keys: bool = False,
+    values: str = "apart",
 ):
-    """ONE program walks every span; see the module docstring. With
-    ``values_in_keys`` (a latent cache held once) there is no V operand, no
-    V ring and no V semaphore: every ``v_*`` name below IS its ``k_*``
-    twin, so a fold reads its values from the key slot it has waited for."""
-    once = values_in_keys
+    """ONE program walks every span; see the module docstring. ``values``
+    is the form of the layer's pages (``CACHE_FORMS``), which says where a
+    fold finds its values. "apart": a V operand, a V ring and a V semaphore
+    beside K's. Else ONE stream, and a fold reads its values from the slot
+    it has waited for: "once", the key's own rows (a latent cache held
+    once); "joined", the slot's second half (a page is a block's keys and
+    then its values, one descriptor)."""
+    assert values in CACHE_FORMS, values
+    apart, joined = values == "apart", values == "joined"
     rest = list(rest)
-    v_hbm = k_hbm if once else rest.pop(0)
+    v_hbm = rest.pop(0) if apart else k_hbm
     k_scales_ref = v_scales_ref = None
     if quantized:
         k_scales_ref = rest.pop(0)
-        v_scales_ref = k_scales_ref if once else rest.pop(0)
+        v_scales_ref = rest.pop(0) if apart else k_scales_ref
     # o_hbm [T + TQL, H, D]; VMEM q_s [NBUF + 1, TQS, H, D] short spans' q
     # rows, q_l [2, TQL, H, D] long spans' q tiles, o_s [2, TQS, H, D],
-    # o_l [2, TQL, H, D], k_buf / v_buf [NBUF, PP*bs*kvH, D] (cache dtype)
+    # o_l [2, TQL, H, D], k_buf / v_buf [NBUF, PP*bs*kvH, D] (cache dtype;
+    # joined, ONE ring [2 * NBUF, PP*bs*kvH, D]: slot s is rows 2s, a fold's
+    # keys, and 2s + 1, its values)
     o_hbm, q_s, q_l, o_s, o_l, k_buf = (rest.pop(0) for _ in range(6))
-    v_buf = k_buf if once else rest.pop(0)
+    v_buf = rest.pop(0) if apart else k_buf
     # DMA semaphores: qs [NBUF + 1], ql / os / ol [2], k / v [NBUF]
     qs_sem, ql_sem, os_sem, ol_sem, k_sem = (rest.pop(0) for _ in range(5))
-    v_sem = k_sem if once else rest.pop(0)
+    v_sem = rest.pop(0) if apart else k_sem
     (st,) = rest       # SMEM [_NSTATE] int32
     # What a fold streams from HBM: K's pages, and V's where they are apart.
-    streams = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))[: 1 if once else 2]
+    streams = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))[: 2 if apart else 1]
     S = q_len_ref.shape[0]
-    NBUF = k_buf.shape[0]
+    NBUF = k_buf.shape[0] // (2 if joined else 1)
     # The producer may have entered NBUF tiles past the one being folded.
     NQ = NBUF + 1
     TQS = q_s.shape[1]          # rows of a short span: diffusion_block
@@ -327,13 +349,26 @@ def _ragged_kernel(
     G = H // kvH
     bs = block_size
     page_rows = bs * kvH
-    PP = k_buf.shape[1] // page_rows
+    PP = k_buf.shape[-2] // page_rows
     N = PP * page_rows          # K rows a fold: PP*bs keys x kvH heads
     scale = 1.0 / (D**0.5)
     B = diffusion_block
     f32 = jnp.float32
 
     # -- geometry, shared by the producer and the consumer -----------------
+
+    # Where K and V of fold ``slot`` lie in the ring(s): joined, ONE ring of
+    # 2 * NBUF rows of ``[N, D]``, a fold's keys at row 2 * slot and its
+    # values behind them; else a ring each (or the keys' own rows again).
+    def whole(slot):
+        """What one wait covers of a ring: fold ``slot``'s rows."""
+        return pl.ds(2 * slot, 2) if joined else slot
+
+    def k_at(slot):
+        return k_buf, (2 * slot if joined else slot)
+
+    def v_at(slot):
+        return v_buf, (2 * slot + 1 if joined else slot)
 
     def is_short(ql):
         return ql <= TQS
@@ -397,8 +432,9 @@ def _ragged_kernel(
                 page = block_tables_ref[ps, jnp.minimum(pf * PP + h, last)]
                 rows = pl.ds(h * page_rows, page_rows)
                 for hbm, buf, sem in streams:
+                    # joined: K's rows and V's of the page, ONE descriptor
                     pltpu.make_async_copy(
-                        hbm.at[page], buf.at[slot, rows], sem.at[slot]
+                        hbm.at[page], buf.at[whole(slot), rows], sem.at[slot]
                     ).start()
                 return c
 
@@ -430,15 +466,25 @@ def _ragged_kernel(
         slot = jax.lax.rem(i, NBUF)
         for _, buf, sem in streams:
             pltpu.make_async_copy(
-                buf.at[slot], buf.at[slot], sem.at[slot]
+                buf.at[whole(slot)], buf.at[whole(slot)], sem.at[slot]
             ).wait()
         return slot
 
-    def dequant(ref, scales_ref, s, f, slot, last):
+    def slab(at, slot):
+        """K's or V's ``[N, D]`` rows of a fold, as stored."""
+        buf, row = at(slot)
+        return buf[row]
+
+    def slab_heads(at, slot):
+        """The same as f32 ``[PP*bs, kvH, D]``: load, cast, THEN reshape
+        (a packed reshape reads wrong rows at few heads, PR 22)."""
+        return slab(at, slot).astype(f32).reshape(PP * bs, kvH, D)
+
+    def dequant(at, scales_ref, s, f, slot, last):
         """A ring slot as f32 ``[PP*bs, kvH, D]`` times its pages' scale
         rows (the pages ``produce`` fetched: the tail's are page ``last``):
         exactly ``int8 * scale``, the oracle's arithmetic."""
-        x = heads_view(ref, slot, PP * bs, kvH, D)
+        x = slab_heads(at, slot)
         rows = []
         for h in range(PP):
             j = jnp.minimum(f * PP + h, last)
@@ -494,17 +540,18 @@ def _ragged_kernel(
     exp = jnp.exp2 if by_word else jnp.exp
     post = scale * LOG2E if by_word else scale
 
-    def head_rows(buf, slot):
-        """A bf16 ring slot as f32 ``[kvH, KEYS, D]``: each cached head's
-        keys read at their stride, no cast of the slot and no relayout. A
-        word holds row ``2w`` low and row ``2w + 1`` high, and a bf16 is
-        the high half of its f32: a shift or a mask IS the cast."""
+    def head_rows(at, slot):
+        """A bf16 ring slot's K or V as f32 ``[kvH, KEYS, D]``: each cached
+        head's keys read at their stride, no cast of the slot and no
+        relayout. A word holds row ``2w`` low and row ``2w + 1`` high, and
+        a bf16 is the high half of its f32: a shift or a mask IS the cast."""
         if kvH == 1:
-            return buf[slot].astype(f32)[None]
+            return slab(at, slot).astype(f32)[None]
+        buf, row = at(slot)
         words = buf.bitcast(jnp.uint32)
         heads = []
         for j in range(kvH // 2):
-            w = words[slot, pl.ds(j, KEYS, stride=kvH // 2), :]
+            w = words[row, pl.ds(j, KEYS, stride=kvH // 2), :]
             heads.append(pltpu.bitcast(w << 16, f32))
             heads.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000), f32))
         return jnp.stack(heads)
@@ -512,16 +559,15 @@ def _ragged_kernel(
     def slot_heads(s, f, slot, last):
         """``(K, V)`` of a ring slot as ``[kvH, KEYS, D]``: under
         ``by_word`` bf16 K (the MXU's operand as stored) and f32 V, else
-        both f32 through ``dequant`` / ``heads_view`` and a relayout."""
+        both f32 through ``dequant`` / ``slab_heads`` and a relayout."""
         if by_word:
-            return (head_rows(k_buf, slot).astype(jnp.bfloat16),
-                    head_rows(v_buf, slot))
+            return (head_rows(k_at, slot).astype(jnp.bfloat16),
+                    head_rows(v_at, slot))
         if quantized:
-            k = dequant(k_buf, k_scales_ref, s, f, slot, last)
-            v = dequant(v_buf, v_scales_ref, s, f, slot, last)
+            k = dequant(k_at, k_scales_ref, s, f, slot, last)
+            v = dequant(v_at, v_scales_ref, s, f, slot, last)
         else:
-            k = heads_view(k_buf, slot, KEYS, kvH, D)
-            v = heads_view(v_buf, slot, KEYS, kvH, D)
+            k, v = slab_heads(k_at, slot), slab_heads(v_at, slot)
         return jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
 
     # -- a short span: a row a lane, or a block of a block-diffusion model --
@@ -585,12 +631,13 @@ def _ragged_kernel(
                 pv = (((2,), (1,)), ((0,), (0,)))  # [kvH, GT, D]
             else:
                 if quantized:
-                    k = dequant(k_buf, k_scales_ref, s, f, slot, nb - 1)
-                    v = dequant(v_buf, v_scales_ref, s, f, slot, nb - 1)
+                    k = dequant(k_at, k_scales_ref, s, f, slot, nb - 1)
+                    v = dequant(v_at, v_scales_ref, s, f, slot, nb - 1)
                     k, v = k.reshape(N, D), v.reshape(N, D)
                 else:
-                    k = k_buf[slot] if direct else k_buf[slot].astype(f32)
-                    v = v_buf[slot].astype(f32)
+                    k = slab(k_at, slot)
+                    k = k if direct else k.astype(f32)
+                    v = slab(v_at, slot).astype(f32)
                 qk = (((1,), (1,)), ((), ()))      # [M, N]
                 pv = (((1,), (0,)), ((), ()))      # [M, D]
             scores = jax.lax.dot_general(
@@ -804,8 +851,8 @@ def _ragged_kernel(
 )
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,             # [T, H, D] flat token batch (budget-padded)
-    k_cache: jnp.ndarray,       # [num_slots, kvH, D]
-    v_cache: jnp.ndarray | None,  # None: the values are K's leading columns
+    k_cache: jnp.ndarray,       # [num_slots, kvH, D]; joined, see below
+    v_cache: jnp.ndarray | None,  # None: the values are in ``k_cache``
     block_tables: jnp.ndarray,  # [S, max_blocks] int32
     q_start: jnp.ndarray,       # [S] int32 — prefix length per span
     q_len: jnp.ndarray,         # [S] int32 — span rows (0 = idle)
@@ -831,28 +878,39 @@ def ragged_paged_attention_pallas(
     VMEM, and the compiled program count is unchanged — quantization
     only changes dtypes inside the existing budget-ladder grid.
 
-    ``v_cache=None`` is a latent cache held once: the key entry ``[latent |
-    rotated k_pe]`` holds the values in its leading columns, so the kernel
-    streams ONE array through one ring (half the DMA bytes, half the ring's
-    VMEM) and folds ``P @ K``; the result's columns past the latent's
-    width are ``P @ k_pe``, which the caller does not read. ``v_scales``
-    is then ``k_scales``."""
+    ``v_cache=None`` is ONE array that holds the values too, and the kernel
+    streams it through one ring (``page_form`` reads which off the array).
+    ``[num_slots, 1, D]``, a latent cache held once: the key entry ``[latent
+    | rotated k_pe]`` holds the values in its leading columns (half the DMA
+    bytes, half the ring's VMEM), the fold is ``P @ K``, and the result's
+    columns past the latent's width are ``P @ k_pe``, which the caller does
+    not read; ``v_scales`` is then ``k_scales``. ``[num_blocks, 2, bs, kvH,
+    D]``, a (k, v) layer's pages JOINED: a block's keys and then its values
+    are one contiguous page, so a page is one descriptor of twice the bytes
+    where two (the ring holds what the two rings held)."""
     T, H, D = q.shape
     S = block_tables.shape[0]
-    kvH = k_cache.shape[1]
+    kvH = k_cache.shape[-2]
     assert diffusion_block == 1 or not window, "no window under a block mask"
     TQS = diffusion_block
     TQL = max(q_tile, long_tile(H, kvH))
     quantized = k_scales is not None
-    once = v_cache is None
-    pages = [
-        c.reshape(-1, block_size * kvH, D)
-        for c in ((k_cache,) if once else (k_cache, v_cache))
-    ]
-    scales = [] if not quantized else [k_scales] if once else [k_scales, v_scales]
-    nbuf, pp = ring_shape(
-        block_size * kvH * D * k_cache.dtype.itemsize, block_size
-    )
+    values = page_form(k_cache, v_cache)
+    page_rows = block_size * kvH
+    if values == "joined":
+        assert not quantized, "int8 pages keep K and V apart (a scale each)"
+        pages = [k_cache.reshape(-1, 2, page_rows, D)]
+    else:
+        pages = [
+            c.reshape(-1, page_rows, D)
+            for c in ((k_cache, v_cache) if values == "apart" else (k_cache,))
+        ]
+    scales = [k_scales, v_scales][: len(pages)] if quantized else []
+    # K's share of a slot sizes the fold, joined or apart: the ring(s) hold
+    # twice ``SLOT_BYTES`` a slot either way.
+    nbuf, pp = ring_shape(page_rows * D * k_cache.dtype.itemsize, block_size)
+    # joined, ONE ring of 2 * nbuf rows: a fold's keys, then its values
+    ring = (2 * nbuf if values == "joined" else nbuf, pp * page_rows, D)
     # Tail pad: the last tile of a span ending near row T-1 reads a whole
     # tile from its dynamic offset; padding keeps every read in bounds
     # without aligning spans. The pad rows are never written back.
@@ -873,10 +931,7 @@ def ragged_paged_attention_pallas(
             pltpu.VMEM((2, TQL, H, D), q.dtype),
             pltpu.VMEM((2, TQS, H, D), q.dtype),
             pltpu.VMEM((2, TQL, H, D), q.dtype),
-            *(
-                pltpu.VMEM((nbuf, pp * block_size * kvH, D), p.dtype)
-                for p in pages
-            ),
+            *(pltpu.VMEM(ring, p.dtype) for p in pages),
             pltpu.SemaphoreType.DMA((nbuf + 1,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -888,7 +943,7 @@ def ragged_paged_attention_pallas(
     kernel = functools.partial(
         _ragged_kernel, block_size=block_size, num_kv_heads=kvH,
         window=window, quantized=quantized, diffusion_block=diffusion_block,
-        values_in_keys=once,
+        values=values,
     )
     operands = [
         block_tables.astype(jnp.int32),

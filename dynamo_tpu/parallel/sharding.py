@@ -108,14 +108,22 @@ def llama_param_specs(cfg: ModelConfig) -> Params:
     return specs
 
 
-def kv_cache_spec(replicated: bool = False, sp: bool = False) -> P:
-    """[num_slots, n_cache_heads, head_dim] — heads over tp; MLA models
+def kv_cache_spec(
+    replicated: bool = False, sp: bool = False, form: str = "apart"
+) -> P:
+    """[num_slots, n_cache_heads, head_dim] — heads over tp; under ``form``
+    "joined" (``EngineConfig.cache_form``; never with ``sp`` or
+    ``replicated``) a (k, v) layer's pages in ONE array [num_blocks, 2,
+    block_size, n_cache_heads, head_dim], the same axis. MLA models
     pass replicated=True (one shared latent head per token — q heads
     shard, the cache does not; models/llama.py _qkv_mla). ``sp`` shards
     the SLOT axis over the sp mesh axis IN ADDITION to the tp head
     sharding — the long-context mode where total KV capacity is
     sp x tp x one device's arrays (ops/attention.py AttnDispatch kv_sp;
     composes with tensor parallelism since r05)."""
+    if form == "joined":
+        assert not sp and not replicated
+        return P(None, None, None, "tp", None)
     if sp:
         return P("sp", None, None) if replicated else P("sp", "tp", None)
     return P(None, None, None) if replicated else P(None, "tp", None)

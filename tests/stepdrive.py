@@ -44,3 +44,18 @@ def reference_greedy(cfg, params, prompt, n: int, *, length: int) -> list[int]:
         logits = llama.reference_forward(cfg, params, jnp.asarray(padded))
         tokens.append(int(jnp.argmax(logits[len(tokens) - 1])))
     return tokens[len(prompt):]
+
+
+def slot_rows(layer) -> list:
+    """A paged layer's entries as ``[num_slots, heads, D]`` arrays (keys,
+    then values), whatever form its pages take on the device
+    (ops/attention.py ``page_form``): a joined layer's one array taken
+    apart, any other layer's arrays as they are."""
+    from dynamo_tpu.ops.attention import page_form
+
+    if page_form(*layer) != "joined":
+        return [np.asarray(a) for a in layer]
+    (pages,) = layer
+    pages = np.asarray(pages)
+    return [pages[:, j].reshape(-1, *pages.shape[3:]) for j in range(2)]
+

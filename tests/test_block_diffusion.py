@@ -30,6 +30,7 @@ from dynamo_tpu.ops.pallas.ragged_attention import (
 )
 from dynamo_tpu.ops.sampling import commit_block, commit_floor_rows
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import slot_rows
 
 pytestmark = pytest.mark.anyio
 
@@ -413,8 +414,8 @@ async def served(cfg, prompts, n, hook=None, **stop):
         # the last retire may still be under way: its pass ends first
         await engine.stop()
         bs = cfg.block_size
-        caches = [np.asarray(a) for layer in engine.runner.kv_caches
-                  for a in layer]
+        caches = [a for layer in engine.runner.kv_caches
+                  for a in slot_rows(layer)]
         state = {}
         for key, made in commits.items():
             ends = [end for end, _h, _t in made]
@@ -677,23 +678,27 @@ def test_a_ride_is_one_span_of_two_blocks_for_the_program():
         [(first + masks, [1], 0, samp)], feed=feed).toks)[0]
     np.testing.assert_array_equal(got, want)
     assert (got >= 0).sum() == 1                   # the floor: one row
-    for x, y in zip(jax.tree.leaves(lone.kv_caches),
-                    jax.tree.leaves(ride.kv_caches)):
-        np.testing.assert_allclose(
-            np.asarray(x)[8:16], np.asarray(y)[8:16], atol=2e-5)
+    for one, other in zip(lone.kv_caches, ride.kv_caches):
+        for x, y in zip(slot_rows(one), slot_rows(other)):
+            assert np.abs(x[8:16]).max() > 0
+            np.testing.assert_allclose(x[8:16], y[8:16], atol=2e-5)
 
 
 #: sha256 (16 digits) of the runner's own ladder program (its jaxpr, the
-#: kernels' source locations taken out) at T 32 and T 16 on the tree BEFORE
-#: the ride (commit 89f6afb), with the Pallas kernels: a dense preset, one
-#: with a recurrent state beside a latent layer, and the block program
-#: itself, whose compiled ladder the ride leaves as it was (a 2B span is a
-#: span the program already took). A PR that MEANS to change those programs
-#: regenerates them (``_runner_step_hash`` below, on its parent).
+#: kernels' source locations taken out) at T 32 and T 16, with the Pallas
+#: kernels: a dense preset, one with a recurrent state beside a latent
+#: layer, and the block program itself, whose compiled ladder the ride
+#: leaves as it was (a 2B span is a span the program already took). A PR
+#: that MEANS to change those programs regenerates them
+#: (``_runner_step_hash`` below). ``tiny_ling_test`` is the tree's BEFORE
+#: the ride (commit 89f6afb); the other two were regenerated by PR 59, which
+#: meant to change them: a (k, v) layer's pages are ONE joined array
+#: whatever the dtype (one scatter, one cache operand a layer), and Ling's
+#: latent pair, which stays apart, kept its hashes through it.
 PARENT_RUNNER_HASHES = {
-    "tiny_test": ("55c71453ed8a5e23", "572dbe0b3a317d53"),
+    "tiny_test": ("a4ae601558612106", "e0d1bccee1270484"),
     "tiny_ling_test": ("8435d41fc787258d", "a6b644bfcd10cd29"),
-    "tiny_sdar_test": ("ce23ed6b0ab6bb62", "a51a03e47cc677de"),
+    "tiny_sdar_test": ("d0b44d919e8d354e", "e5c4fe3daf58dfe1"),
 }
 
 

@@ -33,6 +33,7 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.models import llama, moe
 from dynamo_tpu.models.config import PRESETS, ModelConfig
 from dynamo_tpu.runtime.engine import Context
+from stepdrive import slot_rows
 
 pytestmark = pytest.mark.anyio
 
@@ -289,7 +290,7 @@ def test_a_tp_mesh_serves_the_two_pools_as_one_chip_does():
     runner = ModelRunner(
         cfg, mesh=build_mesh(cfg.mesh_shape, devices=jax.devices()[:2]),
         rng_seed=SEED)
-    assert {k.shape[0] for k, _ in runner.kv_caches} == {
+    assert {slot_rows(layer)[0].shape[0] for layer in runner.kv_caches} == {
         n * cfg.block_size for n in cfg.group_num_blocks}
     two = grouped_span.drive(runner, tokens, LENS, 6, 11)
     err = np.abs(two["logits"] - one["logits"]).max() / np.abs(
@@ -535,7 +536,8 @@ def test_groups_cost_the_other_models_nothing():
     assert cfg.group_num_blocks == (32,)
     runner = ModelRunner(cfg, rng_seed=0)
     assert runner._ladder_variant == "plain"
-    assert {k.shape[0] for k, _ in runner.kv_caches} == {32 * 8}
+    assert {slot_rows(layer)[0].shape[0] for layer in runner.kv_caches} == {
+        32 * 8}
     sched = Scheduler(cfg, BlockAllocator(32, 8))
     seq = _sequence(40)
     sched.add(seq)
@@ -649,7 +651,8 @@ def test_a_mixed_model_on_one_table_is_the_parents_program():
     cfg.validate()
     runner = ModelRunner(cfg, rng_seed=0)
     assert runner._ladder_variant == "plain"
-    assert {k.shape[0] for k, _ in runner.kv_caches} == {32 * 8}
+    assert {slot_rows(layer)[0].shape[0] for layer in runner.kv_caches} == {
+        32 * 8}
     sched = Scheduler(cfg, BlockAllocator(32, 8))
     seq = _sequence(60)
     sched.add(seq)

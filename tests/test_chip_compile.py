@@ -21,6 +21,7 @@ run — `python chip_smoke.py` is.
 from __future__ import annotations
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -90,6 +91,36 @@ def _kernel_count(text: str) -> int:
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+_HLO_OP = re.compile(r" = \w+\[([\d,]+)\](?:\{[^}]*\})? ([\w\-]+)\(")
+
+
+def _pool_copies(text: str, kv_heads: int, width: int = D) -> list[str]:
+    """The compiled program's ``copy`` / ``transpose`` ops that produce a
+    whole layer's pool of joined pages (in any view of it)."""
+    pool = NUM_BLOCKS * 2 * BS * kv_heads * width
+    out = []
+    for line in text.splitlines():
+        m = _HLO_OP.search(line)
+        if m and m.group(2) in ("copy", "transpose") and (
+            np.prod([int(x) for x in m.group(1).split(",")]) == pool
+        ):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_pool_copies_reads_an_hlo_line():
+    pool = f"bf16[{NUM_BLOCKS},2,{BS},{KVH},{D}]"
+    lay = "{4,3,2,1,0:T(8,128)(2,1)}"
+    text = "\n".join([
+        f"  %copy.1 = {pool}{lay} copy(%p.1), metadata={{}}",
+        f"  %bitcast.2 = bf16[{NUM_BLOCKS},2,{BS * KVH},{D}]{lay} bitcast(%p.1)",
+        f"  %t.3 = bf16[{NUM_BLOCKS},2,{BS * KVH},{D}] transpose(%p.1)",
+        "  %copy.4 = bf16[256,32,128]{2,1,0} copy(%q)",
+    ])
+    assert [ln.split()[0] for ln in _pool_copies(text, KVH)] == [
+        "%copy.1", "%t.3"]
+
+
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 @pytest.mark.parametrize("T", [16, 256])
 def test_ragged_kernel_compiles_at_served_widths(mosaic, one_chip, T, kv_dtype):
@@ -154,6 +185,38 @@ def test_ragged_kernel_compiles_at_the_cells_chip_shapes(
     assert _kernel_count(compiled.as_text()) == 1
 
 
+@pytest.mark.parametrize(
+    "heads,kv_heads,block,T,window",
+    [
+        (8, 2, 1, 256, 4096),     # mistral-7b-tp4: 16 KiB joined pages
+        (32, 8, 1, 256, 4096),    # one chip's dense cells: 64 KiB
+        (32, 4, 4, 512, 0),       # sdar-30b-a3b: 32 KiB, blocks of 4 rows
+        (128, 8, 1, 1024, 4096),  # command-a-plus: a window layer
+        (128, 8, 1, 1024, 0),     # and a full one
+        (32, 32, 1, 256, 0),      # no GQA: 256 KiB pages, folds of 128 keys
+    ],
+)
+def test_ragged_kernel_compiles_over_joined_pages(
+    mosaic, one_chip, heads, kv_heads, block, T, window
+):
+    """A (k, v) layer's JOINED pages (PR 59) at the cells' per-chip shapes:
+    ONE descriptor a page into one ring of ``2 * NBUF`` rows passes Mosaic
+    within the kernel's VMEM, and the kernel's view of the array, ``[blocks,
+    2, bs * kvH, D]``, costs no copy of the pool (a bitcast)."""
+    i32 = partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    pages = _sds((NUM_BLOCKS, 2, BS, kv_heads, D), jnp.bfloat16, one_chip)
+    lanes = 129
+    compiled = ragged_kernel.ragged_paged_attention_pallas.lower(
+        _sds((T, heads, D), jnp.bfloat16, one_chip), pages, None,
+        i32((lanes, 256)), i32((lanes,)), i32((lanes,)), i32((lanes,)),
+        i32((lanes,)), block_size=BS, window=window, diffusion_block=block,
+    ).compile()
+    text = compiled.as_text()
+    assert _kernel_count(text) == 1
+    assert _pool_copies(text, kv_heads) == []
+    assert " copy(" not in text
+
+
 @pytest.mark.parametrize("with_stats", [False, True])
 def test_kv_sp_decode_kernel_compiles_at_served_widths(
     mosaic, one_chip, with_stats
@@ -189,12 +252,11 @@ def _unified_step_args(cfg: ModelConfig, T: int, sharding_of):
         lambda a, s: _sds(a.shape, a.dtype, sharding_of(s)),
         params, specs,
     )
-    kv_sh = sharding_of(kv_cache_spec(False))
+    # a (k, v) layer's pages as the engine serves a bfloat16 cache: joined
+    # (EngineConfig.cache_form), heads over tp
+    kv_sh = sharding_of(kv_cache_spec(False, form="joined"))
     kv = [
-        (
-            _sds((NUM_BLOCKS * BS, cfg.num_kv_heads, D), jnp.bfloat16, kv_sh),
-            _sds((NUM_BLOCKS * BS, cfg.num_kv_heads, D), jnp.bfloat16, kv_sh),
-        )
+        (_sds((NUM_BLOCKS, 2, BS, cfg.num_kv_heads, D), jnp.bfloat16, kv_sh),)
         for _ in range(cfg.num_layers)
     ]
     i32 = partial(_sds, dtype=jnp.int32, sharding=sharding_of(P()))
@@ -234,12 +296,37 @@ def test_unified_step_1b_compiles_one_chip_and_tp4(mosaic, one_chip, tp4):
     one_text, tp_text = single.as_text(), sharded.as_text()
     assert _kernel_count(one_text) == cfg.num_layers
     assert _kernel_count(tp_text) == cfg.num_layers
+    # the kernel's view of the joined pages is a bitcast, the write one
+    # scatter in place: nothing copies a layer's pool
+    assert _pool_copies(one_text, cfg.num_kv_heads) == []
+    assert _pool_copies(tp_text, cfg.num_kv_heads // 4) == []
     assert "all-reduce" in tp_text and "all-reduce" not in one_text
     one_bytes = single.memory_analysis().argument_size_in_bytes
     tp_bytes = sharded.memory_analysis().argument_size_in_bytes
     # Weights + KV of the 1B at these sizes are ~4.6 GB on one chip.
     assert 4.0e9 < one_bytes < 5.5e9, one_bytes
     assert 0.2 < tp_bytes / one_bytes < 0.3, (tp_bytes, one_bytes)
+
+
+@pytest.mark.parametrize("T", [1024, 512])
+def test_joined_write_lays_no_pool_out_anew_at_the_wide_rungs(
+    mosaic, one_chip, T
+):
+    """The joined pages' write at the budgets the long-context cells run
+    (PR 59): indexed on the pages' own (block, row) axes, XLA's scatter at
+    1,024 rows chose a layout of its own for the WHOLE pool and copied it
+    in and out a layer (3.6 GB of temporaries in the Command A+ step);
+    as rows of ``[blocks * 2 * bs, kvH, D]`` it is the two arrays' row
+    scatter and writes in place."""
+    cfg = ModelConfig.llama32_1b().scaled(num_layers=2)
+    compiled = _compile_unified(
+        cfg, T, AttnDispatch(use_pallas=True), lambda _spec: one_chip
+    )
+    text = compiled.as_text()
+    assert _kernel_count(text) == cfg.num_layers
+    assert _pool_copies(text, cfg.num_kv_heads) == []
+    pool = NUM_BLOCKS * 2 * BS * cfg.num_kv_heads * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool
 
 
 def test_sdar_block_step_compiles_at_published_widths(
@@ -379,8 +466,9 @@ def test_nemotron_share_step_compiles_at_published_widths(
     )
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
     lanes, rows = 129, 132
-    page = sds((65536 * BS, 2, 128), jnp.bfloat16)
-    kv = [(page, page) if cfg.layer_kind(li) == "attn" else ()
+    # the one attention layer's pages, joined (EngineConfig.cache_form)
+    pages = sds((65536, 2, BS, 2, 128), jnp.bfloat16)
+    kv = [(pages,) if cfg.layer_kind(li) == "attn" else ()
           for li in range(cfg.num_layers)]
     rec = [
         tuple(sds(shape, dt) for shape, dt in
@@ -477,11 +565,13 @@ def test_command_a_share_step_compiles_at_published_widths(
         lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
-    kv = []
-    for li in range(cfg.num_layers):
-        page = sds((pools[cfg.layer_cache_group(li)] * BS, 8, 128),
-                   jnp.bfloat16)
-        kv.append((page, page))
+    assert ecfg.cache_form == "joined"
+    # a layer's pages are its group's pool, K and V of a block one page
+    kv = [
+        (sds((pools[cfg.layer_cache_group(li)], 2, BS, 8, 128),
+             jnp.bfloat16),)
+        for li in range(cfg.num_layers)
+    ]
     i32 = partial(sds, dtype=jnp.int32)
     T, rows, MB = 1024, 52, ecfg.max_blocks_per_seq
     meta = (
@@ -499,6 +589,11 @@ def test_command_a_share_step_compiles_at_published_widths(
         params, kv, *meta).compile()
     # four layers x (the ragged kernel + gate, up, down)
     assert _kernel_count(compiled.as_text()) == 4 * 4
+    # neither pool is laid out anew around its write
+    assert [
+        ln for ln in compiled.as_text().splitlines()
+        if " copy(" in ln and ",2,16,8,128]" in ln
+    ] == []
     mem = compiled.memory_analysis()
     # weights 9.47 GB, the window pool 3.04 GB, the full pool 2.36 GB
     assert 14.6e9 < mem.argument_size_in_bytes < 15.1e9

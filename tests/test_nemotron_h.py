@@ -90,10 +90,10 @@ def test_runner_logits_equal_the_references_forward_pass(
         engine_config(ModelConfig.tiny_nemotron_h_test(held=held), **longer),
         rng_seed=SEED)
     assert runner.attention_path == ("pallas" if pallas == "1" else "xla")
-    # one layer in seven pages: 2 arrays x 2 heads x 16 (lane-padded to 128
-    # on the Pallas path) x 4 B
+    # one layer in seven pages: 2 entries x 2 heads x 16 (lane-padded to 128
+    # on the Pallas path) x 4 B, in ONE array of joined pages
     assert runner.kv_bytes_per_token == 2 * 2 * (128 if pallas == "1" else 16) * 4
-    assert [len(c) for c in runner.kv_caches] == [0, 0, 0, 2, 0, 0, 0]
+    assert [len(c) for c in runner.kv_caches] == [0, 0, 0, 1, 0, 0, 0]
     assert len(runner.rec_state) == 3
     pad = 64 * -(-(max(lens) + 6) // 64)
     tokens = check.sample_tokens(11, 384, [n + 6 for n in lens], pad)
@@ -478,7 +478,7 @@ async def test_engine_serves_lanes_that_join_and_leave():
         assert snap["recurrent_state_bytes"] == 3 * 3 * (
             8 * 8 * 16 * 4 + 3 * (64 + 2 * 2 * 16) * 4)
         assert snap["kv_bytes_per_token"] == 2 * 2 * 16 * 4
-        assert snap["kv_cache_arrays_per_layer"] == 2
+        assert snap["kv_cache_arrays_per_layer"] == 1   # joined pages
         assert snap["ssd_chunk_tiles_total"] == sum(
             r["ssd_chunk_tiles"] for r in steps) > 0
         assert snap["ssd_chunk_rows_total"] == sum(

@@ -14,7 +14,9 @@ records cannot split:
 - ``split``: the context varied at fixed spans, then the spans varied at a
   fixed context; a least-squares fit of both gives the cost a span (grid
   step, q in, output out, a cold ring) and a page (DMA issue and wait,
-  the fold's arithmetic), beside what their bytes would take;
+  the fold's arithmetic), beside what their bytes would take and the
+  descriptors a page takes (``descriptors_per_page``: 1 where the
+  shape's pages are joined or held once, 2 where K and V are apart);
 - ``ladder``: the ring depth and pages a fold (``ring_shape`` in
   ``ops/pallas/ragged_attention.py``), overridden point by point;
 - ``ops``: one traced chain, device time an op a layer: the kernel as the
@@ -35,6 +37,15 @@ records cannot split:
 
 (``--kernel-file``: another checkout's kernel, so that parent and change are
 read in one call on one chip.)
+
+A shape's cache is built in the form the engine would give it (the
+engine's own rule, ``engine/config.py`` ``cache_form_of``, asked by
+``cache_form`` below): a (k, v) layer's pages JOINED, one array and one descriptor a
+page; ``once`` for a latent held once (``dsv2``); K and V apart for the
+latent layer that still stores its latent twice (``mla``), under ``--form
+apart`` (this kernel's two-stream path beside its joined one), and for a
+``--kernel-file`` that predates the joined form (PR 58's and older: the
+same keys and values in two arrays).
 
 A line of JSON a measurement on stdout, all of them in
 ``chiprun_out/ragged_kernel_bench.jsonl``. Times are host clock around
@@ -68,6 +79,8 @@ from chipbench.costs.latent_once_paged_attention import (
 from chipbench.costs.ragged_paged_attention import cost
 from chipbench.costs.window_full_paged_attention import one_layer
 from chipbench.peaks import peaks_for
+from dynamo_tpu.engine.config import cache_form_of
+from dynamo_tpu.ops.attention import join_pages
 from dynamo_tpu.ops.pallas import latent_expanded
 from dynamo_tpu.ops.pallas import ragged_attention as ragged_kernel
 
@@ -194,11 +207,38 @@ def build(shape: dict, contexts: np.ndarray, prefill_ctx: int, rng):
     # a latent held once has no V: the values are K's leading columns
     v = None if shape.get("once") else jax.random.normal(
         kv, cshape, jnp.bfloat16)
+    if cache_form(shape) == "joined":
+        # the SAME keys and values as ONE array of joined pages
+        k, v = join_pages(k, v, BS), None
     meta = tuple(
         jnp.asarray(a)
         for a in (tables, q_start, q_len, q_start + q_len, row_start)
     )
     return q, k, v, meta, spans
+
+
+#: ``--form``: "engine" builds each shape's cache as the engine would
+#: (``cache_form_of``); "apart" keeps K and
+#: V an array each, for this kernel's two-stream path beside its joined one.
+FORM = "engine"
+
+
+def cache_form(shape: dict) -> str:
+    """The form ``build`` gives a shape's cache: the engine's own rule
+    (``engine/config.py`` ``cache_form_of``) over what the shape shows (a
+    latent held once says ``once``; a latent layer that stores its latent
+    twice, ``D`` of its own at one cached head, is Ling's), unless
+    ``--form apart`` asks for this kernel's two streams or the kernel under
+    measurement predates joined pages (a parent's ``--kernel-file``)."""
+    form = cache_form_of(
+        entries=1 if shape.get("once") else 2, pool=True,
+        latent=shape["kvH"] == 1 and "D" in shape, kv_quant=None,
+        kv_sp=False,
+    )
+    reads_joined = hasattr(kernel_mod, "page_form")
+    if form == "joined" and (FORM == "apart" or not reads_joined):
+        return "apart"
+    return form
 
 
 def bound_us(shape: dict, spans) -> tuple[float, float]:
@@ -326,7 +366,8 @@ def measure(name: str, what: str, shape: dict, contexts, prefill_ctx: int,
         bytes_bound_us_per_span=round(bytes_us / len(spans), 3),
         flops_bound_us=round(flops_us, 2),
         roofline_pct=round(100 * max(bytes_us, flops_us) / us, 2),
-        device=jax.devices()[0].device_kind, **extra,
+        device=jax.devices()[0].device_kind, cache_form=cache_form(shape),
+        **extra,
     )
     if args.kernel_file:
         line["kernel_file"] = args.kernel_file
@@ -570,8 +611,14 @@ def sweep_split(name, shape, args, rng):
         lines.append(measure(
             name, "spans", lanes_only, np.full(lanes, args.fit_ctx), 0,
             args, rng, ctx=args.fit_ctx))
-    page_bytes = 2 * BS * shape["kvH"] * width(shape) * 2  # K and V
+    form = cache_form(shape)
+    # a page as the fit counts it: a block's K and V (its K alone where
+    # the latent is held once), in one descriptor or in two
+    page_bytes = (1 if form == "once" else 2) * BS * shape["kvH"] * width(
+        shape) * 2
     emit(dict(shape=name, sweep="fit", **fit(lines),
+              cache_form=form, kernel_file=args.kernel_file,
+              descriptors_per_page=2 if form == "apart" else 1,
               page_bytes=page_bytes,
               bytes_bound_us_per_page=round(
                   1e6 * page_bytes / chip_peaks()["hbm_bytes_per_s"], 4)))
@@ -664,6 +711,9 @@ def main(argv=None) -> int:
     ap.add_argument("--kernel-file", default=None, metavar="PATH",
                     help="measure the kernel of this file (another "
                     "checkout's ops/pallas/ragged_attention.py)")
+    ap.add_argument("--form", default="engine", choices=("engine", "apart"),
+                    help="the cache each shape is built as: the engine's "
+                    "choice, or K and V apart (this kernel's two streams)")
     ap.add_argument("--set", action="append", default=[],
                     metavar="NAME=INT", help="override a kernel constant")
     ap.add_argument("--deadline", type=int, default=1200,
@@ -679,6 +729,8 @@ def main(argv=None) -> int:
     watchdog = threading.Timer(args.deadline, lambda: os._exit(3))
     watchdog.daemon = True
     watchdog.start()
+    global FORM
+    FORM = args.form
     if args.kernel_file:
         global kernel_mod
         spec = importlib.util.spec_from_file_location(
