@@ -117,10 +117,16 @@ class TpuEngine:
         #: The model keeps a recurrent state beside the paged cache
         #: (docs/architecture/unified_step.md "State that is not pages").
         self._rec_on = cfg.model.has_recurrent
-        #: the kind of its recurrent layers ("kda" | "retention" | "ssd")
+        #: the kind of its recurrent layers ("kda" | "retention" | "ssd" |
+        #: "conv")
         self._rec_kind = (
             cfg.model.layer_kind(cfg.model.recurrent_layers[0])
             if self._rec_on else ""
+        )
+        #: its layers whose mixer is a gated short convolution
+        self._conv_layers = sum(
+            cfg.model.layer_kind(li) == "conv"
+            for li in cfg.model.recurrent_layers
         )
         #: what the delta rule's chunk kernel served: tiles, and the rows
         #: in them (rows / (tiles x its tile) is how full the tiles run)
@@ -1489,7 +1495,12 @@ class TpuEngine:
             lanes=len(roles),
             folds=folds,
         )
-        if self._rec_on:
+        if self._conv_layers:
+            # Gated short convolutions: no kernel of their own and no split
+            # by span length, so every row fed goes through every such
+            # layer alike (rows x those layers).
+            note["conv_rows"] = (n_dec + n_pre) * self._conv_layers
+        elif self._rec_on:
             # What the state table saw, under its layers' kind.
             kind = self._rec_kind
             lanes = sum(r[3] == 1 for r in roles)
@@ -3092,6 +3103,11 @@ class TpuEngine:
             "kv_bytes_per_token": getattr(
                 self.runner, "kv_bytes_per_token", 0
             ),
+            # The share of a stored page that is lane padding (0-1): a half
+            # where 64-wide heads are stored 128 wide for the kernel.
+            "kv_cache_lane_pad_perc": getattr(
+                self.runner, "kv_cache_lane_pad", 0.0
+            ),
             "kv_reused_device_blocks_total": self._reused_device_blocks,
             "kv_reused_host_blocks_total": self._reused_host_blocks,
             "kv_reused_disk_blocks_total": self._reused_disk_blocks,
@@ -3198,6 +3214,9 @@ class TpuEngine:
             ),
             "recurrent_state_bytes": getattr(
                 self.runner, "recurrent_state_bytes", 0
+            ),
+            "recurrent_state_bytes_per_slot": getattr(
+                self.runner, "recurrent_state_bytes_per_slot", 0
             ),
             # slots a sequence owns / slots (the trash slot left out)
             "recurrent_state_usage_perc": (

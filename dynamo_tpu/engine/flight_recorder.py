@@ -218,6 +218,7 @@ class FlightRecorder:
         ssd_chunk_rows: int = 0,
         ssd_chunk_tiles: int = 0,
         ssd_fresh_spans: int = 0,
+        conv_rows: int = 0,
         kv_full_blocks: int = 0,
         kv_window_blocks: int = 0,
         kv_window_released: int = 0,
@@ -269,7 +270,10 @@ class FlightRecorder:
         are a state-space (Mamba-2) model's: the lanes of one row
         (``ssd_recurrent``), the rows of the longer spans and the tiles of
         ``ops/pallas/ssd.py`` ``TILE`` rows they filled (``ssd_chunk``),
-        the spans that started from zeros. The five ``kv_`` /
+        the spans that started from zeros. ``conv_rows`` is a model's whose
+        recurrent layers are gated short convolutions (LFM2): the rows fed
+        times those layers (no kernel of its own to count tiles of). The
+        five ``kv_`` /
         ``context_`` fields are a model's that keeps its cache by layer
         group (docs/architecture/cache_groups.md): blocks in use in the
         full-attention and in the windowed pools as the step is noted,
@@ -328,6 +332,7 @@ class FlightRecorder:
             "ssd_chunk_rows": ssd_chunk_rows,
             "ssd_chunk_tiles": ssd_chunk_tiles,
             "ssd_fresh_spans": ssd_fresh_spans,
+            "conv_rows": conv_rows,
             "kv_full_blocks": kv_full_blocks,
             "kv_window_blocks": kv_window_blocks,
             "kv_window_released": kv_window_released,

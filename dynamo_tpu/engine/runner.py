@@ -688,6 +688,14 @@ class ModelRunner(WarmupPlanMixin):
         self.kv_arrays_per_layer = max(
             (len(arrays) for arrays in kv_caches), default=m.cache_arrays
         )
+        #: The share of a stored page that is lane padding (0-1): a head
+        #: narrower than the kernel's lane row is stored a whole row wide
+        #: (ops/pallas/attention.py ``cache_head_dim``), the rest zeros.
+        #: 0 wherever the head fills its row, and where no layer pages.
+        self.kv_cache_lane_pad = (
+            1.0 - m.kv_cache_head_dim / self.cache_head_dim
+            if m.has_pool else 0.0
+        )
         # The state that is not pages (docs/architecture/unified_step.md):
         # for each recurrent layer the arrays its kind keeps
         # (``ModelConfig.recurrent_state_arrays``: a delta-rule layer's
@@ -711,6 +719,10 @@ class ModelRunner(WarmupPlanMixin):
         #: that keeps keys and values only); fixed at construction.
         self.recurrent_state_bytes = m.recurrent_state_bytes(
             cfg.max_num_seqs + 1, self.dtype.name
+        )
+        #: and what ONE sequence's slot holds over all its layers
+        self.recurrent_state_bytes_per_slot = m.recurrent_state_bytes(
+            1, self.dtype.name
         )
         self._step = 0
         # Weight-quant observability (DT011 surfaces read these via

@@ -224,6 +224,7 @@ class HttpService:
                 "moe_grouped_rows_total",
                 "recurrent_state_slots_in_use",
                 "recurrent_state_bytes",
+                "recurrent_state_bytes_per_slot",
                 "recurrent_state_usage_perc",
                 "kda_chunk_tiles_total",
                 "kda_chunk_rows_total",

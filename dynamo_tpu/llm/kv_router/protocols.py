@@ -61,6 +61,7 @@ class ForwardPassMetrics:
     # State that is not pages (zero on models without recurrent layers).
     recurrent_state_slots_in_use: int = 0
     recurrent_state_bytes: int = 0
+    recurrent_state_bytes_per_slot: int = 0
     recurrent_state_usage_perc: float = 0.0
     batch_fill_ratio: float = 0.0
     # SLO-aware co-location (engine/coloc.py; ROADMAP #3): the live

@@ -48,6 +48,7 @@ _GAUGES = (
     ("moe_grouped_rows_total", "Routed rows through the grouped expert path"),
     ("recurrent_state_slots_in_use", "Recurrent-state slots a sequence owns"),
     ("recurrent_state_bytes", "Recurrent (linear-attention or retention) state resident on the device, bytes"),
+    ("recurrent_state_bytes_per_slot", "Recurrent state one sequence's slot holds over all its layers, bytes"),
     ("recurrent_state_usage_perc", "Recurrent-state slots a sequence owns over the slots there are (0-1)"),
     ("batch_fill_ratio", "Unified batch fill (real tokens / budget)"),
     ("coloc_quantum", "Live prefill quantum (coloc controller)"),

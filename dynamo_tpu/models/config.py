@@ -35,7 +35,8 @@ class LayerSpec:
     that takes a layer index has its field (tests/test_layer_spec.py fails
     by name on one that has not), and no index reaches the body."""
 
-    kind: str            # layer_kind: "attn" | "kda" | "retention" | "ssd" | "none"
+    kind: str            # layer_kind: "attn" | "kda" | "retention" | "ssd" |
+    #                      "conv" | "none"
     cache_arrays: int    # layer_cache_arrays: 2 = (k, v), 1 = the latent once
     #                      (entries a token; how many ARRAYS hold a (k, v)
     #                      layer's is EngineConfig.cache_form's to say)
@@ -229,6 +230,25 @@ class ModelConfig:
     ssm_state_size: int = 0
     moe_latent_size: int = 0
     moe_shared_expert_intermediate_size: int = 0
+    # --- a gated short convolution as a layer's mixer (LFM2 family,
+    # model_type lfm2_moe; docs/architecture/unified_step.md "A convolution
+    # mixer: a state that is a tail alone") --- layer_types: the PUBLISHED
+    # list, whole, "conv" or "full_attention" a layer (num_layers takes its
+    # head, so a cut never rewrites it); () = the layer's kind is said
+    # otherwise. A "conv" layer's mixer is ``C * conv(B * u)`` behind one
+    # projection D -> 3 D and in front of one D -> D: a depthwise causal
+    # convolution of linear_conv_kernel taps (HF conv_L_cache), no bias, no
+    # activation. Its ONLY state is the last linear_conv_kernel - 1 rows of
+    # ``B * u`` a sequence, in the served dtype: no float32 state and no
+    # recurrence kernel. Every layer is a mixer and a feed-forward part.
+    # use_expert_bias: the sigmoid router's selection bias is a trained
+    # buffer (seeded weights DRAW it, models/llama.py EXPERT_BIAS_INIT_STD,
+    # so that ranking by s + b and weighing by s differ).
+    # norm_topk_eps e > 0: the chosen experts' weights are s / (sum s + e)
+    # (the family's modelling code adds 1e-6); 0 = s / sum s.
+    layer_types: tuple = ()
+    use_expert_bias: bool = False
+    norm_topk_eps: float = 0.0
 
     @property
     def is_moe(self) -> bool:
@@ -241,12 +261,15 @@ class ModelConfig:
 
     def layer_kind(self, layer_idx: int) -> str:
         """"kda" for a delta-rule linear-attention layer, "retention" for a
-        power-retention layer and "ssd" for a Mamba-2 state-space layer
-        (``RECURRENT_KINDS``: they keep a recurrent state), "attn" for one
+        power-retention layer, "ssd" for a Mamba-2 state-space layer and
+        "conv" for a gated short convolution (``RECURRENT_KINDS``: they
+        keep a state a sequence in the state table), "attn" for one
         that reads keys and values through the paged cache, "none" for a
         layer that is a feed-forward part alone (it owns neither)."""
         if self.layer_pattern:
             return PATTERN_KINDS[self.layer_pattern[layer_idx]][0]
+        if self.layer_types:
+            return "conv" if self.layer_types[layer_idx] == "conv" else "attn"
         if self.retention_degree:
             return "retention"
         if self.layer_group_size and (layer_idx + 1) % self.layer_group_size:
@@ -284,9 +307,15 @@ class ModelConfig:
         float32, and of ``phi(k)``, ``[N, kvH, R (padded to 8), d]``
         float32 (ops/power_retention.py ``state_shapes``). "ssd": the state
         ``[N, H, P, n]`` float32 and the convolution's tail ``[N, K - 1,
-        H P + 2 G n]`` (ops/ssd.py)."""
+        H P + 2 G n]`` (ops/ssd.py). "conv": ONE array, the tail ``[N, K -
+        1, hidden]`` in the served dtype: the layer has no other state."""
         kind = self.layer_kind(layer_idx)
         H, d = self.num_heads, self.head_dim
+        if kind == "conv":
+            return ((
+                (n_slots, self.linear_conv_kernel - 1, self.hidden_size),
+                dtype,
+            ),)
         if kind == "ssd":
             return (
                 ((n_slots, self.mamba_num_heads, self.mamba_head_dim,
@@ -504,6 +533,8 @@ class ModelConfig:
             return ModelConfig._from_hf_brumby(cfg)
         if cfg.get("model_type") == "nemotron_h":
             return ModelConfig._from_hf_nemotron_h(cfg)
+        if cfg.get("model_type") == "lfm2_moe":
+            return ModelConfig._from_hf_lfm2_moe(cfg)
         return ModelConfig(
             name=cfg.get("model_type", "llama"),
             vocab_size=cfg["vocab_size"],
@@ -691,6 +722,64 @@ class ModelConfig:
             linear_conv_kernel=cfg["conv_kernel"],
             nope_full_layers=True,
             embed_init_std=1.0,
+        )
+
+    @staticmethod
+    def _from_hf_lfm2_moe(cfg: dict) -> "ModelConfig":
+        """HF ``lfm2_moe`` config.json (LFM2-24B-A2B) -> ModelConfig: by the
+        published ``layer_types`` a layer's mixer is a gated short
+        convolution or GQA with per-head q/k norms; behind
+        ``num_dense_layers`` dense SwiGLU layers every FFN is sigmoid-scored
+        experts (``use_expert_bias``: the bias ranks, the unbiased scores
+        weigh, over their sum + 1e-6), no shared expert. The head width is
+        hidden_size / num_attention_heads where the config gives none, and
+        the embedding is tied where it has no key, as the family's released
+        configs have both."""
+        types = tuple(cfg["layer_types"])
+        rope = cfg.get("rope_parameters") or {}
+        unserved = {
+            "a layer type outside conv | full_attention":
+                bool(set(types) - {"conv", "full_attention"}),
+            "layer_types shorter than num_hidden_layers":
+                len(types) < cfg["num_hidden_layers"],
+            "conv_bias": cfg.get("conv_bias"),
+            "a rope_type that is not default":
+                rope.get("rope_type", "default") != "default",
+            "a shared expert": cfg.get("n_shared_experts"),
+        }
+        for what, on in unserved.items():
+            if on:
+                raise NotImplementedError(
+                    f"lfm2_moe with {what} is not implemented")
+        return ModelConfig(
+            name=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim")
+            or cfg["hidden_size"] // cfg["num_attention_heads"],
+            rope_theta=float(
+                rope.get("rope_theta", cfg.get("rope_theta", 1000000.0))),
+            rms_eps=cfg.get("norm_eps", 1e-5),
+            max_position=cfg.get("max_position_embeddings", 128000),
+            tie_word_embeddings=cfg.get(
+                "tie_word_embeddings", cfg.get("tie_embedding", True)),
+            qk_norm=True,
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            first_k_dense_replace=cfg.get("num_dense_layers", 0) or 0,
+            gating="sigmoid",
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            norm_topk_eps=1e-6,
+            routed_scaling_factor=float(
+                cfg.get("routed_scaling_factor", 1.0)),
+            use_expert_bias=bool(cfg.get("use_expert_bias", True)),
+            layer_types=types,
+            linear_conv_kernel=cfg["conv_L_cache"],
         )
 
     @staticmethod
@@ -1526,6 +1615,92 @@ class ModelConfig:
         )
 
     @staticmethod
+    def lfm2_24b_a2b() -> "ModelConfig":
+        """LFM2-24B-A2B (HF LiquidAI/LFM2-24B-A2B config.json, model_type
+        lfm2_moe): 40 layers, 2,048 wide, each a mixer and a feed-forward
+        part. By the published ``layer_types`` 30 mixers are gated 3-tap
+        convolutions (a 2,048 -> 6,144 projection to ``[B | C | u]``, a
+        depthwise causal convolution over ``B * u``, gated by ``C``, a
+        2,048 -> 2,048 projection out) and 10 (layers 2, 6, .. 38) are 32
+        query heads over 8 cached heads of 64 with per-head q/k RMSNorm and
+        rotary embedding at 1e6. Layers 0 and 1 keep a SwiGLU MLP of
+        11,776; from layer 2 on, 64 sigmoid-scored experts of 1,536, 4 a
+        token chosen by score + bias and weighted by the scores over their
+        sum + 1e-6, no shared expert. The head width (the config has no
+        key: 2,048 / 32) and the tied embedding are the family's released
+        configs'."""
+        return ModelConfig(
+            name="lfm2-24b-a2b",
+            vocab_size=65536,
+            hidden_size=2048,
+            intermediate_size=11776,
+            num_layers=40,
+            num_heads=32,
+            num_kv_heads=8,
+            head_dim=64,
+            rope_theta=1000000.0,
+            rms_eps=1e-5,
+            max_position=128000,
+            tie_word_embeddings=True,
+            qk_norm=True,
+            num_experts=64,
+            num_experts_per_tok=4,
+            moe_intermediate_size=1536,
+            first_k_dense_replace=2,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            norm_topk_eps=1e-6,
+            routed_scaling_factor=1.0,
+            use_expert_bias=True,
+            layer_types=LFM2_24B_LAYER_TYPES,
+            linear_conv_kernel=3,
+        )
+
+    @staticmethod
+    def lfm2_24b_a2b_l10() -> "ModelConfig":
+        """The first ten layers of LFM2-24B-A2B (``conv conv attn conv conv
+        conv attn conv conv conv``: both dense layers, then two whole
+        periods of 4): every expert and the whole vocabulary, the first of
+        four pipeline stages on one chip."""
+        return ModelConfig.lfm2_24b_a2b().scaled(
+            name="lfm2-24b-a2b-l10", num_layers=10)
+
+    @staticmethod
+    def tiny_lfm2_test(vocab_size: int = 384) -> "ModelConfig":
+        """Hermetic LFM2-style test model: seven layers of the published
+        list's head (``conv conv attn conv conv conv attn``: two dense
+        layers and a period, with the next period's attention layer), 4
+        query heads over 2 cached heads of 16 (under a lane row: the padded
+        cache wherever the kernel serves), 16 experts (the grouped path),
+        4 a token."""
+        return ModelConfig(
+            name="tiny-lfm2-test",
+            vocab_size=vocab_size,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=7,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=1000000.0,
+            rms_eps=1e-5,
+            max_position=512,
+            tie_word_embeddings=True,
+            qk_norm=True,
+            num_experts=16,
+            num_experts_per_tok=4,
+            moe_intermediate_size=32,
+            first_k_dense_replace=2,
+            gating="sigmoid",
+            norm_topk_prob=True,
+            norm_topk_eps=1e-6,
+            routed_scaling_factor=1.0,
+            use_expert_bias=True,
+            layer_types=LFM2_24B_LAYER_TYPES,
+            linear_conv_kernel=3,
+        )
+
+    @staticmethod
     def command_a_plus() -> "ModelConfig":
         """Command A+ 05-2026 (HF CohereLabs/command-a-plus-05-2026
         config.json, model_type cohere2_moe; 218B-A25B): 32 parallel-block
@@ -1715,7 +1890,7 @@ class ModelConfig:
 
 
 #: the layer kinds that keep a recurrent state in the state table
-RECURRENT_KINDS = ("kda", "retention", "ssd")
+RECURRENT_KINDS = ("kda", "retention", "ssd", "conv")
 #: a letter of ``ModelConfig.layer_pattern`` -> (layer_kind, layer_ffn)
 PATTERN_KINDS = {
     "M": ("ssd", "none"), "*": ("attn", "none"),
@@ -1725,6 +1900,12 @@ PATTERN_KINDS = {
 NEMOTRON_3_SUPER_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
     "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+)
+
+#: LFM2-24B-A2B's ``layer_types``, 40 entries as published: attention at
+#: layers 2, 6, .. 38, a gated short convolution everywhere else
+LFM2_24B_LAYER_TYPES = tuple(
+    "full_attention" if li % 4 == 2 else "conv" for li in range(40)
 )
 
 #: SDAR's generation settings (its generate script's defaults; the
@@ -1766,6 +1947,9 @@ PRESETS = {
     "nemotron-3-super": ModelConfig.nemotron_3_super,
     "nemotron-3-super-ep4-l11": ModelConfig.nemotron_3_super_ep4_l11,
     "tiny-nemotron-h-test": ModelConfig.tiny_nemotron_h_test,
+    "lfm2-24b-a2b": ModelConfig.lfm2_24b_a2b,
+    "lfm2-24b-a2b-l10": ModelConfig.lfm2_24b_a2b_l10,
+    "tiny-lfm2-test": ModelConfig.tiny_lfm2_test,
     "brumby-14b": ModelConfig.brumby_14b,
     "tiny-brumby-test": ModelConfig.tiny_brumby_test,
 }

@@ -193,11 +193,14 @@ def init_layer_params(
     if cfg.layer_pattern:
         return _init_one_part_layer(key, cfg, li, dtype)
     kda = cfg.layer_kind(li) == "kda"
+    conv = cfg.layer_kind(li) == "conv"
     # (A linear-attention layer draws more matrices than 16 keys hold; the
     # other kinds keep the split their weights have always come from.)
     keys = iter(jax.random.split(key, 24 if kda else 16))
     if kda:
         layer = _init_kda_mixer(keys, cfg, dtype)
+    elif conv:
+        layer = _init_conv_mixer(keys, cfg, dtype)
     elif cfg.is_mla:
         # DeepSeek-V2/V3 MLA: latent KV compression (kv_lora_rank)
         # plus a decoupled roped path (qk_rope_head_dim); see
@@ -249,7 +252,12 @@ def init_layer_params(
         E, Eh = cfg.num_experts, cfg.experts_here
         Im = cfg.moe_intermediate_size or I
         layer["w_router"] = dense(next(keys), (D, E))
-        if cfg.gating == "sigmoid":
+        if cfg.use_expert_bias:
+            # A trained buffer: drawn, so that a seeded model ranks by
+            # score + bias and weighs by the score, two different orders.
+            layer["router_bias"] = EXPERT_BIAS_INIT_STD * jax.random.normal(
+                next(keys), (E,), jnp.float32)
+        elif cfg.gating == "sigmoid":
             layer["router_bias"] = jnp.zeros((E,), jnp.float32)
         layer["w_gate"] = _dense3(next(keys), (Eh, D, Im), D, dtype)
         layer["w_up"] = _dense3(next(keys), (Eh, D, Im), D, dtype)
@@ -263,11 +271,11 @@ def init_layer_params(
         layer["w_gate"] = dense(next(keys), (D, I))
         layer["w_up"] = dense(next(keys), (D, I))
         layer["w_down"] = dense(next(keys), (I, D))
-    if cfg.qkv_bias and not kda:
+    if cfg.qkv_bias and not (kda or conv):
         layer["bq"] = jnp.zeros((H * hd,), dtype)
         layer["bk"] = jnp.zeros((kvH * hd,), dtype)
         layer["bv"] = jnp.zeros((kvH * hd,), dtype)
-    if cfg.qk_norm and not kda:
+    if cfg.qk_norm and not (kda or conv):
         layer["ln_q_head"] = norm_init((hd,))
         layer["ln_k_head"] = norm_init((hd,))
     return layer
@@ -345,6 +353,26 @@ def _init_ssd_mixer(keys, cfg: ModelConfig, dtype) -> Params:
     layer["w_out"] = _dense_init(next(keys), (di, D), dtype)
     layer["ln_ssd"] = jnp.ones((di,), dtype)
     return layer
+
+
+#: The deviation a seeded model draws a router's trained selection bias at
+#: (``ModelConfig.use_expert_bias``): scores are sigmoids in (0, 1), so a
+#: tenth moves the ranking of near neighbours and leaves most choices.
+EXPERT_BIAS_INIT_STD = 0.1
+
+
+def _init_conv_mixer(keys, cfg: ModelConfig, dtype) -> Params:
+    """A gated short convolution's mixer and its layer's two norms: the
+    in-projection to ``[B | C | u]``, the depthwise taps ``[K, D]`` (no
+    bias), the out-projection."""
+    D, K = cfg.hidden_size, cfg.linear_conv_kernel
+    return {
+        "w_in": _dense_init(next(keys), (D, 3 * D), dtype),
+        "conv_w": _dense_init(next(keys), (K, D), dtype),
+        "w_out": _dense_init(next(keys), (D, D), dtype),
+        "ln_attn": jnp.ones((D,), dtype),
+        "ln_mlp": jnp.ones((D,), dtype),
+    }
 
 
 def retention_gate_bias(kv_heads: int) -> jnp.ndarray:
@@ -582,6 +610,7 @@ def _moe_mlp(
         num_experts_per_tok=cfg.num_experts_per_tok,
         gating=cfg.gating,
         norm_topk_prob=cfg.norm_topk_prob,
+        norm_topk_eps=cfg.norm_topk_eps,
         routed_scaling_factor=cfg.routed_scaling_factor,
         n_group=cfg.n_group,
         topk_group=cfg.topk_group,
@@ -706,6 +735,32 @@ def _ssd_mixer(
     return qdot(y.astype(h.dtype), layer["w_out"]), (S, tail)
 
 
+def _conv_mixer(
+    layer: Params, h: jnp.ndarray, cfg: ModelConfig, state, meta,
+    state_slot, use_pallas: bool,
+):
+    """A gated short convolution's mixer over the flat ragged batch
+    (LFM2): ``h`` [T, D] normed rows -> (y [T, D], the layer's new state).
+    ``[B | C | u] = h W_in``; ``a = B * u``; ``v`` the depthwise causal
+    convolution of ``a`` (no bias, no activation), continued from the
+    slot's tail; ``y = (C * v) W_out``. ``state`` is ONE array, the tail
+    ``[N+1, K-1, D]``: the last ``K - 1`` rows of ``a`` before each slot's
+    next position, in the served dtype (what the convolution reads of a
+    row inside a span it reads of the tail across a dispatch boundary).
+    The convolution and the two gates run in XLA: ``use_pallas`` decides
+    nothing here."""
+    from dynamo_tpu.ops.linear_attention import causal_conv
+
+    (tail,) = state
+    with jax.named_scope("in_proj"):
+        B, C, u = jnp.split(qdot(h, layer["w_in"]), 3, axis=-1)
+    with jax.named_scope("conv"):
+        v, tail = causal_conv(B * u, layer["conv_w"], tail, *meta, state_slot)
+        y = (C.astype(jnp.float32) * v).astype(h.dtype)
+    with jax.named_scope("out_proj"):
+        return qdot(y, layer["w_out"]), (tail,)
+
+
 def _retention_inputs(layer: Params, h: jnp.ndarray, cfg: ModelConfig,
                       spec: LayerSpec, positions):
     """A power-retention layer's (q, k, v, log gate) from its normed rows:
@@ -824,7 +879,7 @@ def _layer_rows(
     if spec.kind != "attn":
         # A recurrent layer: its state in and out, no pages.
         mixer = {
-            "kda": _kda_mixer, "ssd": _ssd_mixer,
+            "kda": _kda_mixer, "ssd": _ssd_mixer, "conv": _conv_mixer,
             "retention": partial(_retention_mixer, spec=spec),
         }[spec.kind]
         with jax.named_scope(f"{spec.kind}_mixer"):
@@ -1036,11 +1091,13 @@ def unified(
     (docs/architecture/cache_groups.md).
 
     A model with recurrent layers (``cfg.layer_kind``: "kda", a delta-rule
-    linear-attention layer; "retention", a power-retention layer) takes
+    linear-attention layer; "retention", a power-retention layer; "ssd", a
+    state-space layer; "conv", a gated short convolution) takes
     ``rec_state``, the state arrays of each of them in order (``cfg.
     recurrent_state_arrays``: a (state, convolution tail) pair; an (S, z)
-    pair), and ``state_slot``, and returns the new ``rec_state`` as its
-    last result; those layers' entries of ``kv_caches`` are empty."""
+    pair; the tail alone), and ``state_slot``, and returns the new
+    ``rec_state`` as its last result; those layers' entries of
+    ``kv_caches`` are empty."""
     from dynamo_tpu.models.moe import note_experts_hit
     from dynamo_tpu.ops.attention import pallas_enabled
 
@@ -1132,10 +1189,11 @@ def hidden_states(
         if spec.kind == "none":     # a feed-forward part alone
             x = _residual_mlp(x, layer, cfg, spec, h=h)
             continue
-        if spec.kind in ("kda", "ssd"):
+        if spec.kind in ("kda", "ssd", "conv"):
             # One span from zeros: slot 1 of a fresh two-slot state.
             one = jnp.ones((1,), jnp.int32)
-            mixer = _kda_mixer if spec.kind == "kda" else _ssd_mixer
+            mixer = {"kda": _kda_mixer, "ssd": _ssd_mixer,
+                     "conv": _conv_mixer}[spec.kind]
             y, _ = mixer(
                 layer, h, cfg,
                 tuple(
@@ -1252,6 +1310,69 @@ def _load_one_part_layers(cfg: ModelConfig, tensors: dict, w) -> Params:
     }
 
 
+def _load_lfm2_layers(cfg: ModelConfig, tensors: dict, w) -> Params:
+    """The params of an LFM2 model (``cfg.layer_types``; HF ``lfm2_moe``)
+    from its checkpoint tensors: ``model.layers.{i}.operator_norm`` and
+    ``.ffn_norm``; a conv layer's ``conv.in_proj``, ``conv.conv`` ([D, 1,
+    K] -> our [K, D]) and ``conv.out_proj``; an attention layer's
+    ``self_attn.{q,k,v}_proj``, ``out_proj`` and ``{q,k}_layernorm``; a
+    dense layer's ``feed_forward.{w1,w3,w2}`` (gate, up, down); an expert
+    layer's ``feed_forward.gate``, ``expert_bias`` and
+    ``experts.{e}.{w1,w3,w2}``; ``model.embedding_norm`` behind the last
+    layer. ``w(name)`` reads a tensor transposed to [in, out]. No
+    checkpoint of the family is here: the names are the public modelling
+    code's, exercised on a seeded state dict (tests/test_lfm2.py)."""
+    layers = []
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}"
+        layer = {
+            "ln_attn": w(f"{p}.operator_norm.weight", transpose=False),
+            "ln_mlp": w(f"{p}.ffn_norm.weight", transpose=False),
+        }
+        if cfg.layer_kind(i) == "conv":
+            taps = jnp.asarray(tensors[f"{p}.conv.conv.weight"])
+            layer.update(
+                w_in=w(f"{p}.conv.in_proj.weight"),
+                conv_w=taps[:, 0, :].T.astype(layer["ln_attn"].dtype),
+                w_out=w(f"{p}.conv.out_proj.weight"),
+            )
+        else:
+            a = f"{p}.self_attn"
+            layer.update(
+                wq=w(f"{a}.q_proj.weight"), wk=w(f"{a}.k_proj.weight"),
+                wv=w(f"{a}.v_proj.weight"), wo=w(f"{a}.out_proj.weight"),
+                ln_q_head=w(f"{a}.q_layernorm.weight", transpose=False),
+                ln_k_head=w(f"{a}.k_layernorm.weight", transpose=False),
+            )
+        f = f"{p}.feed_forward"
+        names = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
+        if cfg.moe_layer(i):
+            layer["w_router"] = w(f"{f}.gate.weight")
+            layer["router_bias"] = (
+                jnp.asarray(tensors[f"{f}.expert_bias"], jnp.float32)
+                if cfg.use_expert_bias
+                else jnp.zeros((cfg.num_experts,), jnp.float32)
+            )
+            lo = cfg.expert_held_offset
+            for ours, theirs in names:
+                layer[ours] = jnp.stack([
+                    w(f"{f}.experts.{e}.{theirs}.weight")
+                    for e in range(lo, lo + cfg.experts_here)
+                ])
+        else:
+            for ours, theirs in names:
+                layer[ours] = w(f"{f}.{theirs}.weight")
+        layers.append(layer)
+    params = {
+        "embed": w("model.embed_tokens.weight", transpose=False),
+        "layers": layers,
+        "ln_f": w("model.embedding_norm.weight", transpose=False),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w("lm_head.weight")
+    return params
+
+
 def load_hf_weights(
     cfg: ModelConfig,
     model_dir: str,
@@ -1297,13 +1418,15 @@ def load_hf_weights(
             arr = arr.T
         return jnp.asarray(arr, dtype=dtype)
 
-    if cfg.layer_pattern:
+    if cfg.layer_pattern or cfg.layer_types:
         if policy is not None and policy.active:
             raise NotImplementedError(
                 "load_hf_weights: a weight-quant policy over a model whose "
-                "layers are one part each is not implemented"
+                "layers are one part each, or whose mixers are convolutions, "
+                "is not implemented"
             )
-        return _load_one_part_layers(cfg, tensors, w)
+        load = _load_one_part_layers if cfg.layer_pattern else _load_lfm2_layers
+        return load(cfg, tensors, w)
     layers = []
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}"

@@ -38,6 +38,9 @@ class MoeConfig:
     # selection-bias correction; Mixtral/V2 use "softmax").
     gating: str = "softmax"
     norm_topk_prob: bool = True
+    # e > 0: the chosen experts' weights are s / (sum s + e), as LFM2's
+    # modelling code normalises them (1e-6); 0 = s / sum s.
+    norm_topk_eps: float = 0.0
     routed_scaling_factor: float = 1.0
     # Group-limited selection ("noaux_tc"): experts split into n_group
     # groups; each group scores as the sum of its top-2 biased scores and
@@ -150,8 +153,10 @@ def moe_route(
     _, topi = jax.lax.top_k(sel, cfg.num_experts_per_tok)        # [T, k]
     gates_k = jnp.take_along_axis(probs, topi, axis=-1)          # [T, k]
     if cfg.norm_topk_prob:
-        gates_k = gates_k / jnp.maximum(
-            gates_k.sum(axis=-1, keepdims=True), 1e-20
+        total = gates_k.sum(axis=-1, keepdims=True)
+        gates_k = gates_k / (
+            total + cfg.norm_topk_eps if cfg.norm_topk_eps
+            else jnp.maximum(total, 1e-20)
         )
     return topi, gates_k * cfg.routed_scaling_factor
 

@@ -194,6 +194,9 @@ def test_ragged_kernel_compiles_at_the_cells_chip_shapes(
         (128, 8, 1, 1024, 4096),  # command-a-plus: a window layer
         (128, 8, 1, 1024, 0),     # and a full one
         (32, 32, 1, 256, 0),      # no GQA: 256 KiB pages, folds of 128 keys
+        # lfm2-24b-a2b-l10: 64-wide heads stored a lane row (128) wide, at
+        # the budget its cell serves
+        (32, 8, 1, 512, 0),
     ],
 )
 def test_ragged_kernel_compiles_over_joined_pages(
@@ -500,6 +503,67 @@ def test_nemotron_share_step_compiles_at_published_widths(
     # the state and the pages alias their outputs
     # (13.117 GB of arguments, 0.18 GB of temporaries, 3.82 GB aliased)
     assert 12.9e9 < mem.argument_size_in_bytes < 13.4e9, (
+        mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
+
+
+@pytest.mark.slow  # a minute of many-threaded compiling beside the suite's timing-gated tests
+@pytest.mark.parametrize("T", [512, 1024])
+def test_lfm2_l10_step_compiles_at_published_widths(
+    mosaic, one_chip, monkeypatch, T
+):
+    """The whole of ``lfm2-24b-a2b-l10`` (eight gated short convolutions,
+    two attention layers of 32 heads over 8 cached heads of 64 stored 128
+    wide, two dense MLPs, eight expert layers of all 64 experts, the whole
+    tied vocabulary) in one unified step with the state table of 128
+    lanes: the ragged kernel over lane-padded joined pages, the grouped
+    expert path's three kernels a layer, no kernel of the convolution's
+    own, within one chip's memory."""
+    from dynamo_tpu.models import moe
+
+    monkeypatch.setenv("DYNAMO_TPU_PALLAS", "1")
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = ModelConfig.lfm2_24b_a2b_l10()
+    sds = partial(_sds, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
+    lanes, rows = 129, 132
+    # an attention layer's pages, joined, a head stored 128 wide
+    pages = sds((16385, 2, BS, 8, 128), jnp.bfloat16)
+    kv = [(pages,) if cfg.layer_kind(li) == "attn" else ()
+          for li in range(cfg.num_layers)]
+    rec = [
+        tuple(sds(shape, dt) for shape, dt in
+              cfg.recurrent_state_arrays(li, lanes, "bfloat16"))
+        for li in cfg.recurrent_layers
+    ]
+    assert [len(r) for r in rec] == [1] * 8
+    i32 = partial(sds, dtype=jnp.int32)
+    meta = (
+        i32((T,)), i32((T,)), i32((T,)), i32((T,)), i32((rows, 128)),
+        i32((rows,)), i32((rows,)), i32((rows,)), i32((rows,)),
+    )
+
+    def step(params, kv, rec, slot, *meta):
+        logits, kv, rec = llama.unified(
+            cfg, params, kv, *meta, BS, attn=AttnDispatch(use_pallas=True),
+            rec_state=rec, state_slot=slot,
+        )
+        return jnp.argmax(logits, axis=-1), kv, rec
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, kv, rec, i32((rows,)), *meta
+    ).compile()
+    # 2 attention layers' ragged kernel, 8 expert layers x (gate, up, down)
+    assert _kernel_count(compiled.as_text()) == 2 + 24
+    mem = compiled.memory_analysis()
+    # weights 10.53 GB, pages 2 x 1.07 GB, the tails 8 MB: all arguments,
+    # and the tails and the pages alias their outputs
+    assert 12.5e9 < mem.argument_size_in_bytes < 12.9e9, (
         mem.argument_size_in_bytes)
     assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes

@@ -31,18 +31,19 @@ from dynamo_tpu.parallel.mesh import build_mesh
 #: KDA + experts, KDA + experts under a routed clamp, under a shared clamp,
 #: latent attention + experts; tiny-command-a-test: window layers with
 #: rotary pairs, full layers without; tiny-nemotron-h-test: a state-space
-#: mixer alone, attention alone, experts alone.
+#: mixer alone, attention alone, experts alone; tiny-lfm2-test: a gated
+#: convolution + dense MLP, a convolution + experts, attention + experts.
 TINY_BODIES = {
     "tiny-test": 1, "tiny-moe-test": 1, "tiny-mla-test": 2,
     "tiny-gemma-test": 2, "tiny-sdar-test": 1, "tiny-ling-test": 5,
     "tiny-command-a-test": 2, "tiny-brumby-test": 1,
-    "tiny-nemotron-h-test": 3,
+    "tiny-nemotron-h-test": 3, "tiny-lfm2-test": 3,
 }
 #: And of the configurations the benchmark's cells serve (ISSUE 50's table).
 CELL_BODIES = {
     "mistral-7b": 1, "mixtral-8x7b": 1, "sdar-30b-a3b": 1, "brumby-14b": 1,
     "command-a-plus-ep8-l4": 2, "ling-3.0-flash-ep4-l8": 3,
-    "nemotron-3-super-ep4-l11": 3,
+    "nemotron-3-super-ep4-l11": 3, "lfm2-24b-a2b-l10": 3,
 }
 
 
@@ -186,6 +187,7 @@ BODY_AND_HELPERS = (
     "_layer", "_layer_rows", "_rope_qk", "_residual_attn", "_residual_mlp", "_mlp",
     "_moe_mlp", "_qkv", "_qkv_mla", "_mla_out", "_kda_mixer",
     "_retention_inputs", "_retention_mixer", "_ssd_mixer", "_relu2",
+    "_conv_mixer",
 )
 
 
